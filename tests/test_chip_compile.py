@@ -1,0 +1,198 @@
+"""Compile the main-path kernels for a described TPU v5e — no chip needed.
+
+The TPU compiler is installed with libtpu and compiles for a topology
+that is described, not attached: ``jit(...).lower(shapes).compile()``
+raises here what the chip's compiler would raise there (a Mosaic
+lowering failure, a slice off the tiling, too much VMEM/SMEM, a program
+that does not fit HBM).  Nothing runs, so this says nothing about
+verdicts or times — ``chip_smoke.py`` on the chip does.
+
+Everything about the topology happens inside the module-scoped ``topo``
+fixture (never at import, in a ``skipif`` or a ``parametrize`` argument):
+only one process may load libtpu, and every xdist worker imports every
+test file.  The kernels need only their geometry, so every operand is a
+``ShapeDtypeStruct`` and no graph is packed.  Keep these tests in this
+one file — a second file could land on another worker, whose fixture
+would then skip.
+"""
+
+import numpy as np
+import pytest
+
+from uigc_tpu.ops import pallas_decremental as pd
+from uigc_tpu.ops import pallas_trace as pt
+
+LANE = pt.LANE
+BLOCK_ROWS = pt.ROWS * pt.SUB_TPU
+GROUP_ROWS = pt.ROWS * pt.GROUP_TPU
+
+#: BASELINE config 5 as ``IncrementalPallasLayout.rebuild`` packs it
+#: (powerlaw_actor_graph(10M, seed 0): 49.7M pairs, quantum-padded
+#: blocks) — the geometry chip_smoke.py prints on the chip.
+GEOM_10M = dict(n=10_000_000, n_blocks=24_576, n_super=2442, r_rows=2496)
+#: a 20k-actor layout (pow2-padded blocks)
+GEOM_SMALL = dict(n=20_000, n_blocks=128, n_super=5, r_rows=64)
+#: 10M over four shards, as ``pack_shard_layouts`` packs it
+MESH_10M = dict(n_pad=10_010_624, n_blocks=16_384, r_rows=2496, bucket_m=1024)
+MESH_SMALL = dict(n_pad=65_536, n_blocks=256, r_rows=64, bucket_m=1024)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip can be written to the persistent
+    # cache but never read back without one; keep it off around these.
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _struct(shape, dtype, sharding):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _layout_structs(geom, s):
+    rows = geom["n_blocks"] * BLOCK_ROWS
+    return [
+        _struct((geom["n_blocks"],), np.int32, s),  # bmeta1
+        _struct((geom["n_blocks"],), np.int32, s),  # bmeta2
+        _struct((rows, LANE), np.int32, s),  # row_pos
+        _struct((rows, LANE), np.int32, s),  # emeta
+    ]
+
+
+def _node_structs(geom, s):
+    # recv_count is int64 on the host; x64 is off, so the device sees int32
+    return [
+        _struct((geom["n"],), np.uint8, s),
+        _struct((geom["n"],), np.int32, s),
+    ]
+
+
+def _spec(geom):
+    return (("dense", geom["n_blocks"], pt.SUB_TPU, pt.GROUP_TPU),)
+
+
+def _compile_trace(geom, s, mode, with_stats=False):
+    fn = pt.get_trace_fn_multi(
+        geom["n"], _spec(geom), geom["n_super"], geom["r_rows"], pt.S_ROWS,
+        interpret=False, mode=mode, with_stats=with_stats,
+    )
+    args = _node_structs(geom, s)
+    if mode in (pt.MODE_JUMP, pt.MODE_AUTO):
+        args.append(_struct((geom["n"] + 1,), np.int32, s))
+    return fn.lower(*args, *_layout_structs(geom, s)).compile()
+
+
+def _compile_wake(geom, s, mode, with_stats=False):
+    fn = pd.get_wake_fn(
+        geom["n"], _spec(geom), geom["n_super"], geom["r_rows"], pt.S_ROWS,
+        interpret=False, mode=mode, with_stats=with_stats,
+    )
+    words = _struct((geom["r_rows"], LANE), np.int32, s)
+    args = _node_structs(geom, s) + [words] * 7
+    if mode in (pt.MODE_JUMP, pt.MODE_AUTO):
+        args.append(_struct((geom["n"] + 1,), np.int32, s))
+    return fn.lower(*args, *_layout_structs(geom, s)).compile()
+
+
+def _mosaic_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def test_full_trace_compiles_at_10m(one_chip):
+    compiled = _compile_trace(GEOM_10M, one_chip, pt.MODE_AUTO)
+    assert _mosaic_calls(compiled) >= 1
+    mem = compiled.memory_analysis()
+    # operands + temps must fit one v5e's 16 GB with room to spare
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
+
+
+def test_decremental_wake_compiles_at_10m(one_chip):
+    compiled = _compile_wake(GEOM_10M, one_chip, pt.MODE_AUTO)
+    assert _mosaic_calls(compiled) >= 2  # closure + repair fixpoints
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
+
+
+@pytest.mark.parametrize("with_stats", [False, True], ids=["plain", "stats"])
+@pytest.mark.parametrize("mode", pt.TRACE_MODES)
+@pytest.mark.parametrize("program", ["trace", "wake"])
+def test_trace_modes_compile(one_chip, program, mode, with_stats):
+    build = _compile_trace if program == "trace" else _compile_wake
+    assert _mosaic_calls(build(GEOM_SMALL, one_chip, mode, with_stats)) >= 1
+
+
+def test_int8_contraction_compiles(one_chip, monkeypatch):
+    """UIGC_KERNEL_INT8=1 swaps the one-hot contraction's datapath; the
+    flag is read at kernel build time and keyed into the fn cache."""
+    monkeypatch.setenv("UIGC_KERNEL_INT8", "1")
+    assert _mosaic_calls(_compile_trace(GEOM_SMALL, one_chip, pt.MODE_AUTO)) >= 1
+
+
+@pytest.mark.parametrize("geom", [MESH_SMALL, MESH_10M], ids=["64k", "10m"])
+@pytest.mark.parametrize("program", ["trace", "wake"])
+def test_sharded_programs_compile(topo, program, geom):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from uigc_tpu.parallel import sharded_trace as st
+
+    D = 4
+    mesh = Mesh(np.array(topo.devices[:D]), ("gc",))
+    nodes = NamedSharding(mesh, P("gc"))
+    dev = NamedSharding(mesh, P("gc", None))
+    dev3 = NamedSharding(mesh, P("gc", None, None))
+    n_pad, nb = geom["n_pad"], geom["n_blocks"]
+    make = (
+        st.make_sharded_pallas_trace
+        if program == "trace"
+        else st.make_sharded_decremental_wake
+    )
+    fn = make(
+        mesh, n_pad, n_pad // D, nb, geom["r_rows"], pt.S_ROWS,
+        geom["bucket_m"], interpret=False, sub=pt.SUB_TPU,
+        group=pt.GROUP_TPU, mode=pt.MODE_AUTO,
+    )
+    args = [
+        _struct((n_pad,), np.uint8, nodes),
+        _struct((n_pad,), np.int32, nodes),
+    ]
+    if program == "wake":
+        args += [_struct((n_pad // 32,), np.int32, nodes)] * 7
+    args += [
+        _struct((D, nb), np.int32, dev),
+        _struct((D, nb), np.int32, dev),
+        _struct((D, nb * BLOCK_ROWS, LANE), np.int32, dev3),
+        _struct((D, nb * BLOCK_ROWS, LANE), np.int32, dev3),
+        _struct((D, geom["bucket_m"]), np.int32, dev),
+        _struct((D, geom["bucket_m"]), np.int32, dev),
+        _struct((n_pad + 1,), np.int32, NamedSharding(mesh, P())),
+    ]
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= (1 if program == "trace" else 2)
+    assert "all-gather" in text

@@ -414,6 +414,47 @@ def test_ring_concurrent_writer_reader():
     assert flat.tolist() == list(range(total))
 
 
+def test_drain_is_a_consistent_cut(monkeypatch):
+    """An actor flushes from two threads (constructor on the spawner's,
+    batches on a dispatcher's).  Its older row lands in ring A just
+    after A was drained, its newer row in ring B just before B is:
+    folding the newer one alone would break per-actor FIFO (the older
+    row carries the creator's ref, so the actor would be swept alive).
+    Rows stamped after the drain began wait for the next drain."""
+    plane = PackedPlane(entry_field_size=4)
+    rings = {}
+    for name in "AB":  # one ring per thread, A registered (drained) first
+        t = threading.Thread(target=lambda n=name: rings.update({n: plane.ring()}))
+        t.start()
+        t.join()
+
+    def put(ring, uid):
+        v = ring.begin()
+        v[:] = -1
+        v[0] = plane.next_seq()
+        v[1] = uid
+        ring.commit()
+
+    put(rings["B"], 1)  # stamped before the drain: belongs to it
+    orig = PackedRing.drain
+    raced = []
+
+    def drain(self):
+        out = orig(self)
+        if self is rings["A"] and not raced:
+            raced.append(True)
+            put(rings["A"], 7)  # the actor's older row: A already drained
+            put(rings["B"], 7)  # its newer row: B not drained yet
+        return out
+
+    monkeypatch.setattr(PackedRing, "drain", drain)
+    first = plane.drain()
+    assert first[:, 1].tolist() == [1]
+    second = plane.drain()
+    assert sorted(second[:, 1].tolist()) == [7, 7]
+    assert plane.drain() is None
+
+
 def test_packed_plane_default_on_single_node():
     """Engine wiring: single-node array backend gets the plane; the
     oracle backend (no array fold) does not."""

@@ -186,19 +186,13 @@ def test_int8_mxu_flag_parity():
     must produce oracle-identical marks.  The subprocess arm validates
     the env wiring end-to-end (a fresh interpreter with the flag set);
     test_int8_ab_in_process covers the in-process A/B path."""
-    import subprocess
-    import sys
-
-    _run_int8_subprocess(pin_cpu=True)
-
-
-def _run_int8_subprocess(pin_cpu: bool):
     import os
     import subprocess
     import sys
 
     code = """
-PIN_CPU
+import jax
+jax.config.update("jax_platforms", "cpu")
 import numpy as np
 from uigc_tpu.ops import pallas_trace, trace as trace_ops
 assert pallas_trace._int8_mxu(), "int8 flag did not take effect"
@@ -211,12 +205,7 @@ assert np.array_equal(
     pallas_trace.trace_marks_pallas(*g), trace_ops.trace_marks_np(*g)
 )
 print("INT8 PARITY OK")
-""".replace(
-        "PIN_CPU",
-        'import jax\njax.config.update("jax_platforms", "cpu")'
-        if pin_cpu
-        else "",
-    )
+"""
     env = dict(os.environ, UIGC_KERNEL_INT8="1")
     out = subprocess.run(
         [sys.executable, "-c", code],
@@ -230,16 +219,28 @@ print("INT8 PARITY OK")
 
 
 @pytest.mark.tpu
-def test_int8_mxu_compiled_parity():
+def test_int8_mxu_compiled_parity(monkeypatch):
     """The int8 contraction through the real Mosaic lowering — interpret
-    mode cannot catch an int8-dot lowering failure."""
-    _run_int8_subprocess(pin_cpu=False)
+    mode cannot catch an int8-dot lowering failure.  In process: this
+    process holds the chip, so a child could not reach it (the flag is
+    read at kernel build time and keyed into the fn cache)."""
+    from uigc_tpu.ops import pallas_trace, trace as trace_ops
+
+    monkeypatch.setenv("UIGC_KERNEL_INT8", "1")
+    assert pallas_trace._int8_mxu()
+    rng = np.random.default_rng(3)
+    flags, recv, supervisor, src, dst, w = g = random_graph(rng, 1200, 5000)
+    prep = pallas_trace.prepare_chunks(src, dst, w, supervisor, 1200)
+    got = pallas_trace.trace_marks_layouts(
+        flags, recv, [prep], interpret=False
+    )
+    assert np.array_equal(got, trace_ops.trace_marks_np(*g))
 
 
 def test_int8_ab_in_process(monkeypatch):
     """UIGC_KERNEL_INT8 is read at kernel build time and keyed into the
-    fn cache, so one process can A/B both MXU datapaths (VERDICT r4
-    weak #6: the old import-time read froze the choice per process).
+    fn cache, so one process can A/B both MXU datapaths (an
+    import-time read would freeze the choice per process).
     The contraction is exact in both (operands are 0/1 bits)."""
     import numpy as np
 

@@ -572,6 +572,11 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     args = parser.parse_args(argv)
 
+    if args.selfcheck or args.demo:
+        # the two modes that drive a device backend
+        from uigc_tpu.utils.platform import enable_compile_cache
+
+        enable_compile_cache()
     if args.selfcheck:
         return run_selfcheck()
     if args.demo:

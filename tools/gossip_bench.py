@@ -23,6 +23,10 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 BASE = {
+    # Two OS processes (parent + Popen'd child), and a chip belongs to
+    # one: both collectors stay on the host backend and neither touches
+    # a JAX backend.
+    "uigc.crgc.shadow-graph": "array",
     "uigc.crgc.wakeup-interval": 10,
     "uigc.crgc.egress-finalize-interval": 10,
     "uigc.crgc.num-nodes": 2,

@@ -1,9 +1,9 @@
 """Measure per-wake Pallas-layout maintenance: full repack vs incremental.
 
 Round 1 re-ran prepare_chunks (a full lexsort over every live pair)
-before nearly every collector wake on a churning graph (VERDICT r1, weak
-item 3).  The incremental layout (ops/pallas_incremental.py) replaces
-that with O(changes) maintenance: in-place masking for deletes plus a
+before nearly every collector wake on a churning graph.  The incremental
+layout (ops/pallas_incremental.py) replaces that with O(changes)
+maintenance: in-place masking for deletes plus a
 small delta pack for inserts.  This tool measures both costs on the same
 synthetic power-law graph and churn stream — host-side work only, so the
 numbers are platform-independent (the kernel itself is benchmarked by

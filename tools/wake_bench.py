@@ -60,9 +60,9 @@ def main():
     from uigc_tpu.ops import pallas_incremental as pinc
     from uigc_tpu.ops import trace as trace_ops
     from uigc_tpu.ops.slotmap import pack_keys
-    from uigc_tpu.utils.platform import apply_platform_override, is_tpu_platform
+    from uigc_tpu.utils.platform import enable_compile_cache, is_tpu_platform
 
-    apply_platform_override()
+    enable_compile_cache()
     platform = jax.devices()[0].platform
     on_tpu = is_tpu_platform(platform)
     n = args.actors or (10_000_000 if on_tpu and not args.small else 1 << 16)

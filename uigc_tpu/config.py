@@ -61,7 +61,7 @@ DEFAULTS: Dict[str, Any] = {
     #            array squared each sweep (O(log diameter) sweeps)
     #   "auto" - jump always on, pull gates switched per sweep when the
     #            dirty-chunk density crosses the pull threshold
-    # A config knob so A/B runs (BENCH_TPU_SESSION) need no code edits.
+    # A config knob so A/B runs need no code edits.
     "uigc.crgc.trace-mode": "auto",
     # Dirty-chunk density (fraction of walk chunks dirty) above which
     # "auto" turns the pull gates on for a sweep; tuned from

@@ -156,6 +156,15 @@ class ArrayShadowGraph:
         )
         self.decremental = decremental
         self._dec = None
+        #: which implementation the device trace resolved to, recorded
+        #: once at the first device wake: "pallas" (Mosaic-compiled, a
+        #: TPU), "pallas-interpret" (the kernel interpreted — CPU test
+        #: tier) or "xla" (the plain XLA trace — "device" off-TPU).
+        #: None until a device wake ran; a caller that needs the chip
+        #: asserts on it rather than trusting the platform default.
+        self.trace_impl: Optional[str] = None
+        #: device wakes dispatched (synchronous + pipelined)
+        self.device_wakes = 0
         self.total_actors_seen = 0
 
         cap = max(16, initial_capacity)
@@ -865,13 +874,14 @@ class ArrayShadowGraph:
 
     def compute_marks(self) -> np.ndarray:
         if self.use_device:
+            self._note_device_wake()
             with events.recorder.timed(events.DEVICE_TRACE) as ev:
                 if self.decremental:
                     return _readback(
                         self._compute_marks_decremental(ev),
                         "marks.decremental",
                     )
-                if self._on_tpu():
+                if self.trace_impl != "xla":
                     return _readback(
                         self._compute_marks_pallas(ev), "marks.pallas"
                     )
@@ -956,6 +966,26 @@ class ArrayShadowGraph:
 
             tpu = self._is_tpu = not pallas_trace.default_interpret()
         return tpu
+
+    def _uses_pallas(self) -> bool:
+        """Does this backend's device trace run the Pallas kernel?  The
+        decremental wake always does (interpreted off-TPU); the full
+        retrace takes the plain XLA trace off-TPU."""
+        return self.decremental or self._on_tpu()
+
+    def _note_device_wake(self) -> None:
+        """Count a device wake and, on the first, record which
+        implementation the platform resolved it to (``trace_impl``)."""
+        self.device_wakes += 1
+        if self.trace_impl is None:
+            from ...ops import pallas_trace
+
+            if not self._uses_pallas():
+                self.trace_impl = "xla"
+            elif pallas_trace.default_interpret():
+                self.trace_impl = "pallas-interpret"
+            else:
+                self.trace_impl = "pallas"
 
     def _stamp_sweep_stats(self, ev, stats: Optional[dict]) -> None:
         """Attach the fixpoint's per-sweep frontier decomposition to the
@@ -1119,6 +1149,7 @@ class ArrayShadowGraph:
 
         if self._pending_wake is not None:
             return
+        self._note_device_wake()
         handle, mark_dev = self._start_wake()
         self._pending_wake = (
             handle,

@@ -166,6 +166,9 @@ class MeshShadowGraph(ArrayShadowGraph):
 
         self._jit_cache: Dict[str, object] = {}
 
+    def _uses_pallas(self) -> bool:
+        return True  # every mesh trace runs the per-shard Pallas kernel
+
     @property
     def can_pipeline(self) -> bool:
         # The mesh pipelined wake overlaps host ingest with the SHARDED
@@ -640,6 +643,7 @@ class MeshShadowGraph(ArrayShadowGraph):
         return jax.device_put(words.view(np.int32), nodes_s)
 
     def compute_marks(self) -> np.ndarray:
+        self._note_device_wake()
         with events.recorder.timed(events.DEVICE_TRACE) as ev:
             ev.fields["trace_mode"] = self.trace_mode
             self._sync_device()

@@ -125,6 +125,8 @@ class PackedPlane:
         #: only at sweep keeps the release single-writer.
         self.uid_strong: Dict[int, object] = {}
         self._rings: Dict[int, PackedRing] = {}
+        #: rows drained past the last cut, folded by the next drain
+        self._held: Optional[np.ndarray] = None
         self._lock = threading.Lock()
         self._tl = threading.local()
 
@@ -145,11 +147,34 @@ class PackedPlane:
         return r
 
     def drain(self) -> Optional[np.ndarray]:
-        """All committed rows from every ring, unsorted (merge_packed
-        restores flush order from the seq column)."""
+        """The committed rows stamped before this call, from every ring,
+        unsorted (merge_packed restores flush order from the seq
+        column).
+
+        The rings are drained one after another, which alone is not a
+        consistent cut: one actor flushes from more than one thread (its
+        constructor runs on the spawner's thread, its batches on a
+        dispatcher's), so a ring drained later can hold that actor's
+        NEWER row while its older row — committed after the earlier
+        ring's drain — is missed.  Folding the newer row alone breaks
+        the per-actor FIFO that CRGC's soundness rests on (the older
+        row carries the creator's ref: without it the actor looks
+        unreferenced and is swept alive).  So a stamp is taken first
+        and rows at or past it are held for the next drain: a row below
+        the cut was stamped before the cut, its actor's older rows were
+        committed before that, and every ring's drain comes after."""
+        cut = self.next_seq()
         with self._lock:
             rings = list(self._rings.values())
         parts = [p for p in (r.drain() for r in rings) if p is not None]
+        if self._held is not None:
+            parts.append(self._held)
+            self._held = None
         if not parts:
             return None
-        return parts[0] if len(parts) == 1 else np.concatenate(parts)
+        rows = parts[0] if len(parts) == 1 else np.concatenate(parts)
+        late = rows[:, 0] >= cut
+        if late.any():
+            self._held = rows[late]
+            rows = rows[~late]
+        return rows if rows.shape[0] else None

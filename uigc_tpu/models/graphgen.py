@@ -16,12 +16,6 @@ from ..ops import trace as trace_ops
 
 _F = trace_ops
 
-#: Bump when the generator's model changes (degree law, attachment
-#: bias, garbage topology, rng stream).  Benchmark layout caches fold
-#: this into their key so a model change can never silently serve a
-#: packed graph the current code no longer generates.
-GRAPH_MODEL_VERSION = 1
-
 
 def powerlaw_actor_graph(
     n: int,

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, Optional
 
 from ..interfaces import Message, NoRefs
 from ..runtime.behaviors import AbstractBehavior, Behaviors
@@ -96,9 +96,11 @@ def run_tree(
     engine: str = "crgc",
     config: Optional[Dict[str, Any]] = None,
     timeout_s: float = 300.0,
+    inspect: Optional[Callable[[ActorSystem], None]] = None,
 ) -> Dict[str, Any]:
     """Configs 1-2: an acyclic ownership tree of ``n_actors`` is released
-    by the root and must be fully collected.
+    by the root and must be fully collected.  ``inspect`` is called with
+    the live system after the last actor stopped, before it terminates.
 
     The root spawns the top level directly, so ``fanout >= n_actors``
     yields a flat topology — the shape a weighted-refcount engine (MAC)
@@ -139,6 +141,8 @@ def run_tree(
         left = latch.await_zero(timeout_s)
         collect_s = time.perf_counter() - t0
         assert left == 0, f"{left} actors never collected"
+        if inspect is not None:
+            inspect(system)
         return {"n_collected": n_actors, "build_s": build_s, "collect_s": collect_s}
     finally:
         system.terminate()
@@ -149,9 +153,11 @@ def run_rings(
     ring_size: int = 100,
     config: Optional[Dict[str, Any]] = None,
     timeout_s: float = 300.0,
+    inspect: Optional[Callable[[ActorSystem], None]] = None,
 ) -> Dict[str, Any]:
     """Config 3: mutually-referencing actor rings — cyclic garbage that a
-    trace-based engine must collect after the root releases the heads."""
+    trace-based engine must collect after the root releases the heads.
+    ``inspect`` as in :func:`run_tree`."""
     n_actors = n_rings * ring_size
     latch = _Latch(n_actors)
 
@@ -203,6 +209,8 @@ def run_rings(
         left = latch.await_zero(timeout_s)
         collect_s = time.perf_counter() - t0
         assert left == 0, f"{left} ring members never collected"
+        if inspect is not None:
+            inspect(system)
         return {"n_collected": n_actors, "build_s": build_s, "collect_s": collect_s}
     finally:
         system.terminate()

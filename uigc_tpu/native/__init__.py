@@ -10,6 +10,8 @@ time it is needed; ``is_available()`` reports whether that worked.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,7 +19,15 @@ from typing import Optional
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "crgc_shadow.cpp")
-_LIB = os.path.join(_HERE, "libuigc_crgc.so")
+
+
+def _lib_path() -> str:
+    """The library's name carries a digest of the source it was built
+    from, so the one that loads is always the one the committed source
+    gives — mtimes survive neither git checkouts nor machine copies."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"libuigc_crgc.{digest}.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -29,10 +39,10 @@ _p_i32 = ctypes.POINTER(ctypes.c_int32)
 _p_u8 = ctypes.POINTER(ctypes.c_uint8)
 
 
-def _build() -> None:
+def _build(lib_path: str) -> None:
     # Unique temp name: concurrent builders (separate processes) must not
     # clobber each other's half-written output before the atomic replace.
-    tmp = f"{_LIB}.{os.getpid()}.tmp"
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", _SRC, "-o", tmp]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True)
@@ -40,10 +50,17 @@ def _build() -> None:
             raise RuntimeError(
                 f"g++ failed (exit {proc.returncode}): {proc.stderr.strip()}"
             )
-        os.replace(tmp, _LIB)
+        os.replace(tmp, lib_path)
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
+    # builds of earlier sources
+    for stale in glob.glob(os.path.join(_HERE, "libuigc_crgc*.so")):
+        if stale != lib_path:
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass
 
 
 def _declare(lib: ctypes.CDLL) -> None:
@@ -101,19 +118,16 @@ def load() -> ctypes.CDLL:
         if _build_error is not None:
             raise RuntimeError(f"native library unavailable: {_build_error}")
         try:
-            # mtimes survive neither git checkouts nor cross-machine
-            # copies, so a same-age .so is treated as stale too; and if a
-            # prebuilt .so fails to load (wrong arch/libc), rebuild once
-            # from source before giving up.
-            if not os.path.exists(_LIB) or (
-                os.path.getmtime(_LIB) <= os.path.getmtime(_SRC)
-            ):
-                _build()
+            # If a prebuilt .so fails to load (wrong arch/libc), rebuild
+            # once from source before giving up.
+            lib_path = _lib_path()
+            if not os.path.exists(lib_path):
+                _build(lib_path)
             try:
-                lib = ctypes.CDLL(_LIB)
+                lib = ctypes.CDLL(lib_path)
             except OSError:
-                _build()
-                lib = ctypes.CDLL(_LIB)
+                _build(lib_path)
+                lib = ctypes.CDLL(lib_path)
             _declare(lib)
         except Exception as exc:  # noqa: BLE001 - report any toolchain failure
             _build_error = str(exc)

@@ -84,6 +84,14 @@ def _native_lib_checked():
     return lib
 
 
+def probe_backend() -> str:
+    """Which probe implementation batches run on: ``"native"`` (the C
+    kernels) or ``"numpy"`` (no toolchain, a load failure or a hash
+    mismatch).  Same results either way; entry points print it so a
+    run says what its fold was."""
+    return "native" if _native_lib_checked() is not None else "numpy"
+
+
 def _ptr(a: np.ndarray):
     return a.ctypes.data_as(_C_I64_P)
 

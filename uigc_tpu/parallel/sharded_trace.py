@@ -142,23 +142,16 @@ def shard_graph(
     }
 
 
-def _shard_map_compat(local_fn, mesh, in_specs, out_specs):
-    """shard_map with the check_vma/check_rep compat fallback (pallas_call
-    does not propagate the varying-mesh-axes annotation)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(
-            local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except TypeError:
-        return shard_map(
-            local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+def _shard_map_unchecked(local_fn, mesh, in_specs, out_specs):
+    """shard_map with the varying-mesh-axes check off: pallas_call does
+    not propagate the annotation, and jax has no replication rule for
+    the while fixpoint under shard_map."""
+    from jax import shard_map
+
+    return shard_map(
+        local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 def _seed_masks(flags, recv):
@@ -317,10 +310,7 @@ def make_sharded_trace(mesh, axis: str = "gc"):
     spec_nodes = P(axis)
     spec_pairs = P(axis, None)
 
-    # check_vma/check_rep must be off: jax has no replication rule for
-    # the while fixpoint under shard_map (the compat shim handles both
-    # keyword spellings across jax versions).
-    fn = _shard_map_compat(
+    fn = _shard_map_unchecked(
         local_trace,
         mesh,
         (spec_nodes, spec_nodes, spec_pairs, spec_pairs),
@@ -576,7 +566,7 @@ def make_sharded_pallas_trace(
     )
     if use_jump:
         in_specs = in_specs + (P(),)  # replicated jump parents
-    fn = _shard_map_compat(local_trace, mesh, in_specs, spec_dev)
+    fn = _shard_map_unchecked(local_trace, mesh, in_specs, spec_dev)
 
     @jax.jit
     def traced(*args):
@@ -594,10 +584,7 @@ def make_sharded_mask(mesh, axis: str = "gc"):
     fn(row_pos, emeta, ri, col) with row_pos/emeta [D, nb*8, LANE] and
     ri/col [D, k] (ri padded with nb*8 = dropped)."""
     jax, jnp = _jax()
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..ops import pallas_trace as pt
@@ -645,10 +632,7 @@ def make_sharded_fold(mesh, axis: str = "gc", donate: bool = False):
     caller (the live mesh backend, per wake) updates its device arrays in
     place instead of copying the whole sharded state per fold."""
     jax, jnp = _jax()
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local_fold(flags, recv, slot, recv_delta, flag_set, flag_clear):
@@ -917,7 +901,7 @@ def make_sharded_decremental_wake(
     if use_jump:
         in_specs = in_specs + (P(),)  # replicated jump parents
     out_specs = (spec_dev,) * 6
-    fn = _shard_map_compat(local_wake, mesh, in_specs, out_specs)
+    fn = _shard_map_unchecked(local_wake, mesh, in_specs, out_specs)
 
     @jax.jit
     def wake(*args):
