@@ -108,10 +108,10 @@ def _compile_trace(geom, s, mode, with_stats=False):
     return fn.lower(*args, *_layout_structs(geom, s)).compile()
 
 
-def _compile_wake(geom, s, mode, with_stats=False):
+def _compile_wake(geom, s, mode):
     fn = pd.get_wake_fn(
         geom["n"], _spec(geom), geom["n_super"], geom["r_rows"], pt.S_ROWS,
-        interpret=False, mode=mode, with_stats=with_stats,
+        interpret=False, mode=mode,
     )
     words = _struct((geom["r_rows"], LANE), np.int32, s)
     args = _node_structs(geom, s) + [words] * 7
@@ -139,12 +139,36 @@ def test_decremental_wake_compiles_at_10m(one_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
 
 
-@pytest.mark.parametrize("with_stats", [False, True], ids=["plain", "stats"])
-@pytest.mark.parametrize("mode", pt.TRACE_MODES)
-@pytest.mark.parametrize("program", ["trace", "wake"])
+@pytest.mark.parametrize(
+    "program,mode,with_stats",
+    # the full trace has a with_stats variant; the wake has one program
+    [("trace", m, s) for m in pt.TRACE_MODES for s in (False, True)]
+    + [("wake", m, False) for m in pt.TRACE_MODES],
+    ids=lambda v: {False: "plain", True: "stats"}.get(v, v),
+)
 def test_trace_modes_compile(one_chip, program, mode, with_stats):
-    build = _compile_trace if program == "trace" else _compile_wake
-    assert _mosaic_calls(build(GEOM_SMALL, one_chip, mode, with_stats)) >= 1
+    if program == "trace":
+        compiled = _compile_trace(GEOM_SMALL, one_chip, mode, with_stats)
+    else:
+        compiled = _compile_wake(GEOM_SMALL, one_chip, mode)
+    assert _mosaic_calls(compiled) >= 1
+
+
+@pytest.mark.parametrize("mode", pt.TRACE_MODES)
+def test_wake_program_counts_and_names(one_chip, mode):
+    """The one wake program per mode: its outputs hold the sweep
+    counters, and the compiled text names its phases and its kernel, so a
+    device trace of it can be summed by phase."""
+    compiled = _compile_wake(GEOM_SMALL, one_chip, mode)
+    *words, stats = compiled.out_info
+    assert len(words) == 5
+    assert stats["closure_sweeps"].shape == stats["n_sweeps"].shape == ()
+    for key in ("dirty_chunks", "tiles_skipped", "pull_on"):
+        assert stats[key].shape == (pt.MAX_SWEEP_STATS,)
+    text = compiled.as_text()
+    for phase in ("closure", "repair"):
+        assert f"{pd.WAKE_SCOPE}/{phase}" in text
+    assert pt.KERNEL_NAME in text
 
 
 def test_int8_contraction_compiles(one_chip, monkeypatch):

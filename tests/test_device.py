@@ -53,7 +53,6 @@ from uigc_tpu.engines.crgc.arrays import audit_donation  # noqa: E402
 from uigc_tpu.telemetry.device import (  # noqa: E402
     DeviceObservatory,
     ledger_families,
-    sweep_attribution,
     validate_device_doc,
 )
 from uigc_tpu.telemetry.metrics import MetricsRegistry  # noqa: E402
@@ -71,31 +70,6 @@ def clean_recorder():
     events.recorder.reset()
     with events.recorder._lock:
         events.recorder._listeners.clear()
-
-
-# ------------------------------------------------------------------- #
-# Attribution math
-# ------------------------------------------------------------------- #
-
-
-def test_sweep_attribution_reconciles_by_construction():
-    ms, bytes_est = sweep_attribution(0.012, 3, [100, 50, 1])
-    assert len(ms) == len(bytes_est) == 3
-    assert abs(sum(ms) - 12.0) < 1e-9
-    # dirty-chunk weighting: the 100-chunk sweep gets 100/151 of it
-    assert ms[0] > ms[1] > ms[2]
-    assert abs(ms[0] - 12.0 * 100 / 151) < 1e-9
-    assert bytes_est[0] == 100 * 12288  # CHUNK_BYTES_EST
-
-
-def test_sweep_attribution_degrades_without_stats():
-    ms, _ = sweep_attribution(0.010, 4, None)
-    assert len(ms) == 4
-    assert all(abs(x - 2.5) < 1e-9 for x in ms)
-    assert sweep_attribution(0.010, 0, None) == ([], [])
-    # short stats vector: missing entries weight 1, never raises
-    ms, _ = sweep_attribution(0.010, 3, [7])
-    assert abs(sum(ms) - 10.0) < 1e-9
 
 
 # ------------------------------------------------------------------- #
@@ -241,8 +215,8 @@ def test_validate_device_doc_rejects_malformed():
     try:
         doc = good.to_doc()
         assert validate_device_doc(doc) == []
-        doc["recent_wakes"] = [{"n_sweeps": 2, "sweep_device_ms": [1.0]}]
-        assert any("sweep_device_ms" in p for p in validate_device_doc(doc))
+        doc["recent_wakes"] = [{"n_sweeps": 2, "sweep_dirty_chunks": [1, 1, 1]}]
+        assert any("sweep_dirty_chunks" in p for p in validate_device_doc(doc))
     finally:
         good.close()
 
@@ -393,8 +367,12 @@ def test_device_observatory_live_planes():
             assert entry["misses"] <= 1, (geom, entry)
         assert sum(e["hits"] for e in dec_streams.values()) >= 3
 
-        # -- sweep plane: attribution reconciles with the profiler's
-        # device phase (record["device_s"]) within 10% per wake.
+        # -- sweep plane: the wake program's own counters reach the
+        # records, and the four phases of the device call lie inside the
+        # bracket around them (record["device_s"]) and fill it to within
+        # 10% (in the best wake: at this size a call takes milliseconds,
+        # and a thread switch between two brackets of a loaded host is
+        # not the phases' fault).
         def has_stats_wake():
             return any(
                 r.get("n_sweeps") for r in obs.to_doc()["recent_wakes"]
@@ -406,11 +384,17 @@ def test_device_observatory_live_planes():
         doc = obs.to_doc()
         stats_wakes = [r for r in doc["recent_wakes"] if r.get("n_sweeps")]
         assert stats_wakes
+        left_out = []
         for rec in stats_wakes:
-            ms = rec["sweep_device_ms"]
-            assert len(ms) == int(rec["n_sweeps"])
-            device_ms = rec["device_s"] * 1000.0
-            assert abs(sum(ms) - device_ms) <= 0.10 * device_ms
+            assert len(rec["sweep_dirty_chunks"]) == int(rec["n_sweeps"])
+            assert rec["closure_sweeps"] >= 0
+            inside = sum(
+                rec["phases"][p]
+                for p in ("layout", "upload", "device", "readback")
+            )
+            assert inside <= rec["device_s"]
+            left_out.append(1.0 - inside / rec["device_s"])
+        assert min(left_out) <= 0.10, left_out
 
         # -- transfer plane negative case: idle (transfer-free) wakes
         # commit nothing — the graph-dirty gate skips the trace, so the

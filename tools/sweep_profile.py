@@ -265,8 +265,8 @@ def main():
         pack2d_ms = timed(pack2d, hits2d)
 
     # --- per-mode fixpoint decomposition, through the wake profiler -- #
-    # The same DEVICE_TRACE event fields the engine stamps per wake
-    # (engines/crgc/arrays.py _stamp_sweep_stats) flow through a real
+    # The same per-wake fields the engine notes into its active wake
+    # (engines/crgc/arrays.py _note_sweep_stats) flow through a real
     # WakeProfiler here, so this tool exercises — and its JSON matches —
     # the telemetry pipeline the pull-density threshold is tuned from.
     modes = [m.strip() for m in args.modes.split(",") if m.strip()]
@@ -306,18 +306,12 @@ def main():
                         fix_ms = (time.perf_counter() - t0) * 1e3
                         k = int(stats["n_sweeps"])
                         ev.fields["trace_mode"] = mode
-                        ev.fields["n_sweeps"] = k
-                        ev.fields["sweep_dirty_chunks"] = (
-                            stats["dirty_chunks"][:k].tolist()
-                        )
-                        ev.fields["sweep_changed_supers"] = (
-                            stats["changed_supers"][:k].tolist()
-                        )
-                        ev.fields["sweep_tiles_skipped"] = (
-                            stats["tiles_skipped"][:k].tolist()
-                        )
-                        ev.fields["sweep_pull_on"] = (
-                            stats["pull_on"][:k].tolist()
+                        wk.note(
+                            n_sweeps=k,
+                            sweep_dirty_chunks=stats["dirty_chunks"][:k].tolist(),
+                            sweep_changed_supers=stats["changed_supers"][:k].tolist(),
+                            sweep_tiles_skipped=stats["tiles_skipped"][:k].tolist(),
+                            sweep_pull_on=stats["pull_on"][:k].tolist(),
                         )
                 wk.end(mode=mode)
                 kk = min(k, len(stats["dirty_chunks"]))

@@ -22,7 +22,7 @@ jump-parent maintenance writes alongside the churn (minimum-fold on
 insert, invalidate-on-remove — exactly the IncrementalPallasLayout
 rules), so the chain exercises the production invariant that a pointer
 never outlives the pair it was built from.  A stats replay (the same
-staged wakes run unchained with the with_stats wake fn) reports the
+staged wakes run unchained; the wake fn counts its own sweeps) reports the
 per-wake repair sweep counts next to the chain figure, and ``--json``
 dumps the whole result as a BENCH_WAKE-style artifact so the
 sweep-count reduction is regression-tracked.
@@ -271,7 +271,7 @@ def main():
                 jarg = (jp,)
             else:
                 jarg = ()
-            state = wake_raw(
+            *state, _stats = wake_raw(
                 flags,
                 recv,
                 dev["del_w"][k],
@@ -285,7 +285,7 @@ def main():
                 dev["xsrc"][k],
                 dev["xdst"][k],
             )
-            return (flags, recv, row_pos, emeta, jp, state)
+            return (flags, recv, row_pos, emeta, jp, tuple(state))
 
         flags, recv, row_pos, emeta, _jp, state = jax.lax.fori_loop(
             0, k_hi, body,
@@ -326,13 +326,13 @@ def main():
 
     if not args.no_stats:
         # Per-wake sweep counts: the same staged wakes replayed
-        # UNCHAINED with the with_stats wake fn (device results feed
-        # forward, churn applied host-side from the staged arrays), so
-        # the sweep-count reduction is visible next to the chain figure.
+        # UNCHAINED through the jitted wake fn, which counts its own
+        # sweeps (device results feed forward, churn applied host-side
+        # from the staged arrays), so the sweep-count reduction is
+        # visible next to the chain figure.
         log("sweep-count replay...")
         wake_stats = pdec.get_wake_fn(
             n, specs, prep["n_super"], r_rows, prep["s_rows"], mode=mode,
-            with_stats=True,
         )
         flags_k = flags0.copy()
         recv_k = recv0.copy()

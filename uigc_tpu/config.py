@@ -305,10 +305,11 @@ DEFAULTS: Dict[str, Any] = {
     # Chrome-trace/Perfetto JSON.  Off by default — it is per-message
     # overhead.
     "uigc.telemetry.tracing": False,
-    # Collector wake profiler: break each Bookkeeper wake into
-    # ingest/fold/trace/sweep/broadcast phases with device-vs-host time
-    # (hooks the tpu.device_trace / crgc.sweep events); dump BENCH-style
-    # JSON via system.telemetry.profiler.  Enables the event recorder.
+    # Collector wake profiler: break each Bookkeeper wake into exclusive
+    # phases (ingest, fold, trace, layout, upload, device, readback,
+    # sweep, broadcast), also written as uigc:<phase> annotations onto a
+    # jax.profiler trace's clock; dump BENCH-style JSON via
+    # system.telemetry.profiler.  Enables the event recorder.
     "uigc.telemetry.wake-profile": False,
     # Localhost HTTP exposition: serve /metrics (Prometheus text) and
     # /metrics.json on 127.0.0.1.  -1 disables; 0 binds an ephemeral
@@ -386,9 +387,9 @@ DEFAULTS: Dict[str, Any] = {
     # ledger (uigc_device_ledger_bytes{family} + peak watermarks),
     # compile-cache hit/miss telemetry with the recompile_storm alert,
     # host-transfer accounting for the annotated readback sites, the
-    # donation audit, and per-sweep device-time attribution on the wake
+    # donation audit, and the wake program's sweep counts on the wake
     # records.  Serves /device on the metrics HTTP server.  Implies the
-    # metrics registry and the wake profiler (attribution needs both).
+    # metrics registry and the wake profiler (the counts need both).
     "uigc.telemetry.device": False,
     # Compile-cache miss rate (misses/s over the rule window) above
     # which recompile_storm fires — a healthy steady state compiles

@@ -114,19 +114,20 @@ class ArrayShadowGraph:
         )
         self.trace_mode = trace_mode
         self.pull_density = pull_density
-        #: collect the per-sweep frontier decomposition (with_stats
-        #: fixpoint + device->host stat readback) this wake.  Set by the
-        #: collector when a wake profiler is attached — the only
-        #: consumer that carries the fields into per-wake records — so
-        #: metrics-only or sanitizer-only telemetry setups never pay
-        #: the stats variant on the wake path.
-        self.sweep_stats = False
+        #: the collector's active wake (telemetry/profile.py), set by
+        #: the collector for the length of a wake while a profiler is
+        #: attached, else None.  The backend's one road to the profiler:
+        #: ``events.wake_phase`` brackets its steps on it (layout,
+        #: upload, device, readback, sweep) and ``note`` hands it the
+        #: wake's counters.  The decremental wake runs one program with
+        #: or without it; the full re-trace (``device`` backend) takes
+        #: its ``with_stats`` variant while it is set.
+        self.profile_wake = None
         #: capture the marking-parent array on the next trace (the
-        #: why-live provenance forest, telemetry/inspect.py).  Gated
-        #: exactly like ``sweep_stats``: the collector sets it per wake
-        #: only when a liveness inspector asked for verdict-exact
-        #: capture, so plain wakes run the parent-free kernels and pay
-        #: nothing.
+        #: why-live provenance forest, telemetry/inspect.py).  The
+        #: collector sets it per wake only when a liveness inspector
+        #: asked for verdict-exact capture, so plain wakes run the
+        #: parent-free kernels and pay nothing.
         self.capture_parents = False
         #: (mark, parent) of the last captured trace: ``last_parents[i]``
         #: is the slot whose propagation first marked slot ``i`` at that
@@ -876,14 +877,15 @@ class ArrayShadowGraph:
         if self.use_device:
             self._note_device_wake()
             with events.recorder.timed(events.DEVICE_TRACE) as ev:
+                ev.fields["trace_mode"] = self.trace_mode
                 if self.decremental:
                     return _readback(
-                        self._compute_marks_decremental(ev),
+                        self._compute_marks_decremental(),
                         "marks.decremental",
                     )
                 if self.trace_impl != "xla":
                     return _readback(
-                        self._compute_marks_pallas(ev), "marks.pallas"
+                        self._compute_marks_pallas(), "marks.pallas"
                     )
                 return _readback(
                     trace_ops.trace_marks_jax(
@@ -987,27 +989,23 @@ class ArrayShadowGraph:
             else:
                 self.trace_impl = "pallas"
 
-    def _stamp_sweep_stats(self, ev, stats: Optional[dict]) -> None:
-        """Attach the fixpoint's per-sweep frontier decomposition to the
-        enclosing DEVICE_TRACE event — the wake profiler
-        (telemetry/profile.py) carries these fields into its per-wake
-        records, which is where the pull-density threshold is tuned
-        from data (tools/sweep_profile.py reads the same shapes)."""
-        ev.fields["trace_mode"] = self.trace_mode
-        if stats is None:
-            return
+    def _note_sweep_stats(self, stats: dict) -> None:
+        """Hand the fixpoint's sweep counters to the active wake's
+        record (telemetry/profile.py): sweep counts and the per-sweep
+        frontier decomposition, which is where the pull-density
+        threshold is tuned from data (tools/sweep_profile.py writes the
+        same fields)."""
         k = int(stats["n_sweeps"])
-        ev.fields["n_sweeps"] = k
+        fields = {"n_sweeps": k}
+        if "closure_sweeps" in stats:
+            fields["closure_sweeps"] = int(stats["closure_sweeps"])
         k = min(k, len(stats["dirty_chunks"]))
-        ev.fields["sweep_dirty_chunks"] = stats["dirty_chunks"][:k].tolist()
-        if "changed_supers" in stats:
-            ev.fields["sweep_changed_supers"] = (
-                stats["changed_supers"][:k].tolist()
-            )
-        ev.fields["sweep_tiles_skipped"] = stats["tiles_skipped"][:k].tolist()
-        ev.fields["sweep_pull_on"] = stats["pull_on"][:k].tolist()
+        for key in ("dirty_chunks", "changed_supers", "tiles_skipped", "pull_on"):
+            if key in stats:
+                fields["sweep_" + key] = [int(x) for x in stats[key][:k]]
+        self.profile_wake.note(**fields)
 
-    def _compute_marks_pallas(self, ev=None) -> np.ndarray:
+    def _compute_marks_pallas(self) -> np.ndarray:
         """Device trace through the Pallas propagation kernel.
 
         Layout maintenance is incremental (ops/pallas_incremental.py):
@@ -1027,11 +1025,11 @@ class ArrayShadowGraph:
             ),
             lambda l: l.needs_repack,
         )
-        if ev is not None and self.sweep_stats:
+        if self.profile_wake is not None:
             marks, stats = self._inc.trace(
                 self.flags, self.recv_count, with_stats=True
             )
-            self._stamp_sweep_stats(ev, stats)
+            self._note_sweep_stats(stats)
             return marks
         return self._inc.trace(self.flags, self.recv_count)
 
@@ -1060,31 +1058,39 @@ class ArrayShadowGraph:
                 )
         return obj
 
-    def _compute_marks_decremental(self, ev=None) -> np.ndarray:
+    def _compute_marks_decremental(self) -> np.ndarray:
         """Per-wake detection through the decremental tracer: the wake
         cost is proportional to the churn's affected region, not the
         graph (ops/pallas_decremental.py; the steady-state analogue of
         the reference's 50ms incremental collect, LocalGC.scala:144-186,
-        at scales where a full re-trace cannot meet the cadence)."""
-        self._dec = self._synced_dec()
-        self._dec.collect_stats = ev is not None and self.sweep_stats
+        at scales where a full re-trace cannot meet the cadence).
+
+        The device call in its four steps, each a profiler phase when a
+        wake is attached: layout maintenance, upload, the wake program
+        from dispatch until its result is ready, readback."""
+        import jax
+
+        wake = self.profile_wake
+        with events.wake_phase(wake, "layout"):
+            dec = self._dec = self._synced_dec()
         try:
-            marks = self._dec.marks(self.flags, self.recv_count)
-            if self._dec.collect_stats:
-                ls = self._dec.last_stats
-                self._stamp_sweep_stats(
-                    ev,
-                    None if ls is None else {
-                        k: np.asarray(v)  # readback: sweep-stat words
-                        for k, v in ls.items()
-                    },
-                )
+            with events.wake_phase(wake, "upload"):
+                flags_dev = jax.device_put(self.flags)
+                recv_dev = jax.device_put(self.recv_count)
+                staged = dec.stage_wake()
+            with events.wake_phase(wake, "device"):
+                mark_w = dec.wake_device(flags_dev, recv_dev, staged)
+                mark_w.block_until_ready()
+            with events.wake_phase(wake, "readback"):
+                marks = dec.unpack_marks(mark_w)
+                if wake is not None:  # and the wake's own counters
+                    self._note_sweep_stats(dec.wake_stats(1)[-1])
             return marks
         except Exception:
-            # A poisoned async result surfaces at the readback inside
-            # marks(), after the tracer committed state; drop it so the
+            # A poisoned async result surfaces at the wait or at the
+            # readback, after the tracer committed state; drop it so the
             # next wake re-derives instead of feeding poisoned arrays.
-            self._dec.invalidate()
+            dec.invalidate()
             raise
 
     # ------------------------------------------------------------- #
@@ -1200,7 +1206,8 @@ class ArrayShadowGraph:
                 mark = np.asarray(dec.unpack_marks(mark_w))  # readback: accounted in the handle
             else:
                 mark = _readback(dec.unpack_marks(mark_w), "marks.harvest")
-            with events.recorder.timed(events.SWEEP):
+            wake = self.profile_wake
+            with events.wake_phase(wake, "sweep"), events.recorder.timed(events.SWEEP):
                 garbage, kill = trace_ops.garbage_and_kills_np(
                     snap_flags, snap_sup, mark
                 )
@@ -1218,6 +1225,7 @@ class ArrayShadowGraph:
                     self._kill_slots_bulk(kill_slots)
                 if garbage_slots.size:
                     self._free_slots_batch(garbage, garbage_slots)
+                self._note_sweep(wake, should_kill, kill_slots, garbage_slots)
             ev.fields["num_garbage_actors"] = int(garbage_slots.size)
             ev.fields["num_live_actors"] = int(np.count_nonzero(mark))
         return int(garbage_slots.size)
@@ -1235,10 +1243,11 @@ class ArrayShadowGraph:
                 mark = self._compute_marks_with_parents()
             else:
                 mark = self.compute_marks()
-            # The sweep (kill decisions + slot frees) nests in its own
-            # timed event so the wake profiler can attribute
-            # trace-vs-sweep time (telemetry/profile.py).
-            with events.recorder.timed(events.SWEEP):
+            # The sweep (kill decisions + slot frees) is its own
+            # profiler phase, so trace stays exclusive of it, and its
+            # own timed event.
+            wake = self.profile_wake
+            with events.wake_phase(wake, "sweep"), events.recorder.timed(events.SWEEP):
                 garbage, kill = trace_ops.garbage_and_kills_np(
                     self.flags, self.supervisor, mark
                 )
@@ -1250,10 +1259,20 @@ class ArrayShadowGraph:
 
                 if garbage_slots.size:
                     self._free_slots_batch(garbage, garbage_slots)
+                self._note_sweep(wake, should_kill, kill_slots, garbage_slots)
 
             ev.fields["num_garbage_actors"] = int(garbage_slots.size)
             ev.fields["num_live_actors"] = int(np.count_nonzero(mark))
         return int(garbage_slots.size)
+
+    @staticmethod
+    def _note_sweep(wake, should_kill, kill_slots, garbage_slots) -> None:
+        """The sweep's counts into the active wake's record."""
+        if wake is not None:
+            wake.note(
+                kills=int(kill_slots.size) if should_kill else 0,
+                freed=int(garbage_slots.size),
+            )
 
     def _kill_slots_bulk(self, kill_slots: np.ndarray) -> None:
         """Send StopMsg to every kill slot's cell as ONE bulk teardown:

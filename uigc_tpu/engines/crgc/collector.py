@@ -20,7 +20,6 @@ trace.  Multi-node (num-nodes > 1, attached to a Fabric):
 
 from __future__ import annotations
 
-from contextlib import nullcontext
 from typing import TYPE_CHECKING, Any, Dict, Optional, Set
 
 from ...runtime.behaviors import RawBehavior
@@ -57,9 +56,7 @@ START_WAVE = _StartWave()
 FINALIZE_EGRESSES = _FinalizeEgresses()
 
 
-def _phase(wake: Any, name: str):
-    """Profiler phase bracket, or a no-op when no wake is active."""
-    return wake.phase(name) if wake is not None else nullcontext()
+_phase = events.wake_phase
 
 
 class DeltaMsg:
@@ -385,19 +382,19 @@ class Bookkeeper(RawBehavior):
         inside a ``gc_wave`` span whose context becomes the causal
         parent of the terminations it triggers, and the wake profiler
         brackets the pipeline phases (ingest/fold/trace/broadcast here;
-        the sweep share is attributed from the ``crgc.sweep`` event the
-        backends emit inside their trace)."""
+        the backend, handed the wake as ``profile_wake``, brackets its
+        own inside the trace: layout/upload/device/readback/sweep)."""
         engine = self.engine
         tel = engine.system.telemetry
         tracer = tel.tracer if tel is not None and tel.tracer.enabled else None
         prof = engine.wake_profiler
         insp = engine.liveness_inspector
         wake = prof.begin_wake() if prof is not None else None
-        if hasattr(self.shadow_graph, "sweep_stats"):
-            # Device backends collect the per-sweep frontier stats only
-            # when a profiler is attached to carry them (arrays.py
-            # _stamp_sweep_stats -> WakeProfiler per-wake records).
-            self.shadow_graph.sweep_stats = wake is not None
+        # The backend's one road to the profiler: the active wake (None
+        # without a profiler), for its phase brackets and its counters.
+        # It selects no program: a decremental wake runs the same one
+        # with or without it.
+        self.shadow_graph.profile_wake = wake
         if hasattr(self.shadow_graph, "capture_parents"):
             # Why-live parent capture follows the same gating discipline:
             # only a liveness inspector that asked for verdict-exact
@@ -422,6 +419,7 @@ class Bookkeeper(RawBehavior):
             # or _active dangles and later sweep/device events are
             # credited to a dead wake.
             if wake is not None:
+                self.shadow_graph.profile_wake = None
                 wake.end(entries=count, garbage=n_garbage)
         if insp is not None:
             # Flight recorder + leak watchdog ride the collector thread

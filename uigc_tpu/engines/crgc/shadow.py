@@ -126,6 +126,10 @@ class ShadowGraph:
         self.total_actors_seen = 0
         self.from_set: List[Shadow] = []
         self.shadow_map: Dict["ActorCell", Shadow] = {}
+        #: the collector's active wake (telemetry/profile.py), set by
+        #: the collector for the length of a wake while a profiler is
+        #: attached, else None: ``events.wake_phase`` brackets on it
+        self.profile_wake = None
         #: why-live parent capture (telemetry/inspect.py), gated per wake
         #: by the collector exactly like the array backend's flag: when
         #: set, the next trace records ``last_parents`` — a
@@ -324,9 +328,10 @@ class ShadowGraph:
 
             num_garbage = 0
             num_live = 0
-            # The sweep in its own timed event, for the wake profiler's
-            # trace-vs-sweep attribution (telemetry/profile.py).
-            with events.recorder.timed(events.SWEEP):
+            # The sweep as its own profiler phase (trace stays exclusive
+            # of it) and its own timed event.
+            wake = self.profile_wake
+            with events.wake_phase(wake, "sweep"), events.recorder.timed(events.SWEEP):
                 kills: List[Any] = []
                 for shadow in self.from_set:
                     if shadow.mark != marked:
@@ -343,6 +348,8 @@ class ShadowGraph:
                     else:
                         num_live += 1
                 dispatch_kills(kills)
+                if wake is not None:
+                    wake.note(kills=len(kills), freed=num_garbage)
 
                 self.from_set = to_set
                 self.marked = not marked

@@ -61,6 +61,10 @@ class NativeShadowGraph:
         self._cell_of_id: Dict[int, "ActorCell"] = {}
         self._node_ids: Dict[str, int] = {}
         self._next_seq = 0
+        #: the collector's active wake (telemetry/profile.py), set by
+        #: the collector for the length of a wake while a profiler is
+        #: attached, else None: ``events.wake_phase`` brackets on it
+        self.profile_wake = None
         self._reset_batch()
 
     def __del__(self):  # pragma: no cover - interpreter-shutdown ordering
@@ -262,9 +266,11 @@ class NativeShadowGraph:
                 )
             )
             # Host-side sweep (the C trace already freed its own state)
-            # in its own timed event for the wake profiler's
-            # trace-vs-sweep attribution (telemetry/profile.py).
-            with events.recorder.timed(events.SWEEP):
+            # as its own profiler phase and its own timed event.
+            wake = self.profile_wake
+            if wake is not None:
+                wake.note(kills=int(n_kill.value), freed=n_garbage)
+            with events.wake_phase(wake, "sweep"), events.recorder.timed(events.SWEEP):
                 if should_kill and n_kill.value:
                     from ..runtime.cell import tell_bulk
 

@@ -47,6 +47,8 @@ ShadowGraph.java:205-289).
 
 from __future__ import annotations
 
+import weakref
+from collections import deque
 from typing import Dict, List, Optional, Set
 
 import numpy as np
@@ -59,6 +61,25 @@ from .pallas_incremental import IncrementalPallasLayout
 
 _fn_cache: Dict[tuple, object] = {}
 
+#: root of the wake program's named scopes; its phases nest under it as
+#: ``uigc.wake/<phase>`` and the shared helpers of pallas_trace.py
+#: (``push``, ``hits``, ``jump``, ``sat``, ``dirty``) under those
+WAKE_SCOPE = "uigc.wake"
+WAKE_PHASES = ("pack", "suspects", "closure", "gate", "repair")
+#: wakes whose stats handles a tracer keeps (device arrays of a few
+#: hundred bytes each, read back only by :meth:`DecrementalTracer.wake_stats`)
+STATS_KEPT = 256
+
+_live_tracers: "weakref.WeakSet" = weakref.WeakSet()
+
+
+def live_tracers() -> list:
+    """The :class:`DecrementalTracer` objects alive in this process: the
+    road by which a reader that holds no reference to the system under
+    test (a benchmark's per-layer reader, after the window) finds their
+    :meth:`~DecrementalTracer.wake_stats`."""
+    return list(_live_tracers)
+
 
 def _build_wake_fn(
     n: int,
@@ -69,21 +90,31 @@ def _build_wake_fn(
     interpret: bool,
     mode: str = pt.MODE_PUSH,
     pull_density: float = pt.DEFAULT_PULL_DENSITY,
-    with_stats: bool = False,
 ):
     """The jitted wake: (flags, recv, del_words, fresh_words, prev
     state, [jump parents,] *layout args) -> (mark_w, seed_w, halted_w,
-    iu_w, table) with all word tables (r_rows, LANE) int32 device
-    arrays.
+    iu_w, table, stats) with all word tables (r_rows, LANE) int32 device
+    arrays and ``stats`` the wake's sweep counters (below).
 
     ``mode`` applies to the REPAIR fixpoint only (pallas_trace MODE_*
     docs): on a cold start the repair IS the full derivation, which is
     where the O(diameter) sweep wall lives.  The closure phase stays a
     plain push fixpoint: it is bounded by the churn's region (usually
     shallow), and jump hits there would only over-approximate the
-    closure — sound but more re-derivation for nothing.  ``with_stats``
-    appends a per-wake stats dict (repair sweep count + per-sweep
-    frontier decomposition) to the returned tuple."""
+    closure — sound but more re-derivation for nothing.
+
+    ``stats`` is counted by the program that runs, every wake (one
+    program per geometry; a few scalar updates per sweep):
+    ``closure_sweeps`` and ``n_sweeps`` (repair) are int32 scalars,
+    ``dirty_chunks``, ``tiles_skipped`` and ``pull_on`` hold the repair
+    fixpoint's first ``pt.MAX_SWEEP_STATS`` sweeps (later ones fold into
+    the last slot).  They stay on the device until somebody asks
+    (:meth:`DecrementalTracer.wake_stats`).
+
+    The phases carry named scopes (``uigc.wake/pack``, ``/suspects``,
+    ``/closure``, ``/gate``, ``/repair``, and the helpers' own inside
+    the two loops): compile-time metadata that a device trace shows per
+    operation, so the wake's device time can be summed by phase."""
     import jax
     import jax.numpy as jnp
 
@@ -115,19 +146,18 @@ def _build_wake_fn(
     def wake_fn(flags, recv_count, del_w, fresh_w, prev_mark_w,
                 prev_seed_w, prev_halted_w, prev_iu_w, prev_table,
                 *rest):
+        with pt.scope(WAKE_SCOPE):
+            return wake_body(flags, recv_count, del_w, fresh_w,
+                             prev_mark_w, prev_seed_w, prev_halted_w,
+                             prev_iu_w, prev_table, *rest)
+
+    def wake_body(flags, recv_count, del_w, fresh_w, prev_mark_w,
+                  prev_seed_w, prev_halted_w, prev_iu_w, prev_table,
+                  *rest):
         if use_jump:
             jump_j0, *layout_args = rest
         else:
             jump_j0, layout_args = None, rest
-        in_use = (flags & F.FLAG_IN_USE) != 0
-        halted = (flags & F.FLAG_HALTED) != 0
-        seed = (
-            ((flags & F.FLAG_ROOT) != 0)
-            | ((flags & F.FLAG_BUSY) != 0)
-            | (recv_count != 0)
-            | ((flags & F.FLAG_INTERNED) == 0)
-        )
-
         def pack(active):
             return pt.pack_bools(active, n, r_rows, jnp)
 
@@ -146,10 +176,19 @@ def _build_wake_fn(
             the dst-gated kernels behave exactly like the plain ones."""
             return gated_sweep(table, d, l, layout_args, gate=gate)
 
-        iu_w = pack(in_use)
-        nh_w = pack(~halted)
-        halted_w = pack(halted)
-        seed_w = pack(in_use & (~halted) & seed)
+        with pt.scope("pack"):
+            in_use = (flags & F.FLAG_IN_USE) != 0
+            halted = (flags & F.FLAG_HALTED) != 0
+            seed = (
+                ((flags & F.FLAG_ROOT) != 0)
+                | ((flags & F.FLAG_BUSY) != 0)
+                | (recv_count != 0)
+                | ((flags & F.FLAG_INTERNED) == 0)
+            )
+            iu_w = pack(in_use)
+            nh_w = pack(~halted)
+            halted_w = pack(halted)
+            seed_w = pack(in_use & (~halted) & seed)
 
         # --- 1. suspect seeds --------------------------------------- #
         # A previously-marked node is suspect when any input of its old
@@ -157,31 +196,34 @@ def _build_wake_fn(
         # oracle gates marks on in_use, so the mark itself must go), it
         # newly halted (stops propagating), it stopped seeding, or an
         # in-edge was deleted.
-        s_w = (
-            (~iu_w)
-            | (halted_w & ~prev_halted_w)
-            | (prev_seed_w & ~seed_w)
-            | del_w
-        ) & prev_mark_w
+        with pt.scope("suspects"):
+            s_w = (
+                (~iu_w)
+                | (halted_w & ~prev_halted_w)
+                | (prev_seed_w & ~seed_w)
+                | del_w
+            ) & prev_mark_w
 
         # --- 2. closure: marks that depended on a suspect ----------- #
         def c_cond(carry):
-            return carry[-1]
+            return carry[3]
 
         zero_gate = jnp.zeros((n_super,), jnp.int32)
 
         def c_body(carry):
-            closure_w, d, l, _ = carry
+            closure_w, d, l, _, sweeps = carry
             hits2d = contribs(closure_w, d, l, zero_gate)
             hit_w = pt.pack_hits_table(hits2d, r_rows, jnp)
             new_closure = closure_w | (hit_w & prev_mark_w)
             d2, l2, changed = dirty_chunks(new_closure, closure_w)
-            return new_closure, d2, l2, changed
+            return new_closure, d2, l2, changed, sweeps + 1
 
-        d0, l0, changed0 = dirty_chunks(s_w, jnp.zeros_like(s_w))
-        closure_w, _, _, _ = jax.lax.while_loop(
-            c_cond, c_body, (s_w, d0, l0, changed0)
-        )
+        with pt.scope("closure"):
+            d0, l0, changed0 = dirty_chunks(s_w, jnp.zeros_like(s_w))
+            closure_w, _, _, _, closure_sweeps = jax.lax.while_loop(
+                c_cond, c_body,
+                (s_w, d0, l0, changed0, jnp.zeros((), jnp.int32)),
+            )
 
         # per-supertile gate: closure members must re-derive; fresh
         # insert destinations must see their new pairs' contributions at
@@ -201,18 +243,14 @@ def _build_wake_fn(
         # Newly-in-use nodes (slot reuse) are the additive mirror of the
         # fresh-insert case: reachable but with no word change anywhere,
         # so their supertile must re-derive once to pick the mark up.
-        suspect_g = (
-            per_super(closure_w)
-            | per_super(fresh_w)
-            | per_super(iu_w & ~prev_iu_w)
-        )
+        with pt.scope("gate"):
+            suspect_g = (
+                per_super(closure_w)
+                | per_super(fresh_w)
+                | per_super(iu_w & ~prev_iu_w)
+            )
 
         # --- 3. repair fixpoint ------------------------------------- #
-        mark_w0 = (prev_mark_w & ~closure_w) | seed_w
-        table0 = mark_w0 & nh_w
-        rd0, rl0, rchanged0 = dirty_chunks(table0, prev_table)
-        trans_w = iu_w & nh_w  # jump-transparent intermediates
-
         def r_cond(carry):
             return carry["changed"]
 
@@ -246,52 +284,55 @@ def _build_wake_fn(
                 jh, jump_j = pt.jump_sweep(
                     table, carry["jump"], trans_w, n, jnp
                 )
-                new_mark_w = new_mark_w | (pack(jh) & iu_w)
+                with pt.scope("jump"):  # the pack of its hits is its cost
+                    new_mark_w = new_mark_w | (pack(jh) & iu_w)
             new_table = new_mark_w & nh_w
             d2, l2, changed = dirty_chunks(new_table, table)
             # The gated sweep fully re-derives suspect supertiles; the
             # monotone dirty machinery is sufficient (and cheaper) after.
+            i = jnp.minimum(carry["sweep_i"], pt.MAX_SWEEP_STATS - 1)
             out = dict(carry, mark=new_mark_w, table=new_table, d=d2,
-                       l=l2, use_gate=jnp.array(False), changed=changed)
+                       l=l2, use_gate=jnp.array(False), changed=changed,
+                       sweep_i=carry["sweep_i"] + 1,
+                       st_dirty=carry["st_dirty"].at[i].set(n_dirty))
             if use_jump:
                 out["jump"] = jump_j
-            if with_stats:
-                i = jnp.minimum(carry["sweep_i"], pt.MAX_SWEEP_STATS - 1)
-                out["sweep_i"] = carry["sweep_i"] + 1
-                out["st_dirty"] = carry["st_dirty"].at[i].set(n_dirty)
-                if use_pull:
-                    out["st_skip"] = carry["st_skip"].at[i].set(
-                        jnp.where(pull_on, sat.sum(), 0)
-                    )
-                    out["st_pull"] = carry["st_pull"].at[i].set(
-                        pull_on.astype(jnp.int32)
-                    )
+            if use_pull:
+                out["st_skip"] = carry["st_skip"].at[i].set(
+                    jnp.where(pull_on, sat.sum(), 0)
+                )
+                out["st_pull"] = carry["st_pull"].at[i].set(
+                    pull_on.astype(jnp.int32)
+                )
             return out
 
-        # Run at least one gated sweep whenever anything is suspect,
-        # even if the table diff alone is empty.
-        run0 = rchanged0 | (suspect_g.sum() > 0)
-        carry0 = {"mark": mark_w0, "table": table0, "d": rd0, "l": rl0,
-                  "use_gate": jnp.array(True), "changed": run0}
-        if use_jump:
-            carry0["jump"] = jump_j0.astype(jnp.int32)
-        if with_stats:
+        with pt.scope("repair"):
+            mark_w0 = (prev_mark_w & ~closure_w) | seed_w
+            table0 = mark_w0 & nh_w
+            rd0, rl0, rchanged0 = dirty_chunks(table0, prev_table)
+            trans_w = iu_w & nh_w  # jump-transparent intermediates
+            # Run at least one gated sweep whenever anything is suspect,
+            # even if the table diff alone is empty.
+            run0 = rchanged0 | (suspect_g.sum() > 0)
             zero_stats = jnp.zeros((pt.MAX_SWEEP_STATS,), jnp.int32)
-            carry0.update(
-                sweep_i=jnp.zeros((), jnp.int32), st_dirty=zero_stats,
-                st_skip=zero_stats, st_pull=zero_stats,
-            )
-        out = jax.lax.while_loop(r_cond, r_body, carry0)
-        mark_w, table = out["mark"], out["table"]
-        if with_stats:
-            stats = {
-                "n_sweeps": out["sweep_i"],
-                "dirty_chunks": out["st_dirty"],
-                "tiles_skipped": out["st_skip"],
-                "pull_on": out["st_pull"],
-            }
-            return mark_w, seed_w, halted_w, iu_w, table, stats
-        return mark_w, seed_w, halted_w, iu_w, table
+            carry0 = {"mark": mark_w0, "table": table0, "d": rd0,
+                      "l": rl0, "use_gate": jnp.array(True),
+                      "changed": run0,
+                      "sweep_i": jnp.zeros((), jnp.int32),
+                      "st_dirty": zero_stats}
+            if use_jump:
+                carry0["jump"] = jump_j0.astype(jnp.int32)
+            if use_pull:
+                carry0.update(st_skip=zero_stats, st_pull=zero_stats)
+            out = jax.lax.while_loop(r_cond, r_body, carry0)
+        stats = {
+            "closure_sweeps": closure_sweeps,
+            "n_sweeps": out["sweep_i"],
+            "dirty_chunks": out["st_dirty"],
+            "tiles_skipped": out.get("st_skip", zero_stats),
+            "pull_on": out.get("st_pull", zero_stats),
+        }
+        return out["mark"], seed_w, halted_w, iu_w, out["table"], stats
 
     jitted = jax.jit(wake_fn)
     jitted.raw = wake_fn  # unjitted body, for callers composing it
@@ -299,11 +340,11 @@ def _build_wake_fn(
 
 
 def get_wake_fn(n, specs, n_super, r_rows, s_rows, interpret=None,
-                mode=pt.MODE_PUSH, pull_density=pt.DEFAULT_PULL_DENSITY,
-                with_stats=False):
-    """Cached jitted wake fn; its ``raw`` attribute is the unjitted body
-    for callers that compose wakes inside a larger program (the chained
-    wake benchmark scans K of them in one jit)."""
+                mode=pt.MODE_PUSH, pull_density=pt.DEFAULT_PULL_DENSITY):
+    """Cached jitted wake fn, one per geometry and mode; its ``raw``
+    attribute is the unjitted body for callers that compose wakes inside
+    a larger program (the chained wake benchmark scans K of them in one
+    jit)."""
     if interpret is None:
         interpret = pt.default_interpret()
     # _int8_mxu in the key: the flag is read at kernel build time, so
@@ -311,7 +352,7 @@ def get_wake_fn(n, specs, n_super, r_rows, s_rows, interpret=None,
     # process instead of requiring a restart per arm.
     key = (
         n, tuple(specs), n_super, r_rows, s_rows, interpret,
-        pt._int8_mxu(), mode, pull_density, with_stats,
+        pt._int8_mxu(), mode, pull_density,
     )
     fn = _fn_cache.get(key)
     if fn is None:
@@ -320,7 +361,7 @@ def get_wake_fn(n, specs, n_super, r_rows, s_rows, interpret=None,
         t0 = _time.perf_counter()
         fn = _fn_cache[key] = _build_wake_fn(
             n, tuple(specs), n_super, r_rows, s_rows, interpret,
-            mode=mode, pull_density=pull_density, with_stats=with_stats,
+            mode=mode, pull_density=pull_density,
         )
         if events.recorder.enabled:
             # Compile-cache plane (telemetry/device.py): one miss per
@@ -352,12 +393,10 @@ class DecrementalTracer:
         self.layout = IncrementalPallasLayout(n, interpret=interpret, **kwargs)
         self.n = n
         self.interpret = interpret
-        #: when set, each wake runs the with_stats variant of the wake
-        #: fn and leaves the repair fixpoint's per-sweep frontier
-        #: decomposition (device arrays, read back lazily) in
-        #: ``last_stats`` for the wake profiler
-        self.collect_stats = False
-        self.last_stats: Optional[dict] = None
+        #: the sweep counters of the last STATS_KEPT wakes, as the wake
+        #: program left them on the device (wake_stats reads them back)
+        self._stats: deque = deque(maxlen=STATS_KEPT)
+        _live_tracers.add(self)
         self._mark_w = None
         self._seed_w = None
         self._halted_w = None
@@ -428,9 +467,13 @@ class DecrementalTracer:
         )
         return jax.device_put(words.view(np.int32).reshape(r_rows, pt.LANE))
 
-    def wake_device(self, flags_dev, recv_dev):
-        """Run one wake; returns the packed mark words (device).  Use
-        :meth:`marks` for the boolean vector."""
+    def stage_wake(self) -> tuple:
+        """The host's share of a wake before its dispatch: the layout's
+        device operands (tier deltas and jump-parent writes go up here),
+        the wake program for the geometry they have, and the suspect id
+        words uploaded.  Returns what :meth:`wake_device` takes as
+        ``staged``; a caller that times upload and run apart (the
+        ``decremental`` backend) calls it first."""
         import jax
 
         preps, args = self.layout.prepare_device_wake()
@@ -445,7 +488,6 @@ class DecrementalTracer:
             self.interpret,
             mode=self.layout.mode,
             pull_density=self.layout.pull_density,
-            with_stats=self.collect_stats,
         )
         if self._mark_w is None or self._mark_w.shape[0] != r_rows:
             z = jax.device_put(np.zeros((r_rows, pt.LANE), np.int32))
@@ -456,7 +498,13 @@ class DecrementalTracer:
             # full seed-diff dirty set)
         del_w = self._id_words(self._pending_del_dst, r_rows)
         fresh_w = self._id_words(self._pending_fresh_dst, r_rows)
-        out = fn(
+        return fn, del_w, fresh_w, args
+
+    def wake_device(self, flags_dev, recv_dev, staged=None):
+        """Run one wake; returns the packed mark words (device).  Use
+        :meth:`marks` for the boolean vector."""
+        fn, del_w, fresh_w, args = staged or self.stage_wake()
+        *state, stats = fn(
             flags_dev,
             recv_dev,
             del_w,
@@ -474,12 +522,35 @@ class DecrementalTracer:
         # must invalidate() (the previous fixpoint is lost with the
         # device state anyway), which makes the next wake a full
         # re-derivation and the drained suspects irrelevant.
-        if self.collect_stats:
-            *out, self.last_stats = out
-        self._mark_w, self._seed_w, self._halted_w, self._iu_w, self._table = out
+        self._mark_w, self._seed_w, self._halted_w, self._iu_w, self._table = state
+        self._stats.append(stats)
         self._pending_del_dst.clear()
         self._pending_fresh_dst.clear()
         return self._mark_w
+
+    def wake_stats(self, last_n: Optional[int] = None) -> List[dict]:
+        """The sweep counters of the last ``last_n`` wakes (all that are
+        kept, at most STATS_KEPT, when None), oldest first, read back
+        from the device now: per wake ``closure_sweeps``, ``n_sweeps``
+        (repair) and, for the repair's first ``pt.MAX_SWEEP_STATS``
+        sweeps, ``dirty_chunks``, ``tiles_skipped`` and ``pull_on``.
+        Waits for a wake still in flight; costs the wakes nothing."""
+        import jax
+
+        kept = list(self._stats)
+        if last_n is not None:
+            kept = kept[max(0, len(kept) - last_n):]
+        out = []
+        for host in jax.device_get(kept):  # readback: a few hundred bytes of counters per wake, on request
+            k = min(int(host["n_sweeps"]), pt.MAX_SWEEP_STATS)
+            out.append({
+                "closure_sweeps": int(host["closure_sweeps"]),
+                "n_sweeps": int(host["n_sweeps"]),
+                "dirty_chunks": host["dirty_chunks"][:k].tolist(),
+                "tiles_skipped": host["tiles_skipped"][:k].tolist(),
+                "pull_on": host["pull_on"][:k].tolist(),
+            })
+        return out
 
     def invalidate(self) -> None:
         """Drop the previous-fixpoint device state (after a failed or

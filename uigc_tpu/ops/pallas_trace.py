@@ -137,6 +137,23 @@ GATE_PUSH = 0  # walk the dirty chunks inside the block's span (default)
 GATE_FULL = 1  # walk the FULL span (decremental repair re-derivation)
 GATE_SKIP = 2  # skip the block outright (saturated destination tile)
 
+#: name of the propagate kernel's ``pallas_call``: what its events are
+#: called in a device trace (the HLO instruction carries it)
+KERNEL_NAME = "uigc_propagate"
+
+
+def scope(name: str):
+    """``jax.named_scope(name)``: names a phase of the trace programs.
+    Compile-time metadata only (it lands in each HLO instruction's
+    ``op_name``, which a device trace shows per event), so a scope
+    costs nothing per wake.  The shared helpers below open ``push``,
+    ``hits``, ``jump``, ``sat`` and ``dirty``; the wake program nests
+    them under ``uigc.wake/<phase>`` (ops/pallas_decremental.py)."""
+    import jax
+
+    return jax.named_scope(name)
+
+
 
 def jump_parents(psrc, pdst, n: int) -> np.ndarray:
     """Min-source jump-parent array: J[d] = the smallest source with a
@@ -351,11 +368,12 @@ def jump_sweep(table, jump_j, trans_w, n, jnp, steps: int = JUMP_STEPS):
     Parents never extend through an opaque node, and the host layer
     invalidates J[d] whenever the pair it was built from is removed, so
     a jump can never cross a deleted edge or a halted relay."""
-    hits = bits_at(table, jump_j[:n], n, jnp)
-    for _ in range(steps):
-        j2 = jump_j[jump_j]
-        can = bits_at(trans_w, jump_j, n, jnp) & (j2 < n)
-        jump_j = jnp.where(can, j2, jump_j)
+    with scope("jump"):
+        hits = bits_at(table, jump_j[:n], n, jnp)
+        for _ in range(steps):
+            j2 = jump_j[jump_j]
+            can = bits_at(trans_w, jump_j, n, jnp) & (j2 < n)
+            jump_j = jnp.where(can, j2, jump_j)
     return hits, jump_j
 
 
@@ -364,10 +382,11 @@ def saturated_tiles(mark_w, iu_w, n_super, sup_words, jnp):
     left): the destination-pull summary.  A saturated tile's blocks can
     be skipped outright — every contribution they could make would land
     on an already-marked or never-markable bit."""
-    un = (iu_w & ~mark_w).reshape(-1)[: n_super * sup_words]
-    return (
-        ~(un.reshape(n_super, sup_words).any(axis=1))
-    ).astype(jnp.int32)
+    with scope("sat"):
+        un = (iu_w & ~mark_w).reshape(-1)[: n_super * sup_words]
+        return (
+            ~(un.reshape(n_super, sup_words).any(axis=1))
+        ).astype(jnp.int32)
 
 
 def hier_dirty_lists(table, table_prev, n_chunks, group_rows, n_super,
@@ -440,28 +459,34 @@ def dirty_group_lists(table, table_prev, n_chunks, group_rows, jnp):
     """Prefix D and compacted index list L of the walk groups whose words
     changed — the kernel ABI build_propagate consumes (D sized
     n_chunks+1, L sized n_chunks, plus the any-changed flag)."""
-    chunk_ids = jnp.arange(n_chunks, dtype=jnp.int32)
-    diff = (
-        (table != table_prev).reshape(n_chunks, group_rows * LANE).any(axis=1)
-    )
-    counts = diff.astype(jnp.int32)
-    d = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)])
-    pos = jnp.where(diff, d[:-1], n_chunks)
-    l = (
-        jnp.zeros((n_chunks + 1,), jnp.int32).at[pos].set(chunk_ids)[:n_chunks]
-    )
-    return d, l, d[n_chunks] > 0
+    with scope("dirty"):
+        chunk_ids = jnp.arange(n_chunks, dtype=jnp.int32)
+        diff = (
+            (table != table_prev)
+            .reshape(n_chunks, group_rows * LANE)
+            .any(axis=1)
+        )
+        counts = diff.astype(jnp.int32)
+        d = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)])
+        pos = jnp.where(diff, d[:-1], n_chunks)
+        l = (
+            jnp.zeros((n_chunks + 1,), jnp.int32)
+            .at[pos]
+            .set(chunk_ids)[:n_chunks]
+        )
+        return d, l, d[n_chunks] > 0
 
 
 def pack_hits_table(hits2d, r_rows, jnp):
     """pack_hits_words padded and reshaped into the (r_rows, LANE) word
     table — the exact per-sweep pack on the fixpoint path (trace_fn's
     pack2d) and the expression benchmark probes must time."""
-    flat = pack_hits_words(hits2d, jnp)
-    flat = jnp.concatenate(
-        [flat, jnp.zeros((r_rows * LANE - flat.shape[0],), jnp.int32)]
-    )
-    return flat.reshape(r_rows, LANE)
+    with scope("hits"):
+        flat = pack_hits_words(hits2d, jnp)
+        flat = jnp.concatenate(
+            [flat, jnp.zeros((r_rows * LANE - flat.shape[0],), jnp.int32)]
+        )
+        return flat.reshape(r_rows, LANE)
 
 
 def unpack_table(words, n, jnp):
@@ -488,6 +513,10 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
     sub_iota_rows = jnp.arange(s_rows, dtype=jnp.int32)
 
     def sweep(table, d, l, layout_args, gate=None):
+        with scope("push"):
+            return push(table, d, l, layout_args, gate)
+
+    def push(table, d, l, layout_args, gate):
         contrib = jnp.zeros((t_rows, LANE), jnp.float32)
         xla_hits2d = jnp.zeros((t_rows, LANE), bool)
         have_xla = False
@@ -1184,6 +1213,7 @@ def build_propagate(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((out_tiles * s_rows, LANE), jnp.float32),
         interpret=interpret,
+        name=KERNEL_NAME,
     )
 
 

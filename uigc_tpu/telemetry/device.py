@@ -40,13 +40,12 @@ structured events):
   on the committing thread, so reading the profiler's active-wake stack
   is race-free.
 
-Per-sweep attribution: the fixpoint runs all its sweeps inside one XLA
-program, so true per-sweep device timings are not separable without
-instrumenting the kernel.  :func:`sweep_attribution` distributes the
-wake's measured device seconds across sweeps weighted by each sweep's
-dirty-chunk count (the frontier stats PR 6 already streams back), plus
-a coarse bytes-touched model — an explicitly labelled *estimate* whose
-total always reconciles with the measured device time by construction.
+Per-sweep counts: the fixpoint runs all its sweeps inside one XLA
+program and counts them there (``n_sweeps``, ``closure_sweeps`` and the
+per-sweep dirty chunks in each wake record).  Device TIME per phase of
+that program is not estimated here: it is read from a profiler trace,
+where every operation carries its ``uigc.wake/<phase>`` scope
+(PROFILING.md "The wake on the profiler's clock").
 
 ``tools/device_report.py`` renders :meth:`DeviceObservatory.to_doc`
 (also served as ``/device`` on the metrics HTTP server) into the
@@ -62,13 +61,6 @@ import weakref
 from typing import Any, Dict, List, Optional, Tuple
 
 from ..utils import events
-
-#: Coarse bytes-touched model: one dirty walk chunk covers 32,768 node
-#: bits (the pre-hierarchy granularity PERF_WAKE.md names); a sweep
-#: touching it reads the mark words, writes them back, and reads the
-#: packed layout rows gated to it — modelled as three 4KB streams.
-#: An estimate for *relative* attribution, not a bandwidth claim.
-CHUNK_BYTES_EST = 3 * (32768 // 8)
 
 #: Per-entry byte estimates for the bookkeeping maps the ledger cannot
 #: measure exactly (CPython dict/list overhead; coarse on purpose —
@@ -205,34 +197,6 @@ def ledger_families(graph: Any) -> Dict[str, Dict[str, int]]:
     except Exception:
         pass
     return out
-
-
-def sweep_attribution(
-    device_s: float,
-    n_sweeps: int,
-    dirty_chunks: Optional[List[int]] = None,
-) -> Tuple[List[float], List[int]]:
-    """Distribute one wake's measured device seconds across its sweeps.
-
-    Weights are each sweep's dirty-chunk count (the work driver the PR 6
-    frontier stats stream back); a missing/short stats vector degrades
-    to equal weights.  Returns ``(per_sweep_ms, per_sweep_bytes_est)``;
-    ``sum(per_sweep_ms) == device_s * 1000`` by construction, so the
-    attribution always reconciles with the profiler's device time."""
-    n = max(0, int(n_sweeps))
-    if n == 0:
-        return [], []
-    weights = [1.0] * n
-    if dirty_chunks:
-        for i in range(min(n, len(dirty_chunks))):
-            try:
-                weights[i] = max(1.0, float(dirty_chunks[i]))
-            except (TypeError, ValueError):
-                pass
-    total = sum(weights)
-    ms = [float(device_s) * 1000.0 * w / total for w in weights]
-    bytes_est = [int(w * CHUNK_BYTES_EST) for w in weights]
-    return ms, bytes_est
 
 
 #: compile-cache geometry labelling lives with the event vocabulary so
@@ -627,9 +591,9 @@ def validate_device_doc(doc: Any) -> List[str]:
             problems.append("recent_wakes entry is not an object")
             break
         n = rec.get("n_sweeps")
-        ms = rec.get("sweep_device_ms")
-        if ms is not None:
-            if not isinstance(ms, list) or (n and len(ms) != int(n)):
-                problems.append("sweep_device_ms does not match n_sweeps")
+        dirty = rec.get("sweep_dirty_chunks")
+        if dirty is not None:
+            if not isinstance(dirty, list) or (n and len(dirty) > int(n)):
+                problems.append("sweep_dirty_chunks does not match n_sweeps")
                 break
     return problems

@@ -7,17 +7,36 @@ pipeline's named phases:
 
 - ``ingest``     draining the mutator entry queue + packed rows
 - ``fold``       merging the drained batch into the shadow graph
-- ``trace``      the liveness trace (mark computation; includes the
-                 device kernel dispatch on device backends)
-- ``sweep``      kill decisions + slot frees (attributed from the
-                 ``crgc.sweep`` event every backend emits, and
-                 subtracted from the enclosing trace bracket)
+- ``trace``      the liveness trace: what of the mark computation the
+                 phases below do not cover (all of it on host backends)
+- ``layout``     kernel-layout maintenance of the device backends
+                 (``apply_log`` of the pair log, or a ``rebuild``)
+- ``upload``     host -> device: layout deltas, suspect id words, flags
+                 and receive counts
+- ``device``     dispatch of the wake program until its result is ready
+- ``readback``   device -> host: the verdicts unpacked to a bool vector
+- ``sweep``      kill decisions + slot frees (its record carries the
+                 ``kills`` and ``freed`` counts)
 - ``broadcast``  delta-graph serialization + peer broadcast (multi-node)
 
-Device time is attributed by hooking the ``tpu.device_trace`` event:
-the profiler registers as a recorder listener and credits device
-durations committed on the wake's thread to the active wake, so every
-phase report carries both host wall time and the device share.
+Phases are exclusive: a nested phase pauses the enclosing one, so the
+phases of a wake add up to its ``wall_s`` less the few statements
+between brackets.  The collector hands the active wake to its backend
+(``profile_wake``), which brackets its own steps; that handle is the one
+road by which a backend reaches the profiler.
+
+Every bracket is also written onto a profiler trace's clock: while a
+``jax.profiler`` trace runs, a wake shows as a ``uigc:wake`` annotation
+on the collector's thread and its phases as ``uigc:<phase>`` inside it,
+all carrying the wake's ordinal (``wake=<n>``), so the device's op line
+can be read against what the collector was doing.  Annotations exist
+only where a profiler is attached; with no trace running one costs well
+under a microsecond.
+
+``device_s`` is the host clock around the whole device CALL (the
+``tpu.device_trace`` event: layout, upload, run and readback together):
+the profiler registers as a recorder listener and credits the durations
+committed on the wake's thread to the active wake.
 
 Dumps are BENCH-style JSON (one ``wake_profile`` document per node),
 matching the ``tools/*_bench.py`` artifact convention.
@@ -33,15 +52,20 @@ from typing import Any, Dict, List, Optional
 
 from ..utils import events
 
-PHASES = ("ingest", "fold", "trace", "sweep", "broadcast")
+PHASES = ("ingest", "fold", "trace", "layout", "upload", "device",
+          "readback", "sweep", "broadcast")
 
-#: DEVICE_TRACE event fields copied into the per-wake record (the
-#: fixpoint's per-sweep frontier decomposition; engines/crgc/arrays.py
-#: _stamp_sweep_stats stamps them, tools/sweep_profile.py reads them)
-_SWEEP_FIELDS = (
-    "trace_mode", "n_sweeps", "sweep_dirty_chunks",
-    "sweep_changed_supers", "sweep_tiles_skipped", "sweep_pull_on",
-)
+#: prefix of every annotation the profiler writes into a trace
+ANNOTATION_PREFIX = "uigc:"
+WAKE_ANNOTATION = ANNOTATION_PREFIX + "wake"
+
+
+def trace_annotation(name: str, **args: Any):
+    """The default ``annotate`` hook: a ``jax.profiler.TraceAnnotation``
+    (jax is imported here, as everywhere in the package, on first use)."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 class _PhaseFrame:
@@ -58,13 +82,15 @@ class _Phase:
     nested phase pauses the enclosing one (so ``broadcast`` inside the
     ingest drain loop is never double-counted)."""
 
-    __slots__ = ("wake", "name")
+    __slots__ = ("wake", "name", "mark")
 
     def __init__(self, wake: "_Wake", name: str):
         self.wake = wake
         self.name = name
+        self.mark = None
 
     def __enter__(self) -> "_Phase":
+        self.mark = self.wake.annotate(ANNOTATION_PREFIX + self.name)
         now = time.perf_counter()
         stack = self.wake.stack
         if stack:
@@ -83,30 +109,45 @@ class _Phase:
         )
         if stack:
             stack[-1].last_start = now
+        self.mark.__exit__(None, None, None)
 
 
 class _Wake:
     """Accounting for one in-flight collector wake."""
 
-    __slots__ = ("profiler", "thread", "t0", "start", "phases", "stack",
-                 "device_s", "sweep_s", "trace_fields")
+    __slots__ = ("profiler", "thread", "ordinal", "t0", "start", "phases",
+                 "stack", "device_s", "fields", "mark")
 
-    def __init__(self, profiler: "WakeProfiler"):
+    def __init__(self, profiler: "WakeProfiler", ordinal: int):
         self.profiler = profiler
         self.thread = threading.get_ident()
-        self.t0 = time.time()
-        self.start = time.perf_counter()
+        self.ordinal = ordinal
         self.phases: Dict[str, float] = {}
         self.stack: List[_PhaseFrame] = []
         self.device_s = 0.0
-        self.sweep_s = 0.0
-        self.trace_fields: Dict[str, Any] = {}
+        self.fields: Dict[str, Any] = {}
+        self.mark = profiler.annotate(WAKE_ANNOTATION, wake=ordinal)
+        self.mark.__enter__()
+        self.t0 = time.time()
+        self.start = time.perf_counter()
+
+    def annotate(self, name: str):
+        mark = self.profiler.annotate(name, wake=self.ordinal)
+        mark.__enter__()
+        return mark
 
     def phase(self, name: str) -> _Phase:
         return _Phase(self, name)
 
+    def note(self, **fields: Any) -> None:
+        """Fields for this wake's record, from whoever holds the wake
+        (the backend: sweep counters, ``kills``, ``freed``)."""
+        self.fields.update(fields)
+
     def end(self, **fields: Any) -> None:
-        self.profiler._finish(self, time.perf_counter() - self.start, fields)
+        wall_s = time.perf_counter() - self.start
+        self.mark.__exit__(None, None, None)
+        self.profiler._finish(self, wall_s, fields)
 
 
 class WakeProfiler:
@@ -115,8 +156,12 @@ class WakeProfiler:
     recorder listener (device/sweep attribution); both are done by
     :meth:`uigc_tpu.telemetry.Telemetry.attach`."""
 
-    def __init__(self, node: str, max_recent: int = 256, registry=None):
+    def __init__(self, node: str, max_recent: int = 256, registry=None,
+                 annotate=trace_annotation):
         self.node = node
+        #: ``annotate(name, **args)`` -> context manager bracketing the
+        #: wake and each phase on a profiler trace's clock
+        self.annotate = annotate
         self._lock = threading.Lock()
         self._active: Optional[_Wake] = None
         #: Prometheus face (optional): per-phase wake durations as one
@@ -129,7 +174,7 @@ class WakeProfiler:
             self._phase_hist = registry.histogram(
                 "uigc_wake_phase_seconds",
                 "Exclusive time of one collector-wake phase, by phase "
-                "(ingest/fold/trace/sweep/broadcast).",
+                "(" + "/".join(PHASES) + ").",
             )
             self._device_hist = registry.histogram(
                 "uigc_wake_device_seconds",
@@ -149,40 +194,24 @@ class WakeProfiler:
     # -- wake lifecycle (called from the Bookkeeper thread) ---------- #
 
     def begin_wake(self) -> _Wake:
-        wake = _Wake(self)
+        # wakes follow one another on the collector's thread, so the
+        # count of finished ones numbers the next
+        wake = _Wake(self, self._wakes)
         self._active = wake
         return wake
 
     def _finish(self, wake: _Wake, wall_s: float, fields: Dict[str, Any]) -> None:
         self._active = None
         phases = {name: wake.phases.get(name, 0.0) for name in PHASES}
-        # The sweep ran inside the trace bracket: report it as its own
-        # phase and keep trace exclusive.
-        phases["sweep"] += wake.sweep_s
-        phases["trace"] = max(0.0, phases["trace"] - wake.sweep_s)
         record = {
             "t": wake.t0,
+            "wake": wake.ordinal,
             "wall_s": wall_s,
             "device_s": wake.device_s,
             "phases": phases,
-            **wake.trace_fields,
+            **wake.fields,
             **fields,
         }
-        if record.get("n_sweeps") and wake.device_s > 0.0:
-            # Per-sweep device attribution (uigc_tpu/telemetry/device.py):
-            # the wake's measured device seconds distributed over its
-            # sweeps by dirty-chunk weight.  Sums back to device_s by
-            # construction, so downstream reports always reconcile with
-            # this profiler's own device figure.
-            from .device import sweep_attribution
-
-            ms, bytes_est = sweep_attribution(
-                wake.device_s,
-                int(record["n_sweeps"]),
-                record.get("sweep_dirty_chunks"),
-            )
-            record["sweep_device_ms"] = ms
-            record["sweep_bytes_est"] = bytes_est
         if self._phase_hist is not None:
             for name in PHASES:
                 self._phase_hist.observe(phases[name], phase=name)
@@ -203,27 +232,17 @@ class WakeProfiler:
             self._totals["trace"]["device_total_s"] += wake.device_s
             self._recent.append(record)
 
-    # -- recorder listener (device / sweep attribution) -------------- #
+    # -- recorder listener (the device call) ------------------------- #
 
     def __call__(self, name: str, fields: Dict[str, Any]) -> None:
-        if name != events.DEVICE_TRACE and name != events.SWEEP:
+        if name != events.DEVICE_TRACE:
             return
         wake = self._active
         if wake is None or wake.thread != threading.get_ident():
             return
-        duration = fields.get("duration_s") or 0.0
-        if name == events.DEVICE_TRACE:
-            wake.device_s += duration
-            # Per-sweep frontier decomposition stamped by the device
-            # backends (arrays._stamp_sweep_stats / sweep_profile):
-            # carried into the per-wake record — the data the
-            # pull-density threshold is tuned from (PROFILING.md
-            # "Reading sweep_profile").
-            for key in _SWEEP_FIELDS:
-                if key in fields:
-                    wake.trace_fields[key] = fields[key]
-        else:
-            wake.sweep_s += duration
+        wake.device_s += fields.get("duration_s") or 0.0
+        if "trace_mode" in fields:
+            wake.fields.setdefault("trace_mode", fields["trace_mode"])
 
     # -- reading ----------------------------------------------------- #
 

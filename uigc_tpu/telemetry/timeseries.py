@@ -628,18 +628,12 @@ class MetricsSampler:
                     "uigc_wake_device_seconds", rec.get("device_s", 0.0), t=t
                 )
                 # Device-plane decomposition (present when a device
-                # backend ran the stats-variant fixpoint): sweep count
-                # and the worst single sweep's attributed device time —
-                # the regression explainer's time-plane inputs
+                # backend's fixpoint counted its sweeps): the sweep
+                # count, the regression explainer's time-plane input
                 # (uigc_tpu/telemetry/device.py, device_wake_regression).
                 if rec.get("n_sweeps"):
                     store.record(
                         "uigc_device_sweeps", int(rec["n_sweeps"]), t=t
-                    )
-                sweep_ms = rec.get("sweep_device_ms")
-                if sweep_ms:
-                    store.record(
-                        "uigc_device_sweep_ms_max", max(sweep_ms), t=t
                     )
         if self.graph_fn is not None:
             self._sample_send_matrix(now)

@@ -17,6 +17,7 @@ import threading
 import time
 import traceback
 from bisect import bisect_left
+from contextlib import nullcontext
 from collections import defaultdict
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -58,6 +59,15 @@ SWEEP = "crgc.sweep"
 HOST_TRANSFER = "tpu.host_transfer"
 DONATION_COPY = "tpu.donation_copy"
 COMPILE = "tpu.compile"
+
+
+def wake_phase(wake: Any, name: str):
+    """Bracket of one phase of the collector wake ``wake`` (the handle
+    telemetry/profile.py's ``begin_wake`` returns, which the collector
+    gives its backend as ``profile_wake``), or a no-op when no profiler
+    is attached.  Lives here because every layer that brackets a phase
+    imports this module and none of them imports the telemetry package."""
+    return wake.phase(name) if wake is not None else nullcontext()
 
 
 def compile_geom(key: Any) -> str:
