@@ -163,7 +163,8 @@ def test_wake_program_counts_and_names(one_chip, mode):
     *words, stats = compiled.out_info
     assert len(words) == 5
     assert stats["closure_sweeps"].shape == stats["n_sweeps"].shape == ()
-    for key in ("dirty_chunks", "tiles_skipped", "pull_on"):
+    assert stats["jump_sweeps"].shape == ()
+    for key in ("dirty_chunks", "tiles_skipped", "pull_on", "jump_on"):
         assert stats[key].shape == (pt.MAX_SWEEP_STATS,)
     text = compiled.as_text()
     for phase in ("closure", "repair"):

@@ -97,11 +97,17 @@ def test_jump_collapses_chain_sweeps():
 def test_mode_sweep_counts_at_powerlaw_geometry():
     """At the benchmark graph model (powerlaw, the 10M-actor geometry's
     shape at reduced n — sweep counts are hardware-independent and only
-    weakly size-dependent) the jump/auto fixpoint must converge in <=6
-    sweeps where push needs more."""
+    weakly size-dependent) ``jump`` must converge in <=6 sweeps where
+    push needs more, and ``auto`` must NOT pay for it: on the v5e a jump
+    sweep costs nine push sweeps (PERF.md section 6, PR 28), the graph
+    is shallow, so auto runs push's sweeps and never engages the jump.
+    (Until PR 28 this asserted ``auto <= 6`` and ``auto < push``: the
+    CPU-era claim that a sweep is dear and a gather cheap.)  Four walk
+    chunks, so that the dense middle of the fixpoint shows as dense: in
+    a one-chunk layout every sweep looks sparse."""
     from uigc_tpu.models.graphgen import powerlaw_actor_graph
 
-    n = 1 << 14
+    n = 1 << 17
     g = powerlaw_actor_graph(n, seed=0, garbage_fraction=0.5)
     prep = pallas_trace.prepare_chunks(
         g["edge_src"].astype(np.int32),
@@ -117,16 +123,71 @@ def test_mode_sweep_counts_at_powerlaw_geometry():
         g["flags"], g["recv_count"], g["supervisor"],
         g["edge_src"], g["edge_dst"], g["edge_weight"],
     )
-    sweeps = {}
-    for mode in ("push", "auto"):
+    sweeps, jumps = {}, {}
+    for mode in ("push", "auto", "jump"):
         marks, stats = pallas_trace.trace_marks_layouts(
             g["flags"], g["recv_count"], [prep], mode=mode,
-            jump_parent=jp if mode == "auto" else None, with_stats=True,
+            jump_parent=None if mode == "push" else jp, with_stats=True,
         )
         assert np.array_equal(marks, expected), mode
         sweeps[mode] = int(stats["n_sweeps"])
-    assert sweeps["auto"] <= 6
-    assert sweeps["auto"] < sweeps["push"]
+        jumps[mode] = int(stats["jump_sweeps"])
+    assert sweeps["jump"] <= 6
+    assert sweeps["jump"] < sweeps["push"]
+    assert sweeps["auto"] == sweeps["push"]
+    assert jumps == {"push": 0, "auto": 0, "jump": sweeps["jump"]}
+
+
+def chain_graph(n):
+    flags = np.full(n, F.FLAG_IN_USE | F.FLAG_INTERNED, dtype=np.uint8)
+    flags[0] |= F.FLAG_ROOT
+    src = np.arange(n - 1, dtype=np.int32)
+    return (flags, np.zeros(n, dtype=np.int64),
+            np.full(n, -1, dtype=np.int32), src, src + 1,
+            np.ones(n - 1, dtype=np.int64))
+
+
+def auto_price(prep):
+    """AUTO's price of a jump sweep for a one-layout trace, from the same
+    helper and the same arguments as the trace fn takes it."""
+    n_chunks = prep["r_rows"] // (pallas_trace.ROWS * prep["group"])
+    pull_cut = max(1, round(pallas_trace.DEFAULT_PULL_DENSITY * n_chunks))
+    return pallas_trace.auto_jump_policy(
+        prep["n"],
+        pallas_trace.kernel_slots((pallas_trace.layout_spec(prep),)),
+        n_chunks, pull_cut,
+    ).price
+
+
+@pytest.mark.parametrize("n", [1000, 5000])
+def test_auto_engages_the_jump_on_a_chain(n):
+    """The other side of laziness: on a chain (diameter = n, one walk
+    chunk dirty per sweep) ``auto`` stays sparse sweep after sweep, so
+    it engages the jump once it has walked the price of one jump sweep,
+    keeps it engaged, and finishes in O(price + log n) sweeps where push
+    needs n - 1 — with the oracle's marks."""
+    flags, recv, sup, src, dst, w = chain_graph(n)
+    expected = trace_ops.trace_marks_np(flags, recv, sup, src, dst, w)
+    assert expected.all()
+    prep = pallas_trace.prepare_chunks(src, dst, w, sup, n)
+    jp = pallas_trace.jump_parents_from_graph(src, dst, w, sup, n)
+    price = auto_price(prep)
+    assert 1 < price < n // 8  # long enough to cross it, far under n
+    marks, stats = pallas_trace.trace_marks_layouts(
+        flags, recv, [prep], mode="auto", jump_parent=jp, with_stats=True
+    )
+    assert np.array_equal(marks, expected)
+    k = int(stats["n_sweeps"])
+    assert k <= pallas_trace.MAX_SWEEP_STATS  # every sweep has its slot
+    jump_on = stats["jump_on"][:k].tolist()
+    # one chunk walked per sweep: engaged exactly when the price is paid,
+    # and from then on to the end
+    assert jump_on == [0] * price + [1] * (k - price)
+    assert int(stats["jump_sweeps"]) == k - price > 0
+    # 4^k reach per engaged sweep (JUMP_STEPS = 2), plus the sweep that
+    # finds nothing new
+    assert k - price <= np.log2(n) / 2 + 3
+    assert k * 8 < n - 1
 
 
 def test_no_edges():

@@ -147,6 +147,52 @@ def test_sharded_pallas_matches_host(seed, mode):
     assert np.array_equal(mark, mark_host)
 
 
+def test_sharded_auto_engages_where_one_device_does():
+    """``auto`` means one thing in every program: on a chain the sharded
+    trace engages the pointer jump on the same sweep as the
+    single-device trace of the same node space (both count the same
+    replicated dirty chunks against the same price), finishes in the
+    same number of sweeps, and gives the oracle's marks."""
+    import jax
+
+    from uigc_tpu.ops import pallas_trace as pt
+    from uigc_tpu.parallel import make_sharded_pallas_trace, pack_shard_layouts
+
+    n_devices = min(8, len(jax.devices()))
+    s_rows = 8
+    n_pad = n_devices * 2 * s_rows * 128  # two supertiles a shard
+    flags = np.full(n_pad, trace_ops.FLAG_IN_USE | trace_ops.FLAG_INTERNED, np.uint8)
+    flags[0] |= trace_ops.FLAG_ROOT
+    recv = np.zeros(n_pad, np.int64)
+    psrc = np.arange(n_pad - 1, dtype=np.int64)
+    pdst = psrc + 1
+    jp = pt.jump_parents(psrc, pdst, n_pad)
+
+    prep = pt.prepare_pairs(psrc, pdst, n_pad, s_rows=s_rows)
+    one, one_stats = pt.trace_marks_layouts(
+        flags, recv, [prep], mode="auto", jump_parent=jp, with_stats=True
+    )
+    assert one.all()
+
+    stacked, meta, _ = pack_shard_layouts(psrc, pdst, n_pad, n_devices, s_rows=s_rows)
+    m = 64
+    traced = make_sharded_pallas_trace(
+        build_mesh(n_devices), n_pad, meta["shard_size"], meta["n_blocks"],
+        meta["r_rows"], s_rows, m, sub=meta["sub"], group=meta["group"],
+        mode="auto", with_stats=True,
+    )
+    mark, stats = traced(
+        flags, recv, stacked["bmeta1"], stacked["bmeta2"], stacked["row_pos"],
+        stacked["emeta"], np.full((n_devices, m), n_pad, np.int32),
+        np.zeros((n_devices, m), np.int32), jp,
+    )
+    assert np.asarray(mark).all()
+    sweeps, jumped = int(stats["n_sweeps"]), int(stats["jump_sweeps"])
+    assert 0 < jumped < sweeps  # engaged, and not from sweep 0
+    # engagement is for good, so the first jump sweep is sweeps - jumped
+    assert (sweeps, jumped) == (int(one_stats["n_sweeps"]), int(one_stats["jump_sweeps"]))
+
+
 @pytest.mark.parametrize("mode", ["push", "auto"])
 def test_sharded_decremental_wakes(mode):
     """The closure+repair wake on the virtual mesh: flag churn (halts,

@@ -26,6 +26,7 @@ from uigc_tpu.ops import pallas_trace as pt
 from uigc_tpu.ops import trace as F
 from uigc_tpu.ops.pallas_incremental import EDGE
 from uigc_tpu.telemetry import profile
+from uigc_tpu.utils import events
 
 
 # ------------------------------------------------------------------- #
@@ -62,14 +63,23 @@ def powerlaw(n=2048):
 GRAPHS = {"chain": chain, "powerlaw": powerlaw}
 
 #: repair sweeps of wake 0 (full derivation) and of the two churn wakes,
-#: and the marked actors after each, as the commit before this one
-#: counted them with its ``with_stats`` wake program
+#: and the marked actors after each, as the commit before PR 27
+#: counted them with its ``with_stats`` wake program.  The two ``auto``
+#: rows changed in PR 28 (they were the ``jump`` rows: [6, 1, 1] and
+#: [4, 4, 5]): ``auto`` no longer jumps from sweep 0, it engages once
+#: the push fixpoint has stayed sparse for the price of a jump sweep,
+#: 5 one-chunk sweeps on this chain (then the 6 sweeps ``jump`` needs)
+#: and 1 on this power-law graph (64 blocks for 8k pairs: a sweep
+#: streams 65k slots, as much as the 10k gathers of a jump sweep cost).
 PARENT_SWEEPS = {
-    ("chain", "auto"): [6, 1, 1], ("chain", "jump"): [6, 1, 1],
+    ("chain", "auto"): [11, 1, 1], ("chain", "jump"): [6, 1, 1],
     ("chain", "push"): [256, 1, 1], ("chain", "pull"): [256, 1, 1],
-    ("powerlaw", "auto"): [4, 4, 5], ("powerlaw", "jump"): [4, 4, 5],
+    ("powerlaw", "auto"): [5, 5, 5], ("powerlaw", "jump"): [4, 4, 5],
     ("powerlaw", "push"): [5, 5, 5], ("powerlaw", "pull"): [5, 5, 5],
 }
+#: the repair sweeps of those wakes that ran the pointer jump under
+#: ``auto`` (``jump``: all of them; ``push`` and ``pull``: none)
+AUTO_JUMP_SWEEPS = {"chain": [6, 0, 0], "powerlaw": [4, 4, 4]}
 PARENT_MARKED = {"chain": [256, 151, 51], "powerlaw": [2005, 2003, 2003]}
 #: tiles_skipped of wake 0's last kept sweep: the chain saturates its one
 #: tile, which only the pull gates count
@@ -128,6 +138,14 @@ def test_wake_counters_match_the_parents_stats_variant(graph, mode):
         assert w["dirty_chunks"] == [1] * k
         assert w["pull_on"] == [1 if use_pull else 0] * k
         assert len(w["tiles_skipped"]) == k
+        # the jump: never in push and pull, every sweep in jump, and in
+        # auto from the sweep it engages on to the end of the fixpoint
+        assert len(w["jump_on"]) == k
+        assert w["jump_sweeps"] == sum(w["jump_on"])
+        assert w["jump_on"] == sorted(w["jump_on"])
+    want = {pt.MODE_JUMP: [w["n_sweeps"] for w in stats],
+            pt.MODE_AUTO: AUTO_JUMP_SWEEPS[graph]}.get(mode, [0, 0, 0])
+    assert [w["jump_sweeps"] for w in stats] == want
     assert stats[0]["tiles_skipped"][-1] == PARENT_LAST_SKIP.get((graph, mode), 0)
     assert all(not any(w["tiles_skipped"]) for w in stats[1:])
     assert tracer.wake_stats(1) == stats[-1:]
@@ -293,6 +311,13 @@ def test_annotations_enclose_every_phase_on_the_collectors_thread():
     try:
         prof = kit.system.telemetry.profiler
         notes = prof.annotate = FakeAnnotations()
+        device_events = []
+
+        def on_event(name, fields):
+            if name == events.DEVICE_TRACE:
+                device_events.append(dict(fields))
+
+        events.recorder.add_listener(on_event)
 
         def swept():
             return any(r.get("freed") for r in prof.wakes_since(0.0))
@@ -300,6 +325,7 @@ def test_annotations_enclose_every_phase_on_the_collectors_thread():
         _churn(kit, root, Spawn, Drop, swept)
         records = prof.wakes_since(0.0)
     finally:
+        events.recorder.remove_listener(on_event)
         kit.shutdown()
     log = list(notes.log)
     # the hook was swapped in while the collector ran: start at a whole wake
@@ -326,10 +352,19 @@ def test_annotations_enclose_every_phase_on_the_collectors_thread():
     assert any(r.get("n_sweeps", 0) >= 1 for r in records)
     for r in (r for r in records if r["device_s"] > 0):
         assert r["closure_sweeps"] >= 0 and len(r["sweep_dirty_chunks"]) == r["n_sweeps"]
+        # auto, a shallow tree: the jump's decision is recorded per sweep
+        assert len(r["sweep_jump_on"]) == r["n_sweeps"]
+        assert r["jump_sweeps"] == sum(r["sweep_jump_on"])
         inside = sum(r["phases"][p] for p in ("layout", "upload", "device", "readback"))
         assert inside <= r["device_s"] * 1.001
         assert sum(r["phases"].values()) <= r["wall_s"]
     assert sorted(r["wake"] for r in records) == [r["wake"] for r in records]
+    # the device call's event carries the same counters as the record
+    counted = [e for e in device_events if "n_sweeps" in e]
+    assert counted and all(
+        e["trace_mode"] == "auto" and e["jump_sweeps"] == sum(e["sweep_jump_on"])
+        and len(e["sweep_jump_on"]) == e["n_sweeps"] for e in counted
+    )
 
 
 def test_no_annotation_and_one_program_without_a_profiler(monkeypatch):

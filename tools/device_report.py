@@ -311,7 +311,8 @@ def render_device_doc(
             f"    closure sweeps {r.get('closure_sweeps', '-')}, repair sweeps "
             f"{int(r['n_sweeps'])}, dirty chunks per sweep "
             f"{r.get('sweep_dirty_chunks') or []}, pull on "
-            f"{r.get('sweep_pull_on') or []}"
+            f"{r.get('sweep_pull_on') or []}, jump sweeps "
+            f"{r.get('jump_sweeps', '-')}"
         )
     lines.append("")
 

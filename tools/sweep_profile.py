@@ -9,7 +9,12 @@ only their sum across ~12 sweeps):
   every block still streams its row_pos/emeta and runs the skip branch);
 - the **word-space pack2d** of per-sweep hits into the word table (the
   per-sweep XLA cost outside the kernel), plus the legacy O(n)
-  bool-space pack (now paid only once per trace, for seed/gate vectors).
+  bool-space pack (now paid only once per trace, for seed/gate vectors);
+- one **pointer-jump sweep** (``pallas_trace.jump_sweep``: 1 + 2 *
+  JUMP_STEPS gathers over all n actors, over the graph's own jump
+  parents), and from it and the full-dirty sweep the ns per gathered
+  element and per streamed pair slot whose ratio is
+  ``pallas_trace.JUMP_GATHER_COST``: re-measure it here on a new chip.
 
 Plus, per trace mode (uigc.crgc.trace-mode: push/pull/jump/auto), the
 **per-sweep frontier decomposition** of the real fixpoint — sweep
@@ -22,7 +27,11 @@ threshold is tuned from recorded wake data instead of guessed.
 same graph geometry: sweep counts are hardware-independent, so the
 push-vs-jump convergence (O(diameter) vs O(log diameter) sweeps) is
 measurable without a chip — the number the ISSUE-6 acceptance
-criterion is judged against.
+criterion is judged against.  ``auto`` is simulated with the program's
+own policy helper (``pallas_trace.auto_jump_policy``) on the layout's
+chip geometry, and ``jump_sweeps`` says per mode how many sweeps ran
+the pointer jump: whether ``auto`` would engage it on a new graph
+shape can be had without a chip.
 
 Prints one JSON line.  Usage: python tools/sweep_profile.py [--n 10000000]
        [--simulate] [--modes auto,push,pull,jump] [--skip-probes]
@@ -63,14 +72,20 @@ def timed(fn, *args, reps=5):
     return statistics.median(ts) * 1e3
 
 
-def simulate_sweeps(graph, n, modes, jump_steps=None):
+def simulate_sweeps(graph, n, modes, jump_steps=None, geometry=None):
     """Hardware-independent fixpoint sweep counts per trace mode, by
     direct numpy simulation of the kernel's per-sweep semantics
     (pallas_trace trace_fn: table = mark & ~halted, hits gated by
-    in_use, jump parents squared ``JUMP_STEPS`` times per sweep through
-    transparent intermediates).  Pull gating changes per-sweep WORK,
-    never the sweep count, so pull reports push's count and auto
-    jump's."""
+    in_use, jump parents squared ``JUMP_STEPS`` times per engaged sweep
+    through transparent intermediates, the loop running while the table
+    changed).  Pull gating changes per-sweep WORK, never the sweep
+    count, so pull reports push's count.  ``auto`` is simulated with the
+    program's own policy (``pt.auto_jump_policy``) on the dirty walk
+    chunks of ``geometry`` = (n_slots, n_chunks, chunk_nodes): the slots
+    and chunks of the layout as the decremental backend packs it.
+
+    Returns {mode: {"sweeps", "jump_sweeps", "dirty_chunks"}}, and under
+    "auto" also the policy's "price"."""
     from uigc_tpu.ops import pallas_trace as pt
     from uigc_tpu.ops import trace as trace_ops
 
@@ -98,41 +113,75 @@ def simulate_sweeps(graph, n, modes, jump_steps=None):
     mark0 = in_use & (~halted) & seed
     trans = in_use & (~halted)
     trans_pad = np.concatenate([trans, [False]])
+    if geometry is None:
+        geometry = layout_geometry(psrc, pdst, n)
+    n_slots, n_chunks, chunk_nodes = geometry
+    pull_cut = max(1, int(round(pt.DEFAULT_PULL_DENSITY * n_chunks)))
+    bounds = np.arange(0, n, chunk_nodes)
 
-    counts = {}
-    # Pull gating changes per-sweep work, never the sweep count, so
-    # only the push/jump variants are actually simulated and the other
-    # modes alias their counts.
-    aliases = {pt.MODE_PULL: pt.MODE_PUSH, pt.MODE_AUTO: pt.MODE_JUMP}
+    out = {}
     for mode in modes:
-        src = aliases.get(mode, mode)
-        if src in counts:
+        if mode == pt.MODE_PULL and pt.MODE_PUSH in out:
+            out[mode] = out[pt.MODE_PUSH]
             continue
-        use_jump = src == pt.MODE_JUMP
+        engaged, spent, decide = mode == pt.MODE_JUMP, 0, None
+        if mode == pt.MODE_AUTO:
+            decide = pt.auto_jump_policy(
+                n, n_slots, n_chunks, pull_cut, steps=jump_steps
+            )
+        use_jump = mode in (pt.MODE_JUMP, pt.MODE_AUTO)
         j = pt.jump_parents(psrc, pdst, n) if use_jump else None
         mark = mark0.copy()
-        sweeps = 0
+        table, table_prev = mark & ~halted, np.zeros(n, bool)
+        dirty, jump_sweeps = [], 0
         while True:
-            sweeps += 1
-            active = mark & ~halted
+            n_dirty = int(
+                np.add.reduceat(table != table_prev, bounds).astype(bool).sum()
+            )
+            dirty.append(n_dirty)
+            if decide is not None:
+                engaged, spent = decide(engaged, spent, n_dirty)
             new = mark.copy()
-            hit_dst = pdst[active[psrc]]
+            hit_dst = pdst[table[psrc]]
             new[hit_dst] |= in_use[hit_dst]
-            if use_jump:
-                active_pad = np.concatenate([active, [False]])
-                jh = active_pad[j[:n]] & in_use
-                new |= jh
+            if engaged:
+                jump_sweeps += 1
+                active_pad = np.concatenate([table, [False]])
+                new |= active_pad[j[:n]] & in_use
                 for _ in range(jump_steps):
                     j2 = j[j]
                     can = trans_pad[j] & (j2 < n)
                     j = np.where(can, j2, j)
-            if np.array_equal(new, mark):
+            mark, table_prev, table = new, table, new & ~halted
+            # the device fixpoint's sweep count includes the final
+            # no-change sweep that proves convergence: same convention
+            if np.array_equal(table, table_prev):
                 break
-            mark = new
-        # the device fixpoint's sweep count includes the final
-        # no-change sweep that proves convergence — same convention
-        counts[src] = sweeps
-    return {m: counts[aliases.get(m, m)] for m in modes}
+        out[mode] = {"sweeps": len(dirty), "jump_sweeps": jump_sweeps,
+                     "dirty_chunks": dirty}
+        if decide is not None:
+            out[mode]["price"] = decide.price
+    return out
+
+
+def layout_geometry(psrc, pdst, n):
+    """(n_slots, n_chunks, chunk_nodes) of the pairs' layout as the
+    decremental backend packs it on the chip
+    (``IncrementalPallasLayout.rebuild``: pow2/quantum-padded blocks,
+    the chip's walk geometry): what AUTO's price of a jump sweep is
+    built from.  One host pack (44 s at 10M actors)."""
+    from uigc_tpu.ops import pallas_trace as pt
+
+    prep = pt.prepare_pairs(
+        psrc, pdst, n, pad_blocks_pow2=True, sub=pt.SUB_TPU,
+        group=pt.GROUP_TPU,
+    )
+    group_rows = pt.ROWS * pt.GROUP_TPU
+    return (
+        pt.kernel_slots((pt.layout_spec(prep),)),
+        prep["r_rows"] // group_rows,
+        group_rows * pt.LANE * pt.WORD_BITS,
+    )
 
 
 def main():
@@ -169,7 +218,7 @@ def main():
         # Sweep counts are hardware-independent: pure numpy, no device.
         graph = powerlaw_actor_graph(n, seed=seed, garbage_fraction=frac)
         modes = [m.strip() for m in args.modes.split(",") if m.strip()]
-        counts = simulate_sweeps(graph, n, modes)
+        sim = simulate_sweeps(graph, n, modes)
         print(
             json.dumps(
                 {
@@ -180,7 +229,10 @@ def main():
                         + (graph["supervisor"] >= 0).sum()
                     ),
                     "jump_steps": pt.JUMP_STEPS,
-                    "sweeps": counts,
+                    "sweeps": {m: sim[m]["sweeps"] for m in modes},
+                    "jump_sweeps": {m: sim[m]["jump_sweeps"] for m in modes},
+                    "dirty_chunks": {m: sim[m]["dirty_chunks"] for m in modes},
+                    "auto_jump_price": sim.get(pt.MODE_AUTO, {}).get("price"),
                 }
             )
         )
@@ -200,7 +252,11 @@ def main():
     n_blocks = prep["n_blocks"]
     n_chunks = r_rows // (pt.ROWS * prep["group"])
 
-    full_ms = none_ms = half_ms = pack_ms = pack2d_ms = None
+    jp = pt.jump_parents_from_graph(
+        graph["edge_src"], graph["edge_dst"],
+        graph["edge_weight"], graph["supervisor"], n,
+    )
+    full_ms = none_ms = half_ms = pack_ms = pack2d_ms = jump_ms = None
     if not args.skip_probes:
         propagate = pt.build_propagate(
             n_blocks, n_super, r_rows, s_rows, pt.default_interpret(),
@@ -264,6 +320,15 @@ def main():
         hits2d = jax.device_put(np.ones((t_rows, pt.LANE), bool))
         pack2d_ms = timed(pack2d, hits2d)
 
+        # One pointer-jump sweep over the graph's own jump parents: the
+        # 1 + 2 * JUMP_STEPS gathers over all n actors that AUTO's price
+        # of engaging the jump is built from (pt.JUMP_GATHER_COST).
+        @jax.jit
+        def jump(table, jump_j, trans_w):
+            return pt.jump_sweep(table, jump_j, trans_w, n, jnp)
+
+        jump_ms = timed(jump, table, jax.device_put(jp), table)
+
     # --- per-mode fixpoint decomposition, through the wake profiler -- #
     # The same per-wake fields the engine notes into its active wake
     # (engines/crgc/arrays.py _note_sweep_stats) flow through a real
@@ -281,10 +346,6 @@ def main():
         events.recorder.enable()
         events.recorder.add_listener(profiler)
         flags_h, recv_h = graph["flags"], graph["recv_count"]
-        jp = pt.jump_parents_from_graph(
-            graph["edge_src"], graph["edge_dst"],
-            graph["edge_weight"], graph["supervisor"], n,
-        )
         try:
             for mode in modes:
                 use_jump = mode in (pt.MODE_JUMP, pt.MODE_AUTO)
@@ -312,6 +373,8 @@ def main():
                             sweep_changed_supers=stats["changed_supers"][:k].tolist(),
                             sweep_tiles_skipped=stats["tiles_skipped"][:k].tolist(),
                             sweep_pull_on=stats["pull_on"][:k].tolist(),
+                            jump_sweeps=int(stats["jump_sweeps"]),
+                            sweep_jump_on=stats["jump_on"][:k].tolist(),
                         )
                 wk.end(mode=mode)
                 kk = min(k, len(stats["dirty_chunks"]))
@@ -322,6 +385,8 @@ def main():
                     "changed_supers": stats["changed_supers"][:kk].tolist(),
                     "tiles_skipped": stats["tiles_skipped"][:kk].tolist(),
                     "pull_on": stats["pull_on"][:kk].tolist(),
+                    "jump_sweeps": int(stats["jump_sweeps"]),
+                    "jump_on": stats["jump_on"][:kk].tolist(),
                 }
         finally:
             events.recorder.remove_listener(profiler)
@@ -340,6 +405,8 @@ def main():
         "wake_profile_recent": wake_records,
     }
     if not args.skip_probes:
+        gathered = (1 + 2 * pt.JUMP_STEPS) * n
+        slots = pt.kernel_slots((pt.layout_spec(prep),))
         out.update(
             {
                 "sweep_full_dirty_ms": round(full_ms, 2),
@@ -347,6 +414,15 @@ def main():
                 "sweep_no_dirty_ms": round(none_ms, 2),
                 "pack_seed_ms": round(pack_ms, 2),
                 "pack2d_per_sweep_ms": round(pack2d_ms, 2),
+                "jump_sweep_ms": round(jump_ms, 2),
+                # the two costs pt.JUMP_GATHER_COST is the ratio of
+                "gather_ns_per_element": round(jump_ms * 1e6 / gathered, 3),
+                "stream_ns_per_slot": round(full_ms * 1e6 / slots, 3),
+                "stream_ns_per_pair": round(
+                    full_ms * 1e6 / prep["n_pairs"], 3
+                ),
+                "n_slots": slots,
+                "jump_gather_cost": pt.JUMP_GATHER_COST,
             }
         )
     print(json.dumps(out))

@@ -58,9 +58,15 @@ DEFAULTS: Dict[str, Any] = {
     #            output supertile has no unmarked in-use node left are
     #            skipped outright (dense mid-sweep pruning)
     #   "jump" - push + pointer-jumping through a min-source parent
-    #            array squared each sweep (O(log diameter) sweeps)
-    #   "auto" - jump always on, pull gates switched per sweep when the
-    #            dirty-chunk density crosses the pull threshold
+    #            array squared each sweep (O(log diameter) sweeps); the
+    #            setting for a deployment known to be deep
+    #   "auto" - pull gates switched per sweep when the dirty-chunk
+    #            density crosses the pull threshold; pointer jumping
+    #            engaged lazily, for the rest of a fixpoint, once its
+    #            sparse sweeps have walked as many chunks as one jump
+    #            sweep costs (on the v5e a jump sweep costs about nine
+    #            push sweeps): a shallow graph never pays for it, a deep
+    #            one at most about twice what "jump" would
     # A config knob so A/B runs need no code edits.
     "uigc.crgc.trace-mode": "auto",
     # Dirty-chunk density (fraction of walk chunks dirty) above which

@@ -880,12 +880,13 @@ class ArrayShadowGraph:
                 ev.fields["trace_mode"] = self.trace_mode
                 if self.decremental:
                     return _readback(
-                        self._compute_marks_decremental(),
+                        self._compute_marks_decremental(ev.fields),
                         "marks.decremental",
                     )
                 if self.trace_impl != "xla":
                     return _readback(
-                        self._compute_marks_pallas(), "marks.pallas"
+                        self._compute_marks_pallas(ev.fields),
+                        "marks.pallas",
                     )
                 return _readback(
                     trace_ops.trace_marks_jax(
@@ -989,23 +990,26 @@ class ArrayShadowGraph:
             else:
                 self.trace_impl = "pallas"
 
-    def _note_sweep_stats(self, stats: dict) -> None:
+    def _note_sweep_stats(self, stats: dict, event: dict) -> None:
         """Hand the fixpoint's sweep counters to the active wake's
-        record (telemetry/profile.py): sweep counts and the per-sweep
-        frontier decomposition, which is where the pull-density
-        threshold is tuned from data (tools/sweep_profile.py writes the
-        same fields)."""
+        record (telemetry/profile.py) and to the ``DEVICE_TRACE``
+        ``event``'s fields: sweep counts, how many sweeps ran the pointer
+        jump, and the per-sweep frontier decomposition, which is where
+        the pull-density threshold is tuned from data
+        (tools/sweep_profile.py writes the same fields)."""
         k = int(stats["n_sweeps"])
-        fields = {"n_sweeps": k}
+        fields = {"n_sweeps": k, "jump_sweeps": int(stats["jump_sweeps"])}
         if "closure_sweeps" in stats:
             fields["closure_sweeps"] = int(stats["closure_sweeps"])
         k = min(k, len(stats["dirty_chunks"]))
-        for key in ("dirty_chunks", "changed_supers", "tiles_skipped", "pull_on"):
+        for key in ("dirty_chunks", "changed_supers", "tiles_skipped",
+                    "pull_on", "jump_on"):
             if key in stats:
                 fields["sweep_" + key] = [int(x) for x in stats[key][:k]]
         self.profile_wake.note(**fields)
+        event.update(fields)
 
-    def _compute_marks_pallas(self) -> np.ndarray:
+    def _compute_marks_pallas(self, event: dict) -> np.ndarray:
         """Device trace through the Pallas propagation kernel.
 
         Layout maintenance is incremental (ops/pallas_incremental.py):
@@ -1029,7 +1033,7 @@ class ArrayShadowGraph:
             marks, stats = self._inc.trace(
                 self.flags, self.recv_count, with_stats=True
             )
-            self._note_sweep_stats(stats)
+            self._note_sweep_stats(stats, event)
             return marks
         return self._inc.trace(self.flags, self.recv_count)
 
@@ -1058,7 +1062,7 @@ class ArrayShadowGraph:
                 )
         return obj
 
-    def _compute_marks_decremental(self) -> np.ndarray:
+    def _compute_marks_decremental(self, event: dict) -> np.ndarray:
         """Per-wake detection through the decremental tracer: the wake
         cost is proportional to the churn's affected region, not the
         graph (ops/pallas_decremental.py; the steady-state analogue of
@@ -1084,7 +1088,7 @@ class ArrayShadowGraph:
             with events.wake_phase(wake, "readback"):
                 marks = dec.unpack_marks(mark_w)
                 if wake is not None:  # and the wake's own counters
-                    self._note_sweep_stats(dec.wake_stats(1)[-1])
+                    self._note_sweep_stats(dec.wake_stats(1)[-1], event)
             return marks
         except Exception:
             # A poisoned async result surfaces at the wait or at the

@@ -98,15 +98,24 @@ def _build_wake_fn(
 
     ``mode`` applies to the REPAIR fixpoint only (pallas_trace MODE_*
     docs): on a cold start the repair IS the full derivation, which is
-    where the O(diameter) sweep wall lives.  The closure phase stays a
-    plain push fixpoint: it is bounded by the churn's region (usually
-    shallow), and jump hits there would only over-approximate the
-    closure — sound but more re-derivation for nothing.
+    where the O(diameter) sweep wall lives.  ``jump`` runs the pointer
+    jump in every repair sweep; ``auto`` decides per sweep, from the
+    dirty-chunk counts in its carry, and runs ``jump_sweep`` under a
+    ``lax.cond`` only once the fixpoint has stayed sparse for what one
+    jump sweep costs (``pt.auto_jump_policy``): on the v5e a jump sweep
+    over 10M actors costs nine push sweeps, so a shallow repair never
+    engages it and a deep one does after a bounded wait.  Until then the
+    jump-parent operand passes through the loop untouched.  The closure
+    phase stays a plain push fixpoint: it is bounded by the churn's
+    region (usually shallow), and jump hits there would only
+    over-approximate the closure — sound but more re-derivation for
+    nothing.
 
     ``stats`` is counted by the program that runs, every wake (one
     program per geometry; a few scalar updates per sweep):
-    ``closure_sweeps`` and ``n_sweeps`` (repair) are int32 scalars,
-    ``dirty_chunks``, ``tiles_skipped`` and ``pull_on`` hold the repair
+    ``closure_sweeps``, ``n_sweeps`` (repair) and ``jump_sweeps`` (the
+    repair sweeps that ran the jump) are int32 scalars, ``dirty_chunks``,
+    ``tiles_skipped``, ``pull_on`` and ``jump_on`` hold the repair
     fixpoint's first ``pt.MAX_SWEEP_STATS`` sweeps (later ones fold into
     the last slot).  They stay on the device until somebody asks
     (:meth:`DecrementalTracer.wake_stats`).
@@ -142,6 +151,9 @@ def _build_wake_fn(
     t_rows = n_super * s_rows
     sup_words = s_rows * (pt.LANE // pt.WORD_BITS)  # words per supertile
     pull_cut = max(1, int(round(pull_density * n_chunks)))
+    auto_jump = pt.auto_jump_policy(
+        n, pt.kernel_slots(specs), n_chunks, pull_cut
+    )
 
     def wake_fn(flags, recv_count, del_w, fresh_w, prev_mark_w,
                 prev_seed_w, prev_halted_w, prev_iu_w, prev_table,
@@ -254,6 +266,11 @@ def _build_wake_fn(
         def r_cond(carry):
             return carry["changed"]
 
+        def run_jump(mark_w, table, jump_j):
+            jh, jump_j = pt.jump_sweep(table, jump_j, trans_w, n, jnp)
+            with pt.scope("jump"):  # the pack of its hits is its cost
+                return mark_w | (pack(jh) & iu_w), jump_j
+
         def r_body(carry):
             mark_w, table = carry["mark"], carry["table"]
             d, l = carry["d"], carry["l"]
@@ -281,11 +298,10 @@ def _build_wake_fn(
             hit_w = pt.pack_hits_table(hits2d, r_rows, jnp)
             new_mark_w = mark_w | (hit_w & iu_w)
             if use_jump:
-                jh, jump_j = pt.jump_sweep(
-                    table, carry["jump"], trans_w, n, jnp
+                new_mark_w, jump_j, jump_state = pt.jump_step(
+                    mode, auto_jump, carry["jump_state"], n_dirty,
+                    run_jump, new_mark_w, table, carry["jump"],
                 )
-                with pt.scope("jump"):  # the pack of its hits is its cost
-                    new_mark_w = new_mark_w | (pack(jh) & iu_w)
             new_table = new_mark_w & nh_w
             d2, l2, changed = dirty_chunks(new_table, table)
             # The gated sweep fully re-derives suspect supertiles; the
@@ -296,7 +312,12 @@ def _build_wake_fn(
                        sweep_i=carry["sweep_i"] + 1,
                        st_dirty=carry["st_dirty"].at[i].set(n_dirty))
             if use_jump:
-                out["jump"] = jump_j
+                jump_on = jump_state[0].astype(jnp.int32)
+                out.update(
+                    jump=jump_j, jump_state=jump_state,
+                    jump_sweeps=carry["jump_sweeps"] + jump_on,
+                    st_jump=carry["st_jump"].at[i].set(jump_on),
+                )
             if use_pull:
                 out["st_skip"] = carry["st_skip"].at[i].set(
                     jnp.where(pull_on, sat.sum(), 0)
@@ -321,7 +342,10 @@ def _build_wake_fn(
                       "sweep_i": jnp.zeros((), jnp.int32),
                       "st_dirty": zero_stats}
             if use_jump:
-                carry0["jump"] = jump_j0.astype(jnp.int32)
+                carry0.update(jump=jump_j0.astype(jnp.int32),
+                              jump_state=pt.jump_state0(mode, jnp),
+                              jump_sweeps=jnp.zeros((), jnp.int32),
+                              st_jump=zero_stats)
             if use_pull:
                 carry0.update(st_skip=zero_stats, st_pull=zero_stats)
             out = jax.lax.while_loop(r_cond, r_body, carry0)
@@ -331,6 +355,8 @@ def _build_wake_fn(
             "dirty_chunks": out["st_dirty"],
             "tiles_skipped": out.get("st_skip", zero_stats),
             "pull_on": out.get("st_pull", zero_stats),
+            "jump_sweeps": out.get("jump_sweeps", jnp.zeros((), jnp.int32)),
+            "jump_on": out.get("st_jump", zero_stats),
         }
         return out["mark"], seed_w, halted_w, iu_w, out["table"], stats
 
@@ -532,8 +558,9 @@ class DecrementalTracer:
         """The sweep counters of the last ``last_n`` wakes (all that are
         kept, at most STATS_KEPT, when None), oldest first, read back
         from the device now: per wake ``closure_sweeps``, ``n_sweeps``
-        (repair) and, for the repair's first ``pt.MAX_SWEEP_STATS``
-        sweeps, ``dirty_chunks``, ``tiles_skipped`` and ``pull_on``.
+        (repair), ``jump_sweeps`` (the repair sweeps that ran the pointer
+        jump) and, for the repair's first ``pt.MAX_SWEEP_STATS`` sweeps,
+        ``dirty_chunks``, ``tiles_skipped``, ``pull_on`` and ``jump_on``.
         Waits for a wake still in flight; costs the wakes nothing."""
         import jax
 
@@ -549,6 +576,8 @@ class DecrementalTracer:
                 "dirty_chunks": host["dirty_chunks"][:k].tolist(),
                 "tiles_skipped": host["tiles_skipped"][:k].tolist(),
                 "pull_on": host["pull_on"][:k].tolist(),
+                "jump_sweeps": int(host["jump_sweeps"]),
+                "jump_on": host["jump_on"][:k].tolist(),
             })
         return out
 

@@ -114,8 +114,13 @@ MODE_PULL = "pull"
 #: needs O(log diameter) sweeps instead of O(diameter) ("Adaptive
 #: Work-Efficient Connected Components on the GPU", PAPERS.md).
 MODE_JUMP = "jump"
-#: jump acceleration always on, pull gates switched per sweep when the
-#: dirty-chunk density crosses ``pull_density`` — the default.
+#: the default: pull gates switched per sweep when the dirty-chunk
+#: density crosses ``pull_density``, and pointer jumping engaged lazily,
+#: only once the push fixpoint has stayed sparse for as many chunk walks
+#: as one jump sweep costs (``auto_jump_policy``).  A shallow graph (the
+#: 10M power-law benchmark: 12 push sweeps) never pays for a jump; a
+#: deep one (a chain) pays at most about twice what jumping from sweep 0
+#: would have cost.  A deployment known to be deep sets ``jump``.
 MODE_AUTO = "auto"
 TRACE_MODES = (MODE_AUTO, MODE_PUSH, MODE_PULL, MODE_JUMP)
 #: dirty-chunk density (fraction of walk chunks dirty) above which AUTO
@@ -126,8 +131,26 @@ DEFAULT_PULL_DENSITY = 0.25
 #: pointer doublings applied per sweep.  One doubling gives the classic
 #: 2^k reach-per-sweep schedule; two squares the relation twice per
 #: sweep (4^k), which at the 10M-actor benchmark geometry converges in
-#: ~4 sweeps instead of ~12 (tools/sweep_profile.py --simulate).
+#: 5 sweeps instead of 12 (tools/sweep_profile.py --simulate; the chip
+#: counted the same 5, PERF.md section 6).  What the sweeps cost on the
+#: v5e is the other way round from what that design assumed: a jump
+#: sweep is 1 + 2 * JUMP_STEPS gathers over all n actors, 370 ms at 10M
+#: (7.4 ns a gathered element), against 27-32 ms for a push sweep with
+#: every live chunk dirty and the pull gates on (78 ms walking all 39
+#: chunks ungated: 0.86 ns a streamed pair slot) — so the 7 sweeps saved
+#: cost 1.8 s (PERF.md section 6, PRs 27 and 28).  Hence AUTO's laziness.
 JUMP_STEPS = 2
+#: what one element gathered by ``jump_sweep`` costs on the v5e, in pair
+#: slots streamed by the propagate kernel.  PR 27's traced runs gave 7.3
+#: ns (1,833 ms of ``jump_ms.rederive`` over 5 sweeps x 5 gathers x 10M
+#: elements) against 0.85 ns (a 42 ms sweep over 49.7M pairs).  PR 28
+#: measured both alone at the 10M geometry (tools/sweep_profile.py on
+#: the chip): 7.406 ns a gathered element (one jump sweep 370.3 ms) and
+#: 0.859 ns a slot (the full-dirty sweep, all 39 chunks, no gate: 77.59
+#: ms over the 90.3M slots of 22,045 blocks, 49.7M pairs in them), so
+#: 8.62.  A measured property of the hardware, not a setting: AUTO's
+#: price of a jump sweep (``auto_jump_policy``) is built from it.
+JUMP_GATHER_COST = 8.6
 #: per-sweep stat ring length for with_stats builds (sweeps beyond this
 #: fold into the last slot; fixpoints run ~4-12 sweeps)
 MAX_SWEEP_STATS = 32
@@ -375,6 +398,84 @@ def jump_sweep(table, jump_j, trans_w, n, jnp, steps: int = JUMP_STEPS):
             can = bits_at(trans_w, jump_j, n, jnp) & (j2 < n)
             jump_j = jnp.where(can, j2, jump_j)
     return hits, jump_j
+
+
+def kernel_slots(specs) -> int:
+    """Pair slots the propagate kernels stream when every chunk is walked:
+    the packed layouts' capacity, static in their specs (xla tiers, the
+    landing pads of the newest churn, are not kernel work)."""
+    return sum(
+        spec[1] * ROWS * spec[-2] * LANE for spec in specs if spec[0] != "xla"
+    )
+
+
+def auto_jump_policy(n: int, n_slots: int, n_chunks: int, pull_cut: int,
+                     steps: int = JUMP_STEPS):
+    """When ``trace-mode: auto`` jumps: the one statement of it, shared
+    by the four fixpoints (full trace, wake, and their sharded forms) and
+    by ``tools/sweep_profile.py --simulate``.
+
+    Returns ``decide(engaged, spent, n_dirty) -> (engaged, spent)``,
+    called once per sweep before the sweep runs, on Python, numpy or
+    traced scalars alike.  ``spent`` counts the chunk walks of the
+    sweeps so far that were *sparse*: they ran under the pull cut
+    (``n_dirty < pull_cut``, the regime AUTO already tells apart) or
+    walked a single chunk (as sparse as the geometry can show: a layout
+    of under six chunks has ``pull_cut`` 1); each counts at least 1.
+    The jump engages, and stays engaged to the end of the fixpoint, once
+    ``spent`` reaches ``decide.price``: one jump sweep in chunk walks,
+    its ``(1 + 2 * steps) * n`` gathered elements times
+    JUMP_GATHER_COST over the ``n_slots / n_chunks`` pair slots of a
+    walk chunk.
+
+    Dense sweeps are left out on purpose: they are productive (millions
+    of new marks each at 10M), and a rule that counted them would engage
+    near the end of a shallow fixpoint and pay a jump sweep to save less
+    than one.  A fixpoint that STAYS sparse sweep after sweep is the
+    signature of depth, and there this is the ski-rental rule: at most
+    about twice the cost of having jumped from the start."""
+    per_chunk = max(1, n_slots // max(1, n_chunks))
+    gathered = (1 + 2 * steps) * n
+    price = max(1, int(round(gathered * JUMP_GATHER_COST / per_chunk)))
+    sparse_cut = max(pull_cut, 2)
+
+    def decide(engaged, spent, n_dirty):
+        engaged = engaged | (spent >= price)
+        walked = n_dirty + (n_dirty < 1)
+        return engaged, spent + (n_dirty < sparse_cut) * walked
+
+    decide.price = price
+    return decide
+
+
+def jump_step(mode, decide, state, n_dirty, run, mark_w, table, jump_j):
+    """The pointer jump's share of one sweep of a ``jump`` or ``auto``
+    fixpoint.  ``run(mark_w, table, jump_j) -> (mark_w | jump hits,
+    advanced jump_j)``; ``state`` is the carried (engaged, spent) of
+    ``decide`` (``auto_jump_policy``), starting from ``jump_state0``.
+    Returns (mark_w, jump_j, state).
+
+    ``jump`` runs it in every sweep, with no conditional in the program.
+    ``auto`` runs it under ``lax.cond`` on the carried flag; skipped,
+    marks and parents pass through untouched (the doublings depend only
+    on ``jump_j`` and the transparency table, so starting them late
+    loses nothing).  The conditional sits under the ``jump`` scope:
+    whatever it costs is the jump's."""
+    import jax
+
+    if mode == MODE_JUMP:
+        return (*run(mark_w, table, jump_j), state)
+    engaged, spent = decide(*state, n_dirty)
+    with scope("jump"):
+        mark_w, jump_j = jax.lax.cond(
+            engaged, run, lambda m, _t, j: (m, j), mark_w, table, jump_j
+        )
+    return mark_w, jump_j, (engaged, spent)
+
+
+def jump_state0(mode, jnp):
+    """(engaged, chunk walks spent while sparse) before the first sweep."""
+    return jnp.array(mode == MODE_JUMP), jnp.zeros((), jnp.int32)
 
 
 def saturated_tiles(mark_w, iu_w, n_super, sup_words, jnp):
@@ -1250,8 +1351,9 @@ def _build_trace_fn_multi(
     ``mode`` selects the propagation strategy (module MODE_* docs); jump
     and auto modes take a jump-parent operand right after flags/recv.
     ``with_stats`` returns (marks, stats) where stats carries the sweep
-    count and per-sweep frontier decomposition (dirty chunks, changed
-    supertiles, tiles skipped, pull-gate decision) for the profiler."""
+    count, the sweeps that ran the jump, and the per-sweep frontier
+    decomposition (dirty chunks, changed supertiles, tiles skipped,
+    pull-gate and jump decisions) for the profiler."""
     import jax
     import jax.numpy as jnp
 
@@ -1279,6 +1381,10 @@ def _build_trace_fn_multi(
     sup_words = s_rows * (LANE // WORD_BITS)  # words per supertile
     # AUTO's per-sweep pull decision, in dirty-chunk counts
     pull_cut = max(1, int(round(pull_density * n_chunks)))
+    # ... and its per-sweep jump decision
+    auto_jump = auto_jump_policy(
+        n, kernel_slots(specs), n_chunks, pull_cut
+    )
 
     def trace_fn(flags, recv_count, *rest):
         if use_jump:
@@ -1329,6 +1435,10 @@ def _build_trace_fn_multi(
         # it: the pull gates (masked saturation update) or the stats.
         track_super = use_pull or with_stats
 
+        def run_jump(mark_w, table, jump_j):
+            jh, jump_j = jump_sweep(table, jump_j, trans_w, n, jnp)
+            return mark_w | (pack(jh) & iu_w), jump_j
+
         def body(carry):
             mark_w, table = carry["mark"], carry["table"]
             d, l = carry["d"], carry["l"]
@@ -1358,11 +1468,13 @@ def _build_trace_fn_multi(
             hits2d = sweep(table, d, l, layout_args, gate=gate)
             hit_w = pack2d(hits2d)
             new_mark_w = mark_w | (hit_w & iu_w)
+            jump_on = jnp.array(False)
             if use_jump:
-                jh, jump_j = jump_sweep(
-                    table, carry["jump"], trans_w, n, jnp
+                new_mark_w, jump_j, jump_state = jump_step(
+                    mode, auto_jump, carry["jump_state"], n_dirty,
+                    run_jump, new_mark_w, table, carry["jump"],
                 )
-                new_mark_w = new_mark_w | (pack(jh) & iu_w)
+                jump_on = jump_state[0]
             new_table = new_mark_w & nh_w
             d2, l2, changed, sup_ch2 = dirty_chunks(new_table, table)
             out = dict(carry, mark=new_mark_w, table=new_table, d=d2,
@@ -1372,9 +1484,13 @@ def _build_trace_fn_multi(
             if use_pull:
                 out["sat"] = sat
             if use_jump:
-                out["jump"] = jump_j
+                out.update(jump=jump_j, jump_state=jump_state)
             if with_stats:
                 i = jnp.minimum(carry["sweep_i"], MAX_SWEEP_STATS - 1)
+                out["st_jump"] = carry["st_jump"].at[i].set(
+                    jump_on.astype(jnp.int32)
+                )
+                out["jump_sweeps"] = carry["jump_sweeps"] + jump_on
                 out["sweep_i"] = carry["sweep_i"] + 1
                 out["st_dirty"] = carry["st_dirty"].at[i].set(n_dirty)
                 out["st_super"] = carry["st_super"].at[i].set(
@@ -1403,13 +1519,15 @@ def _build_trace_fn_multi(
                 mark_w0, iu_w, n_super, sup_words, jnp
             )
         if use_jump:
-            carry0["jump"] = jump_j0.astype(jnp.int32)
+            carry0.update(jump=jump_j0.astype(jnp.int32),
+                          jump_state=jump_state0(mode, jnp))
         if with_stats:
             zero_stats = jnp.zeros((MAX_SWEEP_STATS,), jnp.int32)
             carry0.update(
                 sweep_i=jnp.zeros((), jnp.int32), st_dirty=zero_stats,
                 st_super=zero_stats, st_skip=zero_stats,
-                st_pull=zero_stats,
+                st_pull=zero_stats, st_jump=zero_stats,
+                jump_sweeps=jnp.zeros((), jnp.int32),
             )
         out = jax.lax.while_loop(cond, body, carry0)
         if not with_stats:
@@ -1420,6 +1538,8 @@ def _build_trace_fn_multi(
             "changed_supers": out["st_super"],
             "tiles_skipped": out["st_skip"],
             "pull_on": out["st_pull"],
+            "jump_sweeps": out["jump_sweeps"],
+            "jump_on": out["st_jump"],
         }
         return unpack(out["mark"]), stats
 

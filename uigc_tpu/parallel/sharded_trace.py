@@ -401,6 +401,21 @@ def pack_shard_layouts(
     return stacked, meta, slot_vals
 
 
+def _mesh_jump_policy(mesh, n_pad, n_blocks, sub, n_chunks, pull_cut):
+    """``pt.auto_jump_policy`` for a sharded fixpoint, in the mesh's
+    totals: the whole node space and every shard's ``n_blocks`` blocks,
+    as one chip holding the graph would count them (block padding
+    apart).  What a shard pays differs, since each walks only its own
+    blocks while the pointer doublings are replicated, so on D chips a
+    jump sweep is dearer than this price says, by up to D: ``auto`` then
+    engages early by that factor, still within a bounded multiple of
+    the best (no four-chip cell measures it; PERF.md section 7)."""
+    from ..ops import pallas_trace as pt
+
+    slots = mesh.devices.size * n_blocks * pt.ROWS * sub * pt.LANE
+    return pt.auto_jump_policy(n_pad, slots, n_chunks, pull_cut)
+
+
 def make_sharded_pallas_trace(
     mesh,
     n_pad: int,
@@ -415,6 +430,7 @@ def make_sharded_pallas_trace(
     group: int = None,
     mode: str = None,
     pull_density: float = None,
+    with_stats: bool = False,
 ):
     """The mesh trace with the Pallas propagation kernel per shard.
 
@@ -432,10 +448,15 @@ def make_sharded_pallas_trace(
     and pull/auto skip blocks whose local destination supertile is
     saturated (the pull decision and the dirty density are both derived
     from replicated tables, so every shard agrees on the sweep plan).
+    auto engages the jump by the single-device rule on the same
+    replicated dirty count (``pt.auto_jump_policy`` over the mesh's
+    whole node space and all shards' slots), so the shards agree on that
+    too, and a graph engages on the same sweep on one chip and on four.
 
     fn(flags, recv, bmeta1, bmeta2, row_pos, emeta, bsrc, bdst[, jump_j])
     -> mark with flags/recv sharded by node range, layout operands
-    sharded on their leading device axis, jump_j replicated.
+    sharded on their leading device axis, jump_j replicated.  With
+    ``with_stats`` it returns (mark, {"n_sweeps", "jump_sweeps"}).
     """
     jax, jnp = _jax()
     from jax.sharding import PartitionSpec as P
@@ -472,6 +493,9 @@ def make_sharded_pallas_trace(
     n_chunks = r_rows // group_rows
     words_pad = r_rows * pt.LANE
     pull_cut = max(1, int(round(pull_density * n_chunks)))
+    auto_jump = _mesh_jump_policy(
+        mesh, n_pad, n_blocks, sub, n_chunks, pull_cut
+    )
 
     def local_trace(flags, recv, bmeta1, bmeta2, row_pos, emeta, bsrc,
                     bdst, *rest):
@@ -513,8 +537,12 @@ def make_sharded_pallas_trace(
             gather_table(iu_w & nh_w) if use_jump else None
         )
 
+        def run_jump(mark_w, table, jump_j):
+            jh, jump_j = jump_local(table, trans_table, jump_j)
+            return mark_w | (pack_words(jh) & iu_w), jump_j
+
         def body(carry):
-            mark_w, table, d, l, jump_j, _ = carry
+            mark_w, table, d, l, jump_j, jump_state, counts, _ = carry
             if use_pull:
                 sat = pt.saturated_tiles(
                     mark_w, iu_w, n_super_shard, sup_words, jnp
@@ -529,11 +557,16 @@ def make_sharded_pallas_trace(
             hits2d = sweep_hits(table, d, l, gate)
             new_mark_w = mark_w | (pt.pack_hits_words(hits2d, jnp) & iu_w)
             if use_jump:
-                jh, jump_j = jump_local(table, trans_table, jump_j)
-                new_mark_w = new_mark_w | (pack_words(jh) & iu_w)
+                new_mark_w, jump_j, jump_state = pt.jump_step(
+                    mode, auto_jump, jump_state, d[n_chunks], run_jump,
+                    new_mark_w, table, jump_j,
+                )
             new_table = gather_table(new_mark_w & nh_w)
             d2, l2, changed = dirty_chunks(new_table, table)
-            return new_mark_w, new_table, d2, l2, jump_j, changed
+            # [sweeps, sweeps that ran the jump]
+            counts = counts + jnp.stack([jnp.array(True), jump_state[0]])
+            return (new_mark_w, new_table, d2, l2, jump_j, jump_state,
+                    counts, changed)
 
         mark_w0 = pack_words(mark0)
         table0 = gather_table(mark_w0 & nh_w)
@@ -543,12 +576,14 @@ def make_sharded_pallas_trace(
             if use_jump
             else jnp.zeros((1,), jnp.int32)
         )
-        mark_w, _, _, _, _, _ = jax.lax.while_loop(
-            cond, body, (mark_w0, table0, d0, l0, jj0, changed0)
+        mark_w, _, _, _, _, _, counts, _ = jax.lax.while_loop(
+            cond, body,
+            (mark_w0, table0, d0, l0, jj0, pt.jump_state0(mode, jnp),
+             jnp.zeros((2,), jnp.int32), changed0),
         )
         shifts = jnp.arange(pt.WORD_BITS, dtype=jnp.int32)
         bits = (mark_w[:, None] >> shifts[None, :]) & 1
-        return (bits.reshape(-1) > 0).reshape(1, -1)
+        return (bits.reshape(-1) > 0).reshape(1, -1), counts
 
     spec_nodes = P(axis)
     spec_dev = P(axis, None)
@@ -566,11 +601,18 @@ def make_sharded_pallas_trace(
     )
     if use_jump:
         in_specs = in_specs + (P(),)  # replicated jump parents
-    fn = _shard_map_unchecked(local_trace, mesh, in_specs, spec_dev)
+    fn = _shard_map_unchecked(
+        local_trace, mesh, in_specs, (spec_dev, P())
+    )
 
     @jax.jit
     def traced(*args):
-        return fn(*args).reshape(-1)
+        mark, counts = fn(*args)
+        if not with_stats:
+            return mark.reshape(-1)
+        return mark.reshape(-1), {
+            "n_sweeps": counts[0], "jump_sweeps": counts[1],
+        }
 
     return traced
 
@@ -734,6 +776,9 @@ def make_sharded_decremental_wake(
     words_pad = r_rows * pt.LANE
     sup_words = s_rows * (pt.LANE // pt.WORD_BITS)
     pull_cut = max(1, int(round(pull_density * n_chunks)))
+    auto_jump = _mesh_jump_policy(
+        mesh, n_pad, n_blocks, sub, n_chunks, pull_cut
+    )
 
     def local_wake(flags, recv, del_w, fresh_w, p_mark, p_seed, p_halt,
                    p_iu, p_active, bmeta1, bmeta2, row_pos, emeta,
@@ -834,8 +879,12 @@ def make_sharded_decremental_wake(
         def r_cond(carry):
             return carry[-1]
 
+        def run_jump(mark_w, table, jump_j):
+            jh, jump_j = jump_local(table, trans_table, jump_j)
+            return mark_w | (pack_words(jh) & iu_w), jump_j
+
         def r_body(carry):
-            mark_w, table, d, l, use_gate, jump_j, _ = carry
+            mark_w, table, d, l, use_gate, jump_j, jump_state, _ = carry
             # Gate composition as in the single-device wake: the repair
             # forcing (GATE_FULL on suspect tiles, first sweep only)
             # under the pull skip (GATE_SKIP on saturated tiles).  Both
@@ -859,22 +908,27 @@ def make_sharded_decremental_wake(
             hits2d = sweep_hits(table, d, l, gate)
             new_mark = mark_w | (pack2d(hits2d) & iu_w)
             if use_jump:
-                jh, jump_j = jump_local(table, trans_table, jump_j)
-                new_mark = new_mark | (pack_words(jh) & iu_w)
+                # AUTO decides on the same replicated dirty count as
+                # the pull decision above
+                new_mark, jump_j, jump_state = pt.jump_step(
+                    mode, auto_jump, jump_state, d[n_chunks], run_jump,
+                    new_mark, table, jump_j,
+                )
             new_table = gather_table(new_mark & nh_w)
             d2, l2, changed = dirty_chunks(new_table, table)
             return (new_mark, new_table, d2, l2, jnp.array(False),
-                    jump_j, changed)
+                    jump_j, jump_state, changed)
 
         jj0 = (
             jump_j0.reshape(-1).astype(jnp.int32)
             if use_jump
             else jnp.zeros((1,), jnp.int32)
         )
-        mark_w, _, _, _, _, _, _ = jax.lax.while_loop(
+        mark_w, _, _, _, _, _, _, _ = jax.lax.while_loop(
             r_cond,
             r_body,
-            (mark_w0, table0, rd0, rl0, jnp.array(True), jj0, run0),
+            (mark_w0, table0, rd0, rl0, jnp.array(True), jj0,
+             pt.jump_state0(mode, jnp), run0),
         )
         active_w = mark_w & nh_w
 
