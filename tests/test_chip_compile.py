@@ -30,6 +30,9 @@ GROUP_ROWS = pt.ROWS * pt.GROUP_TPU
 #: (powerlaw_actor_graph(10M, seed 0): 49.7M pairs, quantum-padded
 #: blocks) — the geometry chip_smoke.py prints on the chip.
 GEOM_10M = dict(n=10_000_000, n_blocks=24_576, n_super=2442, r_rows=2496)
+#: chain_actor_graph(1M) (the benchmark's ``chain-1m``: 2.0M pairs, 4
+#: walk chunks), as ``IncrementalPallasLayout.rebuild`` packs it
+GEOM_CHAIN_1M = dict(n=1_000_000, n_blocks=4096, n_super=245, r_rows=256)
 #: a 20k-actor layout (pow2-padded blocks)
 GEOM_SMALL = dict(n=20_000, n_blocks=128, n_super=5, r_rows=64)
 #: 10M over four shards, as ``pack_shard_layouts`` packs it
@@ -139,6 +142,22 @@ def test_decremental_wake_compiles_at_10m(one_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
 
 
+@pytest.mark.parametrize("mode", [pt.MODE_AUTO, pt.MODE_JUMP])
+def test_decremental_wake_compiles_at_chain_1m(one_chip, mode):
+    """The geometry on which the jump is taken: its sub-scopes are in the
+    compiled text, and ``auto`` prices a jump sweep at 10 chunk walks."""
+    compiled = _compile_wake(GEOM_CHAIN_1M, one_chip, mode)
+    assert _mosaic_calls(compiled) >= 2
+    text = compiled.as_text()
+    for part in ("hits", "double", "pack"):
+        assert f"/jump/{part}/" in text, part
+    fn = pd.get_wake_fn(
+        GEOM_CHAIN_1M["n"], _spec(GEOM_CHAIN_1M), GEOM_CHAIN_1M["n_super"],
+        GEOM_CHAIN_1M["r_rows"], pt.S_ROWS, interpret=False, mode=mode,
+    )
+    assert fn.jump_price == 10
+
+
 @pytest.mark.parametrize(
     "program,mode,with_stats",
     # the full trace has a with_stats variant; the wake has one program
@@ -163,7 +182,7 @@ def test_wake_program_counts_and_names(one_chip, mode):
     *words, stats = compiled.out_info
     assert len(words) == 5
     assert stats["closure_sweeps"].shape == stats["n_sweeps"].shape == ()
-    assert stats["jump_sweeps"].shape == ()
+    assert stats["jump_sweeps"].shape == stats["jump_spent"].shape == ()
     for key in ("dirty_chunks", "tiles_skipped", "pull_on", "jump_on"):
         assert stats[key].shape == (pt.MAX_SWEEP_STATS,)
     text = compiled.as_text()

@@ -177,6 +177,9 @@ def test_scopes_in_the_lowered_wake_program(mode):
     assert f"push/{pt.KERNEL_NAME}" in text
     if "jump" not in helpers:
         assert "/jump" not in text
+    else:  # the jump's parts carry scopes of their own
+        for part in ("hits", "double", "pack"):
+            assert f"/jump/{part}/" in text, part
     if "sat" not in helpers:
         assert "/sat" not in text
 

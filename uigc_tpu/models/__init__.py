@@ -1,3 +1,3 @@
-from .graphgen import powerlaw_actor_graph, ring_graph
+from .graphgen import chain_actor_graph, powerlaw_actor_graph, ring_graph
 
-__all__ = ["powerlaw_actor_graph", "ring_graph"]
+__all__ = ["chain_actor_graph", "powerlaw_actor_graph", "ring_graph"]

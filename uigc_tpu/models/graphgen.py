@@ -3,7 +3,8 @@
 Produces graphs directly in the kernel layout (ops/trace.py arrays):
 power-law out-degree actor graphs with a controllable garbage fraction —
 the BASELINE config-5 workload ("10M-actor power-law refob graph") — plus
-the ring/clique cyclic-garbage topologies of config 3.
+the ring/clique cyclic-garbage topologies of config 3 and the deep chain
+of config 1.
 """
 
 from __future__ import annotations
@@ -110,6 +111,53 @@ def powerlaw_actor_graph(
         "edge_src": edge_src,
         "edge_dst": edge_dst,
         "edge_weight": edge_weight,
+        "expected_garbage": expected_garbage,
+        "n_live": n_live,
+        "n_garbage": n_garbage,
+    }
+
+
+def chain_actor_graph(n: int, garbage_fraction: float = 0.5) -> Dict[str, np.ndarray]:
+    """A graph as deep as it is long (BASELINE config 1, the upstream
+    default test workload: an acyclic chain, every actor spawned by,
+    supervised by and referenced from the one before it), beside a
+    released ring (config 3) as its garbage half.
+
+    Slots ``[0, n_live)`` are one chain: slot 0 is the only root,
+    ``supervisor[i] = i - 1`` and one reference ``i - 1 -> i``.  Slots
+    ``[n_live, n)`` are one ring ``g -> g + 1 -> ... -> n_live`` with the
+    same supervisor pointers inside it; its head is supervised by slot 0
+    (a child keeps its supervisor alive, not the other way round) and has
+    no reference from outside, so the ring is garbage.  Slot order is
+    spawn order, as the runtime interns.  A push fixpoint needs one sweep
+    per hop here (``n_live - 1`` of them): the graph the pointer jump is
+    for.  No randomness; same return dict as ``powerlaw_actor_graph``."""
+    n_garbage = int(n * garbage_fraction)
+    n_live = n - n_garbage
+    if n_live < 1:
+        n_live, n_garbage = 1, n - 1
+
+    flags = np.full(n, _F.FLAG_IN_USE | _F.FLAG_INTERNED | _F.FLAG_LOCAL, dtype=np.uint8)
+    flags[0] |= _F.FLAG_ROOT
+    supervisor = np.arange(-1, n - 1, dtype=np.int32)
+    if n_garbage > 0:
+        supervisor[n_live] = 0
+
+    chain = np.arange(n_live, dtype=np.int32)
+    ring = np.arange(n_live, n, dtype=np.int32)
+    ring_dst = np.roll(ring, -1) if n_garbage > 1 else ring[:0]
+    edge_src = np.concatenate([chain[:-1], ring[: ring_dst.size]])
+    edge_dst = np.concatenate([chain[1:], ring_dst])
+
+    expected_garbage = np.zeros(n, dtype=bool)
+    expected_garbage[n_live:] = True
+    return {
+        "flags": flags,
+        "recv_count": np.zeros(n, dtype=np.int64),
+        "supervisor": supervisor,
+        "edge_src": edge_src,
+        "edge_dst": edge_dst,
+        "edge_weight": np.ones(edge_src.shape[0], dtype=np.int64),
         "expected_garbage": expected_garbage,
         "n_live": n_live,
         "n_garbage": n_garbage,

@@ -113,8 +113,10 @@ def _build_wake_fn(
 
     ``stats`` is counted by the program that runs, every wake (one
     program per geometry; a few scalar updates per sweep):
-    ``closure_sweeps``, ``n_sweeps`` (repair) and ``jump_sweeps`` (the
-    repair sweeps that ran the jump) are int32 scalars, ``dirty_chunks``,
+    ``closure_sweeps``, ``n_sweeps`` (repair), ``jump_sweeps`` (the
+    repair sweeps that ran the jump) and ``jump_spent`` (the policy's
+    ``spent`` at exit, to be read against the static
+    :attr:`DecrementalTracer.jump_price`) are int32 scalars, ``dirty_chunks``,
     ``tiles_skipped``, ``pull_on`` and ``jump_on`` hold the repair
     fixpoint's first ``pt.MAX_SWEEP_STATS`` sweeps (later ones fold into
     the last slot).  They stay on the device until somebody asks
@@ -268,7 +270,7 @@ def _build_wake_fn(
 
         def run_jump(mark_w, table, jump_j):
             jh, jump_j = pt.jump_sweep(table, jump_j, trans_w, n, jnp)
-            with pt.scope("jump"):  # the pack of its hits is its cost
+            with pt.scope("jump"), pt.scope("pack"):  # its hits' pack is its cost
                 return mark_w | (pack(jh) & iu_w), jump_j
 
         def r_body(carry):
@@ -357,11 +359,16 @@ def _build_wake_fn(
             "pull_on": out.get("st_pull", zero_stats),
             "jump_sweeps": out.get("jump_sweeps", jnp.zeros((), jnp.int32)),
             "jump_on": out.get("st_jump", zero_stats),
+            # the policy's chunk walks spent while sparse, at exit
+            "jump_spent": (out["jump_state"][1] if use_jump
+                           else jnp.zeros((), jnp.int32)),
         }
         return out["mark"], seed_w, halted_w, iu_w, out["table"], stats
 
     jitted = jax.jit(wake_fn)
     jitted.raw = wake_fn  # unjitted body, for callers composing it
+    #: what ``auto`` prices one jump sweep at, in chunk walks (static)
+    jitted.jump_price = auto_jump.price if use_jump else None
     return jitted
 
 
@@ -432,6 +439,14 @@ class DecrementalTracer:
         self._pending_fresh_dst: Set[int] = set()
         self._unpack = None
         self._zeros = None
+        self._wake_fn = None
+
+    @property
+    def jump_price(self) -> Optional[int]:
+        """What ``auto`` prices one jump sweep at, in chunk walks
+        (``pt.auto_jump_policy``), for the geometry of the last wake
+        staged; None before the first wake and in a mode without jump."""
+        return getattr(self._wake_fn, "jump_price", None)
 
     # -- building / mutation (layout pass-throughs that watch removals) --
 
@@ -524,6 +539,7 @@ class DecrementalTracer:
             # full seed-diff dirty set)
         del_w = self._id_words(self._pending_del_dst, r_rows)
         fresh_w = self._id_words(self._pending_fresh_dst, r_rows)
+        self._wake_fn = fn
         return fn, del_w, fresh_w, args
 
     def wake_device(self, flags_dev, recv_dev, staged=None):
@@ -559,8 +575,10 @@ class DecrementalTracer:
         kept, at most STATS_KEPT, when None), oldest first, read back
         from the device now: per wake ``closure_sweeps``, ``n_sweeps``
         (repair), ``jump_sweeps`` (the repair sweeps that ran the pointer
-        jump) and, for the repair's first ``pt.MAX_SWEEP_STATS`` sweeps,
-        ``dirty_chunks``, ``tiles_skipped``, ``pull_on`` and ``jump_on``.
+        jump), ``jump_spent`` (the ``auto`` policy's sparse chunk walks
+        at exit; against :attr:`jump_price`) and, for the repair's first
+        ``pt.MAX_SWEEP_STATS`` sweeps, ``dirty_chunks``,
+        ``tiles_skipped``, ``pull_on`` and ``jump_on``.
         Waits for a wake still in flight; costs the wakes nothing."""
         import jax
 
@@ -578,6 +596,7 @@ class DecrementalTracer:
                 "pull_on": host["pull_on"][:k].tolist(),
                 "jump_sweeps": int(host["jump_sweeps"]),
                 "jump_on": host["jump_on"][:k].tolist(),
+                "jump_spent": int(host["jump_spent"]),
             })
         return out
 

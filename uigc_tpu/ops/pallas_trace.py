@@ -390,13 +390,18 @@ def jump_sweep(table, jump_j, trans_w, n, jnp, steps: int = JUMP_STEPS):
     eventually mark v — the jump only collapses the sweeps in between.
     Parents never extend through an opaque node, and the host layer
     invalidates J[d] whenever the pair it was built from is removed, so
-    a jump can never cross a deleted edge or a halted relay."""
+    a jump can never cross a deleted edge or a halted relay.
+
+    Its two parts carry scopes of their own under ``jump``: ``jump/hits``
+    and ``jump/double`` (the wake program adds ``jump/pack``)."""
     with scope("jump"):
-        hits = bits_at(table, jump_j[:n], n, jnp)
-        for _ in range(steps):
-            j2 = jump_j[jump_j]
-            can = bits_at(trans_w, jump_j, n, jnp) & (j2 < n)
-            jump_j = jnp.where(can, j2, jump_j)
+        with scope("hits"):  # the gather of the parents' bits
+            hits = bits_at(table, jump_j[:n], n, jnp)
+        with scope("double"):  # 2 gathers a doubling
+            for _ in range(steps):
+                j2 = jump_j[jump_j]
+                can = bits_at(trans_w, jump_j, n, jnp) & (j2 < n)
+                jump_j = jnp.where(can, j2, jump_j)
     return hits, jump_j
 
 
