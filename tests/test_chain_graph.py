@@ -77,6 +77,41 @@ def test_chain_through_the_tracer(mode):
         assert w["jump_spent"] == 0 and w["jump_sweeps"] == w["n_sweeps"]
 
 
+def test_simulated_closure_of_a_supervised_chain_swallows_it():
+    """``tools/sweep_profile.py simulate_sweeps`` with the wake's closure
+    policy: one suspect in mid-chain closes over every mark (forward by
+    the references, back by the supervisors), a hop a sweep, and each
+    mode leaves the closure at its own price, a share of its own
+    derivation."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
+    try:
+        from sweep_profile import simulate_sweeps
+    finally:
+        sys.path.pop(0)
+    g = chain_actor_graph(N)
+    n_live = g["n_live"]
+    modes = [pt.MODE_PUSH, pt.MODE_AUTO, pt.MODE_JUMP]
+    # one walk chunk over all actors; slots enough for a price of a few sweeps
+    sim = simulate_sweeps(g, N, modes, geometry=(8 * N, 1, N), suspects=[n_live // 2])
+    assert sim[pt.MODE_PUSH]["sweeps"] == n_live  # a hop a sweep, and the one that finds no more
+    assert sim[pt.MODE_JUMP]["sweeps"] <= math.log2(N) / 2 + 3
+    assert sim[pt.MODE_AUTO]["sweeps"] <= sim[pt.MODE_AUTO]["price"] + math.log2(N) / 2 + 3
+    for mode in modes:
+        c, walks = sim[mode]["closure"], sum(sim[mode]["dirty_chunks"])
+        assert walks == sim[mode]["sweeps"]  # one chunk: a walk a sweep
+        assert c["marks"] == c["sizes"][-1] == n_live  # the closure is every mark
+        assert c["full_sweeps"] == n_live // 2 + 1  # the longer way round, and the last look
+        assert c["price"] == pt.closure_price(walks)
+        assert c["bailed"] and c["sweeps"] == c["spent"] == c["price"]
+    assert sim[pt.MODE_PUSH]["closure"]["price"] == n_live // 8
+    # a suspect in the released ring was never marked: nothing to close over
+    sim = simulate_sweeps(g, N, [pt.MODE_JUMP], geometry=(8 * N, 1, N), suspects=[n_live + 5])
+    assert sim[pt.MODE_JUMP]["closure"]["sweeps"] == 0 and not sim[pt.MODE_JUMP]["closure"]["bailed"]
+
+
 class Spawned(NoRefs):
     def __init__(self, name):
         self.name = name

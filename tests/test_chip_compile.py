@@ -117,7 +117,9 @@ def _compile_wake(geom, s, mode):
         interpret=False, mode=mode,
     )
     words = _struct((geom["r_rows"], LANE), np.int32, s)
-    args = _node_structs(geom, s) + [words] * 7
+    # suspects (2), the previous fixpoint (5 word tables and the walks
+    # of its last derivation from nothing)
+    args = _node_structs(geom, s) + [words] * 7 + [_struct((), np.int32, s)]
     if mode in (pt.MODE_JUMP, pt.MODE_AUTO):
         args.append(_struct((geom["n"] + 1,), np.int32, s))
     return fn.lower(*args, *_layout_structs(geom, s)).compile()
@@ -140,6 +142,12 @@ def test_decremental_wake_compiles_at_10m(one_chip):
     assert _mosaic_calls(compiled) >= 2  # closure + repair fixpoints
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
+    # the next wake's previous state: five word tables and the carried
+    # walks of the last derivation from nothing, then the counters
+    *words, walks, stats = compiled.out_info
+    assert [w.shape for w in words] == [(GEOM_10M["r_rows"], LANE)] * 5
+    assert walks.shape == () and walks.dtype == np.int32
+    assert stats["closure_bailed"].shape == stats["closure_spent"].shape == ()
 
 
 @pytest.mark.parametrize("mode", [pt.MODE_AUTO, pt.MODE_JUMP])
@@ -179,9 +187,11 @@ def test_wake_program_counts_and_names(one_chip, mode):
     counters, and the compiled text names its phases and its kernel, so a
     device trace of it can be summed by phase."""
     compiled = _compile_wake(GEOM_SMALL, one_chip, mode)
-    *words, stats = compiled.out_info
-    assert len(words) == 5
+    *words, walks, stats = compiled.out_info
+    assert len(words) == 5 and walks.shape == ()
     assert stats["closure_sweeps"].shape == stats["n_sweeps"].shape == ()
+    assert stats["closure_bailed"].shape == stats["closure_spent"].shape == ()
+    assert stats["gated_tiles"].shape == ()
     assert stats["jump_sweeps"].shape == stats["jump_spent"].shape == ()
     for key in ("dirty_chunks", "tiles_skipped", "pull_on", "jump_on"):
         assert stats[key].shape == (pt.MAX_SWEEP_STATS,)

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from uigc_tpu.ops import pallas_decremental as pd
+from uigc_tpu.ops import pallas_trace as pt
 from uigc_tpu.ops import trace as trace_ops
 from uigc_tpu.ops.pallas_incremental import EDGE, SUP
 
@@ -338,3 +339,157 @@ def test_selective_gating_at_scale(seed):
             f"{int((got != expected).sum())} mismatched marks"
         )
     assert tracer.layout.stats["anomalies"] == 0
+
+
+# ------------------------------------------------------------------- #
+# the closure's price and the cold road (PR 30)
+# ------------------------------------------------------------------- #
+
+CHUNK = 8 * 128 * 32  # actors in one walk chunk at the interpreted geometry
+
+
+def supervised_tree(n, fan=8):
+    """A tree as a runtime builds it: every actor referenced from and
+    supervised by its parent, so every live actor reaches the root by its
+    supervisor chain and the marks are one strongly connected component.
+    Returns (flags, recv, src, dst, supervisor)."""
+    flags = np.full(n, F.FLAG_IN_USE | F.FLAG_INTERNED, np.uint8)
+    flags[0] |= F.FLAG_ROOT
+    child = np.arange(1, n, dtype=np.int32)
+    parent = ((child - 1) // fan).astype(np.int32)
+    sup = np.full(n, -1, np.int32)
+    sup[child] = parent
+    return flags, np.zeros(n, np.int64), parent, child, sup
+
+
+def _walks(w):
+    assert w["n_sweeps"] <= len(w["dirty_chunks"])  # every sweep kept its slot
+    return sum(w["dirty_chunks"])
+
+
+@pytest.mark.parametrize(
+    # every trace mode on one walk chunk, and the default on three, where
+    # a sweep costs as many walks as it has dirty chunks
+    "mode,n",
+    [(m, 4096) for m in pt.TRACE_MODES] + [(pt.MODE_AUTO, 2 * CHUNK + 4000)],
+)
+def test_closure_that_swallows_the_marks_gives_up(mode, n):
+    """Released references among live, supervised actors: every suspect's
+    closure is every mark, so the wake leaves the closure once it has cost
+    its share of a derivation and re-derives from the seeds, ungated, with
+    the oracle's verdicts."""
+    flags, recv, src, dst, sup = supervised_tree(n, fan=8 if n > CHUNK else 2)
+    n_chunks = -(-n // CHUNK)
+    w = np.ones(src.size, np.int64)
+    tracer = pd.DecrementalTracer(n, mode=mode)
+    assert tracer.closure_price is None  # no previous fixpoint, no closure
+    tracer.rebuild(src, dst, w, sup)
+    assert np.array_equal(tracer.marks(flags, recv), np.ones(n, bool))
+    rng = np.random.default_rng(30)
+    for wake in range(2):
+        price = tracer.closure_price
+        assert price == pt.closure_price(_walks(tracer.wake_stats(1)[0]))
+        cut = rng.choice(np.flatnonzero(w), 24, replace=False)
+        w[cut] = 0
+        tracer.apply_log([(False, int(src[i]), int(dst[i]), EDGE) for i in cut])
+        got = tracer.marks(flags, recv)
+        expected = trace_ops.trace_marks_np(flags, recv, sup, src, dst, w)
+        assert np.array_equal(got, expected) and not expected.all(), wake
+        s = tracer.wake_stats(1)[0]
+        assert s["closure_bailed"] == 1 and s["closure_spent"] >= price
+        # it left as soon as it could: without its last sweep it was under
+        assert s["closure_spent"] - n_chunks < price and s["closure_sweeps"] <= price
+        # the cold road: sweep 1 walks the root's chunk and forces no tile
+        assert s["gated_tiles"] == 0 and s["dirty_chunks"][0] == 1
+        assert max(s["dirty_chunks"]) == min(n_chunks, 2)  # a level spans two chunks
+
+
+@pytest.mark.parametrize("mode", [pt.MODE_AUTO, pt.MODE_PUSH])
+@pytest.mark.parametrize("event", ["release", "halt"])
+def test_closure_of_an_island_keeps_the_regional_repair(event, mode):
+    """Suspects among actors that no supervisor chain ties to the live
+    set: the closure is the island, finishes under its price, and the
+    repair forces the island's supertile and no other."""
+    n, n_live = 1024, 100
+    # the live set: a chain, every actor referenced from and supervised by
+    # the one before it (a derivation of a hundred walks under push)
+    flags = np.zeros(n, np.uint8)
+    flags[:n_live] = F.FLAG_IN_USE | F.FLAG_INTERNED
+    flags[0] |= F.FLAG_ROOT
+    recv = np.zeros(n, np.int64)
+    sup = np.full(n, -1, np.int32)
+    sup[1:n_live] = np.arange(n_live - 1)
+    # the island, in another supertile: a busy actor outside every
+    # supervisor chain and two references hanging off it in a row
+    isle = np.arange(900, 903, dtype=np.int32)
+    flags[isle] = F.FLAG_IN_USE | F.FLAG_INTERNED
+    flags[isle[0]] |= F.FLAG_BUSY
+    src = np.concatenate([np.arange(n_live - 1, dtype=np.int32), isle[:-1]])
+    dst = np.concatenate([np.arange(1, n_live, dtype=np.int32), isle[1:]])
+    w = np.ones(src.size, np.int64)
+    tracer = pd.DecrementalTracer(n, mode=mode, s_rows=1)
+    tracer.rebuild(src, dst, w, sup)
+    assert tracer.marks(flags, recv).sum() == n_live + 3
+    price = tracer.closure_price
+    assert price >= pt.CLOSURE_MIN_WALKS
+    if event == "release":  # the island's first reference
+        w[-2] = 0
+        tracer.apply_log([(False, int(isle[0]), int(isle[1]), EDGE)])
+        dead = isle[1:]
+    else:  # its second actor halts: marked still, but passes nothing on
+        flags = flags.copy()
+        flags[isle[1]] |= F.FLAG_HALTED
+        dead = isle[2:]
+    got = tracer.marks(flags, recv)
+    assert np.array_equal(got, trace_ops.trace_marks_np(flags, recv, sup, src, dst, w))
+    assert not got[dead].any() and got.sum() == n_live + 3 - dead.size
+    s = tracer.wake_stats(1)[0]
+    # the suspect, the one after it, and the sweep that finds no more
+    assert (s["closure_sweeps"], s["closure_spent"], s["closure_bailed"]) == (2, 2, 0)
+    assert s["gated_tiles"] == 1  # of 8
+    assert tracer.closure_price == price  # no derivation from nothing, no new price
+
+
+@pytest.mark.parametrize("how", ["first", "invalidate", "rebuild"])
+def test_a_derivation_from_nothing_is_not_gated(how):
+    """With no previous fixpoint the first repair sweep walks the chunks
+    that hold seeds and forces no supertile; the warm wake after it gates
+    the one tile of a newly-in-use actor, as before."""
+    n = CHUNK + 4000  # two walk chunks, the root in the first
+    flags, recv, src, dst, sup = supervised_tree(n)
+    flags[n - 1] = 0  # a slot not yet in use
+    w = np.ones(src.size, np.int64)
+    tracer = pd.DecrementalTracer(n)
+    tracer.rebuild(src, dst, w, sup)
+    if how != "first":
+        tracer.marks(flags, recv)
+        if how == "invalidate":
+            tracer.invalidate()
+        else:
+            tracer.rebuild(src, dst, w, sup)
+        assert tracer.closure_price is None
+    got = tracer.marks(flags, recv)
+    assert got[: n - 1].all() and not got[n - 1]
+    s = tracer.wake_stats(1)[0]
+    assert (s["closure_sweeps"], s["closure_bailed"], s["gated_tiles"]) == (0, 0, 0)
+    assert s["dirty_chunks"][0] == 1 and max(s["dirty_chunks"]) == 2
+    assert tracer.closure_price == pt.closure_price(_walks(s))
+    # the warm road is what it was: no suspects, one tile forced
+    flags = flags.copy()
+    flags[n - 1] = F.FLAG_IN_USE | F.FLAG_INTERNED
+    assert tracer.marks(flags, recv).all()
+    s = tracer.wake_stats(1)[0]
+    assert (s["closure_sweeps"], s["closure_bailed"], s["gated_tiles"]) == (0, 0, 1)
+
+
+@pytest.mark.parametrize("walks", [0, 1, 8, 9, 192, 1000])
+def test_closure_policy_on_ints(walks):
+    """``pt.closure_gives_up`` on Python ints: never under the price,
+    always at it, the price a share of the carried derivation walks."""
+    price = pt.closure_price(walks)
+    assert price == max(pt.CLOSURE_MIN_WALKS, -(-walks // round(1 / pt.CLOSURE_SHARE)))
+    assert not any(pt.closure_gives_up(spent, walks) for spent in range(price))
+    assert all(pt.closure_gives_up(spent, walks) for spent in range(price, price + 40))
+    # at the 10M geometry: 192 walks a derivation, 20 a closure sweep
+    if walks == 192:
+        assert price == 24 and not pt.closure_gives_up(20, walks) and pt.closure_gives_up(40, walks)

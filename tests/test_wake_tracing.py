@@ -6,9 +6,11 @@ sweep counters, and the collector's phases as trace annotations.
 - the counters of the ONE wake program equal, per mode, what the
   ``with_stats`` variant of the commit before gave on a small chain and
   a small power-law graph (the numbers below were produced by that
-  commit, with ``collect_stats``), ``closure_sweeps`` equals a numpy
-  closure loop over the same deletions, and the verdicts equal the
-  oracle's;
+  commit, with ``collect_stats``) wherever the wake still takes the
+  regional repair, and a derivation from nothing where its closure gave
+  up; ``closure_sweeps``, ``closure_spent`` and ``closure_bailed`` equal
+  a numpy closure loop over the same deletions under the program's own
+  policy (``pt.closure_gives_up``), and the verdicts equal the oracle's;
 - ``WakeProfiler`` records hold the new phases, exclusive and adding up
   to ``wall_s``; an ``annotate`` hook sees ``uigc:wake`` enclose every
   phase on the collector's thread, and nothing without a profiler.
@@ -38,7 +40,9 @@ def chain(n=256):
     flags = np.full(n, F.FLAG_IN_USE | F.FLAG_INTERNED, np.uint8)
     flags[0] |= F.FLAG_ROOT
     src = np.arange(n - 1, dtype=np.int32)
-    return flags, src, src + 1, [[(150, 151)], [(50, 51), (220, 221)]]
+    # the third cut's closure (46..50) is small beside the derivation of
+    # the 51 that are left: under push and pull it stays under its price
+    return flags, src, src + 1, [[(150, 151)], [(50, 51), (220, 221)], [(45, 46)]]
 
 
 def powerlaw(n=2048):
@@ -72,36 +76,48 @@ GRAPHS = {"chain": chain, "powerlaw": powerlaw}
 #: and 1 on this power-law graph (64 blocks for 8k pairs: a sweep
 #: streams 65k slots, as much as the 10k gathers of a jump sweep cost).
 PARENT_SWEEPS = {
-    ("chain", "auto"): [11, 1, 1], ("chain", "jump"): [6, 1, 1],
-    ("chain", "push"): [256, 1, 1], ("chain", "pull"): [256, 1, 1],
+    ("chain", "auto"): [11, 1, 1, 1], ("chain", "jump"): [6, 1, 1, 1],
+    ("chain", "push"): [256, 1, 1, 1], ("chain", "pull"): [256, 1, 1, 1],
+    ("powerlaw", "auto"): [5, 5, 5], ("powerlaw", "jump"): [4, 4, 5],
+    ("powerlaw", "push"): [5, 5, 5], ("powerlaw", "pull"): [5, 5, 5],
+}
+#: the repair sweeps of a wake whose closure gave up (PR 30): those of a
+#: derivation from nothing of the graph as that wake finds it
+COLD_SWEEPS = {
+    ("chain", "auto"): [11, 11, 10, 10], ("chain", "jump"): [6, 6, 5, 5],
+    ("chain", "push"): [256, 151, 51, 46], ("chain", "pull"): [256, 151, 51, 46],
     ("powerlaw", "auto"): [5, 5, 5], ("powerlaw", "jump"): [4, 4, 5],
     ("powerlaw", "push"): [5, 5, 5], ("powerlaw", "pull"): [5, 5, 5],
 }
 #: the repair sweeps of those wakes that ran the pointer jump under
-#: ``auto`` (``jump``: all of them; ``push`` and ``pull``: none)
-AUTO_JUMP_SWEEPS = {"chain": [6, 0, 0], "powerlaw": [4, 4, 4]}
-PARENT_MARKED = {"chain": [256, 151, 51], "powerlaw": [2005, 2003, 2003]}
+#: ``auto`` (``jump``: all of them; ``push`` and ``pull``: none), on the
+#: regional road and on the cold one
+AUTO_JUMP_SWEEPS = {"chain": [6, 0, 0, 0], "powerlaw": [4, 4, 4]}
+AUTO_JUMP_SWEEPS_COLD = {"chain": [6, 6, 5, 5], "powerlaw": [4, 4, 4]}
+PARENT_MARKED = {"chain": [256, 151, 51, 46], "powerlaw": [2005, 2003, 2003]}
 #: tiles_skipped of wake 0's last kept sweep: the chain saturates its one
 #: tile, which only the pull gates count
 PARENT_LAST_SKIP = {("chain", "auto"): 1, ("chain", "pull"): 1}
 
 
-def closure_sweeps_np(src, dst, alive, deleted_dst, prev_mark):
+def closure_sweeps_np(src, dst, alive, deleted_dst, prev_mark, derivation_walks):
     """The closure loop in numpy: suspects are the previously marked
     destinations of deleted pairs; every sweep adds the previously marked
-    successors of the closure; the loop runs while a sweep changed it."""
+    successors of the closure; the loop runs while a sweep changed it and
+    the policy has not given up (one walk chunk at these sizes: a sweep
+    costs one walk).  Returns (sweeps, gave up)."""
     closure = np.zeros_like(prev_mark)
     closure[deleted_dst] = True
     closure &= prev_mark
     sweeps, changed = 0, bool(closure.any())
-    while changed:
+    while changed and not pt.closure_gives_up(sweeps, derivation_walks):
         hits = np.zeros_like(closure)
         hits[dst[alive & closure[src]]] = True
         new = closure | (hits & prev_mark)
         changed = bool((new != closure).any())
         closure = new
         sweeps += 1
-    return sweeps
+    return sweeps, changed
 
 
 @pytest.mark.parametrize("mode", pt.TRACE_MODES)
@@ -114,23 +130,42 @@ def test_wake_counters_match_the_parents_stats_variant(graph, mode):
     alive = np.ones(src.size, bool)
     tracer = pd.DecrementalTracer(n, mode=mode)
     tracer.rebuild(src, dst, np.ones(src.size, np.int64), sup)
-    prev, marked, closure_want = None, [], [0]
+    prev, marked, closures = None, [], []
     for batch in [None] + cuts:
         if batch is not None:
             tracer.apply_log([(False, s, d, EDGE) for s, d in batch])
             gone = {(s, d) for s, d in batch}
             alive &= np.array([(s, d) not in gone for s, d in zip(src.tolist(), dst.tolist())])
-            closure_want.append(
-                closure_sweeps_np(src, dst, alive, [d for _, d in batch], prev)
-            )
+            closures.append((alive.copy(), [d for _, d in batch], prev))
         prev = tracer.marks(flags, recv)
         oracle = F.trace_marks_np(flags, recv, sup, src, dst, alive.astype(np.int64))
         assert np.array_equal(prev, oracle)
         marked.append(int(prev.sum()))
     stats = tracer.wake_stats()
-    assert [w["n_sweeps"] for w in stats] == PARENT_SWEEPS[graph, mode]
     assert marked == PARENT_MARKED[graph]
-    assert [w["closure_sweeps"] for w in stats] == closure_want
+    # wake 0 is a derivation from nothing; every later wake closes over
+    # its suspects until the closure is done or has cost its share of the
+    # last such derivation, and then repairs the region or re-derives
+    want = {pt.MODE_JUMP: None, pt.MODE_AUTO: AUTO_JUMP_SWEEPS[graph]}.get(mode, [0] * len(stats))
+    want_cold = {pt.MODE_JUMP: None, pt.MODE_AUTO: AUTO_JUMP_SWEEPS_COLD[graph]}.get(mode, want)
+    walks, bails = None, []
+    for i, w in enumerate(stats):
+        sweeps, bailed = closure_sweeps_np(src, dst, *closures[i - 1], walks) if i else (0, False)
+        cold = bailed or i == 0
+        bails.append(int(bailed))
+        assert (w["closure_sweeps"], w["closure_spent"], w["closure_bailed"]) == (sweeps, sweeps, bailed), i
+        assert w["n_sweeps"] == (COLD_SWEEPS if cold else PARENT_SWEEPS)[graph, mode][i], i
+        assert w["gated_tiles"] == (0 if cold else 1), i
+        jumps = want_cold if cold else want
+        assert w["jump_sweeps"] == (w["n_sweeps"] if jumps is None else jumps[i]), i
+        if cold:
+            walks = w["n_sweeps"]
+    assert tracer.closure_price == pt.closure_price(walks)
+    # the power-law graph's closures swallow its marks; the chain's last
+    # one is five actors long and stays under a push derivation's price
+    assert bails == {"powerlaw": [0, 1, 1]}.get(
+        graph, [0, 1, 1, int(mode in (pt.MODE_JUMP, pt.MODE_AUTO))]
+    )
     use_pull = mode in (pt.MODE_PULL, pt.MODE_AUTO)
     for w in stats:
         k = min(w["n_sweeps"], pt.MAX_SWEEP_STATS)
@@ -143,9 +178,6 @@ def test_wake_counters_match_the_parents_stats_variant(graph, mode):
         assert len(w["jump_on"]) == k
         assert w["jump_sweeps"] == sum(w["jump_on"])
         assert w["jump_on"] == sorted(w["jump_on"])
-    want = {pt.MODE_JUMP: [w["n_sweeps"] for w in stats],
-            pt.MODE_AUTO: AUTO_JUMP_SWEEPS[graph]}.get(mode, [0, 0, 0])
-    assert [w["jump_sweeps"] for w in stats] == want
     assert stats[0]["tiles_skipped"][-1] == PARENT_LAST_SKIP.get((graph, mode), 0)
     assert all(not any(w["tiles_skipped"]) for w in stats[1:])
     assert tracer.wake_stats(1) == stats[-1:]
@@ -161,7 +193,8 @@ def test_scopes_in_the_lowered_wake_program(mode):
     fn, del_w, fresh_w, args = tracer.stage_wake()
     text = fn.lower(
         jax.device_put(flags), jax.device_put(np.zeros(64, np.int32)), del_w, fresh_w,
-        tracer._mark_w, tracer._seed_w, tracer._halted_w, tracer._iu_w, tracer._table, *args,
+        tracer._mark_w, tracer._seed_w, tracer._halted_w, tracer._iu_w, tracer._table,
+        tracer._walks, *args,
     ).as_text(debug_info=True)
     for phase in pd.WAKE_PHASES:
         assert f"{pd.WAKE_SCOPE}/{phase}" in text, phase

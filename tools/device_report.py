@@ -308,7 +308,8 @@ def render_device_doc(
             )
         )
         lines.append(
-            f"    closure sweeps {r.get('closure_sweeps', '-')}, repair sweeps "
+            f"    closure sweeps {r.get('closure_sweeps', '-')}"
+            f"{' (gave up)' if r.get('closure_bailed') else ''}, repair sweeps "
             f"{int(r['n_sweeps'])}, dirty chunks per sweep "
             f"{r.get('sweep_dirty_chunks') or []}, pull on "
             f"{r.get('sweep_pull_on') or []}, jump sweeps "

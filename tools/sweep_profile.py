@@ -72,7 +72,8 @@ def timed(fn, *args, reps=5):
     return statistics.median(ts) * 1e3
 
 
-def simulate_sweeps(graph, n, modes, jump_steps=None, geometry=None):
+def simulate_sweeps(graph, n, modes, jump_steps=None, geometry=None,
+                    suspects=None):
     """Hardware-independent fixpoint sweep counts per trace mode, by
     direct numpy simulation of the kernel's per-sweep semantics
     (pallas_trace trace_fn: table = mark & ~halted, hits gated by
@@ -84,8 +85,17 @@ def simulate_sweeps(graph, n, modes, jump_steps=None, geometry=None):
     chunks of ``geometry`` = (n_slots, n_chunks, chunk_nodes): the slots
     and chunks of the layout as the decremental backend packs it.
 
-    Returns {mode: {"sweeps", "jump_sweeps", "dirty_chunks"}}, and under
-    "auto" also the policy's "price"."""
+    With ``suspects`` (actor ids: the targets of released references,
+    say) the decremental wake's closure over the derived marks is
+    simulated too, with the program's own policy
+    (``pt.closure_gives_up``, priced from each mode's derivation walks):
+    the closure is push-only whatever the mode.
+
+    Returns {mode: {"sweeps", "jump_sweeps", "dirty_chunks"}}, under
+    "auto" also the policy's "price", and with ``suspects`` under every
+    mode "closure": {"price", "sweeps", "spent", "bailed"} as the wake
+    would run it, and "full_sweeps", "sizes" (closure members after each
+    sweep) and "marks" as it would end unpriced."""
     from uigc_tpu.ops import pallas_trace as pt
     from uigc_tpu.ops import trace as trace_ops
 
@@ -135,9 +145,7 @@ def simulate_sweeps(graph, n, modes, jump_steps=None, geometry=None):
         table, table_prev = mark & ~halted, np.zeros(n, bool)
         dirty, jump_sweeps = [], 0
         while True:
-            n_dirty = int(
-                np.add.reduceat(table != table_prev, bounds).astype(bool).sum()
-            )
+            n_dirty = _dirty_chunks(table, table_prev, bounds)
             dirty.append(n_dirty)
             if decide is not None:
                 engaged, spent = decide(engaged, spent, n_dirty)
@@ -161,7 +169,48 @@ def simulate_sweeps(graph, n, modes, jump_steps=None, geometry=None):
                      "dirty_chunks": dirty}
         if decide is not None:
             out[mode]["price"] = decide.price
+    if suspects is not None:
+        # the fixpoint's marks are the same in every mode
+        c_dirty, sizes = _simulate_closure(psrc, pdst, mark, suspects, bounds)
+        for res in out.values():
+            walks = sum(res["dirty_chunks"])
+            sweeps = spent = 0
+            while (sweeps < len(c_dirty)
+                   and not pt.closure_gives_up(spent, walks)):
+                spent += c_dirty[sweeps]
+                sweeps += 1
+            res["closure"] = {
+                "price": pt.closure_price(walks), "sweeps": sweeps,
+                "spent": spent, "bailed": sweeps < len(c_dirty),
+                "full_sweeps": len(c_dirty), "sizes": sizes,
+                "marks": int(mark.sum()),
+            }
     return out
+
+
+def _dirty_chunks(table, table_prev, bounds) -> int:
+    """Walk chunks (starting at ``bounds``) in which the two differ."""
+    return int(np.add.reduceat(table != table_prev, bounds).astype(bool).sum())
+
+
+def _simulate_closure(psrc, pdst, mark, suspects, bounds):
+    """The wake's closure loop run to its end: the previously marked
+    suspects, then per sweep the marked successors of the closure, while
+    a sweep changed it.  Returns (dirty walk chunks, closure members)
+    per sweep; the last sweep is the one that finds nothing new."""
+    closure = np.zeros_like(mark)
+    closure[np.asarray(suspects, np.int64)] = True
+    closure &= mark
+    prev = np.zeros_like(mark)
+    dirty, sizes = [], []
+    while not np.array_equal(closure, prev):
+        dirty.append(_dirty_chunks(closure, prev, bounds))
+        new = closure.copy()
+        hit_dst = pdst[closure[psrc]]
+        new[hit_dst] |= mark[hit_dst]
+        prev, closure = closure, new
+        sizes.append(int(closure.sum()))
+    return dirty, sizes
 
 
 def layout_geometry(psrc, pdst, n):
@@ -193,6 +242,11 @@ def main():
         help="numpy sweep-count simulation per mode (no device work)",
     )
     ap.add_argument(
+        "--suspects", type=int, default=0,
+        help="with --simulate: also close over this many suspects (targets "
+        "of live references, drawn with seed 0) under the wake's policy",
+    )
+    ap.add_argument(
         "--modes", default="push,pull,jump,auto",
         help="comma-separated trace modes for the fixpoint decomposition",
     )
@@ -218,7 +272,14 @@ def main():
         # Sweep counts are hardware-independent: pure numpy, no device.
         graph = powerlaw_actor_graph(n, seed=seed, garbage_fraction=frac)
         modes = [m.strip() for m in args.modes.split(",") if m.strip()]
-        sim = simulate_sweeps(graph, n, modes)
+        suspects = None
+        if args.suspects:
+            live = np.flatnonzero(graph["edge_weight"] > 0)
+            pick = np.random.default_rng(0).choice(
+                live, args.suspects, replace=False
+            )
+            suspects = graph["edge_dst"][pick]
+        sim = simulate_sweeps(graph, n, modes, suspects=suspects)
         print(
             json.dumps(
                 {
@@ -233,6 +294,8 @@ def main():
                     "jump_sweeps": {m: sim[m]["jump_sweeps"] for m in modes},
                     "dirty_chunks": {m: sim[m]["dirty_chunks"] for m in modes},
                     "auto_jump_price": sim.get(pt.MODE_AUTO, {}).get("price"),
+                    **({"closure": {m: sim[m]["closure"] for m in modes}}
+                       if suspects is not None else {}),
                 }
             )
         )

@@ -247,7 +247,8 @@ def main():
 
     @jax.jit
     def chained(k_hi, row_pos, emeta):
-        state0 = (zeros_w,) * 5
+        # no previous fixpoint: five zero word tables and zero walks
+        state0 = (zeros_w,) * 5 + (jnp.zeros((), jnp.int32),)
 
         def body(k, carry):
             flags, recv, row_pos, emeta, jp, state = carry
@@ -340,7 +341,7 @@ def main():
         emeta_h = prep["emeta"].copy()
         jp_h = jp0.copy()
         z = np.zeros((r_rows, pt.LANE), np.int32)
-        state_r = tuple(jax.device_put(z) for _ in range(5))
+        state_r = tuple(jax.device_put(z) for _ in range(5)) + (np.int32(0),)
         sweep_counts = []
         for k in range(K):
             fs, ok = flag_slots[k], flag_slots[k] < n
@@ -363,8 +364,8 @@ def main():
                 prep["bmeta1"], prep["bmeta2"], row_pos_h, emeta_h,
                 xsrc[k], xdst[k],
             )
-            state_r = out[:5]
-            sweep_counts.append(int(out[5]["n_sweeps"]))
+            *state_r, stats_k = out
+            sweep_counts.append(int(stats_k["n_sweeps"]))
         result["sweep_counts"] = sweep_counts
         mean_sweeps = statistics.mean(sweep_counts)
         result["sweeps_mean"] = round(mean_sweeps, 2)

@@ -1001,6 +1001,7 @@ class ArrayShadowGraph:
         fields = {"n_sweeps": k, "jump_sweeps": int(stats["jump_sweeps"])}
         if "closure_sweeps" in stats:
             fields["closure_sweeps"] = int(stats["closure_sweeps"])
+            fields["closure_bailed"] = int(stats["closure_bailed"])
         k = min(k, len(stats["dirty_chunks"]))
         for key in ("dirty_chunks", "changed_supers", "tiles_skipped",
                     "pull_on", "jump_on"):
@@ -1063,11 +1064,13 @@ class ArrayShadowGraph:
         return obj
 
     def _compute_marks_decremental(self, event: dict) -> np.ndarray:
-        """Per-wake detection through the decremental tracer: the wake
-        cost is proportional to the churn's affected region, not the
-        graph (ops/pallas_decremental.py; the steady-state analogue of
-        the reference's 50ms incremental collect, LocalGC.scala:144-186,
-        at scales where a full re-trace cannot meet the cadence).
+        """Per-wake detection through the decremental tracer
+        (ops/pallas_decremental.py; the steady-state analogue of the
+        reference's 50ms incremental collect, LocalGC.scala:144-186):
+        the wake repairs the region the churn may have invalidated
+        where that region is small, an island that no supervisor chain
+        ties to the live set, and otherwise re-derives from the seeds
+        once finding the region has cost its share of a derivation.
 
         The device call in its four steps, each a profiler phase when a
         wake is attached: layout maintenance, upload, the wake program

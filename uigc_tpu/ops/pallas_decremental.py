@@ -20,8 +20,21 @@ Per wake, relative to the previous fixpoint:
    invalidate).
 2. **Closure**: the forward closure of ``S`` through the current layout,
    restricted to previously-marked nodes — every mark that transitively
-   depended on a suspect.  A monotone fixpoint, so the source-side
-   dirty-group machinery bounds its cost by the region size.
+   depended on a suspect.  A monotone fixpoint over the dirty walk
+   chunks, so what it costs is the chunks its frontier touches, sweep
+   after sweep — and under CRGC that is seldom a region.  Marks flow
+   along references AND from a child to its supervisor, so every live
+   actor reaches a root by its supervisor chain, a root reaches every
+   live actor, the marked set is one strongly connected component, and
+   the closure of ANY marked suspect among live actors is every mark (at
+   10M actors one suspect closes over all 5M marks in 16 sweeps, 20,000
+   in 10: PERF.md section 6, PR 30).  Only suspects on an island that
+   no supervisor chain ties to the live set (halted, unrooted or freed
+   actors) have a closure smaller than the marks.  So the loop counts
+   the chunk walks it spends and **gives up** once they reach a price,
+   a fixed share of what the last derivation from nothing cost on this
+   graph (``pt.closure_gives_up``; the derivation's walks ride with the
+   previous state as a device scalar).
 3. **Repair**: clear the closure's marks, reseed from the current seed
    vector, and run the propagation fixpoint where the FIRST sweep forces
    blocks whose output supertile intersects the closure to walk their
@@ -29,6 +42,11 @@ Per wake, relative to the previous fixpoint:
    supertiles must re-derive contributions from ALL in-edges, including
    sources whose table groups never changed.  Later sweeps are monotone
    growth and fall back to the ordinary dirty-group walk.
+   **The cold road**: when the closure gave up, or there is no previous
+   fixpoint (first wake, ``invalidate()``, ``rebuild()``), the region is
+   everything: the marks restart from the seeds, the dirty lists are
+   taken against a zero table and no supertile is forced, so the first
+   sweep walks the chunks that hold seeds and nothing else.
 
 Soundness: a previously-marked node outside the closure retains a support
 path untouched by any deletion, de-seeding, or halt (otherwise some node
@@ -36,8 +54,12 @@ on the path would have entered ``S`` and pushed the rest into the
 closure), so its mark stays valid; closure members are re-derived from
 scratch against that stable boundary.  Additions (new pairs, new seeds)
 ride the same repair fixpoint through the ordinary monotone machinery.
-A cold start degenerates gracefully: with zero previous state the suspect
-set is empty and the repair fixpoint IS the full trace from seeds.
+The cold road needs no such argument: from the seeds against a zero
+table every marked source is a changed word, so the plain dirty walk IS
+the full trace (``pallas_trace._build_trace_fn_multi`` runs exactly
+that), exact by construction.  A wake that gives up costs at most
+(1 + ``pt.CLOSURE_SHARE``) derivations and the sweep that crossed the
+price.
 
 Differential coverage: tests/test_pallas_decremental.py drives random
 mutation/flag-change schedules and compares every wake against the numpy
@@ -93,8 +115,10 @@ def _build_wake_fn(
 ):
     """The jitted wake: (flags, recv, del_words, fresh_words, prev
     state, [jump parents,] *layout args) -> (mark_w, seed_w, halted_w,
-    iu_w, table, stats) with all word tables (r_rows, LANE) int32 device
-    arrays and ``stats`` the wake's sweep counters (below).
+    iu_w, table, walks, stats): the previous state and the next are five
+    word tables, (r_rows, LANE) int32 device arrays, and an int32 scalar,
+    the chunk walks of the last derivation from nothing; ``stats`` is
+    the wake's sweep counters (below).
 
     ``mode`` applies to the REPAIR fixpoint only (pallas_trace MODE_*
     docs): on a cold start the repair IS the full derivation, which is
@@ -106,14 +130,23 @@ def _build_wake_fn(
     over 10M actors costs nine push sweeps, so a shallow repair never
     engages it and a deep one does after a bounded wait.  Until then the
     jump-parent operand passes through the loop untouched.  The closure
-    phase stays a plain push fixpoint: it is bounded by the churn's
-    region (usually shallow), and jump hits there would only
-    over-approximate the closure — sound but more re-derivation for
-    nothing.
+    phase stays a plain push fixpoint, and a priced one: among live
+    actors it would end as every mark (the module docstring), so it ends
+    itself once its chunk walks reach ``pt.closure_price(prev_walks)``
+    and the wake takes the cold road: the derivation from the seeds,
+    ungated, which is also the road of a wake with no previous table.
+    ``prev_walks``, the chunk walks of the last such derivation, comes in
+    with the previous state and goes out with the next (this wake's own
+    walks if it was cold).  Jump hits in the closure would only
+    over-approximate it — sound but more re-derivation for nothing.
 
     ``stats`` is counted by the program that runs, every wake (one
     program per geometry; a few scalar updates per sweep):
-    ``closure_sweeps``, ``n_sweeps`` (repair), ``jump_sweeps`` (the
+    ``closure_sweeps``, ``closure_spent`` (the closure's chunk walks at
+    exit, against :attr:`DecrementalTracer.closure_price`),
+    ``closure_bailed`` (1 if it gave up), ``gated_tiles`` (supertiles
+    the first repair sweep walks in full; 0 on the cold road),
+    ``n_sweeps`` (repair), ``jump_sweeps`` (the
     repair sweeps that ran the jump) and ``jump_spent`` (the policy's
     ``spent`` at exit, to be read against the static
     :attr:`DecrementalTracer.jump_price`) are int32 scalars, ``dirty_chunks``,
@@ -159,15 +192,15 @@ def _build_wake_fn(
 
     def wake_fn(flags, recv_count, del_w, fresh_w, prev_mark_w,
                 prev_seed_w, prev_halted_w, prev_iu_w, prev_table,
-                *rest):
+                prev_walks, *rest):
         with pt.scope(WAKE_SCOPE):
             return wake_body(flags, recv_count, del_w, fresh_w,
                              prev_mark_w, prev_seed_w, prev_halted_w,
-                             prev_iu_w, prev_table, *rest)
+                             prev_iu_w, prev_table, prev_walks, *rest)
 
     def wake_body(flags, recv_count, del_w, fresh_w, prev_mark_w,
                   prev_seed_w, prev_halted_w, prev_iu_w, prev_table,
-                  *rest):
+                  prev_walks, *rest):
         if use_jump:
             jump_j0, *layout_args = rest
         else:
@@ -219,25 +252,38 @@ def _build_wake_fn(
             ) & prev_mark_w
 
         # --- 2. closure: marks that depended on a suspect ----------- #
+        # The loop pays for itself in chunk walks and leaves, still
+        # ``changed``, once they reach the price (pt.closure_gives_up):
+        # under CRGC's supervisor edges the closure of a live suspect is
+        # every mark, and finding that out costs as much as acting on it.
         def c_cond(carry):
-            return carry[3]
+            _, _, _, changed, _, spent = carry
+            return changed & ~pt.closure_gives_up(spent, prev_walks)
 
         zero_gate = jnp.zeros((n_super,), jnp.int32)
+        zero_i = jnp.zeros((), jnp.int32)
 
         def c_body(carry):
-            closure_w, d, l, _, sweeps = carry
+            closure_w, d, l, _, sweeps, spent = carry
             hits2d = contribs(closure_w, d, l, zero_gate)
             hit_w = pt.pack_hits_table(hits2d, r_rows, jnp)
             new_closure = closure_w | (hit_w & prev_mark_w)
             d2, l2, changed = dirty_chunks(new_closure, closure_w)
-            return new_closure, d2, l2, changed, sweeps + 1
+            return (new_closure, d2, l2, changed, sweeps + 1,
+                    spent + d[n_chunks])
 
         with pt.scope("closure"):
-            d0, l0, changed0 = dirty_chunks(s_w, jnp.zeros_like(s_w))
-            closure_w, _, _, _, closure_sweeps = jax.lax.while_loop(
-                c_cond, c_body,
-                (s_w, d0, l0, changed0, jnp.zeros((), jnp.int32)),
+            zero_w = jnp.zeros_like(s_w)
+            d0, l0, changed0 = dirty_chunks(s_w, zero_w)
+            (closure_w, _, _, closure_bailed, closure_sweeps,
+             closure_spent) = jax.lax.while_loop(
+                c_cond, c_body, (s_w, d0, l0, changed0, zero_i, zero_i),
             )
+            # The cold road: the region to repair is everything, because
+            # the closure said so by its cost or because there is no
+            # previous fixpoint (first wake, invalidate(), rebuild()).
+            # Word-table selects on one scalar, not a lax.cond.
+            cold = closure_bailed | ~prev_table.any()
 
         # per-supertile gate: closure members must re-derive; fresh
         # insert destinations must see their new pairs' contributions at
@@ -257,11 +303,16 @@ def _build_wake_fn(
         # Newly-in-use nodes (slot reuse) are the additive mirror of the
         # fresh-insert case: reachable but with no word change anywhere,
         # so their supertile must re-derive once to pick the mark up.
+        # On the cold road nothing is gated: against a zero table every
+        # marked source is a changed word, so the dirty walk alone is the
+        # full trace and the first sweep walks the seeds' chunks only.
         with pt.scope("gate"):
-            suspect_g = (
+            suspect_g = jnp.where(
+                cold,
+                zero_gate,
                 per_super(closure_w)
                 | per_super(fresh_w)
-                | per_super(iu_w & ~prev_iu_w)
+                | per_super(iu_w & ~prev_iu_w),
             )
 
         # --- 3. repair fixpoint ------------------------------------- #
@@ -312,6 +363,7 @@ def _build_wake_fn(
             out = dict(carry, mark=new_mark_w, table=new_table, d=d2,
                        l=l2, use_gate=jnp.array(False), changed=changed,
                        sweep_i=carry["sweep_i"] + 1,
+                       walks=carry["walks"] + n_dirty,
                        st_dirty=carry["st_dirty"].at[i].set(n_dirty))
             if use_jump:
                 jump_on = jump_state[0].astype(jnp.int32)
@@ -330,9 +382,12 @@ def _build_wake_fn(
             return out
 
         with pt.scope("repair"):
-            mark_w0 = (prev_mark_w & ~closure_w) | seed_w
+            kept_w = jnp.where(cold, zero_w, prev_mark_w & ~closure_w)
+            mark_w0 = kept_w | seed_w
             table0 = mark_w0 & nh_w
-            rd0, rl0, rchanged0 = dirty_chunks(table0, prev_table)
+            rd0, rl0, rchanged0 = dirty_chunks(
+                table0, jnp.where(cold, zero_w, prev_table)
+            )
             trans_w = iu_w & nh_w  # jump-transparent intermediates
             # Run at least one gated sweep whenever anything is suspect,
             # even if the table diff alone is empty.
@@ -341,29 +396,35 @@ def _build_wake_fn(
             carry0 = {"mark": mark_w0, "table": table0, "d": rd0,
                       "l": rl0, "use_gate": jnp.array(True),
                       "changed": run0,
-                      "sweep_i": jnp.zeros((), jnp.int32),
+                      "sweep_i": zero_i, "walks": zero_i,
                       "st_dirty": zero_stats}
             if use_jump:
                 carry0.update(jump=jump_j0.astype(jnp.int32),
                               jump_state=pt.jump_state0(mode, jnp),
-                              jump_sweeps=jnp.zeros((), jnp.int32),
-                              st_jump=zero_stats)
+                              jump_sweeps=zero_i, st_jump=zero_stats)
             if use_pull:
                 carry0.update(st_skip=zero_stats, st_pull=zero_stats)
             out = jax.lax.while_loop(r_cond, r_body, carry0)
+        # what a derivation from nothing costs on this graph, for the
+        # next wakes' closure price: this wake's walks if it was one
+        walks = jnp.where(cold, out["walks"], prev_walks)
         stats = {
             "closure_sweeps": closure_sweeps,
+            "closure_bailed": closure_bailed.astype(jnp.int32),
+            "closure_spent": closure_spent,
+            # supertiles whose blocks the first repair sweep walks in full
+            "gated_tiles": suspect_g.sum(),
             "n_sweeps": out["sweep_i"],
             "dirty_chunks": out["st_dirty"],
             "tiles_skipped": out.get("st_skip", zero_stats),
             "pull_on": out.get("st_pull", zero_stats),
-            "jump_sweeps": out.get("jump_sweeps", jnp.zeros((), jnp.int32)),
+            "jump_sweeps": out.get("jump_sweeps", zero_i),
             "jump_on": out.get("st_jump", zero_stats),
             # the policy's chunk walks spent while sparse, at exit
-            "jump_spent": (out["jump_state"][1] if use_jump
-                           else jnp.zeros((), jnp.int32)),
+            "jump_spent": out["jump_state"][1] if use_jump else zero_i,
         }
-        return out["mark"], seed_w, halted_w, iu_w, out["table"], stats
+        return (out["mark"], seed_w, halted_w, iu_w, out["table"], walks,
+                stats)
 
     jitted = jax.jit(wake_fn)
     jitted.raw = wake_fn  # unjitted body, for callers composing it
@@ -419,7 +480,7 @@ class DecrementalTracer:
     halted/in-use bits, active table) and the deleted-destination set gathered
     from the mutation log, and runs the closure+repair wake.  The first
     wake (or any wake after the previous state was invalidated) runs the
-    full derivation through the same code path.
+    full derivation through the same program, on its cold road.
     """
 
     def __init__(self, n: int, interpret: Optional[bool] = None, **kwargs):
@@ -435,6 +496,9 @@ class DecrementalTracer:
         self._halted_w = None
         self._iu_w = None
         self._table = None
+        #: chunk walks of the last derivation from nothing (device
+        #: scalar): what the next wakes' closure price is a share of
+        self._walks = None
         self._pending_del_dst: Set[int] = set()
         self._pending_fresh_dst: Set[int] = set()
         self._unpack = None
@@ -448,6 +512,16 @@ class DecrementalTracer:
         staged; None before the first wake and in a mode without jump."""
         return getattr(self._wake_fn, "jump_price", None)
 
+    @property
+    def closure_price(self) -> Optional[int]:
+        """What the next wake's suspect closure may cost before the wake
+        gives it up, in chunk walks (``pt.closure_price`` of the last
+        derivation from nothing, read back from the device now); None
+        while there is no previous fixpoint: that wake has no closure."""
+        if self._walks is None:
+            return None
+        return pt.closure_price(int(self._walks))  # readback: one scalar, on request
+
     # -- building / mutation (layout pass-throughs that watch removals) --
 
     def rebuild(self, edge_src, edge_dst, edge_weight, supervisor) -> None:
@@ -456,10 +530,7 @@ class DecrementalTracer:
         remove()/apply_log(), so the next wake re-derives everything (the
         zero prev-state path)."""
         self.layout.rebuild(edge_src, edge_dst, edge_weight, supervisor)
-        self._mark_w = self._seed_w = self._halted_w = None
-        self._iu_w = self._table = None
-        self._pending_del_dst.clear()
-        self._pending_fresh_dst.clear()
+        self.invalidate()
 
     def insert(self, src: int, dst: int, kind: int) -> None:
         if dst < self.n:
@@ -534,9 +605,10 @@ class DecrementalTracer:
             z = jax.device_put(np.zeros((r_rows, pt.LANE), np.int32))
             self._mark_w = self._seed_w = self._halted_w = z
             self._iu_w = self._table = z
+            self._walks = jax.device_put(np.zeros((), np.int32))
             # every previous mark is gone: everything must re-derive,
-            # which the zero prev-state does for free (empty suspects,
-            # full seed-diff dirty set)
+            # which the zero prev-state does on the cold road (empty
+            # suspects, no gate, the seeds' chunks as the dirty set)
         del_w = self._id_words(self._pending_del_dst, r_rows)
         fresh_w = self._id_words(self._pending_fresh_dst, r_rows)
         self._wake_fn = fn
@@ -556,6 +628,7 @@ class DecrementalTracer:
             self._halted_w,
             self._iu_w,
             self._table,
+            self._walks,
             *args,
         )
         # State + suspects commit when dispatch succeeds.  Under async
@@ -564,7 +637,8 @@ class DecrementalTracer:
         # must invalidate() (the previous fixpoint is lost with the
         # device state anyway), which makes the next wake a full
         # re-derivation and the drained suspects irrelevant.
-        self._mark_w, self._seed_w, self._halted_w, self._iu_w, self._table = state
+        (self._mark_w, self._seed_w, self._halted_w, self._iu_w,
+         self._table, self._walks) = state
         self._stats.append(stats)
         self._pending_del_dst.clear()
         self._pending_fresh_dst.clear()
@@ -573,9 +647,12 @@ class DecrementalTracer:
     def wake_stats(self, last_n: Optional[int] = None) -> List[dict]:
         """The sweep counters of the last ``last_n`` wakes (all that are
         kept, at most STATS_KEPT, when None), oldest first, read back
-        from the device now: per wake ``closure_sweeps``, ``n_sweeps``
-        (repair), ``jump_sweeps`` (the repair sweeps that ran the pointer
-        jump), ``jump_spent`` (the ``auto`` policy's sparse chunk walks
+        from the device now: per wake ``closure_sweeps``,
+        ``closure_spent``, ``closure_bailed`` and ``gated_tiles`` (the
+        closure's chunk walks, whether it gave up at its price, and the
+        supertiles the first repair sweep was forced through),
+        ``n_sweeps`` (repair), ``jump_sweeps`` (the repair sweeps that
+        ran the pointer jump), ``jump_spent`` (the ``auto`` policy's sparse chunk walks
         at exit; against :attr:`jump_price`) and, for the repair's first
         ``pt.MAX_SWEEP_STATS`` sweeps, ``dirty_chunks``,
         ``tiles_skipped``, ``pull_on`` and ``jump_on``.
@@ -590,6 +667,9 @@ class DecrementalTracer:
             k = min(int(host["n_sweeps"]), pt.MAX_SWEEP_STATS)
             out.append({
                 "closure_sweeps": int(host["closure_sweeps"]),
+                "closure_bailed": int(host["closure_bailed"]),
+                "closure_spent": int(host["closure_spent"]),
+                "gated_tiles": int(host["gated_tiles"]),
                 "n_sweeps": int(host["n_sweeps"]),
                 "dirty_chunks": host["dirty_chunks"][:k].tolist(),
                 "tiles_skipped": host["tiles_skipped"][:k].tolist(),
@@ -605,7 +685,7 @@ class DecrementalTracer:
         poisoned wake, or any external doubt about it): the next wake
         re-derives everything from the current seeds."""
         self._mark_w = self._seed_w = self._halted_w = None
-        self._iu_w = self._table = None
+        self._iu_w = self._table = self._walks = None
         self._pending_del_dst.clear()
         self._pending_fresh_dst.clear()
 

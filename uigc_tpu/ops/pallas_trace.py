@@ -57,6 +57,7 @@ only positive-weight edges propagate.
 
 from __future__ import annotations
 
+import math
 from typing import Dict
 
 import numpy as np
@@ -151,6 +152,20 @@ JUMP_STEPS = 2
 #: 8.62.  A measured property of the hardware, not a setting: AUTO's
 #: price of a jump sweep (``auto_jump_policy``) is built from it.
 JUMP_GATHER_COST = 8.6
+#: the share of a derivation from nothing (in chunk walks) that a wake's
+#: suspect closure may cost before the wake gives it up and takes the
+#: cold road (``closure_gives_up``).  Settled by measurement on the v5e,
+#: as JUMP_GATHER_COST is: PERF.md section 6, PR 30.
+CLOSURE_SHARE = 1 / 8
+#: and the least that price can be, in chunk walks.  At a geometry of one
+#: or two chunks a derivation is a handful of walks and its share rounds
+#: to one: no closure at all, where the served cell's closures (sessions:
+#: islands that no supervisor chain ties to the resident tree) finish in
+#: one to three one-chunk sweeps of 1.7 ms and a derivation costs 36 ms
+#: (its jump sweeps, which a count of walks does not see; PERF.md section
+#: 6, PR 30).  Four walks are under the share wherever a derivation is
+#: over 32, so the 10M geometry never sees the floor.
+CLOSURE_MIN_WALKS = 4
 #: per-sweep stat ring length for with_stats builds (sweeps beyond this
 #: fold into the last slot; fixpoints run ~4-12 sweeps)
 MAX_SWEEP_STATS = 32
@@ -451,6 +466,42 @@ def auto_jump_policy(n: int, n_slots: int, n_chunks: int, pull_cut: int,
 
     decide.price = price
     return decide
+
+
+def closure_price(derivation_walks: int) -> int:
+    """What a wake's suspect closure may cost before the wake gives it
+    up, in chunk walks: CLOSURE_SHARE of the ``derivation_walks`` that the
+    last derivation from nothing cost on this graph, and at least
+    CLOSURE_MIN_WALKS."""
+    return max(CLOSURE_MIN_WALKS, math.ceil(CLOSURE_SHARE * derivation_walks))
+
+
+def closure_gives_up(spent, derivation_walks):
+    """When the decremental wake stops closing over its suspects and
+    re-derives everything from the seeds instead: the one statement of
+    it, shared by the wake program (ops/pallas_decremental.py, where
+    both arguments are traced scalars in the carry) and by
+    ``tools/sweep_profile.py --simulate`` (Python ints).
+
+    Asked before each closure sweep that still has something to do.
+    ``spent`` is the chunk walks of the closure sweeps so far,
+    ``derivation_walks`` those of the last derivation from nothing on
+    this graph (the same unit, counted by the same loop).  True once
+    ``spent`` has reached ``closure_price(derivation_walks)``.
+
+    Why a price and not a size: under CRGC a child marks its supervisor,
+    so the live set is one strongly connected component and the closure
+    of any marked suspect is every mark; the regional repair that
+    follows IS the derivation from nothing, having first paid the
+    closure to find that out (10 sweeps of 22 at 10M, PERF.md section 6,
+    PR 30).  A closure confined to a halted or unrooted island finishes
+    under the price and keeps its regional repair.  This is the
+    ski-rental rule again: a wake that gives up costs at most
+    (1 + CLOSURE_SHARE) derivations and the sweep that crossed the
+    price."""
+    return (spent >= CLOSURE_MIN_WALKS) & (
+        spent >= CLOSURE_SHARE * derivation_walks
+    )
 
 
 def jump_step(mode, decide, state, n_dirty, run, mark_w, table, jump_j):
