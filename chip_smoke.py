@@ -6,20 +6,21 @@ falls back.  Default run (one chip):
 
 1. device     — JAX must report a TPU.
 2. data plane — BASELINE config 5 at full width: a 10M-actor power-law
-   graph held on the device by the objects the engine holds
-   (``IncrementalPallasLayout`` = the ``device`` backend's full retrace,
-   ``DecrementalTracer`` = the ``decremental`` backend), default
-   trace-mode, a cold wake plus churn wakes folded through ``apply_log``;
-   first- and last-wake verdicts equal the numpy oracle.
+   graph held on the device by the object the ``decremental`` backend
+   holds (``DecrementalTracer`` over its ``IncrementalPallasLayout``),
+   default trace-mode: the first wake, and a second from
+   ``invalidate()``, both derivations from nothing with verdicts equal
+   to the numpy oracle.  Wakes under churn are the benchmark's
+   (``benchmark/drivers/tracer_wake.py``).
 3. served path — ``ActorSystem`` -> CRGC engine -> Bookkeeper -> device
    backend through ``models/workloads.py`` with uigcsan attached: the
    10k-actor tree, 100 rings x 100 and a 100k-actor tree on
-   ``decremental`` and on ``device``.
+   ``decremental``.
 
 ``--chips 4`` runs ONLY the mesh phase and what it is compared with: the
 sharded trace and sharded decremental wake over a 4-device mesh on the
 same 10M graph (verdicts equal to the oracle and to the one-device
-trace), then the served path on ``mesh`` and ``mesh-decremental``.
+derivation), then the served path on ``mesh`` and ``mesh-decremental``.
 
 ``--rehearse`` is the CPU rehearsal (interpret-mode kernels, tiny sizes,
 no platform check); it refuses to run on anything but the CPU platform
@@ -66,17 +67,11 @@ def parse_args():
         "--actors", type=int, default=10_000_000,
         help="data-plane graph size (default: BASELINE config 5's 10M)",
     )
-    ap.add_argument("--churn", type=int, default=20_000,
-                    help="pair transitions per churn wake")
-    ap.add_argument("--wakes", type=int, default=3, help="churn wakes (>= 3)")
     ap.add_argument(
         "--rehearse", action="store_true",
         help="CPU rehearsal: tiny sizes, interpreted kernels, no chip",
     )
-    args = ap.parse_args()
-    if args.wakes < 3:
-        ap.error("--wakes must be >= 3")
-    return args
+    return ap.parse_args()
 
 
 # --------------------------------------------------------------------- #
@@ -115,15 +110,6 @@ def phase_device(args):
     return info
 
 
-def expected_impl(args, backend: str) -> str:
-    """What ``trace_impl`` must have resolved to: the compiled kernel on
-    the chip; off it (rehearsal) the interpreted kernel, except the
-    ``device`` backend's full retrace, which takes the XLA trace."""
-    if not args.rehearse:
-        return "pallas"
-    return "xla" if backend == "device" else "pallas-interpret"
-
-
 def peak_bytes():
     import jax
 
@@ -145,7 +131,7 @@ def make_graph(args, n):
 
     with Clock() as c:
         graph = powerlaw_actor_graph(n, seed=args.seed, garbage_fraction=0.5)
-        psrc, pdst, kinds = IncrementalPallasLayout.pairs_from_graph(
+        psrc, pdst, _ = IncrementalPallasLayout.pairs_from_graph(
             graph["edge_src"], graph["edge_dst"], graph["edge_weight"],
             graph["supervisor"],
         )
@@ -164,7 +150,7 @@ def make_graph(args, n):
         "numpy oracle disagrees with the generator's garbage partition",
     )
     say(f"oracle (numpy) on the initial graph: {c.s:.1f}s")
-    return graph, (psrc, pdst, kinds), oracle
+    return graph, (psrc, pdst), oracle
 
 
 def phase_data_plane(args, n):
@@ -174,14 +160,12 @@ def phase_data_plane(args, n):
     from uigc_tpu.ops import pallas_trace as pt
     from uigc_tpu.ops import trace as trace_ops
     from uigc_tpu.ops.pallas_decremental import DecrementalTracer
-    from uigc_tpu.ops.slotmap import pack_keys
 
-    graph, (psrc, pdst, kinds), oracle0 = make_graph(args, n)
+    graph, _, oracle = make_graph(args, n)
     flags, recv = graph["flags"], graph["recv_count"]
 
-    # -- pack: one layout serves both backends' objects ---------------- #
     tracer = DecrementalTracer(n)  # trace-mode default (auto), as the engine
-    layout = tracer.layout  # IncrementalPallasLayout: the `device` backend
+    layout = tracer.layout
     check(layout.mode == pt.MODE_AUTO, f"default trace-mode is {layout.mode}")
     check(
         pt.default_interpret() == args.rehearse,
@@ -204,108 +188,44 @@ def phase_data_plane(args, n):
         f"{layout.jump_parent.nbytes})"
     )
 
-    # -- full retrace, cold (the `device` backend's call) --------------- #
-    with Clock() as c:
-        marks_full0 = layout.trace(flags, recv)
-    say(f"full retrace, first call (compile + upload + run): {c.s:.1f}s")
-    check(np.array_equal(marks_full0, oracle0), "full retrace != oracle (first wake)")
-    with Clock() as c:
-        marks_again = layout.trace(flags, recv)
-    say(f"full retrace, second call (upload + run): {c.s:.2f}s")
-    check(np.array_equal(marks_again, oracle0), "full retrace not repeatable")
-
-    # -- what block_until_ready does here (device-resident operands) --- #
+    # -- first wake: the derivation from nothing ------------------------ #
     flags_dev, recv_dev = jax.device_put(flags), jax.device_put(recv)
-    int(layout.trace_device(flags_dev, recv_dev)[0])  # warm both programs
+    with Clock() as c:
+        marks = tracer.unpack_marks(tracer.wake_device(flags_dev, recv_dev))
+    say(f"wake 0 (cold: compile + upload + derivation): {c.s:.1f}s")
+    check(np.array_equal(marks, oracle), "wake != oracle (first wake)")
+
+    # -- again from invalidate(), operands resident, and what
+    #    block_until_ready does here ----------------------------------- #
+    tracer.invalidate()
     t0 = time.perf_counter()
-    out = layout.trace_device(flags_dev, recv_dev)
+    mark_w = tracer.wake_device(flags_dev, recv_dev)
     t1 = time.perf_counter()
-    out.block_until_ready()
+    mark_w.block_until_ready()
     t2 = time.perf_counter()
-    int(out[0])
+    int(mark_w[0, 0])
     t3 = time.perf_counter()
     say(
-        f"trace_device resident: dispatch {t1 - t0:.4f}s, "
+        f"wake 1 (from invalidate(), resident): dispatch {t1 - t0:.4f}s, "
         f"block_until_ready {t2 - t1:.4f}s, 1-element readback after "
         f"{t3 - t2:.4f}s"
     )
-
-    # -- decremental: first wake = full derivation --------------------- #
-    with Clock() as c:
-        marks_dec0 = tracer.unpack_marks(tracer.wake_device(flags_dev, recv_dev))
-    say(f"decremental wake 0 (cold: compile + full derivation): {c.s:.1f}s")
-    check(np.array_equal(marks_dec0, oracle0), "decremental != oracle (first wake)")
-
-    # -- churn wakes: releases + new refs through apply_log ------------- #
-    rng = np.random.default_rng(args.seed + 7)
-    base_keys_sorted = np.sort(pack_keys(psrc, pdst, kinds))
-    removable = np.nonzero(kinds == 0)[0]  # churn stays edge-kind only
-    removed = np.zeros(psrc.size, dtype=bool)
-    ins_src, ins_dst, ins_seen = [], [], set()
-    half = max(1, args.churn // 2)
-    scatters_before = layout._dev_scatter is not None
-    for w in range(1, args.wakes + 1):
-        cand = rng.choice(removable, half, replace=False)
-        cand = cand[~removed[cand]]
-        new_s = rng.integers(0, n, half, dtype=np.int64)
-        new_d = rng.integers(0, n, half, dtype=np.int64)
-        new_keys = pack_keys(new_s, new_d, np.zeros(half, np.int64))
-        pos = np.minimum(
-            np.searchsorted(base_keys_sorted, new_keys),
-            base_keys_sorted.size - 1,
-        )
-        fresh = base_keys_sorted[pos] != new_keys
-        log = [
-            (False, int(s), int(d), 0)
-            for s, d in zip(psrc[cand].tolist(), pdst[cand].tolist())
-        ]
-        for key, s, d, f in zip(
-            new_keys.tolist(), new_s.tolist(), new_d.tolist(), fresh.tolist()
-        ):
-            if f and key not in ins_seen:
-                ins_seen.add(key)
-                log.append((True, s, d, 0))
-                ins_src.append(s)
-                ins_dst.append(d)
-        removed[cand] = True
-        with Clock() as ch:
-            tracer.apply_log(log)
-        with Clock() as cw:
-            mark_w = tracer.wake_device(flags_dev, recv_dev)
-            mark_w.block_until_ready()
-        say(
-            f"churn wake {w}: {len(log)} transitions "
-            f"({cand.size} releases), apply_log {ch.s:.3f}s, wake "
-            f"{cw.s:.2f}s, tiers: frozen={len(layout.frozen)} "
-            f"pending={len(layout.pending)} masked_base={layout.masked_base}"
-        )
     check(
-        not scatters_before and layout._dev_scatter is not None,
-        "the donated in-place mask scatter never ran",
+        np.array_equal(tracer.unpack_marks(mark_w), oracle),
+        "wake != oracle (second derivation)",
+    )
+    stats = tracer.wake_stats()
+    check(
+        [s["closure_sweeps"] for s in stats] == [0, 0]
+        and stats[0]["dirty_chunks"] == stats[1]["dirty_chunks"],
+        f"a derivation from nothing ran a closure or did not repeat: {stats}",
     )
     check(layout.stats["anomalies"] == 0, f"layout anomalies: {layout.stats}")
-
-    # -- last wake: both paths against the oracle on the churned graph -- #
-    marks_dec = tracer.unpack_marks(mark_w)
-    with Clock() as c:
-        oracle = trace_ops.trace_marks_np(
-            flags, recv, np.full(n, -1, np.int32),
-            np.concatenate([psrc, np.asarray(ins_src, np.int64)]),
-            np.concatenate([pdst, np.asarray(ins_dst, np.int64)]),
-            np.concatenate(
-                [(~removed).astype(np.int64), np.ones(len(ins_src), np.int64)]
-            ),
-        )
-    say(f"oracle (numpy) on the churned graph: {c.s:.1f}s")
-    check(np.array_equal(marks_dec, oracle), "decremental != oracle (last wake)")
-    with Clock() as c:
-        marks_full = layout.trace(flags, recv)
-    say(f"full retrace on the churned layout (new tier set compiles): {c.s:.1f}s")
-    check(np.array_equal(marks_full, oracle), "full retrace != oracle (last wake)")
     in_use = (flags & trace_ops.FLAG_IN_USE) != 0
     say(
-        f"data plane OK: garbage first wake {int((in_use & ~oracle0).sum())}, "
-        f"last wake {int((in_use & ~oracle).sum())}; layout stats "
+        f"data plane OK: garbage {int((in_use & ~oracle).sum())}; repair sweeps "
+        f"{stats[1]['n_sweeps']} (jumping {stats[1]['jump_sweeps']}), dirty "
+        f"chunks {stats[1]['dirty_chunks']}; layout stats "
         f"{ {k: (round(v, 2) if isinstance(v, float) else v) for k, v in layout.stats.items()} }; "
         f"device peak bytes {peak_bytes()}"
     )
@@ -339,11 +259,12 @@ def make_inspector(args, backend, extra=None):
                 seen, quiet_since = now, time.monotonic()
             time.sleep(0.02)
         check(graph.device_wakes > 0, f"{backend}: no device wake ran")
+        # the compiled kernel on the chip, the interpreted one off it
         check(
-            graph.trace_impl == expected_impl(args, backend),
+            graph.trace_impl
+            == ("pallas-interpret" if args.rehearse else "pallas"),
             f"{backend}: trace resolved to {graph.trace_impl!r}",
         )
-        check(graph._on_tpu() != args.rehearse, f"{backend}: _on_tpu() wrong")
         # A device trace that raises stops the Bookkeeper cell while the
         # application runs on (runtime/cell.py: unmanaged system cells):
         # collection observed above, and the collector still alive here.
@@ -401,11 +322,12 @@ def phase_mesh_data_plane(args, n):
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from uigc_tpu.ops import pallas_decremental as pd
     from uigc_tpu.ops import pallas_trace as pt
     from uigc_tpu.parallel import sharded_trace as st
 
     D = args.chips
-    graph, (psrc, pdst, _), oracle = make_graph(args, n)
+    graph, (psrc, pdst), oracle = make_graph(args, n)
     mesh = st.build_mesh(D)
     check(mesh.devices.size == D, "mesh is short of devices")
 
@@ -471,7 +393,7 @@ def phase_mesh_data_plane(args, n):
     )
     del operands, stacked, out, mark
 
-    # -- what it is compared with: the one-device trace, same graph ----- #
+    # -- what it is compared with: the one-device derivation, same graph #
     with Clock() as c:
         prep = pt.prepare_chunks(
             graph["edge_src"].astype(np.int32),
@@ -480,12 +402,12 @@ def phase_mesh_data_plane(args, n):
         )
     say(f"one-device pack: {c.s:.1f}s")
     with Clock() as c:
-        marks_one = pt.trace_marks_layouts(
+        marks_one, _ = pd.derive(
             graph["flags"], graph["recv_count"], [prep], mode=pt.MODE_AUTO,
             jump_parent=pt.jump_parents(psrc, pdst, n),
         )
-    say(f"one-device trace (compile + upload + run): {c.s:.1f}s")
-    check(np.array_equal(marks_one, oracle), "one-device trace != oracle")
+    say(f"one-device derivation (compile + upload + run): {c.s:.1f}s")
+    check(np.array_equal(marks_one, oracle), "one-device derivation != oracle")
     check(np.array_equal(marks_one, marks_mesh), "one-device != sharded verdict")
     say(f"mesh data plane OK; device peak bytes (device 0) {peak_bytes()}")
 
@@ -540,7 +462,6 @@ def main() -> None:
 
     if args.rehearse:
         args.actors = min(args.actors, 1 << 15)
-        args.churn = min(args.churn, 512)
     n = args.actors
     check(args.rehearse or n >= 1_000_000, "--actors below 1M is a rehearsal size")
     if args.chips == 1:
@@ -548,7 +469,7 @@ def main() -> None:
         shapes = [("tree", 10_000), ("rings", 100), ("tree", 100_000)]
         if args.rehearse:
             shapes = [("tree", 300), ("rings", 6), ("tree", 1_500)]
-        served(args, ["decremental", "device"], shapes)
+        served(args, ["decremental"], shapes)
     else:
         phase_mesh_data_plane(args, n)
         shapes = [("tree", 10_000), ("rings", 100)]

@@ -1,8 +1,9 @@
 """Run the live actor runtime against every shadow-graph backend.
 
 The oracle is the reference-exact pointer graph; "array" folds into dense
-numpy arrays; "device" additionally runs the trace through the JAX kernel.
-All three must produce identical lifecycle behavior.
+numpy arrays; "decremental" additionally runs the trace on the device, as
+the decremental wake (interpreted here); the mesh backends shard it.
+All must produce identical lifecycle behavior.
 """
 
 import pytest
@@ -73,8 +74,8 @@ from conftest import NATIVE_BACKEND
 @pytest.mark.parametrize(
     "backend",
     [
-        "oracle", "array", "device", "mesh", "decremental",
-        "mesh-decremental", NATIVE_BACKEND,
+        "oracle", "array", "mesh", "decremental", "mesh-decremental",
+        NATIVE_BACKEND,
     ],
 )
 def test_cycle_collection_all_backends(backend):
@@ -94,25 +95,17 @@ def test_cycle_collection_all_backends(backend):
         kit.shutdown()
 
 
-def test_cycle_collection_device_pallas(monkeypatch):
-    """The device backend's Pallas trace path, forced on CPU (interpret
-    mode) by faking the platform check; same lifecycle contract."""
-    from uigc_tpu.engines.crgc.arrays import ArrayShadowGraph
+def test_device_backend_name_is_refused():
+    """``shadow-graph: device`` (the full re-trace, gone with PR 31) is an
+    unknown name like any other: the error names the valid ones."""
+    from uigc_tpu.engines.crgc.engine import SHADOW_GRAPHS
 
-    monkeypatch.setattr(ArrayShadowGraph, "_on_tpu", lambda self: True)
-    kit = ActorTestKit(
-        {"uigc.crgc.wakeup-interval": 10, "uigc.crgc.shadow-graph": "device"}
-    )
-    try:
-        probe = kit.create_test_probe(timeout_s=60.0)
-        root = kit.spawn(Behaviors.setup_root(lambda ctx: Root(ctx, probe)), "root")
-        probe.expect_message_type(Spawned)
-        probe.expect_message_type(Spawned)
-        root.tell(Drop())
-        probe.expect_message_type(Stopped)
-        probe.expect_message_type(Stopped)
-    finally:
-        kit.shutdown()
+    assert "device" not in SHADOW_GRAPHS and "decremental" in SHADOW_GRAPHS
+    with pytest.raises(ValueError) as err:
+        ActorTestKit({"uigc.crgc.shadow-graph": "device"})
+    assert "'device'" in str(err.value)
+    for name in SHADOW_GRAPHS:
+        assert name in str(err.value)
 
 
 class LoneRoot(AbstractBehavior):
@@ -230,7 +223,6 @@ def test_pipelined_stalled_wake_expires():
         CrgcContext(delta_graph_size=64, entry_field_size=4),
         "uigc://test",
         use_device=True,
-        decremental=True,
     )
 
     class NeverReady:

@@ -33,6 +33,10 @@ GEOM_10M = dict(n=10_000_000, n_blocks=24_576, n_super=2442, r_rows=2496)
 #: chain_actor_graph(1M) (the benchmark's ``chain-1m``: 2.0M pairs, 4
 #: walk chunks), as ``IncrementalPallasLayout.rebuild`` packs it
 GEOM_CHAIN_1M = dict(n=1_000_000, n_blocks=4096, n_super=245, r_rows=256)
+#: the served cell's resident tree (the benchmark's ``tree-100k``: 100,001
+#: actors in a backend capacity of 131,072, 300,001 pairs, one walk
+#: chunk), as ``IncrementalPallasLayout.rebuild`` packs it
+GEOM_TREE_100K = dict(n=131_072, n_blocks=1024, n_super=32, r_rows=64)
 #: a 20k-actor layout (pow2-padded blocks)
 GEOM_SMALL = dict(n=20_000, n_blocks=128, n_super=5, r_rows=64)
 #: 10M over four shards, as ``pack_shard_layouts`` packs it
@@ -100,17 +104,6 @@ def _spec(geom):
     return (("dense", geom["n_blocks"], pt.SUB_TPU, pt.GROUP_TPU),)
 
 
-def _compile_trace(geom, s, mode, with_stats=False):
-    fn = pt.get_trace_fn_multi(
-        geom["n"], _spec(geom), geom["n_super"], geom["r_rows"], pt.S_ROWS,
-        interpret=False, mode=mode, with_stats=with_stats,
-    )
-    args = _node_structs(geom, s)
-    if mode in (pt.MODE_JUMP, pt.MODE_AUTO):
-        args.append(_struct((geom["n"] + 1,), np.int32, s))
-    return fn.lower(*args, *_layout_structs(geom, s)).compile()
-
-
 def _compile_wake(geom, s, mode):
     fn = pd.get_wake_fn(
         geom["n"], _spec(geom), geom["n_super"], geom["r_rows"], pt.S_ROWS,
@@ -127,14 +120,6 @@ def _compile_wake(geom, s, mode):
 
 def _mosaic_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
-
-
-def test_full_trace_compiles_at_10m(one_chip):
-    compiled = _compile_trace(GEOM_10M, one_chip, pt.MODE_AUTO)
-    assert _mosaic_calls(compiled) >= 1
-    mem = compiled.memory_analysis()
-    # operands + temps must fit one v5e's 16 GB with room to spare
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
 
 
 def test_decremental_wake_compiles_at_10m(one_chip):
@@ -166,19 +151,26 @@ def test_decremental_wake_compiles_at_chain_1m(one_chip, mode):
     assert fn.jump_price == 10
 
 
+def test_decremental_wake_compiles_at_tree_100k(one_chip):
+    """The served cell's geometry: one walk chunk, so every sweep counts
+    as sparse and ``auto`` prices a jump sweep at a single chunk walk."""
+    compiled = _compile_wake(GEOM_TREE_100K, one_chip, pt.MODE_AUTO)
+    assert _mosaic_calls(compiled) >= 2
+    assert "/jump/double/" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 1 << 30
+    fn = pd.get_wake_fn(
+        GEOM_TREE_100K["n"], _spec(GEOM_TREE_100K), GEOM_TREE_100K["n_super"],
+        GEOM_TREE_100K["r_rows"], pt.S_ROWS, interpret=False, mode=pt.MODE_AUTO,
+    )
+    assert fn.jump_price == 1
+
+
 @pytest.mark.parametrize(
-    "program,mode,with_stats",
-    # the full trace has a with_stats variant; the wake has one program
-    [("trace", m, s) for m in pt.TRACE_MODES for s in (False, True)]
-    + [("wake", m, False) for m in pt.TRACE_MODES],
-    ids=lambda v: {False: "plain", True: "stats"}.get(v, v),
+    "mode", pt.TRACE_MODES, ids=lambda m: f"wake-{m}-plain"
 )
-def test_trace_modes_compile(one_chip, program, mode, with_stats):
-    if program == "trace":
-        compiled = _compile_trace(GEOM_SMALL, one_chip, mode, with_stats)
-    else:
-        compiled = _compile_wake(GEOM_SMALL, one_chip, mode)
-    assert _mosaic_calls(compiled) >= 1
+def test_trace_modes_compile(one_chip, mode):
+    assert _mosaic_calls(_compile_wake(GEOM_SMALL, one_chip, mode)) >= 1
 
 
 @pytest.mark.parametrize("mode", pt.TRACE_MODES)
@@ -205,7 +197,7 @@ def test_int8_contraction_compiles(one_chip, monkeypatch):
     """UIGC_KERNEL_INT8=1 swaps the one-hot contraction's datapath; the
     flag is read at kernel build time and keyed into the fn cache."""
     monkeypatch.setenv("UIGC_KERNEL_INT8", "1")
-    assert _mosaic_calls(_compile_trace(GEOM_SMALL, one_chip, pt.MODE_AUTO)) >= 1
+    assert _mosaic_calls(_compile_wake(GEOM_SMALL, one_chip, pt.MODE_AUTO)) >= 1
 
 
 @pytest.mark.parametrize("geom", [MESH_SMALL, MESH_10M], ids=["64k", "10m"])

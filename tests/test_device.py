@@ -518,7 +518,7 @@ def test_uigc_top_device_panel_degrades():
 
 
 def test_committed_device_figures_absent_on_cpu_trajectory(tmp_path):
-    # the real repo: TPU sessions predate wake_chain device figures
+    # the real repo: TPU sessions predate the chained-wake device figures
     assert device_report.committed_device_figures(str(REPO)) is None
     doc = {"device_per_wake_ms": 2.5, "sweeps_mean": 4.0}
     (tmp_path / "BENCH_WAKE_r01.json").write_text(json.dumps(doc))

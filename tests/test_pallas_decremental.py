@@ -493,3 +493,65 @@ def test_closure_policy_on_ints(walks):
     # at the 10M geometry: 192 walks a derivation, 20 a closure sweep
     if walks == 192:
         assert price == 24 and not pt.closure_gives_up(20, walks) and pt.closure_gives_up(40, walks)
+
+
+def test_derive_is_the_tracers_first_wake():
+    """``pd.derive`` over a layout's tiers and a tracer's first wake are
+    one program on one set of operands: the same marks, the same
+    counters, and the same cached wake fn."""
+    rng = np.random.default_rng(9)
+    n = 3000
+    g = OracleGraph(rng, n, n_edges=3 * n)
+    src, dst, w, sup = g.arrays()
+    tracer = pd.DecrementalTracer(n, s_rows=8, freeze_threshold=16)
+    tracer.rebuild(src, dst, w, sup)
+    _rand_schedule(rng, g, tracer, k=60)
+    tracer.marks(g.flags, g.recv)  # freezes the 60
+    _rand_schedule(rng, g, tracer, k=6)  # and a live tier beside them
+    layout = tracer.layout
+    preps = layout.prepare_wake()
+    assert len(preps) >= 3 and "xla_src" in preps[-1]
+    fns_before = len(pd._fn_cache)
+    marks, stats = pd.derive(
+        g.flags, g.recv, preps, mode=layout.mode,
+        pull_density=layout.pull_density, jump_parent=layout.jump_parent,
+    )
+    assert np.array_equal(marks, g.oracle_marks())
+    tracer.invalidate()
+    assert np.array_equal(tracer.marks(g.flags, g.recv), marks)
+    assert tracer.wake_stats(1)[0] == stats
+    assert len(pd._fn_cache) == fns_before + 1
+    assert (stats["closure_sweeps"], stats["gated_tiles"]) == (0, 0)
+
+
+def test_derive_refuses_a_jump_mode_without_parents():
+    from uigc_tpu.utils.validation import InvariantViolation
+
+    n = 200
+    flags = np.full(n, F.FLAG_IN_USE | F.FLAG_INTERNED, np.uint8)
+    e = np.zeros(0, np.int32)
+    prep = pt.prepare_chunks(e, e, np.zeros(0, np.int64), np.full(n, -1, np.int32), n)
+    with pytest.raises(InvariantViolation):
+        pd.derive(flags, np.zeros(n, np.int64), [prep], mode=pt.MODE_AUTO)
+
+
+def test_graft_entry_derives_the_oracles_marks():
+    """``__graft_entry__.entry()``: a jittable step and its example
+    arguments; the step's first output is the packed marks of its graph."""
+    import jax
+    import jax.numpy as jnp
+
+    import __graft_entry__ as graft
+    from uigc_tpu.models import powerlaw_actor_graph
+
+    fn, args = graft.entry()
+    out = jax.jit(fn)(*args)
+    g = powerlaw_actor_graph(4096, seed=0, garbage_fraction=0.5)
+    expected = trace_ops.trace_marks_np(
+        g["flags"], g["recv_count"], g["supervisor"],
+        g["edge_src"], g["edge_dst"], g["edge_weight"],
+    )
+    n = g["flags"].shape[0]
+    assert np.array_equal(np.asarray(pt.unpack_table(out[0], n, jnp)), expected)
+    in_use = (g["flags"] & F.FLAG_IN_USE) != 0
+    assert np.array_equal(in_use & ~expected, g["expected_garbage"])

@@ -3,10 +3,13 @@
 The layout must produce byte-identical mark vectors to the numpy oracle
 at every point of a random mutation history — inserts into the delta,
 in-place base masking on delete, supervisor retargeting, forced repacks,
-and delete-then-reinsert of the same pair (the masked-slot path).  On
-CPU the kernel runs in Pallas interpret mode; the graph-level test also
-drives the whole engine fold path through it (reference semantics:
-ShadowGraph.java:205-289).
+and delete-then-reinsert of the same pair (the masked-slot path).  The
+layout is read through the one program that walks it, the decremental
+wake: a ``DecrementalTracer`` invalidated before each derivation, so that
+every verdict is derived from nothing over the layout as it stands and
+the tracer's own repair plays no part.  On CPU the kernel runs in Pallas
+interpret mode; the graph-level test also drives the whole engine fold
+path through it (reference semantics: ShadowGraph.java:205-289).
 """
 
 import numpy as np
@@ -14,8 +17,15 @@ import pytest
 
 from uigc_tpu.ops import pallas_incremental as pinc
 from uigc_tpu.ops import trace as trace_ops
+from uigc_tpu.ops.pallas_decremental import DecrementalTracer
 
 F = trace_ops
+
+
+def derive(tracer, gt):
+    """Marks from nothing over the tracer's layout as it stands."""
+    tracer.invalidate()
+    return tracer.marks(gt.flags, gt.recv)
 
 
 class GroundTruth:
@@ -94,9 +104,10 @@ def run_history(seed, n, steps, check_every, interpret=True, **layout_kw):
     # revisit logic need multi-supertile coverage; the production default
     # of 32 would collapse n=2500 into one supertile).
     layout_kw.setdefault("s_rows", 8)
-    layout = pinc.IncrementalPallasLayout(n, interpret=interpret, **layout_kw)
+    tracer = DecrementalTracer(n, interpret=interpret, **layout_kw)
+    layout = tracer.layout
     src, dst, w = gt.edge_arrays()
-    layout.rebuild(src, dst, w, gt.supervisor)
+    tracer.rebuild(src, dst, w, gt.supervisor)
 
     checks = 0
     for step in range(steps):
@@ -104,8 +115,8 @@ def run_history(seed, n, steps, check_every, interpret=True, **layout_kw):
         if (step + 1) % check_every == 0:
             if layout.needs_repack:
                 src, dst, w = gt.edge_arrays()
-                layout.rebuild(src, dst, w, gt.supervisor)
-            got = layout.trace(gt.flags, gt.recv)
+                tracer.rebuild(src, dst, w, gt.supervisor)
+            got = derive(tracer, gt)
             expected = gt.expected_marks()
             assert np.array_equal(got, expected), f"divergence at step {step}"
             checks += 1
@@ -150,37 +161,35 @@ def test_delete_then_reinsert_base_pair():
     gt.flags[a] |= F.FLAG_ROOT
     gt.edges[(a, b)] = True
     gt.edges[(b, c)] = True
-    layout = pinc.IncrementalPallasLayout(n, s_rows=8, interpret=True)
+    tracer = DecrementalTracer(n, s_rows=8, interpret=True)
+    layout = tracer.layout
     src, dst, w = gt.edge_arrays()
-    layout.rebuild(src, dst, w, gt.supervisor)
-    assert layout.trace(gt.flags, gt.recv)[c]
+    tracer.rebuild(src, dst, w, gt.supervisor)
+    assert derive(tracer, gt)[c]
 
     # delete (a,b) from the base -> c unreachable
     del gt.edges[(a, b)]
     layout.remove(a, b, pinc.EDGE)
-    got = layout.trace(gt.flags, gt.recv)
+    got = derive(tracer, gt)
     assert not got[b] and not got[c]
     assert np.array_equal(got, gt.expected_marks())
 
     # re-insert the same pair -> lands in the delta, reachability restored
     gt.edges[(a, b)] = True
     layout.insert(a, b, pinc.EDGE)
-    got = layout.trace(gt.flags, gt.recv)
+    got = derive(tracer, gt)
     assert got[b] and got[c]
     assert np.array_equal(got, gt.expected_marks())
     assert layout.stats["anomalies"] == 0
 
 
-def test_graph_level_protocol_parity(monkeypatch):
+def test_graph_level_protocol_parity():
     """Drive the full entry-fold path (ArrayShadowGraph) through the
     incremental Pallas layout in interpret mode: the _pair_log plumbing
     between graph mutations and the layout is what's under test."""
-    from uigc_tpu.engines.crgc.arrays import ArrayShadowGraph
     from test_trace_parity import Sim
 
-    monkeypatch.setattr(ArrayShadowGraph, "_on_tpu", lambda self: True)
-
-    sim = Sim(11, backend="device")
+    sim = Sim(11, backend="decremental")
     for _ in range(6):
         for _ in range(80):
             sim.random_step()
@@ -198,16 +207,17 @@ def test_graph_level_protocol_parity(monkeypatch):
     survivors = {a.cell for a in sim.live_actors()}
     assert survivors == {sim.root.cell}
 
-    inc = sim.array._inc
-    assert inc is not None and inc.stats["anomalies"] == 0
+    dec = sim.array._dec
+    assert dec is not None and dec.layout.stats["anomalies"] == 0
 
 
 @pytest.mark.parametrize("seed", [0, 1])
-def test_trace_device_matches_trace(seed):
-    """The device-resident operand path (trace_device: mirrors + O(churn)
-    masking scatters) must produce the same marks as the host-operand
-    trace across a mutation history with freezes and consolidations —
-    including after rebuilds, which must invalidate the mirrors."""
+def test_resident_operands_match_a_fresh_tracer(seed):
+    """The device-resident operands (mirrors + O(churn) masking
+    scatters) must give the same marks as a tracer rebuilt from the
+    graph as it stands, whose mirrors are uploaded whole, across a
+    mutation history with freezes and consolidations — including after
+    rebuilds, which must invalidate the mirrors."""
     import jax
 
     rng = np.random.default_rng(seed)
@@ -215,28 +225,39 @@ def test_trace_device_matches_trace(seed):
     gt = GroundTruth(rng, n)
     for _ in range(n * 2):
         gt.edges[(int(rng.integers(0, n)), int(rng.integers(0, n)))] = True
-    layout = pinc.IncrementalPallasLayout(
+    tracer = DecrementalTracer(
         n, s_rows=8, interpret=True, freeze_threshold=24, max_frozen=2
     )
+    layout = tracer.layout
     src, dst, w = gt.edge_arrays()
-    layout.rebuild(src, dst, w, gt.supervisor)
+    tracer.rebuild(src, dst, w, gt.supervisor)
 
     flags_dev = jax.device_put(gt.flags)
     recv_dev = jax.device_put(gt.recv)
+
+    def resident():
+        tracer.invalidate()
+        return tracer.unpack_marks(tracer.wake_device(flags_dev, recv_dev))
+
+    def fresh():
+        other = DecrementalTracer(n, s_rows=8, interpret=True)
+        other.rebuild(*gt.edge_arrays(), gt.supervisor)
+        return other.unpack_marks(other.wake_device(flags_dev, recv_dev))
+
     for step in range(8):
         for _ in range(40):
             gt.mutate(layout)
-        got = np.asarray(layout.trace_device(flags_dev, recv_dev))
-        expected = gt.expected_marks()
-        assert np.array_equal(got, expected), f"divergence at step {step}"
+        got = resident()
+        assert np.array_equal(got, fresh()), f"divergence at step {step}"
+        assert np.array_equal(got, gt.expected_marks())
     assert layout.stats["anomalies"] == 0
     # the run must actually exercise the frozen-tier mirrors and their
     # GC at consolidation, or this test is not covering what it claims
     assert layout.stats["freezes"] > 0
     assert layout.stats["consolidations"] >= 1
+    assert layout._dev_scatter is not None  # the O(churn) sync ran
 
     # a forced rebuild must drop stale mirrors
     src, dst, w = gt.edge_arrays()
-    layout.rebuild(src, dst, w, gt.supervisor)
-    got = np.asarray(layout.trace_device(flags_dev, recv_dev))
-    assert np.array_equal(got, gt.expected_marks())
+    tracer.rebuild(src, dst, w, gt.supervisor)
+    assert np.array_equal(resident(), gt.expected_marks())

@@ -3,16 +3,36 @@
 Random graphs with all the semantic wrinkles — halted nodes, roots,
 negative/zero-weight edges, supervisor pointers, free slots — must produce
 identical mark vectors (the reference author's dual-graph technique,
-reference: ShadowGraph.java:176-199).  On CPU the kernel runs in Pallas
+reference: ShadowGraph.java:176-199).  The kernel is reached through the
+one program a chip runs it in, the decremental wake deriving from nothing
+(``pallas_decremental.derive``).  On CPU the kernel runs in Pallas
 interpret mode; on TPU it compiles for real.
 """
 
 import numpy as np
 import pytest
 
-from uigc_tpu.ops import pallas_trace, trace as trace_ops
+from uigc_tpu.ops import pallas_decremental, pallas_trace, trace as trace_ops
 
 F = trace_ops
+
+
+def derive_graph(flags, recv, supervisor, edge_src, edge_dst, edge_weight,
+                 mode="push"):
+    """Marks from nothing over one full pack of the graph's pairs."""
+    n = flags.shape[0]
+    prep = pallas_trace.prepare_chunks(
+        edge_src, edge_dst, edge_weight, supervisor, n
+    )
+    jp = None
+    if mode in (pallas_trace.MODE_JUMP, pallas_trace.MODE_AUTO):
+        jp = pallas_trace.jump_parents_from_graph(
+            edge_src, edge_dst, edge_weight, supervisor, n
+        )
+    marks, _ = pallas_decremental.derive(
+        flags, recv, [prep], mode=mode, jump_parent=jp
+    )
+    return marks
 
 
 def random_graph(rng, n, n_edges):
@@ -44,7 +64,7 @@ def test_pallas_matches_oracle(seed, n, n_edges):
     rng = np.random.default_rng(seed)
     g = random_graph(rng, n, n_edges)
     expected = trace_ops.trace_marks_np(*g)
-    got = pallas_trace.trace_marks_pallas(*g)
+    got = derive_graph(*g)
     assert np.array_equal(got, expected)
 
 
@@ -58,15 +78,15 @@ def test_trace_modes_match_oracle(seed, mode):
     rng = np.random.default_rng(seed)
     g = random_graph(rng, 1500, 6000)
     expected = trace_ops.trace_marks_np(*g)
-    got = pallas_trace.trace_marks_pallas(*g, mode=mode)
+    got = derive_graph(*g, mode=mode)
     assert np.array_equal(got, expected)
 
 
 def test_jump_collapses_chain_sweeps():
     """The ISSUE-6 acceptance shape: on a long chain (diameter = n) the
     push fixpoint needs O(n) sweeps while pointer-jumping converges in
-    O(log n) — and both agree with the oracle.  Sweep counts come from
-    the with_stats fixpoint, which is what the wake profiler reports."""
+    O(log n) — and both agree with the oracle.  Sweep counts are the
+    wake's own counters, which is what the wake profiler reports."""
     n = 200
     flags = np.full(n, F.FLAG_IN_USE | F.FLAG_INTERNED, dtype=np.uint8)
     flags[0] |= F.FLAG_ROOT
@@ -79,16 +99,16 @@ def test_jump_collapses_chain_sweeps():
     prep = pallas_trace.prepare_chunks(src, dst, w, sup, n)
     jp = pallas_trace.jump_parents_from_graph(src, dst, w, sup, n)
 
-    push_marks, push_stats = pallas_trace.trace_marks_layouts(
-        flags, recv, [prep], mode="push", with_stats=True
+    push_marks, push_stats = pallas_decremental.derive(
+        flags, recv, [prep], mode="push"
     )
-    jump_marks, jump_stats = pallas_trace.trace_marks_layouts(
-        flags, recv, [prep], mode="jump", jump_parent=jp, with_stats=True
+    jump_marks, jump_stats = pallas_decremental.derive(
+        flags, recv, [prep], mode="jump", jump_parent=jp
     )
     assert np.array_equal(push_marks, expected)
     assert np.array_equal(jump_marks, expected)
-    push_sweeps = int(push_stats["n_sweeps"])
-    jump_sweeps = int(jump_stats["n_sweeps"])
+    push_sweeps = push_stats["n_sweeps"]
+    jump_sweeps = jump_stats["n_sweeps"]
     assert push_sweeps >= n - 1  # O(diameter)
     assert jump_sweeps <= 10  # O(log diameter) at JUMP_STEPS=2
     assert jump_sweeps * 6 < push_sweeps
@@ -125,13 +145,13 @@ def test_mode_sweep_counts_at_powerlaw_geometry():
     )
     sweeps, jumps = {}, {}
     for mode in ("push", "auto", "jump"):
-        marks, stats = pallas_trace.trace_marks_layouts(
+        marks, stats = pallas_decremental.derive(
             g["flags"], g["recv_count"], [prep], mode=mode,
-            jump_parent=None if mode == "push" else jp, with_stats=True,
+            jump_parent=None if mode == "push" else jp,
         )
         assert np.array_equal(marks, expected), mode
-        sweeps[mode] = int(stats["n_sweeps"])
-        jumps[mode] = int(stats["jump_sweeps"])
+        sweeps[mode] = stats["n_sweeps"]
+        jumps[mode] = stats["jump_sweeps"]
     assert sweeps["jump"] <= 6
     assert sweeps["jump"] < sweeps["push"]
     assert sweeps["auto"] == sweeps["push"]
@@ -149,7 +169,7 @@ def chain_graph(n):
 
 def auto_price(prep):
     """AUTO's price of a jump sweep for a one-layout trace, from the same
-    helper and the same arguments as the trace fn takes it."""
+    helper and the same arguments as the wake fn takes it."""
     n_chunks = prep["r_rows"] // (pallas_trace.ROWS * prep["group"])
     pull_cut = max(1, round(pallas_trace.DEFAULT_PULL_DENSITY * n_chunks))
     return pallas_trace.auto_jump_policy(
@@ -173,17 +193,16 @@ def test_auto_engages_the_jump_on_a_chain(n):
     jp = pallas_trace.jump_parents_from_graph(src, dst, w, sup, n)
     price = auto_price(prep)
     assert 1 < price < n // 8  # long enough to cross it, far under n
-    marks, stats = pallas_trace.trace_marks_layouts(
-        flags, recv, [prep], mode="auto", jump_parent=jp, with_stats=True
+    marks, stats = pallas_decremental.derive(
+        flags, recv, [prep], mode="auto", jump_parent=jp
     )
     assert np.array_equal(marks, expected)
-    k = int(stats["n_sweeps"])
+    k = stats["n_sweeps"]
     assert k <= pallas_trace.MAX_SWEEP_STATS  # every sweep has its slot
-    jump_on = stats["jump_on"][:k].tolist()
     # one chunk walked per sweep: engaged exactly when the price is paid,
     # and from then on to the end
-    assert jump_on == [0] * price + [1] * (k - price)
-    assert int(stats["jump_sweeps"]) == k - price > 0
+    assert stats["jump_on"] == [0] * price + [1] * (k - price)
+    assert stats["jump_sweeps"] == k - price > 0
     # 4^k reach per engaged sweep (JUMP_STEPS = 2), plus the sweep that
     # finds nothing new
     assert k - price <= np.log2(n) / 2 + 3
@@ -199,7 +218,7 @@ def test_no_edges():
     e = np.zeros(0, dtype=np.int32)
     w = np.zeros(0, dtype=np.int64)
     expected = trace_ops.trace_marks_np(flags, recv, sup, e, e, w)
-    got = pallas_trace.trace_marks_pallas(flags, recv, sup, e, e, w)
+    got = derive_graph(flags, recv, sup, e, e, w)
     assert np.array_equal(got, expected)
 
 
@@ -215,7 +234,7 @@ def test_long_chain():
     w = np.ones(n - 1, dtype=np.int64)
     expected = trace_ops.trace_marks_np(flags, recv, sup, src, dst, w)
     assert expected.all()
-    got = pallas_trace.trace_marks_pallas(flags, recv, sup, src, dst, w)
+    got = derive_graph(flags, recv, sup, src, dst, w)
     assert np.array_equal(got, expected)
 
 
@@ -238,7 +257,7 @@ def test_wide_geometry_matches_oracle(seed, sub, group):
         edge_src, edge_dst, edge_weight, supervisor, flags.shape[0],
         s_rows=8, sub=sub, group=group,
     )
-    got = pallas_trace.trace_marks_prepared(flags, recv, prep)
+    got, _ = pallas_decremental.derive(flags, recv, [prep])
     assert np.array_equal(got, expected)
 
 
@@ -259,12 +278,10 @@ from uigc_tpu.ops import pallas_trace, trace as trace_ops
 assert pallas_trace._int8_mxu(), "int8 flag did not take effect"
 import sys
 sys.path.insert(0, "tests")
-from test_pallas_trace import random_graph
+from test_pallas_trace import derive_graph, random_graph
 rng = np.random.default_rng(3)
 g = random_graph(rng, 1200, 5000)
-assert np.array_equal(
-    pallas_trace.trace_marks_pallas(*g), trace_ops.trace_marks_np(*g)
-)
+assert np.array_equal(derive_graph(*g), trace_ops.trace_marks_np(*g))
 print("INT8 PARITY OK")
 """
     env = dict(os.environ, UIGC_KERNEL_INT8="1")
@@ -285,16 +302,12 @@ def test_int8_mxu_compiled_parity(monkeypatch):
     mode cannot catch an int8-dot lowering failure.  In process: this
     process holds the chip, so a child could not reach it (the flag is
     read at kernel build time and keyed into the fn cache)."""
-    from uigc_tpu.ops import pallas_trace, trace as trace_ops
-
     monkeypatch.setenv("UIGC_KERNEL_INT8", "1")
     assert pallas_trace._int8_mxu()
     rng = np.random.default_rng(3)
     flags, recv, supervisor, src, dst, w = g = random_graph(rng, 1200, 5000)
     prep = pallas_trace.prepare_chunks(src, dst, w, supervisor, 1200)
-    got = pallas_trace.trace_marks_layouts(
-        flags, recv, [prep], interpret=False
-    )
+    got, _ = pallas_decremental.derive(flags, recv, [prep], interpret=False)
     assert np.array_equal(got, trace_ops.trace_marks_np(*g))
 
 
@@ -308,6 +321,7 @@ def test_int8_ab_in_process(monkeypatch):
     from uigc_tpu.models.graphgen import powerlaw_actor_graph
     from uigc_tpu.ops import pallas_trace as pt
 
+    pd = pallas_decremental
     n = 1 << 11
     g = powerlaw_actor_graph(n, seed=5, garbage_fraction=0.4)
     prep = pt.prepare_chunks(
@@ -318,11 +332,9 @@ def test_int8_ab_in_process(monkeypatch):
         n,
     )
     marks = {}
-    keys_before = len(pt._fn_cache)
+    keys_before = set(pd._fn_cache)
     for flag in ("0", "1"):
         monkeypatch.setenv("UIGC_KERNEL_INT8", flag)
-        marks[flag] = np.asarray(
-            pt.trace_marks_prepared(g["flags"], g["recv_count"], prep)
-        )
+        marks[flag], _ = pd.derive(g["flags"], g["recv_count"], [prep])
     assert np.array_equal(marks["0"], marks["1"])
-    assert len(pt._fn_cache) >= keys_before + 2  # one kernel per datapath
+    assert len(set(pd._fn_cache) - keys_before) == 2  # one program per datapath

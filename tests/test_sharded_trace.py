@@ -150,11 +150,12 @@ def test_sharded_pallas_matches_host(seed, mode):
 def test_sharded_auto_engages_where_one_device_does():
     """``auto`` means one thing in every program: on a chain the sharded
     trace engages the pointer jump on the same sweep as the
-    single-device trace of the same node space (both count the same
+    single-device derivation of the same node space (both count the same
     replicated dirty chunks against the same price), finishes in the
     same number of sweeps, and gives the oracle's marks."""
     import jax
 
+    from uigc_tpu.ops import pallas_decremental
     from uigc_tpu.ops import pallas_trace as pt
     from uigc_tpu.parallel import make_sharded_pallas_trace, pack_shard_layouts
 
@@ -169,8 +170,8 @@ def test_sharded_auto_engages_where_one_device_does():
     jp = pt.jump_parents(psrc, pdst, n_pad)
 
     prep = pt.prepare_pairs(psrc, pdst, n_pad, s_rows=s_rows)
-    one, one_stats = pt.trace_marks_layouts(
-        flags, recv, [prep], mode="auto", jump_parent=jp, with_stats=True
+    one, one_stats = pallas_decremental.derive(
+        flags, recv, [prep], mode="auto", jump_parent=jp
     )
     assert one.all()
 
@@ -190,7 +191,7 @@ def test_sharded_auto_engages_where_one_device_does():
     sweeps, jumped = int(stats["n_sweeps"]), int(stats["jump_sweeps"])
     assert 0 < jumped < sweeps  # engaged, and not from sweep 0
     # engagement is for good, so the first jump sweep is sweeps - jumped
-    assert (sweeps, jumped) == (int(one_stats["n_sweeps"]), int(one_stats["jump_sweeps"]))
+    assert (sweeps, jumped) == (one_stats["n_sweeps"], one_stats["jump_sweeps"])
 
 
 @pytest.mark.parametrize("mode", ["push", "auto"])

@@ -1,7 +1,7 @@
 """Compiled-on-TPU parity tier (``UIGC_TEST_TPU=1 python -m pytest tests/``).
 
 Every test here runs the Pallas trace kernel with ``interpret=False`` on a
-real chip and checks byte-identical marks against the numpy oracle
+real chip, inside the wake program that runs it there, and checks byte-identical marks against the numpy oracle
 (reference semantics: ShadowGraph.java:205-289).  The default CPU tier runs
 the same kernels in interpret mode only, which cannot catch Mosaic lowering
 failures — a kernel can trace fine interpreted and still be uncompilable on
@@ -12,7 +12,7 @@ deliberate kernel break must turn THIS file red on a TPU host.
 import numpy as np
 import pytest
 
-from uigc_tpu.ops import pallas_trace, trace as trace_ops
+from uigc_tpu.ops import pallas_decremental, pallas_trace, trace as trace_ops
 from test_pallas_incremental import run_history
 from test_pallas_trace import random_graph
 
@@ -29,7 +29,7 @@ def test_compiled_matches_oracle(seed, n, n_edges):
     expected = trace_ops.trace_marks_np(*g)
     flags, recv, supervisor, src, dst, w = g
     prep = pallas_trace.prepare_chunks(src, dst, w, supervisor, n)
-    got = pallas_trace.trace_marks_layouts(flags, recv, [prep], interpret=False)
+    got, _ = pallas_decremental.derive(flags, recv, [prep], interpret=False)
     assert np.array_equal(got, expected)
 
 
@@ -48,7 +48,7 @@ def test_compiled_million_actor_parity():
     sup = np.full(n, -1, np.int32)
     expected = trace_ops.trace_marks_np(flags, recv, sup, src, dst, w)
     prep = pallas_trace.prepare_chunks(src, dst, w, sup, n)
-    got = pallas_trace.trace_marks_layouts(flags, recv, [prep], interpret=False)
+    got, _ = pallas_decremental.derive(flags, recv, [prep], interpret=False)
     assert np.array_equal(got, expected)
 
 
@@ -73,8 +73,8 @@ def test_compiled_decremental_wakes():
     hardware, diffed against the from-scratch oracle across churn wakes
     incl. a released cycle and a halt cascade."""
     from test_pallas_decremental import OracleGraph, _rand_schedule
-    from uigc_tpu.ops import pallas_decremental as pd
 
+    pd = pallas_decremental
     rng = np.random.default_rng(7)
     n = 1 << 12
     g = OracleGraph(rng, n, n_edges=4 * n)

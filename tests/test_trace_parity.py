@@ -137,7 +137,10 @@ def graph_cells(graph):
 
 
 class Sim:
-    def __init__(self, seed, backend="array"):
+    def __init__(self, seed, backend="array", cold=False):
+        """``cold``: the device backend's previous fixpoint is dropped
+        before every trace, so each wake is a derivation from nothing."""
+        self.cold = cold
         self.rng = random.Random(seed)
         self.system = FakeSystem()
         self.context = CrgcContext(delta_graph_size=64, entry_field_size=4)
@@ -161,8 +164,7 @@ class Sim:
             self.array = ArrayShadowGraph(
                 self.context,
                 self.system.address,
-                use_device=(backend in ("device", "decremental")),
-                decremental=(backend == "decremental"),
+                use_device=(backend == "decremental"),
             )
         root_cell = FakeCell(self.system)
         self.root = SimActor(self, root_cell, None, self.context)
@@ -217,6 +219,11 @@ class Sim:
         assert before_oracle == before_array
 
         self.oracle.trace(should_kill=False)
+        if self.cold:
+            if hasattr(self.array, "invalidate_wake_state"):  # the mesh
+                self.array.invalidate_wake_state()
+            elif self.array._dec is not None:
+                self.array._dec.invalidate()
         self.array.trace(should_kill=False)
 
         after_oracle = set(self.oracle.shadow_map.keys())
@@ -262,12 +269,15 @@ from conftest import NATIVE_AVAILABLE, NATIVE_BACKEND
 
 @pytest.mark.parametrize(
     "backend",
-    ["array", "device", "mesh", "decremental", "mesh-decremental",
-     NATIVE_BACKEND],
+    ["array", "mesh", "decremental", "mesh-decremental",
+     "decremental-cold", "mesh-decremental-cold", NATIVE_BACKEND],
 )
 @pytest.mark.parametrize("seed", [7, 42, 20260729])
 def test_random_protocol_parity(seed, backend):
-    sim = Sim(seed, backend=backend)
+    cold = backend.endswith("-cold")
+    if cold:
+        backend = backend[: -len("-cold")]
+    sim = Sim(seed, backend=backend, cold=cold)
     for round_no in range(20):
         for _ in range(150):
             sim.random_step()
@@ -292,12 +302,19 @@ def test_random_protocol_parity(seed, backend):
     assert survivors == {sim.root.cell}, (
         f"{len(survivors) - 1} actors never collected"
     )
+    if cold and backend == "decremental":
+        # every wake took the cold road: no closure, no supertile forced
+        stats = sim.array._dec.wake_stats()
+        assert len(stats) == 25
+        assert all(
+            s["closure_sweeps"] == 0 and s["gated_tiles"] == 0 for s in stats
+        )
 
 
 def test_supervisor_marking_parity():
     """A live child must keep its (otherwise-garbage) parent alive in both
     implementations (reference: ShadowGraph.java:242-267)."""
-    backends = ["array", "device"] + (["native"] if NATIVE_AVAILABLE else [])
+    backends = ["array", "decremental"] + (["native"] if NATIVE_AVAILABLE else [])
     for backend in backends:
         sim = Sim(1, backend=backend)
         parent = sim.root.spawn()

@@ -237,6 +237,47 @@ def test_stats_handles_are_bounded_and_tracers_are_found():
     assert ident not in {id(t) for t in pd.live_tracers()}
 
 
+def test_sweep_profile_prints_the_wakes_own_rows(monkeypatch, capsys):
+    """``tools/sweep_profile.py``'s measured mode reads the wake program:
+    its per-sweep rows, per mode, are ``wake_stats()`` of a tracer's first
+    wake over the same graph, and its profiler records carry them."""
+    import json
+    import os
+    import sys
+
+    from uigc_tpu.models import powerlaw_actor_graph
+
+    n, modes = 4096, ["auto", "jump", "pull"]
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
+    try:
+        import sweep_profile
+    finally:
+        sys.path.pop(0)
+    # the tool's compile cache is the chip's business, not this process's
+    monkeypatch.setattr("uigc_tpu.utils.platform.enable_compile_cache", lambda: None)
+    monkeypatch.setattr(
+        sys, "argv",
+        ["sweep_profile.py", "--n", str(n), "--skip-probes", "--modes", ",".join(modes)],
+    )
+    sweep_profile.main()
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert sorted(out["modes"]) == sorted(modes)
+
+    g = powerlaw_actor_graph(n, seed=0, garbage_fraction=0.5)
+    rows = ("dirty_chunks", "tiles_skipped", "pull_on", "jump_on")
+    for mode, record in zip(modes, out["wake_profile_recent"]):
+        tracer = pd.DecrementalTracer(n, mode=mode)
+        tracer.rebuild(g["edge_src"], g["edge_dst"], g["edge_weight"], g["supervisor"])
+        tracer.marks(g["flags"], g["recv_count"])
+        want = tracer.wake_stats()[0]
+        got = out["modes"][mode]
+        assert got.pop("fixpoint_ms") > 0
+        assert got == {k: want[k] for k in ("n_sweeps", "jump_sweeps") + rows}
+        assert got["n_sweeps"] == len(got["dirty_chunks"]) > 1
+        assert record["mode"] == mode and record["n_sweeps"] == want["n_sweeps"]
+        assert all(record["sweep_" + k] == want[k] for k in rows)
+
+
 # ------------------------------------------------------------------- #
 # the profiler's phases and annotations
 # ------------------------------------------------------------------- #

@@ -167,7 +167,7 @@ FAMILIES: Dict[str, Tuple[str, List[Metric]]] = {
     ),
     # Device plane (telemetry/device.py + tools/device_report.py): the
     # TPU-session artifacts gate the same figures the wake-budget
-    # explainer decomposes.  Rounds that lack the wake_chain_bench keys
+    # explainer decomposes.  Rounds that lack the device-figure keys
     # SKIP — a missing metric must never read as a pass.
     "DEVICE": (
         "BENCH_TPU_SESSION_r*.json",

@@ -74,14 +74,14 @@ def fmt_bytes(n: Optional[float]) -> str:
 
 def committed_device_figures(repo: str = REPO) -> Optional[Dict[str, Any]]:
     """The newest committed device-plane figures: scans the
-    ``BENCH_WAKE_r*.json`` (wake_chain_bench dumps) and
+    ``BENCH_WAKE_r*.json`` (dumps of the former wake_chain_bench) and
     ``BENCH_TPU_SESSION_r*.json`` trajectories for ``device_per_wake_ms``
     / ``sweeps_mean`` / ``device_per_sweep_ms``.  Returns None when no
     committed round carries them (the honest no-TPU-rounds answer)."""
     # Families number their rounds independently, so never compare
-    # round numbers ACROSS them: the WAKE family (wake_chain_bench's
-    # own dumps) is the canonical device_per_wake_ms artifact and wins
-    # outright; TPU sessions are the fallback for rounds where only the
+    # round numbers ACROSS them: the WAKE family (the former
+    # wake_chain_bench's dumps) is the canonical device_per_wake_ms
+    # artifact and wins outright; TPU sessions are the fallback for rounds where only the
     # session document was committed.
     for pattern in ("BENCH_WAKE_r*.json", "BENCH_TPU_SESSION_r*.json"):
         candidates: List[Tuple[int, str]] = []

@@ -1,7 +1,7 @@
 """Decompose the Pallas trace's per-sweep cost at graph scale.
 
-Times three things the full fixpoint mixes together (bench.py reports
-only their sum across ~12 sweeps):
+Times three things the full fixpoint mixes together (a wake reports only
+their sum across ~12 sweeps):
 
 - a **full-dirty** propagation sweep (every chunk dirty: worst-case walk
   + every block's one-hot contraction);
@@ -17,10 +17,11 @@ only their sum across ~12 sweeps):
   ``pallas_trace.JUMP_GATHER_COST``: re-measure it here on a new chip.
 
 Plus, per trace mode (uigc.crgc.trace-mode: push/pull/jump/auto), the
-**per-sweep frontier decomposition** of the real fixpoint — sweep
-count, dirty-chunk density, supertiles changed, tiles pull-skipped,
-and the auto mode's per-sweep pull decision — emitted through the
-telemetry wake profiler (telemetry/profile.py), so the pull-density
+**per-sweep frontier decomposition** of the real fixpoint, the wake
+program deriving from nothing (``pallas_decremental.derive``) — sweep
+count, dirty-chunk density, tiles pull-skipped, and the auto mode's
+per-sweep pull and jump decisions, as ``wake_stats()`` gives them —
+emitted through the telemetry wake profiler (telemetry/profile.py), so the pull-density
 threshold is tuned from recorded wake data instead of guessed.
 
 ``--simulate`` instead runs the numpy sweep-count simulation at the
@@ -76,7 +77,7 @@ def simulate_sweeps(graph, n, modes, jump_steps=None, geometry=None,
                     suspects=None):
     """Hardware-independent fixpoint sweep counts per trace mode, by
     direct numpy simulation of the kernel's per-sweep semantics
-    (pallas_trace trace_fn: table = mark & ~halted, hits gated by
+    (the wake's repair loop: table = mark & ~halted, hits gated by
     in_use, jump parents squared ``JUMP_STEPS`` times per engaged sweep
     through transparent intermediates, the loop running while the table
     changed).  Pull gating changes per-sweep WORK, never the sweep
@@ -260,6 +261,7 @@ def main():
     import jax.numpy as jnp
 
     from uigc_tpu.models import powerlaw_actor_graph
+    from uigc_tpu.ops import pallas_decremental
     from uigc_tpu.ops import pallas_trace as pt
     from uigc_tpu.utils.platform import enable_compile_cache, is_tpu_platform
 
@@ -373,7 +375,7 @@ def main():
         pack_ms = timed(pack, active)
 
         # The per-sweep pack actually on the fixpoint path now: word-space
-        # pack2d of a (t_rows, LANE) hits plane (pallas_trace trace_fn).
+        # pack of a (t_rows, LANE) hits plane (pt.pack_hits_table).
         t_rows = n_super * s_rows
 
         @jax.jit
@@ -414,11 +416,9 @@ def main():
                 use_jump = mode in (pt.MODE_JUMP, pt.MODE_AUTO)
 
                 def run():
-                    return pt.trace_marks_layouts(
-                        flags_h, recv_h, [prep],
-                        mode=mode,
+                    return pallas_decremental.derive(
+                        flags_h, recv_h, [prep], mode=mode,
                         jump_parent=jp if use_jump else None,
-                        with_stats=True,
                     )
 
                 wk = profiler.begin_wake()
@@ -428,28 +428,23 @@ def main():
                         t0 = time.perf_counter()
                         _, stats = run()
                         fix_ms = (time.perf_counter() - t0) * 1e3
-                        k = int(stats["n_sweeps"])
                         ev.fields["trace_mode"] = mode
+                        rows = {
+                            k: stats[k]
+                            for k in ("dirty_chunks", "tiles_skipped",
+                                      "pull_on", "jump_on")
+                        }
                         wk.note(
-                            n_sweeps=k,
-                            sweep_dirty_chunks=stats["dirty_chunks"][:k].tolist(),
-                            sweep_changed_supers=stats["changed_supers"][:k].tolist(),
-                            sweep_tiles_skipped=stats["tiles_skipped"][:k].tolist(),
-                            sweep_pull_on=stats["pull_on"][:k].tolist(),
-                            jump_sweeps=int(stats["jump_sweeps"]),
-                            sweep_jump_on=stats["jump_on"][:k].tolist(),
+                            n_sweeps=stats["n_sweeps"],
+                            jump_sweeps=stats["jump_sweeps"],
+                            **{"sweep_" + k: v for k, v in rows.items()},
                         )
                 wk.end(mode=mode)
-                kk = min(k, len(stats["dirty_chunks"]))
                 mode_out[mode] = {
-                    "n_sweeps": k,
+                    "n_sweeps": stats["n_sweeps"],
                     "fixpoint_ms": round(fix_ms, 2),
-                    "dirty_chunks": stats["dirty_chunks"][:kk].tolist(),
-                    "changed_supers": stats["changed_supers"][:kk].tolist(),
-                    "tiles_skipped": stats["tiles_skipped"][:kk].tolist(),
-                    "pull_on": stats["pull_on"][:kk].tolist(),
-                    "jump_sweeps": int(stats["jump_sweeps"]),
-                    "jump_on": stats["jump_on"][:kk].tolist(),
+                    "jump_sweeps": stats["jump_sweeps"],
+                    **rows,
                 }
         finally:
             events.recorder.remove_listener(profiler)

@@ -37,13 +37,14 @@ DEFAULTS: Dict[str, Any] = {
     # Which shadow-graph implementation the collector uses:
     #   "oracle" - pointer-based graph mirroring the JVM semantics exactly
     #   "array"  - dense-array graph folded on host (numpy)
-    #   "device" - dense-array graph with the trace run on the TPU via JAX
     #   "native" - C++ data plane (uigc_tpu/native/), batch fold + trace
     #   "mesh"   - fold/trace state sharded across a jax device mesh
     #              (engines/crgc/mesh.py); per-wake deltas stream to the
     #              devices, the trace all_gathers marks over ICI
-    #   "decremental" - device trace that re-derives only the churn's
-    #              affected region per wake from the previous fixpoint
+    #   "decremental" - dense-array graph with the trace run on the
+    #              device: each wake re-derives the region the churn may
+    #              have invalidated from the previous fixpoint, or
+    #              everything where there is none
     #              (ops/pallas_decremental.py: suspect closure + repair)
     #   "mesh-decremental" - the mesh backend with the decremental wake
     #              per shard (one word all_gather per sweep)
@@ -51,7 +52,7 @@ DEFAULTS: Dict[str, Any] = {
     # Devices in the mesh backend's mesh; 0 = all visible devices.
     "uigc.crgc.mesh-devices": 0,
     # Propagation strategy for the device-trace fixpoint (the Pallas
-    # "device"/"decremental"/"mesh*" backends; ops/pallas_trace.py):
+    # "decremental"/"mesh*" backends; ops/pallas_trace.py):
     #   "push" - source-push sweeps over the dirty-chunk frontier (the
     #            pre-mode behavior; O(diameter) sweeps)
     #   "pull" - push + destination-pull saturation gates: blocks whose

@@ -6,8 +6,9 @@ reference holds a ``HashMap<ActorRef, Shadow>`` of pointer-linked shadows
 interns actors into dense integer slots and keeps all node state in flat
 numpy arrays — exactly the layout the trace kernels (ops/trace.py) consume
 and the layout that ships to the device.  The fold (merge_entry) is a
-host-side scatter; the trace runs either on host (numpy) or on the TPU
-(JAX), selected by ``use_device``.
+host-side scatter; the trace runs either on the host (numpy) or on the
+device (the decremental wake, ops/pallas_decremental.py), selected by
+``use_device``.
 
 Liveness semantics are identical to the oracle ShadowGraph; differential
 tests (tests/test_trace_parity.py) drive both over the same entry streams
@@ -95,7 +96,6 @@ class ArrayShadowGraph:
         context: CrgcContext,
         local_address: Optional[str] = None,
         use_device: bool = False,
-        decremental: bool = False,
         initial_capacity: int = 1024,
         trace_mode: str = "auto",
         pull_density: float = 0.25,
@@ -104,6 +104,10 @@ class ArrayShadowGraph:
 
         self.context = context
         self.local_address = local_address
+        #: where the trace runs: False = the host (numpy), True = the
+        #: device, as per-wake closure+repair detection relative to the
+        #: previous fixpoint (ops/pallas_decremental.py; interpreted
+        #: where the platform is no TPU)
         self.use_device = use_device
         #: device-trace propagation strategy (uigc.crgc.trace-mode;
         #: pallas_trace MODE_* docs) + the auto mode's pull threshold
@@ -119,9 +123,8 @@ class ArrayShadowGraph:
         #: attached, else None.  The backend's one road to the profiler:
         #: ``events.wake_phase`` brackets its steps on it (layout,
         #: upload, device, readback, sweep) and ``note`` hands it the
-        #: wake's counters.  The decremental wake runs one program with
-        #: or without it; the full re-trace (``device`` backend) takes
-        #: its ``with_stats`` variant while it is set.
+        #: wake's counters.  The wake runs one program with or without
+        #: it.
         self.profile_wake = None
         #: capture the marking-parent array on the next trace (the
         #: why-live provenance forest, telemetry/inspect.py).  The
@@ -148,21 +151,12 @@ class ArrayShadowGraph:
         #: assigning a dict.  Fed by every fold plane; rows naming a
         #: swept slot are purged with the slot.
         self.send_matrix: Optional[Dict[int, int]] = None
-        #: per-wake closure+repair detection relative to the previous
-        #: fixpoint (ops/pallas_decremental.py) instead of a full
-        #: re-trace from seeds; works in interpret mode too, so it is
-        #: not gated on the platform check.
-        assert not decremental or use_device, (
-            "decremental detection runs on the device trace path"
-        )
-        self.decremental = decremental
-        self._dec = None
+        self._dec = None  # lazily-built DecrementalTracer
         #: which implementation the device trace resolved to, recorded
         #: once at the first device wake: "pallas" (Mosaic-compiled, a
-        #: TPU), "pallas-interpret" (the kernel interpreted — CPU test
-        #: tier) or "xla" (the plain XLA trace — "device" off-TPU).
-        #: None until a device wake ran; a caller that needs the chip
-        #: asserts on it rather than trusting the platform default.
+        #: TPU) or "pallas-interpret" (the kernel interpreted — CPU test
+        #: tier).  None until a device wake ran; a caller that needs the
+        #: chip asserts on it rather than trusting the platform default.
         self.trace_impl: Optional[str] = None
         #: device wakes dispatched (synchronous + pipelined)
         self.device_wakes = 0
@@ -209,12 +203,11 @@ class ArrayShadowGraph:
         #: consumed it: (insert?, src, dst, kind).  ``None`` means either
         #: "no consumer yet" or "too much churn / geometry change" — the
         #: consumer does a full rebuild (which re-enables the log).  Off
-        #: by default so backends that never consume it (host array, the
-        #: XLA trace off-TPU) pay one None check per mutation instead of
+        #: by default so a backend that never consumes it (the host
+        #: array) pays one None check per mutation instead of
         #: accumulating up to ``_log_cap`` dead tuples.
         self._pair_log: Optional[List[tuple]] = None
         self._log_cap = 1 << 20
-        self._inc = None  # lazily-built IncrementalPallasLayout
         #: slots whose flags/recv changed since last consumed; enabled
         #: (non-None) by backends that mirror node features elsewhere
         #: (the mesh backend's sharded device arrays)
@@ -250,7 +243,6 @@ class ArrayShadowGraph:
         # Node capacity sets the bit-table/supertile geometry: the whole
         # Pallas layout must be rebuilt.
         self._pair_log = None
-        self._inc = None
         self._dec = None
 
     def _grow_edges(self, min_free: int = 1) -> None:
@@ -878,26 +870,9 @@ class ArrayShadowGraph:
             self._note_device_wake()
             with events.recorder.timed(events.DEVICE_TRACE) as ev:
                 ev.fields["trace_mode"] = self.trace_mode
-                if self.decremental:
-                    return _readback(
-                        self._compute_marks_decremental(ev.fields),
-                        "marks.decremental",
-                    )
-                if self.trace_impl != "xla":
-                    return _readback(
-                        self._compute_marks_pallas(ev.fields),
-                        "marks.pallas",
-                    )
                 return _readback(
-                    trace_ops.trace_marks_jax(
-                        self.flags,
-                        self.recv_count,
-                        self.supervisor,
-                        self.edge_src,
-                        self.edge_dst,
-                        self.edge_weight,
-                    ),
-                    "marks.xla",
+                    self._compute_marks_decremental(ev.fields),
+                    "marks.decremental",
                 )
         # Host path: slice to the occupancy watermark.  Slots allocate
         # lowest-first (IntStack from_range), so live slots cluster low
@@ -962,20 +937,6 @@ class ArrayShadowGraph:
         self.last_parents_mark = mark
         return mark
 
-    def _on_tpu(self) -> bool:
-        tpu = getattr(self, "_is_tpu", None)
-        if tpu is None:
-            from ...ops import pallas_trace
-
-            tpu = self._is_tpu = not pallas_trace.default_interpret()
-        return tpu
-
-    def _uses_pallas(self) -> bool:
-        """Does this backend's device trace run the Pallas kernel?  The
-        decremental wake always does (interpreted off-TPU); the full
-        retrace takes the plain XLA trace off-TPU."""
-        return self.decremental or self._on_tpu()
-
     def _note_device_wake(self) -> None:
         """Count a device wake and, on the first, record which
         implementation the platform resolved it to (``trace_impl``)."""
@@ -983,9 +944,7 @@ class ArrayShadowGraph:
         if self.trace_impl is None:
             from ...ops import pallas_trace
 
-            if not self._uses_pallas():
-                self.trace_impl = "xla"
-            elif pallas_trace.default_interpret():
+            if pallas_trace.default_interpret():
                 self.trace_impl = "pallas-interpret"
             else:
                 self.trace_impl = "pallas"
@@ -997,71 +956,15 @@ class ArrayShadowGraph:
         jump, and the per-sweep frontier decomposition, which is where
         the pull-density threshold is tuned from data
         (tools/sweep_profile.py writes the same fields)."""
-        k = int(stats["n_sweeps"])
-        fields = {"n_sweeps": k, "jump_sweeps": int(stats["jump_sweeps"])}
-        if "closure_sweeps" in stats:
-            fields["closure_sweeps"] = int(stats["closure_sweeps"])
-            fields["closure_bailed"] = int(stats["closure_bailed"])
-        k = min(k, len(stats["dirty_chunks"]))
-        for key in ("dirty_chunks", "changed_supers", "tiles_skipped",
-                    "pull_on", "jump_on"):
-            if key in stats:
-                fields["sweep_" + key] = [int(x) for x in stats[key][:k]]
+        fields = {
+            key: stats[key]
+            for key in ("n_sweeps", "jump_sweeps", "closure_sweeps",
+                        "closure_bailed")
+        }
+        for key in ("dirty_chunks", "tiles_skipped", "pull_on", "jump_on"):
+            fields["sweep_" + key] = stats[key]
         self.profile_wake.note(**fields)
         event.update(fields)
-
-    def _compute_marks_pallas(self, event: dict) -> np.ndarray:
-        """Device trace through the Pallas propagation kernel.
-
-        Layout maintenance is incremental (ops/pallas_incremental.py):
-        pair transitions recorded in ``_pair_log`` are folded into the
-        cached base+delta layout in O(changes), so a churning graph no
-        longer pays a full O(E log E) repack before every wake.  A full
-        rebuild happens only on node-capacity growth, log overflow, or
-        when accumulated churn crosses the layout's repack threshold."""
-        from ...ops import pallas_incremental
-
-        self._inc = self._sync_layout(
-            self._inc,
-            lambda: pallas_incremental.IncrementalPallasLayout(
-                self.capacity,
-                mode=self.trace_mode,
-                pull_density=self.pull_density,
-            ),
-            lambda l: l.needs_repack,
-        )
-        if self.profile_wake is not None:
-            marks, stats = self._inc.trace(
-                self.flags, self.recv_count, with_stats=True
-            )
-            self._note_sweep_stats(stats, event)
-            return marks
-        return self._inc.trace(self.flags, self.recv_count)
-
-    def _sync_layout(self, obj, make, needs_repack) -> object:
-        """The pair-log consumption state machine shared by the Pallas
-        and decremental paths: (re)build on a missing object, geometry
-        change, or log overflow (``_pair_log is None``); otherwise fold
-        the log and repack when accumulated churn crosses the layout's
-        threshold.  Returns the up-to-date object."""
-        if obj is None or self._pair_log is None:
-            if obj is None or obj.n != self.capacity:
-                obj = make()
-            obj.rebuild(
-                self.edge_src, self.edge_dst, self.edge_weight, self.supervisor
-            )
-            self._pair_log = []
-        elif self._pair_log:
-            obj.apply_log(self._pair_log)
-            self._pair_log.clear()
-            if needs_repack(obj):
-                obj.rebuild(
-                    self.edge_src,
-                    self.edge_dst,
-                    self.edge_weight,
-                    self.supervisor,
-                )
-        return obj
 
     def _compute_marks_decremental(self, event: dict) -> np.ndarray:
         """Per-wake detection through the decremental tracer
@@ -1117,7 +1020,7 @@ class ArrayShadowGraph:
 
     @property
     def can_pipeline(self) -> bool:
-        return self.use_device and self.decremental
+        return self.use_device
 
     @property
     def has_pending_wake(self) -> bool:
@@ -1126,19 +1029,36 @@ class ArrayShadowGraph:
     def _synced_dec(self):
         """The decremental tracer, synced with the pair log (the one
         construction site for both the synchronous and pipelined
-        paths)."""
+        paths): (re)built on a missing tracer, a geometry change or a
+        log overflow (``_pair_log is None``); otherwise the log is
+        folded in O(changes), and the layout repacked when accumulated
+        churn crosses its threshold."""
         from ...ops import pallas_decremental
 
-        self._dec = self._sync_layout(
-            self._dec,
-            lambda: pallas_decremental.DecrementalTracer(
-                self.capacity,
-                mode=self.trace_mode,
-                pull_density=self.pull_density,
-            ),
-            lambda d: d.layout.needs_repack,
-        )
-        return self._dec
+        dec = self._dec
+        if dec is None or self._pair_log is None:
+            if dec is None or dec.n != self.capacity:
+                dec = pallas_decremental.DecrementalTracer(
+                    self.capacity,
+                    mode=self.trace_mode,
+                    pull_density=self.pull_density,
+                )
+            dec.rebuild(
+                self.edge_src, self.edge_dst, self.edge_weight, self.supervisor
+            )
+            self._pair_log = []
+        elif self._pair_log:
+            dec.apply_log(self._pair_log)
+            self._pair_log.clear()
+            if dec.layout.needs_repack:
+                dec.rebuild(
+                    self.edge_src,
+                    self.edge_dst,
+                    self.edge_weight,
+                    self.supervisor,
+                )
+        self._dec = dec
+        return dec
 
     def _start_wake(self) -> tuple:
         """Dispatch one asynchronous wake; returns ``(handle,

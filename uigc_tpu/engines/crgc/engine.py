@@ -27,6 +27,12 @@ if TYPE_CHECKING:  # pragma: no cover
     from ...runtime.system import ActorSystem
 
 
+#: the values of ``uigc.crgc.shadow-graph`` (config.py describes each)
+SHADOW_GRAPHS = (
+    "oracle", "array", "decremental", "native", "mesh", "mesh-decremental",
+)
+
+
 class CrgcSpawnInfo(SpawnInfo):
     """(reference: CRGC.scala:22-24)"""
 
@@ -121,14 +127,13 @@ class CRGC(Engine):
             from .shadow import ShadowGraph
 
             return ShadowGraph(self.crgc_context, self.system.address)
-        elif self.shadow_graph_impl in ("array", "device", "decremental"):
+        elif self.shadow_graph_impl in ("array", "decremental"):
             from .arrays import ArrayShadowGraph
 
             return ArrayShadowGraph(
                 self.crgc_context,
                 self.system.address,
-                use_device=(self.shadow_graph_impl in ("device", "decremental")),
-                decremental=(self.shadow_graph_impl == "decremental"),
+                use_device=(self.shadow_graph_impl == "decremental"),
                 trace_mode=self.system.config.get_string("uigc.crgc.trace-mode"),
                 pull_density=self.system.config.get_float(
                     "uigc.crgc.pull-density"
@@ -151,7 +156,10 @@ class CRGC(Engine):
                     "uigc.crgc.pull-density"
                 ),
             )
-        raise ValueError(f"bad shadow-graph impl {self.shadow_graph_impl!r}")
+        raise ValueError(
+            f"bad shadow-graph impl {self.shadow_graph_impl!r}; valid: "
+            + ", ".join(SHADOW_GRAPHS)
+        )
 
     # ----------------------------------------------------------------- #
     # Root support

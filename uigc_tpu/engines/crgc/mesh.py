@@ -166,9 +166,6 @@ class MeshShadowGraph(ArrayShadowGraph):
 
         self._jit_cache: Dict[str, object] = {}
 
-    def _uses_pallas(self) -> bool:
-        return True  # every mesh trace runs the per-shard Pallas kernel
-
     @property
     def can_pipeline(self) -> bool:
         # The mesh pipelined wake overlaps host ingest with the SHARDED

@@ -7,7 +7,6 @@ from .trace import (
     FLAG_ROOT,
     garbage_and_kills_np,
     pseudoroots_np,
-    trace_marks_jax,
     trace_marks_np,
 )
 
@@ -20,6 +19,5 @@ __all__ = [
     "FLAG_ROOT",
     "garbage_and_kills_np",
     "pseudoroots_np",
-    "trace_marks_jax",
     "trace_marks_np",
 ]

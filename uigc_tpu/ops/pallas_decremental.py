@@ -5,7 +5,7 @@ propagation sweeps over a 10M-actor graph even when the wake's churn
 touched a few thousand nodes.  The reference never faces this regime (its
 collector traces ~10^4-10^5 node-local shadows per 50ms wake,
 LocalGC.scala:144-186); at BASELINE.md's 10M-actor scale the <=10ms p50
-detection target is unreachable by full re-trace (PERF_WAKE.md).  Marks
+detection target is unreachable by full re-trace.  Marks
 do not shrink monotonically under churn — releasing a ref can turn live
 actors into garbage — so a sound incremental wake must re-derive exactly
 the region whose old derivation might have depended on what changed.
@@ -56,8 +56,9 @@ scratch against that stable boundary.  Additions (new pairs, new seeds)
 ride the same repair fixpoint through the ordinary monotone machinery.
 The cold road needs no such argument: from the seeds against a zero
 table every marked source is a changed word, so the plain dirty walk IS
-the full trace (``pallas_trace._build_trace_fn_multi`` runs exactly
-that), exact by construction.  A wake that gives up costs at most
+the full trace, exact by construction: it is the one derivation from
+nothing a single chip has (:func:`derive` runs it over given layouts,
+the tracer's first wake over its own).  A wake that gives up costs at most
 (1 + ``pt.CLOSURE_SHARE``) derivations and the sweep that crossed the
 price.
 
@@ -427,7 +428,6 @@ def _build_wake_fn(
                 stats)
 
     jitted = jax.jit(wake_fn)
-    jitted.raw = wake_fn  # unjitted body, for callers composing it
     #: what ``auto`` prices one jump sweep at, in chunk walks (static)
     jitted.jump_price = auto_jump.price if use_jump else None
     return jitted
@@ -435,10 +435,7 @@ def _build_wake_fn(
 
 def get_wake_fn(n, specs, n_super, r_rows, s_rows, interpret=None,
                 mode=pt.MODE_PUSH, pull_density=pt.DEFAULT_PULL_DENSITY):
-    """Cached jitted wake fn, one per geometry and mode; its ``raw``
-    attribute is the unjitted body for callers that compose wakes inside
-    a larger program (the chained wake benchmark scans K of them in one
-    jit)."""
+    """Cached jitted wake fn, one per geometry and mode."""
     if interpret is None:
         interpret = pt.default_interpret()
     # _int8_mxu in the key: the flag is read at kernel build time, so
@@ -471,6 +468,120 @@ def get_wake_fn(n, specs, n_super, r_rows, s_rows, interpret=None,
             geom=events.compile_geom(key), hit=True,
         )
     return fn
+
+
+def wake_fn_for(preps, interpret, mode, pull_density):
+    """The wake program for the geometry ``preps`` have (the first, a
+    packed layout, pins it)."""
+    first = preps[0]
+    return get_wake_fn(
+        first["n"],
+        tuple(pt.layout_spec(p) for p in preps),
+        first["n_super"],
+        first["r_rows"],
+        first["s_rows"],
+        interpret,
+        mode=mode,
+        pull_density=pull_density,
+    )
+
+
+def no_previous_state(r_rows: int) -> tuple:
+    """The previous state of a wake that has none: five zero word tables
+    and zero walks.  Every previous mark is gone, so everything must
+    re-derive, which the wake does on its cold road (empty suspects, no
+    gate, the seeds' chunks as the dirty set)."""
+    import jax
+
+    z = jax.device_put(np.zeros((r_rows, pt.LANE), np.int32))
+    return (z, z, z, z, z, jax.device_put(np.zeros((), np.int32)))
+
+
+def _host_stats(host: dict) -> dict:
+    """One wake's counters, read back, as :meth:`DecrementalTracer.wake_stats`
+    and :func:`derive` return them."""
+    k = min(int(host["n_sweeps"]), pt.MAX_SWEEP_STATS)
+    return {
+        "closure_sweeps": int(host["closure_sweeps"]),
+        "closure_bailed": int(host["closure_bailed"]),
+        "closure_spent": int(host["closure_spent"]),
+        "gated_tiles": int(host["gated_tiles"]),
+        "n_sweeps": int(host["n_sweeps"]),
+        "dirty_chunks": host["dirty_chunks"][:k].tolist(),
+        "tiles_skipped": host["tiles_skipped"][:k].tolist(),
+        "pull_on": host["pull_on"][:k].tolist(),
+        "jump_sweeps": int(host["jump_sweeps"]),
+        "jump_on": host["jump_on"][:k].tolist(),
+        "jump_spent": int(host["jump_spent"]),
+    }
+
+
+def derivation(flags, recv_count, preps, interpret=None, mode=pt.MODE_PUSH,
+               pull_density=pt.DEFAULT_PULL_DENSITY, jump_parent=None):
+    """A derivation from nothing over these layouts, as (fn, args): the
+    wake program for their geometry and its operands with no suspects and
+    no previous state, so that ``fn(*args)`` runs the cold road, as a
+    tracer's first wake does.
+
+    The layouts share a node space; their contributions are combined
+    before thresholding, so the union of their pairs propagates.  The
+    first must be a packed (non-xla) one; it pins the geometry.  ``mode``
+    jump/auto requires ``jump_parent``, the (n + 1,) min-source parent
+    array over the SAME live pair set the layouts hold
+    (``pt.jump_parents`` / ``IncrementalPallasLayout.jump_parent``): a
+    stale parent crossing a deleted pair would carry marks along a dead
+    edge."""
+    first = preps[0]
+    n = first["n"]
+    require(
+        "xla_src" not in first, "trace.layouts",
+        "the first layout pins the packed geometry",
+    )
+    for p in preps[1:]:
+        require(
+            p["n"] == n
+            and (
+                "xla_src" in p
+                or all(
+                    p[k] == first[k]
+                    for k in ("n_super", "r_rows", "s_rows", "sub", "group")
+                )
+            ),
+            "trace.layouts", "layouts must share node space and geometry",
+        )
+    fn = wake_fn_for(preps, interpret, mode, pull_density)
+    state = no_previous_state(first["r_rows"])
+    no_suspects = state[0]  # a zero word table: nothing deleted, nothing fresh
+    args = [flags[:n], recv_count[:n], no_suspects, no_suspects, *state]
+    if mode in (pt.MODE_JUMP, pt.MODE_AUTO):
+        require(
+            jump_parent is not None, "trace.jump_parent",
+            "jump modes need the parent array", mode=mode,
+        )
+        args.append(jump_parent)
+    for p in preps:
+        args.extend(pt.device_args(p))
+    return fn, args
+
+
+def derive(flags, recv_count, preps, interpret=None, mode=pt.MODE_PUSH,
+           pull_density=pt.DEFAULT_PULL_DENSITY, jump_parent=None):
+    """Marks of this graph from nothing over these layouts
+    (:func:`derivation`, run).  Returns (marks, stats): the oracle's
+    (n,) bool vector and the wake's counters as
+    :meth:`DecrementalTracer.wake_stats` gives them."""
+    import jax
+    import jax.numpy as jnp
+
+    fn, args = derivation(
+        flags, recv_count, preps, interpret, mode, pull_density, jump_parent
+    )
+    mark_w, *_, stats = fn(*args)
+    marks = pt.unpack_table(mark_w, preps[0]["n"], jnp)
+    return (
+        np.asarray(marks),  # readback: host boundary: device marks -> np result contract
+        _host_stats(jax.device_get(stats)),  # readback: a few hundred bytes of counters, the result contract
+    )
 
 
 class DecrementalTracer:
@@ -586,29 +697,14 @@ class DecrementalTracer:
         words uploaded.  Returns what :meth:`wake_device` takes as
         ``staged``; a caller that times upload and run apart (the
         ``decremental`` backend) calls it first."""
-        import jax
-
         preps, args = self.layout.prepare_device_wake()
-        first = preps[0]
-        r_rows = first["r_rows"]
-        fn = get_wake_fn(
-            self.n,
-            tuple(pt.layout_spec(p) for p in preps),
-            first["n_super"],
-            r_rows,
-            first["s_rows"],
-            self.interpret,
-            mode=self.layout.mode,
-            pull_density=self.layout.pull_density,
+        r_rows = preps[0]["r_rows"]
+        fn = wake_fn_for(
+            preps, self.interpret, self.layout.mode, self.layout.pull_density
         )
         if self._mark_w is None or self._mark_w.shape[0] != r_rows:
-            z = jax.device_put(np.zeros((r_rows, pt.LANE), np.int32))
-            self._mark_w = self._seed_w = self._halted_w = z
-            self._iu_w = self._table = z
-            self._walks = jax.device_put(np.zeros((), np.int32))
-            # every previous mark is gone: everything must re-derive,
-            # which the zero prev-state does on the cold road (empty
-            # suspects, no gate, the seeds' chunks as the dirty set)
+            (self._mark_w, self._seed_w, self._halted_w, self._iu_w,
+             self._table, self._walks) = no_previous_state(r_rows)
         del_w = self._id_words(self._pending_del_dst, r_rows)
         fresh_w = self._id_words(self._pending_fresh_dst, r_rows)
         self._wake_fn = fn
@@ -662,23 +758,10 @@ class DecrementalTracer:
         kept = list(self._stats)
         if last_n is not None:
             kept = kept[max(0, len(kept) - last_n):]
-        out = []
-        for host in jax.device_get(kept):  # readback: a few hundred bytes of counters per wake, on request
-            k = min(int(host["n_sweeps"]), pt.MAX_SWEEP_STATS)
-            out.append({
-                "closure_sweeps": int(host["closure_sweeps"]),
-                "closure_bailed": int(host["closure_bailed"]),
-                "closure_spent": int(host["closure_spent"]),
-                "gated_tiles": int(host["gated_tiles"]),
-                "n_sweeps": int(host["n_sweeps"]),
-                "dirty_chunks": host["dirty_chunks"][:k].tolist(),
-                "tiles_skipped": host["tiles_skipped"][:k].tolist(),
-                "pull_on": host["pull_on"][:k].tolist(),
-                "jump_sweeps": int(host["jump_sweeps"]),
-                "jump_on": host["jump_on"][:k].tolist(),
-                "jump_spent": int(host["jump_spent"]),
-            })
-        return out
+        return [
+            _host_stats(host)
+            for host in jax.device_get(kept)  # readback: a few hundred bytes of counters per wake, on request
+        ]
 
     def invalidate(self) -> None:
         """Drop the previous-fixpoint device state (after a failed or

@@ -29,7 +29,7 @@ This module keeps the full pack off the per-wake path with three tiers:
 Per-wake maintenance is therefore O(changes since last wake), plus an
 amortized freeze/consolidate.  The trace launches the propagation
 kernel once per packed tier and combines all contributions before
-thresholding (pallas_trace.trace_marks_layouts), which is equivalent to
+thresholding (pallas_trace.build_sweep_contribs), which is equivalent to
 one layout holding the union of the pairs.
 
 Pairs are keyed (src, dst, kind) where kind distinguishes refob edges
@@ -98,7 +98,6 @@ class IncrementalPallasLayout:
         self.min_repack = min_repack
         self.freeze_threshold = freeze_threshold
         self.max_frozen = max_frozen
-        self.interpret = interpret
         self.base: Optional[Dict[str, np.ndarray]] = None
         #: packed (src, dst, kind) key -> packed (row << 8 | col) into the
         #: base row_pos/emeta.  Sorted numpy bulk + churn overlays, so a
@@ -134,7 +133,7 @@ class IncrementalPallasLayout:
             "pack_s": 0.0,
             "anomalies": 0,
         }
-        #: device-resident mirrors (trace_device): mirror token -> dict of
+        #: device-resident mirrors (_device_args): mirror token -> dict of
         #: device arrays; plus per-prep masked-slot write queues so the
         #: mirror syncs in O(churn) instead of re-uploading the layout.
         #: Tokens are monotonically assigned and stamped into the prep
@@ -450,10 +449,10 @@ class IncrementalPallasLayout:
     def prepare_wake(self) -> list:
         """The per-wake layout maintenance: freeze an overflowing live
         tier, consolidate an overlong frozen chain, and materialize the
-        tier list for this trace.  Split out from :meth:`trace` so its
-        host cost can be measured without launching the kernel
-        (tools/pack_bench.py)."""
-        assert self.base is not None, "rebuild() before trace()"
+        tier list for this trace.  Apart from the device-operand
+        assembly so its host cost can be measured without launching the
+        kernel (tools/pack_bench.py)."""
+        assert self.base is not None, "rebuild() before a wake"
         if len(self.pending) > self.freeze_threshold:
             self._freeze_pending()
         if len(self.frozen) > self.max_frozen:
@@ -467,17 +466,8 @@ class IncrementalPallasLayout:
             preps.append(pt.xla_tier(psrc, pdst, self.n, self._xla_cap))
         return preps
 
-    def trace(self, flags, recv_count, with_stats: bool = False):
-        preps = self.prepare_wake()
-        return pt.trace_marks_layouts(
-            flags, recv_count, preps, interpret=self.interpret,
-            mode=self.mode, pull_density=self.pull_density,
-            jump_parent=self.jump_parent if self.use_jump else None,
-            with_stats=with_stats,
-        )
-
     # ----------------------------------------------------------------- #
-    # Device-resident trace (steady-state wake path on real hardware)
+    # Device-resident operands (steady-state wake path on real hardware)
     # ----------------------------------------------------------------- #
 
     def _device_args(self, prep) -> list:
@@ -574,8 +564,8 @@ class IncrementalPallasLayout:
 
     def prepare_device_wake(self):
         """prepare_wake + device-operand assembly + mirror GC: the
-        device-resident wake entry shared by :meth:`trace_device` and the
-        decremental tracer (ops/pallas_decremental.py).  Returns
+        device-resident wake entry of the decremental tracer
+        (ops/pallas_decremental.py).  Returns
         (preps, args) with the jump-parent mirror leading ``args`` for
         jump/auto-mode layouts."""
         preps = self.prepare_wake()
@@ -592,22 +582,3 @@ class IncrementalPallasLayout:
                 del self._dev_mirror[pid]
                 self._dev_writes.pop(pid, None)
         return preps, args
-
-    def trace_device(self, flags_dev, recv_dev):
-        """Like :meth:`trace`, but every packed layout's operand arrays
-        stay device-resident between wakes (the reference's steady state:
-        LocalGC.scala:144-186 never re-ships its graph per wake) and the
-        mark vector is returned as a device array, so callers can reduce
-        garbage counts/ids on device instead of pulling 10M bools."""
-        preps, args = self.prepare_device_wake()
-        fn = pt.get_trace_fn_multi(
-            self.n,
-            tuple(pt.layout_spec(p) for p in preps),
-            preps[0]["n_super"],
-            preps[0]["r_rows"],
-            preps[0]["s_rows"],
-            self.interpret,
-            mode=self.mode,
-            pull_density=self.pull_density,
-        )
-        return fn(flags_dev, recv_dev, *args)

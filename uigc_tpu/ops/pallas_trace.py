@@ -64,7 +64,6 @@ import numpy as np
 
 from . import trace as trace_ops
 from ..utils import events
-from ..utils.validation import require
 
 LANE = 128  # lanes per vreg row
 ROWS = 8  # sublane rows per edge-slot sub-block (slot row = src row mod 8)
@@ -166,7 +165,7 @@ CLOSURE_SHARE = 1 / 8
 #: 6, PR 30).  Four walks are under the share wherever a derivation is
 #: over 32, so the 10M geometry never sees the floor.
 CLOSURE_MIN_WALKS = 4
-#: per-sweep stat ring length for with_stats builds (sweeps beyond this
+#: per-sweep stat ring length of the wake's counters (sweeps beyond this
 #: fold into the last slot; fixpoints run ~4-12 sweeps)
 MAX_SWEEP_STATS = 32
 
@@ -364,7 +363,7 @@ def _build_parents_fn():
 def marking_parents_jax(flags, recv_count, supervisor, edge_src, edge_dst,
                         edge_weight):
     """Device (XLA) mark fixpoint with marking-parent capture.  Same
-    mark contract as ``trace_ops.trace_marks_jax``; additionally returns
+    mark contract as ``trace_ops.trace_marks_np``; additionally returns
     ``parent`` (int32[n], -1 = pseudoroot seed or unmarked, else the
     minimum source whose propagation first marked the slot) — matching
     ``trace_ops.trace_marks_np_parents`` exactly, which is the parity
@@ -432,7 +431,7 @@ def kernel_slots(specs) -> int:
 def auto_jump_policy(n: int, n_slots: int, n_chunks: int, pull_cut: int,
                      steps: int = JUMP_STEPS):
     """When ``trace-mode: auto`` jumps: the one statement of it, shared
-    by the four fixpoints (full trace, wake, and their sharded forms) and
+    by the three fixpoints (the wake, and the sharded trace and wake) and
     by ``tools/sweep_profile.py --simulate``.
 
     Returns ``decide(engaged, spent, n_dirty) -> (engaged, spent)``,
@@ -546,32 +545,6 @@ def saturated_tiles(mark_w, iu_w, n_super, sup_words, jnp):
         ).astype(jnp.int32)
 
 
-def hier_dirty_lists(table, table_prev, n_chunks, group_rows, n_super,
-                     sup_words, jnp):
-    """The hierarchical frontier: per-supertile summary bits above the
-    walk-chunk dirty lists.
-
-    Level 1 (coarse, destination space): one summary bit per supertile —
-    did any of its words change this sweep.  Feeds the pull gates (a
-    tile's saturation can only flip where its summary fired, so the
-    per-sweep saturation update is masked to the changed tiles and the
-    rest carry over) and the frontier-density stats.
-    Level 2 (fine, source space): the existing compacted dirty-chunk
-    prefix/list the kernels walk (``dirty_group_lists``) — the word
-    diff is shared between both levels (XLA CSEs the duplicate
-    comparison inside one trace).
-
-    Returns (d, l, changed, super_changed) with d/l/changed exactly as
-    ``dirty_group_lists`` produces them."""
-    d, l, changed = dirty_group_lists(table, table_prev, n_chunks,
-                                      group_rows, jnp)
-    flat = (table != table_prev).reshape(-1)[: n_super * sup_words]
-    super_changed = flat.reshape(n_super, sup_words).any(axis=1).astype(
-        jnp.int32
-    )
-    return d, l, changed, super_changed
-
-
 def _int8_mxu() -> bool:
     """UIGC_KERNEL_INT8=1 runs the one-hot contraction in int8 with
     int32 accumulation (A and B are 0/1, so it is exact) — on chips
@@ -636,8 +609,8 @@ def dirty_group_lists(table, table_prev, n_chunks, group_rows, jnp):
 
 def pack_hits_table(hits2d, r_rows, jnp):
     """pack_hits_words padded and reshaped into the (r_rows, LANE) word
-    table — the exact per-sweep pack on the fixpoint path (trace_fn's
-    pack2d) and the expression benchmark probes must time."""
+    table — the exact per-sweep pack on the fixpoint path and the
+    expression benchmark probes must time."""
     with scope("hits"):
         flat = pack_hits_words(hits2d, jnp)
         flat = jnp.concatenate(
@@ -655,8 +628,8 @@ def unpack_table(words, n, jnp):
 
 
 def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
-    """The per-layout propagation sweep shared by the full trace and the
-    decremental wake: returns fn(table, d, l, layout_args, gate) -> hits
+    """The per-layout propagation sweep of the decremental wake's two
+    fixpoints: returns fn(table, d, l, layout_args, gate) -> hits
     plane (t_rows, LANE) bool.
 
     ``propagates`` holds one kernel per packed spec (None for xla
@@ -1117,12 +1090,18 @@ def xla_tier(psrc, pdst, n: int, capacity: int) -> Dict[str, np.ndarray]:
     return {"xla_src": src, "xla_dst": dst, "capacity": capacity, "n": n}
 
 
-_fn_cache: Dict[tuple, object] = {}
-
-
 def layout_spec(prep: Dict[str, np.ndarray]) -> tuple:
-    """The static shape signature of a packed layout (kernel cache key
-    component)."""
+    """The static shape signature of a layout (kernel cache key
+    component), one of:
+      ("dense", n_blocks, sub, group)   — full layout, every supertile
+      ("compact", n_blocks, out_tiles, sub, group) — only touched
+        supertiles; the kernel output is scattered into the global
+        contribution by the layout's ``super_ids`` operand
+      ("xla", capacity)                 — raw pair arrays propagated by
+        an XLA scatter-max; O(capacity) per iteration but zero pack and
+        zero recompile cost, the landing tier for the newest churn
+    Packed layouts sharing a trace must share (sub, group): the walk
+    geometry fixes the dirty-list granularity."""
     if "xla_src" in prep:
         return ("xla", prep["capacity"])
     if "out_supers" in prep:
@@ -1140,8 +1119,7 @@ def build_layout_propagates(
     specs, n_super, r_rows, s_rows, interpret, dst_gate=False
 ):
     """One propagation kernel per packed layout spec (None for xla
-    tiers) — the builder loop shared by the full trace and the
-    decremental wake."""
+    tiers), for the decremental wake."""
     out = []
     for spec in specs:
         if spec[0] == "dense":
@@ -1374,234 +1352,6 @@ def build_propagate(
     )
 
 
-def _build_trace_fn_multi(
-    n: int,
-    specs: tuple,
-    n_super: int,
-    r_rows: int,
-    s_rows: int,
-    interpret: bool,
-    mode: str = MODE_PUSH,
-    pull_density: float = DEFAULT_PULL_DENSITY,
-    with_stats: bool = False,
-):
-    """Trace fn over one or more pair layouts sharing a node space.
-
-    ``specs`` holds one static shape signature per layout:
-      ("dense", n_blocks, sub, group)   — full layout, every supertile
-      ("compact", n_blocks, out_tiles, sub, group) — only touched
-        supertiles; the kernel output is scattered into the global
-        contribution by the layout's ``super_ids`` operand
-      ("xla", capacity)                 — raw pair arrays propagated by
-        an XLA scatter-max; O(capacity) per iteration but zero pack and
-        zero recompile cost, the landing tier for the newest churn
-    Packed layouts sharing a trace must share (sub, group): the walk
-    geometry fixes the dirty-list granularity.
-
-    Each layout contributes per fixpoint iteration; contributions are
-    combined *before* thresholding, so the result is identical to a
-    single layout holding the union of the pairs.  This is what lets a
-    churning graph keep a big, static "base" layout plus small delta
-    tiers (ops/pallas_incremental) instead of re-packing everything.
-
-    ``mode`` selects the propagation strategy (module MODE_* docs); jump
-    and auto modes take a jump-parent operand right after flags/recv.
-    ``with_stats`` returns (marks, stats) where stats carries the sweep
-    count, the sweeps that ran the jump, and the per-sweep frontier
-    decomposition (dirty chunks, changed supertiles, tiles skipped,
-    pull-gate and jump decisions) for the profiler."""
-    import jax
-    import jax.numpy as jnp
-
-    F = trace_ops
-    require(
-        mode in TRACE_MODES, "config.trace_mode",
-        "bad trace mode", mode=mode, valid=TRACE_MODES,
-    )
-    use_jump = mode in (MODE_JUMP, MODE_AUTO)
-    use_pull = mode in (MODE_PULL, MODE_AUTO)
-
-    geoms = {spec[-2:] for spec in specs if spec[0] != "xla"}
-    assert len(geoms) == 1, "packed layouts must share (sub, group)"
-    ((_, group),) = geoms
-    group_rows = ROWS * group
-
-    propagates = build_layout_propagates(
-        specs, n_super, r_rows, s_rows, interpret, dst_gate=use_pull
-    )
-
-    n_words_pad = r_rows * LANE
-    n_chunks = r_rows // group_rows  # dirty granularity = one walk group
-    n_pad_nodes = n_super * s_rows * LANE  # contrib coverage, >= n
-    t_rows = n_super * s_rows  # contrib rows (128 nodes each)
-    sup_words = s_rows * (LANE // WORD_BITS)  # words per supertile
-    # AUTO's per-sweep pull decision, in dirty-chunk counts
-    pull_cut = max(1, int(round(pull_density * n_chunks)))
-    # ... and its per-sweep jump decision
-    auto_jump = auto_jump_policy(
-        n, kernel_slots(specs), n_chunks, pull_cut
-    )
-
-    def trace_fn(flags, recv_count, *rest):
-        if use_jump:
-            jump_j0, *layout_args = rest
-        else:
-            jump_j0, layout_args = None, rest
-        in_use = (flags & F.FLAG_IN_USE) != 0
-        halted = (flags & F.FLAG_HALTED) != 0
-        seed = (
-            ((flags & F.FLAG_ROOT) != 0)
-            | ((flags & F.FLAG_BUSY) != 0)
-            | (recv_count != 0)
-            | ((flags & F.FLAG_INTERNED) == 0)
-        )
-        mark0 = in_use & (~halted) & seed
-
-        def pack(active):
-            return pack_bools(active, n, r_rows, jnp)
-
-        def pack2d(hits2d):
-            """Per-sweep word-space pack of the (t_rows, LANE) hits
-            plane: O(n/32) output instead of the O(n) scatter+shift
-            repack of the bool-space pack."""
-            return pack_hits_table(hits2d, r_rows, jnp)
-
-        def unpack(words):
-            return unpack_table(words, n, jnp)
-
-        def dirty_chunks(table, table_prev):
-            return hier_dirty_lists(
-                table, table_prev, n_chunks, group_rows, n_super,
-                sup_words, jnp,
-            )
-
-        def cond(carry):
-            return carry["changed"]
-
-        sweep = build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp)
-
-        # Gate tables: in_use bits (mark gating) and ~halted bits
-        # (propagation gating).  pack() only sets bits < n, so padding
-        # bits stay 0 in both.
-        iu_w = pack(in_use)
-        nh_w = pack(~halted)
-        trans_w = iu_w & nh_w  # jump-transparent intermediates
-
-        # The level-1 summary is carried only when something consumes
-        # it: the pull gates (masked saturation update) or the stats.
-        track_super = use_pull or with_stats
-
-        def run_jump(mark_w, table, jump_j):
-            jh, jump_j = jump_sweep(table, jump_j, trans_w, n, jnp)
-            return mark_w | (pack(jh) & iu_w), jump_j
-
-        def body(carry):
-            mark_w, table = carry["mark"], carry["table"]
-            d, l = carry["d"], carry["l"]
-            n_dirty = d[n_chunks]
-            if use_pull:
-                # Destination-side pull gates: marks grow monotonically
-                # within one fixpoint so saturation only latches on,
-                # and a tile can only flip where the level-1 summary
-                # fired last sweep — the update is masked to those
-                # tiles, the rest carry over.
-                sat = jnp.where(
-                    carry["sup_ch"] > 0,
-                    saturated_tiles(mark_w, iu_w, n_super, sup_words,
-                                    jnp),
-                    carry["sat"],
-                )
-                if mode == MODE_AUTO:
-                    pull_on = n_dirty >= pull_cut
-                else:
-                    pull_on = jnp.array(True)
-                gate = jnp.where(pull_on, sat * GATE_SKIP,
-                                 jnp.zeros_like(sat))
-            else:
-                sat = None
-                pull_on = jnp.array(False)
-                gate = None
-            hits2d = sweep(table, d, l, layout_args, gate=gate)
-            hit_w = pack2d(hits2d)
-            new_mark_w = mark_w | (hit_w & iu_w)
-            jump_on = jnp.array(False)
-            if use_jump:
-                new_mark_w, jump_j, jump_state = jump_step(
-                    mode, auto_jump, carry["jump_state"], n_dirty,
-                    run_jump, new_mark_w, table, carry["jump"],
-                )
-                jump_on = jump_state[0]
-            new_table = new_mark_w & nh_w
-            d2, l2, changed, sup_ch2 = dirty_chunks(new_table, table)
-            out = dict(carry, mark=new_mark_w, table=new_table, d=d2,
-                       l=l2, changed=changed)
-            if track_super:
-                out["sup_ch"] = sup_ch2
-            if use_pull:
-                out["sat"] = sat
-            if use_jump:
-                out.update(jump=jump_j, jump_state=jump_state)
-            if with_stats:
-                i = jnp.minimum(carry["sweep_i"], MAX_SWEEP_STATS - 1)
-                out["st_jump"] = carry["st_jump"].at[i].set(
-                    jump_on.astype(jnp.int32)
-                )
-                out["jump_sweeps"] = carry["jump_sweeps"] + jump_on
-                out["sweep_i"] = carry["sweep_i"] + 1
-                out["st_dirty"] = carry["st_dirty"].at[i].set(n_dirty)
-                out["st_super"] = carry["st_super"].at[i].set(
-                    carry["sup_ch"].sum()
-                )
-                if use_pull:
-                    out["st_skip"] = carry["st_skip"].at[i].set(
-                        jnp.where(pull_on, sat.sum(), 0)
-                    )
-                    out["st_pull"] = carry["st_pull"].at[i].set(
-                        pull_on.astype(jnp.int32)
-                    )
-            return out
-
-        mark_w0 = pack(mark0)
-        table0 = mark_w0 & nh_w
-        d0, l0, changed0, sup_ch0 = dirty_chunks(
-            table0, jnp.zeros_like(table0)
-        )
-        carry0 = {"mark": mark_w0, "table": table0, "d": d0, "l": l0,
-                  "changed": changed0}
-        if track_super:
-            carry0["sup_ch"] = sup_ch0
-        if use_pull:
-            carry0["sat"] = saturated_tiles(
-                mark_w0, iu_w, n_super, sup_words, jnp
-            )
-        if use_jump:
-            carry0.update(jump=jump_j0.astype(jnp.int32),
-                          jump_state=jump_state0(mode, jnp))
-        if with_stats:
-            zero_stats = jnp.zeros((MAX_SWEEP_STATS,), jnp.int32)
-            carry0.update(
-                sweep_i=jnp.zeros((), jnp.int32), st_dirty=zero_stats,
-                st_super=zero_stats, st_skip=zero_stats,
-                st_pull=zero_stats, st_jump=zero_stats,
-                jump_sweeps=jnp.zeros((), jnp.int32),
-            )
-        out = jax.lax.while_loop(cond, body, carry0)
-        if not with_stats:
-            return unpack(out["mark"])
-        stats = {
-            "n_sweeps": out["sweep_i"],
-            "dirty_chunks": out["st_dirty"],
-            "changed_supers": out["st_super"],
-            "tiles_skipped": out["st_skip"],
-            "pull_on": out["st_pull"],
-            "jump_sweeps": out["jump_sweeps"],
-            "jump_on": out["st_jump"],
-        }
-        return unpack(out["mark"]), stats
-
-    return jax.jit(trace_fn)
-
-
 def default_interpret() -> bool:
     """Whether a kernel built without an explicit ``interpret`` runs in
     Pallas interpret mode: False on a TPU (Mosaic compiles it), True on
@@ -1613,164 +1363,3 @@ def default_interpret() -> bool:
     from ..utils.platform import is_tpu_platform
 
     return not is_tpu_platform(jax.devices()[0].platform)
-
-
-def get_trace_fn(
-    prep: Dict[str, np.ndarray],
-    interpret: bool | None = None,
-    mode: str = MODE_PUSH,
-    pull_density: float = DEFAULT_PULL_DENSITY,
-    with_stats: bool = False,
-):
-    """Cached jitted trace fn for a prepared pair-array layout."""
-    return get_trace_fn_multi(
-        prep["n"],
-        (layout_spec(prep),),
-        prep["n_super"],
-        prep["r_rows"],
-        prep["s_rows"],
-        interpret,
-        mode=mode,
-        pull_density=pull_density,
-        with_stats=with_stats,
-    )
-
-
-def get_trace_fn_multi(
-    n: int,
-    specs: tuple,
-    n_super: int,
-    r_rows: int,
-    s_rows: int,
-    interpret: bool | None = None,
-    mode: str = MODE_PUSH,
-    pull_density: float = DEFAULT_PULL_DENSITY,
-    with_stats: bool = False,
-):
-    """Cached jitted trace fn over one or more pair layouts (operand
-    arrays per layout in ``device_args`` order, appended after
-    flags/recv — and, for jump/auto modes, after the jump-parent
-    operand)."""
-    if interpret is None:
-        interpret = default_interpret()
-    key = (
-        n, tuple(specs), n_super, r_rows, s_rows, interpret, _int8_mxu(),
-        mode, pull_density, with_stats,
-    )
-    fn = _fn_cache.get(key)
-    if fn is None:
-        import time as _time
-
-        t0 = _time.perf_counter()
-        fn = _build_trace_fn_multi(
-            n, tuple(specs), n_super, r_rows, s_rows, interpret,
-            mode=mode, pull_density=pull_density, with_stats=with_stats,
-        )
-        _fn_cache[key] = fn
-        if events.recorder.enabled:
-            # Compile-cache plane (telemetry/device.py): per-wake misses
-            # of one (tag, geom) stream are the recompile_storm input.
-            events.recorder.commit(
-                events.COMPILE, duration_s=_time.perf_counter() - t0,
-                tag="trace_fn", geom=events.compile_geom(key), hit=False,
-            )
-    elif events.recorder.enabled:
-        events.recorder.commit(
-            events.COMPILE, tag="trace_fn",
-            geom=events.compile_geom(key), hit=True,
-        )
-    return fn
-
-
-def trace_marks_prepared(flags, recv_count, prep: Dict[str, np.ndarray]) -> np.ndarray:
-    """Run the Pallas-backed trace against pre-packed pair arrays."""
-    return trace_marks_layouts(flags, recv_count, [prep])
-
-
-def trace_marks_layouts(
-    flags,
-    recv_count,
-    preps,
-    interpret: bool | None = None,
-    mode: str = MODE_PUSH,
-    pull_density: float = DEFAULT_PULL_DENSITY,
-    jump_parent: np.ndarray | None = None,
-    with_stats: bool = False,
-):
-    """Run the Pallas-backed trace against one or more pair layouts that
-    share a node space (their per-node contributions are combined before
-    thresholding, so the union of the layouts' pairs propagates).  The
-    first layout must be a packed (non-xla) one; it pins the geometry.
-
-    ``mode`` jump/auto requires ``jump_parent`` — the (n + 1,) min-source
-    parent array over the SAME live pair set the layouts hold
-    (jump_parents / IncrementalPallasLayout.jump_parent); a stale parent
-    crossing a deleted pair would propagate marks along a dead edge."""
-    first = preps[0]
-    n = first["n"]
-    assert "xla_src" not in first, "first layout pins the packed geometry"
-    for p in preps[1:]:
-        assert p["n"] == n, "layouts must share the node space"
-        if "xla_src" not in p:
-            assert (
-                p["n_super"] == first["n_super"]
-                and p["r_rows"] == first["r_rows"]
-                and p["s_rows"] == first["s_rows"]
-                and p["sub"] == first["sub"]
-                and p["group"] == first["group"]
-            ), "layouts must share node-space geometry"
-    fn = get_trace_fn_multi(
-        n,
-        tuple(layout_spec(p) for p in preps),
-        first["n_super"],
-        first["r_rows"],
-        first["s_rows"],
-        interpret,
-        mode=mode,
-        pull_density=pull_density,
-        with_stats=with_stats,
-    )
-    args = []
-    if mode in (MODE_JUMP, MODE_AUTO):
-        require(
-            jump_parent is not None, "trace.jump_parent",
-            "jump modes need the parent array", mode=mode,
-        )
-        args.append(jump_parent)
-    for p in preps:
-        args.extend(device_args(p))
-    out = fn(flags[:n], recv_count[:n], *args)
-    if with_stats:
-        marks, stats = out
-        return np.asarray(marks), {  # readback: host boundary: device marks -> np result contract
-            k: np.asarray(v) for k, v in stats.items()  # readback: host boundary: device stats -> np result contract
-        }
-    return np.asarray(out)  # readback: host boundary: device marks -> np result contract
-
-
-def trace_marks_pallas(
-    flags, recv_count, supervisor, edge_src, edge_dst, edge_weight,
-    mode: str = MODE_PUSH,
-) -> np.ndarray:
-    """Same contract as trace_marks_np/_jax, Pallas propagation inside."""
-    n = flags.shape[0]
-    prep = prepare_chunks(
-        np.asarray(edge_src),  # readback: host-side graph layout prep (inputs are host arrays)
-        np.asarray(edge_dst),  # readback: host-side graph layout prep (inputs are host arrays)
-        np.asarray(edge_weight),  # readback: host-side graph layout prep (inputs are host arrays)
-        np.asarray(supervisor),  # readback: host-side graph layout prep (inputs are host arrays)
-        n,
-    )
-    jp = None
-    if mode in (MODE_JUMP, MODE_AUTO):
-        jp = jump_parents_from_graph(
-            np.asarray(edge_src),  # readback: host-side jump-parent prep (inputs are host arrays)
-            np.asarray(edge_dst),  # readback: host-side jump-parent prep (inputs are host arrays)
-            np.asarray(edge_weight),  # readback: host-side jump-parent prep (inputs are host arrays)
-            np.asarray(supervisor),  # readback: host-side jump-parent prep (inputs are host arrays)
-            n,
-        )
-    return trace_marks_layouts(
-        np.asarray(flags), np.asarray(recv_count), [prep], mode=mode,  # readback: host-side layout prep (inputs are host arrays)
-        jump_parent=jp,
-    )

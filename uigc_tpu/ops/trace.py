@@ -20,10 +20,9 @@ Semantics must match the oracle exactly:
 - supervisors of marked, non-halted actors are marked
   (reference: ShadowGraph.java:242-267)
 
-Two implementations with identical semantics: numpy (host fallback and
-oracle for differential tests) and JAX (jit-compiled; static shapes, so
-buffers are padded to capacity and recompiles happen only on capacity
-doubling).
+This module is the numpy form: the host backend's trace and the oracle
+of the differential tests.  The device form is the decremental wake
+(ops/pallas_decremental.py), over the same flag bits.
 """
 
 from __future__ import annotations
@@ -138,78 +137,6 @@ def trace_marks_np_parents(
             return mark, parent
         parent[newly] = cand[newly]
         mark = mark | newly
-
-
-# --------------------------------------------------------------------- #
-# JAX implementation
-# --------------------------------------------------------------------- #
-
-_jax_trace_cache = {}
-
-
-def _build_jax_trace():
-    import jax
-    import jax.numpy as jnp
-
-    def trace_marks(flags, recv_count, supervisor, edge_src, edge_dst, edge_weight):
-        n = flags.shape[0]
-        in_use = (flags & FLAG_IN_USE) != 0
-        halted = (flags & FLAG_HALTED) != 0
-        seed = (
-            ((flags & FLAG_ROOT) != 0)
-            | ((flags & FLAG_BUSY) != 0)
-            | (recv_count != 0)
-            | ((flags & FLAG_INTERNED) == 0)
-        )
-        mark0 = in_use & (~halted) & seed
-
-        live_edge = edge_weight > 0
-        # Free/dead edges scatter into a sink slot (index n).
-        edst = jnp.where(live_edge, edge_dst, n)
-        esrc = jnp.where(live_edge, edge_src, n)
-        sup_dst = jnp.where(supervisor >= 0, supervisor, n)
-
-        def cond(carry):
-            mark, changed = carry
-            return changed
-
-        def body(carry):
-            mark, _ = carry
-            active = mark & (~halted)
-            active_pad = jnp.concatenate([active, jnp.zeros((1,), bool)])
-            # Edge propagation via scatter-max of the source's active bit.
-            src_active = active_pad[esrc]
-            prop = (
-                jnp.zeros((n + 1,), dtype=jnp.int32)
-                .at[edst]
-                .max(src_active.astype(jnp.int32))
-            )
-            # Supervisor marking.
-            prop = prop.at[sup_dst].max(active.astype(jnp.int32))
-            new_mark = mark | (prop[:n] > 0)
-            new_mark = new_mark & in_use
-            changed = jnp.any(new_mark != mark)
-            return new_mark, changed
-
-        mark, _ = jax.lax.while_loop(cond, body, (mark0, jnp.array(True)))
-        return mark
-
-    return jax.jit(trace_marks)
-
-
-def trace_marks_jax(
-    flags, recv_count, supervisor, edge_src, edge_dst, edge_weight
-):
-    """Device (JAX) mark fixpoint.  Same contract as :func:`trace_marks_np`.
-    Shapes are static; pad buffers to capacity and keep capacity stable to
-    avoid recompiles."""
-    if "fn" not in _jax_trace_cache:
-        _jax_trace_cache["fn"] = _build_jax_trace()
-    fn = _jax_trace_cache["fn"]
-    import numpy as _np
-
-    out = fn(flags, recv_count, supervisor, edge_src, edge_dst, edge_weight)
-    return _np.asarray(out)  # readback: host boundary: device marks -> np result contract
 
 
 def garbage_and_kills_np(

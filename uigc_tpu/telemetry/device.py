@@ -103,7 +103,7 @@ def _tally(out: Dict[str, int], x: Any, depth: int = 0) -> None:
 
 #: (family, attribute names) groups duck-typed off the shadow graph.
 #: Missing attributes contribute nothing — the same walk serves the
-#: host array graph, the device/decremental graph and the mesh graph.
+#: host array graph, the decremental graph and the mesh graph.
 _FAMILY_ATTRS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("node_features", (
         "flags", "recv_count", "supervisor", "_br_seq", "_sup_seq",
@@ -119,10 +119,9 @@ _FAMILY_ATTRS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
 )
 
 #: sub-objects whose ``vars()`` are scanned generically for arrays —
-#: the incremental layout and the decremental tracer own device mirrors
+#: the decremental tracer and its incremental layout own device mirrors
 #: the graph only references indirectly.
 _SCAN_ATTRS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
-    ("incremental_layout", ("_inc",)),
     ("decremental_tracer", ("_dec",)),
 )
 
