@@ -122,6 +122,50 @@ def _mosaic_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+#: what the chip's scalar memory holds (``pt._pad_blocks_target``)
+SMEM_BUDGET = 1 << 20
+
+
+def _scalar_operand_bytes(geom) -> int:
+    """The propagate kernel's scalar-prefetch operands at this geometry:
+    the dirty prefix and list, the gate, ``bmeta1``, ``bmeta2`` and the
+    list of active blocks."""
+    n_chunks = geom["r_rows"] // GROUP_ROWS
+    return 4 * (2 * n_chunks + 1 + geom["n_super"] + 3 * geom["n_blocks"])
+
+
+@pytest.mark.parametrize(
+    "geom", [GEOM_10M, GEOM_CHAIN_1M, GEOM_TREE_100K, GEOM_SMALL],
+    ids=["10m", "chain-1m", "tree-100k", "small"],
+)
+def test_propagate_launch_compiles_with_its_list(one_chip, geom):
+    """One launch as every caller makes it (``build_propagate``'s callable:
+    the list of active blocks in XLA, then a grid as long as the list):
+    one Mosaic kernel, its scalar operands under the SMEM budget, and
+    beside the contributions the count of steps it took."""
+    import jax
+
+    propagate = pt.build_propagate(
+        geom["n_blocks"], geom["n_super"], geom["r_rows"], pt.S_ROWS, False,
+        sub=pt.SUB_TPU, group=pt.GROUP_TPU, dst_gate=True,
+    )
+    n_chunks = geom["r_rows"] // GROUP_ROWS
+    bmeta1, bmeta2, row_pos, emeta = _layout_structs(geom, one_chip)
+    compiled = jax.jit(propagate.with_steps).lower(
+        _struct((n_chunks + 1,), np.int32, one_chip),  # d
+        _struct((n_chunks,), np.int32, one_chip),  # l
+        _struct((geom["n_super"],), np.int32, one_chip),  # gate
+        bmeta1, bmeta2,
+        _struct((geom["r_rows"], LANE), np.int32, one_chip),  # table
+        row_pos, emeta,
+    ).compile()
+    assert _mosaic_calls(compiled) == 1
+    assert _scalar_operand_bytes(geom) < SMEM_BUDGET
+    out, steps = compiled.out_info
+    assert out.shape == (geom["n_super"] * pt.S_ROWS, LANE)
+    assert steps.shape == () and steps.dtype == np.int32
+
+
 def test_decremental_wake_compiles_at_10m(one_chip):
     compiled = _compile_wake(GEOM_10M, one_chip, pt.MODE_AUTO)
     assert _mosaic_calls(compiled) >= 2  # closure + repair fixpoints
@@ -133,6 +177,10 @@ def test_decremental_wake_compiles_at_10m(one_chip):
     assert [w.shape for w in words] == [(GEOM_10M["r_rows"], LANE)] * 5
     assert walks.shape == () and walks.dtype == np.int32
     assert stats["closure_bailed"].shape == stats["closure_spent"].shape == ()
+    assert stats["kernel_steps"].shape == stats["kernel_steps_full"].shape == ()
+    # three n_blocks-long int32 operands in SMEM (bmeta1, bmeta2 and the
+    # list of active blocks) beside the gate and the dirty lists
+    assert _scalar_operand_bytes(GEOM_10M) == 304_996 < SMEM_BUDGET
 
 
 @pytest.mark.parametrize("mode", [pt.MODE_AUTO, pt.MODE_JUMP])
@@ -184,6 +232,7 @@ def test_wake_program_counts_and_names(one_chip, mode):
     assert stats["closure_sweeps"].shape == stats["n_sweeps"].shape == ()
     assert stats["closure_bailed"].shape == stats["closure_spent"].shape == ()
     assert stats["gated_tiles"].shape == ()
+    assert stats["kernel_steps"].shape == stats["kernel_steps_full"].shape == ()
     assert stats["jump_sweeps"].shape == stats["jump_spent"].shape == ()
     for key in ("dirty_chunks", "tiles_skipped", "pull_on", "jump_on"):
         assert stats[key].shape == (pt.MAX_SWEEP_STATS,)

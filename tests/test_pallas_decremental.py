@@ -555,3 +555,72 @@ def test_graft_entry_derives_the_oracles_marks():
     assert np.array_equal(np.asarray(pt.unpack_table(out[0], n, jnp)), expected)
     in_use = (g["flags"] & F.FLAG_IN_USE) != 0
     assert np.array_equal(in_use & ~expected, g["expected_garbage"])
+
+
+def _simulated_kernel_steps(preps, flags, src, dst, pull):
+    """Blocks with work summed over the sweeps of a derivation from the
+    seeds, in numpy: per sweep, the blocks whose chunk span holds a chunk
+    whose table words changed, less (``pull``) those of a saturated tile."""
+    n = flags.size
+    packed = [p for p in preps if "xla_src" not in p]
+    (p0,) = packed  # the base layout; no churn, so no tier
+    super_sz = p0["s_rows"] * pt.LANE
+    in_use = (flags & F.FLAG_IN_USE) != 0
+    c_lo = p0["bmeta2"] >> pt._SPAN_BITS
+    span = p0["bmeta2"] & ((1 << pt._SPAN_BITS) - 1)
+    tile = p0["bmeta1"] >> 1
+    n_chunks = -(-n // CHUNK)
+    mark = in_use & ((flags & F.FLAG_ROOT) != 0)
+    prev = np.zeros(n, bool)
+    steps, sweeps = 0, 0
+    while not np.array_equal(mark, prev):
+        changed = np.flatnonzero(mark != prev) // CHUNK
+        d = np.concatenate([[0], np.cumsum(np.bincount(changed, minlength=n_chunks) > 0)])
+        active = d[c_lo + span] - d[c_lo] > 0
+        if pull:
+            unmarked = np.zeros(p0["n_super"] * super_sz, bool)
+            unmarked[:n] = in_use & ~mark
+            saturated = ~unmarked.reshape(p0["n_super"], super_sz).any(axis=1)
+            active &= ~saturated[tile]
+        steps += int(active.sum())
+        sweeps += 1
+        new = mark.copy()
+        new[dst[mark[src]]] = True
+        prev, mark = mark, new & in_use
+    return steps, sweeps
+
+
+@pytest.mark.parametrize("mode", [pt.MODE_PUSH, pt.MODE_PULL])
+def test_kernel_steps_count_the_blocks_with_work(mode):
+    """``wake_stats()``: ``kernel_steps`` is the blocks with work summed
+    over the wake's sweeps, as a numpy run of the same sweeps counts them,
+    and ``kernel_steps_full`` what as many launches over every block take."""
+    n = 2 * CHUNK + 4000  # three walk chunks
+    flags, recv, src, dst, sup = supervised_tree(n)
+    w = np.ones(src.size, np.int64)
+    tracer = pd.DecrementalTracer(n, mode=mode, s_rows=8)
+    tracer.rebuild(src, dst, w, sup)
+    assert tracer.marks(flags, recv).all()
+    preps, _ = tracer.layout.prepare_device_wake()
+    n_blocks = sum(p["n_blocks"] for p in preps if "xla_src" not in p)
+    # a child marks its supervisor too, but a parent is marked before it
+    steps, sweeps = _simulated_kernel_steps(
+        preps, flags, src, dst, pull=mode == pt.MODE_PULL
+    )
+    s = tracer.wake_stats(1)[0]
+    assert (s["closure_sweeps"], s["n_sweeps"]) == (0, sweeps)
+    assert s["kernel_steps_full"] == sweeps * n_blocks
+    assert s["kernel_steps"] == steps and 0 < steps < s["kernel_steps_full"]
+
+    # a warm wake: the closure loop's launches and the forced tiles count too
+    cut = np.random.default_rng(32).choice(src.size, 24, replace=False)
+    w[cut] = 0
+    tracer.apply_log([(False, int(src[i]), int(dst[i]), EDGE) for i in cut])
+    got = tracer.marks(flags, recv)
+    assert np.array_equal(got, trace_ops.trace_marks_np(flags, recv, sup, src, dst, w))
+    s = tracer.wake_stats(1)[0]
+    preps, _ = tracer.layout.prepare_device_wake()
+    n_blocks = sum(p["n_blocks"] for p in preps if "xla_src" not in p)
+    assert s["closure_sweeps"] > 0
+    assert s["kernel_steps_full"] == (s["closure_sweeps"] + s["n_sweeps"]) * n_blocks
+    assert 0 < s["kernel_steps"] <= s["kernel_steps_full"]

@@ -338,3 +338,216 @@ def test_int8_ab_in_process(monkeypatch):
         marks[flag], _ = pd.derive(g["flags"], g["recv_count"], [prep])
     assert np.array_equal(marks["0"], marks["1"])
     assert len(set(pd._fn_cache) - keys_before) == 2  # one program per datapath
+
+
+# --------------------------------------------------------------------- #
+# The compacted grid: a launch visits only the blocks that have work
+# --------------------------------------------------------------------- #
+
+GRID_N = 70_000  # three walk chunks of 32,768 actors at group 1
+GRID_S_ROWS = 8  # 69 supertiles of 1,024 actors
+GRID_CHUNK_ROWS = pallas_trace.ROWS  # table rows a walk chunk has at group 1
+
+
+def _grid_layout(compact):
+    """A packed layout whose tiles have several blocks each, and the pairs
+    it holds.  The compact one touches every fourth supertile only."""
+    rng = np.random.default_rng(32)
+    m = 150_000
+    psrc = rng.integers(0, GRID_N, m)
+    pdst = rng.integers(0, GRID_N, m)
+    if compact:
+        keep = (pdst // (GRID_S_ROWS * pallas_trace.LANE)) % 4 == 1
+        psrc, pdst = psrc[keep], pdst[keep]
+    prep = pallas_trace.prepare_pairs(
+        psrc, pdst, GRID_N, s_rows=GRID_S_ROWS, pad_blocks_pow2=True,
+        compact_supers=compact, sub=1, group=1,
+    )
+    return prep, psrc, pdst
+
+
+def _block_iters(prep, dirty, gate):
+    """Chunk-iterations per block, by the kernel's rule, in numpy."""
+    c_lo = prep["bmeta2"] >> pallas_trace._SPAN_BITS
+    span = prep["bmeta2"] & ((1 << pallas_trace._SPAN_BITS) - 1)
+    d = np.concatenate([[0], np.cumsum(dirty)])
+    g = gate[prep["bmeta1"] >> 1]
+    return np.where(
+        g == pallas_trace.GATE_SKIP, 0,
+        np.where(g == pallas_trace.GATE_FULL, span, d[c_lo + span] - d[c_lo]),
+    )
+
+
+def _reference_contribs(prep, psrc, pdst, table, dirty, gate):
+    """The uncompacted reference: a segment-sum over the layout's pairs of
+    the source's table bit, for the pairs whose source chunk the sweep
+    walks into their destination tile (dirty, or the tile forced; never a
+    skipped tile)."""
+    super_sz = prep["s_rows"] * pallas_trace.LANE
+    tile = pdst // super_sz
+    if "super_ids" in prep:  # compact: global supertile -> layout tile
+        tile = np.searchsorted(prep["super_ids"][: len(np.unique(tile))], tile)
+    word = psrc >> 5
+    bit = (table[word >> 7, word & 127] >> (psrc & 31)) & 1
+    chunk = (word >> 7) // GRID_CHUNK_ROWS
+    g = gate[tile]
+    walked = np.where(
+        g == pallas_trace.GATE_SKIP, False,
+        (g == pallas_trace.GATE_FULL) | dirty[chunk],
+    )
+    out_tiles = prep.get("out_supers", prep["n_super"])
+    out = np.zeros(out_tiles * super_sz, np.float32)
+    np.add.at(out, tile * super_sz + pdst % super_sz, (bit * walked).astype(np.float32))
+    return out.reshape(-1, pallas_trace.LANE)
+
+
+def _launch(prep, table, dirty, gate, fill=None):
+    """(contributions, steps) of one launch; over a buffer of ``fill``."""
+    import jax
+    import jax.numpy as jnp
+
+    n_chunks = dirty.size
+    d = np.concatenate([[0], np.cumsum(dirty)]).astype(np.int32)
+    l = np.zeros(n_chunks, np.int32)
+    l[d[:-1][dirty]] = np.flatnonzero(dirty)
+    propagate = pallas_trace.build_propagate(
+        prep["n_blocks"], prep.get("out_supers", prep["n_super"]),
+        prep["r_rows"], prep["s_rows"], True, sub=1, group=1, dst_gate=True,
+    )
+    operands = (d, l, gate, prep["bmeta1"], prep["bmeta2"], table,
+                prep["row_pos"], prep["emeta"])
+    if fill is None:
+        out, steps = jax.jit(propagate.with_steps)(*operands)
+    else:
+        plane = jnp.full(
+            (propagate(*operands).shape[0], pallas_trace.LANE), fill, jnp.float32
+        )
+        out, steps = jax.jit(propagate.onto)(plane, *operands)
+    return np.asarray(out), int(steps)
+
+
+@pytest.mark.parametrize(
+    "case",
+    [
+        "nothing_dirty", "one_dirty_chunk", "all_dirty",
+        "forced_tile_clean_chunks", "skipped_tile_dirty_sources",
+        "first_static_block_inactive", "compact_unvisited_tile",
+        "padding_blocks_only",
+    ],
+)
+def test_compacted_grid_equals_uncompacted_reference(case):
+    """The kernel's contributions over a grid as long as the list of active
+    blocks equal the segment-sum over the same pairs, whatever the list
+    leaves out.  A tile it never visits keeps what the output buffer held,
+    so over the zero plane it reads as exactly zero; a tile's first ACTIVE
+    block, wherever it stands among the tile's blocks, overwrites what the
+    buffer held there, so over a buffer of sevens a visited tile reads as
+    over zeros (a block that wrongly accumulated would read seven more)."""
+    compact = case == "compact_unvisited_tile"
+    prep, psrc, pdst = _grid_layout(compact)
+    if case == "padding_blocks_only":  # no pair: dummy and padding blocks
+        psrc = pdst = np.zeros(0, np.int64)
+        prep = pallas_trace.prepare_pairs(
+            psrc, pdst, GRID_N, s_rows=GRID_S_ROWS, pad_blocks_pow2=True,
+            sub=1, group=1,
+        )
+    n_chunks = prep["r_rows"] // GRID_CHUNK_ROWS
+    assert n_chunks == 3
+    out_tiles = prep.get("out_supers", prep["n_super"])
+    rng = np.random.default_rng(7)
+    table = rng.integers(0, 1 << 31, (prep["r_rows"], pallas_trace.LANE)).astype(np.int32)
+    dirty = np.zeros(n_chunks, bool)
+    gate = np.zeros(out_tiles, np.int32)
+    if case in ("one_dirty_chunk", "compact_unvisited_tile"):
+        dirty[1] = True
+    elif case in ("all_dirty", "skipped_tile_dirty_sources"):
+        dirty[:] = True
+    elif case == "first_static_block_inactive":
+        dirty[n_chunks - 1] = True
+    elif case == "padding_blocks_only":
+        dirty[:] = True
+        gate[:] = pallas_trace.GATE_FULL
+    if case == "forced_tile_clean_chunks":
+        gate[[3, out_tiles - 1]] = pallas_trace.GATE_FULL
+    if case == "skipped_tile_dirty_sources":
+        gate[[0, 5]] = pallas_trace.GATE_SKIP
+    if compact:  # one touched tile is saturated: the list never visits it
+        gate[2] = pallas_trace.GATE_SKIP
+
+    n_iter = _block_iters(prep, dirty, gate)
+    block_tile = prep["bmeta1"] >> 1
+    visited = np.zeros(out_tiles, bool)
+    visited[block_tile[n_iter > 0]] = True
+    if case == "first_static_block_inactive":
+        first = (prep["bmeta1"] & 1) == 1
+        late = visited[block_tile] & first & (n_iter == 0)
+        assert late.sum() > 10  # tiles whose first block has no work
+    if case in ("one_dirty_chunk", "all_dirty"):
+        assert visited.all() and (n_iter == 0).any()  # padding never runs
+    if compact:
+        assert not visited[2] and visited.sum() == out_tiles - 1 - (
+            out_tiles - len(np.unique(pdst // (GRID_S_ROWS * pallas_trace.LANE)))
+        )
+
+    out, steps = _launch(prep, table, dirty, gate)
+    assert steps == int((n_iter > 0).sum())
+    if case in ("nothing_dirty", "padding_blocks_only"):
+        assert steps == 0
+    expected = _reference_contribs(prep, psrc, pdst, table, dirty, gate)
+    assert np.array_equal(out, expected)  # NaN anywhere would fail this
+    rows = prep["s_rows"]
+    unvisited = np.repeat(~visited, rows)
+    assert not out[unvisited].any() and (steps == 0 or out[~unvisited].any())
+    # over a buffer deliberately filled with non-zeros
+    if steps == 0:  # the one step of an empty launch zeroes the last block's tile
+        visited[block_tile[-1]] = True
+        unvisited = np.repeat(~visited, rows)
+    out7, steps7 = _launch(prep, table, dirty, gate, fill=7.0)
+    assert steps7 == steps
+    assert np.array_equal(out7, np.where(unvisited[:, None], np.float32(7), expected))
+
+
+def test_unvisited_tiles_of_a_compact_layout_add_nothing():
+    """Through the sweep both fixpoints share: a compact layout's output is
+    scattered into the global plane by ``super_ids``, so a tile it never
+    visited must add zeros there and not what its buffer held."""
+    import jax
+    import jax.numpy as jnp
+
+    dense, psrc_d, pdst_d = _grid_layout(False)
+    comp, psrc_c, pdst_c = _grid_layout(True)
+    specs = (pallas_trace.layout_spec(dense), pallas_trace.layout_spec(comp))
+    props = pallas_trace.build_layout_propagates(
+        specs, dense["n_super"], dense["r_rows"], GRID_S_ROWS, True, dst_gate=True
+    )
+    sweep = pallas_trace.build_sweep_contribs(
+        specs, props, GRID_N, dense["n_super"], GRID_S_ROWS, jnp
+    )
+    rng = np.random.default_rng(9)
+    table = rng.integers(0, 1 << 31, (dense["r_rows"], pallas_trace.LANE)).astype(np.int32)
+    dirty = np.array([False, True, False])
+    gate = np.zeros(dense["n_super"], np.int32)
+    gate[::3] = pallas_trace.GATE_SKIP
+    d = np.concatenate([[0], np.cumsum(dirty)]).astype(np.int32)
+    l = np.array([1, 0, 0], np.int32)
+    args = pallas_trace.device_args(dense) + pallas_trace.device_args(comp)
+    hits, steps = jax.jit(
+        lambda t, d, l, g, *a: sweep.with_steps(t, d, l, a, gate=g)
+    )(table, d, l, gate, *args)
+    expected = (
+        _reference_contribs(dense, psrc_d, pdst_d, table, dirty, gate)
+        + _reference_contribs(
+            {k: v for k, v in comp.items() if k not in ("super_ids", "out_supers")},
+            psrc_c, pdst_c, table, dirty, gate,
+        )
+    ) > 0
+    assert np.array_equal(np.asarray(hits), expected)
+    n_iter_c = _block_iters(comp, dirty, gate[comp["super_ids"]])
+    assert int(steps) == int(
+        (_block_iters(dense, dirty, gate) > 0).sum() + (n_iter_c > 0).sum()
+    )
+    assert np.array_equal(
+        np.asarray(jax.jit(lambda t, d, l, g, *a: sweep(t, d, l, a, gate=g))(
+            table, d, l, gate, *args)),
+        expected,
+    )

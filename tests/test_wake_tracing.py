@@ -208,6 +208,7 @@ def test_scopes_in_the_lowered_wake_program(mode):
     ]:
         assert f"{pd.WAKE_SCOPE}/{loop}/while/body/{helper}" in text, (loop, helper)
     assert f"push/{pt.KERNEL_NAME}" in text
+    assert "push/active/" in text  # the list of blocks with work, beside the kernel
     if "jump" not in helpers:
         assert "/jump" not in text
     else:  # the jump's parts carry scopes of their own

@@ -53,12 +53,11 @@ import numpy as np
 
 
 def _sync(out):
-    """Force completion via a 1-element readback of every output."""
+    """Wait for every output (``block_until_ready`` synchronises on the
+    chip: PERF.md section 6, PR 24)."""
     import jax
 
-    leaves = jax.tree_util.tree_leaves(out)
-    for leaf in leaves:
-        jax.device_get(leaf.ravel()[0])
+    jax.block_until_ready(out)
 
 
 def timed(fn, *args, reps=5):
@@ -323,9 +322,13 @@ def main():
     )
     full_ms = none_ms = half_ms = pack_ms = pack2d_ms = jump_ms = None
     if not args.skip_probes:
-        propagate = pt.build_propagate(
-            n_blocks, n_super, r_rows, s_rows, pt.default_interpret(),
-            sub=prep["sub"], group=prep["group"],
+        # jitted: the kernel's launch is the list of active blocks (XLA)
+        # and a grid as long as the list, one program as in the wake
+        propagate = jax.jit(
+            pt.build_propagate(
+                n_blocks, n_super, r_rows, s_rows, pt.default_interpret(),
+                sub=prep["sub"], group=prep["group"],
+            ).with_steps
         )
         dev = {
             k: jax.device_put(prep[k])
@@ -340,14 +343,7 @@ def main():
         l_full = jax.device_put(np.arange(n_chunks, dtype=np.int32))
         d_none = jax.device_put(np.zeros(n_chunks + 1, dtype=np.int32))
 
-        full_ms = timed(
-            propagate, d_full, l_full, dev["bmeta1"], dev["bmeta2"], table,
-            dev["row_pos"], dev["emeta"],
-        )
-        none_ms = timed(
-            propagate, d_none, l_full, dev["bmeta1"], dev["bmeta2"], table,
-            dev["row_pos"], dev["emeta"],
-        )
+        probes = {"full": (d_full, l_full), "none": (d_none, l_full)}
 
         # half the chunks dirty (even ids): the mid-fixpoint regime
         diff = np.zeros(n_chunks, bool)
@@ -355,10 +351,17 @@ def main():
         dd = np.concatenate([[0], np.cumsum(diff)]).astype(np.int32)
         ll = np.zeros(n_chunks, np.int32)
         ll[dd[:-1][diff]] = np.nonzero(diff)[0].astype(np.int32)
-        half_ms = timed(
-            propagate, jax.device_put(dd), jax.device_put(ll), dev["bmeta1"],
-            dev["bmeta2"], table, dev["row_pos"], dev["emeta"],
+        probes["half"] = (jax.device_put(dd), jax.device_put(ll))
+        layout = (dev["bmeta1"], dev["bmeta2"], table, dev["row_pos"],
+                  dev["emeta"])
+        probe_ms = {k: timed(propagate, *dl, *layout)
+                    for k, dl in probes.items()}
+        full_ms, none_ms, half_ms = (
+            probe_ms[k] for k in ("full", "none", "half")
         )
+        # the grid steps each probe took: its blocks with work
+        probe_steps = {k: int(propagate(*dl, *layout)[1])
+                       for k, dl in probes.items()}
 
         shifts = jnp.arange(pt.WORD_BITS, dtype=jnp.int32)
 
@@ -393,6 +396,8 @@ def main():
             return pt.jump_sweep(table, jump_j, trans_w, n, jnp)
 
         jump_ms = timed(jump, table, jax.device_put(jp), table)
+        # what the host's dispatch and wait cost a probe, whatever it runs
+        floor_ms = timed(jax.jit(lambda x: x + 1), d_none)
 
     # --- per-mode fixpoint decomposition, through the wake profiler -- #
     # The same per-wake fields the engine notes into its active wake
@@ -470,6 +475,8 @@ def main():
                 "sweep_full_dirty_ms": round(full_ms, 2),
                 "sweep_half_dirty_ms": round(half_ms, 2),
                 "sweep_no_dirty_ms": round(none_ms, 2),
+                "sweep_grid_steps": probe_steps,
+                "dispatch_floor_ms": round(floor_ms, 2),
                 "pack_seed_ms": round(pack_ms, 2),
                 "pack2d_per_sweep_ms": round(pack2d_ms, 2),
                 "jump_sweep_ms": round(jump_ms, 2),

@@ -147,7 +147,10 @@ def _build_wake_fn(
     exit, against :attr:`DecrementalTracer.closure_price`),
     ``closure_bailed`` (1 if it gave up), ``gated_tiles`` (supertiles
     the first repair sweep walks in full; 0 on the cold road),
-    ``n_sweeps`` (repair), ``jump_sweeps`` (the
+    ``n_sweeps`` (repair), ``kernel_steps`` and ``kernel_steps_full``
+    (grid steps the propagate kernels took in both loops, over all packed
+    layouts: the blocks that had work; and launches x blocks, what a grid
+    over every block would take), ``jump_sweeps`` (the
     repair sweeps that ran the jump) and ``jump_spent`` (the policy's
     ``spent`` at exit, to be read against the static
     :attr:`DecrementalTracer.jump_price`) are int32 scalars, ``dirty_chunks``,
@@ -183,6 +186,7 @@ def _build_wake_fn(
     )
 
     n_chunks = r_rows // group_rows
+    launch_blocks = sum(spec[1] for spec in specs if spec[0] != "xla")
     n_pad_nodes = n_super * s_rows * pt.LANE
     t_rows = n_super * s_rows
     sup_words = s_rows * (pt.LANE // pt.WORD_BITS)  # words per supertile
@@ -220,9 +224,10 @@ def _build_wake_fn(
 
         def contribs(table, d, l, gate):
             """One propagation sweep over every layout (shared loop:
-            pallas_trace.build_sweep_contribs); a zero gate vector makes
-            the dst-gated kernels behave exactly like the plain ones."""
-            return gated_sweep(table, d, l, layout_args, gate=gate)
+            pallas_trace.build_sweep_contribs) and the grid steps its
+            kernels took; a zero gate vector makes the dst-gated kernels
+            behave exactly like the plain ones."""
+            return gated_sweep.with_steps(table, d, l, layout_args, gate=gate)
 
         with pt.scope("pack"):
             in_use = (flags & F.FLAG_IN_USE) != 0
@@ -258,27 +263,28 @@ def _build_wake_fn(
         # under CRGC's supervisor edges the closure of a live suspect is
         # every mark, and finding that out costs as much as acting on it.
         def c_cond(carry):
-            _, _, _, changed, _, spent = carry
+            _, _, _, changed, _, spent, _ = carry
             return changed & ~pt.closure_gives_up(spent, prev_walks)
 
         zero_gate = jnp.zeros((n_super,), jnp.int32)
         zero_i = jnp.zeros((), jnp.int32)
 
         def c_body(carry):
-            closure_w, d, l, _, sweeps, spent = carry
-            hits2d = contribs(closure_w, d, l, zero_gate)
+            closure_w, d, l, _, sweeps, spent, steps = carry
+            hits2d, took = contribs(closure_w, d, l, zero_gate)
             hit_w = pt.pack_hits_table(hits2d, r_rows, jnp)
             new_closure = closure_w | (hit_w & prev_mark_w)
             d2, l2, changed = dirty_chunks(new_closure, closure_w)
             return (new_closure, d2, l2, changed, sweeps + 1,
-                    spent + d[n_chunks])
+                    spent + d[n_chunks], steps + took)
 
         with pt.scope("closure"):
             zero_w = jnp.zeros_like(s_w)
             d0, l0, changed0 = dirty_chunks(s_w, zero_w)
             (closure_w, _, _, closure_bailed, closure_sweeps,
-             closure_spent) = jax.lax.while_loop(
-                c_cond, c_body, (s_w, d0, l0, changed0, zero_i, zero_i),
+             closure_spent, closure_steps) = jax.lax.while_loop(
+                c_cond, c_body,
+                (s_w, d0, l0, changed0, zero_i, zero_i, zero_i),
             )
             # The cold road: the region to repair is everything, because
             # the closure said so by its cost or because there is no
@@ -348,7 +354,7 @@ def _build_wake_fn(
                 sat = None
                 pull_on = jnp.array(False)
                 gate = base_gate
-            hits2d = contribs(table, d, l, gate)
+            hits2d, took = contribs(table, d, l, gate)
             hit_w = pt.pack_hits_table(hits2d, r_rows, jnp)
             new_mark_w = mark_w | (hit_w & iu_w)
             if use_jump:
@@ -365,6 +371,7 @@ def _build_wake_fn(
                        l=l2, use_gate=jnp.array(False), changed=changed,
                        sweep_i=carry["sweep_i"] + 1,
                        walks=carry["walks"] + n_dirty,
+                       steps=carry["steps"] + took,
                        st_dirty=carry["st_dirty"].at[i].set(n_dirty))
             if use_jump:
                 jump_on = jump_state[0].astype(jnp.int32)
@@ -397,7 +404,7 @@ def _build_wake_fn(
             carry0 = {"mark": mark_w0, "table": table0, "d": rd0,
                       "l": rl0, "use_gate": jnp.array(True),
                       "changed": run0,
-                      "sweep_i": zero_i, "walks": zero_i,
+                      "sweep_i": zero_i, "walks": zero_i, "steps": zero_i,
                       "st_dirty": zero_stats}
             if use_jump:
                 carry0.update(jump=jump_j0.astype(jnp.int32),
@@ -416,6 +423,11 @@ def _build_wake_fn(
             # supertiles whose blocks the first repair sweep walks in full
             "gated_tiles": suspect_g.sum(),
             "n_sweeps": out["sweep_i"],
+            # grid steps the kernels took in both loops, and what as many
+            # launches over every block would have taken
+            "kernel_steps": closure_steps + out["steps"],
+            "kernel_steps_full": (closure_sweeps + out["sweep_i"])
+            * launch_blocks,
             "dirty_chunks": out["st_dirty"],
             "tiles_skipped": out.get("st_skip", zero_stats),
             "pull_on": out.get("st_pull", zero_stats),
@@ -507,6 +519,8 @@ def _host_stats(host: dict) -> dict:
         "closure_spent": int(host["closure_spent"]),
         "gated_tiles": int(host["gated_tiles"]),
         "n_sweeps": int(host["n_sweeps"]),
+        "kernel_steps": int(host["kernel_steps"]),
+        "kernel_steps_full": int(host["kernel_steps_full"]),
         "dirty_chunks": host["dirty_chunks"][:k].tolist(),
         "tiles_skipped": host["tiles_skipped"][:k].tolist(),
         "pull_on": host["pull_on"][:k].tolist(),
@@ -747,9 +761,11 @@ class DecrementalTracer:
         ``closure_spent``, ``closure_bailed`` and ``gated_tiles`` (the
         closure's chunk walks, whether it gave up at its price, and the
         supertiles the first repair sweep was forced through),
-        ``n_sweeps`` (repair), ``jump_sweeps`` (the repair sweeps that
-        ran the pointer jump), ``jump_spent`` (the ``auto`` policy's sparse chunk walks
-        at exit; against :attr:`jump_price`) and, for the repair's first
+        ``n_sweeps`` (repair), ``kernel_steps`` of ``kernel_steps_full``
+        (the grid steps its kernels took, of launches x blocks),
+        ``jump_sweeps`` (the repair sweeps that ran the pointer jump),
+        ``jump_spent`` (the ``auto`` policy's sparse chunk walks at exit;
+        against :attr:`jump_price`) and, for the repair's first
         ``pt.MAX_SWEEP_STATS`` sweeps, ``dirty_chunks``,
         ``tiles_skipped``, ``pull_on`` and ``jump_on``.
         Waits for a wake still in flight; costs the wakes nothing."""

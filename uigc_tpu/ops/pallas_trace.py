@@ -43,8 +43,9 @@ contraction on the MXU:
 A and B are 0/1 so bf16 inputs with f32 accumulation are exact, doubling
 MXU rate.  The output BlockSpec revisits one supertile block per run of
 grid steps via a scalar-prefetched supertile-id, so accumulation happens
-in VMEM and each block hits HBM exactly once per sweep.  Empty supertiles
-get a dummy all-padding group so every output block is initialized.
+in VMEM and each block hits HBM exactly once per sweep.  The grid visits
+only the blocks that have work this sweep (``build_propagate``); a
+supertile none of whose blocks has any reads as zeros.
 
 Per-edge metadata is packed into two int32 arrays (source row; and
 lane|bit|dst_lane|dst_sub) to halve HBM streaming per sweep.
@@ -630,7 +631,8 @@ def unpack_table(words, n, jnp):
 def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
     """The per-layout propagation sweep of the decremental wake's two
     fixpoints: returns fn(table, d, l, layout_args, gate) -> hits
-    plane (t_rows, LANE) bool.
+    plane (t_rows, LANE) bool; ``fn.with_steps`` returns beside it the
+    grid steps the sweep's kernels took (``build_propagate``).
 
     ``propagates`` holds one kernel per packed spec (None for xla
     tiers).  ``gate`` is the per-global-supertile dst-gate vector for
@@ -643,6 +645,9 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
     sub_iota_rows = jnp.arange(s_rows, dtype=jnp.int32)
 
     def sweep(table, d, l, layout_args, gate=None):
+        return with_steps(table, d, l, layout_args, gate)[0]
+
+    def with_steps(table, d, l, layout_args, gate=None):
         with scope("push"):
             return push(table, d, l, layout_args, gate)
 
@@ -650,6 +655,7 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
         contrib = jnp.zeros((t_rows, LANE), jnp.float32)
         xla_hits2d = jnp.zeros((t_rows, LANE), bool)
         have_xla = False
+        steps = jnp.zeros((), jnp.int32)
         pos = 0
         for spec, propagate in zip(specs, propagates):
             if spec[0] == "xla":
@@ -670,18 +676,22 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
                 )
                 have_xla = True
                 continue
-            if spec[0] == "compact":
-                bmeta1, bmeta2, row_pos, emeta, super_ids = layout_args[
-                    pos:pos + 5
-                ]
-                pos += 5
-                if gate is None:
-                    c = propagate(d, l, bmeta1, bmeta2, table, row_pos, emeta)
-                else:
-                    c = propagate(
-                        d, l, gate[super_ids], bmeta1, bmeta2, table,
-                        row_pos, emeta,
-                    )
+            compact = spec[0] == "compact"
+            bmeta1, bmeta2, row_pos, emeta = layout_args[pos:pos + 4]
+            pos += 4
+            gates = ()
+            if compact:
+                super_ids = layout_args[pos]
+                pos += 1
+                if gate is not None:
+                    gates = (gate[super_ids],)
+            elif gate is not None:
+                gates = (gate,)
+            c, took = propagate.with_steps(
+                d, l, *gates, bmeta1, bmeta2, table, row_pos, emeta
+            )
+            steps = steps + took
+            if compact:
                 rows = (
                     super_ids[:, None] * s_rows + sub_iota_rows[None, :]
                 ).reshape(-1)
@@ -689,20 +699,13 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
                     c, mode="drop", unique_indices=False
                 )
             else:
-                bmeta1, bmeta2, row_pos, emeta = layout_args[pos:pos + 4]
-                pos += 4
-                if gate is None:
-                    c = propagate(d, l, bmeta1, bmeta2, table, row_pos, emeta)
-                else:
-                    c = propagate(
-                        d, l, gate, bmeta1, bmeta2, table, row_pos, emeta
-                    )
                 contrib = contrib + c
         hits2d = contrib > 0
         if have_xla:
             hits2d = hits2d | xla_hits2d
-        return hits2d
+        return hits2d, steps
 
+    sweep.with_steps = with_steps
     return sweep
 
 
@@ -1006,7 +1009,10 @@ def prepare_pairs(
             )
             n_blocks = padded
 
-    # meta1 = supertile id | first-visit bit; meta2 = chunk range
+    # meta1 = supertile id | first block of its tile; meta2 = chunk range.
+    # The kernel reads the tile only: which block initialises an output
+    # tile is decided per sweep, among the blocks that have work
+    # (build_propagate).
     bmeta1 = (block_super << 1 | block_first).astype(np.int32)
     bmeta2 = (c_lo << _SPAN_BITS | span).astype(np.int32)
 
@@ -1153,16 +1159,31 @@ def build_propagate(
 ):
     """One propagation sweep as a pallas_call: gather source bits from the
     packed table, one-hot segment-sum into per-supertile contributions.
+    Returns ``propagate(d, l, [gate,] bmeta1, bmeta2, table, row_pos,
+    emeta) -> contributions``; ``propagate.with_steps`` returns beside
+    them the grid steps the launch took.
 
     Operands (after the scalar-prefetch ones): the (r_rows, LANE) bit
     table, then row_pos and emeta.  Scalar-prefetch operands are the
     dirty-chunk prefix D (size n_chunks + 1, D[c] = number of dirty
     chunks below c), the compacted dirty-chunk index list L, and bmeta1,
-    bmeta2: each block walks only the *dirty* chunks inside its span, and
-    a block with none skips its gather and matmul entirely.  Correct
-    under the trace's monotone OR-accumulation: a clean chunk's words are
-    unchanged since the sweep that last walked them, so the skipped
-    contribution is already in the mark vector.
+    bmeta2: each block walks only the *dirty* chunks inside its span.
+    Correct under the trace's monotone OR-accumulation: a clean chunk's
+    words are unchanged since the sweep that last walked them, so the
+    skipped contribution is already in the mark vector.
+
+    A block with no chunk to walk is not visited at all.  Before each
+    launch the callable computes, in XLA and by the kernel's own rule
+    (``block_iters``), the list of blocks that have work, in block order;
+    the list is one more scalar-prefetch operand, the index maps of
+    row_pos, emeta and the output read block and tile through it, and the
+    grid is as long as the list (a dynamic bound): a grid over all
+    n_blocks paid 0.24 us for every step that skipped, 5.9 ms a sweep at
+    10M actors, three steps in four of a derivation (PERF.md section 6,
+    PR 32).  A tile's active blocks stay consecutive, so its first ACTIVE
+    block initialises it and each output block still hits HBM once a
+    sweep; a tile with none keeps the zeros of the plane aliased in as
+    the output buffer.
 
     With ``dst_gate`` a fifth scalar-prefetch operand S (one int per
     output tile) selects the walk per block from the destination side:
@@ -1193,34 +1214,63 @@ def build_propagate(
     block_rows = ROWS * sub
     group_rows = ROWS * group
     use_int8 = _int8_mxu()
+    span_mask = (1 << _SPAN_BITS) - 1
+
+    def block_iters(d, gate, bmeta1, bmeta2):
+        """Chunk-iterations per block this sweep: the kernel's own rule,
+        on whole vectors (here) or on one block's scalars (in a step)."""
+        c_lo = jax.lax.shift_right_logical(bmeta2, _SPAN_BITS)
+        span = bmeta2 & span_mask
+        n_iter = d[c_lo + span] - d[c_lo]
+        if dst_gate:
+            g = gate[bmeta1 >> 1]
+            n_iter = jnp.where(
+                g == GATE_SKIP, 0, jnp.where(g == GATE_FULL, span, n_iter)
+            )
+        return n_iter
+
+    def active_blocks(d, gate, bmeta1, bmeta2):
+        """(act, count): the blocks with work this sweep, in block order,
+        in the first ``count`` entries of ``act`` (the rest the last
+        block).  A sort and not a prefix sum and a scatter: on the v5e the
+        scatter of 24,576 ids costs 122 us a launch, the sort 18 (PERF.md
+        section 6, PR 32)."""
+        with scope("active"):
+            active = block_iters(d, gate, bmeta1, bmeta2) > 0
+            ids = jnp.arange(n_blocks, dtype=jnp.int32)
+            act = jnp.sort(jnp.where(active, ids, n_blocks))
+            return (
+                jnp.minimum(act, n_blocks - 1),
+                active.sum(dtype=jnp.int32),
+            )
 
     def kernel(*refs):
         if dst_gate:
-            d_ref, l_ref, s_ref, meta1_ref, meta2_ref = refs[:5]
-            table_ref, row_ref, emeta_ref, out_ref = refs[5:]
+            d_ref, l_ref, s_ref, meta1_ref, meta2_ref, act_ref = refs[:6]
         else:
-            d_ref, l_ref, meta1_ref, meta2_ref = refs[:4]
-            table_ref, row_ref, emeta_ref, out_ref = refs[4:]
+            d_ref, l_ref, meta1_ref, meta2_ref, act_ref = refs[:5]
+            s_ref = None
+        # the aliased plane (refs[-5]) is never read: it is what an
+        # unvisited output tile holds
+        table_ref, row_ref, emeta_ref, out_ref = refs[-4:]
         i = pl.program_id(0)
-        m2 = meta2_ref[i]
+        blk = act_ref[i]
+        m1 = meta1_ref[blk]
+        m2 = meta2_ref[blk]
         c_lo = jax.lax.shift_right_logical(m2, _SPAN_BITS)
-        span = m2 & ((1 << _SPAN_BITS) - 1)
-        first = (meta1_ref[i] & 1) == 1
+        span = m2 & span_mask
+        # The first ACTIVE block of a tile initialises it: the list keeps
+        # block order, so a tile's active blocks are consecutive steps.
+        before = meta1_ref[act_ref[jnp.maximum(i - 1, 0)]]
+        first = (i == 0) | ((before >> 1) != (m1 >> 1))
 
         j_lo = d_ref[c_lo]
-        j_hi = d_ref[c_lo + span]
+        n_iter = block_iters(d_ref, s_ref, m1, m2)
         if dst_gate:
-            g = s_ref[meta1_ref[i] >> 1]
-            gated = g == GATE_FULL
-            n_iter = jnp.where(
-                g == GATE_SKIP,
-                0,
-                jnp.where(gated, span, j_hi - j_lo),
-            )
+            gated = s_ref[m1 >> 1] == GATE_FULL
             l_cap = l_ref.shape[0] - 1
         else:
             gated = None
-            n_iter = j_hi - j_lo
 
         row_iota = jax.lax.broadcasted_iota(jnp.int32, (block_rows, LANE), 0)
         r8_iota = row_iota & 7  # slot row class = src row mod 8
@@ -1311,45 +1361,83 @@ def build_propagate(
             def _():
                 out_ref[:] = out_ref[:] + acc
 
+        # only the one step of a launch with no active block gets here
         @pl.when(jnp.logical_not(n_iter > 0) & first)
         def _():
             out_ref[:] = jnp.zeros((s_rows, LANE), jnp.float32)
 
-    def imap_block(i, *_meta):
-        return (i, 0)
+    def imap_block(i, *meta):
+        return (meta[-1][i], 0)
 
     def imap_table(i, *_meta):
         return (0, 0)
 
-    if dst_gate:
+    def imap_out(i, *meta):
+        return (meta[-3][meta[-1][i]] >> 1, 0)
 
-        def imap_out(i, d, l, sg, m1, m2):
-            return (m1[i] >> 1, 0)
-
-    else:
-
-        def imap_out(i, d, l, m1, m2):
-            return (m1[i] >> 1, 0)
-
+    n_scalars = 6 if dst_gate else 5
     blockmap = pl.BlockSpec((block_rows, LANE), imap_block)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5 if dst_gate else 4,
-        grid=(n_blocks,),
-        in_specs=[
-            # bit table: whole array, VMEM-resident across all steps
-            pl.BlockSpec((r_rows, LANE), imap_table),
-            blockmap,  # row_pos
-            blockmap,  # emeta
-        ],
-        out_specs=pl.BlockSpec((s_rows, LANE), imap_out),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((out_tiles * s_rows, LANE), jnp.float32),
-        interpret=interpret,
-        name=KERNEL_NAME,
-    )
+    out_shape = jax.ShapeDtypeStruct((out_tiles * s_rows, LANE), jnp.float32)
+
+    def assemble(steps, *operands):
+        """The call over a grid of ``steps``, a traced value: a dynamic
+        bound is part of the grid spec, so the call is assembled where it
+        is traced and not once in ``build_propagate``'s body."""
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_scalars,
+            grid=(steps,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pl.ANY),  # plane, aliased out
+                # bit table: whole array, VMEM-resident across all steps
+                pl.BlockSpec((r_rows, LANE), imap_table),
+                blockmap,  # row_pos
+                blockmap,  # emeta
+            ],
+            out_specs=pl.BlockSpec((s_rows, LANE), imap_out),
+        )
+        return pl.pallas_call(  # uigc-lint: disable=UC304
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=out_shape,
+            input_output_aliases={n_scalars: 0},
+            interpret=interpret,
+            name=KERNEL_NAME,
+        )(*operands)
+
+    # Jitted (inlined, as ``pallas_call``'s own wrapper is): the closure
+    # and the repair loop of a wake program launch with the same shapes,
+    # so the second is a cache hit and the program traces and lowers ONE
+    # kernel per layout, as it did when the call was built once (0.4 s a
+    # program otherwise, 5 s of the served cell's set-up).
+    launch = jax.jit(assemble, inline=True)
+
+    def onto(plane, d, l, *operands):
+        """(contributions, grid steps that had work) of one launch over
+        the output buffer ``plane``, which it consumes.  The grid is as
+        long as the list of active blocks, and at least one step: a launch
+        with nothing to do writes one zero tile.  Tiles with no active
+        block are never visited and keep what ``plane`` held; the first
+        active block of a tile overwrites it."""
+        gate = operands[0] if dst_gate else None
+        bmeta1, bmeta2, table, row_pos, emeta = operands[-5:]
+        act, count = active_blocks(d, gate, bmeta1, bmeta2)
+        out = launch(
+            jnp.maximum(count, 1), d, l, *operands[:-3], act, plane, table,
+            row_pos, emeta,
+        )
+        return out, count
+
+    def with_steps(d, l, *operands):
+        """``onto`` a zero plane: an unvisited tile contributes nothing."""
+        zeros = jnp.zeros(out_shape.shape, out_shape.dtype)
+        return onto(zeros, d, l, *operands)
+
+    def propagate(d, l, *operands):
+        return with_steps(d, l, *operands)[0]
+
+    propagate.with_steps = with_steps
+    propagate.onto = onto
+    return propagate
 
 
 def default_interpret() -> bool:
