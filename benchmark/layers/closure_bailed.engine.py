@@ -1,0 +1,6 @@
+"""``closure_bailed.engine``: ``closure_bailed`` (``layers/closure_bailed.py``) in the engine-fold cell,
+where the wake is the collector's own (``drivers/engine_fold.py``) and moves that cell's end-to-end metric."""
+
+from harness.cell import reader_of
+
+read = reader_of("layers", "closure_bailed")

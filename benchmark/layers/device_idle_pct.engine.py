@@ -1,0 +1,6 @@
+"""``device_idle_pct.engine``: ``device_idle_pct`` (``layers/device_idle_pct.py``) in the engine-fold cell,
+where the wake is the collector's own (``drivers/engine_fold.py``) and moves that cell's end-to-end metric."""
+
+from harness.cell import reader_of
+
+read = reader_of("layers", "device_idle_pct")

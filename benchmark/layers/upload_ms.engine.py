@@ -1,0 +1,6 @@
+"""``upload_ms.engine``: ``upload_ms.served`` (``layers/upload_ms.served.py``) in the engine-fold cell,
+where the wake is the collector's own (``drivers/engine_fold.py``) and moves that cell's end-to-end metric."""
+
+from harness.cell import reader_of
+
+read = reader_of("layers", "upload_ms.served")

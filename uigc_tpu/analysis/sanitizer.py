@@ -80,6 +80,19 @@ def _path(cell: Any) -> str:
     return getattr(cell, "path", repr(cell))
 
 
+class _ForeignActor:
+    """The oracle's stand-in for a foreign actor (engines/crgc/packed.py:
+    known by uid alone, its cell lives in a mutator process): an
+    identity for the oracle's maps, an address and a path for reports."""
+
+    __slots__ = ("uid", "system", "path")
+
+    def __init__(self, uid: int, system: Any):
+        self.uid = uid
+        self.system = system
+        self.path = f"foreign:{uid}"
+
+
 class _Tap(EngineTap):
     """Mutator-side ground truth: every send/recv/create/release as the
     engine performs it, before any recording machinery can lose it."""
@@ -274,6 +287,11 @@ class Sanitizer:
         self._folded_undo: Set[str] = set()
         self._delta_seq: Dict[str, int] = {}
         self._seen_packed_seqs: Set[int] = set()
+        #: foreign actors the oracle holds, by their row code, and the
+        #: codes of those it swept (a late row naming one is dropped, as
+        #: ArrayShadowGraph's tombstone drops it)
+        self._foreign: Dict[int, _ForeignActor] = {}
+        self._foreign_swept: Set[int] = set()
         #: memoized pseudo-root closure; invalidated by every fold so a
         #: cascade of stop decisions costs one traversal, not one each.
         self._reach_cache: Optional[Set[Any]] = None
@@ -473,11 +491,24 @@ class Sanitizer:
                 "duplicate flush stamps in the packed entry stream",
                 stamps=sorted(set(dup_stamps)),
             )
+        from ..engines.crgc.packed import FOREIGN_BIT
+
         plane = self.engine.packed_plane
         resolve = self.system.resolve_cell
         pins = plane.uid_strong
+        foreign = self._foreign
+        swept = self._foreign_swept
 
         def cell_of(uid: int) -> Any:
+            if uid >= FOREIGN_BIT:
+                if uid in swept:
+                    return None
+                cell = foreign.get(uid)
+                if cell is None:
+                    cell = foreign[uid] = _ForeignActor(
+                        uid ^ FOREIGN_BIT, self.system
+                    )
+                return cell
             cell = pins.get(uid)
             return cell if cell is not None else resolve(uid)
 
@@ -581,6 +612,13 @@ class Sanitizer:
             with events.recorder.suppressed():
                 n_oracle = self.oracle.trace(should_kill=False)
             self.checks += 1
+            if self._foreign:
+                alive = self.oracle.shadow_map
+                for code in [
+                    c for c, cell in self._foreign.items() if cell not in alive
+                ]:
+                    del self._foreign[code]
+                    self._foreign_swept.add(code)
         events.recorder.commit(
             events.ANALYSIS_CHECK,
             node=self.system.address,

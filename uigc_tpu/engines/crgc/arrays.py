@@ -36,6 +36,7 @@ from ...utils import events
 from ...utils.validation import require
 from . import refob as refob_info
 from .messages import StopMsg, WaveMsg
+from .packed import FOREIGN_BIT
 from .state import CrgcContext, Entry
 
 if TYPE_CHECKING:  # pragma: no cover
@@ -45,6 +46,10 @@ if TYPE_CHECKING:  # pragma: no cover
 _F = trace_ops
 _PAIR_EDGE = pallas_incremental_kinds.EDGE
 _PAIR_SUP = pallas_incremental_kinds.SUP
+#: ``_fuid_to_slot`` values below the slots: never seen / swept for good
+_UNSEEN = -1
+_SWEPT = -2
+_NO_UIDS = np.empty(0, dtype=np.int64)
 
 
 def _readback(value, site: str) -> np.ndarray:
@@ -184,6 +189,19 @@ class ArrayShadowGraph:
         self._sup_seq = np.full(cap, -1, dtype=np.int64)
         self._plane = None
         self._resolve_cell = None
+        #: foreign actors (packed.py): the mutator side's dense uid ->
+        #: slot, ``_UNSEEN`` before the first row names it and ``_SWEPT``
+        #: for good once its slot was freed (a foreign uid resolves
+        #: through no registry, so the map itself has to remember that
+        #: the actor was proven garbage).  ``_slot_uid`` holds a foreign
+        #: slot's uid with ``FOREIGN_BIT`` set; such a slot has no cell.
+        self._fuid_to_slot = np.full(1024, _UNSEEN, dtype=np.int64)
+        self._has_foreign = False
+        #: where the sweep hands the foreign uids to stop and the ones
+        #: it freed, once per trace: ``sink(kill_uids, freed_uids)``,
+        #: two int64 arrays, on the collector's thread
+        #: (``CRGC.set_foreign_sink``)
+        self.foreign_sink = None
 
         ecap = max(16, initial_capacity * 2)
         self.edge_capacity = ecap
@@ -217,27 +235,32 @@ class ArrayShadowGraph:
     # Capacity management (static-shape friendly: powers of two)
     # ------------------------------------------------------------- #
 
-    def _grow_nodes(self) -> None:
+    def _grow_nodes(self, min_free: int = 1) -> None:
+        """Grow in one jump to the power-of-two capacity that yields
+        ``min_free`` free slots (as :meth:`_grow_edges` does)."""
         old = self.capacity
         new = old * 2
-        self.flags = np.concatenate([self.flags, np.zeros(old, dtype=np.uint8)])
+        while new - old + len(self.free_slots) < min_free:
+            new *= 2
+        more = new - old
+        self.flags = np.concatenate([self.flags, np.zeros(more, dtype=np.uint8)])
         self.recv_count = np.concatenate(
-            [self.recv_count, np.zeros(old, dtype=np.int64)]
+            [self.recv_count, np.zeros(more, dtype=np.int64)]
         )
         self.supervisor = np.concatenate(
-            [self.supervisor, np.full(old, -1, dtype=np.int32)]
+            [self.supervisor, np.full(more, -1, dtype=np.int32)]
         )
-        self.cells.extend([None] * old)
-        self.locations.extend([None] * old)
+        self.cells.extend([None] * more)
+        self.locations.extend([None] * more)
         self.free_slots.push_range(old, new)
         self._slot_uid = np.concatenate(
-            [self._slot_uid, np.full(old, -1, dtype=np.int64)]
+            [self._slot_uid, np.full(more, -1, dtype=np.int64)]
         )
         self._br_seq = np.concatenate(
-            [self._br_seq, np.full(old, -1, dtype=np.int64)]
+            [self._br_seq, np.full(more, -1, dtype=np.int64)]
         )
         self._sup_seq = np.concatenate(
-            [self._sup_seq, np.full(old, -1, dtype=np.int64)]
+            [self._sup_seq, np.full(more, -1, dtype=np.int64)]
         )
         self.capacity = new
         # Node capacity sets the bit-table/supertile geometry: the whole
@@ -609,27 +632,48 @@ class ArrayShadowGraph:
         self._resolve_cell = resolve_cell
 
     def _slots_for_uids(self, uids: np.ndarray) -> np.ndarray:
-        """Map uids -> slots through the dense array, interning unseen
-        uids (the only per-item Python in the packed fold, bounded by
-        the spawn rate rather than the flush rate).
-
-        An unresolvable uid maps to -1 and the caller drops the fields
-        naming it.  That is sound, not lossy: a uid resolves through
-        the plane's strong pin (held from flush until the actor's slot
-        is swept) or the system's weak registry (hit for any cell the
-        runtime still references, i.e. every live actor), so
-        unresolvable means the collector already PROVED the actor
-        garbage and swept it — and garbage is monotone, so late facts
+        """Map uids -> slots, interning unseen ones; -1 where the uid
+        names an actor that was already swept, and the caller drops the
+        fields naming it.  That is sound, not lossy: the collector
+        PROVED the actor garbage, and garbage is monotone, so late facts
         about it (receive deltas, deactivations, edges) change nothing
-        the sweep has not already settled."""
-        m = self._uid_to_slot
-        maxu = int(uids.max(initial=0))
-        if maxu >= m.shape[0]:
-            grown = max(m.shape[0] * 2, maxu + 1)
-            m = np.concatenate(
-                [m, np.full(grown - m.shape[0], -1, dtype=np.int64)]
-            )
-            self._uid_to_slot = m
+        the sweep has not already settled.
+
+        A uid with ``FOREIGN_BIT`` goes through the foreign map
+        (:meth:`_slots_for_foreign`), every other through the local one
+        (:meth:`_slots_for_local`); one batch may hold both."""
+        top = int(uids.max(initial=0))
+        if top < FOREIGN_BIT:  # all local: the one pass the map needs anyway
+            return self._slots_for_local(uids, top)
+        is_foreign = uids >= FOREIGN_BIT
+        slots = np.empty(uids.shape[0], dtype=np.int64)
+        fuids = uids[is_foreign] ^ FOREIGN_BIT
+        slots[is_foreign] = self._slots_for_foreign(fuids, top ^ FOREIGN_BIT)
+        if fuids.size < uids.size:
+            local = uids[~is_foreign]
+            slots[~is_foreign] = self._slots_for_local(local, int(local.max()))
+        return slots
+
+    @staticmethod
+    def _dense_map(m: np.ndarray, top: int, fill: int) -> np.ndarray:
+        """``m`` (a dense uid -> slot array), grown to hold uid ``top``."""
+        if top < m.shape[0]:
+            return m
+        grown = max(m.shape[0] * 2, top + 1)
+        return np.concatenate([m, np.full(grown - m.shape[0], fill, dtype=np.int64)])
+
+    def _slots_for_local(self, uids: np.ndarray, top: int) -> np.ndarray:
+        """Local uids (``ActorCell.uid``; ``top`` is the largest) through
+        the dense array, interning unseen ones cell by cell (per-item
+        Python, bounded by the spawn rate rather than the flush rate).
+
+        "Swept" and "never seen" are both -1 here and the registries
+        tell them apart: a uid resolves through the plane's strong pin
+        (held from flush until the actor's slot is swept) or the
+        system's weak registry (hit for any cell the runtime still
+        references, i.e. every live actor), so an unresolvable uid is a
+        swept one."""
+        m = self._uid_to_slot = self._dense_map(self._uid_to_slot, top, -1)
         slots = m[uids]
         missing = slots < 0
         if missing.any():
@@ -647,6 +691,40 @@ class ArrayShadowGraph:
             slots = m[uids]
         return slots
 
+    def _slots_for_foreign(self, fuids: np.ndarray, top: int) -> np.ndarray:
+        """Foreign uids (plain, ``FOREIGN_BIT`` off; ``top`` is the
+        largest) through their own dense array, interning all unseen
+        ones at once: slots popped in
+        bulk and every per-slot array written as an array, no cell and
+        no Python per uid.  No registry resolves a foreign uid, so the
+        map keeps a tombstone (``_SWEPT``) where a slot was freed: a
+        late row naming a swept uid is dropped (-1), never re-interned,
+        while a uid never seen before is interned."""
+        m = self._fuid_to_slot = self._dense_map(self._fuid_to_slot, top, _UNSEEN)
+        slots = m[fuids]
+        unseen = slots == _UNSEEN
+        if unseen.any():
+            new = np.unique(fuids[unseen])
+            k = int(new.size)
+            # the stack pops lowest-first: the lowest uid, the lowest
+            # slot; what is free now goes first, then what growing adds
+            have = min(k, len(self.free_slots))
+            at = self.free_slots.pop_batch(have)[::-1]
+            if have < k:
+                self._grow_nodes(min_free=k - have)
+                at = np.concatenate([at, self.free_slots.pop_batch(k - have)[::-1]])
+            self.flags[at] = _F.FLAG_IN_USE  # not interned, not local
+            self.recv_count[at] = 0
+            self.supervisor[at] = -1
+            self._slot_uid[at] = new | FOREIGN_BIT
+            m[new] = at
+            self.total_actors_seen += k
+            self._has_foreign = True
+            if self._node_log is not None:
+                self._node_log.update(at.tolist())
+            slots = m[fuids]
+        return np.where(slots == _SWEPT, -1, slots)
+
     def merge_packed(self, rows: np.ndarray) -> None:
         """Fold a drained batch of packed rows: restore global flush
         order from the seq column, map uids to slots, and run the same
@@ -655,6 +733,7 @@ class ArrayShadowGraph:
         guarding cross-batch staleness (see _apply_batch) and fields
         naming proven-garbage uids dropped (see _slots_for_uids)."""
         E = self.context.entry_field_size
+        seen = self.total_actors_seen
         order = np.argsort(rows[:, 0], kind="stable")
         R = rows[order]
 
@@ -747,6 +826,11 @@ class ArrayShadowGraph:
             sl, brr, rdd, ek, esign, sp_s, sp_parent,
             sl_seq=sl_seq, sp_seq=sp_seq,
         )
+        if self.profile_wake is not None:
+            self.profile_wake.note(
+                fold_rows=int(rows.shape[0]),
+                uids_interned=self.total_actors_seen - seen,
+            )
 
     def _apply_edge_deltas(self, keys: np.ndarray, deltas: np.ndarray) -> None:
         """Vectorized ``_update_edge`` over unique packed keys with
@@ -988,6 +1072,9 @@ class ArrayShadowGraph:
                 flags_dev = jax.device_put(self.flags)
                 recv_dev = jax.device_put(self.recv_count)
                 staged = dec.stage_wake()
+                event["upload_bytes"] = self.flags.nbytes + self.recv_count.nbytes
+                if wake is not None:
+                    wake.note(upload_bytes=event["upload_bytes"])
             with events.wake_phase(wake, "device"):
                 mark_w = dec.wake_device(flags_dev, recv_dev, staged)
                 mark_w.block_until_ready()
@@ -1148,11 +1235,7 @@ class ArrayShadowGraph:
                     kill = np.concatenate([kill, pad])
                 garbage_slots = np.nonzero(garbage)[0]
                 kill_slots = np.nonzero(kill)[0]
-                if should_kill and kill_slots.size:
-                    self._kill_slots_bulk(kill_slots)
-                if garbage_slots.size:
-                    self._free_slots_batch(garbage, garbage_slots)
-                self._note_sweep(wake, should_kill, kill_slots, garbage_slots)
+                self._sweep(wake, should_kill, garbage, garbage_slots, kill_slots)
             ev.fields["num_garbage_actors"] = int(garbage_slots.size)
             ev.fields["num_live_actors"] = int(np.count_nonzero(mark))
         return int(garbage_slots.size)
@@ -1180,42 +1263,70 @@ class ArrayShadowGraph:
                 )
                 garbage_slots = np.nonzero(garbage)[0]
                 kill_slots = np.nonzero(kill)[0]
-
-                if should_kill and kill_slots.size:
-                    self._kill_slots_bulk(kill_slots)
-
-                if garbage_slots.size:
-                    self._free_slots_batch(garbage, garbage_slots)
-                self._note_sweep(wake, should_kill, kill_slots, garbage_slots)
+                self._sweep(wake, should_kill, garbage, garbage_slots, kill_slots)
 
             ev.fields["num_garbage_actors"] = int(garbage_slots.size)
             ev.fields["num_live_actors"] = int(np.count_nonzero(mark))
         return int(garbage_slots.size)
 
-    @staticmethod
-    def _note_sweep(wake, should_kill, kill_slots, garbage_slots) -> None:
-        """The sweep's counts into the active wake's record."""
+    def _sweep(
+        self,
+        wake,
+        should_kill: bool,
+        garbage: np.ndarray,
+        garbage_slots: np.ndarray,
+        kill_slots: np.ndarray,
+    ) -> None:
+        """Act on a trace's verdicts: stop the kill set, free every
+        garbage slot, hand the foreign uids among both to the sink, and
+        give the active wake's record the counts.  The sink is called
+        once per trace, also with nothing to hand over: to the mutator
+        side that is the verdict on what it shipped before this wake."""
+        kill_uids = freed_uids = _NO_UIDS
+        if should_kill and kill_slots.size:
+            kill_uids = self._kill_slots_bulk(kill_slots)
+        if garbage_slots.size:
+            freed_uids = self._free_slots_batch(garbage, garbage_slots)
+        sink = self.foreign_sink
+        if sink is not None:
+            sink(kill_uids, freed_uids)
         if wake is not None:
             wake.note(
                 kills=int(kill_slots.size) if should_kill else 0,
                 freed=int(garbage_slots.size),
+                kill_uids=int(kill_uids.size),
             )
 
-    def _kill_slots_bulk(self, kill_slots: np.ndarray) -> None:
+    def _foreign_among(self, slots: np.ndarray):
+        """``(is_foreign, uids)`` of ``slots``: which have no cell but a
+        foreign uid, and those uids (plain)."""
+        codes = self._slot_uid[slots]
+        is_foreign = codes >= FOREIGN_BIT
+        return is_foreign, codes[is_foreign] ^ FOREIGN_BIT
+
+    def _kill_slots_bulk(self, kill_slots: np.ndarray) -> np.ndarray:
         """Send StopMsg to every kill slot's cell as ONE bulk teardown:
         the finalize cascade is batched per dispatcher (and, for remote
         cells, per peer writer), so a wake that kills K actors costs
-        O(batches) dispatcher operations, not O(K)."""
+        O(batches) dispatcher operations, not O(K).  Slots of foreign
+        actors have no cell to tell: their uids are returned, for the
+        sink."""
         from ...runtime.cell import tell_bulk
 
+        kill_uids = _NO_UIDS
+        if self._has_foreign:
+            is_foreign, kill_uids = self._foreign_among(kill_slots)
+            kill_slots = kill_slots[~is_foreign]
         cells = self.cells
         tell_bulk((cells[slot], StopMsg) for slot in kill_slots.tolist())
+        return kill_uids
 
     def _free_slots_batch(
         self, garbage: np.ndarray, garbage_slots: np.ndarray
-    ) -> None:
+    ) -> np.ndarray:
         """Free every garbage slot in one vectorized pass (the sweep,
-        reference: ShadowGraph.java:273-289).
+        reference: ShadowGraph.java:273-289).  Returns the foreign uids
+        among the freed, for the sink.
 
         Incident edges are found by scanning the flat edge arrays — an
         edge is allocated iff its weight is nonzero — instead of per-slot
@@ -1257,21 +1368,30 @@ class ArrayShadowGraph:
         self.flags[garbage_slots] = 0
         self.recv_count[garbage_slots] = 0
 
-        # Invalidate packed-plane uid mappings and drop the strong pins
-        # for freed slots.  A proven-garbage actor can never matter
-        # again (CRGC garbage is monotone), so any later row naming its
-        # uid is droppable — _slots_for_uids handles the unresolvable
-        # case.  Slot reuse also resets the flush-stamp guards.
+        # Invalidate packed-plane uid mappings for freed slots.  A
+        # proven-garbage actor can never matter again (CRGC garbage is
+        # monotone), so any later row naming its uid is droppable, and
+        # _slots_for_uids has to know it for swept: a local uid by
+        # resolving nowhere once its mapping and its strong pin are gone
+        # (dropped here), a foreign uid, which no registry ever
+        # resolved, by the tombstone written here.  Slot reuse also
+        # resets the flush-stamp guards.
         su = self._slot_uid
-        freed_uids = su[garbage_slots]
+        cell_slots = garbage_slots
+        freed_foreign = _NO_UIDS
+        if self._has_foreign:
+            is_foreign, freed_foreign = self._foreign_among(garbage_slots)
+            self._fuid_to_slot[freed_foreign] = _SWEPT
+            cell_slots = garbage_slots[~is_foreign]
+        freed_uids = su[cell_slots]
         had_uid = freed_uids >= 0
         if had_uid.any():
             self._uid_to_slot[freed_uids[had_uid]] = -1
-            su[garbage_slots] = -1
             if self._plane is not None:
                 pop = self._plane.uid_strong.pop
                 for uid in freed_uids[had_uid].tolist():
                     pop(uid, None)
+        su[garbage_slots] = -1
         self._br_seq[garbage_slots] = -1
         self._sup_seq[garbage_slots] = -1
 
@@ -1288,11 +1408,11 @@ class ArrayShadowGraph:
             for key in dead_keys:
                 del sm[key]
 
+        # what a slot holds per cell; a foreign slot holds none of it
         cells = self.cells
         locations = self.locations
         slot_of = self.slot_of
-        slots_list = garbage_slots.tolist()
-        for slot in slots_list:
+        for slot in cell_slots.tolist():
             cell = cells[slot]
             if cell is not None:
                 slot_of.pop(cell, None)
@@ -1300,7 +1420,8 @@ class ArrayShadowGraph:
             locations[slot] = None
         self.free_slots.push_batch(garbage_slots)
         if self._node_log is not None:
-            self._node_log.update(slots_list)
+            self._node_log.update(garbage_slots.tolist())
+        return freed_foreign
 
     # ------------------------------------------------------------- #
     # Waves (reference: ShadowGraph.java:291-299)

@@ -40,6 +40,17 @@ class _Wakeup:
         return "Wakeup"
 
 
+class _Fold:
+    """Drain and fold what has been flushed, and leave the trace to the
+    next wake-up: a bulk loader's message (it ships a large graph as
+    many blocks of rows and wants one verdict, after the last)."""
+
+    __slots__ = ()
+
+    def __repr__(self) -> str:
+        return "Fold"
+
+
 class _StartWave:
     __slots__ = ()
 
@@ -52,6 +63,7 @@ class _FinalizeEgresses:
 
 
 WAKEUP = _Wakeup()
+FOLD = _Fold()
 START_WAVE = _StartWave()
 FINALIZE_EGRESSES = _FinalizeEgresses()
 
@@ -193,6 +205,9 @@ class Bookkeeper(RawBehavior):
         if isinstance(msg, _Wakeup):
             if self.started:
                 self.collect()
+        elif isinstance(msg, _Fold):
+            if self.started:
+                self.collect(trace=False)
         elif isinstance(msg, _StartWave):
             self.shadow_graph.start_wave()
             self._graph_dirty = True
@@ -376,8 +391,9 @@ class Bookkeeper(RawBehavior):
     # Collection (reference: LocalGC.scala:144-196)
     # ------------------------------------------------------------- #
 
-    def collect(self) -> int:
-        """One collector wake.  Observability wrapping (both optional,
+    def collect(self, trace: bool = True) -> int:
+        """One collector wake (``trace=False``: drain and fold only, the
+        graph stays dirty for the next).  Observability wrapping (both optional,
         both attached by ``telemetry.Telemetry``): the whole wake runs
         inside a ``gc_wave`` span whose context becomes the causal
         parent of the terminations it triggers, and the wake profiler
@@ -409,11 +425,11 @@ class Bookkeeper(RawBehavior):
             if tracer is not None:
                 with tracer.span("gc_wave", node=engine.system.address) as span:
                     tracer.note_wave(span.ctx)
-                    count, n_garbage = self._collect_inner(wake)
+                    count, n_garbage = self._collect_inner(wake, trace)
                     span.args["entries"] = count
                     span.args["garbage"] = n_garbage
             else:
-                count, n_garbage = self._collect_inner(wake)
+                count, n_garbage = self._collect_inner(wake, trace)
         finally:
             # A raising wake must still close its profiler accounting,
             # or _active dangles and later sweep/device events are
@@ -446,9 +462,13 @@ class Bookkeeper(RawBehavior):
         self._after_wake(n_garbage)
         return count
 
-    def _collect_inner(self, wake: Any) -> tuple:
+    def _collect_inner(self, wake: Any, trace: bool = True) -> tuple:
         """Drain, fold, trace.  Returns ``(num_entries, n_garbage)``."""
         engine = self.engine
+        if wake is not None:
+            # what the backend counts where it has something to count
+            # (arrays.py: the packed fold, the upload, the sweep)
+            wake.note(fold_rows=0, uids_interned=0, upload_bytes=0, kill_uids=0)
         queue = engine.queue
         pool = engine.entry_pool
         count = 0
@@ -498,6 +518,8 @@ class Bookkeeper(RawBehavior):
         if count:
             self._graph_dirty = True
         graph = self.shadow_graph
+        if not trace:
+            return count, 0
         with _phase(wake, "trace"):
             if self.engine.pipelined and getattr(graph, "can_pipeline", False):
                 # Pipelined: sweep the previous wake's verdicts (if its

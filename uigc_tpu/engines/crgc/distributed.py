@@ -1065,7 +1065,10 @@ class DistributedBookkeeper(Bookkeeper):
 
     # -- the collector wake ------------------------------------------ #
 
-    def _collect_inner(self, wake: Any) -> tuple:
+    def _collect_inner(self, wake: Any, trace: bool = True) -> tuple:
+        # ``trace`` is the bulk loader's fold-only wake (collector.py
+        # _Fold); its rows come through the packed plane, which the
+        # partitioned mode never has, so every wake here is a whole one
         engine = self.engine
         queue = engine.queue
         pool = engine.entry_pool

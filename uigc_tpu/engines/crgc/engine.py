@@ -104,6 +104,25 @@ class CRGC(Engine):
             self.packed_plane = PackedPlane(self.crgc_context.entry_field_size)
             graph.attach_packed_plane(self.packed_plane, system.resolve_cell)
 
+    def set_foreign_sink(self, sink: Optional[Callable[[Any, Any], None]]) -> None:
+        """Where the collector answers for foreign actors (packed.py:
+        actors known by uid alone, whose cells live in the mutator
+        processes that ship this collector their flushes through
+        ``packed_plane.write_foreign``).  ``sink(kill_uids, freed_uids)``
+        is called on the collector's thread once per trace with two
+        int64 arrays of plain foreign uids: the actors to stop (garbage
+        whose supervisor is live: the stop cascades from them, as
+        ``StopMsg`` does among local cells) and every foreign actor the
+        sweep freed, each uid once over the system's life.  Both may be
+        empty: the call is the verdict on what was shipped before the
+        wake."""
+        if self.packed_plane is None:
+            raise ValueError(
+                "foreign actors need the packed plane (uigc.crgc.packed-entries "
+                "on a single node, an array shadow graph)"
+            )
+        self.bookkeeper.shadow_graph.foreign_sink = sink
+
     # Factory hooks so the multi-node engine can substitute richer parts.
 
     def make_bookkeeper(self) -> Bookkeeper:

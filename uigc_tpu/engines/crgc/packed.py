@@ -28,6 +28,21 @@ pins every cell named by an in-flight row so the collector can always
 resolve it; pins live until the actor's slot is swept
 (ArrayShadowGraph._free_slots_batch pops them) — interning alone does
 not release a pin, it only makes future lookups bypass it.
+
+Foreign actors.  An actor whose cell lives in another process (a mutator
+process that ships this collector its entry flushes: the JVM actor
+systems of the north star) has no ``ActorCell`` here: the collector
+knows it by uid alone and answers with the uids to stop.  Such a uid is
+the mutator side's own dense number, carried in a row with
+``FOREIGN_BIT`` set (:func:`foreign`), so one row may name local and
+foreign actors side by side and every other rule of the layout holds.
+A foreign uid names one actor for good: it is never pinned, never
+resolved, and never handed out again after its actor was swept
+(ArrayShadowGraph keeps a tombstone for it).  :meth:`PackedPlane.
+write_foreign` takes a whole block of rows in the mutator side's plain
+uids, tags and stamps them, and publishes them at once; the sweep hands
+the uids to stop to the sink the engine exposes
+(``CRGC.set_foreign_sink``).
 """
 
 from __future__ import annotations
@@ -40,9 +55,31 @@ import numpy as np
 
 ROW_FIXED = 4  # seq, self uid, busy/root bits, recv count
 
+#: set in a row's uid field that names a foreign actor; far above any
+#: ``ActorCell.uid`` a system's counter reaches, and still >= 0, so the
+#: fold's "field in use" tests read it like any uid
+FOREIGN_BIT = 1 << 62
+
 
 def row_width(entry_field_size: int) -> int:
     return ROW_FIXED + 5 * entry_field_size
+
+
+def foreign(uid):
+    """The row code of foreign uid(s) ``uid`` (an int or an array)."""
+    return uid | FOREIGN_BIT
+
+
+def uid_columns(entry_field_size: int) -> np.ndarray:
+    """The columns of a row that hold uids: self, the created pairs,
+    the spawned children and the target of each updated pair (its other
+    half is the packed refob info, which is no uid)."""
+    E = entry_field_size
+    return np.concatenate([
+        [1],
+        np.arange(ROW_FIXED, ROW_FIXED + 3 * E),
+        np.arange(ROW_FIXED + 3 * E, ROW_FIXED + 5 * E, 2),
+    ])
 
 
 class PackedRing:
@@ -78,15 +115,34 @@ class PackedRing:
     def commit(self) -> None:
         self.w += 1
 
-    def _grow(self) -> None:
-        # Reader excluded by the lock; relinearize [r, w) from 0.
+    def extend(self, rows: np.ndarray) -> None:
+        """Write a block of rows and publish them at once: the reader
+        sees all of the block or none of it."""
+        k = rows.shape[0]
+        if self.w - self.r + k > self.cap:
+            with self.lock:
+                self._grow(self.w - self.r + k)
+        cap = self.cap
+        i0 = self.w & (cap - 1)
+        head = min(k, cap - i0)
+        self.buf[i0 : i0 + head] = rows[:head]
+        if head < k:  # wraps
+            self.buf[: k - head] = rows[head:]
+        self.w += k
+
+    def _grow(self, need: int = 0) -> None:
+        # Reader excluded by the lock; relinearize [r, w) from 0, into
+        # the power of two that holds ``need`` rows (at least double).
         cap, r, w = self.cap, self.r, self.w
-        new = np.empty((cap * 2, self.buf.shape[1]), dtype=np.int64)
+        new_cap = cap * 2
+        while new_cap < need:
+            new_cap *= 2
+        new = np.empty((new_cap, self.buf.shape[1]), dtype=np.int64)
         idx = (np.arange(r, w) & (cap - 1))
         count = w - r
         new[:count] = self.buf[idx]
         self.buf = new
-        self.cap = cap * 2
+        self.cap = new_cap
         self.r = 0
         self.w = count
 
@@ -117,6 +173,7 @@ class PackedPlane:
         #: itertools.count.__next__ is a single C call — atomic under
         #: the GIL, so concurrent flushes get distinct ordered stamps.
         self._seq = itertools.count()
+        self._uid_cols = uid_columns(entry_field_size)
         #: cells named by in-flight rows; dict.setdefault / .pop are
         #: individually atomic under the GIL.  Pins persist until the
         #: collector SWEEPS the actor's slot (_free_slots_batch), not
@@ -132,6 +189,26 @@ class PackedPlane:
 
     def next_seq(self) -> int:
         return next(self._seq)
+
+    def write_foreign(self, rows: np.ndarray) -> None:
+        """Hand over a block of rows whose every uid is foreign: the
+        flushes a mutator process shipped, in its own plain uids (-1 =
+        empty field), in flush order.  The block is tagged
+        (``FOREIGN_BIT`` on every uid field in use), stamped with
+        consecutive flush stamps in its order (column 0 is overwritten)
+        and published to the calling thread's ring at once, so a drain
+        takes all of it or none.  ``rows`` is written in place."""
+        k = rows.shape[0]
+        if not k:
+            return
+        cols = self._uid_cols
+        uids = rows[:, cols]
+        np.bitwise_or(uids, FOREIGN_BIT, out=uids, where=uids >= 0)
+        rows[:, cols] = uids
+        # islice over the C counter takes no Python step per stamp, so
+        # no other thread's flush lands inside the block's stamps
+        rows[:, 0] = np.fromiter(itertools.islice(self._seq, k), np.int64, k)
+        self.ring().extend(rows)
 
     def ring(self) -> PackedRing:
         r = getattr(self._tl, "ring", None)

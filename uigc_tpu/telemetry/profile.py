@@ -16,8 +16,14 @@ pipeline's named phases:
 - ``device``     dispatch of the wake program until its result is ready
 - ``readback``   device -> host: the verdicts unpacked to a bool vector
 - ``sweep``      kill decisions + slot frees (its record carries the
-                 ``kills`` and ``freed`` counts)
+                 ``kills`` and ``freed`` counts, and ``kill_uids``: the
+                 foreign uids handed to the engine's sink to stop)
 - ``broadcast``  delta-graph serialization + peer broadcast (multi-node)
+
+A wake's record also carries ``fold_rows`` (packed rows folded),
+``uids_interned`` (uids the fold interned, local or foreign) and
+``upload_bytes`` (what the device call's ``device_put``s were handed):
+0 where a backend has nothing to count.
 
 Phases are exclusive: a nested phase pauses the enclosing one, so the
 phases of a wake add up to its ``wall_s`` less the few statements
