@@ -214,6 +214,17 @@ def test_decremental_wake_compiles_at_tree_100k(one_chip):
     assert fn.jump_price == 1
 
 
+def test_verdict_reduce_compiles_at_the_engine_cells_capacity(one_chip):
+    """The sweep's reduce of the wake's words (``verdict_words``) at
+    2^24 slots: one fusion, 2 MB of words and a scalar out."""
+    import jax.numpy as jnp
+
+    words = _struct((4096, LANE), jnp.int32, one_chip)
+    compiled = pd.verdict_reduce().lower(words, words).compile()
+    assert compiled.memory_analysis().output_size_in_bytes < 3 << 20
+    assert "popcnt" in compiled.as_text()
+
+
 @pytest.mark.parametrize(
     "mode", pt.TRACE_MODES, ids=lambda m: f"wake-{m}-plain"
 )

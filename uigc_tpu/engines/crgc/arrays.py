@@ -25,12 +25,13 @@ dropping them preserves liveness verdicts while keeping slots recyclable.
 
 from __future__ import annotations
 
-from typing import TYPE_CHECKING, Dict, List, Optional, Set
+from typing import TYPE_CHECKING, Dict, List, NamedTuple, Optional, Set, Tuple
 
 import numpy as np
 
 from ...ops import pallas_incremental as pallas_incremental_kinds
 from ...ops import trace as trace_ops
+from ...ops.edgeindex import EndpointIndex
 from ...ops.i64map import I64Map, IntStack
 from ...utils import events
 from ...utils.validation import require
@@ -50,6 +51,22 @@ _PAIR_SUP = pallas_incremental_kinds.SUP
 _UNSEEN = -1
 _SWEPT = -2
 _NO_UIDS = np.empty(0, dtype=np.int64)
+#: a death of more slots than this share of the edge capacity is swept
+#: by the one scan over the edge arrays: the index costs about a
+#: microsecond a dead slot with its references (binary searches over the
+#: runs, then gathers), the scan ten to twenty nanoseconds an edge slot
+_SCAN_SHARE = 64
+
+
+class PackedVerdicts(NamedTuple):
+    """A device wake's verdicts as the host reads them
+    (``DecrementalTracer.verdict_words``): the words of the slots in use
+    and unmarked, and the number of marks.  What :meth:`compute_marks`
+    returns in place of a dense mark vector where the trace ran as the
+    device's wake."""
+
+    garbage_w: np.ndarray
+    num_live: int
 
 
 def _readback(value, site: str) -> np.ndarray:
@@ -208,14 +225,20 @@ class ArrayShadowGraph:
         self.edge_src = np.zeros(ecap, dtype=np.int32)
         self.edge_dst = np.zeros(ecap, dtype=np.int32)
         self.edge_weight = np.zeros(ecap, dtype=np.int64)
-        #: packed (owner << 32 | target) int64 key -> edge id.  An edge is
-        #: allocated iff its weight is nonzero, which is what lets the
-        #: sweep find every edge incident to a garbage set with one
-        #: vectorized scan instead of per-slot incident sets.  A
+        #: packed (owner << 32 | target) int64 key -> edge id, the exact
+        #: pair.  An edge is allocated iff its weight is nonzero.  A
         #: vectorized hash table, not a dict: the fold's per-batch key
         #: traffic is the collector's hottest map (ops/i64map.py).
         self.edge_of = I64Map()
         self.free_edges = IntStack.from_range(0, ecap)
+        #: the allocated edges by either endpoint (ops/edgeindex.py):
+        #: told of every id allocated, asked by the sweep for the edges
+        #: that hang on the dead, so that a small death costs by the
+        #: dead and not by ``edge_capacity``
+        self._endpoints = EndpointIndex()
+        #: the sweep's membership vector, true at the slots being freed
+        #: for the length of :meth:`_free_slots_batch` and nowhere else
+        self._dying = np.zeros(cap, dtype=bool)
 
         #: changelog of pair transitions since the Pallas layout last
         #: consumed it: (insert?, src, dst, kind).  ``None`` means either
@@ -262,6 +285,7 @@ class ArrayShadowGraph:
         self._sup_seq = np.concatenate(
             [self._sup_seq, np.full(more, -1, dtype=np.int64)]
         )
+        self._dying = np.zeros(new, dtype=bool)
         self.capacity = new
         # Node capacity sets the bit-table/supertile geometry: the whole
         # Pallas layout must be rebuilt.
@@ -352,6 +376,7 @@ class ArrayShadowGraph:
             if not self.free_edges:
                 self._grow_edges()
             eid = self.free_edges.pop()
+            self._endpoints.add(eid)
             self.edge_of[key] = eid
             self.edge_src[eid] = owner
             self.edge_dst[eid] = target
@@ -879,6 +904,7 @@ class ArrayShadowGraph:
             if len(self.free_edges) < need:
                 self._grow_edges(min_free=need)
             aa = self.free_edges.pop_batch(need)
+            self._endpoints.add_batch(aa)
             self.edge_src[aa] = (new_keys >> 32).astype(np.int32)
             self.edge_dst[aa] = (new_keys & 0xFFFFFFFF).astype(np.int32)
             self.edge_weight[aa] = d_new
@@ -949,15 +975,15 @@ class ArrayShadowGraph:
     # Trace + sweep (reference: ShadowGraph.java:205-289)
     # ------------------------------------------------------------- #
 
-    def compute_marks(self) -> np.ndarray:
+    def compute_marks(self):
+        """The trace's verdicts: the dense mark vector from the host
+        fixpoint, :class:`PackedVerdicts` from the device's wake (the
+        sweep takes either, :meth:`_verdict_slots`)."""
         if self.use_device:
             self._note_device_wake()
             with events.recorder.timed(events.DEVICE_TRACE) as ev:
                 ev.fields["trace_mode"] = self.trace_mode
-                return _readback(
-                    self._compute_marks_decremental(ev.fields),
-                    "marks.decremental",
-                )
+                return self._compute_marks_decremental(ev.fields)
         # Host path: slice to the occupancy watermark.  Slots allocate
         # lowest-first (IntStack from_range), so live slots cluster low
         # and the 12-sweep fixpoint need not scan the grown capacity —
@@ -1050,7 +1076,7 @@ class ArrayShadowGraph:
         self.profile_wake.note(**fields)
         event.update(fields)
 
-    def _compute_marks_decremental(self, event: dict) -> np.ndarray:
+    def _compute_marks_decremental(self, event: dict) -> PackedVerdicts:
         """Per-wake detection through the decremental tracer
         (ops/pallas_decremental.py; the steady-state analogue of the
         reference's 50ms incremental collect, LocalGC.scala:144-186):
@@ -1061,7 +1087,8 @@ class ArrayShadowGraph:
 
         The device call in its four steps, each a profiler phase when a
         wake is attached: layout maintenance, upload, the wake program
-        from dispatch until its result is ready, readback."""
+        from dispatch until its result is ready, readback (of the
+        verdict words: 1/8 of a byte a slot, not a bool vector)."""
         import jax
 
         wake = self.profile_wake
@@ -1079,16 +1106,23 @@ class ArrayShadowGraph:
                 mark_w = dec.wake_device(flags_dev, recv_dev, staged)
                 mark_w.block_until_ready()
             with events.wake_phase(wake, "readback"):
-                marks = dec.unpack_marks(mark_w)
+                verdicts = self._read_verdicts(dec, mark_w, "marks.decremental")
                 if wake is not None:  # and the wake's own counters
                     self._note_sweep_stats(dec.wake_stats(1)[-1], event)
-            return marks
+            return verdicts
         except Exception:
             # A poisoned async result surfaces at the wait or at the
             # readback, after the tracer committed state; drop it so the
             # next wake re-derives instead of feeding poisoned arrays.
             dec.invalidate()
             raise
+
+    @staticmethod
+    def _read_verdicts(dec, mark_w, site: str) -> PackedVerdicts:
+        """The verdict words of ``dec``'s last wake on the host, the
+        crossing accounted at ``site``."""
+        garbage_w, marked = dec.verdict_words(mark_w)
+        return PackedVerdicts(_readback(garbage_w, site), marked)
 
     # ------------------------------------------------------------- #
     # Pipelined collection (SURVEY §7 "hard parts": the 50ms cadence
@@ -1210,35 +1244,24 @@ class ArrayShadowGraph:
         dec, mark_w, snap_flags, snap_sup, _ = self._pending_wake
         self._pending_wake = None
         with events.recorder.timed(events.TRACING) as ev:
-            # unpack_marks auto-invalidates the tracer on readback
-            # failure, so a poisoned wake needs no handling here.
+            # the handle invalidates itself where the readback fails, so
+            # a poisoned wake needs no handling here
             if getattr(dec, "accounts_readback", False):
-                # The handle already routed the crossing through
-                # _readback (the mesh wake handle does, under its
-                # collective lock) — accounting it again here would
+                # The mesh wake handle: a dense vector, whose crossing
+                # it already routed through _readback under its
+                # collective lock — accounting it again here would
                 # double-count every harvested wake's transfer bytes.
-                mark = np.asarray(dec.unpack_marks(mark_w))  # readback: accounted in the handle
+                verdicts = np.asarray(dec.unpack_marks(mark_w))  # readback: accounted in the handle
             else:
-                mark = _readback(dec.unpack_marks(mark_w), "marks.harvest")
-            wake = self.profile_wake
-            with events.wake_phase(wake, "sweep"), events.recorder.timed(events.SWEEP):
-                garbage, kill = trace_ops.garbage_and_kills_np(
-                    snap_flags, snap_sup, mark
-                )
-                if garbage.shape[0] < self.capacity:
-                    # capacity grew between launch and harvest: slots beyond
-                    # the snapshot were interned after it, so they carry no
-                    # verdict (not garbage) — pad so the sweep's edge scans
-                    # index the grown arrays safely
-                    pad = np.zeros(self.capacity - garbage.shape[0], bool)
-                    garbage = np.concatenate([garbage, pad])
-                    kill = np.concatenate([kill, pad])
-                garbage_slots = np.nonzero(garbage)[0]
-                kill_slots = np.nonzero(kill)[0]
-                self._sweep(wake, should_kill, garbage, garbage_slots, kill_slots)
-            ev.fields["num_garbage_actors"] = int(garbage_slots.size)
-            ev.fields["num_live_actors"] = int(np.count_nonzero(mark))
-        return int(garbage_slots.size)
+                verdicts = self._read_verdicts(dec, mark_w, "marks.harvest")
+            # Slots beyond the snapshot were interned after it: they
+            # carry no verdict, and none of them is among these ids.
+            n_garbage, n_live = self._sweep(
+                should_kill, snap_flags, snap_sup, verdicts
+            )
+            ev.fields["num_garbage_actors"] = n_garbage
+            ev.fields["num_live_actors"] = n_live
+        return n_garbage
 
     def trace(self, should_kill: bool) -> int:
         # A synchronous trace sweeps against CURRENT state; an
@@ -1250,52 +1273,105 @@ class ArrayShadowGraph:
         self._pending_wake = None
         with events.recorder.timed(events.TRACING) as ev:
             if self.capture_parents:
-                mark = self._compute_marks_with_parents()
+                verdicts = self._compute_marks_with_parents()
             else:
-                mark = self.compute_marks()
-            # The sweep (kill decisions + slot frees) is its own
-            # profiler phase, so trace stays exclusive of it, and its
-            # own timed event.
-            wake = self.profile_wake
-            with events.wake_phase(wake, "sweep"), events.recorder.timed(events.SWEEP):
-                garbage, kill = trace_ops.garbage_and_kills_np(
-                    self.flags, self.supervisor, mark
-                )
-                garbage_slots = np.nonzero(garbage)[0]
-                kill_slots = np.nonzero(kill)[0]
-                self._sweep(wake, should_kill, garbage, garbage_slots, kill_slots)
+                verdicts = self.compute_marks()
+            n_garbage, n_live = self._sweep(
+                should_kill, self.flags, self.supervisor, verdicts
+            )
+            ev.fields["num_garbage_actors"] = n_garbage
+            ev.fields["num_live_actors"] = n_live
+        return n_garbage
 
-            ev.fields["num_garbage_actors"] = int(garbage_slots.size)
-            ev.fields["num_live_actors"] = int(np.count_nonzero(mark))
-        return int(garbage_slots.size)
+    @staticmethod
+    def _verdict_slots(
+        flags: np.ndarray, supervisor: np.ndarray, verdicts
+    ) -> Tuple[np.ndarray, np.ndarray, int]:
+        """``(garbage_slots, kill_slots, num_live)`` of a trace's
+        verdicts over the ``flags`` and ``supervisor`` it was taken
+        over, both ascending: the nonzeros of
+        ``trace_ops.garbage_and_kills_np``.  A dense mark vector goes
+        through that function; :class:`PackedVerdicts` never become a
+        vector: the few nonzero words are expanded to slot ids and the
+        kill rule (local, not halted, supervisor marked) is applied to
+        those slots alone.  A slot is garbage iff its bit is set and
+        ``flags`` has it in use; a supervisor is marked iff it is in use
+        and its garbage bit is clear, since marks never leave the
+        in-use set."""
+        if not isinstance(verdicts, PackedVerdicts):
+            garbage, kill = trace_ops.garbage_and_kills_np(
+                flags, supervisor, verdicts
+            )
+            return (
+                np.nonzero(garbage)[0],
+                np.nonzero(kill)[0],
+                int(np.count_nonzero(verdicts)),
+            )
+        words = verdicts.garbage_w
+        at = np.flatnonzero(words)
+        bits = np.unpackbits(
+            words[at].view(np.uint8), bitorder="little"
+        )  # little-endian words: byte order is bit order
+        hit = np.flatnonzero(bits)
+        g = (at[hit >> 5] << 5) | (hit & 31)
+        # in use by the flags GIVEN, as the dense rule has it: the words
+        # are of the flags as the device read them, and a backend whose
+        # device_put aliases host memory (the CPU's) may have read slots
+        # a fold interned after a pipelined launch's snapshot
+        g = g[(flags[g] & _F.FLAG_IN_USE) != 0]
+        f = flags[g]
+        sup = supervisor[g].astype(np.int64)
+        has_sup = sup >= 0
+        sup[~has_sup] = 0
+        sup_marked = (
+            has_sup
+            & ((flags[sup] & _F.FLAG_IN_USE) != 0)
+            & ((words[sup >> 5] >> (sup & 31).astype(np.uint32)) & 1 == 0)
+        )
+        kill = (
+            ((f & _F.FLAG_LOCAL) != 0) & ((f & _F.FLAG_HALTED) == 0) & sup_marked
+        )
+        return g, g[kill], verdicts.num_live
 
     def _sweep(
         self,
-        wake,
         should_kill: bool,
-        garbage: np.ndarray,
-        garbage_slots: np.ndarray,
-        kill_slots: np.ndarray,
-    ) -> None:
-        """Act on a trace's verdicts: stop the kill set, free every
-        garbage slot, hand the foreign uids among both to the sink, and
-        give the active wake's record the counts.  The sink is called
-        once per trace, also with nothing to hand over: to the mutator
-        side that is the verdict on what it shipped before this wake."""
-        kill_uids = freed_uids = _NO_UIDS
-        if should_kill and kill_slots.size:
-            kill_uids = self._kill_slots_bulk(kill_slots)
-        if garbage_slots.size:
-            freed_uids = self._free_slots_batch(garbage, garbage_slots)
-        sink = self.foreign_sink
-        if sink is not None:
-            sink(kill_uids, freed_uids)
-        if wake is not None:
-            wake.note(
-                kills=int(kill_slots.size) if should_kill else 0,
-                freed=int(garbage_slots.size),
-                kill_uids=int(kill_uids.size),
+        flags: np.ndarray,
+        supervisor: np.ndarray,
+        verdicts,
+    ) -> Tuple[int, int]:
+        """Act on a trace's verdicts, taken over ``flags`` and
+        ``supervisor`` (the graph's own, or a pipelined wake's
+        snapshot): stop the kill set, free every garbage slot, hand the
+        foreign uids among both to the sink, and give the active wake's
+        record the counts.  The one sweep of every backend; its own
+        profiler phase and timed event, so the trace stays exclusive of
+        it.  The sink is called once per trace, also with nothing to
+        hand over: to the mutator side that is the verdict on what it
+        shipped before this wake.  Returns ``(garbage actors, live
+        actors)``."""
+        wake = self.profile_wake
+        with events.wake_phase(wake, "sweep"), events.recorder.timed(events.SWEEP):
+            garbage_slots, kill_slots, n_live = self._verdict_slots(
+                flags, supervisor, verdicts
             )
+            kill_uids = freed_uids = _NO_UIDS
+            examined = 0
+            if should_kill and kill_slots.size:
+                kill_uids = self._kill_slots_bulk(kill_slots)
+            if garbage_slots.size:
+                freed_uids, examined = self._free_slots_batch(garbage_slots)
+            sink = self.foreign_sink
+            if sink is not None:
+                sink(kill_uids, freed_uids)
+            if wake is not None:
+                wake.note(
+                    kills=int(kill_slots.size) if should_kill else 0,
+                    freed=int(garbage_slots.size),
+                    kill_uids=int(kill_uids.size),
+                    sweep_edge_slots=examined,
+                )
+        return int(garbage_slots.size), n_live
 
     def _foreign_among(self, slots: np.ndarray):
         """``(is_foreign, uids)`` of ``slots``: which have no cell but a
@@ -1321,24 +1397,42 @@ class ArrayShadowGraph:
         tell_bulk((cells[slot], StopMsg) for slot in kill_slots.tolist())
         return kill_uids
 
-    def _free_slots_batch(
-        self, garbage: np.ndarray, garbage_slots: np.ndarray
-    ) -> np.ndarray:
+    def _free_slots_batch(self, garbage_slots: np.ndarray) -> tuple:
         """Free every garbage slot in one vectorized pass (the sweep,
         reference: ShadowGraph.java:273-289).  Returns the foreign uids
-        among the freed, for the sink.
+        among the freed, for the sink, and the edge slots examined to
+        find the dead edges.
 
-        Incident edges are found by scanning the flat edge arrays — an
-        edge is allocated iff its weight is nonzero — instead of per-slot
-        incident sets, so the sweep is O(edge capacity) numpy + O(dead
-        edges) dict deletions rather than Python set surgery per slot.
+        Incident edges (an edge is allocated iff its weight is nonzero)
+        are asked of the endpoint index, so a small death costs by the
+        dead slots and the references on them, from both ends: a live
+        source may hold an edge into garbage (a negative weight, or the
+        source halted).  A mass death takes one scan over the flat edge
+        arrays instead, cheaper by then than a binary search a slot;
+        which, by the dead against the edge capacity.  Either way the
+        same ids in the same order, then O(dead edges) map deletions.
 
         Supervisor pointers *into* a garbage slot need no scan: a live,
         non-halted child marks its supervisor, so the pointing node is
         garbage in the same sweep and its pointer is cleared here too."""
+        dying = self._dying
+        dying[garbage_slots] = True
+        try:
+            return self._free_dying(garbage_slots, dying)
+        finally:
+            dying[garbage_slots] = False
+
+    def _free_dying(self, garbage_slots: np.ndarray, dying: np.ndarray) -> tuple:
         w = self.edge_weight
-        em = (w != 0) & (garbage[self.edge_src] | garbage[self.edge_dst])
-        eids = np.nonzero(em)[0]
+        if garbage_slots.size * _SCAN_SHARE > self.edge_capacity:
+            examined = self.edge_capacity
+            eids = np.nonzero(
+                (w != 0) & (dying[self.edge_src] | dying[self.edge_dst])
+            )[0]
+        else:
+            eids, examined = self._endpoints.incident(
+                garbage_slots, dying, self.edge_src, self.edge_dst, w
+            )
         if eids.size:
             srcs = self.edge_src[eids]
             dsts = self.edge_dst[eids]
@@ -1403,7 +1497,7 @@ class ArrayShadowGraph:
             dead_keys = [
                 key
                 for key in sm
-                if garbage[key >> 32] or garbage[key & 0xFFFFFFFF]
+                if dying[key >> 32] or dying[key & 0xFFFFFFFF]
             ]
             for key in dead_keys:
                 del sm[key]
@@ -1421,7 +1515,7 @@ class ArrayShadowGraph:
         self.free_slots.push_batch(garbage_slots)
         if self._node_log is not None:
             self._node_log.update(garbage_slots.tolist())
-        return freed_foreign
+        return freed_foreign, examined
 
     # ------------------------------------------------------------- #
     # Waves (reference: ShadowGraph.java:291-299)

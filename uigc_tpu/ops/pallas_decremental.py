@@ -598,6 +598,23 @@ def derive(flags, recv_count, preps, interpret=None, mode=pt.MODE_PUSH,
     )
 
 
+def verdict_reduce():
+    """The jitted reduce behind :meth:`DecrementalTracer.verdict_words`:
+    ``(mark_w, iu_w) -> (iu_w & ~mark_w, popcount(mark_w))`` over the
+    packed word tables, one program for every geometry's shape."""
+    import jax
+
+    reduce = _fn_cache.get(("verdict_reduce",))
+    if reduce is None:
+
+        @jax.jit
+        def reduce(mark_w, iu_w):
+            return iu_w & ~mark_w, jax.lax.population_count(mark_w).sum()
+
+        _fn_cache[("verdict_reduce",)] = reduce
+    return reduce
+
+
 class DecrementalTracer:
     """Per-wake detection state on top of IncrementalPallasLayout.
 
@@ -812,6 +829,32 @@ class DecrementalTracer:
         except Exception:
             self.invalidate()
             raise
+
+    def verdict_words(self, mark_w) -> tuple:
+        """What a sweep needs of the last wake's verdicts, without the
+        (n,) vector: ``(garbage_w, marked)``, the packed words of the
+        slots in use and unmarked (flat uint32, bit ``i & 31`` of word
+        ``i >> 5`` is slot ``i``; marks never leave the in-use set, so a
+        slot in use is marked iff its bit is clear) and the number of
+        marks.  ``mark_w`` is what :meth:`wake_device` returned, taken
+        before the next wake or ``invalidate()``: the in-use words are
+        the ones that wake left.  A reduce of its own on the device, not
+        a part of the wake program; 4 bytes an 32 slots cross to the
+        host.  Poisoned results invalidate as in :meth:`unpack_marks`."""
+        import jax
+
+        require(
+            mark_w is self._mark_w, "decremental.verdict_words",
+            "verdict words of a wake that is no longer the tracer's last",
+        )
+        try:
+            garbage_w, marked = jax.device_get(  # readback: host boundary: the wake's verdict words -> np for the sweep
+                verdict_reduce()(mark_w, self._iu_w)
+            )
+        except Exception:
+            self.invalidate()
+            raise
+        return garbage_w.reshape(-1).view(np.uint32), int(marked)
 
     def marks(self, flags, recv_count) -> np.ndarray:
         """Wake + unpack to the oracle's (n,) bool mark vector."""

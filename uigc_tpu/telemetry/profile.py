@@ -14,10 +14,14 @@ pipeline's named phases:
 - ``upload``     host -> device: layout deltas, suspect id words, flags
                  and receive counts
 - ``device``     dispatch of the wake program until its result is ready
-- ``readback``   device -> host: the verdicts unpacked to a bool vector
+- ``readback``   device -> host: the verdict words (slots in use and
+                 unmarked, a bit a slot) and the count of marks
 - ``sweep``      kill decisions + slot frees (its record carries the
-                 ``kills`` and ``freed`` counts, and ``kill_uids``: the
-                 foreign uids handed to the engine's sink to stop)
+                 ``kills`` and ``freed`` counts, ``kill_uids``: the
+                 foreign uids handed to the engine's sink to stop, and
+                 ``sweep_edge_slots``: the edge slots examined to find
+                 the edges that hang on the dead, the edge capacity
+                 where the sweep scanned)
 - ``broadcast``  delta-graph serialization + peer broadcast (multi-node)
 
 A wake's record also carries ``fold_rows`` (packed rows folded),
