@@ -37,6 +37,10 @@ GEOM_CHAIN_1M = dict(n=1_000_000, n_blocks=4096, n_super=245, r_rows=256)
 #: actors in a backend capacity of 131,072, 300,001 pairs, one walk
 #: chunk), as ``IncrementalPallasLayout.rebuild`` packs it
 GEOM_TREE_100K = dict(n=131_072, n_blocks=1024, n_super=32, r_rows=64)
+#: the engine cell's graph (the benchmark's ``engine-fold-10m``: the 10M
+#: graph folded into 2^24 slots): the widest table pair the kernel holds
+#: in VMEM, 2 x 2 MB
+GEOM_ENGINE_16M = dict(n=1 << 24, n_blocks=24_576, n_super=4096, r_rows=4096)
 #: a 20k-actor layout (pow2-padded blocks)
 GEOM_SMALL = dict(n=20_000, n_blocks=128, n_super=5, r_rows=64)
 #: 10M over four shards, as ``pack_shard_layouts`` packs it
@@ -135,14 +139,17 @@ def _scalar_operand_bytes(geom) -> int:
 
 
 @pytest.mark.parametrize(
-    "geom", [GEOM_10M, GEOM_CHAIN_1M, GEOM_TREE_100K, GEOM_SMALL],
-    ids=["10m", "chain-1m", "tree-100k", "small"],
+    "geom",
+    [GEOM_10M, GEOM_CHAIN_1M, GEOM_TREE_100K, GEOM_SMALL, GEOM_ENGINE_16M],
+    ids=["10m", "chain-1m", "tree-100k", "small", "engine-16m"],
 )
 def test_propagate_launch_compiles_with_its_list(one_chip, geom):
     """One launch as every caller makes it (``build_propagate``'s callable:
     the list of active blocks in XLA, then a grid as long as the list):
-    one Mosaic kernel, its scalar operands under the SMEM budget, and
-    beside the contributions the count of steps it took."""
+    one Mosaic kernel (with the test of what a block gathered around its
+    contraction and the SMEM counter of the steps that contracted), its
+    scalar operands under the SMEM budget, and beside the contributions
+    the count of steps it took and of those that contracted."""
     import jax
 
     propagate = pt.build_propagate(
@@ -156,14 +163,16 @@ def test_propagate_launch_compiles_with_its_list(one_chip, geom):
         _struct((n_chunks,), np.int32, one_chip),  # l
         _struct((geom["n_super"],), np.int32, one_chip),  # gate
         bmeta1, bmeta2,
-        _struct((geom["r_rows"], LANE), np.int32, one_chip),  # table
+        # the table over its new bits (``pt.walk_tables``)
+        _struct((2 * geom["r_rows"], LANE), np.int32, one_chip),
         row_pos, emeta,
     ).compile()
     assert _mosaic_calls(compiled) == 1
     assert _scalar_operand_bytes(geom) < SMEM_BUDGET
-    out, steps = compiled.out_info
+    out, steps, contracted = compiled.out_info
     assert out.shape == (geom["n_super"] * pt.S_ROWS, LANE)
     assert steps.shape == () and steps.dtype == np.int32
+    assert contracted.shape == () and contracted.dtype == np.int32
 
 
 def test_decremental_wake_compiles_at_10m(one_chip):
@@ -178,6 +187,7 @@ def test_decremental_wake_compiles_at_10m(one_chip):
     assert walks.shape == () and walks.dtype == np.int32
     assert stats["closure_bailed"].shape == stats["closure_spent"].shape == ()
     assert stats["kernel_steps"].shape == stats["kernel_steps_full"].shape == ()
+    assert stats["kernel_contractions"].shape == ()
     # three n_blocks-long int32 operands in SMEM (bmeta1, bmeta2 and the
     # list of active blocks) beside the gate and the dirty lists
     assert _scalar_operand_bytes(GEOM_10M) == 304_996 < SMEM_BUDGET
@@ -244,6 +254,7 @@ def test_wake_program_counts_and_names(one_chip, mode):
     assert stats["closure_bailed"].shape == stats["closure_spent"].shape == ()
     assert stats["gated_tiles"].shape == ()
     assert stats["kernel_steps"].shape == stats["kernel_steps_full"].shape == ()
+    assert stats["kernel_contractions"].shape == ()
     assert stats["jump_sweeps"].shape == stats["jump_spent"].shape == ()
     for key in ("dirty_chunks", "tiles_skipped", "pull_on", "jump_on"):
         assert stats[key].shape == (pt.MAX_SWEEP_STATS,)

@@ -19,6 +19,7 @@ from uigc_tpu.ops import trace as trace_ops
 from uigc_tpu.ops.pallas_incremental import EDGE, SUP
 
 F = trace_ops
+CHUNK = 8 * 128 * 32  # actors in one walk chunk at the interpreted geometry
 
 
 class OracleGraph:
@@ -149,9 +150,112 @@ def _drive_random_wakes(rng, g, tracer, seed, wakes):
             f"seed {seed} wake {wake}: "
             f"{int((got != expected).sum())} mismatched marks"
         )
+        s = tracer.wake_stats(1)[0]
+        assert 0 <= s["kernel_contractions"] <= s["kernel_steps"]
     # SUP removals must have matched their packed kind (a key-kind
     # mismatch shows up as a silently-dropped anomaly)
     assert tracer.layout.stats["anomalies"] == 0
+
+
+def _island_graph(rng, n, chain, density=0.6):
+    """An :class:`OracleGraph` whose suspects close over islands: few
+    references and hardly a supervisor, so no giant component ties the
+    marks together, and a rooted chain that no other pair enters, which
+    makes the derivation from nothing deep and so the closure's price (a
+    share of that derivation's chunk walks) high enough to stay under."""
+    g = OracleGraph(rng, n, n_edges=int(density * n))
+    for key in list(g.pairs):
+        if key[1] < chain or (key[2] == SUP and rng.random() > 0.07):
+            del g.pairs[key]
+    g.flags[:chain] = F.FLAG_IN_USE | F.FLAG_INTERNED
+    g.flags[0] |= F.FLAG_ROOT
+    g.recv[:chain] = 0
+    for hop in range(chain - 1):
+        g.pairs[(hop, hop + 1, EDGE)] = None
+    return g
+
+
+@pytest.mark.parametrize(
+    "seed,mode,n,density,chain",
+    [
+        # ``auto`` jumps down the chain, so its derivation is cheap and its
+        # price the floor: one walk chunk, a walk a closure sweep
+        (0, pt.MODE_AUTO, CHUNK, 0.4, 60),
+        (1, pt.MODE_AUTO, CHUNK, 0.4, 60),
+        (1, pt.MODE_PULL, 2 * CHUNK + 500, 0.6, 160),
+        (2, pt.MODE_PUSH, 2 * CHUNK + 500, 0.6, 160),
+    ],
+)
+def test_warm_wakes_that_gather_only_new_bits_match_oracle(
+    seed, mode, n, density, chain
+):
+    """The kernels' blocks gather the bits that are NEW since the sweep
+    before (forced tiles the full table), and contract only if they found
+    one.  The random schedule above is no test of that: since the closure
+    is priced its wakes all give up and derive from nothing.  Here the
+    closures are islands (``_island_graph``), so most wakes stay on the
+    warm road: a closure that ends, tiles forced through their full span,
+    a first repair sweep against the previous wake's table.  Over up to
+    three walk chunks and 65 tiles, with every event of the generator
+    (deletions, fresh inserts, halts, seeds dropped, slots freed and
+    reused, tiers frozen), the marks equal the oracle's after every wake
+    and the steps that contracted stay under the steps taken."""
+    rng = np.random.default_rng(seed)
+    g = _island_graph(rng, n, chain, density)
+    tracer = pd.DecrementalTracer(
+        n, freeze_threshold=64, max_frozen=2, mode=mode, s_rows=8
+    )
+    _drive_random_wakes(rng, g, tracer, seed, wakes=8)
+    stats = tracer.wake_stats()
+    warm = [s for s in stats if s["gated_tiles"]]
+    assert len(warm) >= 3 and not any(s["closure_bailed"] for s in warm)
+    assert all(0 < s["kernel_contractions"] < s["kernel_steps"] for s in stats)
+
+
+def _sweep_profile():
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
+    try:
+        import sweep_profile
+    finally:
+        sys.path.pop(0)
+    return sweep_profile
+
+
+@pytest.mark.parametrize("mode", [pt.MODE_AUTO, pt.MODE_PUSH, pt.MODE_PULL])
+def test_contractions_of_a_derivation_with_an_unreachable_half(mode):
+    """On the benchmark's graph shape (``powerlaw_actor_graph``: half the
+    actors released and unreachable) a derivation from nothing walks
+    blocks that have nothing to contribute: sources never marked, or
+    marked sweeps ago.  ``wake_stats()``'s ``kernel_contractions`` is
+    under ``kernel_steps``, and both equal what
+    ``tools/sweep_profile.py simulate_sweeps`` counts per sweep from the
+    tracer's own packed layout (here at the interpreted geometry; the same
+    code is the counter's oracle at the chip's)."""
+    from uigc_tpu.models import powerlaw_actor_graph
+
+    n = 4 * CHUNK
+    g = powerlaw_actor_graph(n, seed=0, garbage_fraction=0.5)
+    tracer = pd.DecrementalTracer(n, mode=mode)
+    tracer.rebuild(g["edge_src"], g["edge_dst"], g["edge_weight"], g["supervisor"])
+    got = tracer.marks(g["flags"], g["recv_count"])
+    assert np.array_equal(got, trace_ops.trace_marks_np(
+        g["flags"], g["recv_count"], g["supervisor"],
+        g["edge_src"], g["edge_dst"], g["edge_weight"],
+    ))
+    s = tracer.wake_stats(1)[0]
+    assert 0 < s["kernel_contractions"] < s["kernel_steps"]
+
+    preps, _ = tracer.layout.prepare_device_wake()
+    (layout,) = [p for p in preps if "xla_src" not in p]
+    sim = _sweep_profile().simulate_sweeps(g, n, [mode], layout=layout)[mode]
+    assert sim["sweeps"] == s["n_sweeps"] == len(sim["steps"])
+    assert sim["dirty_chunks"] == s["dirty_chunks"]
+    assert sum(sim["steps"]) == s["kernel_steps"]
+    assert sum(sim["contracting"]) == s["kernel_contractions"]
+    assert all(c <= t for c, t in zip(sim["contracting"], sim["steps"]))
 
 
 def test_released_cycle_dies():
@@ -338,14 +442,14 @@ def test_selective_gating_at_scale(seed):
             f"seed {seed} wake {wake}: "
             f"{int((got != expected).sum())} mismatched marks"
         )
+        s = tracer.wake_stats(1)[0]
+        assert 0 <= s["kernel_contractions"] <= s["kernel_steps"]
     assert tracer.layout.stats["anomalies"] == 0
 
 
 # ------------------------------------------------------------------- #
 # the closure's price and the cold road (PR 30)
 # ------------------------------------------------------------------- #
-
-CHUNK = 8 * 128 * 32  # actors in one walk chunk at the interpreted geometry
 
 
 def supervised_tree(n, fan=8):

@@ -378,19 +378,29 @@ def _block_iters(prep, dirty, gate):
     )
 
 
-def _reference_contribs(prep, psrc, pdst, table, dirty, gate):
-    """The uncompacted reference: a segment-sum over the layout's pairs of
-    the source's table bit, for the pairs whose source chunk the sweep
-    walks into their destination tile (dirty, or the tile forced; never a
-    skipped tile)."""
+def _bits(table, node):
+    """The table's bits at these node ids."""
+    word = node >> 5
+    return (table[word >> 7, word & 127] >> (node & 31)) & 1
+
+
+def _reference_contribs(prep, psrc, pdst, table, dirty, gate, new=None):
+    """The uncompacted reference, which contracts whatever it gathered: a
+    segment-sum over the layout's pairs of the source's bit, for the pairs
+    whose source chunk the sweep walks into their destination tile (dirty,
+    or the tile forced; never a skipped tile).  The bit is the table's in
+    a forced tile and ``new``'s (the table's own where none is given) in
+    every other."""
     super_sz = prep["s_rows"] * pallas_trace.LANE
     tile = pdst // super_sz
     if "super_ids" in prep:  # compact: global supertile -> layout tile
         tile = np.searchsorted(prep["super_ids"][: len(np.unique(tile))], tile)
-    word = psrc >> 5
-    bit = (table[word >> 7, word & 127] >> (psrc & 31)) & 1
-    chunk = (word >> 7) // GRID_CHUNK_ROWS
+    chunk = (psrc >> 12) // GRID_CHUNK_ROWS
     g = gate[tile]
+    bit = np.where(
+        g == pallas_trace.GATE_FULL, _bits(table, psrc),
+        _bits(table if new is None else new, psrc),
+    )
     walked = np.where(
         g == pallas_trace.GATE_SKIP, False,
         (g == pallas_trace.GATE_FULL) | dirty[chunk],
@@ -401,8 +411,31 @@ def _reference_contribs(prep, psrc, pdst, table, dirty, gate):
     return out.reshape(-1, pallas_trace.LANE)
 
 
-def _launch(prep, table, dirty, gate, fill=None):
-    """(contributions, steps) of one launch; over a buffer of ``fill``."""
+def _gathering_blocks(prep, table, dirty, gate, new=None):
+    """Per block, whether the kernel's walk gathers a set bit this sweep,
+    from the packed layout alone: a slot's source is decoded from
+    ``row_pos`` and ``emeta``, it is walked if its chunk is (the block's
+    tile forced, or the chunk dirty and the tile not skipped), and a
+    forced block reads the table, every other ``new``."""
+    src = pallas_trace.slot_sources(prep, -1)
+    held = src >= 0
+    src = np.where(held, src, 0)
+    g = gate[prep["bmeta1"] >> 1][:, None]
+    chunk = (src >> 12) // GRID_CHUNK_ROWS  # 2^12 nodes a table row
+    walked = held & (g != pallas_trace.GATE_SKIP) & (
+        (g == pallas_trace.GATE_FULL) | dirty[chunk]
+    )
+    bit = np.where(
+        g == pallas_trace.GATE_FULL, _bits(table, src),
+        _bits(table if new is None else new, src),
+    )
+    return (walked & (bit > 0)).any(axis=1)
+
+
+def _launch(prep, table, dirty, gate, fill=None, new=None):
+    """(contributions, steps, steps that contracted) of one launch; over a
+    buffer of ``fill``.  The kernel's table operand is ``table`` over
+    ``new``, the table's own bits where none is given."""
     import jax
     import jax.numpy as jnp
 
@@ -414,16 +447,17 @@ def _launch(prep, table, dirty, gate, fill=None):
         prep["n_blocks"], prep.get("out_supers", prep["n_super"]),
         prep["r_rows"], prep["s_rows"], True, sub=1, group=1, dst_gate=True,
     )
-    operands = (d, l, gate, prep["bmeta1"], prep["bmeta2"], table,
+    tables = np.concatenate([table, table if new is None else new])
+    operands = (d, l, gate, prep["bmeta1"], prep["bmeta2"], tables,
                 prep["row_pos"], prep["emeta"])
     if fill is None:
-        out, steps = jax.jit(propagate.with_steps)(*operands)
+        out, steps, contracted = jax.jit(propagate.with_steps)(*operands)
     else:
         plane = jnp.full(
             (propagate(*operands).shape[0], pallas_trace.LANE), fill, jnp.float32
         )
-        out, steps = jax.jit(propagate.onto)(plane, *operands)
-    return np.asarray(out), int(steps)
+        out, steps, contracted = jax.jit(propagate.onto)(plane, *operands)
+    return np.asarray(out), int(steps), int(contracted)
 
 
 @pytest.mark.parametrize(
@@ -489,10 +523,11 @@ def test_compacted_grid_equals_uncompacted_reference(case):
             out_tiles - len(np.unique(pdst // (GRID_S_ROWS * pallas_trace.LANE)))
         )
 
-    out, steps = _launch(prep, table, dirty, gate)
+    out, steps, contracted = _launch(prep, table, dirty, gate)
     assert steps == int((n_iter > 0).sum())
+    assert contracted == int(_gathering_blocks(prep, table, dirty, gate).sum())
     if case in ("nothing_dirty", "padding_blocks_only"):
-        assert steps == 0
+        assert steps == contracted == 0
     expected = _reference_contribs(prep, psrc, pdst, table, dirty, gate)
     assert np.array_equal(out, expected)  # NaN anywhere would fail this
     rows = prep["s_rows"]
@@ -502,8 +537,97 @@ def test_compacted_grid_equals_uncompacted_reference(case):
     if steps == 0:  # the one step of an empty launch zeroes the last block's tile
         visited[block_tile[-1]] = True
         unvisited = np.repeat(~visited, rows)
-    out7, steps7 = _launch(prep, table, dirty, gate, fill=7.0)
-    assert steps7 == steps
+    out7, steps7, contracted7 = _launch(prep, table, dirty, gate, fill=7.0)
+    assert (steps7, contracted7) == (steps, contracted)
+    assert np.array_equal(out7, np.where(unvisited[:, None], np.float32(7), expected))
+
+
+def _set_bits(nodes, r_rows):
+    """A word table with exactly these nodes' bits set."""
+    flat = np.zeros(r_rows * pallas_trace.LANE, np.int64)
+    np.bitwise_or.at(flat, nodes >> 5, np.int64(1) << (nodes & 31))
+    return flat.astype(np.uint32).view(np.int32).reshape(r_rows, pallas_trace.LANE)
+
+
+@pytest.mark.parametrize("compact", [False, True], ids=["dense", "compact"])
+@pytest.mark.parametrize(
+    "case",
+    ["few_new_bits", "first_block_gathers_nothing", "a_tile_gathers_nothing",
+     "nothing_new"],
+)
+def test_a_block_contracts_only_when_it_gathered_a_bit(case, compact):
+    """A walked block whose gather found nothing skips its contraction, and
+    the contributions stay those of the formulation that contracts
+    whatever it gathered, bit for bit: in tiles under ``GATE_PUSH`` (the
+    NEW bits of the dirty chunks), ``GATE_FULL`` (the full table over the
+    full span) and ``GATE_SKIP``.  A skipped block that is the first
+    active one of its tile still zeroes the tile (over a buffer of sevens
+    it would read seven otherwise), one that is not touches nothing, and
+    the kernel's count of the steps that contracted is the count of the
+    blocks that gathered a bit, taken from the packed layout in numpy."""
+    prep, psrc, pdst = _grid_layout(compact)
+    n_chunks = prep["r_rows"] // GRID_CHUNK_ROWS
+    out_tiles = prep.get("out_supers", prep["n_super"])
+    rng = np.random.default_rng(39)
+    table = rng.integers(0, 1 << 31, (prep["r_rows"], pallas_trace.LANE)).astype(np.int32)
+    dirty = np.ones(n_chunks, bool)
+    gate = np.zeros(out_tiles, np.int32)
+    # a compact layout pads its tiles: the last that holds a pair
+    forced = [3, len(np.unique(pdst // (GRID_S_ROWS * pallas_trace.LANE))) - 1]
+    gate[forced] = pallas_trace.GATE_FULL
+    gate[[0, 5]] = pallas_trace.GATE_SKIP
+    block_tile = prep["bmeta1"] >> 1
+    n_iter = _block_iters(prep, dirty, gate)
+    set_src = np.unique(psrc[_bits(table, psrc) > 0])
+    if case == "few_new_bits":
+        dirty[1] = False
+        n_iter = _block_iters(prep, dirty, gate)
+        new = _set_bits(rng.choice(set_src, 60, replace=False), prep["r_rows"])
+    elif case == "first_block_gathers_nothing":
+        # the sources of each tile's LAST active block alone are new
+        active = np.flatnonzero(n_iter > 0)
+        last = active[np.r_[block_tile[active][1:] != block_tile[active][:-1], True]]
+        src = pallas_trace.slot_sources(prep, -1)[last]
+        new = _set_bits(np.intersect1d(src, set_src)[::7], prep["r_rows"])
+    elif case == "a_tile_gathers_nothing":
+        super_sz = prep["s_rows"] * pallas_trace.LANE
+        tile = pdst // super_sz
+        if compact:
+            tile = np.searchsorted(prep["super_ids"][: len(np.unique(tile))], tile)
+        quiet = np.isin(tile, [2, 9])
+        new = table & ~_set_bits(np.unique(psrc[quiet]), prep["r_rows"])
+    else:
+        new = np.zeros_like(table)
+    assert not (new & ~table).any()  # what is new is set
+
+    gathers = _gathering_blocks(prep, table, dirty, gate, new)
+    assert not gathers[n_iter == 0].any()
+    visited = np.zeros(out_tiles, bool)
+    visited[block_tile[n_iter > 0]] = True
+    tile_gathers = np.zeros(out_tiles, bool)
+    tile_gathers[block_tile[gathers]] = True
+    if case == "first_block_gathers_nothing":
+        active = np.flatnonzero(n_iter > 0)
+        first = active[np.r_[True, block_tile[active][1:] != block_tile[active][:-1]]]
+        late = tile_gathers[block_tile[first]] & ~gathers[first]
+        assert late.sum() > 10  # a later block of the tile gathers, its first not
+    if case == "a_tile_gathers_nothing":
+        assert visited[[2, 9]].all() and not tile_gathers[[2, 9]].any()
+    if case == "nothing_new":  # the forced tiles alone read the table
+        assert sorted(np.flatnonzero(tile_gathers)) == forced
+    if case == "few_new_bits":
+        assert 0 < gathers.sum() < (n_iter > 0).sum() // 2
+
+    expected = _reference_contribs(prep, psrc, pdst, table, dirty, gate, new)
+    assert expected[np.repeat(tile_gathers, prep["s_rows"])].any()
+    out, steps, contracted = _launch(prep, table, dirty, gate, new=new)
+    assert steps == int((n_iter > 0).sum())
+    assert contracted == int(gathers.sum()) <= steps
+    assert np.array_equal(out, expected)
+    # a visited tile reads as over zeros whatever the buffer held
+    out7, steps7, contracted7 = _launch(prep, table, dirty, gate, fill=7.0, new=new)
+    assert (steps7, contracted7) == (steps, contracted)
+    unvisited = np.repeat(~visited, prep["s_rows"])
     assert np.array_equal(out7, np.where(unvisited[:, None], np.float32(7), expected))
 
 
@@ -531,9 +655,10 @@ def test_unvisited_tiles_of_a_compact_layout_add_nothing():
     d = np.concatenate([[0], np.cumsum(dirty)]).astype(np.int32)
     l = np.array([1, 0, 0], np.int32)
     args = pallas_trace.device_args(dense) + pallas_trace.device_args(comp)
-    hits, steps = jax.jit(
+    tables = np.concatenate([table, table])
+    hits, steps, contracted = jax.jit(
         lambda t, d, l, g, *a: sweep.with_steps(t, d, l, a, gate=g)
-    )(table, d, l, gate, *args)
+    )(tables, d, l, gate, *args)
     expected = (
         _reference_contribs(dense, psrc_d, pdst_d, table, dirty, gate)
         + _reference_contribs(
@@ -546,8 +671,12 @@ def test_unvisited_tiles_of_a_compact_layout_add_nothing():
     assert int(steps) == int(
         (_block_iters(dense, dirty, gate) > 0).sum() + (n_iter_c > 0).sum()
     )
+    assert int(contracted) == int(
+        _gathering_blocks(dense, table, dirty, gate).sum()
+        + _gathering_blocks(comp, table, dirty, gate[comp["super_ids"]]).sum()
+    )
     assert np.array_equal(
         np.asarray(jax.jit(lambda t, d, l, g, *a: sweep(t, d, l, a, gate=g))(
-            table, d, l, gate, *args)),
+            tables, d, l, gate, *args)),
         expected,
     )

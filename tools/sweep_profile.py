@@ -4,7 +4,9 @@ Times three things the full fixpoint mixes together (a wake reports only
 their sum across ~12 sweeps):
 
 - a **full-dirty** propagation sweep (every chunk dirty: worst-case walk
-  + every block's one-hot contraction);
+  + every block's one-hot contraction), and the same walk over a table
+  with no new bit, in which every block skips its contraction: the
+  difference is what a contraction costs a step;
 - a **no-dirty** sweep (empty dirty list: pure grid/stream overhead —
   every block still streams its row_pos/emeta and runs the skip branch);
 - the **word-space pack2d** of per-sweep hits into the word table (the
@@ -73,7 +75,7 @@ def timed(fn, *args, reps=5):
 
 
 def simulate_sweeps(graph, n, modes, jump_steps=None, geometry=None,
-                    suspects=None):
+                    suspects=None, layout=None):
     """Hardware-independent fixpoint sweep counts per trace mode, by
     direct numpy simulation of the kernel's per-sweep semantics
     (the wake's repair loop: table = mark & ~halted, hits gated by
@@ -85,13 +87,25 @@ def simulate_sweeps(graph, n, modes, jump_steps=None, geometry=None,
     chunks of ``geometry`` = (n_slots, n_chunks, chunk_nodes): the slots
     and chunks of the layout as the decremental backend packs it.
 
+    With ``layout`` (the pairs packed, ``chip_layout`` at the chip's
+    geometry; it then gives ``geometry`` too) every sweep also counts,
+    by the propagate kernel's own rules, the grid ``steps`` it takes (the
+    blocks with a dirty chunk in their span whose tile the pull gate does
+    not skip) and how many of them are ``contracting`` (a slot's source
+    bit is new since the sweep before): the oracle of ``wake_stats()``'s
+    ``kernel_steps`` and ``kernel_contractions`` on a derivation from
+    nothing.  Pull gating changes the steps, so ``pull`` is then
+    simulated on its own.
+
     With ``suspects`` (actor ids: the targets of released references,
     say) the decremental wake's closure over the derived marks is
     simulated too, with the program's own policy
     (``pt.closure_gives_up``, priced from each mode's derivation walks):
     the closure is push-only whatever the mode.
 
-    Returns {mode: {"sweeps", "jump_sweeps", "dirty_chunks"}}, under
+    Returns {mode: {"sweeps", "jump_sweeps", "dirty_chunks"}} (with
+    ``layout`` also "steps", "contracting" and "chunk_iterations", per
+    sweep), under
     "auto" also the policy's "price", and with ``suspects`` under every
     mode "closure": {"price", "sweeps", "spent", "bailed"} as the wake
     would run it, and "full_sweeps", "sizes" (closure members after each
@@ -124,14 +138,17 @@ def simulate_sweeps(graph, n, modes, jump_steps=None, geometry=None,
     trans = in_use & (~halted)
     trans_pad = np.concatenate([trans, [False]])
     if geometry is None:
-        geometry = layout_geometry(psrc, pdst, n)
+        if layout is None:
+            layout = chip_layout(psrc, pdst, n)
+        geometry = layout_geometry(layout)
     n_slots, n_chunks, chunk_nodes = geometry
     pull_cut = max(1, int(round(pt.DEFAULT_PULL_DENSITY * n_chunks)))
     bounds = np.arange(0, n, chunk_nodes)
+    walk = _BlockWalk(layout, n, in_use) if layout is not None else None
 
     out = {}
     for mode in modes:
-        if mode == pt.MODE_PULL and pt.MODE_PUSH in out:
+        if mode == pt.MODE_PULL and pt.MODE_PUSH in out and walk is None:
             out[mode] = out[pt.MODE_PUSH]
             continue
         engaged, spent, decide = mode == pt.MODE_JUMP, 0, None
@@ -143,10 +160,15 @@ def simulate_sweeps(graph, n, modes, jump_steps=None, geometry=None,
         j = pt.jump_parents(psrc, pdst, n) if use_jump else None
         mark = mark0.copy()
         table, table_prev = mark & ~halted, np.zeros(n, bool)
-        dirty, jump_sweeps = [], 0
+        dirty, jump_sweeps, per_sweep = [], 0, []
         while True:
             n_dirty = _dirty_chunks(table, table_prev, bounds)
             dirty.append(n_dirty)
+            if walk is not None:
+                pull_on = mode == pt.MODE_PULL or (
+                    mode == pt.MODE_AUTO and n_dirty >= pull_cut
+                )
+                per_sweep.append(walk.sweep(table, table_prev, mark, pull_on))
             if decide is not None:
                 engaged, spent = decide(engaged, spent, n_dirty)
             new = mark.copy()
@@ -167,6 +189,10 @@ def simulate_sweeps(graph, n, modes, jump_steps=None, geometry=None,
                 break
         out[mode] = {"sweeps": len(dirty), "jump_sweeps": jump_sweeps,
                      "dirty_chunks": dirty}
+        if walk is not None:
+            steps, contracting, iters = (list(c) for c in zip(*per_sweep))
+            out[mode].update(steps=steps, contracting=contracting,
+                             chunk_iterations=iters)
         if decide is not None:
             out[mode]["price"] = decide.price
     if suspects is not None:
@@ -213,19 +239,68 @@ def _simulate_closure(psrc, pdst, mark, suspects, bounds):
     return dirty, sizes
 
 
-def layout_geometry(psrc, pdst, n):
-    """(n_slots, n_chunks, chunk_nodes) of the pairs' layout as the
-    decremental backend packs it on the chip
-    (``IncrementalPallasLayout.rebuild``: pow2/quantum-padded blocks,
-    the chip's walk geometry): what AUTO's price of a jump sweep is
-    built from.  One host pack (44 s at 10M actors)."""
+class _BlockWalk:
+    """One propagate launch of a derivation from nothing, counted from
+    the packed layout by the kernel's own rules (``build_propagate``:
+    ``block_iters``, the gather, the test around the contraction)."""
+
+    def __init__(self, prep, n, in_use):
+        from uigc_tpu.ops import pallas_trace as pt
+
+        # a slot's source actor; an empty slot reads the sink, never set
+        self.slot_src = pt.slot_sources(prep, n)
+        self.tile = prep["bmeta1"] >> 1
+        self.c_lo = prep["bmeta2"] >> pt._SPAN_BITS
+        self.c_hi = self.c_lo + (prep["bmeta2"] & ((1 << pt._SPAN_BITS) - 1))
+        self.chunk_nodes = pt.ROWS * prep["group"] * pt.LANE * pt.WORD_BITS
+        self.n_chunks = prep["r_rows"] // (pt.ROWS * prep["group"])
+        self.tile_nodes = prep["s_rows"] * pt.LANE
+        self.n_tiles = prep["n_super"]
+        self.in_use = in_use
+
+    def _per(self, flags, size, count):
+        """``any`` over runs of ``size`` nodes, ``count`` runs."""
+        padded = np.zeros(size * count, bool)
+        padded[: flags.size] = flags
+        return padded.reshape(count, size).any(axis=1)
+
+    def sweep(self, table, table_prev, mark, pull_on):
+        """(grid steps, steps that contract, chunk-iterations) of the
+        sweep that walks ``table`` with the dirty lists taken against
+        ``table_prev``."""
+        dirty = self._per(table != table_prev, self.chunk_nodes, self.n_chunks)
+        d = np.concatenate([[0], np.cumsum(dirty)])
+        n_iter = d[self.c_hi] - d[self.c_lo]
+        active = n_iter > 0
+        if pull_on:  # a saturated tile: no unmarked in-use node left
+            unmarked = self._per(
+                self.in_use & ~mark, self.tile_nodes, self.n_tiles
+            )
+            active &= unmarked[self.tile]
+        new = np.concatenate([table & ~table_prev, [False]])
+        gathers = new[self.slot_src[active]].any(axis=1)
+        return int(active.sum()), int(gathers.sum()), int(n_iter[active].sum())
+
+
+def chip_layout(psrc, pdst, n):
+    """The pairs packed as the decremental backend packs them on the
+    chip (``IncrementalPallasLayout.rebuild``: pow2/quantum-padded
+    blocks, the chip's walk geometry).  One host pack (44 s at 10M
+    actors)."""
     from uigc_tpu.ops import pallas_trace as pt
 
-    prep = pt.prepare_pairs(
+    return pt.prepare_pairs(
         psrc, pdst, n, pad_blocks_pow2=True, sub=pt.SUB_TPU,
         group=pt.GROUP_TPU,
     )
-    group_rows = pt.ROWS * pt.GROUP_TPU
+
+
+def layout_geometry(prep):
+    """(n_slots, n_chunks, chunk_nodes) of a packed layout: what AUTO's
+    price of a jump sweep is built from."""
+    from uigc_tpu.ops import pallas_trace as pt
+
+    group_rows = pt.ROWS * prep["group"]
     return (
         pt.kernel_slots((pt.layout_spec(prep),)),
         prep["r_rows"] // group_rows,
@@ -294,6 +369,12 @@ def main():
                     "sweeps": {m: sim[m]["sweeps"] for m in modes},
                     "jump_sweeps": {m: sim[m]["jump_sweeps"] for m in modes},
                     "dirty_chunks": {m: sim[m]["dirty_chunks"] for m in modes},
+                    # grid steps per sweep, and those that contract
+                    "steps": {m: sim[m]["steps"] for m in modes},
+                    "contracting": {m: sim[m]["contracting"] for m in modes},
+                    "chunk_iterations": {
+                        m: sim[m]["chunk_iterations"] for m in modes
+                    },
                     "auto_jump_price": sim.get(pt.MODE_AUTO, {}).get("price"),
                     **({"closure": {m: sim[m]["closure"] for m in modes}}
                        if suspects is not None else {}),
@@ -352,16 +433,29 @@ def main():
         ll = np.zeros(n_chunks, np.int32)
         ll[dd[:-1][diff]] = np.nonzero(diff)[0].astype(np.int32)
         probes["half"] = (jax.device_put(dd), jax.device_put(ll))
-        layout = (dev["bmeta1"], dev["bmeta2"], table, dev["row_pos"],
-                  dev["emeta"])
-        probe_ms = {k: timed(propagate, *dl, *layout)
-                    for k, dl in probes.items()}
+        # the kernel's table operand is the table over its new bits
+        # (pt.walk_tables): every bit new, so every walked block
+        # contracts; "full_nothing_new" walks the same chunks over no new
+        # bit, so every block skips its contraction
+        zeros = jnp.zeros_like(table)
+        operands = {
+            k: (*dl, dev["bmeta1"], dev["bmeta2"],
+                pt.walk_tables(table, zeros, jnp), dev["row_pos"],
+                dev["emeta"])
+            for k, dl in probes.items()
+        }
+        operands["full_nothing_new"] = (
+            *operands["full"][:4], pt.walk_tables(table, table, jnp),
+            *operands["full"][5:],
+        )
+        probe_ms = {k: timed(propagate, *ops) for k, ops in operands.items()}
         full_ms, none_ms, half_ms = (
             probe_ms[k] for k in ("full", "none", "half")
         )
-        # the grid steps each probe took: its blocks with work
-        probe_steps = {k: int(propagate(*dl, *layout)[1])
-                       for k, dl in probes.items()}
+        # the grid steps each probe took (its blocks with work) and those
+        # of them that contracted
+        probe_steps = {k: [int(c) for c in propagate(*ops)[1:]]
+                       for k, ops in operands.items()}
 
         shifts = jnp.arange(pt.WORD_BITS, dtype=jnp.int32)
 
@@ -449,6 +543,8 @@ def main():
                     "n_sweeps": stats["n_sweeps"],
                     "fixpoint_ms": round(fix_ms, 2),
                     "jump_sweeps": stats["jump_sweeps"],
+                    "kernel_steps": stats["kernel_steps"],
+                    "kernel_contractions": stats["kernel_contractions"],
                     **rows,
                 }
         finally:
@@ -475,7 +571,16 @@ def main():
                 "sweep_full_dirty_ms": round(full_ms, 2),
                 "sweep_half_dirty_ms": round(half_ms, 2),
                 "sweep_no_dirty_ms": round(none_ms, 2),
+                "sweep_full_dirty_nothing_new_ms": round(
+                    probe_ms["full_nothing_new"], 2
+                ),
+                # [grid steps, steps that contracted] per probe
                 "sweep_grid_steps": probe_steps,
+                # what the contraction costs a walked block that needs it
+                "contraction_us_per_step": round(
+                    (full_ms - probe_ms["full_nothing_new"]) * 1e3
+                    / max(probe_steps["full"][1], 1), 3
+                ),
                 "dispatch_floor_ms": round(floor_ms, 2),
                 "pack_seed_ms": round(pack_ms, 2),
                 "pack2d_per_sweep_ms": round(pack2d_ms, 2),

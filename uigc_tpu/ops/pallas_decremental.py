@@ -150,7 +150,9 @@ def _build_wake_fn(
     ``n_sweeps`` (repair), ``kernel_steps`` and ``kernel_steps_full``
     (grid steps the propagate kernels took in both loops, over all packed
     layouts: the blocks that had work; and launches x blocks, what a grid
-    over every block would take), ``jump_sweeps`` (the
+    over every block would take), ``kernel_contractions`` (the steps that
+    contracted, counted by the kernels themselves: the blocks whose
+    gather found a bit), ``jump_sweeps`` (the
     repair sweeps that ran the jump) and ``jump_spent`` (the policy's
     ``spent`` at exit, to be read against the static
     :attr:`DecrementalTracer.jump_price`) are int32 scalars, ``dirty_chunks``,
@@ -222,12 +224,19 @@ def _build_wake_fn(
             specs, gated, n, n_super, s_rows, jnp
         )
 
-        def contribs(table, d, l, gate):
+        def contribs(table, table_prev, d, l, gate):
             """One propagation sweep over every layout (shared loop:
-            pallas_trace.build_sweep_contribs) and the grid steps its
-            kernels took; a zero gate vector makes the dst-gated kernels
-            behave exactly like the plain ones."""
-            return gated_sweep.with_steps(table, d, l, layout_args, gate=gate)
+            pallas_trace.build_sweep_contribs), the grid steps its kernels
+            took and those of them that contracted; a zero gate vector
+            makes the dst-gated kernels behave exactly like the plain
+            ones.  ``d`` and ``l`` are the dirty lists of ``table``
+            against ``table_prev``, the table of the sweep before: the
+            kernels gather the bits that are new since (what was set
+            before has been delivered, or its tile is forced)."""
+            return gated_sweep.with_steps(
+                pt.walk_tables(table, table_prev, jnp), d, l, layout_args,
+                gate=gate,
+            )
 
         with pt.scope("pack"):
             in_use = (flags & F.FLAG_IN_USE) != 0
@@ -263,28 +272,33 @@ def _build_wake_fn(
         # under CRGC's supervisor edges the closure of a live suspect is
         # every mark, and finding that out costs as much as acting on it.
         def c_cond(carry):
-            _, _, _, changed, _, spent, _ = carry
+            changed, spent = carry[4], carry[6]
             return changed & ~pt.closure_gives_up(spent, prev_walks)
 
         zero_gate = jnp.zeros((n_super,), jnp.int32)
         zero_i = jnp.zeros((), jnp.int32)
 
         def c_body(carry):
-            closure_w, d, l, _, sweeps, spent, steps = carry
-            hits2d, took = contribs(closure_w, d, l, zero_gate)
+            (closure_w, closure_prev, d, l, _, sweeps, spent, steps,
+             contracted) = carry
+            hits2d, took, did = contribs(
+                closure_w, closure_prev, d, l, zero_gate
+            )
             hit_w = pt.pack_hits_table(hits2d, r_rows, jnp)
             new_closure = closure_w | (hit_w & prev_mark_w)
             d2, l2, changed = dirty_chunks(new_closure, closure_w)
-            return (new_closure, d2, l2, changed, sweeps + 1,
-                    spent + d[n_chunks], steps + took)
+            return (new_closure, closure_w, d2, l2, changed, sweeps + 1,
+                    spent + d[n_chunks], steps + took, contracted + did)
 
         with pt.scope("closure"):
             zero_w = jnp.zeros_like(s_w)
             d0, l0, changed0 = dirty_chunks(s_w, zero_w)
-            (closure_w, _, _, closure_bailed, closure_sweeps,
-             closure_spent, closure_steps) = jax.lax.while_loop(
+            (closure_w, _, _, _, closure_bailed, closure_sweeps,
+             closure_spent, closure_steps,
+             closure_contracted) = jax.lax.while_loop(
                 c_cond, c_body,
-                (s_w, d0, l0, changed0, zero_i, zero_i, zero_i),
+                (s_w, zero_w, d0, l0, changed0, zero_i, zero_i, zero_i,
+                 zero_i),
             )
             # The cold road: the region to repair is everything, because
             # the closure said so by its cost or because there is no
@@ -354,7 +368,9 @@ def _build_wake_fn(
                 sat = None
                 pull_on = jnp.array(False)
                 gate = base_gate
-            hits2d, took = contribs(table, d, l, gate)
+            hits2d, took, did = contribs(
+                table, carry["table_prev"], d, l, gate
+            )
             hit_w = pt.pack_hits_table(hits2d, r_rows, jnp)
             new_mark_w = mark_w | (hit_w & iu_w)
             if use_jump:
@@ -367,11 +383,13 @@ def _build_wake_fn(
             # The gated sweep fully re-derives suspect supertiles; the
             # monotone dirty machinery is sufficient (and cheaper) after.
             i = jnp.minimum(carry["sweep_i"], pt.MAX_SWEEP_STATS - 1)
-            out = dict(carry, mark=new_mark_w, table=new_table, d=d2,
+            out = dict(carry, mark=new_mark_w, table=new_table,
+                       table_prev=table, d=d2,
                        l=l2, use_gate=jnp.array(False), changed=changed,
                        sweep_i=carry["sweep_i"] + 1,
                        walks=carry["walks"] + n_dirty,
                        steps=carry["steps"] + took,
+                       contracted=carry["contracted"] + did,
                        st_dirty=carry["st_dirty"].at[i].set(n_dirty))
             if use_jump:
                 jump_on = jump_state[0].astype(jnp.int32)
@@ -393,19 +411,19 @@ def _build_wake_fn(
             kept_w = jnp.where(cold, zero_w, prev_mark_w & ~closure_w)
             mark_w0 = kept_w | seed_w
             table0 = mark_w0 & nh_w
-            rd0, rl0, rchanged0 = dirty_chunks(
-                table0, jnp.where(cold, zero_w, prev_table)
-            )
+            table_prev0 = jnp.where(cold, zero_w, prev_table)
+            rd0, rl0, rchanged0 = dirty_chunks(table0, table_prev0)
             trans_w = iu_w & nh_w  # jump-transparent intermediates
             # Run at least one gated sweep whenever anything is suspect,
             # even if the table diff alone is empty.
             run0 = rchanged0 | (suspect_g.sum() > 0)
             zero_stats = jnp.zeros((pt.MAX_SWEEP_STATS,), jnp.int32)
-            carry0 = {"mark": mark_w0, "table": table0, "d": rd0,
+            carry0 = {"mark": mark_w0, "table": table0,
+                      "table_prev": table_prev0, "d": rd0,
                       "l": rl0, "use_gate": jnp.array(True),
                       "changed": run0,
                       "sweep_i": zero_i, "walks": zero_i, "steps": zero_i,
-                      "st_dirty": zero_stats}
+                      "contracted": zero_i, "st_dirty": zero_stats}
             if use_jump:
                 carry0.update(jump=jump_j0.astype(jnp.int32),
                               jump_state=pt.jump_state0(mode, jnp),
@@ -426,6 +444,8 @@ def _build_wake_fn(
             # grid steps the kernels took in both loops, and what as many
             # launches over every block would have taken
             "kernel_steps": closure_steps + out["steps"],
+            # those of them that gathered a new bit and contracted
+            "kernel_contractions": closure_contracted + out["contracted"],
             "kernel_steps_full": (closure_sweeps + out["sweep_i"])
             * launch_blocks,
             "dirty_chunks": out["st_dirty"],
@@ -520,6 +540,7 @@ def _host_stats(host: dict) -> dict:
         "gated_tiles": int(host["gated_tiles"]),
         "n_sweeps": int(host["n_sweeps"]),
         "kernel_steps": int(host["kernel_steps"]),
+        "kernel_contractions": int(host["kernel_contractions"]),
         "kernel_steps_full": int(host["kernel_steps_full"]),
         "dirty_chunks": host["dirty_chunks"][:k].tolist(),
         "tiles_skipped": host["tiles_skipped"][:k].tolist(),
@@ -779,7 +800,9 @@ class DecrementalTracer:
         closure's chunk walks, whether it gave up at its price, and the
         supertiles the first repair sweep was forced through),
         ``n_sweeps`` (repair), ``kernel_steps`` of ``kernel_steps_full``
-        (the grid steps its kernels took, of launches x blocks),
+        (the grid steps its kernels took, of launches x blocks) and
+        ``kernel_contractions`` (those of the steps that gathered a new
+        bit and paid for their contraction),
         ``jump_sweeps`` (the repair sweeps that ran the pointer jump),
         ``jump_spent`` (the ``auto`` policy's sparse chunk walks at exit;
         against :attr:`jump_price`) and, for the repair's first

@@ -45,7 +45,14 @@ MXU rate.  The output BlockSpec revisits one supertile block per run of
 grid steps via a scalar-prefetched supertile-id, so accumulation happens
 in VMEM and each block hits HBM exactly once per sweep.  The grid visits
 only the blocks that have work this sweep (``build_propagate``); a
-supertile none of whose blocks has any reads as zeros.
+supertile none of whose blocks has any reads as zeros.  Building A and B
+and the contraction are what a visited block costs (about 2 of its 2.4 us
+on the v5e), so a block pays for them only when its gather found a bit:
+the gathered bits are reduced to one scalar and everything after the
+gather sits under it.  And what is gathered are the bits that are NEW
+since the sweep before (the table operand holds the full table over its
+new bits; only a block forced by the destination gate reads the full
+one): a bit set earlier was delivered in the sweep after it was set.
 
 Per-edge metadata is packed into two int32 arrays (source row; and
 lane|bit|dst_lane|dst_sub) to halve HBM streaming per sweep.
@@ -608,6 +615,13 @@ def dirty_group_lists(table, table_prev, n_chunks, group_rows, jnp):
         return d, l, d[n_chunks] > 0
 
 
+def walk_tables(table, table_prev, jnp):
+    """The table operand of ``build_propagate``'s kernels, (2 * r_rows,
+    LANE): ``table`` over its bits that are new since ``table_prev``,
+    the table of the sweep before."""
+    return jnp.concatenate([table, table & ~table_prev], axis=0)
+
+
 def pack_hits_table(hits2d, r_rows, jnp):
     """pack_hits_words padded and reshaped into the (r_rows, LANE) word
     table — the exact per-sweep pack on the fixpoint path and the
@@ -644,27 +658,28 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
     n_pad_nodes = t_rows * LANE
     sub_iota_rows = jnp.arange(s_rows, dtype=jnp.int32)
 
-    def sweep(table, d, l, layout_args, gate=None):
-        return with_steps(table, d, l, layout_args, gate)[0]
+    def sweep(tables, d, l, layout_args, gate=None):
+        return with_steps(tables, d, l, layout_args, gate)[0]
 
-    def with_steps(table, d, l, layout_args, gate=None):
+    def with_steps(tables, d, l, layout_args, gate=None):
         with scope("push"):
-            return push(table, d, l, layout_args, gate)
+            return push(tables, d, l, layout_args, gate)
 
-    def push(table, d, l, layout_args, gate):
+    def push(tables, d, l, layout_args, gate):
         contrib = jnp.zeros((t_rows, LANE), jnp.float32)
         xla_hits2d = jnp.zeros((t_rows, LANE), bool)
         have_xla = False
-        steps = jnp.zeros((), jnp.int32)
+        steps = contracted = jnp.zeros((), jnp.int32)
         pos = 0
         for spec, propagate in zip(specs, propagates):
             if spec[0] == "xla":
                 psrc, pdst = layout_args[pos:pos + 2]
                 pos += 2
                 # Source-active bits gathered straight from the packed
-                # table; sink pads (src = n) masked out.
+                # table (the FULL one: the operand's first half); sink
+                # pads (src = n) masked out.
                 word = psrc >> 5
-                w = table[word >> 7, word & 127]
+                w = tables[word >> 7, word & 127]
                 src_active = (((w >> (psrc & 31)) & 1) > 0) & (psrc < n)
                 prop = (
                     jnp.zeros((n_pad_nodes + 1,), jnp.int32)
@@ -687,10 +702,11 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
                     gates = (gate[super_ids],)
             elif gate is not None:
                 gates = (gate,)
-            c, took = propagate.with_steps(
-                d, l, *gates, bmeta1, bmeta2, table, row_pos, emeta
+            c, took, did = propagate.with_steps(
+                d, l, *gates, bmeta1, bmeta2, tables, row_pos, emeta
             )
             steps = steps + took
+            contracted = contracted + did
             if compact:
                 rows = (
                     super_ids[:, None] * s_rows + sub_iota_rows[None, :]
@@ -703,7 +719,7 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
         hits2d = contrib > 0
         if have_xla:
             hits2d = hits2d | xla_hits2d
-        return hits2d, steps
+        return hits2d, steps, contracted
 
     sweep.with_steps = with_steps
     return sweep
@@ -1071,6 +1087,18 @@ def pad_layout_blocks(prep: Dict[str, np.ndarray], target: int) -> None:
     prep["n_blocks"] = target
 
 
+def slot_sources(prep: Dict[str, np.ndarray], empty: int) -> np.ndarray:
+    """(blocks, slots a block) source node of every slot of a packed
+    layout, decoded from ``row_pos`` and ``emeta`` as the kernel's gather
+    reads them; ``empty`` where a slot holds no pair (its row is beyond
+    the table)."""
+    slots = ROWS * prep["sub"] * LANE
+    row = prep["row_pos"].reshape(-1, slots).astype(np.int64)
+    emeta = prep["emeta"].reshape(-1, slots)
+    src = (row * LANE + (emeta & 127)) * WORD_BITS + ((emeta >> 7) & 31)
+    return np.where(row < prep["r_rows"], src, empty)
+
+
 def device_args(prep: Dict[str, np.ndarray]) -> tuple:
     """The kernel operands (after flags/recv) in call order."""
     if "xla_src" in prep:
@@ -1159,18 +1187,40 @@ def build_propagate(
 ):
     """One propagation sweep as a pallas_call: gather source bits from the
     packed table, one-hot segment-sum into per-supertile contributions.
-    Returns ``propagate(d, l, [gate,] bmeta1, bmeta2, table, row_pos,
+    Returns ``propagate(d, l, [gate,] bmeta1, bmeta2, tables, row_pos,
     emeta) -> contributions``; ``propagate.with_steps`` returns beside
-    them the grid steps the launch took.
+    them the grid steps the launch took and those of them that
+    contracted.
 
-    Operands (after the scalar-prefetch ones): the (r_rows, LANE) bit
-    table, then row_pos and emeta.  Scalar-prefetch operands are the
-    dirty-chunk prefix D (size n_chunks + 1, D[c] = number of dirty
-    chunks below c), the compacted dirty-chunk index list L, and bmeta1,
-    bmeta2: each block walks only the *dirty* chunks inside its span.
+    Operands (after the scalar-prefetch ones): the (2 * r_rows, LANE) bit
+    tables (``walk_tables``: the full table over its bits that are new
+    since the sweep before), then row_pos and emeta.  Scalar-prefetch
+    operands are the dirty-chunk prefix D (size n_chunks + 1, D[c] =
+    number of dirty chunks below c), the compacted dirty-chunk index list
+    L, and bmeta1, bmeta2: each block walks only the *dirty* chunks inside
+    its span, and gathers only their NEW bits.
     Correct under the trace's monotone OR-accumulation: a clean chunk's
     words are unchanged since the sweep that last walked them, so the
-    skipped contribution is already in the mark vector.
+    skipped contribution is already in the mark vector; and so is that
+    of a bit of a dirty chunk that was set before the last sweep: it was
+    delivered in the sweep after it was set, or its destination tile was
+    saturated (``GATE_SKIP``) and stays so while its marks stand, or a
+    later wake forces that tile (``GATE_FULL``: the closure's members,
+    fresh inserts, reused slots), and a forced block reads the FULL
+    table.  A caller with no table of the sweep before hands the full
+    table in both halves.
+
+    A block whose gather found no bit skips its contraction: the gathered
+    bits are reduced to one scalar, and the one-hot operands, the MXU and
+    the accumulation run under it.  All such a block still owes, when it
+    is the first active block of its output tile, is the zero tile (the
+    tile's buffer is written back when the block index changes, whatever
+    the step did to it).  Contributions are bit-identical to contracting
+    every block: a contraction of zeros adds zero.  The steps that did
+    contract are counted where it happens, in an SMEM scalar output (the
+    grid is sequential).  On a derivation of the 10M power-law graph
+    25,885 of 69,782 steps have nothing to contract (PERF.md section 6,
+    PR 39).
 
     A block with no chunk to walk is not visited at all.  Before each
     launch the callable computes, in XLA and by the kernel's own rule
@@ -1250,10 +1300,15 @@ def build_propagate(
         else:
             d_ref, l_ref, meta1_ref, meta2_ref, act_ref = refs[:5]
             s_ref = None
-        # the aliased plane (refs[-5]) is never read: it is what an
+        # the aliased plane (refs[-6]) is never read: it is what an
         # unvisited output tile holds
-        table_ref, row_ref, emeta_ref, out_ref = refs[-4:]
+        table_ref, row_ref, emeta_ref, out_ref, cnt_ref = refs[-5:]
         i = pl.program_id(0)
+
+        @pl.when(i == 0)
+        def _():
+            cnt_ref[0] = 0
+
         blk = act_ref[i]
         m1 = meta1_ref[blk]
         m2 = meta2_ref[blk]
@@ -1269,13 +1324,15 @@ def build_propagate(
         if dst_gate:
             gated = s_ref[m1 >> 1] == GATE_FULL
             l_cap = l_ref.shape[0] - 1
+            # a forced block re-derives from the FULL table (the first
+            # half of the operand); every other block gathers what is NEW
+            half = jnp.where(gated, 0, r_rows)
         else:
             gated = None
+            half = r_rows
 
         row_iota = jax.lax.broadcasted_iota(jnp.int32, (block_rows, LANE), 0)
         r8_iota = row_iota & 7  # slot row class = src row mod 8
-        sub_iota = jax.lax.broadcasted_iota(jnp.int32, (s_rows, LANE), 0)
-        lane_iota = jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 1)
 
         @pl.when(n_iter > 0)
         def _():
@@ -1283,8 +1340,6 @@ def build_propagate(
             emeta = emeta_ref[:]
             lane_idx = emeta & 127
             bit_pos = (emeta >> 7) & 31
-            dst_lane = (emeta >> 12) & 127
-            dst_sub = (emeta >> 19) & 31
 
             def chunk_body(j, acc):
                 # One iteration walks a group_rows-row table group:
@@ -1298,8 +1353,8 @@ def build_propagate(
                     c = jnp.where(gated, c_lo + j, lc)
                 else:
                     c = l_ref[j_lo + j]
-                tab_g = table_ref[pl.ds(c * group_rows, group_rows), :]
                 base = c * group_rows
+                tab_g = table_ref[pl.ds(half + base, group_rows), :]
                 for s in range(group):
                     sub_c = tab_g[s * ROWS : (s + 1) * ROWS, :]
                     # Stack the 8-row sub-chunk `sub` times so slot row
@@ -1321,45 +1376,70 @@ def build_propagate(
                 jnp.zeros((block_rows, LANE), jnp.int32),
             )
             bits = jax.lax.shift_right_logical(words, bit_pos) & 1
-            mm_dt = jnp.int8 if use_int8 else jnp.bfloat16
-            acc_dt = jnp.int32 if use_int8 else jnp.float32
-            # int8 has no vector multiply on the VPU (Mosaic: "failed to
-            # legalize arith.muli" on i8): mask in int32, narrow after.
-            vals = bits if use_int8 else bits.astype(mm_dt)
+            # Sublane rows on the VPU first, then one lane reduce.
+            any_bit = jnp.max(jnp.max(bits, axis=0, keepdims=True)) > 0
 
-            # Fused one-hot segment-sum on the MXU: one
-            # (s_rows, block_rows*128) @ (block_rows*128, 128)
-            # contraction per block.
-            a_parts = []
-            b_parts = []
-            for r in range(block_rows):
-                # Mask-multiply instead of jnp.where: a where() whose
-                # selected operand is a sublane-broadcast bf16 vector does
-                # not lower through Mosaic on the current TPU toolchain.
-                # vals is 0/1 bits, so the product is bit-identical to the
-                # select.
-                a_parts.append(
-                    (
-                        (sub_iota == dst_sub[r, :][None, :]).astype(vals.dtype)
-                        * vals[r, :][None, :]
-                    ).astype(mm_dt)
-                )
-                b_parts.append(
-                    (lane_iota == dst_lane[r, :][:, None]).astype(mm_dt)
-                )
-            a = jnp.concatenate(a_parts, axis=1)  # (s_rows, block_rows*LANE)
-            b = jnp.concatenate(b_parts, axis=0)  # (block_rows*LANE, LANE)
-            acc = jnp.dot(a, b, preferred_element_type=acc_dt)
-            if use_int8:
-                acc = acc.astype(jnp.float32)
-
-            @pl.when(first)
+            # A block that gathered nothing has nothing to contract: the
+            # operands below and the MXU are what a visited block costs.
+            @pl.when(any_bit)
             def _():
-                out_ref[:] = acc
+                cnt_ref[0] = cnt_ref[0] + 1
+                dst_lane = (emeta >> 12) & 127
+                dst_sub = (emeta >> 19) & 31
+                sub_iota = jax.lax.broadcasted_iota(
+                    jnp.int32, (s_rows, LANE), 0
+                )
+                lane_iota = jax.lax.broadcasted_iota(
+                    jnp.int32, (LANE, LANE), 1
+                )
+                mm_dt = jnp.int8 if use_int8 else jnp.bfloat16
+                acc_dt = jnp.int32 if use_int8 else jnp.float32
+                # int8 has no vector multiply on the VPU (Mosaic: "failed
+                # to legalize arith.muli" on i8): mask in int32, narrow
+                # after.
+                vals = bits if use_int8 else bits.astype(mm_dt)
 
-            @pl.when(jnp.logical_not(first))
+                # Fused one-hot segment-sum on the MXU: one
+                # (s_rows, block_rows*128) @ (block_rows*128, 128)
+                # contraction per block.
+                a_parts = []
+                b_parts = []
+                for r in range(block_rows):
+                    # Mask-multiply instead of jnp.where: a where() whose
+                    # selected operand is a sublane-broadcast bf16 vector
+                    # does not lower through Mosaic on the current TPU
+                    # toolchain.  vals is 0/1 bits, so the product is
+                    # bit-identical to the select.
+                    a_parts.append(
+                        (
+                            (sub_iota == dst_sub[r, :][None, :]).astype(
+                                vals.dtype
+                            )
+                            * vals[r, :][None, :]
+                        ).astype(mm_dt)
+                    )
+                    b_parts.append(
+                        (lane_iota == dst_lane[r, :][:, None]).astype(mm_dt)
+                    )
+                a = jnp.concatenate(a_parts, axis=1)  # (s_rows, block_rows*LANE)
+                b = jnp.concatenate(b_parts, axis=0)  # (block_rows*LANE, LANE)
+                acc = jnp.dot(a, b, preferred_element_type=acc_dt)
+                if use_int8:
+                    acc = acc.astype(jnp.float32)
+
+                @pl.when(first)
+                def _():
+                    out_ref[:] = acc
+
+                @pl.when(jnp.logical_not(first))
+                def _():
+                    out_ref[:] = out_ref[:] + acc
+
+            # What a skipped block still owes: a tile's buffer is written
+            # back when the block index changes, whatever its steps did.
+            @pl.when(first & jnp.logical_not(any_bit))
             def _():
-                out_ref[:] = out_ref[:] + acc
+                out_ref[:] = jnp.zeros((s_rows, LANE), jnp.float32)
 
         # only the one step of a launch with no active block gets here
         @pl.when(jnp.logical_not(n_iter > 0) & first)
@@ -1378,6 +1458,7 @@ def build_propagate(
     n_scalars = 6 if dst_gate else 5
     blockmap = pl.BlockSpec((block_rows, LANE), imap_block)
     out_shape = jax.ShapeDtypeStruct((out_tiles * s_rows, LANE), jnp.float32)
+    cnt_shape = jax.ShapeDtypeStruct((1,), jnp.int32)
 
     def assemble(steps, *operands):
         """The call over a grid of ``steps``, a traced value: a dynamic
@@ -1388,17 +1469,22 @@ def build_propagate(
             grid=(steps,),
             in_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),  # plane, aliased out
-                # bit table: whole array, VMEM-resident across all steps
-                pl.BlockSpec((r_rows, LANE), imap_table),
+                # bit tables, full over new: whole array, VMEM-resident
+                # across all steps
+                pl.BlockSpec((2 * r_rows, LANE), imap_table),
                 blockmap,  # row_pos
                 blockmap,  # emeta
             ],
-            out_specs=pl.BlockSpec((s_rows, LANE), imap_out),
+            out_specs=[
+                pl.BlockSpec((s_rows, LANE), imap_out),
+                # the steps that contracted: the grid is sequential
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+            ],
         )
         return pl.pallas_call(  # uigc-lint: disable=UC304
             kernel,
             grid_spec=grid_spec,
-            out_shape=out_shape,
+            out_shape=[out_shape, cnt_shape],
             input_output_aliases={n_scalars: 0},
             interpret=interpret,
             name=KERNEL_NAME,
@@ -1412,20 +1498,20 @@ def build_propagate(
     launch = jax.jit(assemble, inline=True)
 
     def onto(plane, d, l, *operands):
-        """(contributions, grid steps that had work) of one launch over
-        the output buffer ``plane``, which it consumes.  The grid is as
-        long as the list of active blocks, and at least one step: a launch
-        with nothing to do writes one zero tile.  Tiles with no active
-        block are never visited and keep what ``plane`` held; the first
-        active block of a tile overwrites it."""
+        """(contributions, grid steps that had work, steps of them that
+        contracted) of one launch over the output buffer ``plane``, which
+        it consumes.  The grid is as long as the list of active blocks,
+        and at least one step: a launch with nothing to do writes one zero
+        tile.  Tiles with no active block are never visited and keep what
+        ``plane`` held; the first active block of a tile overwrites it."""
         gate = operands[0] if dst_gate else None
-        bmeta1, bmeta2, table, row_pos, emeta = operands[-5:]
+        bmeta1, bmeta2, tables, row_pos, emeta = operands[-5:]
         act, count = active_blocks(d, gate, bmeta1, bmeta2)
-        out = launch(
-            jnp.maximum(count, 1), d, l, *operands[:-3], act, plane, table,
+        out, contracted = launch(
+            jnp.maximum(count, 1), d, l, *operands[:-3], act, plane, tables,
             row_pos, emeta,
         )
-        return out, count
+        return out, count, contracted[0]
 
     def with_steps(d, l, *operands):
         """``onto`` a zero plane: an unvisited tile contributes nothing."""

@@ -209,8 +209,13 @@ def make_local_shard_ops(axis, words_pad, r_rows, n_pad, shard_size, jnp):
         t_local = shard_size // pt.LANE
 
         def sweep_hits(table, d, l, gate):
+            # The kernel's table operand is the full table over its new
+            # bits; the mesh counts every set bit as new (the full table
+            # in both halves): a block gathers every set bit of the
+            # chunks it walks, and contracts only if it found one.
+            tables = pt.walk_tables(table, jnp.zeros_like(table), jnp)
             contrib = propagate(
-                d, l, gate, bmeta1, bmeta2, table, row_pos, emeta
+                d, l, gate, bmeta1, bmeta2, tables, row_pos, emeta
             )
             src_active = src_bits(table, bsrc)
             prop = (
