@@ -50,6 +50,8 @@ class FakeWake:
     """Stands for the collector's active wake (``telemetry/profile.py``):
     brackets nothing, keeps what the backend notes."""
 
+    ordinal = 0
+
     def __init__(self):
         self.fields = {}
 
@@ -58,6 +60,12 @@ class FakeWake:
 
     def phase(self, name):
         return nullcontext()
+
+    def part(self, field, annotation=None):
+        return nullcontext()
+
+    def defer(self, read, handle):
+        pass
 
 
 class Rig:

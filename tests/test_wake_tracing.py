@@ -13,7 +13,13 @@ sweep counters, and the collector's phases as trace annotations.
   policy (``pt.closure_gives_up``), and the verdicts equal the oracle's;
 - ``WakeProfiler`` records hold the new phases, exclusive and adding up
   to ``wall_s``; an ``annotate`` hook sees ``uigc:wake`` enclose every
-  phase on the collector's thread, and nothing without a profiler.
+  phase on the collector's thread, and nothing without a profiler;
+- the life of a release on the wake's record: what the drain's oldest
+  flush had waited (``ingest_wait_s``, all three planes), the gap since
+  the wake before, ``stage_s`` and ``dispatch_s`` inside their phases,
+  the stop cascade of what the sweep freed counted in from the
+  dispatchers' threads; the counters' readback is nobody's phase; the
+  profiler listens to no event; and none of it exists without one.
 """
 
 import gc
@@ -307,6 +313,10 @@ class FakeAnnotations:
         return Mark()
 
 
+#: the nested annotations and the phase each lies directly inside
+PARTS = {"uigc:stage": "upload", "uigc:dispatch": "device"}
+
+
 def test_phases_are_exclusive_and_add_up_to_the_wall():
     notes = FakeAnnotations()
     prof = profile.WakeProfiler("n", annotate=notes)
@@ -397,7 +407,11 @@ def test_annotations_enclose_every_phase_on_the_collectors_thread():
             if name == events.DEVICE_TRACE:
                 device_events.append(dict(fields))
 
+        # the profiler alone leaves the recorder off: the test wants the event
+        assert not events.recorder.enabled
+        events.recorder.enable()
         events.recorder.add_listener(on_event)
+        listening = time.time()
 
         def swept():
             return any(r.get("freed") for r in prof.wakes_since(0.0))
@@ -406,6 +420,7 @@ def test_annotations_enclose_every_phase_on_the_collectors_thread():
         records = prof.wakes_since(0.0)
     finally:
         events.recorder.remove_listener(on_event)
+        events.recorder.disable()
         kit.shutdown()
     log = list(notes.log)
     # the hook was swapped in while the collector ran: start at a whole wake
@@ -420,12 +435,16 @@ def test_annotations_enclose_every_phase_on_the_collectors_thread():
                 assert not stack
             else:
                 assert stack and stack[0][0] == "uigc:wake" and stack[0][1] == args
+            # the two parts sit directly inside their phases
+            if name in PARTS:
+                assert stack[-1] == ("uigc:" + PARTS[name], args)
             stack.append((name, args))
             seen.add(name)
         else:
             assert stack.pop() == (name, args)
     assert {"uigc:wake", "uigc:ingest", "uigc:fold", "uigc:trace", "uigc:layout",
-            "uigc:upload", "uigc:device", "uigc:readback", "uigc:sweep"} <= seen
+            "uigc:upload", "uigc:device", "uigc:readback", "uigc:sweep",
+            "uigc:stage", "uigc:dispatch"} <= seen
     swept_rec = [r for r in records if r.get("freed")]
     # (a wake can free slots whose marks were gone already: no sweep then)
     assert swept_rec and all(r["device_s"] > 0 and r["n_sweeps"] >= 0 for r in swept_rec)
@@ -438,13 +457,21 @@ def test_annotations_enclose_every_phase_on_the_collectors_thread():
         inside = sum(r["phases"][p] for p in ("layout", "upload", "device", "readback"))
         assert inside <= r["device_s"] * 1.001
         assert sum(r["phases"].values()) <= r["wall_s"]
+        # parts, not phases: the phases around them keep their meaning
+        assert 0 < r["stage_s"] <= r["phases"]["upload"]
+        assert 0 < r["dispatch_s"] <= r["phases"]["device"]
+        assert r["trace_mode"] == "auto"
     assert sorted(r["wake"] for r in records) == [r["wake"] for r in records]
-    # the device call's event carries the same counters as the record
-    counted = [e for e in device_events if "n_sweeps" in e]
-    assert counted and all(
-        e["trace_mode"] == "auto" and e["jump_sweeps"] == sum(e["sweep_jump_on"])
-        and len(e["sweep_jump_on"]) == e["n_sweeps"] for e in counted
+    # device_s is the bracket of the device call's event, taken beside it
+    # (the event closes around it), whose counters now come with the record
+    assert device_events and all(
+        e["trace_mode"] == "auto" and "upload_bytes" in e and "n_sweeps" not in e
+        for e in device_events
     )
+    called = [r for r in records if r["device_s"] > 0 and r["t"] > listening]
+    assert called
+    for r in called:
+        assert any(0 <= e["duration_s"] - r["device_s"] < 0.05 for e in device_events), r
 
 
 def test_no_annotation_and_one_program_without_a_profiler(monkeypatch):
@@ -467,3 +494,354 @@ def test_no_annotation_and_one_program_without_a_profiler(monkeypatch):
     assert calls == []
     # the program counted its sweeps all the same
     assert stats and all(w["n_sweeps"] >= 0 for w in stats)
+
+
+# ------------------------------------------------------------------- #
+# the life of a release: wait, gap, cascade, on the wake's own record
+# ------------------------------------------------------------------- #
+
+NO_TIMER_MS = 86_400_000  # the test wakes the collector by hand
+
+
+def _wait(cond, timeout=30.0):
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        if cond():
+            return True
+        time.sleep(0.005)
+    return cond()
+
+
+def _tree(extra, fanout=3, depth=2):
+    """A kit whose root spawns, on ``Spawn``, a tree of
+    ``(fanout ** (depth + 1) - 1) / (fanout - 1)`` actors (every
+    constructor inside ``spawn``) and lets go of it on ``Drop``; the
+    ``PostStop``s are counted in ``stopped``."""
+    from uigc_tpu import AbstractBehavior, ActorTestKit, Behaviors, NoRefs
+    from uigc_tpu.runtime.signals import PostStop
+
+    class Spawn(NoRefs):
+        pass
+
+    class Drop(NoRefs):
+        pass
+
+    stopped = []
+
+    def node(level):
+        class Node(AbstractBehavior):
+            def __init__(self, context):
+                super().__init__(context)
+                self.children = [
+                    context.spawn(node(level + 1), f"c{i}") for i in range(fanout)
+                ] if level < depth else []
+
+            def on_message(self, msg):
+                return self
+
+            def on_signal(self, signal):
+                if signal is PostStop:
+                    stopped.append(self.context.name)
+                return None
+
+        return Behaviors.setup(Node)
+
+    class Root(AbstractBehavior):
+        def __init__(self, context):
+            super().__init__(context)
+            self.top = None
+
+        def on_message(self, msg):
+            if isinstance(msg, Spawn):
+                self.top = self.context.spawn(node(0), f"top-{time.time_ns()}")
+            elif self.top is not None:
+                self.context.release(self.top)
+                self.top = None
+            return self
+
+    # a slow timer: the records of three rounds stay among the 256 kept
+    config = {"uigc.crgc.wakeup-interval": 100, "uigc.crgc.shadow-graph": "decremental"}
+    config.update(extra)
+    kit = ActorTestKit(config=config, name="cascade")
+    root = kit.spawn(Behaviors.setup_root(Root), "root")
+    size = (fanout ** (depth + 1) - 1) // (fanout - 1)
+    return kit, root, Spawn, Drop, stopped, size
+
+
+def test_a_released_tree_is_one_wakes_cascade_and_a_later_wake_leaves_it_alone():
+    kit, root, Spawn, Drop, stopped, size = _tree({"uigc.telemetry.wake-profile": True})
+    try:
+        prof = kit.system.telemetry.profiler
+        seen = {}  # ordinal -> the record as first seen whole
+
+        def whole():
+            for r in prof.wakes_since(0.0):
+                if r.get("cascade_s") is not None:
+                    seen.setdefault(r["wake"], r)
+            return len(seen)
+
+        # round 0 warms up: the first device wake compiles for seconds, and
+        # what is flushed meanwhile reaches the collector in one heap
+        for round_ in (0, 1, 2):
+            root.tell(Spawn())
+            time.sleep(0.4)  # every constructor's flush folded: the tree dies whole
+            before = whole()
+            root.tell(Drop())
+            assert _wait(lambda: len(stopped) == (round_ + 1) * size), len(stopped)
+            if round_ == 0:
+                continue  # its tree may have died in pieces, or before a wake saw it
+            assert _wait(lambda: whole() == before + 1), (round_, seen)
+            if round_ == 1:
+                first = max(seen)
+        again = {r["wake"]: r for r in prof.wakes_since(0.0)}[first]
+        last = seen[max(seen)]
+    finally:
+        kit.shutdown()
+    for rec in (seen[first], last):
+        # ONE wake frees the tree, stops its top, and is told of every PostStop
+        assert rec["freed"] == rec["freed_local"] == rec["stopped"] == size
+        assert rec["kills"] == 1
+        assert 0 < rec["sweep_end_s"] <= rec["wall_s"] and 0 < rec["last_stop_s"] < 30
+        assert rec["cascade_s"] == max(0.0, rec["last_stop_s"] - rec["sweep_end_s"])
+    assert last["wake"] > first
+    # the third tree's terminations went to the third wake's record alone
+    assert again == seen[first]
+
+
+def test_a_cascade_that_does_not_end_keeps_its_count_and_goes_with_its_record():
+    prof = profile.WakeProfiler("n", max_recent=4, annotate=FakeAnnotations())
+
+    def wake(freed_local):
+        w = prof.begin_wake()
+        with w.phase("sweep"):
+            if freed_local:
+                w.note(freed=freed_local, freed_local=freed_local)
+        w.end(entries=0, garbage=freed_local)
+        return w.ordinal
+
+    first = wake(3)
+    prof.cell_terminated(first, time.perf_counter())
+    prof.cell_terminated(first, time.perf_counter())
+    # a cell can stop before its wake has ended: counted in at the end
+    early = prof.begin_wake()
+    prof.cell_terminated(early.ordinal, time.perf_counter() + 1.0)
+    prof.wakes_since(0.0)  # a reader looks in between: the count must not be lost
+    with early.phase("sweep"):
+        early.note(freed=1, freed_local=1)
+    early.end(entries=0, garbage=1)
+    recs = {r["wake"]: r for r in prof.wakes_since(0.0)}
+    assert (recs[first]["stopped"], recs[first]["cascade_s"]) == (2, None)
+    assert recs[early.ordinal]["stopped"] == 1 and 0.9 < recs[early.ordinal]["cascade_s"] < 1.1
+    plain = wake(0)
+    assert "cascade_s" not in {r["wake"]: r for r in prof.wakes_since(0.0)}[plain]
+    # the open cascade is dropped when its record has left the recent ones
+    assert first in prof._cascades
+    for _ in range(4):
+        wake(0)
+    assert first not in prof._cascades and not prof._cascades
+
+
+def _manual(extra):
+    """A kit whose collector wakes only when the test tells it to, with
+    one actor under its root that flushes when it has handled a
+    message.  ``wake()`` returns the record of the wake it caused."""
+    from uigc_tpu import AbstractBehavior, ActorTestKit, Behaviors, NoRefs
+    from uigc_tpu.engines.crgc import collector
+
+    class Poke(NoRefs):
+        pass
+
+    class Root(AbstractBehavior):
+        def on_message(self, msg):
+            return self
+
+    config = {"uigc.crgc.wakeup-interval": NO_TIMER_MS, "uigc.telemetry.wake-profile": True}
+    config.update(extra)
+    kit = ActorTestKit(config=config, name="manual")
+    root = kit.spawn(Behaviors.setup_root(Root), "root")
+    engine = kit.system.engine
+    prof = kit.system.telemetry.profiler
+
+    def wake():
+        before = prof.to_json()["wakes"]
+        engine.bookkeeper_cell.tell(collector.WAKEUP)
+        assert _wait(lambda: prof.to_json()["wakes"] > before)
+        return prof.wakes_since(0.0)[before]
+
+    return kit, root, Poke, engine, wake
+
+
+@pytest.mark.parametrize("plane", ["packed", "entry", "foreign"])
+def test_ingest_wait_is_the_age_of_the_oldest_flush_the_wake_drained(plane):
+    packed = plane != "entry"
+    kit, root, Poke, engine, wake = _manual({"uigc.crgc.packed-entries": packed})
+    try:
+        assert (engine.packed_plane is not None) == packed
+        if packed:
+            assert engine.packed_plane.timed
+        time.sleep(0.1)
+        wake()  # what the start-up flushed
+        assert wake()["ingest_wait_s"] is None  # nothing flushed since: nothing waited
+
+        def flushed():
+            if packed:
+                return engine.packed_plane.first_write is not None
+            return engine.queue_since is not None
+
+        assert not flushed()
+        if plane == "foreign":
+            # one flush of a foreign root actor, in its plain uid
+            block = np.full((1, engine.packed_plane.width), -1, dtype=np.int64)
+            block[0, 1:4] = (0, 2, 0)
+            engine.packed_plane.write_foreign(block)
+        else:
+            root.tell(Poke())
+        assert _wait(flushed)
+        time.sleep(0.15)
+        if plane != "foreign":
+            root.tell(Poke())  # a younger flush does not move the clock
+            time.sleep(0.05)
+        rec = wake()
+        assert 0.15 <= rec["ingest_wait_s"] < 5.0, rec
+        assert rec["entries"] >= 1 and not flushed()
+        assert wake()["ingest_wait_s"] is None
+    finally:
+        kit.shutdown()
+
+
+def test_gap_is_the_pause_between_two_wakes():
+    kit, root, Poke, engine, wake = _manual({})
+    try:
+        first = wake()
+        time.sleep(0.2)
+        second = wake()
+        third = wake()
+    finally:
+        kit.shutdown()
+    assert second["wake"] == first["wake"] + 1 and third["wake"] == second["wake"] + 1
+    assert 0.2 <= second["gap_s"] < 5.0
+    assert 0 <= third["gap_s"] < 0.2
+    # and the very first wake of a profiler has no wake before it
+    prof = profile.WakeProfiler("n", annotate=FakeAnnotations())
+    prof.begin_wake().end(entries=0, garbage=0)
+    assert prof.wakes_since(0.0)[0]["gap_s"] is None
+
+
+def test_readback_does_not_wait_for_the_counters(monkeypatch):
+    """The wake's counters stay on the device; whoever reads the records
+    fetches them.  A tracer whose ``wake_stats`` is slow must cost the
+    wake nothing (it was the second, synchronous ``device_get`` of
+    every profiled wake, inside the ``readback`` bracket)."""
+    real = pd.DecrementalTracer.wake_stats
+
+    def slow(self, last_n=None):
+        time.sleep(0.05)
+        return real(self, last_n)
+
+    monkeypatch.setattr(pd.DecrementalTracer, "wake_stats", slow)
+    kit, root, Spawn, Drop = _served({"uigc.telemetry.wake-profile": True})
+    try:
+        prof = kit.system.telemetry.profiler
+
+        def swept():
+            return any(r.get("freed") for r in prof.wakes_since(0.0))
+
+        _churn(kit, root, Spawn, Drop, swept)
+        # inside the profiler: the record as the wake left it
+        with prof._lock:
+            raw = [dict(r) for r in prof._recent if r["device_s"] > 0]
+        read = [r for r in prof.wakes_since(0.0) if r["device_s"] > 0]
+    finally:
+        kit.shutdown()
+    assert raw and read
+    assert all(r["phases"]["readback"] < 0.05 for r in read), [r["phases"] for r in read]
+    assert all(r["n_sweeps"] >= 0 and len(r["sweep_dirty_chunks"]) == r["n_sweeps"] for r in read)
+
+
+def test_deferred_fields_are_read_once_in_one_call_and_a_failing_read_is_survived():
+    prof = profile.WakeProfiler("n", annotate=FakeAnnotations())
+    calls = []
+
+    def read(handles):
+        calls.append(list(handles))
+        return [{"n_sweeps": h} for h in handles]
+
+    def broken(handles):
+        raise RuntimeError("the device state is gone")
+
+    for handle, reader in ((3, read), (5, read), (7, broken)):
+        w = prof.begin_wake()
+        w.defer(reader, handle)
+        w.end(entries=0, garbage=0)
+    w = prof.begin_wake()
+    w.end(entries=0, garbage=0)
+    recs = prof.wakes_since(0.0)
+    assert [r.get("n_sweeps") for r in recs] == [3, 5, None, None]
+    assert calls == [[3, 5]]
+    assert [r.get("n_sweeps") for r in prof.to_json()["recent"]] == [3, 5, None, None]
+    assert calls == [[3, 5]]  # nothing is read twice
+
+
+def test_wake_profile_alone_leaves_the_recorder_off_and_still_fills_device_s():
+    assert "__call__" not in vars(profile.WakeProfiler)
+    was = events.recorder.enabled
+    events.recorder.disable()
+    try:
+        kit, root, Spawn, Drop = _served({"uigc.telemetry.wake-profile": True})
+        try:
+            tel = kit.system.telemetry
+            assert tel.profiler is not None and not callable(tel.profiler)
+            assert not tel._listeners
+            assert not events.recorder.enabled
+            prof = tel.profiler
+
+            def swept():
+                return any(r.get("freed") for r in prof.wakes_since(0.0))
+
+            _churn(kit, root, Spawn, Drop, swept)
+            assert not events.recorder.enabled
+            called = [r for r in prof.wakes_since(0.0) if r["device_s"] > 0]
+            doc = prof.to_json()
+        finally:
+            kit.shutdown()
+    finally:
+        if was:
+            events.recorder.enable()
+    assert called and all(r["trace_mode"] == "auto" for r in called)
+    for r in called:
+        inside = sum(r["phases"][p] for p in ("layout", "upload", "device", "readback"))
+        assert inside <= r["device_s"] * 1.001 <= r["wall_s"] * 1.001
+    assert doc["phases"]["trace"]["device_total_s"] >= sum(r["device_s"] for r in called) > 0
+
+
+@pytest.mark.parametrize("extra", [{}, {"uigc.telemetry.metrics": True}],
+                         ids=["no-telemetry", "metrics-only"])
+def test_without_a_profiler_no_cell_is_stamped_no_write_is_timed_nothing_is_called(
+        extra, monkeypatch):
+    called = []
+    monkeypatch.setattr(profile.WakeProfiler, "cell_terminated",
+                        lambda self, *a: called.append(a))
+    from uigc_tpu.runtime import cell as cell_mod
+
+    stamped = []
+    real = cell_mod.ActorCell.note_freed
+    monkeypatch.setattr(cell_mod.ActorCell, "note_freed",
+                        lambda self, wake: stamped.append(wake) or real(self, wake))
+    kit, root, Spawn, Drop, stopped, size = _tree(extra)
+    try:
+        tel = kit.system.telemetry
+        assert (tel is None) == (not extra) and (tel is None or tel.profiler is None)
+        engine = kit.system.engine
+        plane = engine.packed_plane
+        root.tell(Spawn())
+        time.sleep(0.3)
+        top = next(iter(root.cell.children.values()))
+        cells = [top] + list(top.children.values())
+        root.tell(Drop())
+        assert _wait(lambda: len(stopped) == size)
+        assert all(c.is_terminated for c in cells)
+    finally:
+        kit.shutdown()
+    assert not stamped and not called
+    assert all(getattr(c, "_freed_wake", None) is None for c in cells)
+    assert not plane.timed and plane.first_write is None and engine.queue_since is None

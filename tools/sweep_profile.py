@@ -495,7 +495,7 @@ def main():
 
     # --- per-mode fixpoint decomposition, through the wake profiler -- #
     # The same per-wake fields the engine notes into its active wake
-    # (engines/crgc/arrays.py _note_sweep_stats) flow through a real
+    # (engines/crgc/arrays.py _read_sweep_stats) flow through a real
     # WakeProfiler here, so this tool exercises — and its JSON matches —
     # the telemetry pipeline the pull-density threshold is tuned from.
     modes = [m.strip() for m in args.modes.split(",") if m.strip()]
@@ -503,54 +503,44 @@ def main():
     wake_records = None
     if modes:
         from uigc_tpu.telemetry.profile import WakeProfiler
-        from uigc_tpu.utils import events
 
         profiler = WakeProfiler(node="sweep_profile")
-        was_enabled = events.recorder.enabled
-        events.recorder.enable()
-        events.recorder.add_listener(profiler)
         flags_h, recv_h = graph["flags"], graph["recv_count"]
-        try:
-            for mode in modes:
-                use_jump = mode in (pt.MODE_JUMP, pt.MODE_AUTO)
+        for mode in modes:
+            use_jump = mode in (pt.MODE_JUMP, pt.MODE_AUTO)
 
-                def run():
-                    return pallas_decremental.derive(
-                        flags_h, recv_h, [prep], mode=mode,
-                        jump_parent=jp if use_jump else None,
-                    )
+            def run():
+                return pallas_decremental.derive(
+                    flags_h, recv_h, [prep], mode=mode,
+                    jump_parent=jp if use_jump else None,
+                )
 
-                wk = profiler.begin_wake()
-                with wk.phase("trace"):
-                    with events.recorder.timed(events.DEVICE_TRACE) as ev:
-                        run()  # compile + warmup
-                        t0 = time.perf_counter()
-                        _, stats = run()
-                        fix_ms = (time.perf_counter() - t0) * 1e3
-                        ev.fields["trace_mode"] = mode
-                        rows = {
-                            k: stats[k]
-                            for k in ("dirty_chunks", "tiles_skipped",
-                                      "pull_on", "jump_on")
-                        }
-                        wk.note(
-                            n_sweeps=stats["n_sweeps"],
-                            jump_sweeps=stats["jump_sweeps"],
-                            **{"sweep_" + k: v for k, v in rows.items()},
-                        )
-                wk.end(mode=mode)
-                mode_out[mode] = {
-                    "n_sweeps": stats["n_sweeps"],
-                    "fixpoint_ms": round(fix_ms, 2),
-                    "jump_sweeps": stats["jump_sweeps"],
-                    "kernel_steps": stats["kernel_steps"],
-                    "kernel_contractions": stats["kernel_contractions"],
-                    **rows,
+            wk = profiler.begin_wake()
+            with wk.phase("trace"), wk.part("device_s"):
+                run()  # compile + warmup
+                t0 = time.perf_counter()
+                _, stats = run()
+                fix_ms = (time.perf_counter() - t0) * 1e3
+                rows = {
+                    k: stats[k]
+                    for k in ("dirty_chunks", "tiles_skipped",
+                              "pull_on", "jump_on")
                 }
-        finally:
-            events.recorder.remove_listener(profiler)
-            if not was_enabled:
-                events.recorder.disable()
+                wk.note(
+                    trace_mode=mode,
+                    n_sweeps=stats["n_sweeps"],
+                    jump_sweeps=stats["jump_sweeps"],
+                    **{"sweep_" + k: v for k, v in rows.items()},
+                )
+            wk.end(mode=mode)
+            mode_out[mode] = {
+                "n_sweeps": stats["n_sweeps"],
+                "fixpoint_ms": round(fix_ms, 2),
+                "jump_sweeps": stats["jump_sweeps"],
+                "kernel_steps": stats["kernel_steps"],
+                "kernel_contractions": stats["kernel_contractions"],
+                **rows,
+            }
         wake_records = profiler.to_json()["recent"]
 
     out = {

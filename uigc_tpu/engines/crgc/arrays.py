@@ -25,6 +25,7 @@ dropping them preserves liveness verdicts while keeping slots recyclable.
 
 from __future__ import annotations
 
+from contextlib import contextmanager
 from typing import TYPE_CHECKING, Dict, List, NamedTuple, Optional, Set, Tuple
 
 import numpy as np
@@ -67,6 +68,19 @@ class PackedVerdicts(NamedTuple):
 
     garbage_w: np.ndarray
     num_live: int
+
+
+def _stamp_freed(cells: list, slots: np.ndarray, ordinal: int) -> int:
+    """Leave wake ``ordinal`` on every cell of ``slots`` that reports its
+    own termination (``ActorCell.note_freed``: a local actor's cell; a
+    proxy, a foreign slot's ``None`` or a test's stand-in has no such
+    method).  Returns how many of them have yet to terminate."""
+    stamped = 0
+    for slot in slots.tolist():
+        note_freed = getattr(cells[slot], "note_freed", None)
+        if note_freed is not None and note_freed(ordinal):
+            stamped += 1
+    return stamped
 
 
 def _readback(value, site: str) -> np.ndarray:
@@ -981,8 +995,7 @@ class ArrayShadowGraph:
         sweep takes either, :meth:`_verdict_slots`)."""
         if self.use_device:
             self._note_device_wake()
-            with events.recorder.timed(events.DEVICE_TRACE) as ev:
-                ev.fields["trace_mode"] = self.trace_mode
+            with self._device_call() as ev:
                 return self._compute_marks_decremental(ev.fields)
         # Host path: slice to the occupancy watermark.  Slots allocate
         # lowest-first (IntStack from_range), so live slots cluster low
@@ -1019,8 +1032,7 @@ class ArrayShadowGraph:
         if self.use_device:
             from ...ops import pallas_trace as _pt
 
-            with events.recorder.timed(events.DEVICE_TRACE) as ev:
-                ev.fields["trace_mode"] = self.trace_mode
+            with self._device_call() as ev:
                 ev.fields["capture_parents"] = True
                 mark, parent = _pt.marking_parents_jax(
                     self.flags,
@@ -1059,22 +1071,41 @@ class ArrayShadowGraph:
             else:
                 self.trace_impl = "pallas"
 
-    def _note_sweep_stats(self, stats: dict, event: dict) -> None:
-        """Hand the fixpoint's sweep counters to the active wake's
-        record (telemetry/profile.py) and to the ``DEVICE_TRACE``
-        ``event``'s fields: sweep counts, how many sweeps ran the pointer
-        jump, and the per-sweep frontier decomposition, which is where
-        the pull-density threshold is tuned from data
+    @contextmanager
+    def _device_call(self):
+        """One device call: the ``DEVICE_TRACE`` event (yielded, for its
+        fields) and, on the active wake's record, the same bracket as
+        ``device_s`` with the trace mode.  The wake is the backend's one
+        road to the profiler, so the profiler listens to no event."""
+        wake = self.profile_wake
+        with events.recorder.timed(events.DEVICE_TRACE) as ev, \
+                events.wake_part(wake, "device_s"):
+            ev.fields["trace_mode"] = self.trace_mode
+            if wake is not None:
+                wake.note(trace_mode=self.trace_mode)
+            yield ev
+
+    @staticmethod
+    def _read_sweep_stats(counters: list) -> List[dict]:
+        """The wake records' fields of the fixpoints' sweep counters,
+        read back from the handles wakes deferred (``_Wake.defer``):
+        sweep counts, how many sweeps ran the pointer jump, and the
+        per-sweep frontier decomposition, which is where the
+        pull-density threshold is tuned from data
         (tools/sweep_profile.py writes the same fields)."""
-        fields = {
-            key: stats[key]
-            for key in ("n_sweeps", "jump_sweeps", "closure_sweeps",
-                        "closure_bailed")
-        }
-        for key in ("dirty_chunks", "tiles_skipped", "pull_on", "jump_on"):
-            fields["sweep_" + key] = stats[key]
-        self.profile_wake.note(**fields)
-        event.update(fields)
+        from ...ops import pallas_decremental
+
+        out = []
+        for stats in pallas_decremental.read_counters(counters):
+            fields = {
+                key: stats[key]
+                for key in ("n_sweeps", "jump_sweeps", "closure_sweeps",
+                            "closure_bailed")
+            }
+            for key in ("dirty_chunks", "tiles_skipped", "pull_on", "jump_on"):
+                fields["sweep_" + key] = stats[key]
+            out.append(fields)
+        return out
 
     def _compute_marks_decremental(self, event: dict) -> PackedVerdicts:
         """Per-wake detection through the decremental tracer
@@ -1086,8 +1117,9 @@ class ArrayShadowGraph:
         once finding the region has cost its share of a derivation.
 
         The device call in its four steps, each a profiler phase when a
-        wake is attached: layout maintenance, upload, the wake program
-        from dispatch until its result is ready, readback (of the
+        wake is attached: layout maintenance, upload (of which
+        ``stage_wake`` is timed apart), the wake program from dispatch
+        (timed apart) until its result is ready, readback (of the
         verdict words: 1/8 of a byte a slot, not a bool vector)."""
         import jax
 
@@ -1098,18 +1130,21 @@ class ArrayShadowGraph:
             with events.wake_phase(wake, "upload"):
                 flags_dev = jax.device_put(self.flags)
                 recv_dev = jax.device_put(self.recv_count)
-                staged = dec.stage_wake()
+                with events.wake_part(wake, "stage_s", "stage"):
+                    staged = dec.stage_wake()
                 event["upload_bytes"] = self.flags.nbytes + self.recv_count.nbytes
                 if wake is not None:
                     wake.note(upload_bytes=event["upload_bytes"])
             with events.wake_phase(wake, "device"):
-                mark_w = dec.wake_device(flags_dev, recv_dev, staged)
+                with events.wake_part(wake, "dispatch_s", "dispatch"):
+                    mark_w = dec.wake_device(flags_dev, recv_dev, staged)
                 mark_w.block_until_ready()
+            if wake is not None:
+                # the wake's own counters stay on the device: whoever
+                # reads the record pays for their way to the host
+                wake.defer(self._read_sweep_stats, dec.last_counters())
             with events.wake_phase(wake, "readback"):
-                verdicts = self._read_verdicts(dec, mark_w, "marks.decremental")
-                if wake is not None:  # and the wake's own counters
-                    self._note_sweep_stats(dec.wake_stats(1)[-1], event)
-            return verdicts
+                return self._read_verdicts(dec, mark_w, "marks.decremental")
         except Exception:
             # A poisoned async result surfaces at the wait or at the
             # readback, after the tracer committed state; drop it so the
@@ -1357,6 +1392,12 @@ class ArrayShadowGraph:
             )
             kill_uids = freed_uids = _NO_UIDS
             examined = 0
+            if wake is not None and garbage_slots.size:
+                # before the first StopMsg: the stop cascade is timed to
+                # the last termination of the cells stamped here
+                wake.note(freed_local=_stamp_freed(
+                    self.cells, garbage_slots, wake.ordinal
+                ))
             if should_kill and kill_slots.size:
                 kill_uids = self._kill_slots_bulk(kill_slots)
             if garbage_slots.size:

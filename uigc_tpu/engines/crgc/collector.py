@@ -20,6 +20,7 @@ trace.  Multi-node (num-nodes > 1, attached to a Fabric):
 
 from __future__ import annotations
 
+import time
 from typing import TYPE_CHECKING, Any, Dict, Optional, Set
 
 from ...runtime.behaviors import RawBehavior
@@ -478,6 +479,8 @@ class Bookkeeper(RawBehavior):
             plane = engine.packed_plane
             rows = None
             with _phase(wake, "ingest"):
+                if wake is not None:
+                    wake.note(ingest_wait_s=self._ingest_wait())
                 if plane is not None:
                     rows = plane.drain()
                 batch = []
@@ -550,6 +553,20 @@ class Bookkeeper(RawBehavior):
                 # cannot have changed; skip the device round-trip.
                 n_garbage = 0
         return count, n_garbage
+
+    def _ingest_wait(self) -> Optional[float]:
+        """How long the oldest flush that the drain about to begin takes
+        has waited, in either plane (None: nothing has been flushed).
+        The planes' clocks are taken and cleared here, before the drain:
+        a flush landing in between is timed for the next one."""
+        engine = self.engine
+        since, engine.queue_since = engine.queue_since, None
+        plane = engine.packed_plane
+        if plane is not None:
+            first, plane.first_write = plane.first_write, None
+            if first is not None and (since is None or first < since):
+                since = first
+        return None if since is None else time.perf_counter() - since
 
     def _after_wake(self, n_garbage: int) -> None:
         # Cascade acceleration: a wake that killed actors triggers more

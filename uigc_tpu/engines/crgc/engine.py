@@ -9,6 +9,7 @@ requires no message ordering and tolerates drops and downed nodes.
 
 from __future__ import annotations
 
+import time
 from collections import deque
 from typing import TYPE_CHECKING, Any, Callable, Dict, Iterable, Optional
 
@@ -81,6 +82,10 @@ class CRGC(Engine):
         # append/popleft are atomic, giving the lock-free MPSC hand-off the
         # reference gets from ConcurrentLinkedQueue (CRGC.scala:18,52).
         self.queue: deque = deque()
+        #: ``perf_counter`` of the first entry queued since the collector
+        #: last looked, kept only under a wake profiler (the packed
+        #: plane's ``first_write``, for the object plane)
+        self.queue_since: Optional[float] = None
         self.entry_pool: deque = deque()
         self.packed_plane = None
 
@@ -358,6 +363,8 @@ class CRGC(Engine):
         entry = self._obtain_entry()
         state.flush_to_entry(is_busy, entry)
         self.queue.append(entry)
+        if self.wake_profiler is not None and self.queue_since is None:
+            self.queue_since = time.perf_counter()
 
     # ----------------------------------------------------------------- #
     # Remoting interception (reference: CRGC.scala:223-241)
@@ -439,6 +446,8 @@ class CRGC(Engine):
                 entry.updated_infos[i] = ref.info
             self.queue.append(entry)
             first = False
+        if self.wake_profiler is not None and self.queue_since is None:
+            self.queue_since = time.perf_counter()
 
     # ----------------------------------------------------------------- #
 

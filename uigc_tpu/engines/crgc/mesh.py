@@ -182,8 +182,7 @@ class MeshShadowGraph(ArrayShadowGraph):
         layouts sync mesh-natively first; state commits at dispatch
         (like DecrementalTracer.wake_device), so a pending wake
         discarded by a synchronous trace loses nothing."""
-        with events.recorder.timed(events.DEVICE_TRACE) as ev:
-            ev.fields["trace_mode"] = self.trace_mode
+        with self._device_call():
             self._sync_device()
             self.stats["wakes"] += 1
             with _MESH_COLLECTIVE_LOCK:
@@ -641,8 +640,7 @@ class MeshShadowGraph(ArrayShadowGraph):
 
     def compute_marks(self) -> np.ndarray:
         self._note_device_wake()
-        with events.recorder.timed(events.DEVICE_TRACE) as ev:
-            ev.fields["trace_mode"] = self.trace_mode
+        with self._device_call():
             self._sync_device()
             self.stats["wakes"] += 1
             meta = self._layout_meta

@@ -49,6 +49,7 @@ from __future__ import annotations
 
 import itertools
 import threading
+import time
 from typing import Dict, Optional
 
 import numpy as np
@@ -186,6 +187,14 @@ class PackedPlane:
         self._held: Optional[np.ndarray] = None
         self._lock = threading.Lock()
         self._tl = threading.local()
+        #: set while a wake profiler is attached (telemetry.Telemetry):
+        #: writers then leave the ``perf_counter`` of the first write
+        #: since the collector last looked in :attr:`first_write`.  The
+        #: collector takes and clears it BEFORE its drain, so a row that
+        #: drain holds back (stamped after the cut, so written after the
+        #: clearing) has its time on the clock of the drain that takes it.
+        self.timed = False
+        self.first_write: Optional[float] = None
 
     def next_seq(self) -> int:
         return next(self._seq)
@@ -201,6 +210,7 @@ class PackedPlane:
         k = rows.shape[0]
         if not k:
             return
+        handed = time.perf_counter() if self.timed else None
         cols = self._uid_cols
         uids = rows[:, cols]
         np.bitwise_or(uids, FOREIGN_BIT, out=uids, where=uids >= 0)
@@ -209,6 +219,8 @@ class PackedPlane:
         # no other thread's flush lands inside the block's stamps
         rows[:, 0] = np.fromiter(itertools.islice(self._seq, k), np.int64, k)
         self.ring().extend(rows)
+        if handed is not None and self.first_write is None:
+            self.first_write = handed  # once published, as a flush's
 
     def ring(self) -> PackedRing:
         r = getattr(self._tl, "ring", None)

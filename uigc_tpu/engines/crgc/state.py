@@ -10,6 +10,7 @@ calls them before every record (reference: CRGC.scala:108,121,158,172,215).
 
 from __future__ import annotations
 
+import time
 from typing import TYPE_CHECKING, List, Optional
 
 from ...interfaces import State as StateBase
@@ -261,3 +262,5 @@ class CrgcState(StateBase):
             self.updated_refobs[i] = None
         self.updated_idx = 0
         ring.commit()
+        if plane.timed and plane.first_write is None:
+            plane.first_write = time.perf_counter()

@@ -551,6 +551,19 @@ def _host_stats(host: dict) -> dict:
     }
 
 
+def read_counters(counters) -> List[dict]:
+    """The counters of some wakes (:meth:`DecrementalTracer.
+    last_counters`, or what a wake program returned last), read back
+    from the device now, in one crossing, as :meth:`DecrementalTracer.
+    wake_stats` gives them."""
+    import jax
+
+    return [
+        _host_stats(host)
+        for host in jax.device_get(list(counters))  # readback: a few hundred bytes of counters per wake, on request
+    ]
+
+
 def derivation(flags, recv_count, preps, interpret=None, mode=pt.MODE_PUSH,
                pull_density=pt.DEFAULT_PULL_DENSITY, jump_parent=None):
     """A derivation from nothing over these layouts, as (fn, args): the
@@ -809,15 +822,17 @@ class DecrementalTracer:
         ``pt.MAX_SWEEP_STATS`` sweeps, ``dirty_chunks``,
         ``tiles_skipped``, ``pull_on`` and ``jump_on``.
         Waits for a wake still in flight; costs the wakes nothing."""
-        import jax
-
         kept = list(self._stats)
         if last_n is not None:
             kept = kept[max(0, len(kept) - last_n):]
-        return [
-            _host_stats(host)
-            for host in jax.device_get(kept)  # readback: a few hundred bytes of counters per wake, on request
-        ]
+        return read_counters(kept)
+
+    def last_counters(self):
+        """The last wake's counters as its program left them on the
+        device, not read back: for :func:`read_counters`, later and on
+        whatever thread (the ``decremental`` backend hands them to the
+        wake's profiler record, which outlives the STATS_KEPT here)."""
+        return self._stats[-1]
 
     def invalidate(self) -> None:
         """Drop the previous-fixpoint device state (after a failed or

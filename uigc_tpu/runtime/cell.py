@@ -101,6 +101,9 @@ class ActorCell:
         "overflow_policy",
         "_space_cv",
         "_batch_tid",
+        # the collector wake whose sweep freed this cell: written by
+        # note_freed, so only under a wake profiler, and unset otherwise
+        "_freed_wake",
         "__weakref__",  # the wire codec's uid registry holds cells weakly
     )
 
@@ -789,18 +792,23 @@ class ActorCell:
                 thread=threading.get_ident(),
             )
         tel = self.system.telemetry
-        if tel is not None and tel.tracer.enabled:
-            # Causal parent: the span this stop was processed inside
-            # (a traced message whose handler stopped us), else the
-            # collector wave whose StopMsg — a singleton that cannot
-            # carry per-send context — issued the kill.
-            tracer = tel.tracer
-            tracer.instant(
-                "terminate",
-                parent=tracer.current() or tracer.last_wave,
-                path=self.path,
-                uid=self.uid,
-            )
+        if tel is not None:
+            if tel.tracer.enabled:
+                # Causal parent: the span this stop was processed inside
+                # (a traced message whose handler stopped us), else the
+                # collector wave whose StopMsg — a singleton that cannot
+                # carry per-send context — issued the kill.
+                tracer = tel.tracer
+                tracer.instant(
+                    "terminate",
+                    parent=tracer.current() or tracer.last_wave,
+                    path=self.path,
+                    uid=self.uid,
+                )
+            freed_wake = getattr(self, "_freed_wake", None)
+            if freed_wake is not None and tel.profiler is not None:
+                # the end of this cell's part in that wake's stop cascade
+                tel.profiler.cell_terminated(freed_wake, time.perf_counter())
         if dropped:
             self.system.record_dead_letters_dropped(self, dropped)
         for watcher in watchers:
@@ -808,6 +816,19 @@ class ActorCell:
         if self.parent is not None:
             self.parent.tell_system(_SysChildTerminated(self))
         self.system.unregister_cell(self)
+
+    def note_freed(self, wake: int) -> bool:
+        """The collector's sweep freed this actor in its wake ``wake``
+        (an ordinal of the wake profiler, the only caller's): left on
+        the cell for ``_finalize`` to report with.  False if the cell
+        has terminated already and will report nothing.  Under the lock
+        ``_finalize`` turns terminal under, so a cell told True reports
+        exactly once."""
+        with self._lock:
+            if self._lifecycle == _TERMINATED:
+                return False
+            self._freed_wake = wake
+            return True
 
     def stop(self) -> None:
         """Request this actor to stop (external, e.g. system shutdown)."""

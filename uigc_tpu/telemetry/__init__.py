@@ -148,11 +148,12 @@ class Telemetry:
             # With a registry present the profiler also exports
             # uigc_wake_phase_seconds{phase=...} histograms, not just
             # its BENCH-JSON dump.
+            # No listener: the collector hands its backend the wake.
             self.profiler = WakeProfiler(system.address, registry=self.registry)
-            self._listeners.append(self.profiler)
             engine = getattr(system, "engine", None)
             if engine is not None:
                 engine.wake_profiler = self.profiler
+                self._time_packed_plane(engine, True)
         if inspect_on:
             self.inspector = self._attach_inspector()
         if device_on:
@@ -184,6 +185,14 @@ class Telemetry:
             events.recorder.enable()
             for listener in self._listeners:
                 events.recorder.add_listener(listener)
+
+    @staticmethod
+    def _time_packed_plane(engine: Any, on: bool) -> None:
+        """Writers of the packed plane take the time of a drain's first
+        row only while a profiler reads it (``ingest_wait_s``)."""
+        plane = getattr(engine, "packed_plane", None)
+        if plane is not None:
+            plane.timed = on
 
     def _attach_inspector(self) -> Optional[LivenessInspector]:
         """Wire the liveness inspector: engine-side capture enablement
@@ -385,6 +394,7 @@ class Telemetry:
         engine = getattr(self.system, "engine", None)
         if engine is not None and engine.wake_profiler is self.profiler:
             engine.wake_profiler = None
+            self._time_packed_plane(engine, False)
         if self.observatory is not None:
             if engine is not None and (
                 engine.device_observatory is self.observatory

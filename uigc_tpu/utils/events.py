@@ -70,6 +70,14 @@ def wake_phase(wake: Any, name: str):
     return wake.phase(name) if wake is not None else nullcontext()
 
 
+def wake_part(wake: Any, field: str, annotation: Optional[str] = None):
+    """Bracket of a stretch inside a phase of ``wake``, timed into its
+    record's ``field`` and, where ``annotation`` is given, written onto
+    a trace's clock under that name (telemetry/profile.py ``_Part``); a
+    no-op when no profiler is attached."""
+    return wake.part(field, annotation) if wake is not None else nullcontext()
+
+
 def compile_geom(key: Any) -> str:
     """Short stable label of a compile-cache geometry key (crc32 of its
     repr) for ``tpu.compile`` events: process-stable, bounded label
