@@ -108,18 +108,22 @@ def _spec(geom):
     return (("dense", geom["n_blocks"], pt.SUB_TPU, pt.GROUP_TPU),)
 
 
-def _compile_wake(geom, s, mode):
-    fn = pd.get_wake_fn(
-        geom["n"], _spec(geom), geom["n_super"], geom["r_rows"], pt.S_ROWS,
-        interpret=False, mode=mode,
-    )
+def _lower_wake(fn, geom, s, mode):
     words = _struct((geom["r_rows"], LANE), np.int32, s)
     # suspects (2), the previous fixpoint (5 word tables and the walks
     # of its last derivation from nothing)
     args = _node_structs(geom, s) + [words] * 7 + [_struct((), np.int32, s)]
     if mode in (pt.MODE_JUMP, pt.MODE_AUTO):
         args.append(_struct((geom["n"] + 1,), np.int32, s))
-    return fn.lower(*args, *_layout_structs(geom, s)).compile()
+    return fn.lower(*args, *_layout_structs(geom, s))
+
+
+def _compile_wake(geom, s, mode):
+    fn = pd.get_wake_fn(
+        geom["n"], _spec(geom), geom["n_super"], geom["r_rows"], pt.S_ROWS,
+        interpret=False, mode=mode,
+    )
+    return _lower_wake(fn, geom, s, mode).compile()
 
 
 def _mosaic_calls(compiled) -> int:
@@ -264,11 +268,56 @@ def test_wake_program_counts_and_names(one_chip, mode):
     assert pt.KERNEL_NAME in text
 
 
-def test_int8_contraction_compiles(one_chip, monkeypatch):
-    """UIGC_KERNEL_INT8=1 swaps the one-hot contraction's datapath; the
-    flag is read at kernel build time and keyed into the fn cache."""
-    monkeypatch.setenv("UIGC_KERNEL_INT8", "1")
-    assert _mosaic_calls(_compile_wake(GEOM_SMALL, one_chip, pt.MODE_AUTO)) >= 1
+def _kernel_bodies(text) -> set:
+    """The distinct Mosaic kernels of a lowered program, by their
+    serialized bodies."""
+    import re
+
+    return set(re.findall(r'tpu_custom_call.*?backend_config = "([^"]*)"', text))
+
+
+class _EnvSpy(dict):
+    """``os.environ`` as a dict that notes the keys it is asked for."""
+
+    def __init__(self, env):
+        super().__init__(env)
+        self.asked = set()
+
+    def get(self, key, default=None):
+        self.asked.add(key)
+        return super().get(key, default)
+
+    def __getitem__(self, key):
+        self.asked.add(key)
+        return super().__getitem__(key)
+
+    def __contains__(self, key):
+        self.asked.add(key)
+        return super().__contains__(key)
+
+
+def test_one_propagate_kernel_and_no_knob_in_the_environment(
+    one_chip, monkeypatch
+):
+    """The wake program at 10M launches ONE kernel body from its two
+    fixpoints (there is no second build of the contraction's operands),
+    and building and lowering it asks the environment for no variable of
+    this repo's: what is lowered depends on the geometry alone."""
+    import os
+
+    geom = GEOM_10M
+    spy = _EnvSpy(os.environ)
+    monkeypatch.setattr(os, "environ", spy)
+    # built here and not taken from ``get_wake_fn``'s cache, which an
+    # earlier test has filled: the spy has to see the build
+    fn = pd._build_wake_fn(
+        geom["n"], _spec(geom), geom["n_super"], geom["r_rows"], pt.S_ROWS,
+        False, mode=pt.MODE_AUTO,
+    )
+    text = _lower_wake(fn, geom, one_chip, pt.MODE_AUTO).as_text()
+    assert text.count("tpu_custom_call") == 2  # closure and repair
+    assert len(_kernel_bodies(text)) == 1
+    assert not [k for k in spy.asked if k.upper().startswith("UIGC")]
 
 
 @pytest.mark.parametrize("geom", [MESH_SMALL, MESH_10M], ids=["64k", "10m"])

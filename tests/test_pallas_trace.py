@@ -261,85 +261,6 @@ def test_wide_geometry_matches_oracle(seed, sub, group):
     assert np.array_equal(got, expected)
 
 
-def test_int8_mxu_flag_parity():
-    """UIGC_KERNEL_INT8=1 (int8 one-hot contraction, int32 accumulation)
-    must produce oracle-identical marks.  The subprocess arm validates
-    the env wiring end-to-end (a fresh interpreter with the flag set);
-    test_int8_ab_in_process covers the in-process A/B path."""
-    import os
-    import subprocess
-    import sys
-
-    code = """
-import jax
-jax.config.update("jax_platforms", "cpu")
-import numpy as np
-from uigc_tpu.ops import pallas_trace, trace as trace_ops
-assert pallas_trace._int8_mxu(), "int8 flag did not take effect"
-import sys
-sys.path.insert(0, "tests")
-from test_pallas_trace import derive_graph, random_graph
-rng = np.random.default_rng(3)
-g = random_graph(rng, 1200, 5000)
-assert np.array_equal(derive_graph(*g), trace_ops.trace_marks_np(*g))
-print("INT8 PARITY OK")
-"""
-    env = dict(os.environ, UIGC_KERNEL_INT8="1")
-    out = subprocess.run(
-        [sys.executable, "-c", code],
-        capture_output=True,
-        text=True,
-        env=env,
-        cwd=str(__import__("pathlib").Path(__file__).resolve().parent.parent),
-        timeout=500,
-    )
-    assert "INT8 PARITY OK" in out.stdout, out.stderr[-2000:]
-
-
-@pytest.mark.tpu
-def test_int8_mxu_compiled_parity(monkeypatch):
-    """The int8 contraction through the real Mosaic lowering — interpret
-    mode cannot catch an int8-dot lowering failure.  In process: this
-    process holds the chip, so a child could not reach it (the flag is
-    read at kernel build time and keyed into the fn cache)."""
-    monkeypatch.setenv("UIGC_KERNEL_INT8", "1")
-    assert pallas_trace._int8_mxu()
-    rng = np.random.default_rng(3)
-    flags, recv, supervisor, src, dst, w = g = random_graph(rng, 1200, 5000)
-    prep = pallas_trace.prepare_chunks(src, dst, w, supervisor, 1200)
-    got, _ = pallas_decremental.derive(flags, recv, [prep], interpret=False)
-    assert np.array_equal(got, trace_ops.trace_marks_np(*g))
-
-
-def test_int8_ab_in_process(monkeypatch):
-    """UIGC_KERNEL_INT8 is read at kernel build time and keyed into the
-    fn cache, so one process can A/B both MXU datapaths (an
-    import-time read would freeze the choice per process).
-    The contraction is exact in both (operands are 0/1 bits)."""
-    import numpy as np
-
-    from uigc_tpu.models.graphgen import powerlaw_actor_graph
-    from uigc_tpu.ops import pallas_trace as pt
-
-    pd = pallas_decremental
-    n = 1 << 11
-    g = powerlaw_actor_graph(n, seed=5, garbage_fraction=0.4)
-    prep = pt.prepare_chunks(
-        g["edge_src"].astype(np.int32),
-        g["edge_dst"].astype(np.int32),
-        g["edge_weight"],
-        g["supervisor"],
-        n,
-    )
-    marks = {}
-    keys_before = set(pd._fn_cache)
-    for flag in ("0", "1"):
-        monkeypatch.setenv("UIGC_KERNEL_INT8", flag)
-        marks[flag], _ = pd.derive(g["flags"], g["recv_count"], [prep])
-    assert np.array_equal(marks["0"], marks["1"])
-    assert len(set(pd._fn_cache) - keys_before) == 2  # one program per datapath
-
-
 # --------------------------------------------------------------------- #
 # The compacted grid: a launch visits only the blocks that have work
 # --------------------------------------------------------------------- #
@@ -432,7 +353,7 @@ def _gathering_blocks(prep, table, dirty, gate, new=None):
     return (walked & (bit > 0)).any(axis=1)
 
 
-def _launch(prep, table, dirty, gate, fill=None, new=None):
+def _launch(prep, table, dirty, gate, fill=None, new=None, interpret=True):
     """(contributions, steps, steps that contracted) of one launch; over a
     buffer of ``fill``.  The kernel's table operand is ``table`` over
     ``new``, the table's own bits where none is given."""
@@ -445,7 +366,8 @@ def _launch(prep, table, dirty, gate, fill=None, new=None):
     l[d[:-1][dirty]] = np.flatnonzero(dirty)
     propagate = pallas_trace.build_propagate(
         prep["n_blocks"], prep.get("out_supers", prep["n_super"]),
-        prep["r_rows"], prep["s_rows"], True, sub=1, group=1, dst_gate=True,
+        prep["r_rows"], prep["s_rows"], interpret, sub=prep["sub"], group=1,
+        dst_gate=True,
     )
     tables = np.concatenate([table, table if new is None else new])
     operands = (d, l, gate, prep["bmeta1"], prep["bmeta2"], tables,
@@ -680,3 +602,113 @@ def test_unvisited_tiles_of_a_compact_layout_add_nothing():
             tables, d, l, gate, *args)),
         expected,
     )
+
+
+# --------------------------------------------------------------------- #
+# The contraction: one-hots written as packed bf16 words, over lanes
+# --------------------------------------------------------------------- #
+
+WORDS_N = 8 * 4096  # one walk chunk of sources, eight tiles of 32 x 128 cells
+WORDS_CASES = [
+    "every_cell_its_own_count", "a_block_on_one_cell",
+    "set_beside_clear_same_cell", "second_block_accumulates",
+]
+
+
+def _words_case(case, sub):
+    """(prep, psrc, pdst, table, tile, per-cell sums expected in that tile)
+    of a hand-made layout whose pairs all end in one 32 x 128 tile.  The
+    sums tell a wrong order of the rows inside the operands' packed words
+    apart: ``pltpu.bitcast`` puts bf16 rows 2i and 2i + 1 into word row i,
+    and the kernel writes its one-hots as those words."""
+    rng = np.random.default_rng(41)
+    lane = pallas_trace.LANE
+    s_rows = pallas_trace.S_ROWS
+    table = rng.integers(0, 1 << 31, (8, lane)).astype(np.int32)
+    is_set = _bits(table, np.arange(WORDS_N)) > 0
+    by_row = [np.flatnonzero(is_set[r * 4096 : (r + 1) * 4096]) + r * 4096
+              for r in range(8)]
+    clear = np.flatnonzero(~is_set)
+    slots = pallas_trace.ROWS * sub * lane  # a block's
+    want = np.zeros((s_rows, lane), np.int64)
+    if case == "every_cell_its_own_count":
+        # injective along every row and every column of the tile (131 is
+        # prime), so no permutation of rows, of lanes or of both reads as
+        # the identity: both halves of every word of both operands tell
+        tile = 1
+        si, li = np.mgrid[:s_rows, :lane]
+        want = 1 + (37 * si + li) % 131
+        cell = np.repeat(np.arange(s_rows * lane), want.reshape(-1))
+        psrc = rng.choice(np.flatnonzero(is_set), cell.size)
+    elif case in ("a_block_on_one_cell", "second_block_accumulates"):
+        # 128 * sub set sources in each of the eight row classes fill a
+        # block: its largest sum, and twice that over two blocks
+        tile = 2
+        blocks = 2 if case == "second_block_accumulates" else 1
+        psrc = np.concatenate(
+            [rng.choice(rows, blocks * slots // 8, replace=False)
+             for rows in by_row]
+        )
+        cell = np.full(psrc.size, (s_rows - 1) * lane + lane - 1)  # odd, odd
+        want[-1, -1] = blocks * slots
+    else:
+        # the gathered bit counts, not the slot: every cell of a diagonal
+        # band holds set and clear sources, and reads its set ones alone
+        tile = 3
+        cells = np.arange(s_rows * lane)[:: lane // 2 + 1]
+        n_set = 1 + cells % 3
+        cell = np.concatenate([np.repeat(cells, n_set), np.repeat(cells, 2)])
+        psrc = np.concatenate(
+            [rng.choice(np.flatnonzero(is_set), n_set.sum()),
+             rng.choice(clear, 2 * cells.size)]
+        )
+        want.reshape(-1)[cells] = n_set
+    pdst = tile * s_rows * lane + cell
+    prep = pallas_trace.prepare_pairs(
+        psrc, pdst, WORDS_N, s_rows=s_rows, pad_blocks_pow2=True, sub=sub,
+        group=1,
+    )
+    return prep, psrc, pdst, table, tile, want
+
+
+def _check_words_case(case, sub, interpret):
+    prep, psrc, pdst, table, tile, want = _words_case(case, sub)
+    s_rows, slots = prep["s_rows"], prep["row_pos"].size // prep["n_blocks"]
+    dirty = np.ones(1, bool)
+    gate = np.zeros(prep["n_super"], np.int32)
+    held = pallas_trace.slot_sources(prep, -1) >= 0
+    of_tile = (prep["bmeta1"] >> 1) == tile
+    if case == "a_block_on_one_cell":
+        assert held[of_tile].all() and of_tile.sum() == 1  # one full block
+    if case == "second_block_accumulates":
+        assert held[of_tile].all() and of_tile.sum() == 2
+    if case == "every_cell_its_own_count":
+        assert (held[of_tile].sum(axis=1) > 0).sum() > 100 // sub
+    expected = _reference_contribs(prep, psrc, pdst, table, dirty, gate)
+    rows = slice(tile * s_rows, (tile + 1) * s_rows)
+    assert np.array_equal(expected[rows], want.astype(np.float32))
+    assert want.max() <= of_tile.sum() * slots  # exact in f32 by far
+    out, steps, contracted = _launch(
+        prep, table, dirty, gate, interpret=interpret
+    )
+    assert np.array_equal(out, expected)
+    assert contracted == int(_gathering_blocks(prep, table, dirty, gate).sum())
+    assert contracted == int((held[of_tile].sum(axis=1) > 0).sum()) <= steps
+
+
+@pytest.mark.parametrize("sub", [1, 2])
+@pytest.mark.parametrize("case", WORDS_CASES)
+def test_contraction_of_packed_words_is_the_segment_sum(case, sub):
+    """The kernel's contraction against the numpy segment-sum, on blocks
+    made by hand: every cell of a tile hit by its own number of set slots,
+    a whole block (then two) on one cell of odd row and odd lane, and set
+    sources beside clear ones on the same cell."""
+    _check_words_case(case, sub, interpret=True)
+
+
+@pytest.mark.tpu
+@pytest.mark.parametrize("case", WORDS_CASES)
+def test_contraction_of_packed_words_compiled(case):
+    """The same through Mosaic at the chip's block (32 slot rows): what
+    settles the order of the rows inside a packed word on hardware."""
+    _check_words_case(case, pallas_trace.SUB_TPU, interpret=False)

@@ -470,12 +470,9 @@ def get_wake_fn(n, specs, n_super, r_rows, s_rows, interpret=None,
     """Cached jitted wake fn, one per geometry and mode."""
     if interpret is None:
         interpret = pt.default_interpret()
-    # _int8_mxu in the key: the flag is read at kernel build time, so
-    # flipping UIGC_KERNEL_INT8 between runs A/Bs both datapaths in one
-    # process instead of requiring a restart per arm.
     key = (
-        n, tuple(specs), n_super, r_rows, s_rows, interpret,
-        pt._int8_mxu(), mode, pull_density,
+        n, tuple(specs), n_super, r_rows, s_rows, interpret, mode,
+        pull_density,
     )
     fn = _fn_cache.get(key)
     if fn is None:

@@ -104,6 +104,8 @@ S_ROWS = 32
 # Sentinel row for empty slots: beyond any table chunk, so they never hit.
 _PAD_ROW = np.int32(1 << 28)
 _SPAN_BITS = 12  # chunk index / span fit in 12 bits up to ~134M actors
+#: bf16 1.0 in the low / the high half of a 32-bit word
+_ONE_LO, _ONE_HI = 0x3F80, 0x3F800000
 #: quantum for large-layout block padding (see _pad_blocks_target)
 _BLOCK_QUANTUM = 8192
 
@@ -551,18 +553,6 @@ def saturated_tiles(mark_w, iu_w, n_super, sup_words, jnp):
         return (
             ~(un.reshape(n_super, sup_words).any(axis=1))
         ).astype(jnp.int32)
-
-
-def _int8_mxu() -> bool:
-    """UIGC_KERNEL_INT8=1 runs the one-hot contraction in int8 with
-    int32 accumulation (A and B are 0/1, so it is exact) — on chips
-    whose MXU doubles int8 rate vs bf16 this is a candidate 2x when the
-    sweep is contraction-bound.  Read at kernel BUILD time and part of
-    every kernel-cache key, so one process can A/B by flipping the env
-    var between runs — no restart needed."""
-    import os
-
-    return os.environ.get("UIGC_KERNEL_INT8", "") not in ("", "0")
 
 
 def pack_hits_words(hits2d, jnp):
@@ -1263,7 +1253,6 @@ def build_propagate(
         group = d_group if group is None else group
     block_rows = ROWS * sub
     group_rows = ROWS * group
-    use_int8 = _int8_mxu()
     span_mask = (1 << _SPAN_BITS) - 1
 
     def block_iters(d, gate, bmeta1, bmeta2):
@@ -1386,46 +1375,52 @@ def build_propagate(
                 cnt_ref[0] = cnt_ref[0] + 1
                 dst_lane = (emeta >> 12) & 127
                 dst_sub = (emeta >> 19) & 31
-                sub_iota = jax.lax.broadcasted_iota(
-                    jnp.int32, (s_rows, LANE), 0
-                )
-                lane_iota = jax.lax.broadcasted_iota(
-                    jnp.int32, (LANE, LANE), 1
-                )
-                mm_dt = jnp.int8 if use_int8 else jnp.bfloat16
-                acc_dt = jnp.int32 if use_int8 else jnp.float32
-                # int8 has no vector multiply on the VPU (Mosaic: "failed
-                # to legalize arith.muli" on i8): mask in int32, narrow
-                # after.
-                vals = bits if use_int8 else bits.astype(mm_dt)
+                # One-hot segment-sum on the MXU: one (s_rows,
+                # block_rows*LANE) x (LANE, block_rows*LANE) contraction a
+                # block, over the slot index, which stays on lanes in both
+                # operands (no lane vector moves onto sublanes).  The
+                # one-hots are written as the int32 WORDS of the bf16
+                # operands (``pltpu.bitcast``'s row order: word row i
+                # holds bf16 rows 2i, low half, and 2i + 1).  A slot's
+                # word row is its even row, and the word's value, made
+                # once a block, has bf16 1.0 in the half the row's parity
+                # picks: one compare and one select a vreg and no convert,
+                # where ``(iota == x).astype(bf16)`` went through f32 and
+                # a pack, 2.34 us a block against 0.45 (PERF.md section 6,
+                # PR 41).  Exact: 0 and 1.0 in bf16, summed in f32.
+                one_sub = jnp.where((dst_sub & 1) > 0, _ONE_HI, _ONE_LO)
+                one_lane = jnp.where((dst_lane & 1) > 0, _ONE_HI, _ONE_LO)
 
-                # Fused one-hot segment-sum on the MXU: one
-                # (s_rows, block_rows*128) @ (block_rows*128, 128)
-                # contraction per block.
-                a_parts = []
-                b_parts = []
-                for r in range(block_rows):
-                    # Mask-multiply instead of jnp.where: a where() whose
-                    # selected operand is a sublane-broadcast bf16 vector
-                    # does not lower through Mosaic on the current TPU
-                    # toolchain.  vals is 0/1 bits, so the product is
-                    # bit-identical to the select.
-                    a_parts.append(
-                        (
-                            (sub_iota == dst_sub[r, :][None, :]).astype(
-                                vals.dtype
-                            )
-                            * vals[r, :][None, :]
-                        ).astype(mm_dt)
+                def one_hot_words(n_words, row, word):
+                    """bf16 (2 * n_words, block_rows*LANE): column k holds
+                    ``word[k]`` in word row ``row[k] // 2``."""
+                    even = 2 * jax.lax.broadcasted_iota(
+                        jnp.int32, (n_words, LANE), 0
                     )
-                    b_parts.append(
-                        (lane_iota == dst_lane[r, :][:, None]).astype(mm_dt)
+                    row = row & ~1
+                    parts = [
+                        jnp.where(
+                            even == row[r, :][None, :], word[r, :][None, :], 0
+                        )
+                        for r in range(block_rows)
+                    ]
+                    return pltpu.bitcast(
+                        jnp.concatenate(parts, axis=1), jnp.bfloat16
                     )
-                a = jnp.concatenate(a_parts, axis=1)  # (s_rows, block_rows*LANE)
-                b = jnp.concatenate(b_parts, axis=0)  # (block_rows*LANE, LANE)
-                acc = jnp.dot(a, b, preferred_element_type=acc_dt)
-                if use_int8:
-                    acc = acc.astype(jnp.float32)
+
+                # the sub one-hot times the gathered bit; an odd last
+                # row's word has a spare half
+                a = one_hot_words(
+                    (s_rows + 1) // 2, dst_sub,
+                    jnp.where(bits > 0, one_sub, 0),
+                )
+                b = one_hot_words(LANE // 2, dst_lane, one_lane)
+                acc = jax.lax.dot_general(
+                    a, b, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                if s_rows % 2:
+                    acc = acc[:s_rows]
 
                 @pl.when(first)
                 def _():
