@@ -1,0 +1,6 @@
+"""``ingest_ms.served10m``: ``ingest_ms.served`` (``layers/ingest_ms.served.py``) in the ``served-10m`` cell,
+where the wake is the collector's own, on its timer, beside 5M residents held by uid (``drivers/served_fold.py``)."""
+
+from harness.cell import reader_of
+
+read = reader_of("layers", "ingest_ms.served")

@@ -1,0 +1,6 @@
+"""``kernel_ms.served10m``: ``kernel_ms`` (``layers/kernel_ms.py``) in the ``served-10m`` cell,
+where the wake is the collector's own, on its timer, beside 5M residents held by uid (``drivers/served_fold.py``).  The wakes it counts are the ``bench:wake`` spans that driver writes around the backend's device call."""
+
+from harness.cell import reader_of
+
+read = reader_of("layers", "kernel_ms")

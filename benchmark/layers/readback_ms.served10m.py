@@ -1,0 +1,6 @@
+"""``readback_ms.served10m``: ``readback_ms.served`` (``layers/readback_ms.served.py``) in the ``served-10m`` cell,
+where the wake is the collector's own, on its timer, beside 5M residents held by uid (``drivers/served_fold.py``)."""
+
+from harness.cell import reader_of
+
+read = reader_of("layers", "readback_ms.served")

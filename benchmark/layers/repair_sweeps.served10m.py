@@ -1,0 +1,6 @@
+"""``repair_sweeps.served10m``: ``repair_sweeps`` (``layers/repair_sweeps.py``) in the ``served-10m`` cell,
+where the wake is the collector's own, on its timer, beside 5M residents held by uid (``drivers/served_fold.py``).  The wakes it counts are the ``bench:wake`` spans that driver writes around the backend's device call."""
+
+from harness.cell import reader_of
+
+read = reader_of("layers", "repair_sweeps")

@@ -1,0 +1,6 @@
+"""``sweep_edge_slots.served10m``: ``sweep_edge_slots.served`` (``layers/sweep_edge_slots.served.py``) in the ``served-10m`` cell,
+where the wake is the collector's own, on its timer, beside 5M residents held by uid (``drivers/served_fold.py``)."""
+
+from harness.cell import reader_of
+
+read = reader_of("layers", "sweep_edge_slots.served")

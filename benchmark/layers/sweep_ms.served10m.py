@@ -1,0 +1,6 @@
+"""``sweep_ms.served10m``: ``sweep_ms.served`` (``layers/sweep_ms.served.py``) in the ``served-10m`` cell,
+where the wake is the collector's own, on its timer, beside 5M residents held by uid (``drivers/served_fold.py``)."""
+
+from harness.cell import reader_of
+
+read = reader_of("layers", "sweep_ms.served")

@@ -261,3 +261,28 @@ def test_resident_operands_match_a_fresh_tracer(seed):
     src, dst, w = gt.edge_arrays()
     tracer.rebuild(src, dst, w, gt.supervisor)
     assert np.array_equal(resident(), gt.expected_marks())
+
+
+def test_small_scatters_share_one_padded_length():
+    """The O(churn) syncs of the device mirrors pad their writes to a
+    power of two, and every padded length is a program of its own.  Up
+    to ``_scatter_pad``'s floor they share one: a served wake's writes
+    vary from a few to a few thousand, and nothing may compile inside a
+    benchmark window.  The jump-parent mirror stays the host's."""
+    floor = 1 << pinc._SCATTER_PAD_LOG2
+    assert [pinc._scatter_pad(k) for k in (1, 63, 300, floor, floor + 1, 3 * floor)] == \
+        [floor, floor, floor, floor, 2 * floor, 4 * floor]
+    n = 8192
+    layout = pinc.IncrementalPallasLayout(n, s_rows=8, interpret=True)
+    none = np.empty(0, np.int32)
+    layout.rebuild(none, none, np.empty(0, np.int64), np.full(n, -1, np.int32))
+    assert np.array_equal(np.asarray(layout.jump_device()), layout.jump_parent)
+    for lo, hi in [(0, 3), (3, 300), (300, 310), (310, 2000)]:
+        for d in range(lo, hi):
+            layout.insert(d + 4000, d, pinc.EDGE)
+        assert np.array_equal(np.asarray(layout.jump_device()), layout.jump_parent)
+    for d in range(0, 2000):  # the removals are writes too
+        layout.remove(d + 4000, d, pinc.EDGE)
+    assert np.array_equal(np.asarray(layout.jump_device()), layout.jump_parent)
+    assert (layout.jump_parent == n).all()
+    assert layout._jump_scatter._cache_size() == 1  # five syncs, one program

@@ -228,6 +228,10 @@ class ArrayShadowGraph:
         #: slot's uid with ``FOREIGN_BIT`` set; such a slot has no cell.
         self._fuid_to_slot = np.full(1024, _UNSEEN, dtype=np.int64)
         self._has_foreign = False
+        #: slots foreign actors hold now: up where they are interned,
+        #: down where the sweep frees them (the slots local actors hold
+        #: are ``len(slot_of)``); the wake's record takes both
+        self.actors_foreign = 0
         #: where the sweep hands the foreign uids to stop and the ones
         #: it freed, once per trace: ``sink(kill_uids, freed_uids)``,
         #: two int64 arrays, on the collector's thread
@@ -758,6 +762,7 @@ class ArrayShadowGraph:
             self._slot_uid[at] = new | FOREIGN_BIT
             m[new] = at
             self.total_actors_seen += k
+            self.actors_foreign += k
             self._has_foreign = True
             if self._node_log is not None:
                 self._node_log.update(at.tolist())
@@ -1100,7 +1105,7 @@ class ArrayShadowGraph:
             fields = {
                 key: stats[key]
                 for key in ("n_sweeps", "jump_sweeps", "closure_sweeps",
-                            "closure_bailed")
+                            "closure_bailed", "gated_tiles")
             }
             for key in ("dirty_chunks", "tiles_skipped", "pull_on", "jump_on"):
                 fields["sweep_" + key] = stats[key]
@@ -1411,6 +1416,8 @@ class ArrayShadowGraph:
                     freed=int(garbage_slots.size),
                     kill_uids=int(kill_uids.size),
                     sweep_edge_slots=examined,
+                    actors_local=len(self.slot_of),
+                    actors_foreign=self.actors_foreign,
                 )
         return int(garbage_slots.size), n_live
 
@@ -1517,6 +1524,7 @@ class ArrayShadowGraph:
         if self._has_foreign:
             is_foreign, freed_foreign = self._foreign_among(garbage_slots)
             self._fuid_to_slot[freed_foreign] = _SWEPT
+            self.actors_foreign -= int(freed_foreign.size)
             cell_slots = garbage_slots[~is_foreign]
         freed_uids = su[cell_slots]
         had_uid = freed_uids >= 0

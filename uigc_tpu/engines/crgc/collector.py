@@ -205,7 +205,10 @@ class Bookkeeper(RawBehavior):
     def on_message(self, msg: Any) -> Any:
         if isinstance(msg, _Wakeup):
             if self.started:
-                self.collect()
+                # a bulk load in progress (CRGC.hold_traces): fold, as
+                # FOLD asks for, and leave the trace to the first
+                # wake-up after it
+                self.collect(trace=not self.engine.trace_holds)
         elif isinstance(msg, _Fold):
             if self.started:
                 self.collect(trace=False)

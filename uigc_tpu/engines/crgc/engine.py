@@ -9,8 +9,10 @@ requires no message ordering and tolerates drops and downed nodes.
 
 from __future__ import annotations
 
+import threading
 import time
 from collections import deque
+from contextlib import contextmanager
 from typing import TYPE_CHECKING, Any, Callable, Dict, Iterable, Optional
 
 from ...interfaces import GCMessage, Refob, SpawnInfo
@@ -88,6 +90,11 @@ class CRGC(Engine):
         self.queue_since: Optional[float] = None
         self.entry_pool: deque = deque()
         self.packed_plane = None
+        #: bulk loads in progress (:meth:`hold_traces` counts them in
+        #: and out): while there is one the Bookkeeper's wake-ups fold
+        #: and do not trace
+        self.trace_holds = 0
+        self._hold_lock = threading.Lock()
 
         self.bookkeeper = self.make_bookkeeper()
         self.bookkeeper_cell = system.spawn_system_raw(
@@ -127,6 +134,37 @@ class CRGC(Engine):
                 "on a single node, an array shadow graph)"
             )
         self.bookkeeper.shadow_graph.foreign_sink = sink
+
+    @contextmanager
+    def hold_traces(self):
+        """A bulk load in progress, for the length of the ``with`` block:
+        the Bookkeeper's wake-ups, which keep coming on its timer, drain
+        and fold what has been flushed and do not trace (each is what its
+        ``FOLD`` message asks for once); the first wake-up after the
+        block traces what the load left, and the cadence is the timer's
+        again.
+
+        A loader needs it for its verdicts, not only for its time: it
+        ships a graph that exists already, block by block and in no
+        causal order, so a trace in between sees actors whose only
+        referrer is in a block still to come and takes them for garbage.
+        (And a trace of a large part-loaded graph packs its layout from
+        nothing, for seconds, every time.)  Rows handed to the plane
+        inside the block are all in the first trace after it: that
+        wake-up drains before it traces.  Holds of several loaders
+        overlap; the collector traces when the last has gone."""
+        if self.distributed:
+            raise ValueError(
+                "the partitioned collector's wake is its wave protocol: "
+                "there is no trace to hold"
+            )
+        with self._hold_lock:
+            self.trace_holds += 1
+        try:
+            yield
+        finally:
+            with self._hold_lock:
+                self.trace_holds -= 1
 
     # Factory hooks so the multi-node engine can substitute richer parts.
 

@@ -59,6 +59,21 @@ SUP = 1
 Key = Tuple[int, int, int]
 
 
+#: least padded length of the O(churn) device scatters, log2.  A wake's
+#: writes are padded to a power of two, and every padded length is a
+#: program of its own: with a low floor a served wake's few hundred to
+#: few thousand writes (a round of short sessions) wander over half a
+#: dozen lengths, and one first met after the warm-up compiles in the
+#: middle of the traffic.  Up to this many they share one program; the
+#: padding is dropped writes, microseconds on the device.
+_SCATTER_PAD_LOG2 = 12
+
+
+def _scatter_pad(k: int) -> int:
+    """The padded length of a device scatter of ``k`` writes."""
+    return 1 << max(_SCATTER_PAD_LOG2, int(k - 1).bit_length())
+
+
 class IncrementalPallasLayout:
     """Mutable pair layout with O(changes) per-wake maintenance."""
 
@@ -512,7 +527,7 @@ class IncrementalPallasLayout:
 
                     self._dev_scatter = _scatter
                 k = len(writes)
-                kp = 1 << max(6, int(k - 1).bit_length())
+                kp = _scatter_pad(k)
                 packed = np.fromiter(writes, np.int64, k)
                 rows = np.full(kp, prep["row_pos"].shape[0], dtype=np.int32)
                 cols = np.zeros(kp, dtype=np.int32)
@@ -553,7 +568,7 @@ class IncrementalPallasLayout:
 
                 self._jump_scatter = _jscatter
             k = len(self._jump_writes)
-            kp = 1 << max(6, int(k - 1).bit_length())
+            kp = _scatter_pad(k)
             idx = np.full(kp, self.n + 1, dtype=np.int32)  # pad = dropped
             vals = np.zeros(kp, dtype=np.int32)
             idx[:k] = np.fromiter(self._jump_writes.keys(), np.int64, k)

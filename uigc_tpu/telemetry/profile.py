@@ -27,7 +27,10 @@ pipeline's named phases:
 A wake's record also carries ``fold_rows`` (packed rows folded),
 ``uids_interned`` (uids the fold interned, local or foreign) and
 ``upload_bytes`` (what the device call's ``device_put``s were handed):
-0 where a backend has nothing to count.
+0 where a backend has nothing to count; and, where a sweep ran,
+``actors_local`` and ``actors_foreign``: the slots in use after it by
+kind (actors with a cell; actors held by uid alone), from the graph's
+running counts.
 
 Around and inside the phases, all on ``time.perf_counter()`` (a reader
 puts them on a trace's clock through the ``uigc:wake`` annotation of the
@@ -59,8 +62,9 @@ record's ordinal; ``None`` where there was nothing to time):
                      no annotation; the two times stay ``None`` until
                      every cell has stopped
 
-The wake program's own counters (``n_sweeps``, ``closure_bailed``,
-``sweep_*``...) stay on the device when the wake ends; the backend
+The wake program's own counters (``n_sweeps``, ``closure_sweeps``,
+``closure_bailed``, ``gated_tiles``, ``sweep_*``...) stay on the device
+when the wake ends; the backend
 leaves a handle (:meth:`_Wake.defer`) and the records get them when they
 are read (:meth:`WakeProfiler.wakes_since`, :meth:`WakeProfiler.
 to_json`), on the reader's thread.
