@@ -239,6 +239,30 @@ def test_verdict_reduce_compiles_at_the_engine_cells_capacity(one_chip):
     assert "popcnt" in compiled.as_text()
 
 
+@pytest.mark.parametrize("slots", [4096, 1 << 17, 1 << 21], ids=["served", "engine", "share"])
+def test_node_patch_compiles_at_the_engine_cells_capacity(one_chip, slots):
+    """The scatter that brings the device's ``flags`` and ``recv_count``
+    up to the host's (``arrays._patch_fn``) at 2^24 slots: a served
+    round's padded length, an engine wake's, and the longest before a
+    wake uploads whole.  Both arrays are donated: the outputs alias
+    them, and the program holds no third copy."""
+    import jax.numpy as jnp
+
+    from uigc_tpu.engines.crgc import arrays
+
+    n = GEOM_ENGINE_16M["n"]
+    assert slots <= arrays._patch_pad(n // arrays._PATCH_SHARE)
+    compiled = arrays._patch_fn().lower(
+        _struct((n,), jnp.uint8, one_chip), _struct((n,), jnp.int32, one_chip),
+        _struct((slots,), jnp.int32, one_chip), _struct((slots,), jnp.uint8, one_chip),
+        _struct((slots,), jnp.int32, one_chip),
+    ).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 5 * n  # a byte and an int32 a slot
+    assert memory.output_size_in_bytes - 5 * n < 4096  # and the tuple that holds them
+    assert memory.temp_size_in_bytes < 5 * n
+
+
 @pytest.mark.parametrize(
     "mode", pt.TRACE_MODES, ids=lambda m: f"wake-{m}-plain"
 )

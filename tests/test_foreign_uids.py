@@ -669,8 +669,11 @@ def test_a_sink_needs_the_packed_plane():
 
 
 def test_decremental_wake_record_counts_the_upload():
-    """``upload_bytes`` is what the wake's two ``device_put`` calls were
-    handed: the whole ``flags`` and ``recv_count`` arrays, every wake."""
+    """``upload_bytes`` is what the wake handed the device for node
+    features: the whole ``flags`` and ``recv_count`` arrays on the first
+    wake, after it the padded patch of the slots written since (an int32
+    index, a flag byte and an int32 count a slot)."""
+    from uigc_tpu.engines.crgc import arrays
     from uigc_tpu.telemetry.profile import WakeProfiler
 
     graph, plane, _, sink = new_graph(use_device=True)
@@ -686,6 +689,6 @@ def test_decremental_wake_record_counts_the_upload():
     assert first["fold_rows"] == 10 and first["uids_interned"] == 9
     assert second["fold_rows"] == 1 and second["uids_interned"] == 0
     assert second["kill_uids"] == 1 and second["freed"] == 1
-    want = graph.flags.nbytes + graph.recv_count.nbytes
-    assert first["upload_bytes"] == second["upload_bytes"] == want
+    assert first["upload_bytes"] == graph.flags.nbytes + graph.recv_count.nbytes
+    assert second["upload_bytes"] == arrays._patch_pad(2) * (4 + 1 + 4)
     assert sink.freed.tolist() == [3]

@@ -165,6 +165,17 @@ def _wait(predicate, seconds=60.0):
     return False
 
 
+def _whole_wakes(profiler, n=2, each=lambda: True):
+    """Wait until ``n`` wakes that BEGAN after this call have ended, and
+    return the ordinal of the next.  What was flushed before the call has
+    by then been folded, traced and swept: a drain takes every row stamped
+    before it, and a stopped actor flushes its last before its ``PostStop``
+    (``CRGC.pre_signal``).  ``each`` runs at every poll."""
+    first = profiler.to_json()["wakes"] + 1  # its own ordinal: one in flight began before
+    assert _wait(lambda: each() and profiler.to_json()["wakes"] >= first + n, 120.0)
+    return profiler.to_json()["wakes"]
+
+
 def _kit(backend, **more):
     return ActorTestKit({
         "uigc.crgc.shadow-graph": backend,
@@ -317,14 +328,22 @@ def test_a_session_release_beside_residents_repairs_a_region():
         assert _wait(lambda: sink.freed.size == n_garbage, 120.0)
         world.run_sessions([0])  # warm: the pack after the mass death
         assert _wait(lambda: world.all_stopped([0]), 120.0)
-        time.sleep(0.3)
-        before = engine.wake_profiler.to_json()["wakes"]
+        profiler = engine.wake_profiler
+        before = _whole_wakes(profiler)  # and their second sweep: none of it below
+        by_wake = {}
+
+        def gather():
+            # at every poll: the profiler keeps its last 256 wakes, and an
+            # idle collector on a 10 ms timer makes a hundred a second
+            by_wake.update((r["wake"], r) for r in profiler.to_json()["recent"]
+                           if r["wake"] >= before and r["device_s"] > 0)
+            return True
+
         world.run_sessions([1])
-        assert _wait(lambda: world.all_stopped([1]), 120.0)
-        assert _wait(lambda: len(graph.slot_of) == 21 + 1, 120.0)  # and their second sweep
-        time.sleep(0.3)
-        records = [r for r in engine.wake_profiler.to_json()["recent"]
-                   if r["wake"] >= before and r["device_s"] > 0]
+        assert _wait(lambda: gather() and world.all_stopped([1]), 120.0)
+        _whole_wakes(profiler, each=gather)  # and their second sweep
+        assert len(graph.slot_of) == 21 + 1
+        records = [by_wake[wake] for wake in sorted(by_wake)]
         # a stopped actor's last flush interns its cell once more, and the
         # wake after frees that slot too
         assert records and sum(r["freed"] for r in records) in (world.session, 2 * world.session)

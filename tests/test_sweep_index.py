@@ -140,9 +140,10 @@ class Rig:
         drop = np.unique(drop)
         by = -g.edge_weight[drop] - (rng.random(drop.size) < 0.3)  # to zero, or below
         self.deltas(g.edge_src[drop], g.edge_dst[drop], by)
-        some = pick(4)
+        some, one = pick(4), pick(1)
         g.flags[some] ^= np.uint8(BUSY)
-        g.flags[pick(1)] |= np.uint8(HALTED if rng.random() < 0.3 else 0)
+        g.flags[one] |= np.uint8(HALTED if rng.random() < 0.3 else 0)
+        g._touch_batch(np.concatenate([some, one]))  # as a fold's write would
         return among
 
     def plant_garbage_held_from_live(self):
@@ -265,6 +266,7 @@ def test_sweep_frees_what_the_scan_and_the_dense_oracle_name(case, seed, monkeyp
         if round_ == rounds - 3:
             # a mass death: every root but the keeper lets go
             g.flags[among] &= np.uint8(0xFF & ~(ROOT | BUSY))
+            g._touch_batch(among)
 
         def between(live):
             live = live[live != rig.keeper]

@@ -25,8 +25,10 @@ dropping them preserves liveness verdicts while keeping slots recyclable.
 
 from __future__ import annotations
 
+import functools
+import math
 from contextlib import contextmanager
-from typing import TYPE_CHECKING, Dict, List, NamedTuple, Optional, Set, Tuple
+from typing import TYPE_CHECKING, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -57,6 +59,83 @@ _NO_UIDS = np.empty(0, dtype=np.int64)
 #: microsecond a dead slot with its references (binary searches over the
 #: runs, then gathers), the scan ten to twenty nanoseconds an edge slot
 _SCAN_SHARE = 64
+#: a wake whose dirty-slot log holds more than ``capacity`` over this
+#: uploads ``flags`` and ``recv_count`` whole instead of patching the
+#: device's copies: whole costs by the capacity, a patch by the slot.
+#: On the v5e's host (PERF.md section 6, PR 43) whole is 87-125 ms at
+#: 2^24 slots and 2.5 ms at 2^17, 5-7 ns a slot; a patch 1.4 ms up to
+#: 3k slots, then 43-55 ns a slot written (5.4 ms at 91k, 33 at 718k,
+#: 61 at 1.4M, 125 at 2.7M; 2.1 ms at 21k of 2^17): they cross where a
+#: sixth of the capacity was written.  The log counts a slot as often
+#: as it was written, 1.3-1.4 times in the cells' wakes: an eighth.
+_PATCH_SHARE = 8
+#: the padded length of a patch of ``k`` slots: the layout's O(churn)
+#: scatters' (a power of two, at least 4,096), for their reason: a
+#: served round's few thousand slots share one program
+_patch_pad = pallas_incremental_kinds._scatter_pad
+
+
+@functools.lru_cache(maxsize=None)
+def _patch_fn():
+    """The jitted scatter that brings the device's ``flags`` and
+    ``recv_count`` up to the host's: ``idx`` slots take the values given
+    (absolute, not deltas), a slot out of range (the padding) is
+    dropped, both arrays are donated.  One function for every graph: a
+    program per capacity and padded length."""
+    import jax
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def patch(flags, recv, idx, fvals, rvals):
+        return (
+            flags.at[idx].set(fvals, mode="drop", indices_are_sorted=True),
+            recv.at[idx].set(rvals, mode="drop", indices_are_sorted=True),
+        )
+
+    return patch
+
+
+class _NodeLog:
+    """The slots whose ``flags`` or ``recv_count`` the host wrote since
+    the consumer last took them: the scalars of :meth:`ArrayShadowGraph.
+    _touch` beside the index arrays of the batch sites (kept, not
+    copied: a site hands over an array it no longer writes), so that
+    logging costs an append whatever the batch holds.  Past ``cap``
+    entries, duplicates counted, it keeps nothing more and :meth:`take`
+    says so: the consumer's whole-array road is the cheaper one by then,
+    and a bulk load piles up nothing a patch will never use."""
+
+    __slots__ = ("cap", "_slots", "_arrays", "_size")
+
+    def __init__(self, cap: float = math.inf):
+        self.cap = cap
+        self._slots: List[int] = []
+        self._arrays: List[np.ndarray] = []
+        self._size = 0
+
+    def add(self, slot: int) -> None:
+        if self._size <= self.cap:
+            self._size += 1
+            self._slots.append(slot)
+
+    def extend(self, slots: np.ndarray) -> None:
+        if self._size <= self.cap:
+            self._size += slots.size
+            if self._size > self.cap:
+                self._slots, self._arrays = [], []
+            else:
+                self._arrays.append(slots)
+
+    def take(self) -> Optional[np.ndarray]:
+        """The logged slots, ascending and each once (int64), or None
+        where more than ``cap`` were logged; the log starts anew."""
+        parts = self._arrays
+        if self._slots:
+            parts.append(np.asarray(self._slots, dtype=np.int64))
+        overflowed = self._size > self.cap
+        self._slots, self._arrays, self._size = [], [], 0
+        if overflowed:
+            return None
+        return np.unique(np.concatenate(parts)) if parts else _NO_UIDS
 
 
 class PackedVerdicts(NamedTuple):
@@ -268,9 +347,16 @@ class ArrayShadowGraph:
         self._pair_log: Optional[List[tuple]] = None
         self._log_cap = 1 << 20
         #: slots whose flags/recv changed since last consumed; enabled
-        #: (non-None) by backends that mirror node features elsewhere
-        #: (the mesh backend's sharded device arrays)
-        self._node_log: Optional[Set[int]] = None
+        #: (non-None) by backends that keep node features on a device:
+        #: the ``decremental`` backend while it holds ``_resident`` (a
+        #: log bounded at ``capacity / _PATCH_SHARE``), the mesh backend
+        #: for its sharded arrays (unbounded)
+        self._node_log: Optional[_NodeLog] = None
+        #: ``(flags_dev, recv_dev)``: the device's copies of ``flags``
+        #: and ``recv_count`` at ``capacity``, as the last wake left
+        #: them; None until a wake uploaded both whole, and again after
+        #: a growth or a failed wake (:meth:`_node_operands`)
+        self._resident: Optional[tuple] = None
 
     # ------------------------------------------------------------- #
     # Capacity management (static-shape friendly: powers of two)
@@ -306,9 +392,11 @@ class ArrayShadowGraph:
         self._dying = np.zeros(new, dtype=bool)
         self.capacity = new
         # Node capacity sets the bit-table/supertile geometry: the whole
-        # Pallas layout must be rebuilt.
+        # Pallas layout must be rebuilt, and the device's node arrays
+        # are of the old capacity.
         self._pair_log = None
         self._dec = None
+        self._drop_resident()
 
     def _grow_edges(self, min_free: int = 1) -> None:
         """Grow in one jump to whatever power-of-two capacity yields
@@ -356,6 +444,10 @@ class ArrayShadowGraph:
     def _touch(self, slot: int) -> None:
         if self._node_log is not None:
             self._node_log.add(slot)
+
+    def _touch_batch(self, slots: np.ndarray) -> None:
+        if self._node_log is not None:
+            self._node_log.extend(slots)
 
     def _log_pair(self, insert: bool, src: int, dst: int, kind: int) -> None:
         """Record a live-pair transition for the incremental Pallas
@@ -636,8 +728,7 @@ class ArrayShadowGraph:
                 f[uf] = (f[uf] & keep) | last_bits[fresh]
             else:
                 f[u] = ((f[u] | interned) & keep) | last_bits
-            if self._node_log is not None:
-                self._node_log.update(sl.tolist())
+            self._touch_batch(sl)
 
         if sp_child.size:
             u, ridx = np.unique(sp_child[::-1], return_index=True)
@@ -764,8 +855,7 @@ class ArrayShadowGraph:
             self.total_actors_seen += k
             self.actors_foreign += k
             self._has_foreign = True
-            if self._node_log is not None:
-                self._node_log.update(at.tolist())
+            self._touch_batch(at)
             slots = m[fuids]
         return np.where(slots == _SWEPT, -1, slots)
 
@@ -1122,24 +1212,27 @@ class ArrayShadowGraph:
         once finding the region has cost its share of a derivation.
 
         The device call in its four steps, each a profiler phase when a
-        wake is attached: layout maintenance, upload (of which
-        ``stage_wake`` is timed apart), the wake program from dispatch
+        wake is attached: layout maintenance, upload (the slots of
+        ``flags`` and ``recv_count`` written since the wake before, as
+        a patch of the device's copies, :meth:`_node_operands`; then
+        ``stage_wake``, timed apart), the wake program from dispatch
         (timed apart) until its result is ready, readback (of the
-        verdict words: 1/8 of a byte a slot, not a bool vector)."""
-        import jax
-
+        verdict words: 1/8 of a byte a slot, not a bool vector).
+        ``upload_bytes`` is what the upload handed the device for node
+        features: the patch's padded index and value arrays, both
+        arrays whole where a wake took that road, 0 where no slot was
+        written."""
         wake = self.profile_wake
         with events.wake_phase(wake, "layout"):
             dec = self._dec = self._synced_dec()
         try:
             with events.wake_phase(wake, "upload"):
-                flags_dev = jax.device_put(self.flags)
-                recv_dev = jax.device_put(self.recv_count)
+                flags_dev, recv_dev, nbytes = self._node_operands()
                 with events.wake_part(wake, "stage_s", "stage"):
                     staged = dec.stage_wake()
-                event["upload_bytes"] = self.flags.nbytes + self.recv_count.nbytes
+                event["upload_bytes"] = nbytes
                 if wake is not None:
-                    wake.note(upload_bytes=event["upload_bytes"])
+                    wake.note(upload_bytes=nbytes)
             with events.wake_phase(wake, "device"):
                 with events.wake_part(wake, "dispatch_s", "dispatch"):
                     mark_w = dec.wake_device(flags_dev, recv_dev, staged)
@@ -1153,9 +1246,83 @@ class ArrayShadowGraph:
         except Exception:
             # A poisoned async result surfaces at the wait or at the
             # readback, after the tracer committed state; drop it so the
-            # next wake re-derives instead of feeding poisoned arrays.
+            # next wake re-derives instead of feeding poisoned arrays,
+            # and uploads the node arrays whole: the patch's donated
+            # results came off the same stream.
             dec.invalidate()
+            self._drop_resident()
             raise
+
+    def _drop_resident(self) -> None:
+        """Forget the device's node arrays and the log kept for them:
+        the next wake uploads both whole.  A graph that holds none (the
+        host backend; the mesh backend, whose log is its own) is left
+        as it is."""
+        if self._resident is not None:
+            self._resident = None
+            self._node_log = None
+
+    def _node_operands(self) -> tuple:
+        """``(flags_dev, recv_dev, upload_bytes)``: the device's
+        ``flags`` and ``recv_count`` brought up to the host's, for both
+        roads to ``wake_device``.  The copies stay on the device from
+        wake to wake (``_resident``) and take, in one donating scatter,
+        the current values of the slots logged since (``_node_log``),
+        narrowed as ``device_put`` narrows the whole array, so the
+        device reads bit for bit what a whole upload gives it.  They
+        hang on no fixpoint: ``dec.invalidate()``, a rebuild and a
+        repack leave them valid.  Both arrays go up whole, and a new
+        log starts, where there are no copies (the first wake, after a
+        growth, after a wake that failed) and where the log holds more
+        than ``capacity / _PATCH_SHARE`` slots (a bulk load, a mass
+        death).  One road, its parameter read from the log's size.
+        Where this call or the wake it serves raises, the caller drops
+        the copies (:meth:`_drop_resident`): the patch donated them."""
+        import jax
+
+        held = self._resident
+        dirty = self._node_log.take() if held is not None else None
+        if dirty is None:
+            resident = jax.device_put(self.flags), jax.device_put(self.recv_count)
+            self._node_log = _NodeLog(self.capacity // _PATCH_SHARE)
+            nbytes = self.flags.nbytes + self.recv_count.nbytes
+            if held is None:  # a capacity's first copies
+                resident = self._warm_patches(resident)
+        elif dirty.size:
+            resident, nbytes = self._patch(held, dirty, _patch_pad(dirty.size))
+        else:
+            resident, nbytes = held, 0
+        self._resident = resident
+        return (*resident, nbytes)
+
+    def _patch(self, resident: tuple, dirty: np.ndarray, kp: int) -> tuple:
+        """``((flags_dev, recv_dev), bytes handed over)``: the donated
+        ``resident`` pair with the host's values written at the
+        ``dirty`` slots (ascending), padded to ``kp`` entries with the
+        capacity, a slot out of range."""
+        flags_dev, recv_dev = resident
+        k = dirty.size
+        idx = np.full(kp, self.capacity, dtype=np.int32)
+        fvals = np.zeros(kp, dtype=np.uint8)
+        rvals = np.zeros(kp, dtype=recv_dev.dtype)
+        idx[:k] = dirty
+        fvals[:k] = self.flags[dirty]
+        rvals[:k] = self.recv_count[dirty].astype(rvals.dtype)
+        patched = _patch_fn()(flags_dev, recv_dev, idx, fvals, rvals)
+        if self.donation_audit:
+            audit_donation("decremental.patch", flags_dev, recv_dev)
+        return patched, idx.nbytes + fvals.nbytes + rvals.nbytes
+
+    def _warm_patches(self, resident: tuple) -> tuple:
+        """Run the patch at every padded length this capacity can meet,
+        all padding, so nothing is written: each length is a program of
+        its own, and a wake whose churn is the first to reach one would
+        compile it amid the traffic."""
+        kp = _patch_pad(1)
+        while kp <= _patch_pad(self.capacity // _PATCH_SHARE):
+            resident, _ = self._patch(resident, _NO_UIDS, kp)
+            kp *= 2
+        return resident
 
     @staticmethod
     def _read_verdicts(dec, mark_w, site: str) -> PackedVerdicts:
@@ -1229,12 +1396,13 @@ class ArrayShadowGraph:
         dispatches its sharded wake here, while the snapshot and
         bookkeeping stay in :meth:`launch_trace` — one home for the
         pending-wake tuple layout."""
-        import jax
-
         dec = self._synced_dec()
-        return dec, dec.wake_device(
-            jax.device_put(self.flags), jax.device_put(self.recv_count)
-        )
+        try:
+            flags_dev, recv_dev, _ = self._node_operands()
+            return dec, dec.wake_device(flags_dev, recv_dev)
+        except Exception:
+            self._drop_resident()
+            raise
 
     def launch_trace(self) -> None:
         """Dispatch the device wake without waiting for its result.
@@ -1274,6 +1442,7 @@ class ArrayShadowGraph:
             return False
         self._pending_wake = None
         dec.invalidate()
+        self._drop_resident()
         return True
 
     def harvest_trace(self, should_kill: bool) -> int:
@@ -1293,7 +1462,11 @@ class ArrayShadowGraph:
                 # double-count every harvested wake's transfer bytes.
                 verdicts = np.asarray(dec.unpack_marks(mark_w))  # readback: accounted in the handle
             else:
-                verdicts = self._read_verdicts(dec, mark_w, "marks.harvest")
+                try:
+                    verdicts = self._read_verdicts(dec, mark_w, "marks.harvest")
+                except Exception:
+                    self._drop_resident()  # patched on the stream that failed
+                    raise
             # Slots beyond the snapshot were interned after it: they
             # carry no verdict, and none of them is among these ids.
             n_garbage, n_live = self._sweep(
@@ -1562,8 +1735,7 @@ class ArrayShadowGraph:
                 cells[slot] = None
             locations[slot] = None
         self.free_slots.push_batch(garbage_slots)
-        if self._node_log is not None:
-            self._node_log.update(garbage_slots.tolist())
+        self._touch_batch(garbage_slots)
         return freed_foreign, examined
 
     # ------------------------------------------------------------- #

@@ -41,7 +41,7 @@ from ...ops import trace as trace_ops
 from ...ops.slotmap import PackedSlotMap, fold_log, pack_keys, unpack_keys
 from ...parallel import sharded_trace
 from ...utils import events
-from .arrays import ArrayShadowGraph, _readback, audit_donation
+from .arrays import ArrayShadowGraph, _NodeLog, _readback, audit_donation
 from .state import CrgcContext
 
 _SINK_PAD = 64  # scatter batches are padded to multiples of this
@@ -118,7 +118,7 @@ class MeshShadowGraph(ArrayShadowGraph):
         self.mesh = sharded_trace.build_mesh(n_devices)
         self._fold_fn = sharded_trace.make_sharded_fold(self.mesh, donate=True)
         self._mask_fn = sharded_trace.make_sharded_mask(self.mesh)
-        self._node_log = set()  # enable dirty-slot tracking in the base
+        self._node_log = _NodeLog()  # enable dirty-slot tracking in the base
 
         from ...ops import pallas_trace as pt
 
@@ -322,7 +322,7 @@ class MeshShadowGraph(ArrayShadowGraph):
         self._recv_synced = recv.copy()
 
         self._pair_log = []
-        self._node_log = set()
+        self._node_log = _NodeLog()
         self._wake_state = None
         self._pending_del_dst.clear()
         self._pending_fresh_dst.clear()
@@ -565,11 +565,8 @@ class MeshShadowGraph(ArrayShadowGraph):
                 )
             )
 
-        if self._node_log:
-            slots_arr = np.fromiter(
-                self._node_log, np.int64, len(self._node_log)
-            )
-            self._node_log = set()
+        slots_arr = self._node_log.take()
+        if slots_arr.size:
             # Bucket dirty slots by owning shard and run the sharded fold
             # (parallel/sharded_trace.make_sharded_fold): each device
             # scatter-applies only its own shard's rows — recv as deltas

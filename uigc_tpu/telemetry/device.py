@@ -112,7 +112,7 @@ _FAMILY_ATTRS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("edges", ("edge_src", "edge_dst", "edge_weight")),
     ("parents", ("last_parents", "last_parents_mark")),
     ("jump", ("_jump_parent", "_jump_dev")),
-    ("device_nodes", ("_dev_flags", "_dev_recv")),
+    ("device_nodes", ("_dev_flags", "_dev_recv", "_resident")),
     ("device_layout", ("_dev_stacked", "_stacked")),
     ("device_buckets", ("_dev_psrc", "_dev_pdst", "_pb_src", "_pb_dst")),
     ("wake_state", ("_wake_state", "_pending_wake", "_zero_words")),

@@ -26,8 +26,11 @@ pipeline's named phases:
 
 A wake's record also carries ``fold_rows`` (packed rows folded),
 ``uids_interned`` (uids the fold interned, local or foreign) and
-``upload_bytes`` (what the device call's ``device_put``s were handed):
-0 where a backend has nothing to count; and, where a sweep ran,
+``upload_bytes`` (what the upload handed the device for node features:
+the padded patch of the slots of ``flags`` and ``recv_count`` written
+since the wake before, both arrays at capacity where a wake uploaded
+them whole, 0 where no slot was written): 0 where a backend has nothing
+to count; and, where a sweep ran,
 ``actors_local`` and ``actors_foreign``: the slots in use after it by
 kind (actors with a cell; actors held by uid alone), from the graph's
 running counts.
