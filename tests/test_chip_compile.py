@@ -41,6 +41,9 @@ GEOM_TREE_100K = dict(n=131_072, n_blocks=1024, n_super=32, r_rows=64)
 #: graph folded into 2^24 slots): the widest table pair the kernel holds
 #: in VMEM, 2 x 2 MB
 GEOM_ENGINE_16M = dict(n=1 << 24, n_blocks=24_576, n_super=4096, r_rows=4096)
+#: kron_actor_graph(scale 22) (the benchmark's ``kron-s22``: 69.4M pairs,
+#: 16 walk chunks), as ``IncrementalPallasLayout.rebuild`` packs it
+GEOM_KRON_S22 = dict(n=1 << 22, n_blocks=24_576, n_super=1024, r_rows=1024)
 #: a 20k-actor layout (pow2-padded blocks)
 GEOM_SMALL = dict(n=20_000, n_blocks=128, n_super=5, r_rows=64)
 #: 10M over four shards, as ``pack_shard_layouts`` packs it
@@ -144,8 +147,8 @@ def _scalar_operand_bytes(geom) -> int:
 
 @pytest.mark.parametrize(
     "geom",
-    [GEOM_10M, GEOM_CHAIN_1M, GEOM_TREE_100K, GEOM_SMALL, GEOM_ENGINE_16M],
-    ids=["10m", "chain-1m", "tree-100k", "small", "engine-16m"],
+    [GEOM_10M, GEOM_CHAIN_1M, GEOM_TREE_100K, GEOM_SMALL, GEOM_ENGINE_16M, GEOM_KRON_S22],
+    ids=["10m", "chain-1m", "tree-100k", "small", "engine-16m", "kron-s22"],
 )
 def test_propagate_launch_compiles_with_its_list(one_chip, geom):
     """One launch as every caller makes it (``build_propagate``'s callable:
@@ -153,7 +156,8 @@ def test_propagate_launch_compiles_with_its_list(one_chip, geom):
     one Mosaic kernel (with the test of what a block gathered around its
     contraction and the SMEM counter of the steps that contracted), its
     scalar operands under the SMEM budget, and beside the contributions
-    the count of steps it took and of those that contracted."""
+    the count of steps it took, of those that contracted and of the
+    chunk-iterations their walks take."""
     import jax
 
     propagate = pt.build_propagate(
@@ -173,10 +177,11 @@ def test_propagate_launch_compiles_with_its_list(one_chip, geom):
     ).compile()
     assert _mosaic_calls(compiled) == 1
     assert _scalar_operand_bytes(geom) < SMEM_BUDGET
-    out, steps, contracted = compiled.out_info
+    out, steps, contracted, walks = compiled.out_info
     assert out.shape == (geom["n_super"] * pt.S_ROWS, LANE)
     assert steps.shape == () and steps.dtype == np.int32
     assert contracted.shape == () and contracted.dtype == np.int32
+    assert walks.shape == () and walks.dtype == np.int32
 
 
 def test_decremental_wake_compiles_at_10m(one_chip):
@@ -191,7 +196,7 @@ def test_decremental_wake_compiles_at_10m(one_chip):
     assert walks.shape == () and walks.dtype == np.int32
     assert stats["closure_bailed"].shape == stats["closure_spent"].shape == ()
     assert stats["kernel_steps"].shape == stats["kernel_steps_full"].shape == ()
-    assert stats["kernel_contractions"].shape == ()
+    assert stats["kernel_contractions"].shape == stats["kernel_chunk_walks"].shape == ()
     # three n_blocks-long int32 operands in SMEM (bmeta1, bmeta2 and the
     # list of active blocks) beside the gate and the dirty lists
     assert _scalar_operand_bytes(GEOM_10M) == 304_996 < SMEM_BUDGET
@@ -226,6 +231,21 @@ def test_decremental_wake_compiles_at_tree_100k(one_chip):
         GEOM_TREE_100K["r_rows"], pt.S_ROWS, interpret=False, mode=pt.MODE_AUTO,
     )
     assert fn.jump_price == 1
+
+
+def test_decremental_wake_compiles_at_kron_s22(one_chip):
+    """The Kronecker cell's geometry: 16 walk chunks of a graph that
+    dirties them all, where ``auto`` prices a jump sweep at 29 chunk
+    walks (the chip's run never pays it: its sweeps are dense)."""
+    compiled = _compile_wake(GEOM_KRON_S22, one_chip, pt.MODE_AUTO)
+    assert _mosaic_calls(compiled) >= 2
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4 << 30
+    fn = pd.get_wake_fn(
+        GEOM_KRON_S22["n"], _spec(GEOM_KRON_S22), GEOM_KRON_S22["n_super"],
+        GEOM_KRON_S22["r_rows"], pt.S_ROWS, interpret=False, mode=pt.MODE_AUTO,
+    )
+    assert fn.jump_price == 29
 
 
 def test_verdict_reduce_compiles_at_the_engine_cells_capacity(one_chip):

@@ -230,7 +230,8 @@ def test_contractions_of_a_derivation_with_an_unreachable_half(mode):
     actors released and unreachable) a derivation from nothing walks
     blocks that have nothing to contribute: sources never marked, or
     marked sweeps ago.  ``wake_stats()``'s ``kernel_contractions`` is
-    under ``kernel_steps``, and both equal what
+    under ``kernel_steps``, and both, and the ``kernel_chunk_walks`` of
+    the steps' walks, equal what
     ``tools/sweep_profile.py simulate_sweeps`` counts per sweep from the
     tracer's own packed layout (here at the interpreted geometry; the same
     code is the counter's oracle at the chip's)."""
@@ -255,6 +256,7 @@ def test_contractions_of_a_derivation_with_an_unreachable_half(mode):
     assert sim["dirty_chunks"] == s["dirty_chunks"]
     assert sum(sim["steps"]) == s["kernel_steps"]
     assert sum(sim["contracting"]) == s["kernel_contractions"]
+    assert sum(sim["chunk_iterations"]) == s["kernel_chunk_walks"] >= s["kernel_steps"]
     assert all(c <= t for c, t in zip(sim["contracting"], sim["steps"]))
 
 

@@ -373,12 +373,13 @@ def _launch(prep, table, dirty, gate, fill=None, new=None, interpret=True):
     operands = (d, l, gate, prep["bmeta1"], prep["bmeta2"], tables,
                 prep["row_pos"], prep["emeta"])
     if fill is None:
-        out, steps, contracted = jax.jit(propagate.with_steps)(*operands)
+        out, steps, contracted, walks = jax.jit(propagate.with_steps)(*operands)
     else:
         plane = jnp.full(
             (propagate(*operands).shape[0], pallas_trace.LANE), fill, jnp.float32
         )
-        out, steps, contracted = jax.jit(propagate.onto)(plane, *operands)
+        out, steps, contracted, walks = jax.jit(propagate.onto)(plane, *operands)
+    assert int(walks) == int(_block_iters(prep, dirty, gate).sum())
     return np.asarray(out), int(steps), int(contracted)
 
 
@@ -578,7 +579,7 @@ def test_unvisited_tiles_of_a_compact_layout_add_nothing():
     l = np.array([1, 0, 0], np.int32)
     args = pallas_trace.device_args(dense) + pallas_trace.device_args(comp)
     tables = np.concatenate([table, table])
-    hits, steps, contracted = jax.jit(
+    hits, steps, contracted, walks = jax.jit(
         lambda t, d, l, g, *a: sweep.with_steps(t, d, l, a, gate=g)
     )(tables, d, l, gate, *args)
     expected = (
@@ -597,6 +598,7 @@ def test_unvisited_tiles_of_a_compact_layout_add_nothing():
         _gathering_blocks(dense, table, dirty, gate).sum()
         + _gathering_blocks(comp, table, dirty, gate[comp["super_ids"]]).sum()
     )
+    assert int(walks) == int(_block_iters(dense, dirty, gate).sum() + n_iter_c.sum())
     assert np.array_equal(
         np.asarray(jax.jit(lambda t, d, l, g, *a: sweep(t, d, l, a, gate=g))(
             tables, d, l, gate, *args)),

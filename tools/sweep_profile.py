@@ -452,8 +452,8 @@ def main():
         full_ms, none_ms, half_ms = (
             probe_ms[k] for k in ("full", "none", "half")
         )
-        # the grid steps each probe took (its blocks with work) and those
-        # of them that contracted
+        # the grid steps each probe took (its blocks with work), those
+        # of them that contracted and the chunk-iterations they walked
         probe_steps = {k: [int(c) for c in propagate(*ops)[1:]]
                        for k, ops in operands.items()}
 
@@ -539,6 +539,7 @@ def main():
                 "jump_sweeps": stats["jump_sweeps"],
                 "kernel_steps": stats["kernel_steps"],
                 "kernel_contractions": stats["kernel_contractions"],
+                "kernel_chunk_walks": stats["kernel_chunk_walks"],
                 **rows,
             }
         wake_records = profiler.to_json()["recent"]

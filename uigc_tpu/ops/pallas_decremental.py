@@ -152,7 +152,10 @@ def _build_wake_fn(
     layouts: the blocks that had work; and launches x blocks, what a grid
     over every block would take), ``kernel_contractions`` (the steps that
     contracted, counted by the kernels themselves: the blocks whose
-    gather found a bit), ``jump_sweeps`` (the
+    gather found a bit), ``kernel_chunk_walks`` (the chunk-iterations the
+    kernels' walks took: per block with work, the dirty chunks in its
+    span, or the whole span where the gate forces it; the sum of the
+    vector the list of active blocks is made from), ``jump_sweeps`` (the
     repair sweeps that ran the jump) and ``jump_spent`` (the policy's
     ``spent`` at exit, to be read against the static
     :attr:`DecrementalTracer.jump_price`) are int32 scalars, ``dirty_chunks``,
@@ -227,7 +230,8 @@ def _build_wake_fn(
         def contribs(table, table_prev, d, l, gate):
             """One propagation sweep over every layout (shared loop:
             pallas_trace.build_sweep_contribs), the grid steps its kernels
-            took and those of them that contracted; a zero gate vector
+            took, those of them that contracted and the chunk-iterations
+            they walked; a zero gate vector
             makes the dst-gated kernels behave exactly like the plain
             ones.  ``d`` and ``l`` are the dirty lists of ``table``
             against ``table_prev``, the table of the sweep before: the
@@ -280,25 +284,26 @@ def _build_wake_fn(
 
         def c_body(carry):
             (closure_w, closure_prev, d, l, _, sweeps, spent, steps,
-             contracted) = carry
-            hits2d, took, did = contribs(
+             contracted, walked) = carry
+            hits2d, took, did, iters = contribs(
                 closure_w, closure_prev, d, l, zero_gate
             )
             hit_w = pt.pack_hits_table(hits2d, r_rows, jnp)
             new_closure = closure_w | (hit_w & prev_mark_w)
             d2, l2, changed = dirty_chunks(new_closure, closure_w)
             return (new_closure, closure_w, d2, l2, changed, sweeps + 1,
-                    spent + d[n_chunks], steps + took, contracted + did)
+                    spent + d[n_chunks], steps + took, contracted + did,
+                    walked + iters)
 
         with pt.scope("closure"):
             zero_w = jnp.zeros_like(s_w)
             d0, l0, changed0 = dirty_chunks(s_w, zero_w)
             (closure_w, _, _, _, closure_bailed, closure_sweeps,
-             closure_spent, closure_steps,
-             closure_contracted) = jax.lax.while_loop(
+             closure_spent, closure_steps, closure_contracted,
+             closure_walked) = jax.lax.while_loop(
                 c_cond, c_body,
                 (s_w, zero_w, d0, l0, changed0, zero_i, zero_i, zero_i,
-                 zero_i),
+                 zero_i, zero_i),
             )
             # The cold road: the region to repair is everything, because
             # the closure said so by its cost or because there is no
@@ -368,7 +373,7 @@ def _build_wake_fn(
                 sat = None
                 pull_on = jnp.array(False)
                 gate = base_gate
-            hits2d, took, did = contribs(
+            hits2d, took, did, iters = contribs(
                 table, carry["table_prev"], d, l, gate
             )
             hit_w = pt.pack_hits_table(hits2d, r_rows, jnp)
@@ -390,6 +395,7 @@ def _build_wake_fn(
                        walks=carry["walks"] + n_dirty,
                        steps=carry["steps"] + took,
                        contracted=carry["contracted"] + did,
+                       walked=carry["walked"] + iters,
                        st_dirty=carry["st_dirty"].at[i].set(n_dirty))
             if use_jump:
                 jump_on = jump_state[0].astype(jnp.int32)
@@ -423,7 +429,8 @@ def _build_wake_fn(
                       "l": rl0, "use_gate": jnp.array(True),
                       "changed": run0,
                       "sweep_i": zero_i, "walks": zero_i, "steps": zero_i,
-                      "contracted": zero_i, "st_dirty": zero_stats}
+                      "contracted": zero_i, "walked": zero_i,
+                      "st_dirty": zero_stats}
             if use_jump:
                 carry0.update(jump=jump_j0.astype(jnp.int32),
                               jump_state=pt.jump_state0(mode, jnp),
@@ -446,6 +453,8 @@ def _build_wake_fn(
             "kernel_steps": closure_steps + out["steps"],
             # those of them that gathered a new bit and contracted
             "kernel_contractions": closure_contracted + out["contracted"],
+            # the chunk-iterations their walks took
+            "kernel_chunk_walks": closure_walked + out["walked"],
             "kernel_steps_full": (closure_sweeps + out["sweep_i"])
             * launch_blocks,
             "dirty_chunks": out["st_dirty"],
@@ -538,6 +547,7 @@ def _host_stats(host: dict) -> dict:
         "n_sweeps": int(host["n_sweeps"]),
         "kernel_steps": int(host["kernel_steps"]),
         "kernel_contractions": int(host["kernel_contractions"]),
+        "kernel_chunk_walks": int(host["kernel_chunk_walks"]),
         "kernel_steps_full": int(host["kernel_steps_full"]),
         "dirty_chunks": host["dirty_chunks"][:k].tolist(),
         "tiles_skipped": host["tiles_skipped"][:k].tolist(),
@@ -812,7 +822,8 @@ class DecrementalTracer:
         ``n_sweeps`` (repair), ``kernel_steps`` of ``kernel_steps_full``
         (the grid steps its kernels took, of launches x blocks) and
         ``kernel_contractions`` (those of the steps that gathered a new
-        bit and paid for their contraction),
+        bit and paid for their contraction), ``kernel_chunk_walks`` (the
+        chunk-iterations the steps' walks took),
         ``jump_sweeps`` (the repair sweeps that ran the pointer jump),
         ``jump_spent`` (the ``auto`` policy's sparse chunk walks at exit;
         against :attr:`jump_price`) and, for the repair's first

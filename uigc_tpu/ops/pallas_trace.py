@@ -636,7 +636,8 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
     """The per-layout propagation sweep of the decremental wake's two
     fixpoints: returns fn(table, d, l, layout_args, gate) -> hits
     plane (t_rows, LANE) bool; ``fn.with_steps`` returns beside it the
-    grid steps the sweep's kernels took (``build_propagate``).
+    grid steps the sweep's kernels took, those of them that contracted
+    and the chunk-iterations their walks took (``build_propagate``).
 
     ``propagates`` holds one kernel per packed spec (None for xla
     tiers).  ``gate`` is the per-global-supertile dst-gate vector for
@@ -659,7 +660,7 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
         contrib = jnp.zeros((t_rows, LANE), jnp.float32)
         xla_hits2d = jnp.zeros((t_rows, LANE), bool)
         have_xla = False
-        steps = contracted = jnp.zeros((), jnp.int32)
+        steps = contracted = walks = jnp.zeros((), jnp.int32)
         pos = 0
         for spec, propagate in zip(specs, propagates):
             if spec[0] == "xla":
@@ -692,11 +693,12 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
                     gates = (gate[super_ids],)
             elif gate is not None:
                 gates = (gate,)
-            c, took, did = propagate.with_steps(
+            c, took, did, walked = propagate.with_steps(
                 d, l, *gates, bmeta1, bmeta2, tables, row_pos, emeta
             )
             steps = steps + took
             contracted = contracted + did
+            walks = walks + walked
             if compact:
                 rows = (
                     super_ids[:, None] * s_rows + sub_iota_rows[None, :]
@@ -709,7 +711,7 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
         hits2d = contrib > 0
         if have_xla:
             hits2d = hits2d | xla_hits2d
-        return hits2d, steps, contracted
+        return hits2d, steps, contracted, walks
 
     sweep.with_steps = with_steps
     return sweep
@@ -1179,8 +1181,8 @@ def build_propagate(
     packed table, one-hot segment-sum into per-supertile contributions.
     Returns ``propagate(d, l, [gate,] bmeta1, bmeta2, tables, row_pos,
     emeta) -> contributions``; ``propagate.with_steps`` returns beside
-    them the grid steps the launch took and those of them that
-    contracted.
+    them the grid steps the launch took, those of them that contracted
+    and the chunk-iterations the steps' walks took.
 
     Operands (after the scalar-prefetch ones): the (2 * r_rows, LANE) bit
     tables (``walk_tables``: the full table over its bits that are new
@@ -1269,18 +1271,21 @@ def build_propagate(
         return n_iter
 
     def active_blocks(d, gate, bmeta1, bmeta2):
-        """(act, count): the blocks with work this sweep, in block order,
-        in the first ``count`` entries of ``act`` (the rest the last
-        block).  A sort and not a prefix sum and a scatter: on the v5e the
-        scatter of 24,576 ids costs 122 us a launch, the sort 18 (PERF.md
-        section 6, PR 32)."""
+        """(act, count, walks): the blocks with work this sweep, in block
+        order, in the first ``count`` entries of ``act`` (the rest the
+        last block), and the chunk-iterations their walks will take (a
+        block without work has none).  A sort and not a prefix sum and a
+        scatter: on the v5e the scatter of 24,576 ids costs 122 us a
+        launch, the sort 18 (PERF.md section 6, PR 32)."""
         with scope("active"):
-            active = block_iters(d, gate, bmeta1, bmeta2) > 0
+            n_iter = block_iters(d, gate, bmeta1, bmeta2)
+            active = n_iter > 0
             ids = jnp.arange(n_blocks, dtype=jnp.int32)
             act = jnp.sort(jnp.where(active, ids, n_blocks))
             return (
                 jnp.minimum(act, n_blocks - 1),
                 active.sum(dtype=jnp.int32),
+                n_iter.sum(dtype=jnp.int32),
             )
 
     def kernel(*refs):
@@ -1494,19 +1499,19 @@ def build_propagate(
 
     def onto(plane, d, l, *operands):
         """(contributions, grid steps that had work, steps of them that
-        contracted) of one launch over the output buffer ``plane``, which
-        it consumes.  The grid is as long as the list of active blocks,
+        contracted, chunk-iterations the steps walked) of one launch over
+        the output buffer ``plane``, which it consumes.  The grid is as long as the list of active blocks,
         and at least one step: a launch with nothing to do writes one zero
         tile.  Tiles with no active block are never visited and keep what
         ``plane`` held; the first active block of a tile overwrites it."""
         gate = operands[0] if dst_gate else None
         bmeta1, bmeta2, tables, row_pos, emeta = operands[-5:]
-        act, count = active_blocks(d, gate, bmeta1, bmeta2)
+        act, count, walks = active_blocks(d, gate, bmeta1, bmeta2)
         out, contracted = launch(
             jnp.maximum(count, 1), d, l, *operands[:-3], act, plane, tables,
             row_pos, emeta,
         )
-        return out, count, contracted[0]
+        return out, count, contracted[0], walks
 
     def with_steps(d, l, *operands):
         """``onto`` a zero plane: an unvisited tile contributes nothing."""
