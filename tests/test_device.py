@@ -50,6 +50,7 @@ from uigc_tpu import (  # noqa: E402
     NoRefs,
 )
 from uigc_tpu.engines.crgc.arrays import audit_donation  # noqa: E402
+from uigc_tpu.ops.slotmap import PairLog  # noqa: E402
 from uigc_tpu.telemetry.device import (  # noqa: E402
     DeviceObservatory,
     ledger_families,
@@ -86,15 +87,16 @@ class _FakeGraph:
         self.edge_weight = np.zeros(64, np.int64)
         self.slot_of = {object(): i for i in range(10)}
         self.send_matrix = {1: 2, 3: 4}
-        self._pair_log = [(True, 1, 2, 0)] * 5
+        self._pair_log = PairLog([(True, 1, 2, 0)] * 5)
 
 
 def test_ledger_families_duck_typed():
     fams = ledger_families(_FakeGraph())
     assert fams["node_features"]["host"] == 1024 * (1 + 8)
     assert fams["edges"]["host"] == 64 * (4 + 4 + 8)
-    # maps are entry-count estimates: 10 slots + 2 matrix + 5 log rows
-    assert fams["maps"]["host"] == (10 + 2) * 96 + 5 * 72
+    # maps are entry-count estimates, 10 slots + 2 matrix, and the pair
+    # log's own bytes: 5 rows of four int64
+    assert fams["maps"]["host"] == (10 + 2) * 96 + 5 * 32
     assert fams["node_features"]["device"] == 0
     # an alien object contributes nothing and never raises
     assert isinstance(ledger_families(object()), dict)

@@ -622,7 +622,8 @@ def test_engine_folds_foreign_rows_beside_running_actors_with_uigcsan_clean():
         # the wakes' records carry the path's counters
         records = kit.system.engine.wake_profiler.to_json()["recent"]
         assert all(
-            {"fold_rows", "uids_interned", "upload_bytes", "kill_uids"} <= r.keys() for r in records
+            {"fold_rows", "uids_interned", "upload_bytes", "kill_uids", "layout_rows",
+             "layout_rebuilt"} <= r.keys() for r in records
         )
         assert sum(r["uids_interned"] for r in records) >= n
         assert sum(r["kill_uids"] for r in records) == 20
@@ -678,17 +679,27 @@ def test_decremental_wake_record_counts_the_upload():
 
     graph, plane, _, sink = new_graph(use_device=True)
     profiler = WakeProfiler("test")
-    for wake_no in range(2):
+    logged = []  # the pair log's rows when each wake's fold is done
+    for wake_no in range(4):
         wake = graph.profile_wake = profiler.begin_wake()
-        fold_foreign(graph, plane, _foreign_tree_rows(9) if not wake_no else [
-            row(0, root=True, updated=[(3, 1)])])
+        if wake_no < 2:
+            fold_foreign(graph, plane, _foreign_tree_rows(9) if not wake_no else [
+                row(0, root=True, updated=[(3, 1)])])
+        logged.append(0 if graph._pair_log is None else len(graph._pair_log))
         graph.trace(should_kill=True)
         graph.profile_wake = None
         wake.end(entries=0, garbage=0)
-    first, second = profiler.to_json()["recent"]
+    first, second, third, fourth = profiler.to_json()["recent"]
     assert first["fold_rows"] == 10 and first["uids_interned"] == 9
     assert second["fold_rows"] == 1 and second["uids_interned"] == 0
     assert second["kill_uids"] == 1 and second["freed"] == 1
+    # ``layout_rows`` beside it: the pair transitions the layout phase
+    # folded, which are what the fold logged since the wake before (and,
+    # in the third wake, what the second's sweep found hanging on the
+    # dead); the first wake packs the layout and reads no log
+    assert [r["layout_rebuilt"] for r in (first, second, third, fourth)] == [1, 0, 0, 0]
+    assert logged[0] == 0 and logged[1] == 1 and logged[2] > 0 and logged[3] == 0
+    assert [r["layout_rows"] for r in (first, second, third, fourth)] == logged
     assert first["upload_bytes"] == graph.flags.nbytes + graph.recv_count.nbytes
     assert second["upload_bytes"] == arrays._patch_pad(2) * (4 + 1 + 4)
     assert sink.freed.tolist() == [3]

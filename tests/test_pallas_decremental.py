@@ -730,3 +730,62 @@ def test_kernel_steps_count_the_blocks_with_work(mode):
     assert s["closure_sweeps"] > 0
     assert s["kernel_steps_full"] == (s["closure_sweeps"] + s["n_sweeps"]) * n_blocks
     assert 0 < s["kernel_steps"] <= s["kernel_steps_full"]
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_the_log_as_tuples_and_as_columns_is_one_wake(seed):
+    """Two tracers over one graph, one handed each wake's log as a list
+    of tuples and one as a ``PairLog``: the same verdict words, the same
+    ``wake_stats()`` counter for counter over three churn wakes, and the
+    wake program of the first serves the second (no new cache key)."""
+    import jax
+    from uigc_tpu.ops.slotmap import PairLog
+
+    rng = np.random.default_rng(seed)
+    n = 1 << 11
+    g = OracleGraph(rng, n, n_edges=4 * n)
+    kw = dict(freeze_threshold=64, max_frozen=2)
+    as_tuples, as_columns = pd.DecrementalTracer(n, **kw), pd.DecrementalTracer(n, **kw)
+    src, dst, w, sup = g.arrays()
+    for tracer in (as_tuples, as_columns):
+        tracer.rebuild(src, dst, w, sup)
+
+    class Tee:
+        """What ``_rand_schedule`` takes for a tracer: both, each in its form."""
+
+        def apply_log(self, log):
+            as_tuples.apply_log(log)
+            columns = PairLog()
+            ins, s, d, kind = (np.array(c) for c in zip(*log))
+            for lo in range(0, len(log), 7):  # the fold's batches, as arrays
+                for op in (False, True):
+                    pick = np.flatnonzero(ins[lo:lo + 7] == op) + lo
+                    if pick.size and (kind[pick] == kind[pick[0]]).all():
+                        columns.extend(op, s[pick], d[pick].astype(np.int32), int(kind[pick[0]]))
+                    else:
+                        for i in pick.tolist():
+                            columns.append(log[i])
+            assert sorted(zip(*[c.tolist() for c in columns.columns()])) == \
+                sorted((int(a), b, c, e) for a, b, c, e in log)
+            as_columns.apply_log(columns)
+
+    def wake(tracer):
+        mark_w = tracer.wake_device(jax.device_put(g.flags), jax.device_put(g.recv))
+        words, marked = tracer.verdict_words(mark_w)
+        return words.copy(), marked
+
+    assert np.array_equal(wake(as_tuples)[0], wake(as_columns)[0])
+    for _ in range(3):
+        _rand_schedule(rng, g, Tee(), k=40)
+        words_t, marked_t = wake(as_tuples)
+        keys = set(pd._fn_cache)
+        words_c, marked_c = wake(as_columns)
+        assert set(pd._fn_cache) == keys  # the same tiers, so the same program
+        assert np.array_equal(words_t, words_c) and marked_t == marked_c
+        assert np.array_equal(as_columns.unpack_marks(as_columns._mark_w), g.oracle_marks())
+    stats_t, stats_c = as_tuples.wake_stats(), as_columns.wake_stats()
+    assert len(stats_t) == 4 and stats_t == stats_c
+    for tracer in (as_tuples, as_columns):
+        assert tracer.layout.stats["anomalies"] == 0
+    assert as_tuples.layout.stats["log_rows"] == as_columns.layout.stats["log_rows"] > 0
+    assert as_tuples.layout.stats["log_keys"] == as_columns.layout.stats["log_keys"]

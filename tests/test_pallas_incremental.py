@@ -286,3 +286,226 @@ def test_small_scatters_share_one_padded_length():
     assert np.array_equal(np.asarray(layout.jump_device()), layout.jump_parent)
     assert (layout.jump_parent == n).all()
     assert layout._jump_scatter._cache_size() == 1  # five syncs, one program
+
+
+# --------------------------------------------------------------------- #
+# apply_log: the pair-transition log as columns against a sequential
+# insert() / remove() replay
+# --------------------------------------------------------------------- #
+
+LOG_SHAPES = [
+    "churn", "duplicate_insert", "remove_absent", "insert_then_remove",
+    "remove_then_insert", "frozen_hits", "ids_past_n", "empty", "one_row",
+]
+#: shapes that insert and remove ONE (src, dst) pair in a batch: there
+#: the batched jump-parent fold is, as documented, more conservative
+#: than the replay, so the pointers are held to soundness, not equality
+_IN_BATCH = ("insert_then_remove", "remove_then_insert")
+
+
+def _twin_layouts(seed, n=2500, count=3):
+    """``count`` layouts with one history: a packed base, a frozen tier
+    and a live tier, their device mirrors up (so every write queues)."""
+    rng = np.random.default_rng(seed)
+    src = rng.integers(0, n, 2 * n)
+    dst = rng.integers(0, n, 2 * n)
+    keep = np.unique((src << 32) | dst, return_index=True)[1]
+    src, dst = src[keep].astype(np.int32), dst[keep].astype(np.int32)
+    sup = np.full(n, -1, np.int32)
+    kids = rng.random(n) < 0.3
+    sup[kids] = rng.integers(0, n, int(kids.sum()))
+    base = set(zip(src.tolist(), dst.tolist()))
+    fresh = []
+    while len(fresh) < 120:
+        pair = (int(rng.integers(0, n)), int(rng.integers(0, n)))
+        if pair not in base:
+            base.add(pair)
+            fresh.append(pair)
+    frozen, pending, spare = fresh[:40], fresh[40:60], fresh[60:]
+    twins = []
+    for _ in range(count):
+        layout = pinc.IncrementalPallasLayout(
+            n, s_rows=8, interpret=True, freeze_threshold=24
+        )
+        layout.rebuild(src, dst, np.ones(src.size, np.int64), sup)
+        for s, d in frozen:
+            layout.insert(s, d, pinc.EDGE)
+        layout.prepare_device_wake()  # freezes the 40, uploads the mirrors
+        for s, d in pending:
+            layout.insert(s, d, pinc.EDGE)
+        assert layout.stats["freezes"] == 1 and len(layout.pending) == 20
+        twins.append(layout)
+    homes = {
+        "base": list(zip(src.tolist(), dst.tolist())),
+        "frozen": frozen, "pending": pending, "spare": spare,
+    }
+    return twins, homes
+
+
+def _shaped_log(shape, rng, homes, n):
+    """Per key its ops in order, then all keys' ops shuffled together
+    with each key's order kept (a pair's transitions alternate)."""
+    def some(home, k):
+        at = rng.choice(len(homes[home]), size=k, replace=False)
+        return [homes[home][i] for i in at]
+
+    live = some("base", 30) + some("frozen", 10) + some("pending", 8)
+    spare = homes["spare"]
+    if shape == "churn":
+        gone = {d for _, d in live}
+        ops = [(p, [False]) for p in live]
+        ops += [(p, [True]) for p in spare[:30] if p[1] not in gone]
+        ops += [((s, d, pinc.SUP), [True]) for s, d in spare[30:40] if d not in gone]
+    elif shape == "duplicate_insert":
+        ops = [(p, [True]) for p in live] + [(spare[0], [True])]
+    elif shape == "remove_absent":
+        ops = [(p, [False]) for p in spare[:20]] + [(live[0], [False])]
+    elif shape == "insert_then_remove":
+        ops = [(p, [True, False]) for p in spare[:20] + live]
+    elif shape == "remove_then_insert":
+        ops = [(p, [False, True]) for p in live + spare[:5]]
+        ops += [(spare[6], [False, True, False]), (live[0][::-1], [True, False, True])]
+    elif shape == "frozen_hits":
+        ops = [(p, [False]) for p in homes["frozen"][:25]]
+        ops += [(p, [True]) for p in homes["frozen"][25:30]]
+    elif shape == "ids_past_n":
+        ops = [((n + 7, 3), [True]), ((4, n + 9), [True]), ((n + 2, n + 3), [True]),
+               ((n + 11, 5), [False]), (live[0], [False])]
+    elif shape == "empty":
+        ops = []
+    elif shape == "one_row":
+        ops = [(live[0], [False])] if rng.random() < 0.5 else [(spare[0], [True])]
+    rows = []
+    for pair, seq in ops:
+        kind = pair[2] if len(pair) == 3 else pinc.EDGE
+        when = np.sort(rng.random(len(seq)))
+        rows += [(t, (ins, pair[0], pair[1], kind)) for t, ins in zip(when, seq)]
+    rows.sort(key=lambda r: r[0])
+    return [row for _, row in rows]
+
+
+def _layout_state(layout):
+    preps = [layout.base] + layout.frozen
+    return {
+        "pending": set(layout.pending),
+        "pending_len": np.fromiter(layout.pending, np.int64, len(layout.pending)).size,
+        "frozen_slot": dict(layout.frozen_slot),
+        "base_len": len(layout.base_slot),
+        "base_dead": layout.base_slot._dead.tolist(),
+        "base_extra": dict(layout.base_slot._extra),
+        "masked_base": layout.masked_base,
+        "masked_frozen": layout.masked_frozen,
+        "anomalies": layout.stats["anomalies"],
+        "row_pos": [p["row_pos"].tolist() for p in preps],
+        "emeta": [p["emeta"].tolist() for p in preps],
+        "dev_writes": {
+            tok: sorted(np.concatenate(w).tolist() if w else [])
+            for tok, w in layout._dev_writes.items()
+        },
+    }
+
+
+def _jump_state(layout):
+    return layout.jump_parent.tolist(), set(layout._jump_writes.items())
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("shape", LOG_SHAPES)
+def test_apply_log_matches_a_sequential_replay(shape, seed):
+    """One batched ``apply_log`` leaves the layout as a replay of the
+    same log through ``insert()`` / ``remove()`` does, anomaly for
+    anomaly and queued write for queued write; and the log as a list of
+    tuples and as a ``PairLog`` are one road."""
+    from uigc_tpu.ops.slotmap import PairLog, unpack_keys
+
+    n = 2500
+    (replayed, from_tuples, from_columns), homes = _twin_layouts(seed)
+    log = _shaped_log(shape, np.random.default_rng(100 + seed), homes, n)
+    assert (shape == "empty") == (not log)
+
+    for ins, s, d, kind in log:
+        (replayed.insert if ins else replayed.remove)(s, d, kind)
+    from_tuples.apply_log(log)
+    columns = PairLog()
+    half = len(log) // 2
+    for row in log[:half]:  # the scalar road, then the batched one
+        columns.append(row)
+    for ins, s, d, kind in log[half:]:
+        columns.extend(ins, np.array([s]), np.array([d], np.int32), kind)
+    assert len(columns) == len(log)
+    from_columns.apply_log(columns)
+
+    want = _layout_state(replayed)
+    assert _layout_state(from_tuples) == want
+    assert _layout_state(from_columns) == want
+    assert _jump_state(from_tuples) == _jump_state(from_columns)
+    if shape not in _IN_BATCH:
+        assert _jump_state(from_columns) == _jump_state(replayed)
+    # a pointer is always a current live pair's source (or the sentinel)
+    live = set()
+    for layout_keys in (from_columns.pending, from_columns.frozen_slot):
+        live.update(zip(*[a.tolist() for a in unpack_keys(np.fromiter(layout_keys, np.int64))]))
+    slots = from_columns.base_slot
+    live.update(zip(*[a.tolist() for a in unpack_keys(slots._keys[~slots._dead])]))
+    jump = from_columns.jump_parent
+    for d in np.flatnonzero(jump[:n] != n).tolist():
+        assert (int(jump[d]), d) in live
+    # the work counters: rows in, distinct keys after the fold
+    for layout in (from_tuples, from_columns):
+        assert layout.stats["log_rows"] == len(log)
+        assert layout.stats["log_keys"] == len({row[1:] for row in log})
+        assert layout.stats["base_lookups"] <= 2 * layout.stats["log_keys"]
+    assert replayed.stats["log_rows"] == 0
+
+
+def test_pair_log_keeps_rows_in_order_across_both_roads():
+    """Scalar rows are staged and join the columns, in order, at the
+    next batch or read; growth, ``clear`` and the door for a list."""
+    from uigc_tpu.ops.slotmap import PairLog
+
+    log = PairLog()
+    want = []
+    rng = np.random.default_rng(5)
+    for round_ in range(40):
+        for _ in range(int(rng.integers(0, 4))):
+            row = (bool(rng.random() < 0.5), int(rng.integers(0, 1 << 31)),
+                   int(rng.integers(0, 1 << 31)), int(rng.integers(0, 2)))
+            log.append(row)
+            want.append(row)
+        k = int(rng.integers(0, 200))
+        srcs = rng.integers(0, 1 << 31, k)
+        dsts = rng.integers(0, 1 << 31, k).astype(np.int32)
+        log.extend(round_ % 2 == 0, srcs, dsts, round_ % 2)
+        want += [(round_ % 2 == 0, int(s), int(d), round_ % 2) for s, d in zip(srcs, dsts)]
+        assert len(log) == len(want)
+    assert len(log) > 1024  # outgrew its first room
+    ins, src, dst, kind = log.columns()
+    assert all(c.dtype == np.int64 for c in (ins, src, dst, kind))
+    assert list(zip(ins.astype(bool).tolist(), src.tolist(), dst.tolist(), kind.tolist())) == want
+    assert log.nbytes == 32 * len(want)
+    assert PairLog.of(log) is log
+    again = PairLog.of(want)
+    assert all(np.array_equal(a, b) for a, b in zip(again.columns(), log.columns()))
+    log.clear()
+    assert len(log) == 0 and log.nbytes == 0 and log.columns()[0].size == 0
+    log.append((True, 1, 2, 0))  # the staging list survived the clear
+    assert [c.tolist() for c in log.columns()] == [[1], [1], [2], [0]]
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_fold_log_gives_each_keys_first_and_last_op(seed):
+    """The sorted fold against the two dicts it replaced."""
+    from uigc_tpu.ops.slotmap import PairLog, fold_log, pack_key
+
+    rng = np.random.default_rng(seed)
+    log = [(bool(rng.random() < 0.5), int(rng.integers(0, 12)), int(rng.integers(0, 12)),
+            int(rng.integers(0, 2))) for _ in range(400)]
+    first, last = {}, {}
+    for ins, s, d, kind in log:
+        first.setdefault(pack_key(s, d, kind), ins)
+        last[pack_key(s, d, kind)] = ins
+    removes, cond_removes, inserts, n_keys = fold_log(*PairLog.of(log).columns())
+    assert removes.tolist() == sorted(k for k, ins in first.items() if not ins)
+    assert cond_removes.tolist() == sorted(k for k, ins in first.items() if ins and not last[k])
+    assert inserts.tolist() == sorted(k for k, ins in last.items() if ins)
+    assert n_keys == len(first)

@@ -397,3 +397,51 @@ def test_the_pipelined_road_patches_the_same_copies(first):
         assert np.array_equal(piped.flags, twin.flags)
     assert wake(piped)[3] == wake(twin)[3] == arrays._patch_pad(31) * SLOT_BYTES
     assert_copies_are_the_hosts(piped)
+
+
+# --------------------------------------------------------------------- #
+# (f) the pair log beside the node log: its rows on the wake's record,
+# and past its cap the layout is packed anew
+# --------------------------------------------------------------------- #
+
+
+def test_the_wakes_record_counts_the_layouts_rows_and_a_log_past_its_cap_rebuilds():
+    from uigc_tpu.ops.slotmap import PairLog
+
+    g = new_graph(2048)
+    star(g, 1500)
+    assert g._pair_log is None  # no consumer yet: nothing is logged
+    wake(g)
+    fields = g.profile_wake.fields
+    # the first wake packs the layout from the graph and starts the log
+    assert (fields["layout_rows"], fields["layout_rebuilt"]) == (0, 1)
+    assert isinstance(g._pair_log, PairLog) and len(g._pair_log) == 0
+    release(g, range(10, 20))
+    assert len(g._pair_log) == 10  # ten references went dead
+    assert wake(g)[2] == 10
+    assert (fields["layout_rows"], fields["layout_rebuilt"]) == (10, 0)
+    swept = len(g._pair_log)  # what hung on the dead, logged by the sweep
+    wake(g)
+    assert (fields["layout_rows"], fields["layout_rebuilt"]) == (swept, 0)
+    wake(g)
+    assert (fields["layout_rows"], fields["layout_rebuilt"]) == (0, 0)
+    assert g._dec.layout.stats["log_rows"] == 10 + swept
+    assert g._dec.layout.stats["anomalies"] == 0
+
+    # past the cap, on the batched road and on the scalar one, the log
+    # collapses to the sentinel and the next wake packs from the graph
+    for overflow in (lambda: release(g, range(100, 130)),
+                     lambda: [g._log_pair(False, 0, t, 0) for t in range(40)]):
+        g._log_cap = 16
+        packs = g._dec.layout.stats["rebuilds"]
+        overflow()
+        assert g._pair_log is None
+        g._log_cap = 1 << 20
+        garbage = oracle_garbage(g)
+        assert wake(g)[2] == garbage.size
+        assert (fields["layout_rows"], fields["layout_rebuilt"]) == (0, 1)
+        assert g._dec.layout.stats["rebuilds"] == packs + 1
+        assert isinstance(g._pair_log, PairLog)
+    assert ArrayShadowGraph(
+        CrgcContext(delta_graph_size=64, entry_field_size=E), FakeSystem.address
+    )._log_cap == 1 << 20

@@ -36,6 +36,7 @@ from ...ops import pallas_incremental as pallas_incremental_kinds
 from ...ops import trace as trace_ops
 from ...ops.edgeindex import EndpointIndex
 from ...ops.i64map import I64Map, IntStack
+from ...ops.slotmap import PairLog
 from ...utils import events
 from ...utils.validation import require
 from . import refob as refob_info
@@ -343,8 +344,10 @@ class ArrayShadowGraph:
         #: consumer does a full rebuild (which re-enables the log).  Off
         #: by default so a backend that never consumes it (the host
         #: array) pays one None check per mutation instead of
-        #: accumulating up to ``_log_cap`` dead tuples.
-        self._pair_log: Optional[List[tuple]] = None
+        #: accumulating up to ``_log_cap`` dead rows.  Four int64
+        #: columns (ops/slotmap.PairLog): the fold's batches go in as
+        #: the arrays they are, and the layout folds them as arrays.
+        self._pair_log: Optional[PairLog] = None
         self._log_cap = 1 << 20
         #: slots whose flags/recv changed since last consumed; enabled
         #: (non-None) by backends that keep node features on a device:
@@ -455,7 +458,9 @@ class ArrayShadowGraph:
         log = self._pair_log
         if log is None:
             return
-        if len(log) >= self._log_cap:
+        # len(log), spelt out: a Python-level __len__ costs a scalar
+        # mutation 110 ns, this 30
+        if log.in_columns + len(log.staged) >= self._log_cap:
             self._pair_log = None
             return
         log.append((insert, src, dst, kind))
@@ -474,7 +479,7 @@ class ArrayShadowGraph:
         if len(log) + k > self._log_cap:
             self._pair_log = None
             return
-        log.extend(zip([insert] * k, srcs.tolist(), dsts.tolist(), [kind] * k))
+        log.extend(insert, srcs, dsts, kind)
 
     def _update_edge(self, owner: int, target: int, delta: int) -> None:
         """Zero-count edges are deleted (reference: ShadowGraph.java:64-73)."""
@@ -1364,27 +1369,29 @@ class ArrayShadowGraph:
         from ...ops import pallas_decremental
 
         dec = self._dec
-        if dec is None or self._pair_log is None:
+        log = self._pair_log
+        rows = 0 if log is None else len(log)
+        rebuilt = dec is None or log is None
+        if rebuilt:
             if dec is None or dec.n != self.capacity:
                 dec = pallas_decremental.DecrementalTracer(
                     self.capacity,
                     mode=self.trace_mode,
                     pull_density=self.pull_density,
                 )
+            self._pair_log = PairLog()
+        elif rows:
+            dec.apply_log(log)
+            log.clear()
+            rebuilt = dec.layout.needs_repack
+        if rebuilt:
             dec.rebuild(
                 self.edge_src, self.edge_dst, self.edge_weight, self.supervisor
             )
-            self._pair_log = []
-        elif self._pair_log:
-            dec.apply_log(self._pair_log)
-            self._pair_log.clear()
-            if dec.layout.needs_repack:
-                dec.rebuild(
-                    self.edge_src,
-                    self.edge_dst,
-                    self.edge_weight,
-                    self.supervisor,
-                )
+        if self.profile_wake is not None:
+            # the layout phase's work: the rows it folded, and whether
+            # it packed the graph anew (a rebuild's log was never read)
+            self.profile_wake.note(layout_rows=rows, layout_rebuilt=int(rebuilt))
         self._dec = dec
         return dec
 

@@ -473,7 +473,7 @@ class Bookkeeper(RawBehavior):
             # what the backend counts where it has something to count
             # (arrays.py: the packed fold, the upload, the sweep)
             wake.note(fold_rows=0, uids_interned=0, upload_bytes=0, kill_uids=0,
-                      sweep_edge_slots=0)
+                      sweep_edge_slots=0, layout_rows=0, layout_rebuilt=0)
         queue = engine.queue
         pool = engine.entry_pool
         count = 0

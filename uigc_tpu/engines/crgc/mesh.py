@@ -38,7 +38,9 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ...ops import trace as trace_ops
-from ...ops.slotmap import PackedSlotMap, fold_log, pack_keys, unpack_keys
+from ...ops.slotmap import (
+    PackedSlotMap, PairLog, fold_log, pack_keys, unpack_keys,
+)
 from ...parallel import sharded_trace
 from ...utils import events
 from .arrays import ArrayShadowGraph, _NodeLog, _readback, audit_donation
@@ -321,7 +323,7 @@ class MeshShadowGraph(ArrayShadowGraph):
         # against what the device already holds.
         self._recv_synced = recv.copy()
 
-        self._pair_log = []
+        self._pair_log = PairLog()
         self._node_log = _NodeLog()
         self._wake_state = None
         self._pending_del_dst.clear()
@@ -342,6 +344,7 @@ class MeshShadowGraph(ArrayShadowGraph):
         Batched like IncrementalPallasLayout.apply_log (the net-effect
         argument and anomaly accounting live in slotmap.fold_log): slot
         lookups are one vectorized binary search per batch."""
+        ins, psrc, pdst, kind = self._pair_log.columns()
         if self._use_jump:
             # Batched jump-parent maintenance — the same
             # pt.fold_jump_log rules as the single-device layout plane
@@ -352,23 +355,18 @@ class MeshShadowGraph(ArrayShadowGraph):
             from ...ops import pallas_trace as pt
 
             pt.fold_jump_log(
-                self._jump_parent, self._pair_log, self._n_pad,
+                self._jump_parent, ins, psrc, pdst, self._n_pad,
                 self._jump_writes,
             )
-        removes, cond_removes, inserts = fold_log(self._pair_log)
+        removes, cond_removes, inserts, _ = fold_log(ins, psrc, pdst, kind)
         if self.decremental:
             # Suspect bookkeeping for the decremental wake: removal
             # destinations must re-derive; insert destinations must see
             # their new pair once.  Over-approximation is sound.
-            rem = removes + cond_removes
-            if rem:
-                _, d = unpack_keys(np.fromiter(rem, np.int64, len(rem)))
-                self._pending_del_dst.update(d.tolist())
-            if inserts:
-                _, d = unpack_keys(
-                    np.fromiter(inserts, np.int64, len(inserts))
-                )
-                self._pending_fresh_dst.update(d.tolist())
+            _, d = unpack_keys(np.concatenate([removes, cond_removes]))
+            self._pending_del_dst.update(d.tolist())
+            _, d = unpack_keys(inserts)
+            self._pending_fresh_dst.update(d.tolist())
         writes: Dict[Tuple[int, int], Tuple[int, int]] = {}
         stacked = self._stacked
 
@@ -382,8 +380,7 @@ class MeshShadowGraph(ArrayShadowGraph):
             stacked["emeta"][shard, ri, col] = 0
             self._mask_writes.append((shard, ri, col))
 
-        def free_slot_batch(keys: list, found_is_anomaly: bool) -> None:
-            karr = np.fromiter(keys, np.int64, len(keys))
+        def free_slot_batch(karr: np.ndarray, found_is_anomaly: bool) -> None:
             bucket_vals = self._pb_slot.pop_batch(karr)
             missing = bucket_vals < 0
             base_vals = np.full(karr.size, -1, dtype=np.int64)
@@ -405,21 +402,21 @@ class MeshShadowGraph(ArrayShadowGraph):
                 elif not found_is_anomaly:
                     self.stats["anomalies"] += 1
 
-        if removes:
+        if removes.size:
             free_slot_batch(removes, found_is_anomaly=False)
-        if cond_removes:
+        if cond_removes.size:
             # insert-first/remove-last: net no-op unless the key was
             # already live (anomalous duplicate insert + real remove).
             free_slot_batch(cond_removes, found_is_anomaly=True)
 
-        if inserts:
-            karr = np.fromiter(inserts, np.int64, len(inserts))
-            present = (self._pb_slot.get_batch(karr) >= 0) | (
-                self._base_slot.get_batch(karr) >= 0
+        if inserts.size:
+            present = (self._pb_slot.get_batch(inserts) >= 0) | (
+                self._base_slot.get_batch(inserts) >= 0
             )
-            srcs, dsts = unpack_keys(karr)
+            srcs, dsts = unpack_keys(inserts)
             for key, src, dst, dup in zip(
-                inserts, srcs.tolist(), dsts.tolist(), present.tolist()
+                inserts.tolist(), srcs.tolist(), dsts.tolist(),
+                present.tolist(),
             ):
                 if dup:
                     self.stats["anomalies"] += 1
@@ -438,7 +435,7 @@ class MeshShadowGraph(ArrayShadowGraph):
                 local = dst - shard * self._shard_size
                 self._pb_dst[shard, colm] = local
                 writes[(shard, colm)] = (src, local)
-        self._pair_log = []
+        self._pair_log.clear()
         return list(writes.items())
 
     def _jit(self, name, builder):

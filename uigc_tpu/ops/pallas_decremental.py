@@ -72,7 +72,7 @@ from __future__ import annotations
 
 import weakref
 from collections import deque
-from typing import Dict, List, Optional, Set
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -81,6 +81,7 @@ from . import trace as trace_ops
 from ..utils import events
 from ..utils.validation import require
 from .pallas_incremental import IncrementalPallasLayout
+from .slotmap import PairLog
 
 _fn_cache: Dict[tuple, object] = {}
 
@@ -682,8 +683,10 @@ class DecrementalTracer:
         #: chunk walks of the last derivation from nothing (device
         #: scalar): what the next wakes' closure price is a share of
         self._walks = None
-        self._pending_del_dst: Set[int] = set()
-        self._pending_fresh_dst: Set[int] = set()
+        #: the suspects of the next wake, as the id arrays each mutation
+        #: left (duplicates and all: ``_id_words`` ORs them into words)
+        self._pending_del_dst: List[np.ndarray] = []
+        self._pending_fresh_dst: List[np.ndarray] = []
         self._unpack = None
         self._zeros = None
         self._wake_fn = None
@@ -717,31 +720,39 @@ class DecrementalTracer:
 
     def insert(self, src: int, dst: int, kind: int) -> None:
         if dst < self.n:
-            self._pending_fresh_dst.add(int(dst))
+            self._pending_fresh_dst.append(np.array([dst], np.int64))
         self.layout.insert(src, dst, kind)
 
     def remove(self, src: int, dst: int, kind: int) -> None:
         if dst < self.n:
-            self._pending_del_dst.add(int(dst))
+            self._pending_del_dst.append(np.array([dst], np.int64))
         self.layout.remove(src, dst, kind)
 
-    def apply_log(self, log: List[tuple]) -> None:
-        for ins, _src, dst, _kind in log:
-            # Over-approximation is sound: a removal that nets out (or
-            # hits a never-propagated pending pair) adds a suspect whose
-            # repair is a no-op; an insert dst only forces one full
-            # re-derivation of its supertile.
-            if dst < self.n:
-                (self._pending_fresh_dst if ins else self._pending_del_dst).add(
-                    int(dst)
-                )
+    def apply_log(self, log) -> None:
+        """Replay a pair-transition log, a ``slotmap.PairLog`` or any
+        sequence of ``(insert?, src, dst, kind)`` tuples (turned into
+        columns here, once), into the layout, and take its
+        destinations as the next wake's suspects."""
+        log = PairLog.of(log)
+        ins, _src, dst, _kind = log.columns()
+        # Over-approximation is sound: a removal that nets out (or
+        # hits a never-propagated pending pair) adds a suspect whose
+        # repair is a no-op; an insert dst only forces one full
+        # re-derivation of its supertile.
+        ins = ins != 0
+        inside = dst < self.n
+        deleted, fresh = dst[inside & ~ins], dst[inside & ins]
+        if deleted.size:
+            self._pending_del_dst.append(deleted)
+        if fresh.size:
+            self._pending_fresh_dst.append(fresh)
         self.layout.apply_log(log)
 
     # -- the wake ------------------------------------------------------ #
 
-    def _id_words(self, id_set: Set[int], r_rows: int):
-        # Scatter an id set into a packed word table (device).  The set
-        # is NOT drained here: a wake whose dispatch raises (compile
+    def _id_words(self, id_chunks: List[np.ndarray], r_rows: int):
+        # Scatter id arrays into a packed word table (device).  The
+        # list is NOT drained here: a wake whose dispatch raises (compile
         # error, immediate transport error) keeps its suspects for the
         # retry; wake_device clears them only after dispatch succeeds.
         # An async-poisoned result (error surfacing at readback) loses
@@ -749,13 +760,13 @@ class DecrementalTracer:
         # invalidate(), after which suspects are irrelevant.
         import jax
 
-        if not id_set:
+        if not id_chunks:
             if self._zeros is None or self._zeros.shape[0] != r_rows:
                 self._zeros = jax.device_put(
                     np.zeros((r_rows, pt.LANE), np.int32)
                 )
             return self._zeros
-        ids = np.fromiter(id_set, np.int64, len(id_set))
+        ids = np.concatenate(id_chunks)
         words = np.zeros(r_rows * pt.LANE, dtype=np.uint32)
         np.bitwise_or.at(
             words, ids >> 5, np.uint32(1) << (ids & 31).astype(np.uint32)

@@ -66,6 +66,7 @@ only positive-weight edges propagate.
 from __future__ import annotations
 
 import math
+from itertools import repeat
 from typing import Dict
 
 import numpy as np
@@ -221,11 +222,12 @@ def jump_parents(psrc, pdst, n: int) -> np.ndarray:
     return j
 
 
-def fold_jump_log(jump_parent, log, n: int, writes=None) -> None:
-    """Vectorized jump-parent maintenance for one pair-transition batch
-    ``[(insert?, src, dst, kind), ...]`` — the batched form of the
-    min-fold-on-insert / invalidate-on-remove rules (``jump_parents``),
-    shared by the single-device and mesh layout planes.
+def fold_jump_log(jump_parent, ins, src, dst, n: int, writes=None) -> None:
+    """Vectorized jump-parent maintenance for one pair-transition batch,
+    given as the log's columns (``slotmap.PairLog.columns``) — the
+    batched form of the min-fold-on-insert / invalidate-on-remove rules
+    (``jump_parents``), shared by the single-device and mesh layout
+    planes.
 
     Order-insensitive and conservative: pointers built from any pair
     removed in the batch are invalidated (even when an insert earlier
@@ -240,41 +242,28 @@ def fold_jump_log(jump_parent, log, n: int, writes=None) -> None:
     Mutates ``jump_parent`` in place; when ``writes`` is a dict the
     changed entries are recorded there too (the device-mirror scatter
     queue), O(changed) not O(batch)."""
-    if not log:
-        return
-    arr = np.asarray(log, dtype=np.int64).reshape(len(log), -1)
-    ins = arr[:, 0] != 0
-    src, dst = arr[:, 1], arr[:, 2]
+    ins = ins != 0
     ok = (src >= 0) & (src < n) & (dst >= 0) & (dst < n)
     rs, rd = src[~ins & ok], dst[~ins & ok]
     if rd.size:
-        hit = jump_parent[rd] == rs
-        hrd = rd[hit]
+        hrd = rd[jump_parent[rd] == rs]
         if hrd.size:
             jump_parent[hrd] = n
             if writes is not None:
-                for d in hrd.tolist():
-                    writes[d] = n
+                writes.update(zip(hrd.tolist(), repeat(n)))
     isrc, idst = src[ins & ok], dst[ins & ok]
     if isrc.size and rd.size:
-        removed = set(zip(rs.tolist(), rd.tolist()))
-        keep = np.fromiter(
-            ((s, d) not in removed
-             for s, d in zip(isrc.tolist(), idst.tolist())),
-            bool, isrc.size,
-        )
+        keep = ~np.isin((isrc << 32) | idst, (rs << 32) | rd)
         isrc, idst = isrc[keep], idst[keep]
     if isrc.size:
-        before = jump_parent[idst].copy()
+        before = jump_parent[idst]
         np.minimum.at(
             jump_parent, idst, isrc.astype(jump_parent.dtype)
         )
         if writes is not None:
             after = jump_parent[idst]
             changed = after < before
-            for d, v in zip(idst[changed].tolist(),
-                            after[changed].tolist()):
-                writes[d] = v
+            writes.update(zip(idst[changed].tolist(), after[changed].tolist()))
 
 
 def jump_parents_from_graph(

@@ -9,9 +9,9 @@ stall measured in BENCH_PACK_r02 was that dict's construction.
 
 This map instead stores the bulk mapping as two sorted int64 numpy arrays
 (16 bytes per pair) built vectorized at rebuild time; point lookups are a
-binary search.  Mutations after the rebuild go through small Python
-overlays (an insert dict and a tombstone set) whose size is bounded by
-churn since the rebuild, which the layouts already bound by repacking.
+binary search.  Mutations after the rebuild go through overlays: a small
+Python insert dict, whose size is bounded by churn since the rebuild
+(the layouts bound that by repacking), and a tombstone byte per bulk key.
 
 Keys pack (src, dst, kind) into one int64: src in bits 32..62, dst in
 bits 1..31, kind in bit 0 — node ids must stay below 2^31, which the
@@ -21,6 +21,7 @@ caller packs into an int64.
 
 from __future__ import annotations
 
+from itertools import chain, repeat
 from typing import Optional
 
 import numpy as np
@@ -45,13 +46,96 @@ def unpack_keys(karr: np.ndarray):
     return karr >> 32, (karr >> 1) & 0x7FFFFFFF
 
 
-def fold_log(log):
-    """Fold an alternating pair-transition log [(insert?, src, dst, kind),
-    ...] into its net effect per packed key.
+class PairLog:
+    """A pair-transition log ``(insert?, src, dst, kind)`` as four int64
+    columns, from the graph layer that writes it to the layout maps that
+    fold it: no step between makes a Python object per pair.
+
+    ``extend`` takes a batch's arrays as they are.  ``append`` takes one
+    row as a 4-tuple and IS the ``list.append`` of ``staged`` (a scalar
+    mutation pays what it paid when the log was a list); staged rows
+    join the columns, in order, at the next ``extend`` or read.  A
+    writer that bounds the log per row reads ``in_columns +
+    len(staged)``, which is ``len()`` without a Python-level call."""
+
+    __slots__ = ("append", "staged", "in_columns", "_cols")
+
+    def __init__(self, rows=()):
+        #: rows appended one by one since the columns were last written
+        self.staged: list = list(rows)
+        self.append = self.staged.append
+        #: rows the columns hold
+        self.in_columns = 0
+        self._cols = np.empty((4, 1024), dtype=np.int64)
+
+    @classmethod
+    def of(cls, log) -> "PairLog":
+        """The one door for the old form: ``log`` itself if it is a
+        PairLog, else any sequence of 4-tuples turned into columns."""
+        return log if isinstance(log, cls) else cls(log)
+
+    def __len__(self) -> int:
+        return self.in_columns + len(self.staged)
+
+    @property
+    def nbytes(self) -> int:
+        """Of the rows held, not of the room kept for the next ones."""
+        return 32 * len(self)
+
+    def _room(self, k: int) -> int:
+        """Make room for ``k`` more rows; returns where they start."""
+        n = self.in_columns
+        if n + k > self._cols.shape[1]:
+            cols = np.empty(
+                (4, 1 << int(n + k - 1).bit_length()), dtype=np.int64
+            )
+            cols[:, :n] = self._cols[:, :n]
+            self._cols = cols
+        self.in_columns = n + k
+        return n
+
+    def _flush(self) -> None:
+        staged = self.staged
+        if staged:
+            # half the price of np.asarray(staged), which looks at the
+            # type of every element of every tuple
+            rows = np.fromiter(
+                chain.from_iterable(staged), np.int64, 4 * len(staged)
+            )
+            n = self._room(len(staged))
+            self._cols[:, n:self.in_columns] = rows.reshape(-1, 4).T
+            staged.clear()  # in place: ``append`` is bound to this list
+
+    def extend(self, insert: bool, srcs, dsts, kind: int) -> None:
+        """``len(srcs)`` rows of one op and one kind, in order."""
+        self._flush()
+        n = self._room(len(srcs))
+        rows = self._cols[:, n:self.in_columns]
+        rows[0] = insert
+        rows[1] = srcs
+        rows[2] = dsts
+        rows[3] = kind
+
+    def columns(self):
+        """``(insert, src, dst, kind)``: int64 views of the rows so far,
+        valid until the log is next written or cleared."""
+        self._flush()
+        ins, src, dst, kind = self._cols[:, :self.in_columns]
+        return ins, src, dst, kind
+
+    def clear(self) -> None:
+        self.staged.clear()
+        self.in_columns = 0
+
+
+def fold_log(ins, src, dst, kind):
+    """Fold an alternating pair-transition log, given as its columns
+    (``PairLog.columns``), into its net effect per packed key.
 
     A pair's transitions strictly alternate (graph layers only log
     dead<->live flips), so the net effect is determined by the first and
-    last op.  Returns ``(removes, cond_removes, inserts)``:
+    last op.  Returns ``(removes, cond_removes, inserts, n_keys)``, the
+    three as ascending int64 key arrays, ``n_keys`` the distinct keys:
 
     - ``removes``: first op is a remove — remove from the current home
       (absence is caller drift: count an anomaly);
@@ -61,23 +145,22 @@ def fold_log(log):
       anomaly, matching the sequential scalar replay;
     - ``inserts``: last op is an insert — insert after the removals.
     """
-    first: dict = {}
-    last: dict = {}
-    for ins, src, dst, kind in log:
-        k = pack_key(src, dst, kind)
-        if k not in first:
-            first[k] = ins
-        last[k] = ins
-    removes = [k for k, ins in first.items() if not ins]
-    cond_removes = [k for k, ins in first.items() if ins and not last[k]]
-    inserts = [k for k, ins in last.items() if ins]
-    return removes, cond_removes, inserts
+    keys = pack_keys(src, dst, kind)
+    order = np.argsort(keys, kind="stable")
+    skeys = keys[order]
+    head = np.ones(skeys.size, dtype=bool)
+    head[1:] = skeys[1:] != skeys[:-1]
+    at = np.flatnonzero(head)  # where each key's run of ops starts
+    ukeys = skeys[at]
+    first = ins[order[at]] != 0
+    last = ins[order[np.append(at[1:], skeys.size) - 1]] != 0
+    return ukeys[~first], ukeys[first & ~last], ukeys[last], ukeys.size
 
 
 class PackedSlotMap:
     """int64 key -> int64 value map: sorted bulk arrays + churn overlays."""
 
-    __slots__ = ("_keys", "_vals", "_removed", "_extra")
+    __slots__ = ("_keys", "_vals", "_dead", "_n_dead", "_extra")
 
     def __init__(
         self,
@@ -91,33 +174,30 @@ class PackedSlotMap:
             order = np.argsort(keys)
             self._keys = np.ascontiguousarray(keys[order])
             self._vals = np.ascontiguousarray(vals[order])
-        self._removed: set = set()  # tombstoned bulk keys
+        #: tombstones of the bulk keys, by position (a byte a key, where
+        #: a set of the keys themselves cost ~70)
+        self._dead = np.zeros(self._keys.size, dtype=bool)
+        self._n_dead = 0
         self._extra: dict = {}  # post-rebuild inserts
 
     def __len__(self) -> int:
-        return self._keys.size - len(self._removed) + len(self._extra)
+        return self._keys.size - self._n_dead + len(self._extra)
 
     def _bulk_find(self, key: int) -> int:
-        """Index of ``key`` in the sorted bulk arrays, or -1."""
+        """Index of ``key`` among the live bulk entries, or -1."""
         keys = self._keys
         i = int(np.searchsorted(keys, key))
-        if i < keys.size and keys[i] == key:
+        if i < keys.size and keys[i] == key and not self._dead[i]:
             return i
         return -1
 
     def __contains__(self, key: int) -> bool:
-        if key in self._extra:
-            return True
-        if key in self._removed:
-            return False
-        return self._bulk_find(key) >= 0
+        return key in self._extra or self._bulk_find(key) >= 0
 
     def get(self, key: int) -> Optional[int]:
         val = self._extra.get(key)
         if val is not None:
             return val
-        if key in self._removed:
-            return None
         i = self._bulk_find(key)
         if i < 0:
             return None
@@ -133,12 +213,11 @@ class PackedSlotMap:
         val = self._extra.pop(key, None)
         if val is not None:
             return val
-        if key in self._removed:
-            return None
         i = self._bulk_find(key)
         if i < 0:
             return None
-        self._removed.add(key)
+        self._dead[i] = True
+        self._n_dead += 1
         return int(self._vals[i])
 
     # --------------------------------------------------------------- #
@@ -147,36 +226,42 @@ class PackedSlotMap:
     # --------------------------------------------------------------- #
 
     def _lookup_batch(self, karr: np.ndarray, remove: bool) -> np.ndarray:
-        # Precondition: keys within one batch are unique (callers dedup
-        # via fold_log).  A duplicated bulk key would otherwise be
-        # tombstoned once but resolved for every occurrence — e.g. a
-        # double-free of the same column downstream.
-        assert np.unique(karr).size == karr.size, "batch keys must be unique"
-        out = np.full(karr.size, -1, dtype=np.int64)
+        # Precondition: the batch's keys ascend strictly, as fold_log
+        # gives them.  Unique, because a duplicated bulk key would be
+        # tombstoned once but resolved for every occurrence (a
+        # double-free of the same column downstream); in order, because
+        # the probes then walk the bulk keys front to back where a
+        # batch in log order misses the cache at every level.
+        assert (karr[1:] > karr[:-1]).all(), "batch keys must ascend strictly"
         extra = self._extra
-        removed = self._removed
-        bulk_idx = []
-        for i, k in enumerate(karr.tolist()):
-            if k in extra:
-                out[i] = extra.pop(k) if remove else extra[k]
-            elif k not in removed:
-                bulk_idx.append(i)
-        if bulk_idx and self._keys.size:
-            bi = np.asarray(bulk_idx, dtype=np.int64)
-            kq = karr[bi]
+        if extra:
+            take = extra.pop if remove else extra.get
+            out = np.fromiter(
+                map(take, karr.tolist(), repeat(-1)), np.int64, karr.size
+            )
+            rest = np.flatnonzero(out < 0)
+        else:
+            out = np.full(karr.size, -1, dtype=np.int64)
+            rest = np.arange(karr.size)
+        if rest.size and self._keys.size:
+            kq = karr[rest]
             pos = np.minimum(
                 np.searchsorted(self._keys, kq), self._keys.size - 1
             )
-            found = self._keys[pos] == kq
-            out[bi[found]] = self._vals[pos[found]]
+            found = (self._keys[pos] == kq) & ~self._dead[pos]
+            pos = pos[found]
+            out[rest[found]] = self._vals[pos]
             if remove:
-                removed.update(kq[found].tolist())
+                self._dead[pos] = True
+                self._n_dead += pos.size
         return out
 
     def pop_batch(self, karr: np.ndarray) -> np.ndarray:
-        """Pop every key in ``karr``; returns int64 values, -1 = absent."""
+        """Pop every key in ``karr`` (strictly ascending); returns int64
+        values, -1 = absent."""
         return self._lookup_batch(np.asarray(karr, dtype=np.int64), remove=True)
 
     def get_batch(self, karr: np.ndarray) -> np.ndarray:
-        """Look up every key in ``karr``; returns int64 values, -1 = absent."""
+        """Look up every key in ``karr`` (strictly ascending); returns
+        int64 values, -1 = absent."""
         return self._lookup_batch(np.asarray(karr, dtype=np.int64), remove=False)

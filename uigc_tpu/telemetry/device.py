@@ -62,12 +62,11 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from ..utils import events
 
-#: Per-entry byte estimates for the bookkeeping maps the ledger cannot
-#: measure exactly (CPython dict/list overhead; coarse on purpose —
+#: Per-entry byte estimate for the bookkeeping maps the ledger cannot
+#: measure exactly (CPython dict overhead; coarse on purpose —
 #: the ledger's job is catching growth that never comes back down, and
 #: a constant factor cancels in that comparison).
 _DICT_ENTRY_EST = 96
-_LIST_ENTRY_EST = 72
 
 
 def _array_bytes(x: Any) -> Tuple[int, bool]:
@@ -173,7 +172,6 @@ def ledger_families(graph: Any) -> Dict[str, Dict[str, int]]:
     for attr, per_entry in (
         ("slot_of", _DICT_ENTRY_EST),
         ("send_matrix", _DICT_ENTRY_EST),
-        ("_pair_log", _LIST_ENTRY_EST),
         ("_jump_writes", _DICT_ENTRY_EST),
     ):
         try:
@@ -183,6 +181,11 @@ def ledger_families(graph: Any) -> Dict[str, Dict[str, int]]:
                 maps["items"] += 1
         except Exception:
             continue
+    # the pair log is columns (ops/slotmap.PairLog) and knows its bytes
+    log_bytes = getattr(getattr(graph, "_pair_log", None), "nbytes", None)
+    if log_bytes is not None:
+        maps["host"] += log_bytes
+        maps["items"] += 1
     try:
         edge_of = getattr(graph, "edge_of", None)
         if edge_of is not None:

@@ -29,7 +29,9 @@ A wake's record also carries ``fold_rows`` (packed rows folded),
 ``upload_bytes`` (what the upload handed the device for node features:
 the padded patch of the slots of ``flags`` and ``recv_count`` written
 since the wake before, both arrays at capacity where a wake uploaded
-them whole, 0 where no slot was written): 0 where a backend has nothing
+them whole, 0 where no slot was written), and ``layout_rows`` with
+``layout_rebuilt`` (the pair transitions the ``layout`` phase folded; 1
+where it packed the layout from the graph): 0 where a backend has nothing
 to count; and, where a sweep ran,
 ``actors_local`` and ``actors_foreign``: the slots in use after it by
 kind (actors with a cell; actors held by uid alone), from the graph's
