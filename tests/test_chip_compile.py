@@ -156,8 +156,9 @@ def test_propagate_launch_compiles_with_its_list(one_chip, geom):
     one Mosaic kernel (with the test of what a block gathered around its
     contraction and the SMEM counter of the steps that contracted), its
     scalar operands under the SMEM budget, and beside the contributions
-    the count of steps it took, of those that contracted and of the
-    chunk-iterations their walks take."""
+    the count of steps it took, of those that contracted, of the
+    chunk-iterations their walks take and of the loop trips they take
+    them in."""
     import jax
 
     propagate = pt.build_propagate(
@@ -177,11 +178,12 @@ def test_propagate_launch_compiles_with_its_list(one_chip, geom):
     ).compile()
     assert _mosaic_calls(compiled) == 1
     assert _scalar_operand_bytes(geom) < SMEM_BUDGET
-    out, steps, contracted, walks = compiled.out_info
+    out, steps, contracted, walks, trips = compiled.out_info
     assert out.shape == (geom["n_super"] * pt.S_ROWS, LANE)
     assert steps.shape == () and steps.dtype == np.int32
     assert contracted.shape == () and contracted.dtype == np.int32
     assert walks.shape == () and walks.dtype == np.int32
+    assert trips.shape == () and trips.dtype == np.int32
 
 
 def test_decremental_wake_compiles_at_10m(one_chip):
@@ -197,6 +199,7 @@ def test_decremental_wake_compiles_at_10m(one_chip):
     assert stats["closure_bailed"].shape == stats["closure_spent"].shape == ()
     assert stats["kernel_steps"].shape == stats["kernel_steps_full"].shape == ()
     assert stats["kernel_contractions"].shape == stats["kernel_chunk_walks"].shape == ()
+    assert stats["kernel_walk_trips"].shape == ()
     # three n_blocks-long int32 operands in SMEM (bmeta1, bmeta2 and the
     # list of active blocks) beside the gate and the dirty lists
     assert _scalar_operand_bytes(GEOM_10M) == 304_996 < SMEM_BUDGET
@@ -303,6 +306,8 @@ def test_wake_program_counts_and_names(one_chip, mode):
     assert stats["gated_tiles"].shape == ()
     assert stats["kernel_steps"].shape == stats["kernel_steps_full"].shape == ()
     assert stats["kernel_contractions"].shape == ()
+    for key in ("kernel_chunk_walks", "kernel_walk_trips"):
+        assert stats[key].shape == () and stats[key].dtype == np.int32
     assert stats["jump_sweeps"].shape == stats["jump_spent"].shape == ()
     for key in ("dirty_chunks", "tiles_skipped", "pull_on", "jump_on"):
         assert stats[key].shape == (pt.MAX_SWEEP_STATS,)

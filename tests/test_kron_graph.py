@@ -144,6 +144,7 @@ def test_kron_through_the_tracer_and_the_simulator(two_chunks, mode):
     assert sum(sim["steps"]) == s["kernel_steps"]
     assert sum(sim["contracting"]) == s["kernel_contractions"]
     assert sum(sim["chunk_iterations"]) == s["kernel_chunk_walks"]
+    assert sum(sim["walk_trips"]) == s["kernel_walk_trips"]
     # no slot locality to skip by: every sweep but the last dirties both chunks
     assert s["dirty_chunks"][:-1] == [2] * (s["n_sweeps"] - 1)
     if mode == pt.MODE_AUTO:
@@ -191,5 +192,8 @@ def test_churned_kron_graph_equals_the_oracle_after_every_wake():
         garbage_seen.add(int((~expected).sum()))
         s = tracer.wake_stats(1)[0]
         assert 0 <= s["kernel_contractions"] <= s["kernel_steps"] <= s["kernel_chunk_walks"]
+        # two chunks a trip, one where a block's count is odd
+        assert s["kernel_steps"] <= s["kernel_walk_trips"] <= s["kernel_chunk_walks"]
+        assert s["kernel_chunk_walks"] <= 2 * s["kernel_walk_trips"]
     assert tracer.layout.stats["anomalies"] == 0
     assert len(garbage_seen) > 2  # the churn moved the verdict

@@ -231,7 +231,8 @@ def test_contractions_of_a_derivation_with_an_unreachable_half(mode):
     blocks that have nothing to contribute: sources never marked, or
     marked sweeps ago.  ``wake_stats()``'s ``kernel_contractions`` is
     under ``kernel_steps``, and both, and the ``kernel_chunk_walks`` of
-    the steps' walks, equal what
+    the steps' walks and the ``kernel_walk_trips`` they take them in
+    (two chunks a trip), equal what
     ``tools/sweep_profile.py simulate_sweeps`` counts per sweep from the
     tracer's own packed layout (here at the interpreted geometry; the same
     code is the counter's oracle at the chip's)."""
@@ -257,7 +258,14 @@ def test_contractions_of_a_derivation_with_an_unreachable_half(mode):
     assert sum(sim["steps"]) == s["kernel_steps"]
     assert sum(sim["contracting"]) == s["kernel_contractions"]
     assert sum(sim["chunk_iterations"]) == s["kernel_chunk_walks"] >= s["kernel_steps"]
+    assert sum(sim["walk_trips"]) == s["kernel_walk_trips"]
     assert all(c <= t for c, t in zip(sim["contracting"], sim["steps"]))
+    # a trip walks two chunks, or one where a block's count is odd
+    assert all(
+        t <= r <= i <= 2 * r
+        for t, r, i in zip(sim["steps"], sim["walk_trips"], sim["chunk_iterations"])
+    )
+    assert s["kernel_walk_trips"] < s["kernel_chunk_walks"]  # some trip walked two
 
 
 def test_released_cycle_dies():

@@ -305,7 +305,8 @@ def _bits(table, node):
     return (table[word >> 7, word & 127] >> (node & 31)) & 1
 
 
-def _reference_contribs(prep, psrc, pdst, table, dirty, gate, new=None):
+def _reference_contribs(prep, psrc, pdst, table, dirty, gate, new=None,
+                        chunk_rows=GRID_CHUNK_ROWS):
     """The uncompacted reference, which contracts whatever it gathered: a
     segment-sum over the layout's pairs of the source's bit, for the pairs
     whose source chunk the sweep walks into their destination tile (dirty,
@@ -316,7 +317,7 @@ def _reference_contribs(prep, psrc, pdst, table, dirty, gate, new=None):
     tile = pdst // super_sz
     if "super_ids" in prep:  # compact: global supertile -> layout tile
         tile = np.searchsorted(prep["super_ids"][: len(np.unique(tile))], tile)
-    chunk = (psrc >> 12) // GRID_CHUNK_ROWS
+    chunk = (psrc >> 12) // chunk_rows
     g = gate[tile]
     bit = np.where(
         g == pallas_trace.GATE_FULL, _bits(table, psrc),
@@ -332,7 +333,8 @@ def _reference_contribs(prep, psrc, pdst, table, dirty, gate, new=None):
     return out.reshape(-1, pallas_trace.LANE)
 
 
-def _gathering_blocks(prep, table, dirty, gate, new=None):
+def _gathering_blocks(prep, table, dirty, gate, new=None,
+                      chunk_rows=GRID_CHUNK_ROWS):
     """Per block, whether the kernel's walk gathers a set bit this sweep,
     from the packed layout alone: a slot's source is decoded from
     ``row_pos`` and ``emeta``, it is walked if its chunk is (the block's
@@ -342,7 +344,7 @@ def _gathering_blocks(prep, table, dirty, gate, new=None):
     held = src >= 0
     src = np.where(held, src, 0)
     g = gate[prep["bmeta1"] >> 1][:, None]
-    chunk = (src >> 12) // GRID_CHUNK_ROWS  # 2^12 nodes a table row
+    chunk = (src >> 12) // chunk_rows  # 2^12 nodes a table row
     walked = held & (g != pallas_trace.GATE_SKIP) & (
         (g == pallas_trace.GATE_FULL) | dirty[chunk]
     )
@@ -353,10 +355,12 @@ def _gathering_blocks(prep, table, dirty, gate, new=None):
     return (walked & (bit > 0)).any(axis=1)
 
 
-def _launch(prep, table, dirty, gate, fill=None, new=None, interpret=True):
+def _launch(prep, table, dirty, gate, fill=None, new=None, interpret=True,
+            group=1, dst_gate=True):
     """(contributions, steps, steps that contracted) of one launch; over a
     buffer of ``fill``.  The kernel's table operand is ``table`` over
-    ``new``, the table's own bits where none is given."""
+    ``new``, the table's own bits where none is given.  Without
+    ``dst_gate`` the kernel has no gate operand (``gate`` is zeros)."""
     import jax
     import jax.numpy as jnp
 
@@ -366,20 +370,22 @@ def _launch(prep, table, dirty, gate, fill=None, new=None, interpret=True):
     l[d[:-1][dirty]] = np.flatnonzero(dirty)
     propagate = pallas_trace.build_propagate(
         prep["n_blocks"], prep.get("out_supers", prep["n_super"]),
-        prep["r_rows"], prep["s_rows"], interpret, sub=prep["sub"], group=1,
-        dst_gate=True,
+        prep["r_rows"], prep["s_rows"], interpret, sub=prep["sub"],
+        group=group, dst_gate=dst_gate,
     )
     tables = np.concatenate([table, table if new is None else new])
-    operands = (d, l, gate, prep["bmeta1"], prep["bmeta2"], tables,
-                prep["row_pos"], prep["emeta"])
+    operands = (d, l, *((gate,) if dst_gate else ()), prep["bmeta1"],
+                prep["bmeta2"], tables, prep["row_pos"], prep["emeta"])
     if fill is None:
-        out, steps, contracted, walks = jax.jit(propagate.with_steps)(*operands)
+        out, steps, contracted, walks, trips = jax.jit(propagate.with_steps)(*operands)
     else:
         plane = jnp.full(
             (propagate(*operands).shape[0], pallas_trace.LANE), fill, jnp.float32
         )
-        out, steps, contracted, walks = jax.jit(propagate.onto)(plane, *operands)
-    assert int(walks) == int(_block_iters(prep, dirty, gate).sum())
+        out, steps, contracted, walks, trips = jax.jit(propagate.onto)(plane, *operands)
+    n_iter = _block_iters(prep, dirty, gate)
+    assert int(walks) == int(n_iter.sum())
+    assert int(trips) == int(((n_iter + 1) // 2).sum())  # two chunks a trip
     return np.asarray(out), int(steps), int(contracted)
 
 
@@ -579,7 +585,7 @@ def test_unvisited_tiles_of_a_compact_layout_add_nothing():
     l = np.array([1, 0, 0], np.int32)
     args = pallas_trace.device_args(dense) + pallas_trace.device_args(comp)
     tables = np.concatenate([table, table])
-    hits, steps, contracted, walks = jax.jit(
+    hits, steps, contracted, walks, trips = jax.jit(
         lambda t, d, l, g, *a: sweep.with_steps(t, d, l, a, gate=g)
     )(tables, d, l, gate, *args)
     expected = (
@@ -598,7 +604,9 @@ def test_unvisited_tiles_of_a_compact_layout_add_nothing():
         _gathering_blocks(dense, table, dirty, gate).sum()
         + _gathering_blocks(comp, table, dirty, gate[comp["super_ids"]]).sum()
     )
-    assert int(walks) == int(_block_iters(dense, dirty, gate).sum() + n_iter_c.sum())
+    n_iter_d = _block_iters(dense, dirty, gate)
+    assert int(walks) == int(n_iter_d.sum() + n_iter_c.sum())
+    assert int(trips) == int(((n_iter_d + 1) // 2).sum() + ((n_iter_c + 1) // 2).sum())
     assert np.array_equal(
         np.asarray(jax.jit(lambda t, d, l, g, *a: sweep(t, d, l, a, gate=g))(
             tables, d, l, gate, *args)),
@@ -714,3 +722,104 @@ def test_contraction_of_packed_words_compiled(case):
     """The same through Mosaic at the chip's block (32 slot rows): what
     settles the order of the rows inside a packed word on hardware."""
     _check_words_case(case, pallas_trace.SUB_TPU, interpret=False)
+
+
+# --------------------------------------------------------------------- #
+# The walk: two chunks a trip, a slot vreg's gathers in a row
+# --------------------------------------------------------------------- #
+
+WALK_CHUNKS = 6
+#: per destination tile, the walk chunks its sources lie in (first, count):
+#: spans of 1, 2, 3 and 5 chunks, odd ones beside even ones
+WALK_WINDOWS = [(0, 1), (1, 2), (2, 3), (1, 5), (5, 1), (4, 2), (3, 3), (0, 5)]
+#: the chunk-iterations the blocks of a case take
+WALK_CASES = {
+    "all_dirty": {1, 2, 3, 5},
+    "forced_beside_listed": {1, 2, 3, 4, 5},
+    "odd_tail_at_the_lists_end": {1, 2, 3},
+    "no_gate_operand": {1, 2, 3, 5},
+}
+
+
+def _walk_layout(sub, group):
+    """A layout of one block a tile whose span is the tile's window, and
+    the pairs it holds: under 128 pairs a row class and tile."""
+    rng = np.random.default_rng(47)
+    chunk_nodes = pallas_trace.ROWS * group * pallas_trace.LANE * pallas_trace.WORD_BITS
+    n = WALK_CHUNKS * chunk_nodes
+    super_sz = GRID_S_ROWS * pallas_trace.LANE
+    psrc, pdst = [], []
+    for tile, (first, count) in enumerate(WALK_WINDOWS):
+        lo, hi = first * chunk_nodes, (first + count) * chunk_nodes
+        # both ends of the window hold a source, so the span is the window
+        psrc.append(np.r_[lo, hi - 1, rng.integers(lo, hi, 500)])
+        pdst.append(tile * super_sz + rng.integers(0, super_sz, 502))
+    psrc, pdst = np.concatenate(psrc), np.concatenate(pdst)
+    prep = pallas_trace.prepare_pairs(
+        psrc, pdst, n, s_rows=GRID_S_ROWS, pad_blocks_pow2=True, sub=sub,
+        group=group,
+    )
+    return prep, psrc, pdst
+
+
+def _check_walk_case(case, sub, group, interpret):
+    prep, psrc, pdst = _walk_layout(sub, group)
+    chunk_rows = pallas_trace.ROWS * group
+    assert prep["r_rows"] // chunk_rows == WALK_CHUNKS
+    rng = np.random.default_rng(3)
+    # set bits in every chunk, so a chunk walked into the wrong slots or
+    # read from the wrong half shows; what is new differs from the table
+    table = rng.integers(0, 1 << 31, (prep["r_rows"], pallas_trace.LANE)).astype(np.int32)
+    new = table & rng.integers(0, 1 << 31, table.shape).astype(np.int32)
+    dirty = np.ones(WALK_CHUNKS, bool)
+    gate = np.zeros(prep["n_super"], np.int32)
+    dst_gate = case != "no_gate_operand"
+    if case == "forced_beside_listed":
+        dirty[2] = False  # listed blocks walk what is left of their windows
+        gate[3] = pallas_trace.GATE_FULL  # an odd plain span, over the full table
+        gate[5] = pallas_trace.GATE_SKIP
+    if case == "odd_tail_at_the_lists_end":
+        # the last tile walks chunks 1, 2, 3 of its window 0..4, the
+        # list's last three entries: a trip that read one entry more
+        # would find chunk 0, clean but in the window, and its bits
+        dirty[[0, 4, 5]] = False
+    n_iter = _block_iters(prep, dirty, gate)
+    assert set(n_iter[n_iter > 0]) == WALK_CASES[case], n_iter[n_iter > 0]
+    assert (n_iter[n_iter > 0] % 2).any() and not (n_iter[n_iter > 0] % 2).all()
+
+    # the walks and their trips (two chunks a trip, a trip of one where a
+    # block's count is odd) are held to numpy's inside ``_launch``
+    out, steps, contracted = _launch(
+        prep, table, dirty, gate, new=new, interpret=interpret, group=group,
+        dst_gate=dst_gate,
+    )
+    expected = _reference_contribs(
+        prep, psrc, pdst, table, dirty, gate, new, chunk_rows=chunk_rows
+    )
+    assert expected.any() and np.array_equal(out, expected)
+    assert steps == int((n_iter > 0).sum())
+    assert contracted == int(
+        _gathering_blocks(prep, table, dirty, gate, new, chunk_rows=chunk_rows).sum()
+    )
+
+
+@pytest.mark.parametrize("sub,group", [(1, 1), (2, 2)])
+@pytest.mark.parametrize("case", WALK_CASES)
+def test_walk_of_two_chunks_a_trip_gathers_every_chunk_once(case, sub, group):
+    """Blocks of 1, 2, 3 and 5 chunk-iterations in one launch against the
+    segment-sum over the same pairs, bit for bit: a trip walks two chunks,
+    an odd count's first chunk has a trip of its own, a forced block walks
+    its plain span over the full table beside blocks that walk the dirty
+    list over what is new, to the list's last entry and no further, with
+    and without the gate operand."""
+    _check_walk_case(case, sub, group, interpret=True)
+
+
+@pytest.mark.tpu
+@pytest.mark.parametrize("case", WALK_CASES)
+def test_walk_of_two_chunks_a_trip_compiled(case):
+    """The same through Mosaic at the chip's geometry (32 slot rows,
+    64-row table groups)."""
+    _check_walk_case(
+        case, pallas_trace.SUB_TPU, pallas_trace.GROUP_TPU, interpret=False
+    )

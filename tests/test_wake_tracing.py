@@ -280,9 +280,10 @@ def test_sweep_profile_prints_the_wakes_own_rows(monkeypatch, capsys):
         got = out["modes"][mode]
         assert got.pop("fixpoint_ms") > 0
         counts = ("n_sweeps", "jump_sweeps", "kernel_steps", "kernel_contractions",
-                  "kernel_chunk_walks")
+                  "kernel_chunk_walks", "kernel_walk_trips")
         assert got == {k: want[k] for k in counts + rows}
         assert 0 < got["kernel_contractions"] <= got["kernel_steps"] <= got["kernel_chunk_walks"]
+        assert got["kernel_steps"] <= got["kernel_walk_trips"] <= got["kernel_chunk_walks"]
         assert got["n_sweeps"] == len(got["dirty_chunks"]) > 1
         assert record["mode"] == mode and record["n_sweeps"] == want["n_sweeps"]
         assert all(record["sweep_" + k] == want[k] for k in rows)

@@ -54,6 +54,13 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 import numpy as np
 
 
+#: what a grid step that walks nothing new costs beside its walk on the
+#: v5e, in us: the per-sweep kernel times of a derivation fitted over the
+#: program's own counters (PERF.md section 6; PR 41: 0.338, PR 44: 0.335,
+#: PR 47: 0.343)
+SKIP_US_PER_STEP = 0.343
+
+
 def _sync(out):
     """Wait for every output (``block_until_ready`` synchronises on the
     chip: PERF.md section 6, PR 24)."""
@@ -91,10 +98,12 @@ def simulate_sweeps(graph, n, modes, jump_steps=None, geometry=None,
     geometry; it then gives ``geometry`` too) every sweep also counts,
     by the propagate kernel's own rules, the grid ``steps`` it takes (the
     blocks with a dirty chunk in their span whose tile the pull gate does
-    not skip) and how many of them are ``contracting`` (a slot's source
-    bit is new since the sweep before): the oracle of ``wake_stats()``'s
-    ``kernel_steps`` and ``kernel_contractions`` on a derivation from
-    nothing.  Pull gating changes the steps, so ``pull`` is then
+    not skip), how many of them are ``contracting`` (a slot's source
+    bit is new since the sweep before), the ``chunk_iterations`` of their
+    walks and the ``walk_trips`` of the walks' loops (two chunks a trip):
+    the oracle of ``wake_stats()``'s ``kernel_steps``,
+    ``kernel_contractions``, ``kernel_chunk_walks`` and
+    ``kernel_walk_trips`` on a derivation from nothing.  Pull gating changes the steps, so ``pull`` is then
     simulated on its own.
 
     With ``suspects`` (actor ids: the targets of released references,
@@ -104,8 +113,8 @@ def simulate_sweeps(graph, n, modes, jump_steps=None, geometry=None,
     the closure is push-only whatever the mode.
 
     Returns {mode: {"sweeps", "jump_sweeps", "dirty_chunks"}} (with
-    ``layout`` also "steps", "contracting" and "chunk_iterations", per
-    sweep), under
+    ``layout`` also "steps", "contracting", "chunk_iterations" and
+    "walk_trips", per sweep), under
     "auto" also the policy's "price", and with ``suspects`` under every
     mode "closure": {"price", "sweeps", "spent", "bailed"} as the wake
     would run it, and "full_sweeps", "sizes" (closure members after each
@@ -190,9 +199,11 @@ def simulate_sweeps(graph, n, modes, jump_steps=None, geometry=None,
         out[mode] = {"sweeps": len(dirty), "jump_sweeps": jump_sweeps,
                      "dirty_chunks": dirty}
         if walk is not None:
-            steps, contracting, iters = (list(c) for c in zip(*per_sweep))
+            steps, contracting, iters, trips = (
+                list(c) for c in zip(*per_sweep)
+            )
             out[mode].update(steps=steps, contracting=contracting,
-                             chunk_iterations=iters)
+                             chunk_iterations=iters, walk_trips=trips)
         if decide is not None:
             out[mode]["price"] = decide.price
     if suspects is not None:
@@ -265,9 +276,9 @@ class _BlockWalk:
         return padded.reshape(count, size).any(axis=1)
 
     def sweep(self, table, table_prev, mark, pull_on):
-        """(grid steps, steps that contract, chunk-iterations) of the
-        sweep that walks ``table`` with the dirty lists taken against
-        ``table_prev``."""
+        """(grid steps, steps that contract, chunk-iterations, loop trips
+        of the walks: two chunks a trip) of the sweep that walks ``table``
+        with the dirty lists taken against ``table_prev``."""
         dirty = self._per(table != table_prev, self.chunk_nodes, self.n_chunks)
         d = np.concatenate([[0], np.cumsum(dirty)])
         n_iter = d[self.c_hi] - d[self.c_lo]
@@ -279,7 +290,9 @@ class _BlockWalk:
             active &= unmarked[self.tile]
         new = np.concatenate([table & ~table_prev, [False]])
         gathers = new[self.slot_src[active]].any(axis=1)
-        return int(active.sum()), int(gathers.sum()), int(n_iter[active].sum())
+        walked = n_iter[active]
+        return (int(active.sum()), int(gathers.sum()), int(walked.sum()),
+                int(((walked + 1) // 2).sum()))
 
 
 def chip_layout(psrc, pdst, n):
@@ -375,6 +388,8 @@ def main():
                     "chunk_iterations": {
                         m: sim[m]["chunk_iterations"] for m in modes
                     },
+                    # the trips of the walks' loops, two chunks a trip
+                    "walk_trips": {m: sim[m]["walk_trips"] for m in modes},
                     "auto_jump_price": sim.get(pt.MODE_AUTO, {}).get("price"),
                     **({"closure": {m: sim[m]["closure"] for m in modes}}
                        if suspects is not None else {}),
@@ -453,7 +468,8 @@ def main():
             probe_ms[k] for k in ("full", "none", "half")
         )
         # the grid steps each probe took (its blocks with work), those
-        # of them that contracted and the chunk-iterations they walked
+        # of them that contracted, the chunk-iterations they walked and
+        # the trips of the walks' loops
         probe_steps = {k: [int(c) for c in propagate(*ops)[1:]]
                        for k, ops in operands.items()}
 
@@ -540,6 +556,7 @@ def main():
                 "kernel_steps": stats["kernel_steps"],
                 "kernel_contractions": stats["kernel_contractions"],
                 "kernel_chunk_walks": stats["kernel_chunk_walks"],
+                "kernel_walk_trips": stats["kernel_walk_trips"],
                 **rows,
             }
         wake_records = profiler.to_json()["recent"]
@@ -565,13 +582,23 @@ def main():
                 "sweep_full_dirty_nothing_new_ms": round(
                     probe_ms["full_nothing_new"], 2
                 ),
-                # [grid steps, steps that contracted] per probe
+                # [grid steps, steps that contracted, chunk-iterations,
+                # walk trips] per probe
                 "sweep_grid_steps": probe_steps,
                 # what the contraction costs a walked block that needs it
                 "contraction_us_per_step": round(
                     (full_ms - probe_ms["full_nothing_new"]) * 1e3
                     / max(probe_steps["full"][1], 1), 3
                 ),
+                # what the walk costs a chunk-iteration: the walk that
+                # contracts nothing, less the launch (the no-dirty probe)
+                # and its steps at the skip price
+                "walk_us_per_chunk": round(
+                    ((probe_ms["full_nothing_new"] - none_ms) * 1e3
+                     - probe_steps["full_nothing_new"][0] * SKIP_US_PER_STEP)
+                    / max(probe_steps["full_nothing_new"][2], 1), 4
+                ),
+                "skip_us_per_step": SKIP_US_PER_STEP,
                 "dispatch_floor_ms": round(floor_ms, 2),
                 "pack_seed_ms": round(pack_ms, 2),
                 "pack2d_per_sweep_ms": round(pack2d_ms, 2),

@@ -156,7 +156,11 @@ def _build_wake_fn(
     gather found a bit), ``kernel_chunk_walks`` (the chunk-iterations the
     kernels' walks took: per block with work, the dirty chunks in its
     span, or the whole span where the gate forces it; the sum of the
-    vector the list of active blocks is made from), ``jump_sweeps`` (the
+    vector the list of active blocks is made from), ``kernel_walk_trips``
+    (the trips of the walks' loops, two chunks a trip: per block with
+    work, half its chunk-iterations rounded up, so ``2 - walks / trips``
+    is the share of trips that had one chunk to walk and walked it
+    twice), ``jump_sweeps`` (the
     repair sweeps that ran the jump) and ``jump_spent`` (the policy's
     ``spent`` at exit, to be read against the static
     :attr:`DecrementalTracer.jump_price`) are int32 scalars, ``dirty_chunks``,
@@ -231,9 +235,9 @@ def _build_wake_fn(
         def contribs(table, table_prev, d, l, gate):
             """One propagation sweep over every layout (shared loop:
             pallas_trace.build_sweep_contribs), the grid steps its kernels
-            took, those of them that contracted and the chunk-iterations
-            they walked; a zero gate vector
-            makes the dst-gated kernels behave exactly like the plain
+            took, those of them that contracted, the chunk-iterations they
+            walked and the loop trips they walked them in; a zero gate
+            vector makes the dst-gated kernels behave exactly like the plain
             ones.  ``d`` and ``l`` are the dirty lists of ``table``
             against ``table_prev``, the table of the sweep before: the
             kernels gather the bits that are new since (what was set
@@ -285,8 +289,8 @@ def _build_wake_fn(
 
         def c_body(carry):
             (closure_w, closure_prev, d, l, _, sweeps, spent, steps,
-             contracted, walked) = carry
-            hits2d, took, did, iters = contribs(
+             contracted, walked, tripped) = carry
+            hits2d, took, did, iters, trips = contribs(
                 closure_w, closure_prev, d, l, zero_gate
             )
             hit_w = pt.pack_hits_table(hits2d, r_rows, jnp)
@@ -294,17 +298,17 @@ def _build_wake_fn(
             d2, l2, changed = dirty_chunks(new_closure, closure_w)
             return (new_closure, closure_w, d2, l2, changed, sweeps + 1,
                     spent + d[n_chunks], steps + took, contracted + did,
-                    walked + iters)
+                    walked + iters, tripped + trips)
 
         with pt.scope("closure"):
             zero_w = jnp.zeros_like(s_w)
             d0, l0, changed0 = dirty_chunks(s_w, zero_w)
             (closure_w, _, _, _, closure_bailed, closure_sweeps,
              closure_spent, closure_steps, closure_contracted,
-             closure_walked) = jax.lax.while_loop(
+             closure_walked, closure_tripped) = jax.lax.while_loop(
                 c_cond, c_body,
                 (s_w, zero_w, d0, l0, changed0, zero_i, zero_i, zero_i,
-                 zero_i, zero_i),
+                 zero_i, zero_i, zero_i),
             )
             # The cold road: the region to repair is everything, because
             # the closure said so by its cost or because there is no
@@ -374,7 +378,7 @@ def _build_wake_fn(
                 sat = None
                 pull_on = jnp.array(False)
                 gate = base_gate
-            hits2d, took, did, iters = contribs(
+            hits2d, took, did, iters, trips = contribs(
                 table, carry["table_prev"], d, l, gate
             )
             hit_w = pt.pack_hits_table(hits2d, r_rows, jnp)
@@ -397,6 +401,7 @@ def _build_wake_fn(
                        steps=carry["steps"] + took,
                        contracted=carry["contracted"] + did,
                        walked=carry["walked"] + iters,
+                       tripped=carry["tripped"] + trips,
                        st_dirty=carry["st_dirty"].at[i].set(n_dirty))
             if use_jump:
                 jump_on = jump_state[0].astype(jnp.int32)
@@ -431,6 +436,7 @@ def _build_wake_fn(
                       "changed": run0,
                       "sweep_i": zero_i, "walks": zero_i, "steps": zero_i,
                       "contracted": zero_i, "walked": zero_i,
+                      "tripped": zero_i,
                       "st_dirty": zero_stats}
             if use_jump:
                 carry0.update(jump=jump_j0.astype(jnp.int32),
@@ -456,6 +462,8 @@ def _build_wake_fn(
             "kernel_contractions": closure_contracted + out["contracted"],
             # the chunk-iterations their walks took
             "kernel_chunk_walks": closure_walked + out["walked"],
+            # the trips of the walks' loops, two chunks a trip
+            "kernel_walk_trips": closure_tripped + out["tripped"],
             "kernel_steps_full": (closure_sweeps + out["sweep_i"])
             * launch_blocks,
             "dirty_chunks": out["st_dirty"],
@@ -549,6 +557,7 @@ def _host_stats(host: dict) -> dict:
         "kernel_steps": int(host["kernel_steps"]),
         "kernel_contractions": int(host["kernel_contractions"]),
         "kernel_chunk_walks": int(host["kernel_chunk_walks"]),
+        "kernel_walk_trips": int(host["kernel_walk_trips"]),
         "kernel_steps_full": int(host["kernel_steps_full"]),
         "dirty_chunks": host["dirty_chunks"][:k].tolist(),
         "tiles_skipped": host["tiles_skipped"][:k].tolist(),
@@ -834,7 +843,8 @@ class DecrementalTracer:
         (the grid steps its kernels took, of launches x blocks) and
         ``kernel_contractions`` (those of the steps that gathered a new
         bit and paid for their contraction), ``kernel_chunk_walks`` (the
-        chunk-iterations the steps' walks took),
+        chunk-iterations the steps' walks took) in ``kernel_walk_trips``
+        (the trips of the walks' loops, two chunks a trip),
         ``jump_sweeps`` (the repair sweeps that ran the pointer jump),
         ``jump_spent`` (the ``auto`` policy's sparse chunk walks at exit;
         against :attr:`jump_price`) and, for the repair's first

@@ -12,7 +12,9 @@ sides with the primitives the TPU VPU/MXU actually has:
 1M actors).  Mosaic supports per-vreg dynamic lane shuffles
 (``take_along_axis`` within an (8, 128) register) but nothing across
 vregs, so each grid step walks 8-row table chunks.  Two layout invariants
-make the walk cheap:
+make the walk cheap (and a third thing its loop: a slot vreg gathers
+out of all the table vregs of a trip in a row, under one lane pattern,
+and a trip walks two chunks; ``build_propagate``):
 
 1. *Slot row = source row mod 8.*  An edge whose source bit lives at table
    position (row_e, lane_e) is parked at slot ``(row_e % 8, col)``, so
@@ -82,11 +84,14 @@ ROWS = 8  # sublane rows per edge-slot sub-block (slot row = src row mod 8)
 #: sub-fold fewer grid steps (and their fixed stream/dispatch cost) for
 #: the same total edges.
 SUB_TPU = 4
-#: default 8-row table chunks walked per gather-loop iteration on a real
-#: chip.  The chunk walk was the measured bottleneck at graph scale
-#: (~250ns/iteration of serial loop overhead for ~30ns of VPU work);
-#: walking `group` chunks per iteration cuts iterations ~group-fold and
-#: amortizes the overhead over `group` statically-unrolled sub-gathers.
+#: default 8-row table chunks to a walk chunk (a ``group_rows``-row table
+#: group) on a real chip: the unit of the dirty lists and of a block's
+#: span, walked by `sub` x `group` statically-unrolled lane gathers.  What
+#: a walk costs is its trips and its permutes, not its arithmetic: on the
+#: v5e a chunk-iteration cost 0.21 us as one trip of 32 gathers whose lane
+#: pattern changed with every gather, 0.155 with a slot vreg's eight
+#: gathers in a row and 0.104 with two chunks a trip (``build_propagate``;
+#: PERF.md section 6, PR 47).
 GROUP_TPU = 8
 #: interpret-mode defaults.  The wide geometry statically unrolls
 #: sub*group gather stages per chunk iteration — on the CPU test tier
@@ -625,8 +630,9 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
     """The per-layout propagation sweep of the decremental wake's two
     fixpoints: returns fn(table, d, l, layout_args, gate) -> hits
     plane (t_rows, LANE) bool; ``fn.with_steps`` returns beside it the
-    grid steps the sweep's kernels took, those of them that contracted
-    and the chunk-iterations their walks took (``build_propagate``).
+    grid steps the sweep's kernels took, those of them that contracted,
+    the chunk-iterations their walks took and the loop trips they took
+    them in (``build_propagate``).
 
     ``propagates`` holds one kernel per packed spec (None for xla
     tiers).  ``gate`` is the per-global-supertile dst-gate vector for
@@ -649,7 +655,7 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
         contrib = jnp.zeros((t_rows, LANE), jnp.float32)
         xla_hits2d = jnp.zeros((t_rows, LANE), bool)
         have_xla = False
-        steps = contracted = walks = jnp.zeros((), jnp.int32)
+        steps = contracted = walks = trips = jnp.zeros((), jnp.int32)
         pos = 0
         for spec, propagate in zip(specs, propagates):
             if spec[0] == "xla":
@@ -682,12 +688,13 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
                     gates = (gate[super_ids],)
             elif gate is not None:
                 gates = (gate,)
-            c, took, did, walked = propagate.with_steps(
+            c, took, did, walked, tripped = propagate.with_steps(
                 d, l, *gates, bmeta1, bmeta2, tables, row_pos, emeta
             )
             steps = steps + took
             contracted = contracted + did
             walks = walks + walked
+            trips = trips + tripped
             if compact:
                 rows = (
                     super_ids[:, None] * s_rows + sub_iota_rows[None, :]
@@ -700,7 +707,7 @@ def build_sweep_contribs(specs, propagates, n, n_super, s_rows, jnp):
         hits2d = contrib > 0
         if have_xla:
             hits2d = hits2d | xla_hits2d
-        return hits2d, steps, contracted, walks
+        return hits2d, steps, contracted, walks, trips
 
     sweep.with_steps = with_steps
     return sweep
@@ -1170,8 +1177,9 @@ def build_propagate(
     packed table, one-hot segment-sum into per-supertile contributions.
     Returns ``propagate(d, l, [gate,] bmeta1, bmeta2, tables, row_pos,
     emeta) -> contributions``; ``propagate.with_steps`` returns beside
-    them the grid steps the launch took, those of them that contracted
-    and the chunk-iterations the steps' walks took.
+    them the grid steps the launch took, those of them that contracted,
+    the chunk-iterations the steps' walks took and the loop trips they
+    took them in.
 
     Operands (after the scalar-prefetch ones): the (2 * r_rows, LANE) bit
     tables (``walk_tables``: the full table over its bits that are new
@@ -1190,6 +1198,20 @@ def build_propagate(
     fresh inserts, reused slots), and a forced block reads the FULL
     table.  A caller with no table of the sweep before hands the full
     table in both halves.
+
+    The walk's loop: what does not depend on the chunk is made once a
+    block (a slot's table row less its row class, its lane index by slot
+    vreg; the gather's indices are ``emeta & 127`` and promised in
+    bounds), each of the block's `sub` slot vregs gathers out of every
+    table vreg of a trip in a row (one lane pattern, `group` permutes a
+    chunk: the permute path is what a chunk costs, and a pattern that
+    changes with every gather doubles it), and a trip walks TWO chunks,
+    both chunk ids read and both table groups loaded before the first
+    gather: a loop with a dynamic trip count is a basic block a trip, and
+    nothing of the next chunk could start before the last select of this
+    one.  An odd count's first chunk is walked by a loop of its own, of
+    one trip.  Exactly one sub-chunk of one chunk hits a slot, so the
+    contributions do not depend on the order (PERF.md section 6, PR 47).
 
     A block whose gather found no bit skips its contraction: the gathered
     bits are reduced to one scalar, and the one-hot operands, the MXU and
@@ -1260,10 +1282,11 @@ def build_propagate(
         return n_iter
 
     def active_blocks(d, gate, bmeta1, bmeta2):
-        """(act, count, walks): the blocks with work this sweep, in block
-        order, in the first ``count`` entries of ``act`` (the rest the
-        last block), and the chunk-iterations their walks will take (a
-        block without work has none).  A sort and not a prefix sum and a
+        """(act, count, walks, trips): the blocks with work this sweep, in
+        block order, in the first ``count`` entries of ``act`` (the rest
+        the last block), the chunk-iterations their walks will take (a
+        block without work has none) and the loop trips they take them
+        in, two chunks a trip.  A sort and not a prefix sum and a
         scatter: on the v5e the scatter of 24,576 ids costs 122 us a
         launch, the sort 18 (PERF.md section 6, PR 32)."""
         with scope("active"):
@@ -1275,6 +1298,8 @@ def build_propagate(
                 jnp.minimum(act, n_blocks - 1),
                 active.sum(dtype=jnp.int32),
                 n_iter.sum(dtype=jnp.int32),
+                # two chunks a trip, and a trip of one where the count is odd
+                ((n_iter + 1) >> 1).sum(dtype=jnp.int32),
             )
 
     def kernel(*refs):
@@ -1324,40 +1349,73 @@ def build_propagate(
             lane_idx = emeta & 127
             bit_pos = (emeta >> 7) & 31
 
-            def chunk_body(j, acc):
-                # One iteration walks a group_rows-row table group:
-                # `group` statically-unrolled sub-gathers, each matching
-                # slots whose source row falls in that 8-row sub-chunk.
+            # A slot's table row less its row class, once a block: slot
+            # row (sb * 8 + r8) holds a source of table row key + r8, so
+            # a sub-chunk hits it when key is the sub-chunk's first row.
+            key = row_pos - r8_iota
+            vregs = [slice(k * ROWS, (k + 1) * ROWS) for k in range(sub)]
+            idx_rows = [lane_idx[v, :] for v in vregs]
+            key_rows = [key[v, :] for v in vregs]
+
+            def chunk_of(j):
                 if dst_gate:
                     # Gated blocks walk the plain span; ungated blocks
                     # the compacted dirty list (clamped load: the list
                     # value is unused when gated).
                     lc = l_ref[jnp.minimum(j_lo + j, l_cap)]
-                    c = jnp.where(gated, c_lo + j, lc)
-                else:
-                    c = l_ref[j_lo + j]
-                base = c * group_rows
-                tab_g = table_ref[pl.ds(half + base, group_rows), :]
-                for s in range(group):
-                    sub_c = tab_g[s * ROWS : (s + 1) * ROWS, :]
-                    # Stack the 8-row sub-chunk `sub` times so slot row
-                    # (sb * 8 + r8) gathers from table row (base+8s+r8).
-                    tiled = (
-                        jnp.concatenate([sub_c] * sub, axis=0)
-                        if sub > 1
-                        else sub_c
-                    )
-                    g = jnp.take_along_axis(tiled, lane_idx, axis=1)
-                    hit = (row_pos - (base + s * ROWS)) == r8_iota
-                    acc = jnp.where(hit, g, acc)
-                return acc
+                    return jnp.where(gated, c_lo + j, lc)
+                return l_ref[j_lo + j]
 
-            words = jax.lax.fori_loop(
+            def walk(chunks, accs):
+                # Each chunk is a group_rows-row table group.  All the
+                # chunk ids are read and all the groups loaded before any
+                # gather (a loop with a dynamic trip count is a basic
+                # block a trip: what one trip holds is what the scheduler
+                # can overlap).  Then each of the block's `sub` slot vregs
+                # gathers its lanes out of every table vreg of the trip
+                # in a row (one lane pattern, `group` permutes a chunk)
+                # and keeps the word of the sub-chunk its source row
+                # falls in: exactly one sub-chunk of one chunk hits a
+                # slot, so the order of the selects does not matter.
+                bases = [c * group_rows for c in chunks]
+                tabs = [
+                    table_ref[pl.ds(half + base, group_rows), :]
+                    for base in bases
+                ]
+                accs = list(accs)
+                for k in range(sub):
+                    a = accs[k]
+                    for base, tab_g in zip(bases, tabs):
+                        for s in range(group):
+                            # lane_idx is ``emeta & 127``: in bounds
+                            g = jnp.take_along_axis(
+                                tab_g[s * ROWS : (s + 1) * ROWS, :],
+                                idx_rows[k], axis=1, mode="promise_in_bounds",
+                            )
+                            a = jnp.where(
+                                key_rows[k] == base + s * ROWS, g, a
+                            )
+                    accs[k] = a
+                return tuple(accs)
+
+            # Two chunks a trip; an odd count's first chunk in a loop of
+            # its own, of one trip (``active_blocks`` counts both's trips).
+            odd = n_iter & 1
+            accs = jax.lax.fori_loop(
                 0,
-                n_iter,
-                chunk_body,
-                jnp.zeros((block_rows, LANE), jnp.int32),
+                odd,
+                lambda _, accs: walk([chunk_of(0)], accs),
+                (jnp.zeros((ROWS, LANE), jnp.int32),) * sub,
             )
+            accs = jax.lax.fori_loop(
+                0,
+                n_iter >> 1,
+                lambda t, accs: walk(
+                    [chunk_of(odd + 2 * t), chunk_of(odd + 2 * t + 1)], accs
+                ),
+                accs,
+            )
+            words = jnp.concatenate(accs, axis=0) if sub > 1 else accs[0]
             bits = jax.lax.shift_right_logical(words, bit_pos) & 1
             # Sublane rows on the VPU first, then one lane reduce.
             any_bit = jnp.max(jnp.max(bits, axis=0, keepdims=True)) > 0
@@ -1488,19 +1546,20 @@ def build_propagate(
 
     def onto(plane, d, l, *operands):
         """(contributions, grid steps that had work, steps of them that
-        contracted, chunk-iterations the steps walked) of one launch over
-        the output buffer ``plane``, which it consumes.  The grid is as long as the list of active blocks,
-        and at least one step: a launch with nothing to do writes one zero
+        contracted, chunk-iterations the steps walked, loop trips they
+        walked them in) of one launch over the output buffer ``plane``,
+        which it consumes.  The grid is as long as the list of active
+        blocks, and at least one step: a launch with nothing to do writes one zero
         tile.  Tiles with no active block are never visited and keep what
         ``plane`` held; the first active block of a tile overwrites it."""
         gate = operands[0] if dst_gate else None
         bmeta1, bmeta2, tables, row_pos, emeta = operands[-5:]
-        act, count, walks = active_blocks(d, gate, bmeta1, bmeta2)
+        act, count, walks, trips = active_blocks(d, gate, bmeta1, bmeta2)
         out, contracted = launch(
             jnp.maximum(count, 1), d, l, *operands[:-3], act, plane, tables,
             row_pos, emeta,
         )
-        return out, count, contracted[0], walks
+        return out, count, contracted[0], walks, trips
 
     def with_steps(d, l, *operands):
         """``onto`` a zero plane: an unvisited tile contributes nothing."""
