@@ -472,6 +472,40 @@ def test_sanitizer_clean_single_system():
         kit.shutdown()
 
 
+@pytest.mark.parametrize("backend", ["decremental", "mesh-decremental"])
+def test_mutation_backend_keeps_garbage_alive(backend):
+    """Every trace is ``trace()``, and the mirror holds each one's verdict
+    to the oracle's, on the device backends too: marks that keep a
+    released actor alive (the seeded fault) are a ``verdict.mismatch`` on
+    that very trace, and the same system with its own marks was clean."""
+    from uigc_tpu.ops.trace import FLAG_IN_USE
+
+    kit = ActorTestKit(dict(BASE, **{"uigc.crgc.shadow-graph": backend}))
+    san = kit.system.sanitizer
+    try:
+        graph = kit.system.engine.bookkeeper.shadow_graph  # the mirror
+        owner = kit.spawn(Behaviors.setup_root(lambda c: Owner(c)), "owner")
+        for _ in range(20):
+            owner.tell(Ping())
+
+        def settle(predicate, timeout_s=60.0):  # the first wake compiles
+            deadline = time.monotonic() + timeout_s
+            while not predicate() and time.monotonic() < deadline:
+                time.sleep(0.05)
+
+        settle(lambda: san.checks > 0)
+        assert san.checks > 0 and graph.device_wakes >= san.checks
+        assert san.violations == [], san.report()
+        graph.compute_marks = lambda: (graph.flags & FLAG_IN_USE) != 0
+        owner.tell(Drop())
+        settle(lambda: san.by_rule("verdict.mismatch"))
+        violation = san.by_rule("verdict.mismatch")[0]
+        assert violation.payload["engine_garbage"] == 0
+        assert violation.payload["oracle_garbage"] >= 1
+    finally:
+        kit.shutdown()
+
+
 def test_sanitizer_tap_only_for_mac():
     kit = ActorTestKit(
         {"uigc.engine": "mac", "uigc.analysis.sanitizer": True}

@@ -108,6 +108,36 @@ def test_device_backend_name_is_refused():
         assert name in str(err.value)
 
 
+def test_the_pipelined_road_left_no_handle_and_its_key_is_no_key():
+    """The pipelined wake (gone with PR 48) left one road from a fold to
+    a sweep, ``trace()``: no backend and not the sanitizer's mirror has
+    a second, and ``uigc.crgc.pipelined`` is no key.  A configuration
+    that still sets it builds as before (overrides are not checked
+    against the defaults) and sets what nothing reads."""
+    from uigc_tpu.analysis.sanitizer import _MirrorGraph
+    from uigc_tpu.config import DEFAULTS, Config
+    from uigc_tpu.engines.crgc import mesh
+    from uigc_tpu.engines.crgc.arrays import ArrayShadowGraph
+
+    gone = (
+        "launch_trace", "harvest_trace", "harvest_ready", "expire_stalled_wake",
+        "can_pipeline", "has_pending_wake", "_pending_wake", "_start_wake",
+    )
+    for owner in (ArrayShadowGraph, mesh.MeshShadowGraph, _MirrorGraph):
+        assert [name for name in gone if name in dir(owner)] == []
+        assert "trace" in dir(owner)
+    assert not hasattr(mesh, "_MeshWakeHandle")
+    assert "uigc.crgc.pipelined" not in DEFAULTS
+    with pytest.raises(KeyError):
+        Config().get("uigc.crgc.pipelined")
+    kit = ActorTestKit({"uigc.crgc.pipelined": False, "uigc.crgc.shadow-graph": "decremental"})
+    try:
+        assert not hasattr(kit.system.engine, "pipelined")
+        assert kit.system.engine.bookkeeper.shadow_graph.use_device
+    finally:
+        kit.shutdown()
+
+
 class LoneRoot(AbstractBehavior):
     """A root that spawns workers, never releases them, then stops itself."""
 
@@ -157,88 +187,51 @@ def test_dead_root_does_not_leak_referents():
         kit.shutdown()
 
 
-def test_pipelined_decremental_collection():
-    """uigc.crgc.pipelined: the collector sweeps the previous wake's
-    verdicts while the next runs; cyclic garbage still collapses (a
-    consistent-snapshot verdict is never wrong — CRGC garbage is
-    monotone)."""
+@pytest.mark.parametrize("backend", ["decremental", "mesh-decremental"])
+def test_a_wakeup_with_nothing_folded_makes_no_device_call(backend):
+    """A collector wake has one shape, drain -> fold -> ``trace()``, and
+    the trace is skipped where nothing was folded since the last one: the
+    timer's wake-ups on a quiet system dispatch no wake and answer no
+    sink, and the first fold after them does both."""
+    import time
+
     kit = ActorTestKit(
-        {
-            "uigc.crgc.wakeup-interval": 10,
-            "uigc.crgc.shadow-graph": "decremental",
-            "uigc.crgc.pipelined": True,
-        }
+        {"uigc.crgc.wakeup-interval": 10, "uigc.crgc.shadow-graph": backend}
     )
     try:
-        probe = kit.create_test_probe(timeout_s=30.0)
-        root = kit.spawn(Behaviors.setup_root(lambda ctx: Root(ctx, probe)), "root")
-        probe.expect_message_type(Spawned)
-        probe.expect_message_type(Spawned)
-        probe.expect_no_message(0.2)
-        root.tell(Drop())
-        probe.expect_message_type(Stopped)
-        probe.expect_message_type(Stopped)
-    finally:
-        kit.shutdown()
-
-
-def test_pipelined_mesh_decremental_collection():
-    """uigc.crgc.pipelined + shadow-graph=mesh-decremental: the mesh
-    runs its OWN pipelined wake (launch syncs the shard layouts
-    mesh-natively, then dispatches the sharded decremental wake
-    asynchronously; the harvest sweeps the launch snapshot's verdicts).
-    Cyclic garbage still collapses, and the regression this guards: the
-    base-class path through the single-device tracer would have
-    desynced the shard layouts."""
-    kit = ActorTestKit(
-        {
-            "uigc.crgc.wakeup-interval": 10,
-            "uigc.crgc.shadow-graph": "mesh-decremental",
-            "uigc.crgc.pipelined": True,
-        }
-    )
-    try:
-        graph = kit.system.engine.bookkeeper.shadow_graph
-        assert graph.can_pipeline is True
+        keeper = kit.system.engine.bookkeeper
+        graph = keeper.shadow_graph
+        answers, wakeups = [], []
+        graph.foreign_sink = lambda kills, freed: answers.append(1)
+        collect = keeper.collect
+        keeper.collect = lambda trace=True: wakeups.append(trace) or collect(trace)
         probe = kit.create_test_probe(timeout_s=60.0)
         root = kit.spawn(Behaviors.setup_root(lambda ctx: Root(ctx, probe)), "root")
         probe.expect_message_type(Spawned)
         probe.expect_message_type(Spawned)
+
+        def quiet():
+            # the last flush is folded and traced: no entry for 10 wake-ups
+            seen, folded = len(wakeups), keeper.total_entries
+            time.sleep(0.15)
+            return len(wakeups) >= seen + 10 and keeper.total_entries == folded
+
+        assert any(quiet() for _ in range(40)), "the system never went quiet"
+        assert not keeper._graph_dirty
+        seen = len(wakeups)
+        wakes, calls, folded = graph.device_wakes, len(answers), keeper.total_entries
+        assert wakes >= 1 and calls == wakes  # one answer a trace
+        time.sleep(0.3)
+        assert len(wakeups) >= seen + 10 and all(wakeups[seen:])
+        assert keeper.total_entries == folded
+        assert (graph.device_wakes, len(answers)) == (wakes, calls)
         root.tell(Drop())
         probe.expect_message_type(Stopped)
         probe.expect_message_type(Stopped)
+        assert graph.device_wakes > wakes
+        deadline = time.monotonic() + 10  # the sink hears after the StopMsgs
+        while len(answers) == calls and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert len(answers) > calls
     finally:
         kit.shutdown()
-
-
-def test_pipelined_stalled_wake_expires():
-    """A wake whose device result never lands must expire (tracer
-    invalidated, pipeline freed) instead of deadlocking collection."""
-    import time
-
-    from uigc_tpu.engines.crgc.arrays import ArrayShadowGraph
-    from uigc_tpu.engines.crgc.state import CrgcContext
-
-    graph = ArrayShadowGraph(
-        CrgcContext(delta_graph_size=64, entry_field_size=4),
-        "uigc://test",
-        use_device=True,
-    )
-
-    class NeverReady:
-        def is_ready(self):
-            return False
-
-    class FakeDec:
-        invalidated = False
-
-        def invalidate(self):
-            self.invalidated = True
-
-    dec = FakeDec()
-    graph._pending_wake = (dec, NeverReady(), None, None, time.monotonic() - 60)
-    assert not graph.harvest_ready()
-    assert not graph.expire_stalled_wake(max_age_s=120)  # too young
-    assert graph.has_pending_wake
-    assert graph.expire_stalled_wake(max_age_s=30)
-    assert dec.invalidated and not graph.has_pending_wake

@@ -144,37 +144,13 @@ def random_actor_factory(shared):
 import pytest
 
 
-@pytest.mark.parametrize(
-    "backend,pipelined",
-    [
-        ("array", False),
-        ("decremental", False),
-        ("decremental", True),
-        ("mesh-decremental", True),
-    ],
-    ids=[
-        "array",
-        "decremental",
-        "decremental-pipelined",
-        "mesh-decremental-pipelined",
-    ],
-)
-def test_random_churn_fully_collected(backend, pipelined):
+@pytest.mark.parametrize("backend", ["array", "decremental", "mesh-decremental"])
+def test_random_churn_fully_collected(backend):
     """Unsound GC kills live actors; incomplete GC times out.  The
-    decremental variant must detect every released subgraph (incl.
-    cycles) by regional repair, never by luck of a full re-trace; the
-    pipelined variant additionally sweeps snapshot verdicts while the
-    next wake runs."""
+    decremental variants must detect every released subgraph (incl.
+    cycles) by regional repair, never by luck of a full re-trace."""
     shared = Shared()
-    kit = ActorTestKit(
-        dict(
-            CONFIG,
-            **{
-                "uigc.crgc.shadow-graph": backend,
-                "uigc.crgc.pipelined": pipelined,
-            },
-        )
-    )
+    kit = ActorTestKit(dict(CONFIG, **{"uigc.crgc.shadow-graph": backend}))
     try:
         def make_root(timers):
             def setup(ctx):

@@ -62,11 +62,11 @@ def fold_foreign(g, rows):
 
 
 def wake(g):
-    """One synchronous wake, its verdicts kept: ``(garbage words, marks,
+    """One wake, its verdicts kept: ``(garbage words, marks,
     garbage actors, upload_bytes)``."""
     verdicts = g.compute_marks()
     words, live = verdicts.garbage_w.copy(), verdicts.num_live
-    n_garbage, _ = g._sweep(True, g.flags, g.supervisor, verdicts)
+    n_garbage, _ = g._sweep(True, verdicts)
     return words, live, n_garbage, g.profile_wake.fields["upload_bytes"]
 
 
@@ -364,39 +364,6 @@ def test_the_next_wakes_verdicts_are_right(doubt):
     release(g, range(200, 210))
     assert wake(g)[2:] == (10, arrays._patch_pad(11 + n_garbage) * SLOT_BYTES)
     assert_copies_are_the_hosts(g)
-
-
-# --------------------------------------------------------------------- #
-# (f) the pipelined road takes the same operands
-# --------------------------------------------------------------------- #
-
-
-@pytest.mark.parametrize("first", ["trace", "launch"])
-def test_the_pipelined_road_patches_the_same_copies(first):
-    piped, twin = new_graph(2048), new_graph(2048)
-    for g in (piped, twin):
-        star(g, 1000)
-    if first == "trace":
-        wake(piped)
-    else:  # the copies are established by a launch
-        piped.launch_trace()
-        assert piped.harvest_trace(True) == 0
-    wake(twin)
-    for round_ in range(3):
-        for g in (piped, twin):
-            release(g, range(10 + 40 * round_, 40 + 40 * round_))
-            fold_foreign(g, [row(900 + round_, recv=3)])
-        held = piped._resident
-        piped.launch_trace()
-        assert piped._resident is not held and held[0].is_deleted()  # patched, donated
-        assert_copies_are_the_hosts(piped)
-        piped._pending_wake[1].block_until_ready()
-        fold_foreign(piped, [row(950 + round_, busy=True)])  # lands between launch and harvest
-        fold_foreign(twin, [row(950 + round_, busy=True)])
-        assert piped.harvest_trace(True) == wake(twin)[2] == 30
-        assert np.array_equal(piped.flags, twin.flags)
-    assert wake(piped)[3] == wake(twin)[3] == arrays._patch_pad(31) * SLOT_BYTES
-    assert_copies_are_the_hosts(piped)
 
 
 # --------------------------------------------------------------------- #

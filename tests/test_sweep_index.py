@@ -8,7 +8,8 @@ slots are the nonzeros of ``trace_ops.garbage_and_kills_np`` over the
 oracle's marks.  One driver mutates an ``ArrayShadowGraph`` at the slot
 level (the graph's own mutators, so the pair log and the index hear of
 everything) and is run over the host backend, the decremental wake
-(interpreted here), the pipelined harvest and a graph of foreign uids.
+(interpreted here), the mesh's decremental wake (dense verdicts through
+the same sweep) and a graph of foreign uids.
 """
 
 from __future__ import annotations
@@ -18,7 +19,7 @@ from contextlib import nullcontext
 import numpy as np
 import pytest
 
-from uigc_tpu.engines.crgc import arrays
+from uigc_tpu.engines.crgc import arrays, mesh
 from uigc_tpu.engines.crgc.arrays import ArrayShadowGraph, PackedVerdicts
 from uigc_tpu.engines.crgc.packed import FOREIGN_BIT
 from uigc_tpu.engines.crgc.state import CrgcContext
@@ -77,11 +78,13 @@ class Rig:
         self.rng = np.random.default_rng([seed, len(case)])
         self.foreign = case == "foreign"
         ctx = CrgcContext(delta_graph_size=64, entry_field_size=4)
-        self.graph = g = ArrayShadowGraph(
-            ctx, FakeSystem.address,
-            use_device=case in ("decremental", "pipelined"),
-            initial_capacity=64,
-        )
+        if case == "mesh-decremental":
+            self.graph = g = mesh.MeshShadowGraph(
+                ctx, FakeSystem.address, initial_capacity=64, decremental=True)
+        else:
+            self.graph = g = ArrayShadowGraph(
+                ctx, FakeSystem.address,
+                use_device=case == "decremental", initial_capacity=64)
         g._endpoints.overlay_bound = 8  # seals and merges at this size
         self.wake = g.profile_wake = FakeWake()
         self.answers = []
@@ -160,25 +163,12 @@ class Rig:
 
     # -- one wake -------------------------------------------------------- #
 
-    def expect(self, flags, recv, sup, src, dst, w):
-        """The dense oracle over one state: marks, garbage, kill."""
-        mark = F.trace_marks_np(flags, recv, sup, src, dst, w)
-        garbage, kill = F.garbage_and_kills_np(flags, sup, mark)
-        return mark, garbage, kill
-
-    def sweep_and_check(self, between=None):
+    def sweep_and_check(self):
         g = self.graph
-        mark, garbage, kill = self.expect(
+        # the dense oracle over the state the trace is about to read
+        mark = F.trace_marks_np(
             g.flags, g.recv_count, g.supervisor, g.edge_src, g.edge_dst, g.edge_weight)
-        if self.case == "pipelined":
-            g.launch_trace()
-            # the CPU backend's device_put may alias the host's flags:
-            # let the wake finish before they change under it
-            g._pending_wake[1].block_until_ready()
-            if between is not None:
-                between(np.flatnonzero(mark))  # folds land between launch and harvest
-            pad = np.zeros(g.capacity - garbage.size, bool)
-            garbage, kill = np.concatenate([garbage, pad]), np.concatenate([kill, pad])
+        garbage, kill = F.garbage_and_kills_np(g.flags, g.supervisor, mark)
         w = g.edge_weight.copy()
         src, dst = g.edge_src.copy(), g.edge_dst.copy()
         want = np.nonzero((w != 0) & (garbage[src] | garbage[dst]))[0]
@@ -193,10 +183,7 @@ class Rig:
         self.swept.clear()
         self.answers.clear()
 
-        if self.case == "pipelined":
-            n = g.harvest_trace(should_kill=True)
-        else:
-            n = g.trace(should_kill=True)
+        n = g.trace(should_kill=True)
 
         garbage_slots, kill_slots = np.nonzero(garbage)[0], np.nonzero(kill)[0]
         assert n == garbage_slots.size
@@ -228,9 +215,8 @@ class Rig:
         return garbage_slots.size
 
 
-@pytest.mark.parametrize("seed", [0, 1])
-@pytest.mark.parametrize("case", ["array", "decremental", "pipelined", "foreign"])
-def test_sweep_frees_what_the_scan_and_the_dense_oracle_name(case, seed, monkeypatch):
+def counting_rig(case, seed, monkeypatch):
+    """A rig whose index queries and overlay drops are counted."""
     # graphs of a few hundred slots: the regimes' border moved to where
     # a round's trickle takes the index and a cluster's death the scan
     monkeypatch.setattr(arrays, "_SCAN_SHARE", 8)
@@ -248,7 +234,14 @@ def test_sweep_frees_what_the_scan_and_the_dense_oracle_name(case, seed, monkeyp
 
     monkeypatch.setattr(EndpointIndex, "incident", counted)
     monkeypatch.setattr(EndpointIndex, "_drop", counted_drop)
-    g, rng = rig.graph, rig.rng
+    return rig
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("case", ["array", "decremental", "mesh-decremental", "foreign"])
+def test_sweep_frees_what_the_scan_and_the_dense_oracle_name(case, seed, monkeypatch):
+    rig = counting_rig(case, seed, monkeypatch)
+    g = rig.graph
     # a root that holds nothing live but what plant_...() hangs on it,
     # so that it survives the mass death and little else does
     rig.keeper = int(rig.spawn(1, flags=INTERNED | LOCAL | ROOT)[0])
@@ -267,13 +260,7 @@ def test_sweep_frees_what_the_scan_and_the_dense_oracle_name(case, seed, monkeyp
             # a mass death: every root but the keeper lets go
             g.flags[among] &= np.uint8(0xFF & ~(ROOT | BUSY))
             g._touch_batch(among)
-
-        def between(live):
-            live = live[live != rig.keeper]
-            kids = rig.spawn(int(rng.integers(1, 80)), sup=int(live[0]))
-            rig.deltas(np.full(kids.size, live[0]), kids, np.ones(kids.size, np.int64))
-
-        died = rig.sweep_and_check(between)
+        died = rig.sweep_and_check()
         caps.add((g.capacity, g.edge_capacity))
         among = rig.in_use()
         assert rig.keeper in among
@@ -287,6 +274,65 @@ def test_sweep_frees_what_the_scan_and_the_dense_oracle_name(case, seed, monkeyp
     assert seen["reused_other_source"] and seen["kills"], seen
     assert seen["runs_max"] >= 2 and seen["dropped"], seen
     assert len({c for c, _ in caps}) > 1 and len({e for _, e in caps}) > 1, caps
+
+
+def invalidated(g, monkeypatch):
+    g.invalidate_wake_state()
+
+
+def readback_raises(g, monkeypatch):
+    """The wake's result never lands: the readback raises, after the
+    dispatch committed the wake's state and cleared its suspects."""
+
+    def poisoned(array, site):
+        raise RuntimeError("transport died")
+
+    with monkeypatch.context() as patch:
+        patch.setattr(mesh, "_readback", poisoned)
+        with pytest.raises(RuntimeError, match="transport died"):
+            g.trace(should_kill=True)
+
+
+@pytest.mark.parametrize("doubt", [invalidated, readback_raises])
+def test_the_mesh_derives_from_nothing_after_a_wake_state_it_dropped(doubt, monkeypatch):
+    """The mesh's one wake, ``_compute_marks_decremental``, dispatches
+    and reads back in place: a readback that raises drops the state the
+    dispatch committed, as ``invalidate_wake_state()`` does, and the next
+    wake's verdicts are the dense oracle's over deletions whose suspects
+    went with it."""
+    rig = counting_rig("mesh-decremental", 2, monkeypatch)
+    g = rig.graph
+    rig.keeper = int(rig.spawn(1, flags=INTERNED | LOCAL | ROOT)[0])
+    among = rig.spawn(4, flags=INTERNED | LOCAL | ROOT)
+    # room for the whole script: a growth would rebuild, and drop the
+    # state by itself
+    kids = rig.spawn(400, sup=rig.keeper)
+    rig.deltas(np.full(400, rig.keeper), kids, np.ones(400, np.int64))
+    for _ in range(3):
+        rig.churn(among)
+        rig.sweep_and_check()
+        among = rig.in_use()
+        among = among[(among != rig.keeper) & ~np.isin(among, kids)]
+    assert g._wake_state is not None and g.stats["wakes"] == 3
+    rebuilds = g.stats["rebuilds"]
+    among = rig.churn(among)
+    rig.plant_garbage_held_from_live()
+    roots = among[(g.flags[among] & np.uint8(ROOT)) != 0]
+    g.flags[roots[:2]] &= np.uint8(0xFF & ~(ROOT | BUSY))  # and two roots let go
+    g._touch_batch(roots[:2])
+    g._sync_device()
+    assert g._pending_del_dst  # suspects a repair would have started from
+    doubt(g, monkeypatch)
+    assert g._wake_state is None
+    assert not g._pending_del_dst and not g._pending_fresh_dst
+    assert rig.sweep_and_check() >= 2
+    assert g._wake_state is not None
+    for _ in range(2):  # and repairs go on from the new fixpoint
+        among = rig.in_use()
+        rig.churn(among[(among != rig.keeper) & ~np.isin(among, kids)])
+        rig.sweep_and_check()
+    assert g.stats == {"rebuilds": rebuilds, "wakes": 6 + (doubt is readback_raises),
+                       "anomalies": 0}
 
 
 def test_verdict_words_equal_unpack_marks_off_the_word_grid():
@@ -318,16 +364,11 @@ def test_verdict_words_equal_unpack_marks_off_the_word_grid():
     assert marked == np.count_nonzero(mark)
     bits = np.unpackbits(garbage_w.view(np.uint8), bitorder="little")
     assert np.array_equal(bits[:n].astype(bool), garbage) and not bits[n:].any()
-    g, k, live = ArrayShadowGraph._verdict_slots(flags, sup, PackedVerdicts(garbage_w, marked))
+    graph = ArrayShadowGraph(CrgcContext(delta_graph_size=64, entry_field_size=4))
+    graph.flags, graph.supervisor = flags, sup  # what the device read
+    g, k, live = graph._verdict_slots(PackedVerdicts(garbage_w, marked))
     assert np.array_equal(g, np.nonzero(garbage)[0])
     assert np.array_equal(k, np.nonzero(kill)[0]) and live == marked
-    # words read from flags a later fold has since changed under the
-    # device (a pipelined launch where device_put aliases the host's
-    # memory): a slot the GIVEN flags do not hold in use is no garbage
-    stale = flags.copy()
-    stale[n - 1] = 0
-    g, k, _ = ArrayShadowGraph._verdict_slots(stale, sup, PackedVerdicts(garbage_w, marked))
-    assert np.array_equal(g, np.nonzero(garbage)[0][:-1]) and n - 1 not in k
     tracer.invalidate()
     with pytest.raises(Exception, match="no longer the tracer's last"):
         tracer.verdict_words(mark_w)

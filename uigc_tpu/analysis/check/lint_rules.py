@@ -71,9 +71,6 @@ _ENGINE_MUTATORS = {
     "merge_delta",
     "merge_undo_log",
     "trace",
-    "harvest_trace",
-    "launch_trace",
-    "expire_stalled_wake",
     "start_wave",
     "tell",
     "tell_bulk",

@@ -190,9 +190,8 @@ class _Tap(EngineTap):
 class _MirrorGraph:
     """Wraps the collector's shadow graph: forwards every call to the
     real backend, folds the same facts into the sanitizer's oracle, and
-    cross-checks each trace's verdict.  Unwrapped attributes (pipelined
-    wake control, diagnostics, packed-plane wiring) pass straight
-    through."""
+    cross-checks each trace's verdict.  Unwrapped attributes
+    (diagnostics, packed-plane wiring) pass straight through."""
 
     def __init__(self, real: Any, san: "Sanitizer"):
         # Instance dict bypass: __setattr__ below guards forwarding.
@@ -249,15 +248,7 @@ class _MirrorGraph:
 
     def trace(self, should_kill: bool) -> int:
         n = self._real.trace(should_kill)
-        self._san._check_trace(n, compare=True)
-        return n
-
-    def harvest_trace(self, should_kill: bool) -> int:
-        # Pipelined verdicts were computed from an earlier snapshot; the
-        # oracle holds newer facts, so count equality is not expected —
-        # fold-side checks still ran, and the oracle is compacted here.
-        n = self._real.harvest_trace(should_kill)
-        self._san._check_trace(n, compare=False)
+        self._san._check_trace(n)
         return n
 
 
@@ -603,7 +594,7 @@ class Sanitizer:
 
     # -- verdict cross-check (collector thread) ------------------------ #
 
-    def _check_trace(self, n_real: int, compare: bool) -> None:
+    def _check_trace(self, n_real: int) -> None:
         with self._lock:
             self._reach_cache = None  # the trace compacts the oracle
             # Muted: the oracle re-runs the instrumented trace pipeline;
@@ -625,7 +616,7 @@ class Sanitizer:
             n_garbage=n_real,
             oracle_garbage=n_oracle,
         )
-        if compare and n_oracle != n_real:
+        if n_oracle != n_real:
             self.record(
                 "verdict.mismatch",
                 "engine and oracle disagree on a collection verdict",

@@ -74,14 +74,6 @@ DEFAULTS: Dict[str, Any] = {
     # "auto" turns the pull gates on for a sweep; tuned from
     # tools/sweep_profile.py per-sweep decompositions.
     "uigc.crgc.pull-density": 0.25,
-    # Pipelined collection: the collector dispatches the device wake
-    # asynchronously and sweeps the PREVIOUS wake's verdicts while the
-    # current one runs, overlapping host ingest with the device trace
-    # (SURVEY §7 hard parts).  Sound because CRGC garbage is monotone —
-    # a consistent-snapshot verdict never kills a live actor.  The
-    # decremental and mesh-decremental backends support it; others
-    # ignore the flag.
-    "uigc.crgc.pipelined": False,
     # Distributed (partitioned) collection across cluster nodes
     # (engines/crgc/distributed.py): each node owns only the
     # shadow-graph slice for the partitions the rendezvous map assigns

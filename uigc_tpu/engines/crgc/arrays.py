@@ -274,7 +274,7 @@ class ArrayShadowGraph:
         #: tier).  None until a device wake ran; a caller that needs the
         #: chip asserts on it rather than trusting the platform default.
         self.trace_impl: Optional[str] = None
-        #: device wakes dispatched (synchronous + pipelined)
+        #: device wakes dispatched
         self.device_wakes = 0
         self.total_actors_seen = 0
 
@@ -1247,7 +1247,10 @@ class ArrayShadowGraph:
                 # reads the record pays for their way to the host
                 wake.defer(self._read_sweep_stats, dec.last_counters())
             with events.wake_phase(wake, "readback"):
-                return self._read_verdicts(dec, mark_w, "marks.decremental")
+                garbage_w, marked = dec.verdict_words(mark_w)
+                return PackedVerdicts(
+                    _readback(garbage_w, "marks.decremental"), marked
+                )
         except Exception:
             # A poisoned async result surfaces at the wait or at the
             # readback, after the tracer committed state; drop it so the
@@ -1269,8 +1272,8 @@ class ArrayShadowGraph:
 
     def _node_operands(self) -> tuple:
         """``(flags_dev, recv_dev, upload_bytes)``: the device's
-        ``flags`` and ``recv_count`` brought up to the host's, for both
-        roads to ``wake_device``.  The copies stay on the device from
+        ``flags`` and ``recv_count`` brought up to the host's, for
+        ``wake_device``.  The copies stay on the device from
         wake to wake (``_resident``) and take, in one donating scatter,
         the current values of the slots logged since (``_node_log``),
         narrowed as ``device_put`` narrows the whole array, so the
@@ -1329,43 +1332,12 @@ class ArrayShadowGraph:
             kp *= 2
         return resident
 
-    @staticmethod
-    def _read_verdicts(dec, mark_w, site: str) -> PackedVerdicts:
-        """The verdict words of ``dec``'s last wake on the host, the
-        crossing accounted at ``site``."""
-        garbage_w, marked = dec.verdict_words(mark_w)
-        return PackedVerdicts(_readback(garbage_w, site), marked)
-
-    # ------------------------------------------------------------- #
-    # Pipelined collection (SURVEY §7 "hard parts": the 50ms cadence
-    # can't meet a 10ms detection budget without overlapping host
-    # ingest and the device trace).  launch_trace() snapshots the node
-    # features and dispatches the device wake asynchronously;
-    # harvest_trace() later sweeps with the SNAPSHOT verdicts.  Sound
-    # because CRGC garbage is monotone: an actor unreachable and
-    # quiescent at any consistent snapshot can never be resurrected
-    # (only garbage held references to it), so acting on a stale
-    # verdict kills nothing live — and slots are freed only by the
-    # harvest itself, so the snapshot's slot bindings still hold.
-    # ------------------------------------------------------------- #
-
-    _pending_wake = None
-
-    @property
-    def can_pipeline(self) -> bool:
-        return self.use_device
-
-    @property
-    def has_pending_wake(self) -> bool:
-        return self._pending_wake is not None
-
     def _synced_dec(self):
-        """The decremental tracer, synced with the pair log (the one
-        construction site for both the synchronous and pipelined
-        paths): (re)built on a missing tracer, a geometry change or a
-        log overflow (``_pair_log is None``); otherwise the log is
-        folded in O(changes), and the layout repacked when accumulated
-        churn crosses its threshold."""
+        """The decremental tracer, synced with the pair log (its one
+        construction site): (re)built on a missing tracer, a geometry
+        change or a log overflow (``_pair_log is None``); otherwise the
+        log is folded in O(changes), and the layout repacked when
+        accumulated churn crosses its threshold."""
         from ...ops import pallas_decremental
 
         dec = self._dec
@@ -1395,129 +1367,30 @@ class ArrayShadowGraph:
         self._dec = dec
         return dec
 
-    def _start_wake(self) -> tuple:
-        """Dispatch one asynchronous wake; returns ``(handle,
-        mark_dev)`` where the handle provides ``unpack_marks`` /
-        ``invalidate`` (the contract harvest_trace and
-        expire_stalled_wake consume).  Overridable: the mesh backend
-        dispatches its sharded wake here, while the snapshot and
-        bookkeeping stay in :meth:`launch_trace` — one home for the
-        pending-wake tuple layout."""
-        dec = self._synced_dec()
-        try:
-            flags_dev, recv_dev, _ = self._node_operands()
-            return dec, dec.wake_device(flags_dev, recv_dev)
-        except Exception:
-            self._drop_resident()
-            raise
-
-    def launch_trace(self) -> None:
-        """Dispatch the device wake without waiting for its result.
-        No-op while a wake is already in flight."""
-        import time
-
-        if self._pending_wake is not None:
-            return
-        self._note_device_wake()
-        handle, mark_dev = self._start_wake()
-        self._pending_wake = (
-            handle,
-            mark_dev,
-            self.flags.copy(),
-            self.supervisor.copy(),
-            time.monotonic(),
-        )
-
-    def harvest_ready(self) -> bool:
-        if self._pending_wake is None:
-            return False
-        mark_w = self._pending_wake[1]
-        is_ready = getattr(mark_w, "is_ready", None)
-        return bool(is_ready()) if is_ready is not None else True
-
-    def expire_stalled_wake(self, max_age_s: float) -> bool:
-        """A wake whose device result never lands (wedged transport)
-        must not deadlock the pipeline: past ``max_age_s`` the pending
-        wake is abandoned and the tracer invalidated, so the next wake
-        is a clean full re-derivation.  Returns True if expired."""
-        import time
-
-        if self._pending_wake is None:
-            return False
-        dec, _, _, _, t0 = self._pending_wake
-        if time.monotonic() - t0 < max_age_s:
-            return False
-        self._pending_wake = None
-        dec.invalidate()
-        self._drop_resident()
-        return True
-
-    def harvest_trace(self, should_kill: bool) -> int:
-        """Sweep with the pending wake's verdicts against its snapshot.
-        Returns the number of garbage actors (0 if nothing pending)."""
-        if self._pending_wake is None:
-            return 0
-        dec, mark_w, snap_flags, snap_sup, _ = self._pending_wake
-        self._pending_wake = None
-        with events.recorder.timed(events.TRACING) as ev:
-            # the handle invalidates itself where the readback fails, so
-            # a poisoned wake needs no handling here
-            if getattr(dec, "accounts_readback", False):
-                # The mesh wake handle: a dense vector, whose crossing
-                # it already routed through _readback under its
-                # collective lock — accounting it again here would
-                # double-count every harvested wake's transfer bytes.
-                verdicts = np.asarray(dec.unpack_marks(mark_w))  # readback: accounted in the handle
-            else:
-                try:
-                    verdicts = self._read_verdicts(dec, mark_w, "marks.harvest")
-                except Exception:
-                    self._drop_resident()  # patched on the stream that failed
-                    raise
-            # Slots beyond the snapshot were interned after it: they
-            # carry no verdict, and none of them is among these ids.
-            n_garbage, n_live = self._sweep(
-                should_kill, snap_flags, snap_sup, verdicts
-            )
-            ev.fields["num_garbage_actors"] = n_garbage
-            ev.fields["num_live_actors"] = n_live
-        return n_garbage
-
     def trace(self, should_kill: bool) -> int:
-        # A synchronous trace sweeps against CURRENT state; an
-        # unharvested pipelined wake would later sweep a snapshot whose
-        # slot bindings this sweep is about to invalidate (freed or
-        # re-interned slots) — discard it.  Nothing is lost: the fresh
-        # verdicts computed here are a superset of the snapshot's
-        # (garbage is monotone).
-        self._pending_wake = None
         with events.recorder.timed(events.TRACING) as ev:
             if self.capture_parents:
                 verdicts = self._compute_marks_with_parents()
             else:
                 verdicts = self.compute_marks()
-            n_garbage, n_live = self._sweep(
-                should_kill, self.flags, self.supervisor, verdicts
-            )
+            n_garbage, n_live = self._sweep(should_kill, verdicts)
             ev.fields["num_garbage_actors"] = n_garbage
             ev.fields["num_live_actors"] = n_live
         return n_garbage
 
-    @staticmethod
-    def _verdict_slots(
-        flags: np.ndarray, supervisor: np.ndarray, verdicts
-    ) -> Tuple[np.ndarray, np.ndarray, int]:
+    def _verdict_slots(self, verdicts) -> Tuple[np.ndarray, np.ndarray, int]:
         """``(garbage_slots, kill_slots, num_live)`` of a trace's
-        verdicts over the ``flags`` and ``supervisor`` it was taken
-        over, both ascending: the nonzeros of
+        verdicts over the graph's ``flags`` and ``supervisor``, which
+        the trace read, both ascending: the nonzeros of
         ``trace_ops.garbage_and_kills_np``.  A dense mark vector goes
         through that function; :class:`PackedVerdicts` never become a
         vector: the few nonzero words are expanded to slot ids and the
         kill rule (local, not halted, supervisor marked) is applied to
-        those slots alone.  A slot is garbage iff its bit is set and
-        ``flags`` has it in use; a supervisor is marked iff it is in use
-        and its garbage bit is clear, since marks never leave the
-        in-use set."""
+        those slots alone.  A slot is garbage iff its bit is set (the
+        device cleared the bits of slots not in use); a supervisor is
+        marked iff it is in use and its garbage bit is clear, since
+        marks never leave the in-use set."""
+        flags, supervisor = self.flags, self.supervisor
         if not isinstance(verdicts, PackedVerdicts):
             garbage, kill = trace_ops.garbage_and_kills_np(
                 flags, supervisor, verdicts
@@ -1534,11 +1407,6 @@ class ArrayShadowGraph:
         )  # little-endian words: byte order is bit order
         hit = np.flatnonzero(bits)
         g = (at[hit >> 5] << 5) | (hit & 31)
-        # in use by the flags GIVEN, as the dense rule has it: the words
-        # are of the flags as the device read them, and a backend whose
-        # device_put aliases host memory (the CPU's) may have read slots
-        # a fold interned after a pipelined launch's snapshot
-        g = g[(flags[g] & _F.FLAG_IN_USE) != 0]
         f = flags[g]
         sup = supervisor[g].astype(np.int64)
         has_sup = sup >= 0
@@ -1553,28 +1421,18 @@ class ArrayShadowGraph:
         )
         return g, g[kill], verdicts.num_live
 
-    def _sweep(
-        self,
-        should_kill: bool,
-        flags: np.ndarray,
-        supervisor: np.ndarray,
-        verdicts,
-    ) -> Tuple[int, int]:
-        """Act on a trace's verdicts, taken over ``flags`` and
-        ``supervisor`` (the graph's own, or a pipelined wake's
-        snapshot): stop the kill set, free every garbage slot, hand the
-        foreign uids among both to the sink, and give the active wake's
-        record the counts.  The one sweep of every backend; its own
-        profiler phase and timed event, so the trace stays exclusive of
-        it.  The sink is called once per trace, also with nothing to
-        hand over: to the mutator side that is the verdict on what it
-        shipped before this wake.  Returns ``(garbage actors, live
-        actors)``."""
+    def _sweep(self, should_kill: bool, verdicts) -> Tuple[int, int]:
+        """Act on a trace's verdicts: stop the kill set, free every
+        garbage slot, hand the foreign uids among both to the sink, and
+        give the active wake's record the counts.  The one sweep of
+        every backend; its own profiler phase and timed event, so the
+        trace stays exclusive of it.  The sink is called once per
+        trace, also with nothing to hand over: to the mutator side that
+        is the verdict on what it shipped before this wake.  Returns
+        ``(garbage actors, live actors)``."""
         wake = self.profile_wake
         with events.wake_phase(wake, "sweep"), events.recorder.timed(events.SWEEP):
-            garbage_slots, kill_slots, n_live = self._verdict_slots(
-                flags, supervisor, verdicts
-            )
+            garbage_slots, kill_slots, n_live = self._verdict_slots(verdicts)
             kill_uids = freed_uids = _NO_UIDS
             examined = 0
             if wake is not None and garbage_slots.size:

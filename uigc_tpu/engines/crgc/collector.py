@@ -528,23 +528,7 @@ class Bookkeeper(RawBehavior):
         if not trace:
             return count, 0
         with _phase(wake, "trace"):
-            if self.engine.pipelined and getattr(graph, "can_pipeline", False):
-                # Pipelined: sweep the previous wake's verdicts (if its
-                # device result landed), then dispatch the next wake and
-                # return — the device traces while the mutators keep
-                # folding (SURVEY §7; sound because CRGC garbage is
-                # monotone, see ArrayShadowGraph.launch_trace).  A wake
-                # whose result never lands is expired so a transport outage
-                # cannot deadlock collection forever.
-                n_garbage = 0
-                if graph.harvest_ready():
-                    n_garbage = graph.harvest_trace(should_kill=True)
-                else:
-                    graph.expire_stalled_wake(
-                        max(30.0, self.engine.wakeup_interval_ms / 1000.0 * 20)
-                    )
-                graph.launch_trace()
-            elif self._graph_dirty:
+            if self._graph_dirty:
                 # Cleared before the trace: kills the sweep triggers
                 # re-dirty through their death-flush entries (and
                 # _after_wake re-wakes on progress), so cascades still

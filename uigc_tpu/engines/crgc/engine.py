@@ -65,7 +65,6 @@ class CRGC(Engine):
             "uigc.crgc.egress-finalize-interval"
         )
         self.shadow_graph_impl = config.get_string("uigc.crgc.shadow-graph")
-        self.pipelined = config.get_bool("uigc.crgc.pipelined")
         # Distributed (partitioned) collection: each node owns only its
         # shadow-graph slice and cross-node cycles resolve via the
         # dmark wave protocol (engines/crgc/distributed.py).  Only

@@ -61,9 +61,7 @@ _SINK_PAD = 64  # scatter batches are padded to multiples of this
 #: Only the collective-bearing programs (the sharded trace and the
 #: decremental wake) need the lock; _sync_device's scatters and folds
 #: are per-shard local work with no rendezvous, so they run outside it.
-#: Reentrant: the synchronous decremental path dispatches AND reads
-#: back under one compute_marks hold.
-_MESH_COLLECTIVE_LOCK = threading.RLock()
+_MESH_COLLECTIVE_LOCK = threading.Lock()
 
 #: Traced collective programs shared across graphs: every system in a
 #: process meshes the same devices, so graphs with identical geometry
@@ -167,29 +165,6 @@ class MeshShadowGraph(ArrayShadowGraph):
         self._pending_fresh_dst: set = set()
 
         self._jit_cache: Dict[str, object] = {}
-
-    @property
-    def can_pipeline(self) -> bool:
-        # The mesh pipelined wake overlaps host ingest with the SHARDED
-        # decremental wake: launch_trace syncs the shard layouts
-        # mesh-natively (the base-class path would have routed through
-        # the single-device tracer and desynced them) and dispatches
-        # the wake asynchronously; the base class's harvest machinery
-        # sweeps the snapshot verdicts through _MeshWakeHandle.
-        return self.decremental
-
-    def _start_wake(self) -> tuple:
-        """Dispatch the sharded decremental wake asynchronously (the
-        base launch_trace keeps the snapshot bookkeeping).  The shard
-        layouts sync mesh-natively first; state commits at dispatch
-        (like DecrementalTracer.wake_device), so a pending wake
-        discarded by a synchronous trace loses nothing."""
-        with self._device_call():
-            self._sync_device()
-            self.stats["wakes"] += 1
-            with _MESH_COLLECTIVE_LOCK:
-                out = self._dispatch_decremental_wake(self._layout_meta)
-        return _MeshWakeHandle(self), out[0]
 
     def _shared_program(self, tag: str, meta, factory):
         """Process-wide cache of the traced collective programs, keyed
@@ -731,10 +706,16 @@ class MeshShadowGraph(ArrayShadowGraph):
         return out
 
     def _compute_marks_decremental(self, meta) -> np.ndarray:
-        # same readback + poisoned-result recovery as the pipelined path
-        return _MeshWakeHandle(self).unpack_marks(
-            self._dispatch_decremental_wake(meta)[0]
-        )
+        """One wake's dense marks, dispatched and read back under the
+        caller's hold of the collective lock.  The wake's state was
+        committed at dispatch, so a poisoned result, which surfaces at
+        the readback, drops it: the next wake derives from nothing."""
+        mark_dev = self._dispatch_decremental_wake(meta)[0]
+        try:
+            return _readback(mark_dev, "marks.mesh_harvest")[: self.capacity]
+        except Exception:
+            self.invalidate_wake_state()
+            raise
 
     def invalidate_wake_state(self) -> None:
         """Drop the previous-fixpoint state (failed/poisoned wake): the
@@ -742,40 +723,3 @@ class MeshShadowGraph(ArrayShadowGraph):
         self._wake_state = None
         self._pending_del_dst.clear()
         self._pending_fresh_dst.clear()
-
-
-class _MeshWakeHandle:
-    """Adapter giving the base class's pipelined harvest machinery
-    (ArrayShadowGraph.harvest_trace / expire_stalled_wake) the two
-    operations it needs from an in-flight mesh wake.  The wake's state
-    was already committed at dispatch, so unpacking is a pure readback;
-    a poisoned result auto-invalidates, same contract as
-    DecrementalTracer.unpack_marks."""
-
-    __slots__ = ("graph", "n")
-
-    #: this handle's unpack_marks routes its device->host crossing
-    #: through _readback itself; the base harvest must not re-account it
-    accounts_readback = True
-
-    def __init__(self, graph: "MeshShadowGraph"):
-        self.graph = graph
-        #: capacity at launch: the harvest sweeps against the LAUNCH
-        #: snapshot, so the mark vector must match the snapshot's
-        #: length even if capacity grew in between (the base harvest
-        #: pads the grown tail — no verdict exists for it)
-        self.n = graph.capacity
-
-    def unpack_marks(self, mark_dev) -> np.ndarray:
-        try:
-            # Readback waits for the in-flight collective; take the
-            # process-wide mesh lock so it cannot interleave with
-            # another graph's dispatch (see _MESH_COLLECTIVE_LOCK).
-            with _MESH_COLLECTIVE_LOCK:
-                return _readback(mark_dev, "marks.mesh_harvest")[: self.n]
-        except Exception:
-            self.graph.invalidate_wake_state()
-            raise
-
-    def invalidate(self) -> None:
-        self.graph.invalidate_wake_state()
