@@ -324,6 +324,7 @@ def phase_mesh_data_plane(args, n):
 
     from uigc_tpu.ops import pallas_decremental as pd
     from uigc_tpu.ops import pallas_trace as pt
+    from uigc_tpu.ops import trace as trace_ops
     from uigc_tpu.parallel import sharded_trace as st
 
     D = args.chips
@@ -382,14 +383,53 @@ def phase_mesh_data_plane(args, n):
     with Clock() as c:
         wake = st.make_sharded_decremental_wake(*geom, **kw)
         zeros = jax.device_put(np.zeros(n_pad // 32, np.int32), nodes_s)
-        out = wake(flags, recv, zeros, zeros, *([zeros] * 5), *operands, jump_dev)
+        no_walks = jax.device_put(np.zeros((), np.int32), repl_s)
+        *out, stats = wake(
+            flags, recv, zeros, zeros, *([zeros] * 5), no_walks,
+            *operands, jump_dev,
+        )
         out[0].block_until_ready()
     say(f"sharded decremental wake, cold (compile + run): {c.s:.1f}s")
-    for o in out:
+    mark_w, _, _, iu_w, _, walks = out
+    for o in out[:5]:
         check(len(o.sharding.device_set) == D, "a wake output is on one chip")
+    # the verdict as the mesh backend reads it: packed words, a shard's
+    # for its own slot range, laid end to end
+    garbage_w, marked = pd.verdict_reduce()(mark_w, iu_w)
+    shards = st.shards_in_order(garbage_w)
     check(
-        np.array_equal(np.asarray(out[0])[:n], oracle),
-        "sharded decremental wake != oracle",
+        len(shards) == D
+        and all(sh.shape[0] * 32 == meta["shard_size"] for sh in shards),
+        "a shard's verdict words are not those of its slot range",
+    )
+    words = np.concatenate([np.asarray(sh) for sh in shards]).view(np.uint32)
+    garbage = np.unpackbits(words.view(np.uint8), bitorder="little")[:n] > 0
+    in_use = (graph["flags"] & trace_ops.FLAG_IN_USE) != 0
+    check(
+        np.array_equal(garbage, in_use & ~oracle),
+        "sharded decremental wake's verdict words != oracle",
+    )
+    check(int(marked) == int(oracle.sum()), "sharded wake's mark count != oracle")
+    # one wake's counters, present on all the shards
+    stats = {k: np.asarray(v) for k, v in stats.items()}
+    check(set(stats) == set(pd.WAKE_STATS) | {"gathers"}, "a counter is missing")
+    check(
+        all(v.shape[0] == D for v in stats.values()),
+        "a counter is not reported by every shard",
+    )
+    for k in ("n_sweeps", "closure_sweeps", "closure_bailed", "gathers"):
+        check((stats[k] == stats[k][0]).all(), f"the shards disagree on {k}")
+    check(
+        int(stats["n_sweeps"][0]) >= 1 and int(stats["closure_sweeps"][0]) == 0
+        and int(walks) == int(stats["dirty_chunks"][0].sum()),
+        "a derivation from nothing with a closure, or without sweeps",
+    )
+    check(int(stats["kernel_steps"].sum()) > 0, "no shard's kernel took a step")
+    say(
+        f"sharded wake counters: sweeps {int(stats['n_sweeps'][0])} gathers "
+        f"{int(stats['gathers'][0])} kernel steps by shard "
+        f"{stats['kernel_steps'].tolist()} of {stats['kernel_steps_full'].tolist()}, "
+        f"contractions {stats['kernel_contractions'].tolist()}"
     )
     del operands, stacked, out, mark
 

@@ -398,6 +398,7 @@ def test_sharded_programs_compile(topo, program, geom):
     ]
     if program == "wake":
         args += [_struct((n_pad // 32,), np.int32, nodes)] * 7
+        args += [_struct((), np.int32, NamedSharding(mesh, P()))]  # walks
     args += [
         _struct((D, nb), np.int32, dev),
         _struct((D, nb), np.int32, dev),

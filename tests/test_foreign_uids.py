@@ -69,9 +69,25 @@ class Sink:
         return np.concatenate([k for k, _ in self.calls]) if self.calls else np.empty(0, np.int64)
 
 
-def new_graph(use_device=False):
+#: the backends that hold foreign actors: the host's fixpoint, the
+#: device's wake on one chip, and the wake sharded over a mesh (four of
+#: the virtual CPU devices ``conftest.py`` gives)
+BACKENDS = ("array", "decremental", "mesh-decremental")
+
+
+def new_graph(use_device=False, backend=None):
     ctx = CrgcContext(delta_graph_size=64, entry_field_size=E)
-    graph = ArrayShadowGraph(ctx, FakeSystem.address, use_device=use_device)
+    if backend == "mesh-decremental":
+        from uigc_tpu.engines.crgc.mesh import MeshShadowGraph
+
+        graph = MeshShadowGraph(
+            ctx, FakeSystem.address, n_devices=4, decremental=True
+        )
+    else:
+        graph = ArrayShadowGraph(
+            ctx, FakeSystem.address,
+            use_device=use_device or backend == "decremental",
+        )
     plane = PackedPlane(E)
     registry = {}
     graph.attach_packed_plane(plane, registry.get)
@@ -261,8 +277,9 @@ def test_foreign_world_matches_cell_world(seed):
 # --------------------------------------------------------------------- #
 
 
-def test_swept_uid_is_dropped_and_unseen_uid_is_interned():
-    graph, plane, _, sink = new_graph()
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_swept_uid_is_dropped_and_unseen_uid_is_interned(backend):
+    graph, plane, _, sink = new_graph(backend=backend)
     # 1 is a root holding 2; 3 is referenced by nobody: garbage
     fold_foreign(graph, plane, [
         row(1, root=True, created=[(1, 2)], spawned=[2, 3]),
@@ -354,13 +371,14 @@ def test_each_garbage_uid_reaches_the_sink_once_as_one_array_per_wake():
     assert not sink.calls[-1][0].size and sorted(sink.calls[-1][1].tolist()) == [4, 14]
 
 
-def test_local_and_foreign_actors_in_one_graph():
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_local_and_foreign_actors_in_one_graph(backend):
     """A local actor kept alive only by a foreign one, and the reverse:
     when the keeper lets go, the local one gets ``StopMsg`` and the
     foreign one goes to the sink."""
     from uigc_tpu.engines.crgc.messages import StopMsg
 
-    graph, plane, registry, sink = new_graph()
+    graph, plane, registry, sink = new_graph(backend=backend)
     system = FakeSystem()
     root, kept = FakeCell(1, system), FakeCell(2, system)
     registry.update({1: root, 2: kept})
@@ -440,12 +458,14 @@ def rows_of(flags, recv, supervisor, src, dst, released):
     return rows
 
 
-@pytest.mark.parametrize("backend,n", [("array", 400), ("decremental", 160)])
+@pytest.mark.parametrize(
+    "backend,n", [("array", 400), ("decremental", 160), ("mesh-decremental", 160)]
+)
 @pytest.mark.parametrize("seed", [0, 1])
 def test_random_graphs_against_trace_marks_np(backend, n, seed):
     rng = np.random.default_rng([seed, n])
     flags, halted, recv, supervisor, src, dst, released = random_graph(rng, n)
-    graph, plane, _, sink = new_graph(use_device=(backend == "decremental"))
+    graph, plane, _, sink = new_graph(backend=backend)
     rows = rows_of(flags, recv, supervisor, src, dst, released)
     for at in range(0, len(rows), 97):  # several drains, several blocks
         fold_foreign(graph, plane, rows[at:at + 97])
@@ -488,7 +508,7 @@ def test_random_graphs_against_trace_marks_np(backend, n, seed):
         if batch:
             fold_foreign(graph, plane, batch)
     assert np.unique(sink.freed).size == sink.freed.size, "a uid was delivered twice"
-    if backend == "decremental":
+    if backend != "array":
         assert graph.trace_impl == "pallas-interpret" and graph.device_wakes >= 1
 
 
@@ -703,3 +723,46 @@ def test_decremental_wake_record_counts_the_upload():
     assert first["upload_bytes"] == graph.flags.nbytes + graph.recv_count.nbytes
     assert second["upload_bytes"] == arrays._patch_pad(2) * (4 + 1 + 4)
     assert sink.freed.tolist() == [3]
+
+
+def test_mesh_wake_record_has_every_phase_and_note_of_the_one_chip_road():
+    """A ``WakeProfiler`` attached to a ``mesh-decremental`` graph
+    records what it records of the ``decremental`` road: the same phases
+    above zero (``layout``, ``upload``, ``device``, ``readback``,
+    ``sweep``), the parts ``stage_s`` and ``dispatch_s``, the notes
+    (``upload_bytes``, ``layout_rows``, ``layout_rebuilt``, ``fold_rows``,
+    ``kill_uids``, ...) and the wake program's deferred sweep counters,
+    with the same values where the road does not enter (what the fold
+    and the sweep count, what the program's loops decide)."""
+    from uigc_tpu.telemetry.profile import WakeProfiler
+
+    records = {}
+    for backend in ("decremental", "mesh-decremental"):
+        graph, plane, _, sink = new_graph(backend=backend)
+        profiler = WakeProfiler("test")
+        for wake_no in range(3):
+            wake = graph.profile_wake = profiler.begin_wake()
+            if wake_no < 2:
+                fold_foreign(graph, plane, _foreign_tree_rows(9) if not wake_no else [
+                    row(0, root=True, updated=[(3, 1)])])
+            graph.trace(should_kill=True)
+            graph.profile_wake = None
+            wake.end(entries=0, garbage=0)
+        records[backend] = profiler.to_json()["recent"]
+        assert sink.freed.tolist() == [3]
+    for one, sharded in zip(records["decremental"], records["mesh-decremental"]):
+        assert set(one) <= set(sharded), set(one) - set(sharded)
+        for name in ("layout", "upload", "device", "readback", "sweep"):
+            assert one["phases"][name] > 0 and sharded["phases"][name] > 0, name
+        for part in ("stage_s", "dispatch_s", "device_s"):
+            assert one[part] > 0 and sharded[part] > 0, part
+        assert sharded["stage_s"] <= sharded["phases"]["upload"]
+        assert sharded["dispatch_s"] <= sharded["phases"]["device"]
+        for note in ("layout_rows", "layout_rebuilt", "fold_rows", "uids_interned",
+                     "kill_uids", "freed", "kills", "trace_mode", "n_sweeps",
+                     "closure_sweeps", "closure_bailed", "jump_sweeps"):
+            assert one.get(note) == sharded.get(note), note
+        assert sharded["upload_bytes"] > 0 or sharded["layout_rows"] == 0
+    first = records["mesh-decremental"][0]
+    # the first wake puts both node arrays whole, at the padded size
+    assert first["layout_rebuilt"] == 1 and first["upload_bytes"] == graph._n_pad * (1 + 8)

@@ -256,7 +256,8 @@ def test_sharded_decremental_wakes(mode):
 
     n_words = n_pad // 32
     zeros_w = np.zeros(n_words, np.int32)
-    state = [zeros_w] * 5  # mark, seed, halted, iu, active
+    # mark, seed, halted, iu, active words; the last derivation's walks
+    state = [zeros_w] * 5 + [np.zeros((), np.int32)]
     live_pairs = list(zip(psrc.tolist(), pdst.tolist()))
     bucket_pairs = []
 
@@ -288,9 +289,9 @@ def test_sharded_decremental_wakes(mode):
             bsrc, bdst,
             *((jp,) if use_jump else ()),
         )
-        mark = np.asarray(out[0])[:n]
-        state = [np.asarray(o) for o in out[1:]]
-        return mark
+        state = [np.asarray(o) for o in out[:-1]]
+        mark_w = state[0].view(np.uint32)
+        return np.unpackbits(mark_w.view(np.uint8), bitorder="little")[:n] > 0
 
     # cold start = full derivation
     assert np.array_equal(run_wake([], []), oracle())

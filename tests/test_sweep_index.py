@@ -8,8 +8,8 @@ slots are the nonzeros of ``trace_ops.garbage_and_kills_np`` over the
 oracle's marks.  One driver mutates an ``ArrayShadowGraph`` at the slot
 level (the graph's own mutators, so the pair log and the index hear of
 everything) and is run over the host backend, the decremental wake
-(interpreted here), the mesh's decremental wake (dense verdicts through
-the same sweep) and a graph of foreign uids.
+(interpreted here), the mesh's decremental wake (its shards' verdict words
+through the same sweep) and a graph of foreign uids.
 """
 
 from __future__ import annotations
@@ -73,18 +73,19 @@ class Rig:
     """A graph under random slot-level churn, and what the scan and the
     dense oracle say each of its sweeps must do."""
 
-    def __init__(self, case, seed):
+    def __init__(self, case, seed, **graph_kwargs):
         self.case = case
         self.rng = np.random.default_rng([seed, len(case)])
         self.foreign = case == "foreign"
         ctx = CrgcContext(delta_graph_size=64, entry_field_size=4)
+        graph_kwargs.setdefault("initial_capacity", 64)
         if case == "mesh-decremental":
             self.graph = g = mesh.MeshShadowGraph(
-                ctx, FakeSystem.address, initial_capacity=64, decremental=True)
+                ctx, FakeSystem.address, decremental=True, **graph_kwargs)
         else:
             self.graph = g = ArrayShadowGraph(
                 ctx, FakeSystem.address,
-                use_device=case == "decremental", initial_capacity=64)
+                use_device=case == "decremental", **graph_kwargs)
         g._endpoints.overlay_bound = 8  # seals and merges at this size
         self.wake = g.profile_wake = FakeWake()
         self.answers = []

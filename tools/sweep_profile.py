@@ -266,7 +266,11 @@ class _BlockWalk:
         self.chunk_nodes = pt.ROWS * prep["group"] * pt.LANE * pt.WORD_BITS
         self.n_chunks = prep["r_rows"] // (pt.ROWS * prep["group"])
         self.tile_nodes = prep["s_rows"] * pt.LANE
-        self.n_tiles = prep["n_super"]
+        # a destination shard's layout (``sharded_trace.shard_layout``)
+        # names its tiles from its own first
+        first = prep.get("first_tile", 0)
+        self.tile = self.tile + first
+        self.n_tiles = max(first + prep["n_super"], -(-n // self.tile_nodes))
         self.in_use = in_use
 
     def _per(self, flags, size, count):

@@ -47,7 +47,13 @@ DEFAULTS: Dict[str, Any] = {
     #              everything where there is none
     #              (ops/pallas_decremental.py: suspect closure + repair)
     #   "mesh-decremental" - the mesh backend with the decremental wake
-    #              per shard (one word all_gather per sweep)
+    #              per shard, level with the one-chip wake (priced
+    #              closure and cold road decided on the gathered table,
+    #              the table of new bits, the same counters a shard a
+    #              row, verdicts as packed words); one word all_gather
+    #              per sweep, one psum a wake
+    #              (parallel/sharded_trace.py
+    #              make_sharded_decremental_wake)
     "uigc.crgc.shadow-graph": "array",
     # Devices in the mesh backend's mesh; 0 = all visible devices.
     "uigc.crgc.mesh-devices": 0,

@@ -32,21 +32,44 @@ two levels the reference collapses into one).
 from __future__ import annotations
 
 import threading
+from collections import deque
 from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ...ops import pallas_decremental
 from ...ops import trace as trace_ops
 from ...ops.slotmap import (
     PackedSlotMap, PairLog, fold_log, pack_keys, unpack_keys,
 )
 from ...parallel import sharded_trace
 from ...utils import events
-from .arrays import ArrayShadowGraph, _NodeLog, _readback, audit_donation
+from .arrays import (
+    ArrayShadowGraph, PackedVerdicts, _NodeLog, _readback, audit_donation,
+)
 from .state import CrgcContext
 
-_SINK_PAD = 64  # scatter batches are padded to multiples of this
+#: The O(churn) scatters of a sync (bucket writes, deletion masks, dirty
+#: node rows, jump parents) pad their batches to 4,096 entries times a
+#: power of four: each padded length is a program of its own, so the
+#: classes are few, and ``_warm_scatters`` runs every one a capacity can
+#: meet before the first wake's traffic (a wake whose churn is the first
+#: to reach a length would otherwise compile it amid the traffic, as
+#: ``ArrayShadowGraph._warm_patches`` has it for the one-chip patch).
+_SCATTER_PAD = 4096
+#: the largest batch warmed, as a share of the padded slots (262,144
+#: entries at 2^24): a larger one (a bulk load, a mass death) compiles
+#: when it comes.  Each length is four programs of ~2.6 s on a cold
+#: four-chip host (PERF.md section 6, PR 49)
+_WARM_SHARE = 64
+#: the sharded wake's counters of a shard's OWN work (its kernel's steps,
+#: the tiles it forced or skipped); every other one is decided on the
+#: gathered table and reads alike on every shard
+SHARD_STATS = (
+    "gated_tiles", "kernel_steps", "kernel_contractions", "kernel_chunk_walks",
+    "kernel_walk_trips", "kernel_steps_full", "tiles_skipped",
+)
 
 #: Serializes sharded-collective dispatch + readback across EVERY
 #: MeshShadowGraph in the process.  The virtual CPU mesh (and a real
@@ -78,6 +101,14 @@ _SHARED_PROGRAM_CACHE_MAX = 32
 
 def _pow2(x: int) -> int:
     return 1 << max(0, int(x - 1).bit_length())
+
+
+def _scatter_pad(k: int) -> int:
+    """The padded length of a scatter batch of ``k`` entries."""
+    pad = _SCATTER_PAD
+    while pad < k:
+        pad *= 4
+    return pad
 
 
 class MeshShadowGraph(ArrayShadowGraph):
@@ -139,6 +170,7 @@ class MeshShadowGraph(ArrayShadowGraph):
         self._dev_recv = None
         self._n_pad = 0
         self._shard_size = 0
+        self._warmed_n_pad = 0  # the padding whose scatters are warm
         # --- packed base plane: per-shard Pallas layouts -------------- #
         self._layout_meta: Optional[dict] = None
         self._stacked: Optional[dict] = None  # host truth of the layouts
@@ -160,9 +192,25 @@ class MeshShadowGraph(ArrayShadowGraph):
         #: per-wake closure+repair detection on the mesh
         #: (parallel/sharded_trace.make_sharded_decremental_wake)
         self.decremental = decremental
-        self._wake_state: Optional[list] = None  # mark/seed/halt/iu/active
-        self._pending_del_dst: set = set()
-        self._pending_fresh_dst: set = set()
+        #: the previous fixpoint: mark/seed/halt/iu/active words, sharded,
+        #: and the replicated walks of the last derivation from nothing
+        self._wake_state: Optional[tuple] = None
+        #: the suspects of the next wake, as the id arrays each fold of
+        #: the pair log left (duplicates and all: ``_word_array`` ORs them)
+        self._pending_del_dst: List[np.ndarray] = []
+        self._pending_fresh_dst: List[np.ndarray] = []
+        #: the counters of the last wakes as the sharded wake left them
+        #: on the device, a shard a row (``wake_stats`` reads them back)
+        self._wake_counters: deque = deque(
+            maxlen=pallas_decremental.STATS_KEPT
+        )
+        #: the last wake's verdict words, on the device (sharded) and as
+        #: the sweep took them (``shard_verdict_words``)
+        self._verdict_dev = None
+        self.last_verdict_words: Optional[np.ndarray] = None
+        if decremental:
+            # found by whoever reads the wake programs' counters
+            pallas_decremental.track(self)
 
         self._jit_cache: Dict[str, object] = {}
 
@@ -232,8 +280,14 @@ class MeshShadowGraph(ArrayShadowGraph):
         )
 
     def _full_rebuild(self) -> None:
-        import jax
+        self._pack_from_graph()
+        self._upload_all()
 
+    def _pack_from_graph(self) -> None:
+        """The host's half of a rebuild: the per-shard layouts, the jump
+        parents and the empty insert buckets packed from the graph's
+        arrays.  The device holds nothing valid until ``_upload_all``."""
+        self._dev_ready = False
         self.stats["rebuilds"] += 1
         D = self.n_devices
         super_sz = self.s_rows * 128
@@ -275,8 +329,16 @@ class MeshShadowGraph(ArrayShadowGraph):
         self._pb_count = np.zeros(D, dtype=np.int64)
         self._pb_free = [[] for _ in range(D)]
         self._pb_slot = PackedSlotMap()
+        self._pair_log = PairLog()
+        self._node_log = _NodeLog()
+        self.invalidate_wake_state()
 
-        # --- device arrays ---------------------------------------- #
+    def _upload_all(self) -> int:
+        """The device's half of a rebuild: every operand put whole.
+        Returns the bytes handed over for node features."""
+        import jax
+
+        n_pad, stacked = self._n_pad, self._stacked
         nodes_s, pairs_s, pairs3_s = self._sharding()
         flags = np.zeros(n_pad, dtype=np.uint8)
         flags[: self.capacity] = self.flags
@@ -297,13 +359,12 @@ class MeshShadowGraph(ArrayShadowGraph):
         # folds counts, not absolutes), so per-wake sync needs the diff
         # against what the device already holds.
         self._recv_synced = recv.copy()
-
-        self._pair_log = PairLog()
-        self._node_log = _NodeLog()
-        self._wake_state = None
-        self._pending_del_dst.clear()
-        self._pending_fresh_dst.clear()
+        self._sync_jump_mirror()
+        if self._warmed_n_pad != n_pad:  # a capacity's first copies
+            self._warm_scatters()
+            self._warmed_n_pad = n_pad
         self._dev_ready = True
+        return flags.nbytes + recv.nbytes
 
     # ------------------------------------------------------------- #
     # Incremental device sync (O(churn) per wake)
@@ -338,10 +399,12 @@ class MeshShadowGraph(ArrayShadowGraph):
             # Suspect bookkeeping for the decremental wake: removal
             # destinations must re-derive; insert destinations must see
             # their new pair once.  Over-approximation is sound.
-            _, d = unpack_keys(np.concatenate([removes, cond_removes]))
-            self._pending_del_dst.update(d.tolist())
-            _, d = unpack_keys(inserts)
-            self._pending_fresh_dst.update(d.tolist())
+            _, deleted = unpack_keys(np.concatenate([removes, cond_removes]))
+            if deleted.size:
+                self._pending_del_dst.append(deleted)
+            _, fresh = unpack_keys(inserts)
+            if fresh.size:
+                self._pending_fresh_dst.append(fresh)
         writes: Dict[Tuple[int, int], Tuple[int, int]] = {}
         stacked = self._stacked
 
@@ -450,92 +513,87 @@ class MeshShadowGraph(ArrayShadowGraph):
             w = self._jump_writes
             self._jump_writes = {}
             k = len(w)
-            kp = max(_SINK_PAD, _pow2(k))
-            idx = np.full(kp, self._n_pad + 1, np.int32)  # OOB -> drop
-            vals = np.zeros(kp, np.int32)
+            idx, vals = self._jump_batch(_scatter_pad(k))
             idx[:k] = np.fromiter(w.keys(), np.int64, k)
             vals[:k] = np.fromiter(w.values(), np.int64, k)
+            self._scatter_jump(idx, vals)
 
-            def build_jump():
-                @partial(jax.jit, donate_argnums=(0,))
-                def apply_jump(jp, idx, vals):
-                    return jp.at[idx].set(vals, mode="drop")
+    def _jump_batch(self, kp: int) -> tuple:
+        """An all-padding batch for ``_scatter_jump``."""
+        return np.full(kp, self._n_pad + 1, np.int32), np.zeros(kp, np.int32)  # OOB -> drop
 
-                return apply_jump
-
-            donated = self._jump_dev
-            self._jump_dev = self._jit("jump", build_jump)(
-                donated, idx, vals
-            )
-            if self.donation_audit:
-                audit_donation("mesh.jump", donated)
-
-    def _sync_device(self) -> None:
-        if (
-            not self._dev_ready
-            or self._pair_log is None
-            or self._n_pad < self.capacity
-        ):
-            self._full_rebuild()
-            self._sync_jump_mirror()
-            return
-        pair_writes = self._apply_pair_log() if self._pair_log else []
-        if pair_writes is None:
-            self._full_rebuild()
-            self._sync_jump_mirror()
-            return
+    def _scatter_jump(self, idx, vals) -> None:
         import jax
-        import jax.numpy as jnp
 
+        def build_jump():
+            @partial(jax.jit, donate_argnums=(0,))
+            def apply_jump(jp, idx, vals):
+                return jp.at[idx].set(vals, mode="drop")
+
+            return apply_jump
+
+        donated = self._jump_dev
+        self._jump_dev = self._jit("jump", build_jump)(donated, idx, vals)
+        if self.donation_audit:
+            audit_donation("mesh.jump", donated)
+
+    def _sync_device(self) -> int:
+        """Bring the device's copy up to the host's graph; returns the
+        bytes handed over for node features (``_sync_upload``)."""
+        return self._sync_upload(self._sync_layout())
+
+    def _sync_layout(self) -> Optional[list]:
+        """Layout maintenance, the host's share of a sync: the pair log
+        folded into the host plane in O(changes) (``_apply_pair_log``),
+        or everything packed from the graph where there is no device
+        state, the log overflowed, the capacity outgrew the padding or
+        the insert buckets overflowed.  Returns the bucket scatter batch
+        for ``_sync_upload``, None after a pack."""
+        log = self._pair_log
+        rows = 0 if log is None else len(log)
+        writes = None
+        if self._dev_ready and log is not None and self._n_pad >= self.capacity:
+            writes = self._apply_pair_log() if rows else []
+        if writes is None:
+            self._pack_from_graph()
+        if self.profile_wake is not None:
+            # as ArrayShadowGraph._synced_dec notes them
+            self.profile_wake.note(
+                layout_rows=rows, layout_rebuilt=int(writes is None)
+            )
+        return writes
+
+    def _sync_upload(self, pair_writes: Optional[list]) -> int:
+        """The device's share of a sync: every operand whole after a
+        pack (``pair_writes`` None), else O(churn) scatters into donated
+        buffers: the bucket writes, the base layouts' deletion masks,
+        the dirty node rows, the jump parents.  Returns the bytes handed
+        over for node features (``upload_bytes``)."""
+        if pair_writes is None:
+            return self._upload_all()
+        nbytes = 0
         if pair_writes:
-            k = len(pair_writes)
-            kp = max(_SINK_PAD, _pow2(k))
-            shs = np.full(kp, self.n_devices, dtype=np.int32)  # OOB -> drop
-            cols = np.zeros(kp, dtype=np.int32)
-            srcs = np.zeros(kp, dtype=np.int32)
-            dsts = np.zeros(kp, dtype=np.int32)
+            batch = self._pairs_batch(_scatter_pad(len(pair_writes)))
+            shs, cols, srcs, dsts = batch
             for i, ((sh, colm), (s, d)) in enumerate(pair_writes):
                 shs[i], cols[i], srcs[i], dsts[i] = sh, colm, s, d
-
-            def build_pairs():
-                @partial(jax.jit, donate_argnums=(0, 1))
-                def apply_pairs(psrc, pdst, shs, cols, srcs, dsts):
-                    psrc = psrc.at[shs, cols].set(srcs, mode="drop")
-                    pdst = pdst.at[shs, cols].set(dsts, mode="drop")
-                    return psrc, pdst
-
-                return apply_pairs
-
-            donated_src, donated_dst = self._dev_psrc, self._dev_pdst
-            self._dev_psrc, self._dev_pdst = self._jit("pairs", build_pairs)(
-                donated_src, donated_dst, shs, cols, srcs, dsts
-            )
-            if self.donation_audit:
-                audit_donation("mesh.pairs", donated_src, donated_dst)
+            self._scatter_pairs(*batch)
 
         if self._mask_writes:
             # base-layout deletions: per-shard in-place masking
             D = self.n_devices
-            rows_total = self._stacked["row_pos"].shape[1]
             per_shard: List[List[Tuple[int, int]]] = [[] for _ in range(D)]
             for shard, ri, colm in self._mask_writes:
                 per_shard[shard].append((ri, colm))
             self._mask_writes = []
-            k = max(_SINK_PAD, _pow2(max(len(p) for p in per_shard)))
-            ri = np.full((D, k), rows_total, dtype=np.int32)  # OOB -> drop
-            col = np.zeros((D, k), dtype=np.int32)
+            ri, col = self._mask_batch(
+                _scatter_pad(max(len(p) for p in per_shard))
+            )
             for d in range(D):
                 for i, (r, c) in enumerate(per_shard[d]):
                     ri[d, i] = r
                     col[d, i] = c
-            self._dev_stacked["row_pos"], self._dev_stacked["emeta"] = (
-                self._mask_fn(
-                    self._dev_stacked["row_pos"],
-                    self._dev_stacked["emeta"],
-                    ri,
-                    col,
-                )
-            )
+            self._scatter_masks(ri, col)
 
         slots_arr = self._node_log.take()
         if slots_arr.size:
@@ -551,12 +609,8 @@ class MeshShadowGraph(ArrayShadowGraph):
             slots_arr = slots_arr[order]
             shard = shard[order]
             counts = np.bincount(shard, minlength=D).astype(np.int64)
-            m = max(_SINK_PAD, _pow2(int(counts.max(initial=1))))
-            # per-shard local slot buckets, padded with the sink (= ss)
-            lslot = np.full((D, m), ss, dtype=np.int32)
-            rdelta = np.zeros((D, m), dtype=np.int64)
-            fset = np.zeros((D, m), dtype=np.uint8)
-            fclear = np.zeros((D, m), dtype=np.uint8)
+            batch = self._nodes_batch(_scatter_pad(int(counts.max(initial=1))))
+            lslot, rdelta, fset, fclear = batch
             starts = np.zeros(D, dtype=np.int64)
             starts[1:] = np.cumsum(counts)[:-1]
             col = np.arange(slots_arr.size, dtype=np.int64) - starts[shard]
@@ -567,57 +621,118 @@ class MeshShadowGraph(ArrayShadowGraph):
             fset[shard, col] = new_flags
             fclear[shard, col] = ~new_flags
             self._recv_synced[slots_arr] = new_recv
-            donated_flags, donated_recv = self._dev_flags, self._dev_recv
-            self._dev_flags, self._dev_recv = self._fold_fn(
-                donated_flags, donated_recv, lslot, rdelta, fset, fclear
-            )
-            if self.donation_audit:
-                # The sharded fold donates its node shards
-                # (sharded_trace.make_sharded_fold(donate=True)); a
-                # surviving input means every wake now re-uploads
-                # O(graph) node state instead of O(churn) deltas.
-                audit_donation("mesh.fold", donated_flags, donated_recv)
+            nbytes = sum(a.nbytes for a in batch)
+            self._scatter_nodes(*batch)
 
         self._sync_jump_mirror()
+        return nbytes
+
+    # -- the scatters: an all-padding batch of each, and its dispatch -- #
+
+    def _pairs_batch(self, kp: int) -> tuple:
+        shs = np.full(kp, self.n_devices, dtype=np.int32)  # OOB -> drop
+        return (shs, *(np.zeros(kp, dtype=np.int32) for _ in range(3)))
+
+    def _scatter_pairs(self, shs, cols, srcs, dsts) -> None:
+        import jax
+
+        def build_pairs():
+            @partial(jax.jit, donate_argnums=(0, 1))
+            def apply_pairs(psrc, pdst, shs, cols, srcs, dsts):
+                psrc = psrc.at[shs, cols].set(srcs, mode="drop")
+                pdst = pdst.at[shs, cols].set(dsts, mode="drop")
+                return psrc, pdst
+
+            return apply_pairs
+
+        donated_src, donated_dst = self._dev_psrc, self._dev_pdst
+        self._dev_psrc, self._dev_pdst = self._jit("pairs", build_pairs)(
+            donated_src, donated_dst, shs, cols, srcs, dsts
+        )
+        if self.donation_audit:
+            audit_donation("mesh.pairs", donated_src, donated_dst)
+
+    def _mask_batch(self, k: int) -> tuple:
+        D, rows_total = self.n_devices, self._stacked["row_pos"].shape[1]
+        ri = np.full((D, k), rows_total, dtype=np.int32)  # OOB -> drop
+        return ri, np.zeros((D, k), dtype=np.int32)
+
+    def _scatter_masks(self, ri, col) -> None:
+        self._dev_stacked["row_pos"], self._dev_stacked["emeta"] = (
+            self._mask_fn(
+                self._dev_stacked["row_pos"],
+                self._dev_stacked["emeta"],
+                ri,
+                col,
+            )
+        )
+
+    def _nodes_batch(self, m: int) -> tuple:
+        """Per-shard local slot buckets, padded with the sink (= a
+        shard's size), and their zero deltas."""
+        D = self.n_devices
+        return (
+            np.full((D, m), self._shard_size, dtype=np.int32),
+            np.zeros((D, m), dtype=np.int64),
+            np.zeros((D, m), dtype=np.uint8),
+            np.zeros((D, m), dtype=np.uint8),
+        )
+
+    def _scatter_nodes(self, lslot, rdelta, fset, fclear) -> None:
+        donated_flags, donated_recv = self._dev_flags, self._dev_recv
+        self._dev_flags, self._dev_recv = self._fold_fn(
+            donated_flags, donated_recv, lslot, rdelta, fset, fclear
+        )
+        if self.donation_audit:
+            # The sharded fold donates its node shards
+            # (sharded_trace.make_sharded_fold(donate=True)); a
+            # surviving input means every wake now re-uploads
+            # O(graph) node state instead of O(churn) deltas.
+            audit_donation("mesh.fold", donated_flags, donated_recv)
+
+    def _warm_scatters(self) -> None:
+        """Run every scatter at every padded length this capacity's
+        churn can meet, all padding, so nothing is written."""
+        kp = _SCATTER_PAD
+        while kp <= _scatter_pad(self._n_pad // _WARM_SHARE):
+            self._scatter_pairs(*self._pairs_batch(kp))
+            self._scatter_masks(*self._mask_batch(kp))
+            self._scatter_nodes(*self._nodes_batch(kp))
+            if self._use_jump:
+                self._scatter_jump(*self._jump_batch(kp))
+            kp *= 4
 
     # ------------------------------------------------------------- #
     # Trace
     # ------------------------------------------------------------- #
 
-    def _word_array(self, id_set: set):
-        """Scatter an id set into the node-word array, sharded like the
+    def _word_array(self, id_chunks: List[np.ndarray]):
+        """Scatter id arrays into the node-word array, sharded like the
         node arrays (word w of shard d covers nodes d*shard + 32w..).
-        Empty sets (the quiet steady state) reuse one cached zero array
+        No ids (the quiet steady state) reuse one cached zero array
         instead of allocating + transferring per wake."""
         import jax
 
         nodes_s, _, _ = self._sharding()
         n_words = self._n_pad // 32
-        if not id_set:
+        if not id_chunks:
             z = getattr(self, "_zero_words", None)
             if z is None or z.shape[0] != n_words:
                 z = self._zero_words = jax.device_put(
                     np.zeros(n_words, np.int32), nodes_s
                 )
             return z
-        words = np.zeros(n_words, dtype=np.uint32)
-        ids = np.fromiter(id_set, np.int64, len(id_set))
-        np.bitwise_or.at(
-            words, ids >> 5, np.uint32(1) << (ids & 31).astype(np.uint32)
-        )
+        words = pallas_decremental.id_words(id_chunks, n_words)
         return jax.device_put(words.view(np.int32), nodes_s)
 
-    def compute_marks(self) -> np.ndarray:
+    def compute_marks(self):
         self._note_device_wake()
-        with self._device_call():
+        with self._device_call() as ev:
+            if self.decremental:
+                return self._compute_marks_decremental(ev.fields)
             self._sync_device()
             self.stats["wakes"] += 1
             meta = self._layout_meta
-            if self.decremental:
-                # One hold spans dispatch AND readback: exactly one
-                # collective program is in flight at a time.
-                with _MESH_COLLECTIVE_LOCK:
-                    return self._compute_marks_decremental(meta)
             traced = self._shared_program(
                 "trace",
                 meta,
@@ -650,16 +765,15 @@ class MeshShadowGraph(ArrayShadowGraph):
                 )
                 return _readback(mark, "marks.mesh")[: self.capacity]
 
-    def _dispatch_decremental_wake(self, meta) -> tuple:
-        """Dispatch one closure+repair wake on the mesh (regional
-        re-derivation per shard, one word all_gather per sweep; a
-        zeroed previous state — cold start, post-rebuild — is the full
-        derivation).  State and suspects COMMIT at dispatch; an
-        async-poisoned result surfaces at the first readback, where the
-        caller invalidates so the next wake re-derives from zero state
-        instead of feeding poisoned arrays forever."""
+    def _stage_wake(self) -> tuple:
+        """The host's share of a wake between the sync and the dispatch
+        (``DecrementalTracer.stage_wake``'s): the wake program for the
+        geometry the layouts have, a previous state where there is none,
+        and the suspects' id words put on the device."""
         import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
+        meta = self._layout_meta
         wake = self._shared_program(
             "dec",
             meta,
@@ -678,15 +792,28 @@ class MeshShadowGraph(ArrayShadowGraph):
             ),
         )
         if self._wake_state is None:
-            nodes_s, _, _ = self._sharding()
-            z = jax.device_put(
-                np.zeros(self._n_pad // 32, np.int32), nodes_s
+            # no previous fixpoint: zero words and zero walks, the cold road
+            z = self._word_array([])
+            walks = jax.device_put(
+                np.zeros((), np.int32), NamedSharding(self.mesh, P())
             )
-            self._wake_state = [z] * 5
+            self._wake_state = (z, z, z, z, z, walks)
         del_w = self._word_array(self._pending_del_dst)
         fresh_w = self._word_array(self._pending_fresh_dst)
+        return wake, del_w, fresh_w
+
+    def _dispatch_decremental_wake(self, staged: tuple):
+        """Dispatch one closure+repair wake on the mesh (regional
+        re-derivation per shard, one word all_gather per sweep; a
+        zeroed previous state, cold start or post-rebuild, is the full
+        derivation).  State and suspects COMMIT at dispatch; an
+        async-poisoned result surfaces at the first readback, where the
+        caller invalidates so the next wake re-derives from zero state
+        instead of feeding poisoned arrays forever.  Returns the mark
+        and in-use words (sharded, on the device)."""
+        wake, del_w, fresh_w = staged
         jump = (self._jump_dev,) if self._use_jump else ()
-        out = wake(
+        *state, counters = wake(
             self._dev_flags,
             self._dev_recv,
             del_w,
@@ -700,26 +827,144 @@ class MeshShadowGraph(ArrayShadowGraph):
             self._dev_pdst,
             *jump,
         )
-        self._wake_state = list(out[1:])
+        self._wake_state = tuple(state)
+        self._wake_counters.append(counters)
         self._pending_del_dst.clear()
         self._pending_fresh_dst.clear()
-        return out
+        return state[0], state[3]
 
-    def _compute_marks_decremental(self, meta) -> np.ndarray:
-        """One wake's dense marks, dispatched and read back under the
-        caller's hold of the collective lock.  The wake's state was
+    def _compute_marks_decremental(self, event: dict) -> PackedVerdicts:
+        """One wake's verdict words through the sharded wake, in the
+        phases and with the notes of the one-chip road
+        (``ArrayShadowGraph._compute_marks_decremental``): ``layout``
+        (the pair log folded on the host, or a pack), ``upload`` (the
+        O(churn) scatters, then ``stage``: the program and the suspects'
+        words), ``device`` (``dispatch``, then the wait) and
+        ``readback`` (1/8 of a byte a slot: the garbage words of every
+        shard laid end to end, and the number of marks).  Dispatch and
+        readback share one hold of the collective lock: exactly one
+        collective program is in flight at a time.  The wake's state was
         committed at dispatch, so a poisoned result, which surfaces at
-        the readback, drops it: the next wake derives from nothing."""
-        mark_dev = self._dispatch_decremental_wake(meta)[0]
-        try:
-            return _readback(mark_dev, "marks.mesh_harvest")[: self.capacity]
-        except Exception:
-            self.invalidate_wake_state()
-            raise
+        the wait or the readback, drops it: the next wake derives from
+        nothing."""
+        wake = self.profile_wake
+        with events.wake_phase(wake, "layout"):
+            pair_writes = self._sync_layout()
+        with events.wake_phase(wake, "upload"):
+            nbytes = self._sync_upload(pair_writes)
+            with events.wake_part(wake, "stage_s", "stage"):
+                staged = self._stage_wake()
+            event["upload_bytes"] = nbytes
+            if wake is not None:
+                wake.note(upload_bytes=nbytes)
+        self.stats["wakes"] += 1
+        with _MESH_COLLECTIVE_LOCK:
+            try:
+                with events.wake_phase(wake, "device"):
+                    with events.wake_part(wake, "dispatch_s", "dispatch"):
+                        mark_w, iu_w = self._dispatch_decremental_wake(staged)
+                    mark_w.block_until_ready()
+                if wake is not None:
+                    # the counters stay on the device: whoever reads the
+                    # record pays for their way to the host
+                    wake.defer(self._read_sweep_stats, self._wake_counters[-1])
+                with events.wake_phase(wake, "readback"):
+                    garbage_w, marked = pallas_decremental.verdict_reduce()(
+                        mark_w, iu_w
+                    )
+                    words = _readback(garbage_w, "marks.mesh_decremental")
+                    self._verdict_dev = garbage_w
+                    self.last_verdict_words = words.view(np.uint32)
+                    return PackedVerdicts(
+                        self.last_verdict_words,
+                        int(_readback(marked, "marks.mesh_decremental.live")),
+                    )
+            except Exception:
+                self.invalidate_wake_state()
+                raise
 
     def invalidate_wake_state(self) -> None:
-        """Drop the previous-fixpoint state (failed/poisoned wake): the
-        next wake is a full derivation and pending suspects are moot."""
+        """Drop the previous-fixpoint state (failed/poisoned wake, a
+        pack): the next wake is a full derivation and pending suspects
+        are moot."""
         self._wake_state = None
         self._pending_del_dst.clear()
         self._pending_fresh_dst.clear()
+
+    # ------------------------------------------------------------- #
+    # What the wakes left to read
+    # ------------------------------------------------------------- #
+
+    def wake_stats(self, last_n: Optional[int] = None) -> List[dict]:
+        """The counters of the last ``last_n`` wakes (all that are kept,
+        at most ``pallas_decremental.STATS_KEPT``, when None), oldest first, read back from
+        the device now: per wake the keys of
+        ``DecrementalTracer.wake_stats`` and ``gathers``.  What every
+        shard decides alike on the gathered table (the sweeps of both
+        loops, whether the closure gave up and what it spent, the dirty
+        chunks, the pull and jump decisions, the all-gathers) is given
+        once, as the one-chip wake gives it; a shard's own work
+        (``SHARD_STATS``: the kernel's counters, ``gated_tiles``,
+        ``tiles_skipped``) as a list with one entry a shard, whose sum
+        is the mesh's work and whose maximum its pace.  Waits for a wake
+        still in flight; costs the wakes nothing."""
+        kept = list(self._wake_counters)
+        if last_n is not None:
+            kept = kept[max(0, len(kept) - last_n):]
+        return _shard_stats(kept)
+
+    @staticmethod
+    def _read_sweep_stats(counters: list) -> List[dict]:
+        """The wake records' fields of the sweep counters
+        (``ArrayShadowGraph._read_sweep_stats``), of the mesh as a whole:
+        a shard's own counts summed."""
+        out = []
+        for stats in _shard_stats(counters):
+            fields = {
+                key: stats[key]
+                for key in ("n_sweeps", "jump_sweeps", "closure_sweeps",
+                            "closure_bailed")
+            }
+            fields["gated_tiles"] = sum(stats["gated_tiles"])
+            for key in ("dirty_chunks", "pull_on", "jump_on"):
+                fields["sweep_" + key] = stats[key]
+            fields["sweep_tiles_skipped"] = [
+                sum(sweep) for sweep in zip(*stats["tiles_skipped"])
+            ]
+            out.append(fields)
+        return out
+
+    def shard_verdict_words(self) -> List[np.ndarray]:
+        """The last wake's garbage words as each device holds them for
+        its own slot range, in shard order (uint32; bit ``i & 31`` of
+        word ``i >> 5`` is the shard's slot ``i``): laid end to end they
+        are ``last_verdict_words``, the verdict the sweep took."""
+        return [
+            _readback(rows, "marks.mesh_decremental.shard").view(np.uint32)
+            for rows in sharded_trace.shards_in_order(self._verdict_dev)
+        ]
+
+
+def _shard_stats(counters) -> List[dict]:
+    """Some wakes' counters as the sharded wake left them on the device
+    (a shard a row), read back in one crossing and shaped as
+    ``MeshShadowGraph.wake_stats`` gives them; each shard's row through
+    the one-chip wake's ``pallas_decremental.host_stats``."""
+    import jax
+
+    out = []
+    for host in jax.device_get(list(counters)):  # readback: a few hundred bytes of counters a shard a wake, on request
+        shards = [
+            pallas_decremental.host_stats(
+                {key: rows[d] for key, rows in host.items()}
+            )
+            for d in range(host["n_sweeps"].shape[0])
+        ]
+        stats = {
+            key: [shard[key] for shard in shards] if key in SHARD_STATS
+            else shards[0][key]
+            for key in shards[0]
+        }
+        stats["gathers"] = int(host["gathers"][0])
+        out.append(stats)
+    return out

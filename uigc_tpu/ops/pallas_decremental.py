@@ -93,16 +93,32 @@ WAKE_PHASES = ("pack", "suspects", "closure", "gate", "repair")
 #: wakes whose stats handles a tracer keeps (device arrays of a few
 #: hundred bytes each, read back only by :meth:`DecrementalTracer.wake_stats`)
 STATS_KEPT = 256
+#: the wake program's counters (``_build_wake_fn``'s ``stats``; the
+#: sharded wake's carry the same keys, a shard a row): those kept per
+#: repair sweep, for the first ``pt.MAX_SWEEP_STATS``, and all of them
+SWEEP_STATS = ("dirty_chunks", "tiles_skipped", "pull_on", "jump_on")
+WAKE_STATS = (
+    "closure_sweeps", "closure_bailed", "closure_spent", "gated_tiles",
+    "n_sweeps", "kernel_steps", "kernel_contractions", "kernel_chunk_walks",
+    "kernel_walk_trips", "kernel_steps_full", "jump_sweeps", "jump_spent",
+) + SWEEP_STATS
 
 _live_tracers: "weakref.WeakSet" = weakref.WeakSet()
 
 
 def live_tracers() -> list:
-    """The :class:`DecrementalTracer` objects alive in this process: the
-    road by which a reader that holds no reference to the system under
-    test (a benchmark's per-layer reader, after the window) finds their
-    :meth:`~DecrementalTracer.wake_stats`."""
+    """The objects alive in this process that run wake programs and keep
+    their counters (:class:`DecrementalTracer`; a mesh backend's sharded
+    wake, ``engines/crgc/mesh.py``): the road by which a reader that
+    holds no reference to the system under test (a benchmark's per-layer
+    reader, after the window) finds their ``wake_stats()``."""
     return list(_live_tracers)
+
+
+def track(tracer) -> None:
+    """Have :func:`live_tracers` find ``tracer`` (anything with
+    :meth:`DecrementalTracer.wake_stats`'s signature) while it lives."""
+    _live_tracers.add(tracer)
 
 
 def _build_wake_fn(
@@ -544,28 +560,26 @@ def no_previous_state(r_rows: int) -> tuple:
     return (z, z, z, z, z, jax.device_put(np.zeros((), np.int32)))
 
 
-def _host_stats(host: dict) -> dict:
+def host_stats(host: dict) -> dict:
     """One wake's counters, read back, as :meth:`DecrementalTracer.wake_stats`
-    and :func:`derive` return them."""
+    and :func:`derive` return them (of a sharded wake: one shard's)."""
     k = min(int(host["n_sweeps"]), pt.MAX_SWEEP_STATS)
     return {
-        "closure_sweeps": int(host["closure_sweeps"]),
-        "closure_bailed": int(host["closure_bailed"]),
-        "closure_spent": int(host["closure_spent"]),
-        "gated_tiles": int(host["gated_tiles"]),
-        "n_sweeps": int(host["n_sweeps"]),
-        "kernel_steps": int(host["kernel_steps"]),
-        "kernel_contractions": int(host["kernel_contractions"]),
-        "kernel_chunk_walks": int(host["kernel_chunk_walks"]),
-        "kernel_walk_trips": int(host["kernel_walk_trips"]),
-        "kernel_steps_full": int(host["kernel_steps_full"]),
-        "dirty_chunks": host["dirty_chunks"][:k].tolist(),
-        "tiles_skipped": host["tiles_skipped"][:k].tolist(),
-        "pull_on": host["pull_on"][:k].tolist(),
-        "jump_sweeps": int(host["jump_sweeps"]),
-        "jump_on": host["jump_on"][:k].tolist(),
-        "jump_spent": int(host["jump_spent"]),
+        key: host[key][:k].tolist() if key in SWEEP_STATS else int(host[key])
+        for key in WAKE_STATS
     }
+
+
+def id_words(id_chunks: List[np.ndarray], n_words: int) -> np.ndarray:
+    """Id arrays (duplicates and all) ORed into ``n_words`` flat uint32
+    words, bit ``i & 31`` of word ``i >> 5`` for id ``i``: a wake's
+    suspects as the wake programs take them, one chip's and a mesh's."""
+    ids = np.concatenate(id_chunks)
+    words = np.zeros(n_words, dtype=np.uint32)
+    np.bitwise_or.at(
+        words, ids >> 5, np.uint32(1) << (ids & 31).astype(np.uint32)
+    )
+    return words
 
 
 def read_counters(counters) -> List[dict]:
@@ -576,7 +590,7 @@ def read_counters(counters) -> List[dict]:
     import jax
 
     return [
-        _host_stats(host)
+        host_stats(host)
         for host in jax.device_get(list(counters))  # readback: a few hundred bytes of counters per wake, on request
     ]
 
@@ -645,7 +659,7 @@ def derive(flags, recv_count, preps, interpret=None, mode=pt.MODE_PUSH,
     marks = pt.unpack_table(mark_w, preps[0]["n"], jnp)
     return (
         np.asarray(marks),  # readback: host boundary: device marks -> np result contract
-        _host_stats(jax.device_get(stats)),  # readback: a few hundred bytes of counters, the result contract
+        host_stats(jax.device_get(stats)),  # readback: a few hundred bytes of counters, the result contract
     )
 
 
@@ -683,7 +697,7 @@ class DecrementalTracer:
         #: the sweep counters of the last STATS_KEPT wakes, as the wake
         #: program left them on the device (wake_stats reads them back)
         self._stats: deque = deque(maxlen=STATS_KEPT)
-        _live_tracers.add(self)
+        track(self)
         self._mark_w = None
         self._seed_w = None
         self._halted_w = None
@@ -775,11 +789,7 @@ class DecrementalTracer:
                     np.zeros((r_rows, pt.LANE), np.int32)
                 )
             return self._zeros
-        ids = np.concatenate(id_chunks)
-        words = np.zeros(r_rows * pt.LANE, dtype=np.uint32)
-        np.bitwise_or.at(
-            words, ids >> 5, np.uint32(1) << (ids & 31).astype(np.uint32)
-        )
+        words = id_words(id_chunks, r_rows * pt.LANE)
         return jax.device_put(words.view(np.int32).reshape(r_rows, pt.LANE))
 
     def stage_wake(self) -> tuple:

@@ -237,15 +237,20 @@ def make_local_shard_ops(axis, words_pad, r_rows, n_pad, shard_size, jnp):
         all-gathered tables), so no collective is needed to keep the
         parents coherent — the shard only slices its own destinations
         for the propagation gather."""
-        idx = jax.lax.axis_index(axis)
-        j_loc = jax.lax.dynamic_slice(
-            jump_j, (idx * shard_size,), (shard_size,)
-        )
-        hits = pt.bits_at(table, j_loc, n_pad, jnp)
-        for _ in range(pt.JUMP_STEPS):
-            j2 = jump_j[jump_j]
-            can = pt.bits_at(trans_table, jump_j, n_pad, jnp) & (j2 < n_pad)
-            jump_j = jnp.where(can, j2, jump_j)
+        with pt.scope("jump"):  # the parts as pt.jump_sweep names them
+            with pt.scope("hits"):
+                idx = jax.lax.axis_index(axis)
+                j_loc = jax.lax.dynamic_slice(
+                    jump_j, (idx * shard_size,), (shard_size,)
+                )
+                hits = pt.bits_at(table, j_loc, n_pad, jnp)
+            with pt.scope("double"):
+                for _ in range(pt.JUMP_STEPS):
+                    j2 = jump_j[jump_j]
+                    can = pt.bits_at(trans_table, jump_j, n_pad, jnp) & (
+                        j2 < n_pad
+                    )
+                    jump_j = jnp.where(can, j2, jump_j)
         return hits, jump_j
 
     return pack_words, gather_table, make_sweep, jump_local
@@ -404,6 +409,34 @@ def pack_shard_layouts(
         "group": group,
     }
     return stacked, meta, slot_vals
+
+
+def shards_in_order(x) -> list:
+    """The per-device pieces of an array sharded on its leading axis
+    (node words, a counter with a shard a row), still on their devices,
+    in shard order."""
+    return [
+        sh.data
+        for sh in sorted(
+            x.addressable_shards, key=lambda sh: sh.index[0].start or 0
+        )
+    ]
+
+
+def shard_layout(stacked: dict, meta: dict, shard: int) -> dict:
+    """Shard ``shard``'s layout of ``pack_shard_layouts`` as the packed
+    layout ``prepare_pairs`` gave it (global sources, its own
+    destinations, tiles named from its own first): what a reader that
+    counts a kernel's work from a layout takes
+    (``tools/sweep_profile.py simulate_sweeps``, which finds the shard's
+    tiles in the whole graph's by ``first_tile``)."""
+    n_super = meta["shard_size"] // (meta["s_rows"] * 128)
+    prep = {key: stacked[key][shard] for key in ("bmeta1", "bmeta2", "row_pos", "emeta")}
+    prep.update(
+        n=meta["shard_size"], n_super=n_super, first_tile=shard * n_super,
+        **{key: meta[key] for key in ("n_blocks", "r_rows", "s_rows", "sub", "group")},
+    )
+    return prep
 
 
 def _mesh_jump_policy(mesh, n_pad, n_blocks, sub, n_chunks, pull_cut):
@@ -731,27 +764,61 @@ def make_sharded_decremental_wake(
     pull_density: float = None,
 ):
     """The decremental wake (suspect closure + destination-gated repair,
-    ops/pallas_decremental.py) on the sharded data plane: per-wake cost
-    proportional to the churn's affected region *per shard*, with one
-    packed-word all_gather over ICI per sweep.
+    ops/pallas_decremental.py ``_build_wake_fn``) on the sharded data
+    plane: the same three phases, the same policies and the same
+    counters, per shard, with one packed-word all_gather over ICI per
+    sweep.
 
     fn(flags, recv, del_w, fresh_w, prev_mark_w, prev_seed_w,
-       prev_halted_w, prev_iu_w, prev_active_w,
+       prev_halted_w, prev_iu_w, prev_active_w, prev_walks,
        bmeta1, bmeta2, row_pos, emeta, bsrc, bdst[, jump_j])
-      -> (mark (bool[n_pad]), mark_w, seed_w, halted_w, iu_w, active_w)
+      -> (mark_w, seed_w, halted_w, iu_w, active_w, walks, stats)
 
     flags/recv sharded by node range; every *_w operand is the flat word
     array (n_pad/32 ints) sharded by word range (same node partition);
-    layout operands as in make_sharded_pallas_trace.  A zeroed previous
-    state degenerates to the full derivation from seeds, so cold start
-    and post-rebuild wakes need no separate path.  ``mode`` applies to
-    the repair fixpoint exactly as in the single-device wake
-    (ops/pallas_decremental.py): jump/auto take the replicated
-    jump-parent operand, pull/auto skip saturated local supertiles.
+    ``prev_walks`` / ``walks`` is the replicated int32 scalar of the
+    one-chip wake (the chunk walks of the last derivation from nothing,
+    what the closure's price is a share of); layout operands as in
+    make_sharded_pallas_trace.  The verdict is the words: a slot is
+    garbage iff its bit of ``iu_w & ~mark_w`` is set
+    (``pallas_decremental.verdict_reduce``, which the mesh backend runs
+    over the sharded words as the one-chip backend does over its tables).
+
+    What a shard shares with the one-chip program is the code: the sweep
+    over its layouts is ``pt.build_sweep_contribs`` (its packed blocks
+    as a dense layout, its insert bucket as an xla tier), the kernel's
+    table operand ``pt.walk_tables`` (the bits new since the sweep
+    before), the dirty lists, the pull gate, the jump step and both
+    policies (``pt.closure_gives_up``, ``pt.auto_jump_policy``) the
+    ``pt.*`` helpers.  What differs is where a table comes from: a
+    shard packs its own words and ``gather_table`` all-gathers them
+    (scope ``gather``).  Every loop decision is taken on values all
+    shards hold alike, so every shard leaves a loop in the same sweep
+    and no collective is left waiting: the dirty lists, ``changed``, the
+    closure's ``spent`` and the repair's ``walks`` are counted on the
+    GATHERED table, the cold road on the gathered previous table and on
+    ``closure_bailed``; the one decision that hangs on per-shard state,
+    whether any shard has a tile to force, is a ``psum`` (scope
+    ``agree``).  A zeroed previous state takes the cold road, as on one
+    chip: the derivation from the seeds, ungated.  ``mode`` applies to
+    the repair fixpoint as in the one-chip wake: jump/auto take the
+    replicated jump-parent operand, pull/auto skip saturated local
+    supertiles.
+
+    ``stats`` is the one-chip wake's dict (``_build_wake_fn``'s
+    docstring names the keys) with a leading shard axis on every value:
+    ``(D,)`` for the scalars, ``(D, pt.MAX_SWEEP_STATS)`` for the
+    per-sweep vectors.  The kernel's counters (``kernel_steps``,
+    ``kernel_contractions``, ``kernel_chunk_walks``, ``kernel_walk_trips``,
+    ``kernel_steps_full``), ``gated_tiles`` and ``tiles_skipped`` are a
+    shard's own; the others read alike on every shard.  One key is the
+    mesh's: ``gathers``, the all-gathers of a word table the wake made
+    (one a sweep of either loop, and the fixed ones around them).
     """
     jax, jnp = _jax()
     from jax.sharding import PartitionSpec as P
 
+    from ..ops import pallas_decremental as pd
     from ..ops import pallas_trace as pt
 
     if interpret is None:
@@ -772,9 +839,11 @@ def make_sharded_decremental_wake(
     use_pull = mode in (pt.MODE_PULL, pt.MODE_AUTO)
     super_sz = s_rows * pt.LANE
     n_super_shard = shard_size // super_sz
-    propagate = pt.build_propagate(
-        n_blocks, n_super_shard, r_rows, s_rows, interpret,
-        sub=sub, group=group, dst_gate=True,
+    # a shard's layouts, as the one-chip sweep takes them: its packed
+    # blocks (global sources, local destinations) and its insert bucket
+    specs = (("dense", n_blocks, sub, group), ("xla", bucket_m))
+    gated = pt.build_layout_propagates(
+        specs, n_super_shard, r_rows, s_rows, interpret, dst_gate=True
     )
     group_rows = pt.ROWS * group
     n_chunks = r_rows // group_rows
@@ -785,9 +854,13 @@ def make_sharded_decremental_wake(
         mesh, n_pad, n_blocks, sub, n_chunks, pull_cut
     )
 
-    def local_wake(flags, recv, del_w, fresh_w, p_mark, p_seed, p_halt,
-                   p_iu, p_active, bmeta1, bmeta2, row_pos, emeta,
-                   bsrc, bdst, *rest):
+    def local_wake(*args):
+        with pt.scope(pd.WAKE_SCOPE):
+            return wake_body(*args)
+
+    def wake_body(flags, recv, del_w, fresh_w, p_mark, p_seed, p_halt,
+                  p_iu, p_active, p_walks, bmeta1, bmeta2, row_pos, emeta,
+                  bsrc, bdst, *rest):
         jump_j0 = rest[0] if use_jump else None
         flags = flags.reshape(-1)
         recv = recv.reshape(-1)
@@ -798,36 +871,52 @@ def make_sharded_decremental_wake(
         p_halt = p_halt.reshape(-1)
         p_iu = p_iu.reshape(-1)
         p_active = p_active.reshape(-1)
-        bmeta1 = bmeta1.reshape(-1)
-        bmeta2 = bmeta2.reshape(-1)
-        row_pos = row_pos.reshape(-1, pt.LANE)
-        emeta = emeta.reshape(-1, pt.LANE)
-        bsrc = bsrc.reshape(-1)
-        bdst = bdst.reshape(-1)
+        layout_args = (
+            bmeta1.reshape(-1), bmeta2.reshape(-1),
+            row_pos.reshape(-1, pt.LANE), emeta.reshape(-1, pt.LANE),
+            bsrc.reshape(-1), bdst.reshape(-1),
+        )
 
-        in_use, halted, seed = _seed_masks(flags, recv)
-        pack_words, gather_table, make_sweep, jump_local = (
-            make_local_shard_ops(
-                axis, words_pad, r_rows, n_pad, shard_size, jnp
+        pack_words, gather_table, _, jump_local = make_local_shard_ops(
+            axis, words_pad, r_rows, n_pad, shard_size, jnp
+        )
+        # the sink of a bucket's padding is src = n_pad, which the xla
+        # tier masks by ``src < n``
+        gated_sweep = pt.build_sweep_contribs(
+            specs, gated, n_pad, n_super_shard, s_rows, jnp
+        )
+
+        def gather(words):
+            with pt.scope("gather"):
+                return gather_table(words)
+
+        def contribs(table, table_prev, d, l, gate):
+            """One sweep into this shard (``_build_wake_fn``'s
+            ``contribs``): its hit plane (t_local, LANE), the grid steps
+            its kernel took, those that contracted, the chunk-iterations
+            walked and the trips they were walked in."""
+            return gated_sweep.with_steps(
+                pt.walk_tables(table, table_prev, jnp), d, l, layout_args,
+                gate=gate,
             )
-        )
-        sweep_hits = make_sweep(
-            propagate, bmeta1, bmeta2, row_pos, emeta, bsrc, bdst
-        )
+
+        def pack_hits(hits2d):
+            with pt.scope("hits"):
+                return pt.pack_hits_words(hits2d, jnp)
 
         def dirty_chunks(table, table_prev):
             return pt.dirty_group_lists(
                 table, table_prev, n_chunks, group_rows, jnp
             )
 
-        def pack2d(hits2d):
-            return pt.pack_hits_words(hits2d, jnp)
-
-        iu_w = pack_words(in_use)
-        halted_w = pack_words(halted)
-        nh_w = pack_words(~halted)
-        seed_w = pack_words(in_use & (~halted) & seed)
+        with pt.scope("pack"):
+            in_use, halted, seed = _seed_masks(flags, recv)
+            iu_w = pack_words(in_use)
+            halted_w = pack_words(halted)
+            nh_w = pack_words(~halted)
+            seed_w = pack_words(in_use & (~halted) & seed)
         zero_gate = jnp.zeros((n_super_shard,), jnp.int32)
+        zero_i = jnp.zeros((), jnp.int32)
 
         def per_super(words):
             return (
@@ -837,113 +926,199 @@ def make_sharded_decremental_wake(
             )
 
         # --- 1. suspect seeds (shard-local) ------------------------- #
-        s_w = (
-            (~iu_w)
-            | (halted_w & ~p_halt)
-            | (p_seed & ~seed_w)
-            | del_w
-        ) & p_mark
+        with pt.scope("suspects"):
+            s_w = (
+                (~iu_w)
+                | (halted_w & ~p_halt)
+                | (p_seed & ~seed_w)
+                | del_w
+            ) & p_mark
 
         # --- 2. closure: marks that depended on a suspect ----------- #
+        # Priced as on one chip, on a count every shard holds alike: the
+        # dirty chunks of the gathered table (pt.closure_gives_up).
         def c_cond(carry):
-            return carry[-1]
+            return carry["changed"] & ~pt.closure_gives_up(
+                carry["spent"], p_walks
+            )
 
         def c_body(carry):
-            closure_w, table, d, l, _ = carry
-            hits2d = sweep_hits(table, d, l, zero_gate)
-            new_closure = closure_w | (pack2d(hits2d) & p_mark)
-            new_table = gather_table(new_closure)
+            table, d, l = carry["table"], carry["d"], carry["l"]
+            hits2d, took, did, iters, trips = contribs(
+                table, carry["table_prev"], d, l, zero_gate
+            )
+            new_closure = carry["closure"] | (pack_hits(hits2d) & p_mark)
+            new_table = gather(new_closure)
             d2, l2, changed = dirty_chunks(new_table, table)
-            return new_closure, new_table, d2, l2, changed
+            return {
+                "closure": new_closure, "table": new_table,
+                "table_prev": table, "d": d2, "l": l2, "changed": changed,
+                "sweeps": carry["sweeps"] + 1,
+                "spent": carry["spent"] + d[n_chunks],
+                "steps": carry["steps"] + took,
+                "contracted": carry["contracted"] + did,
+                "walked": carry["walked"] + iters,
+                "tripped": carry["tripped"] + trips,
+            }
 
-        c_table0 = gather_table(s_w)
-        cd0, cl0, cch0 = dirty_chunks(c_table0, jnp.zeros_like(c_table0))
-        closure_w, _, _, _, _ = jax.lax.while_loop(
-            c_cond, c_body, (s_w, c_table0, cd0, cl0, cch0)
-        )
+        with pt.scope("closure"):
+            c_table0 = gather(s_w)
+            zero_t = jnp.zeros_like(c_table0)
+            cd0, cl0, cch0 = dirty_chunks(c_table0, zero_t)
+            closed = jax.lax.while_loop(c_cond, c_body, {
+                "closure": s_w, "table": c_table0, "table_prev": zero_t,
+                "d": cd0, "l": cl0, "changed": cch0, "sweeps": zero_i,
+                "spent": zero_i, "steps": zero_i, "contracted": zero_i,
+                "walked": zero_i, "tripped": zero_i,
+            })
+            closure_w = closed["closure"]
+            closure_bailed = closed["changed"]
+            # The cold road, as on one chip: the closure said by its cost
+            # that the region is everything, or there is no previous
+            # fixpoint.  Both from gathered tables: every shard agrees.
+            prev_table = gather(p_active)
+            cold = closure_bailed | ~prev_table.any()
 
-        suspect_g = (
-            per_super(closure_w)
-            | per_super(fresh_w)
-            | per_super(iu_w & ~p_iu)
-        )
+        with pt.scope("gate"):
+            suspect_g = jnp.where(
+                cold,
+                zero_gate,
+                per_super(closure_w)
+                | per_super(fresh_w)
+                | per_super(iu_w & ~p_iu),
+            )
 
         # --- 3. repair fixpoint ------------------------------------- #
-        mark_w0 = (p_mark & ~closure_w) | seed_w
-        active_w0 = mark_w0 & nh_w
-        table0 = gather_table(active_w0)
-        prev_table = gather_table(p_active)
-        rd0, rl0, rch0 = dirty_chunks(table0, prev_table)
-        # Replicated run-gate decision: every shard must agree on the
-        # first (gated) sweep or the collectives deadlock.
-        any_gate = jax.lax.psum(suspect_g.sum(), axis) > 0
-        run0 = rch0 | any_gate
-        # replicated transparency table for the pointer doubling
-        trans_table = gather_table(iu_w & nh_w) if use_jump else None
-
         def r_cond(carry):
-            return carry[-1]
+            return carry["changed"]
 
         def run_jump(mark_w, table, jump_j):
             jh, jump_j = jump_local(table, trans_table, jump_j)
-            return mark_w | (pack_words(jh) & iu_w), jump_j
+            with pt.scope("jump"), pt.scope("pack"):
+                return mark_w | (pack_words(jh) & iu_w), jump_j
 
         def r_body(carry):
-            mark_w, table, d, l, use_gate, jump_j, jump_state, _ = carry
-            # Gate composition as in the single-device wake: the repair
-            # forcing (GATE_FULL on suspect tiles, first sweep only)
-            # under the pull skip (GATE_SKIP on saturated tiles).  Both
-            # inputs to the pull decision — the dirty density (global
-            # table diff) and the per-shard saturation of LOCAL tiles —
-            # are derived from replicated or own-shard state, so every
-            # shard agrees on the sweep plan without a collective.
-            base_gate = jnp.where(use_gate, suspect_g, zero_gate)
+            mark_w, table = carry["mark"], carry["table"]
+            d, l = carry["d"], carry["l"]
+            n_dirty = d[n_chunks]
+            # Gate composition as in the one-chip wake.  Both inputs to
+            # the pull decision, the dirty density (the gathered table's
+            # diff) and the saturation of LOCAL tiles, are replicated or
+            # own-shard state: the shards agree on the sweep plan.
+            base_gate = jnp.where(carry["use_gate"], suspect_g, zero_gate)
             if use_pull:
                 sat = pt.saturated_tiles(
                     mark_w, iu_w, n_super_shard, sup_words, jnp
                 )
                 if mode == pt.MODE_AUTO:
-                    pull_on = d[n_chunks] >= pull_cut
+                    pull_on = n_dirty >= pull_cut
                 else:
                     pull_on = jnp.array(True)
                 gate = jnp.where(pull_on & (sat > 0), pt.GATE_SKIP,
                                  base_gate)
             else:
+                sat = None
+                pull_on = jnp.array(False)
                 gate = base_gate
-            hits2d = sweep_hits(table, d, l, gate)
-            new_mark = mark_w | (pack2d(hits2d) & iu_w)
+            hits2d, took, did, iters, trips = contribs(
+                table, carry["table_prev"], d, l, gate
+            )
+            new_mark = mark_w | (pack_hits(hits2d) & iu_w)
             if use_jump:
-                # AUTO decides on the same replicated dirty count as
-                # the pull decision above
                 new_mark, jump_j, jump_state = pt.jump_step(
-                    mode, auto_jump, jump_state, d[n_chunks], run_jump,
-                    new_mark, table, jump_j,
+                    mode, auto_jump, carry["jump_state"], n_dirty,
+                    run_jump, new_mark, table, carry["jump"],
                 )
-            new_table = gather_table(new_mark & nh_w)
+            new_table = gather(new_mark & nh_w)
             d2, l2, changed = dirty_chunks(new_table, table)
-            return (new_mark, new_table, d2, l2, jnp.array(False),
-                    jump_j, jump_state, changed)
+            i = jnp.minimum(carry["sweep_i"], pt.MAX_SWEEP_STATS - 1)
+            out = dict(carry, mark=new_mark, table=new_table,
+                       table_prev=table, d=d2, l=l2,
+                       use_gate=jnp.array(False), changed=changed,
+                       sweep_i=carry["sweep_i"] + 1,
+                       walks=carry["walks"] + n_dirty,
+                       steps=carry["steps"] + took,
+                       contracted=carry["contracted"] + did,
+                       walked=carry["walked"] + iters,
+                       tripped=carry["tripped"] + trips,
+                       st_dirty=carry["st_dirty"].at[i].set(n_dirty))
+            if use_jump:
+                jump_on = jump_state[0].astype(jnp.int32)
+                out.update(
+                    jump=jump_j, jump_state=jump_state,
+                    jump_sweeps=carry["jump_sweeps"] + jump_on,
+                    st_jump=carry["st_jump"].at[i].set(jump_on),
+                )
+            if use_pull:
+                out["st_skip"] = carry["st_skip"].at[i].set(
+                    jnp.where(pull_on, sat.sum(), 0)
+                )
+                out["st_pull"] = carry["st_pull"].at[i].set(
+                    pull_on.astype(jnp.int32)
+                )
+            return out
 
-        jj0 = (
-            jump_j0.reshape(-1).astype(jnp.int32)
-            if use_jump
-            else jnp.zeros((1,), jnp.int32)
-        )
-        mark_w, _, _, _, _, _, _, _ = jax.lax.while_loop(
-            r_cond,
-            r_body,
-            (mark_w0, table0, rd0, rl0, jnp.array(True), jj0,
-             pt.jump_state0(mode, jnp), run0),
-        )
-        active_w = mark_w & nh_w
-
-        shifts = jnp.arange(pt.WORD_BITS, dtype=jnp.int32)
-        bits = (mark_w[:, None] >> shifts[None, :]) & 1
-        mark = bits.reshape(-1) > 0
+        with pt.scope("repair"):
+            zero_w = jnp.zeros_like(p_mark)
+            kept_w = jnp.where(cold, zero_w, p_mark & ~closure_w)
+            mark_w0 = kept_w | seed_w
+            table0 = gather(mark_w0 & nh_w)
+            table_prev0 = jnp.where(cold, zero_t, prev_table)
+            rd0, rl0, rch0 = dirty_chunks(table0, table_prev0)
+            # Run at least one gated sweep whenever ANY shard has a tile
+            # to force: the one decision on per-shard state, so the one
+            # collective that is not a table.
+            with pt.scope("agree"):
+                any_gate = jax.lax.psum(suspect_g.sum(), axis) > 0
+            run0 = rch0 | any_gate
+            # replicated transparency table for the pointer doubling
+            trans_table = gather(iu_w & nh_w) if use_jump else None
+            zero_stats = jnp.zeros((pt.MAX_SWEEP_STATS,), jnp.int32)
+            carry0 = {"mark": mark_w0, "table": table0,
+                      "table_prev": table_prev0, "d": rd0, "l": rl0,
+                      "use_gate": jnp.array(True), "changed": run0,
+                      "sweep_i": zero_i, "walks": zero_i, "steps": zero_i,
+                      "contracted": zero_i, "walked": zero_i,
+                      "tripped": zero_i, "st_dirty": zero_stats}
+            if use_jump:
+                carry0.update(
+                    jump=jump_j0.reshape(-1).astype(jnp.int32),
+                    jump_state=pt.jump_state0(mode, jnp),
+                    jump_sweeps=zero_i, st_jump=zero_stats,
+                )
+            if use_pull:
+                carry0.update(st_skip=zero_stats, st_pull=zero_stats)
+            out = jax.lax.while_loop(r_cond, r_body, carry0)
+        mark_w = out["mark"]
+        walks = jnp.where(cold, out["walks"], p_walks)
+        sweeps = closed["sweeps"] + out["sweep_i"]
+        stats = {
+            "closure_sweeps": closed["sweeps"],
+            "closure_bailed": closure_bailed.astype(jnp.int32),
+            "closure_spent": closed["spent"],
+            "gated_tiles": suspect_g.sum(),
+            "n_sweeps": out["sweep_i"],
+            "kernel_steps": closed["steps"] + out["steps"],
+            "kernel_contractions": closed["contracted"] + out["contracted"],
+            "kernel_chunk_walks": closed["walked"] + out["walked"],
+            "kernel_walk_trips": closed["tripped"] + out["tripped"],
+            "kernel_steps_full": sweeps * n_blocks,
+            "dirty_chunks": out["st_dirty"],
+            "tiles_skipped": out.get("st_skip", zero_stats),
+            "pull_on": out.get("st_pull", zero_stats),
+            "jump_sweeps": out.get("jump_sweeps", zero_i),
+            "jump_on": out.get("st_jump", zero_stats),
+            "jump_spent": out["jump_state"][1] if use_jump else zero_i,
+            # a table a sweep of either loop, and the closure's first,
+            # the previous fixpoint's, the repair's first and the jump's
+            # transparency table around them
+            "gathers": sweeps + (4 if use_jump else 3),
+        }
         one = lambda x: x.reshape(1, -1)
         return (
-            one(mark), one(mark_w), one(seed_w), one(halted_w),
-            one(iu_w), one(active_w),
+            one(mark_w), one(seed_w), one(halted_w), one(iu_w),
+            one(mark_w & nh_w), walks,
+            {k: v.reshape((1,) + v.shape) for k, v in stats.items()},
         )
 
     spec_nodes = P(axis)
@@ -954,17 +1129,23 @@ def make_sharded_decremental_wake(
         spec_nodes, spec_nodes,  # flags, recv
         spec_nodes, spec_nodes,  # del_w, fresh_w (word-sharded)
         spec_nodes, spec_nodes, spec_nodes, spec_nodes, spec_nodes,  # prev
+        P(),  # prev_walks (replicated scalar)
         spec_dev, spec_dev, spec_dev3, spec_dev3,  # layout
         spec_dev, spec_dev,  # buckets
     )
     if use_jump:
         in_specs = in_specs + (P(),)  # replicated jump parents
-    out_specs = (spec_dev,) * 6
+    stat_spec = {
+        k: spec_dev if k in pd.SWEEP_STATS else spec_nodes
+        for k in pd.WAKE_STATS + ("gathers",)
+    }
+    out_specs = (spec_dev,) * 5 + (P(), stat_spec)
     fn = _shard_map_unchecked(local_wake, mesh, in_specs, out_specs)
 
-    @jax.jit
-    def wake(*args):
-        outs = fn(*args)
-        return tuple(o.reshape(-1) for o in outs)
+    # named as the one-chip wake's: a device trace knows a module by its
+    # jitted function's name (``jit_wake_fn``)
+    def wake_fn(*args):
+        *words, walks, stats = fn(*args)
+        return (*(w.reshape(-1) for w in words), walks, stats)
 
-    return wake
+    return jax.jit(wake_fn)

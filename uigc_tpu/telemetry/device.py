@@ -114,7 +114,9 @@ _FAMILY_ATTRS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("device_nodes", ("_dev_flags", "_dev_recv", "_resident")),
     ("device_layout", ("_dev_stacked", "_stacked")),
     ("device_buckets", ("_dev_psrc", "_dev_pdst", "_pb_src", "_pb_dst")),
-    ("wake_state", ("_wake_state", "_zero_words")),
+    ("wake_state", (
+        "_wake_state", "_zero_words", "_verdict_dev", "last_verdict_words",
+    )),
 )
 
 #: sub-objects whose ``vars()`` are scanned generically for arrays —
