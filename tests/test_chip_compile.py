@@ -49,6 +49,11 @@ GEOM_SMALL = dict(n=20_000, n_blocks=128, n_super=5, r_rows=64)
 #: 10M over four shards, as ``pack_shard_layouts`` packs it
 MESH_10M = dict(n_pad=10_010_624, n_blocks=16_384, r_rows=2496, bucket_m=1024)
 MESH_SMALL = dict(n_pad=65_536, n_blocks=256, r_rows=64, bucket_m=1024)
+#: the benchmark's ``mesh4-10m``: the 10M graph folded into 2^24 slots over
+#: four shards, the insert buckets at the size the window runs (grown in
+#: the warm-up from the floor of 1,024 to twice what a wake's 10,000 new
+#: references put into the fullest shard: ``mesh.py _grow_buckets``)
+MESH_ENGINE_16M = dict(n_pad=1 << 24, n_blocks=8192, r_rows=4096, bucket_m=32_768)
 
 
 @pytest.fixture(scope="module")
@@ -369,7 +374,9 @@ def test_one_propagate_kernel_and_no_knob_in_the_environment(
     assert not [k for k in spy.asked if k.upper().startswith("UIGC")]
 
 
-@pytest.mark.parametrize("geom", [MESH_SMALL, MESH_10M], ids=["64k", "10m"])
+@pytest.mark.parametrize(
+    "geom", [MESH_SMALL, MESH_10M, MESH_ENGINE_16M], ids=["64k", "10m", "16m-grown"]
+)
 @pytest.mark.parametrize("program", ["trace", "wake"])
 def test_sharded_programs_compile(topo, program, geom):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P

@@ -136,7 +136,8 @@ def test_sharded_verdict_words_equal_the_one_chip_wakes_and_the_oracles(n_device
             freed += graph._sweep(True, verdicts)[0]
             churn(graph, rngs[graphs.index(graph)])
         assert np.array_equal(one.flags, sharded.flags)
-    assert freed > 0 and sharded.stats == {"rebuilds": 1, "wakes": 5, "anomalies": 0}
+    assert freed > 0 and sharded.stats == {
+        "rebuilds": 1, "wakes": 5, "anomalies": 0, "bucket_grows": 0}
     # what the shards decide on the gathered table, they decide alike, and
     # as the one chip does: every wake ends in the same sweep on both
     one_stats = one._dec.wake_stats()

@@ -333,7 +333,7 @@ def test_the_mesh_derives_from_nothing_after_a_wake_state_it_dropped(doubt, monk
         rig.churn(among[(among != rig.keeper) & ~np.isin(among, kids)])
         rig.sweep_and_check()
     assert g.stats == {"rebuilds": rebuilds, "wakes": 6 + (doubt is readback_raises),
-                       "anomalies": 0}
+                       "anomalies": 0, "bucket_grows": 0}
 
 
 def test_verdict_words_equal_unpack_marks_off_the_word_grid():

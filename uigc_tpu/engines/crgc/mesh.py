@@ -20,8 +20,9 @@ The host keeps its mirror (interning, edge dict, sweep bookkeeping) and
 streams *only the per-wake changes* to the device: dirty node rows
 (``_node_log``) and pair transitions (``_pair_log``) are scatter-applied
 with donated buffers, so steady-state host->device traffic is O(churn),
-not O(graph).  Full rebuilds happen only on capacity growth or log
-overflow.
+not O(graph).  Full rebuilds happen only on capacity growth, on log
+overflow, or where the insert buckets overflow at their ceiling (below
+it they grow in place to what they hold: ``_grow_buckets``).
 
 Composes with the multi-node path: a cluster of collectors can each run
 a mesh graph and still gossip DeltaGraphs/undo logs between hosts — the
@@ -63,6 +64,14 @@ _SCATTER_PAD = 4096
 #: when it comes.  Each length is four programs of ~2.6 s on a cold
 #: four-chip host (PERF.md section 6, PR 49)
 _WARM_SHARE = 64
+#: the insert bucket tier's columns a shard after a pack, until the pairs
+#: it holds ask for more: the one-chip live tier's floor
+#: (``IncrementalPallasLayout._xla_cap``).  Every sweep of a wake pays for
+#: every column on every shard, full or empty (``pt.build_sweep_contribs``'
+#: ``"xla"`` branch: ~17 ns a column a sweep on the v5e for the gather, the
+#: scatter-max and its sort), so the tier is sized by what it holds, not
+#: by the capacity (PERF.md section 6, PR 50)
+_BUCKET_FLOOR = 1024
 #: the sharded wake's counters of a shard's OWN work (its kernel's steps,
 #: the tiles it forced or skipped); every other one is decided on the
 #: gathered table and reads alike on every shard
@@ -187,7 +196,8 @@ class MeshShadowGraph(ArrayShadowGraph):
         self._pb_free: List[List[int]] = []
         #: packed (src, dst, kind) key -> packed (shard << 32 | column)
         self._pb_slot = PackedSlotMap()
-        self.stats = {"rebuilds": 0, "wakes": 0, "anomalies": 0}
+        #: ``bucket_grows``: growths of the insert bucket tier in place
+        self.stats = {"rebuilds": 0, "wakes": 0, "anomalies": 0, "bucket_grows": 0}
 
         #: per-wake closure+repair detection on the mesh
         #: (parallel/sharded_trace.make_sharded_decremental_wake)
@@ -247,8 +257,10 @@ class MeshShadowGraph(ArrayShadowGraph):
             if events.recorder.enabled:
                 # Compile-cache plane (telemetry/device.py): a miss here
                 # means a NEW collective program geometry.  One miss per
-                # geometry is healthy; a per-wake miss stream for one
-                # (tag, geom) is the recompile_storm alert's input.
+                # geometry is healthy (a growth of the insert buckets is
+                # one: ``_bucket_m`` is in the key); a per-wake miss
+                # stream for one (tag, geom) is the recompile_storm
+                # alert's input.
                 events.recorder.commit(
                     events.COMPILE,
                     duration_s=_time.perf_counter() - t0,
@@ -319,11 +331,11 @@ class MeshShadowGraph(ArrayShadowGraph):
             self._jump_dev = None  # re-uploaded (replicated) on first sync
 
         # --- empty insert buckets --------------------------------- #
-        # Sized so the bucket tier absorbs a meaningful fraction of the
-        # graph's scale in new pairs before the next rebuild folds them
-        # into the packed base (the freeze/consolidate analogue).
-        m = _pow2(max(1024, self.capacity // (4 * D)))
-        self._bucket_m = m
+        # At the floor, or at the largest size this graph has grown them
+        # to (``_grow_buckets``): like the one-chip ``_xla_cap`` the tier
+        # never shrinks, so a pack keeps the programs the steady state
+        # compiled.
+        m = self._bucket_m = max(_BUCKET_FLOOR, self._bucket_m)
         self._pb_src = np.full((D, m), self._n_pad, dtype=np.int32)
         self._pb_dst = np.zeros((D, m), dtype=np.int32)
         self._pb_count = np.zeros(D, dtype=np.int64)
@@ -370,10 +382,50 @@ class MeshShadowGraph(ArrayShadowGraph):
     # Incremental device sync (O(churn) per wake)
     # ------------------------------------------------------------- #
 
+    def _bucket_ceiling(self) -> int:
+        """The most columns a shard the insert buckets grow to: a
+        meaningful fraction of the graph's scale in new pairs, past which
+        a pack folds them into the packed base (the freeze/consolidate
+        analogue)."""
+        return _pow2(max(_BUCKET_FLOOR, self.capacity // (4 * self.n_devices)))
+
+    def _bucket_fill(self, new=0) -> int:
+        """The fullest shard's bucket columns in use, with ``new`` more
+        a shard."""
+        free = np.fromiter(map(len, self._pb_free), np.int64, self.n_devices)
+        return int((self._pb_count - free + new).max())
+
+    def _grow_buckets(self, need: int) -> None:
+        """Widen the insert buckets in place so that ``need`` columns a
+        shard fill at most half of them (a draw's fluctuation then cannot
+        cross the size again inside a window), or to the ceiling.
+        Columns keep their index, so the slot map, the free lists and the
+        counts stand, the pairs are where they were and the previous
+        fixpoint stays valid.  The wider ``[D, M]`` is a new wake program
+        (``_shared_program``'s key) and a new ``pairs`` scatter at every
+        padded length: the scatters are run here, all padding, so that
+        only the wake that grows compiles (the one device work of the
+        ``layout`` phase: the buckets are put whole, 8 bytes a column,
+        before the wake's own writes are scattered into them)."""
+        import jax
+
+        m = min(self._bucket_ceiling(), _pow2(2 * need))
+        pad = ((0, 0), (0, m - self._bucket_m))
+        self._pb_src = np.pad(self._pb_src, pad, constant_values=self._n_pad)  # sink
+        self._pb_dst = np.pad(self._pb_dst, pad)
+        self._bucket_m = m
+        self.stats["bucket_grows"] += 1
+        _, pairs_s, _ = self._sharding()
+        self._dev_psrc = jax.device_put(self._pb_src, pairs_s)
+        self._dev_pdst = jax.device_put(self._pb_dst, pairs_s)
+        for kp in self._warm_lengths():
+            self._scatter_pairs(*self._pairs_batch(kp))
+
     def _apply_pair_log(self) -> Optional[list]:
         """Fold pair transitions into the host plane; returns the bucket
-        device-scatter batch, or None if the buckets overflowed (full
-        rebuild required).  Deletions hitting the packed base mask its
+        device-scatter batch, or None if the buckets overflowed their
+        ceiling (full rebuild required; below it they grow in place).
+        Deletions hitting the packed base mask its
         slot in place (host + queued device mask); deletions hitting the
         bucket free its column; inserts land in the bucket tier.
 
@@ -452,6 +504,16 @@ class MeshShadowGraph(ArrayShadowGraph):
                 self._base_slot.get_batch(inserts) >= 0
             )
             srcs, dsts = unpack_keys(inserts)
+            # the fullest shard's columns once the new pairs are in
+            # (the removes above have freed theirs)
+            new = np.bincount(
+                dsts[~present] // self._shard_size, minlength=self.n_devices
+            )
+            need = self._bucket_fill(new)
+            if need > self._bucket_m:
+                if need > self._bucket_ceiling():
+                    return None  # overflow at the ceiling: pack
+                self._grow_buckets(need)
             for key, src, dst, dup in zip(
                 inserts.tolist(), srcs.tolist(), dsts.tolist(),
                 present.tolist(),
@@ -465,8 +527,6 @@ class MeshShadowGraph(ArrayShadowGraph):
                     colm = free.pop()
                 else:
                     colm = int(self._pb_count[shard])
-                    if colm >= self._bucket_m:
-                        return None  # bucket overflow
                     self._pb_count[shard] = colm + 1
                 self._pb_slot.add(key, (shard << 32) | colm)
                 self._pb_src[shard, colm] = src
@@ -547,8 +607,8 @@ class MeshShadowGraph(ArrayShadowGraph):
         folded into the host plane in O(changes) (``_apply_pair_log``),
         or everything packed from the graph where there is no device
         state, the log overflowed, the capacity outgrew the padding or
-        the insert buckets overflowed.  Returns the bucket scatter batch
-        for ``_sync_upload``, None after a pack."""
+        the insert buckets overflowed their ceiling.  Returns the bucket
+        scatter batch for ``_sync_upload``, None after a pack."""
         log = self._pair_log
         rows = 0 if log is None else len(log)
         writes = None
@@ -559,7 +619,8 @@ class MeshShadowGraph(ArrayShadowGraph):
         if self.profile_wake is not None:
             # as ArrayShadowGraph._synced_dec notes them
             self.profile_wake.note(
-                layout_rows=rows, layout_rebuilt=int(writes is None)
+                layout_rows=rows, layout_rebuilt=int(writes is None),
+                bucket_cols=self._bucket_m, bucket_fill=self._bucket_fill(),
             )
         return writes
 
@@ -690,17 +751,22 @@ class MeshShadowGraph(ArrayShadowGraph):
             # O(graph) node state instead of O(churn) deltas.
             audit_donation("mesh.fold", donated_flags, donated_recv)
 
+    def _warm_lengths(self):
+        """Every padded length this capacity's churn can meet."""
+        kp = _SCATTER_PAD
+        while kp <= _scatter_pad(self._n_pad // _WARM_SHARE):
+            yield kp
+            kp *= 4
+
     def _warm_scatters(self) -> None:
         """Run every scatter at every padded length this capacity's
         churn can meet, all padding, so nothing is written."""
-        kp = _SCATTER_PAD
-        while kp <= _scatter_pad(self._n_pad // _WARM_SHARE):
+        for kp in self._warm_lengths():
             self._scatter_pairs(*self._pairs_batch(kp))
             self._scatter_masks(*self._mask_batch(kp))
             self._scatter_nodes(*self._nodes_batch(kp))
             if self._use_jump:
                 self._scatter_jump(*self._jump_batch(kp))
-            kp *= 4
 
     # ------------------------------------------------------------- #
     # Trace

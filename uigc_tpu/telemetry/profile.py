@@ -32,7 +32,9 @@ since the wake before, both arrays at capacity where a wake uploaded
 them whole, 0 where no slot was written), and ``layout_rows`` with
 ``layout_rebuilt`` (the pair transitions the ``layout`` phase folded; 1
 where it packed the layout from the graph): 0 where a backend has nothing
-to count; and, where a sweep ran,
+to count; on the mesh backends ``bucket_cols`` and ``bucket_fill`` (the
+insert bucket tier's columns a shard, which every sweep pays for, and
+the fullest shard's columns in use); and, where a sweep ran,
 ``actors_local`` and ``actors_foreign``: the slots in use after it by
 kind (actors with a cell; actors held by uid alone), from the graph's
 running counts.
