@@ -71,6 +71,11 @@ def refs(rigs, src, dst, delta):
     each(rigs, lambda rig: rig.deltas(src, dst, np.full(src.size, delta, np.int64)))
 
 
+def free_stack(graph, shard):
+    """The freed bucket columns of a shard, the next to be taken last."""
+    return graph._pb_free[shard, : graph._pb_nfree[shard]].tolist()
+
+
 def wake(rigs):
     """One wake of every graph: the verdict words against the pointer
     oracle's and the simulator's marks, then the sweep.  Returns the
@@ -167,7 +172,7 @@ def test_a_pair_from_before_the_growth_frees_its_own_column(n_devices, mode, mon
         refs(rigs, root, victim, -1)
         assert wake(rigs) == 1  # it stopped propagating: its target died
         assert grown._pb_slot.get_batch(key)[0] == -1
-        assert grown._pb_free[0] == [col] and grown._pb_src[0, col] == grown._n_pad
+        assert free_stack(grown, 0) == [col] and grown._pb_src[0, col] == grown._n_pad
         assert np.asarray(grown._dev_psrc)[0, col] == grown._n_pad
         assert rigs[1].wake.fields["bucket_fill"] == 99
         # the next insert takes the freed column
@@ -175,7 +180,7 @@ def test_a_pair_from_before_the_growth_frees_its_own_column(n_devices, mode, mon
         refs(rigs, root, new, 1)
         assert wake(rigs) == 0
         assert grown._pb_slot.get_batch(pack_keys([root], [new], [0]))[0] == packed
-        assert grown._pb_free[0] == [] and grown._pb_count[0] == 100
+        assert free_stack(grown, 0) == [] and grown._pb_count[0] == 100
         assert np.asarray(grown._dev_psrc)[0, col] == root
     for graph, grows in ((grown, 1), (twin, 0)):
         assert graph.stats == {"rebuilds": 1, "wakes": 6, "anomalies": 0,

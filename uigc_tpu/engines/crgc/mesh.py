@@ -72,6 +72,8 @@ _WARM_SHARE = 64
 #: scatter-max and its sort), so the tier is sized by what it holds, not
 #: by the capacity (PERF.md section 6, PR 50)
 _BUCKET_FLOOR = 1024
+#: the bucket scatter batch of a sync whose pair log was empty
+_NO_WRITES = np.zeros((4, 0), dtype=np.int64)
 #: the sharded wake's counters of a shard's OWN work (its kernel's steps,
 #: the tiles it forced or skipped); every other one is decided on the
 #: gathered table and reads alike on every shard
@@ -118,6 +120,19 @@ def _scatter_pad(k: int) -> int:
     while pad < k:
         pad *= 4
     return pad
+
+
+def _split_by_shard(shard: np.ndarray, n_shards: int) -> tuple:
+    """A batch's entries split by destination shard, as every sharded
+    scatter of a sync and the insert buckets' free stacks need them: a
+    stable order of the entries by ``shard`` (a shard's entries keep the
+    batch's order), each entry's shard in that order, its rank among its
+    shard's entries, and the entries a shard."""
+    order = np.argsort(shard, kind="stable")
+    shard = shard[order]
+    counts = np.bincount(shard, minlength=n_shards)
+    rank = np.arange(shard.size) - (np.cumsum(counts) - counts)[shard]
+    return order, shard, rank, counts
 
 
 class MeshShadowGraph(ArrayShadowGraph):
@@ -186,14 +201,17 @@ class MeshShadowGraph(ArrayShadowGraph):
         self._dev_stacked: Optional[dict] = None
         #: packed (src, dst, kind) key -> (shard << 40 | ri << 8 | col)
         self._base_slot = PackedSlotMap()
-        #: queued deletion masks for the device layouts [(shard, ri, col)]
-        self._mask_writes: List[Tuple[int, int, int]] = []
+        #: queued deletion masks for the device layouts: the masked slots'
+        #: packed values, an array a batch of removes
+        self._mask_writes: List[np.ndarray] = []
         # --- insert buckets: XLA scatter-max tier for new pairs ------- #
         self._bucket_m = 0  # columns per shard (pow2)
         self._pb_src: Optional[np.ndarray] = None  # [D, M] global src ids
         self._pb_dst: Optional[np.ndarray] = None  # [D, M] local dst ids
-        self._pb_count: Optional[np.ndarray] = None
-        self._pb_free: List[List[int]] = []
+        self._pb_count: Optional[np.ndarray] = None  # [D] columns handed out
+        #: freed columns, a stack a shard: ``_pb_free[d, :_pb_nfree[d]]``
+        self._pb_free: Optional[np.ndarray] = None  # [D, M]
+        self._pb_nfree: Optional[np.ndarray] = None
         #: packed (src, dst, kind) key -> packed (shard << 32 | column)
         self._pb_slot = PackedSlotMap()
         #: ``bucket_grows``: growths of the insert bucket tier in place
@@ -339,7 +357,8 @@ class MeshShadowGraph(ArrayShadowGraph):
         self._pb_src = np.full((D, m), self._n_pad, dtype=np.int32)
         self._pb_dst = np.zeros((D, m), dtype=np.int32)
         self._pb_count = np.zeros(D, dtype=np.int64)
-        self._pb_free = [[] for _ in range(D)]
+        self._pb_free = np.zeros((D, m), dtype=np.int32)
+        self._pb_nfree = np.zeros(D, dtype=np.int64)
         self._pb_slot = PackedSlotMap()
         self._pair_log = PairLog()
         self._node_log = _NodeLog()
@@ -392,14 +411,13 @@ class MeshShadowGraph(ArrayShadowGraph):
     def _bucket_fill(self, new=0) -> int:
         """The fullest shard's bucket columns in use, with ``new`` more
         a shard."""
-        free = np.fromiter(map(len, self._pb_free), np.int64, self.n_devices)
-        return int((self._pb_count - free + new).max())
+        return int((self._pb_count - self._pb_nfree + new).max())
 
     def _grow_buckets(self, need: int) -> None:
         """Widen the insert buckets in place so that ``need`` columns a
         shard fill at most half of them (a draw's fluctuation then cannot
         cross the size again inside a window), or to the ceiling.
-        Columns keep their index, so the slot map, the free lists and the
+        Columns keep their index, so the slot map, the free stacks and the
         counts stand, the pairs are where they were and the previous
         fixpoint stays valid.  The wider ``[D, M]`` is a new wake program
         (``_shared_program``'s key) and a new ``pairs`` scatter at every
@@ -413,6 +431,7 @@ class MeshShadowGraph(ArrayShadowGraph):
         pad = ((0, 0), (0, m - self._bucket_m))
         self._pb_src = np.pad(self._pb_src, pad, constant_values=self._n_pad)  # sink
         self._pb_dst = np.pad(self._pb_dst, pad)
+        self._pb_free = np.pad(self._pb_free, pad)
         self._bucket_m = m
         self.stats["bucket_grows"] += 1
         _, pairs_s, _ = self._sharding()
@@ -421,17 +440,55 @@ class MeshShadowGraph(ArrayShadowGraph):
         for kp in self._warm_lengths():
             self._scatter_pairs(*self._pairs_batch(kp))
 
-    def _apply_pair_log(self) -> Optional[list]:
+    def _remove_keys(self, karr: np.ndarray) -> Tuple[int, np.ndarray]:
+        """Remove a batch of distinct keys (ascending) from wherever each
+        lives.  A hit in the bucket tier frees its column: the sink is
+        written on the host plane and the column pushed on its shard's
+        free stack.  A hit in the packed base masks its slot in place on
+        the host and queues it for the device, as
+        ``IncrementalPallasLayout._mask_base_slots`` does.  Returns how
+        many keys were found and the freed columns, packed as the slot
+        map holds them (shard << 32 | column)."""
+        from ...ops import pallas_trace as pt
+
+        freed = self._pb_slot.pop_batch(karr)
+        missing = freed < 0
+        masked = self._base_slot.pop_batch(karr[missing])
+        masked = masked[masked >= 0]
+        freed = freed[~missing]
+        order, shard, rank, counts = _split_by_shard(freed >> 32, self.n_devices)
+        cols = (freed & 0xFFFFFFFF)[order]
+        self._pb_src[shard, cols] = self._n_pad  # sink
+        self._pb_dst[shard, cols] = 0
+        self._pb_free[shard, self._pb_nfree[shard] + rank] = cols
+        self._pb_nfree += counts
+        if masked.size:
+            slot = (masked >> 40, (masked >> 8) & 0xFFFFFFFF, masked & 0xFF)
+            self._stacked["row_pos"][slot] = pt._PAD_ROW
+            self._stacked["emeta"][slot] = 0
+            self._mask_writes.append(masked)
+        return freed.size + masked.size, freed
+
+    def _apply_pair_log(self) -> Optional[np.ndarray]:
         """Fold pair transitions into the host plane; returns the bucket
         device-scatter batch, or None if the buckets overflowed their
         ceiling (full rebuild required; below it they grow in place).
         Deletions hitting the packed base mask its
         slot in place (host + queued device mask); deletions hitting the
-        bucket free its column; inserts land in the bucket tier.
+        bucket free its column; inserts land in the bucket tier, in a
+        freed column of their destination's shard before a new one.
+
+        The batch is an int64 array of four rows (shard, column, src,
+        local dst): every bucket column the log touched ONCE, with what
+        the host plane holds after all of it (a column freed and taken
+        again in one log carries the insert's pair; XLA gives a scatter
+        with a duplicated index no order).
 
         Batched like IncrementalPallasLayout.apply_log (the net-effect
-        argument and anomaly accounting live in slotmap.fold_log): slot
-        lookups are one vectorized binary search per batch."""
+        argument and anomaly accounting live in slotmap.fold_log), with
+        no interpreted step per pair: slot lookups are one vectorized
+        binary search per batch, and what is the mesh's own, the split by
+        destination shard, is ``_split_by_shard``."""
         ins, psrc, pdst, kind = self._pair_log.columns()
         if self._use_jump:
             # Batched jump-parent maintenance — the same
@@ -457,84 +514,51 @@ class MeshShadowGraph(ArrayShadowGraph):
             _, fresh = unpack_keys(inserts)
             if fresh.size:
                 self._pending_fresh_dst.append(fresh)
-        writes: Dict[Tuple[int, int], Tuple[int, int]] = {}
-        stacked = self._stacked
 
-        def mask_base(packed: int) -> None:
-            from ...ops import pallas_trace as pt
+        # a remove of what lives nowhere is caller drift
+        found, freed = self._remove_keys(removes)
+        self.stats["anomalies"] += removes.size - found
+        # insert-first/remove-last: net no-op unless the key was
+        # already live (anomalous duplicate insert + real remove).
+        found, freed_cond = self._remove_keys(cond_removes)
+        self.stats["anomalies"] += found
 
-            shard = packed >> 40
-            ri = (packed >> 8) & 0xFFFFFFFF
-            col = packed & 0xFF
-            stacked["row_pos"][shard, ri, col] = pt._PAD_ROW
-            stacked["emeta"][shard, ri, col] = 0
-            self._mask_writes.append((shard, ri, col))
-
-        def free_slot_batch(karr: np.ndarray, found_is_anomaly: bool) -> None:
-            bucket_vals = self._pb_slot.pop_batch(karr)
-            missing = bucket_vals < 0
-            base_vals = np.full(karr.size, -1, dtype=np.int64)
-            if missing.any():
-                base_vals[missing] = self._base_slot.pop_batch(karr[missing])
-            for bval, sval in zip(bucket_vals.tolist(), base_vals.tolist()):
-                if bval >= 0:
-                    if found_is_anomaly:
-                        self.stats["anomalies"] += 1
-                    shard, colm = bval >> 32, bval & 0xFFFFFFFF
-                    self._pb_src[shard, colm] = self._n_pad  # sink
-                    self._pb_dst[shard, colm] = 0
-                    self._pb_free[shard].append(colm)
-                    writes[(shard, colm)] = (self._n_pad, 0)
-                elif sval >= 0:
-                    if found_is_anomaly:
-                        self.stats["anomalies"] += 1
-                    mask_base(sval)
-                elif not found_is_anomaly:
-                    self.stats["anomalies"] += 1
-
-        if removes.size:
-            free_slot_batch(removes, found_is_anomaly=False)
-        if cond_removes.size:
-            # insert-first/remove-last: net no-op unless the key was
-            # already live (anomalous duplicate insert + real remove).
-            free_slot_batch(cond_removes, found_is_anomaly=True)
-
-        if inserts.size:
-            present = (self._pb_slot.get_batch(inserts) >= 0) | (
-                self._base_slot.get_batch(inserts) >= 0
-            )
-            srcs, dsts = unpack_keys(inserts)
-            # the fullest shard's columns once the new pairs are in
-            # (the removes above have freed theirs)
-            new = np.bincount(
-                dsts[~present] // self._shard_size, minlength=self.n_devices
-            )
-            need = self._bucket_fill(new)
-            if need > self._bucket_m:
-                if need > self._bucket_ceiling():
-                    return None  # overflow at the ceiling: pack
-                self._grow_buckets(need)
-            for key, src, dst, dup in zip(
-                inserts.tolist(), srcs.tolist(), dsts.tolist(),
-                present.tolist(),
-            ):
-                if dup:
-                    self.stats["anomalies"] += 1
-                    continue
-                shard = dst // self._shard_size
-                free = self._pb_free[shard]
-                if free:
-                    colm = free.pop()
-                else:
-                    colm = int(self._pb_count[shard])
-                    self._pb_count[shard] = colm + 1
-                self._pb_slot.add(key, (shard << 32) | colm)
-                self._pb_src[shard, colm] = src
-                local = dst - shard * self._shard_size
-                self._pb_dst[shard, colm] = local
-                writes[(shard, colm)] = (src, local)
+        present = (self._pb_slot.get_batch(inserts) >= 0) | (
+            self._base_slot.get_batch(inserts) >= 0
+        )
+        keys = inserts[~present]
+        srcs, dsts = unpack_keys(keys)
+        order, shard, rank, new = _split_by_shard(
+            dsts // self._shard_size, self.n_devices
+        )
+        # the fullest shard's columns once the new pairs are in
+        # (the removes above have freed theirs)
+        need = self._bucket_fill(new)
+        if need > self._bucket_m:
+            if need > self._bucket_ceiling():
+                return None  # overflow at the ceiling: pack
+            self._grow_buckets(need)
+        # a duplicate insert of a live pair is caller drift too
+        self.stats["anomalies"] += inserts.size - keys.size
+        # a shard's new pairs pop its free stack, then count on
+        free = self._pb_nfree[shard]
+        reused = rank < free
+        cols = np.where(
+            reused,
+            self._pb_free[shard, np.maximum(free - 1 - rank, 0)],
+            self._pb_count[shard] + rank - free,
+        )
+        popped = np.minimum(new, self._pb_nfree)
+        self._pb_nfree -= popped
+        self._pb_count += new - popped
+        taken = (shard << 32) | cols
+        self._pb_slot.add_batch(keys[order], taken)
+        self._pb_src[shard, cols] = srcs[order]
+        self._pb_dst[shard, cols] = dsts[order] - shard * self._shard_size
         self._pair_log.clear()
-        return list(writes.items())
+        touched = np.unique(np.concatenate([freed, freed_cond, taken]))
+        shs, cols = touched >> 32, touched & 0xFFFFFFFF
+        return np.stack([shs, cols, self._pb_src[shs, cols], self._pb_dst[shs, cols]])
 
     def _jit(self, name, builder):
         fn = self._jit_cache.get(name)
@@ -602,58 +626,60 @@ class MeshShadowGraph(ArrayShadowGraph):
         bytes handed over for node features (``_sync_upload``)."""
         return self._sync_upload(self._sync_layout())
 
-    def _sync_layout(self) -> Optional[list]:
+    def _sync_layout(self) -> Optional[np.ndarray]:
         """Layout maintenance, the host's share of a sync: the pair log
         folded into the host plane in O(changes) (``_apply_pair_log``),
         or everything packed from the graph where there is no device
         state, the log overflowed, the capacity outgrew the padding or
         the insert buckets overflowed their ceiling.  Returns the bucket
-        scatter batch for ``_sync_upload``, None after a pack."""
+        scatter batch for ``_sync_upload`` (``_apply_pair_log``'s four
+        rows, no column where the log was empty), None after a pack."""
         log = self._pair_log
         rows = 0 if log is None else len(log)
         writes = None
         if self._dev_ready and log is not None and self._n_pad >= self.capacity:
-            writes = self._apply_pair_log() if rows else []
+            writes = self._apply_pair_log() if rows else _NO_WRITES
         if writes is None:
             self._pack_from_graph()
         if self.profile_wake is not None:
-            # as ArrayShadowGraph._synced_dec notes them
+            # as ArrayShadowGraph._synced_dec notes them; the last two
+            # are what the wake's two layout scatters will carry
             self.profile_wake.note(
                 layout_rows=rows, layout_rebuilt=int(writes is None),
                 bucket_cols=self._bucket_m, bucket_fill=self._bucket_fill(),
+                bucket_writes=0 if writes is None else writes.shape[1],
+                base_masks=sum(masked.size for masked in self._mask_writes),
             )
         return writes
 
-    def _sync_upload(self, pair_writes: Optional[list]) -> int:
+    def _sync_upload(self, pair_writes: Optional[np.ndarray]) -> int:
         """The device's share of a sync: every operand whole after a
         pack (``pair_writes`` None), else O(churn) scatters into donated
-        buffers: the bucket writes, the base layouts' deletion masks,
-        the dirty node rows, the jump parents.  Returns the bytes handed
-        over for node features (``upload_bytes``)."""
+        buffers: the bucket writes (``_sync_layout``'s four rows, copied
+        into the padded batch), the base layouts' deletion masks (the
+        queued packed slots, split by shard), the dirty node rows, the
+        jump parents.  Returns the bytes handed over for node features
+        (``upload_bytes``)."""
         if pair_writes is None:
             return self._upload_all()
         nbytes = 0
-        if pair_writes:
-            batch = self._pairs_batch(_scatter_pad(len(pair_writes)))
-            shs, cols, srcs, dsts = batch
-            for i, ((sh, colm), (s, d)) in enumerate(pair_writes):
-                shs[i], cols[i], srcs[i], dsts[i] = sh, colm, s, d
+        D = self.n_devices
+        k = pair_writes.shape[1]
+        if k:
+            batch = self._pairs_batch(_scatter_pad(k))
+            for padded, row in zip(batch, pair_writes):
+                padded[:k] = row
             self._scatter_pairs(*batch)
 
         if self._mask_writes:
             # base-layout deletions: per-shard in-place masking
-            D = self.n_devices
-            per_shard: List[List[Tuple[int, int]]] = [[] for _ in range(D)]
-            for shard, ri, colm in self._mask_writes:
-                per_shard[shard].append((ri, colm))
+            masked = np.concatenate(self._mask_writes)
             self._mask_writes = []
-            ri, col = self._mask_batch(
-                _scatter_pad(max(len(p) for p in per_shard))
-            )
-            for d in range(D):
-                for i, (r, c) in enumerate(per_shard[d]):
-                    ri[d, i] = r
-                    col[d, i] = c
+            order, shard, rank, counts = _split_by_shard(masked >> 40, D)
+            masked = masked[order]
+            ri, col = self._mask_batch(_scatter_pad(int(counts.max())))
+            ri[shard, rank] = (masked >> 8) & 0xFFFFFFFF
+            col[shard, rank] = masked & 0xFF
             self._scatter_masks(ri, col)
 
         slots_arr = self._node_log.take()
@@ -663,18 +689,11 @@ class MeshShadowGraph(ArrayShadowGraph):
             # scatter-applies only its own shard's rows — recv as deltas
             # against the synced mirror, flags as set/clear masks that
             # reproduce absolute assignment ((old | set) & ~clear = new).
-            D = self.n_devices
             ss = self._shard_size
-            shard = slots_arr // ss
-            order = np.argsort(shard, kind="stable")
+            order, shard, col, counts = _split_by_shard(slots_arr // ss, D)
             slots_arr = slots_arr[order]
-            shard = shard[order]
-            counts = np.bincount(shard, minlength=D).astype(np.int64)
             batch = self._nodes_batch(_scatter_pad(int(counts.max(initial=1))))
             lslot, rdelta, fset, fclear = batch
-            starts = np.zeros(D, dtype=np.int64)
-            starts[1:] = np.cumsum(counts)[:-1]
-            col = np.arange(slots_arr.size, dtype=np.int64) - starts[shard]
             new_flags = self.flags[slots_arr]
             new_recv = self.recv_count[slots_arr]
             lslot[shard, col] = (slots_arr - shard * ss).astype(np.int32)

@@ -209,6 +209,10 @@ class PackedSlotMap:
         lookup, and the tombstone keeps the stale bulk slot hidden."""
         self._extra[key] = val
 
+    def add_batch(self, keys: np.ndarray, vals: np.ndarray) -> None:
+        """``add`` for a batch: none of ``keys`` may be present."""
+        self._extra.update(zip(keys.tolist(), vals.tolist()))
+
     def pop(self, key: int) -> Optional[int]:
         val = self._extra.pop(key, None)
         if val is not None:

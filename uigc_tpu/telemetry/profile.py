@@ -34,7 +34,10 @@ them whole, 0 where no slot was written), and ``layout_rows`` with
 where it packed the layout from the graph): 0 where a backend has nothing
 to count; on the mesh backends ``bucket_cols`` and ``bucket_fill`` (the
 insert bucket tier's columns a shard, which every sweep pays for, and
-the fullest shard's columns in use); and, where a sweep ran,
+the fullest shard's columns in use) with ``bucket_writes`` and
+``base_masks`` (the bucket columns and the packed-base slots the wake's
+two layout scatters write: what ``layout`` and ``upload`` moved); and,
+where a sweep ran,
 ``actors_local`` and ``actors_foreign``: the slots in use after it by
 kind (actors with a cell; actors held by uid alone), from the graph's
 running counts.
