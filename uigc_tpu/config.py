@@ -114,13 +114,16 @@ DEFAULTS: Dict[str, Any] = {
     # CycleDetector.scala:48 hard-codes 50ms).
     "uigc.mac.wakeup-interval": 50,
     # Whether the cycle detector actually collects cycles.  The reference's
-    # detector is a stub (reference.conf:48); ours implements SCC-based
-    # detection and this flag gates the kill decision.
+    # detector is a stub (reference.conf:48); ours finds the closed sets
+    # of blocked actors and this flag gates the kill decision.
     "uigc.mac.collect-cycles": True,
-    # Blocked-candidate count at which the cycle detector switches from
-    # host Tarjan to the device SCC kernel (ops/scc.py).  0 forces the
-    # device path; large values keep detection host-side.
-    "uigc.mac.device-scc-threshold": 4096,
+    # Where the cycle detector's blocked table lives and its trace runs:
+    # "array" (ArrayShadowGraph, the numpy trace on the host) or
+    # "decremental" (the same graph traced as the device's wake program,
+    # ops/pallas_decremental.py; interpreted where the platform is no
+    # TPU).  The trace mode and pull density are uigc.crgc.trace-mode's
+    # and uigc.crgc.pull-density's.
+    "uigc.mac.shadow-graph": "array",
     # --- Node transport settings (runtime/node.py; no reference
     # analogue — the reference delegates failure detection to Akka
     # Cluster, we carry our own) ---

@@ -692,9 +692,10 @@ class ArrayShadowGraph:
         sp_parent: np.ndarray,
         sl_seq: Optional[np.ndarray] = None,
         sp_seq: Optional[np.ndarray] = None,
-    ) -> None:
-        """The vectorized scatter-applies shared by both fold planes
-        (object entries and packed rows).
+    ) -> int:
+        """The vectorized scatter-applies shared by the fold planes
+        (object entries, packed rows, weighted snapshots).  Returns how
+        many pairs' net weight the batch changed.
 
         ``sl``/``br``/``rd`` run in queue order; rows with ``br == -1``
         are recv-only (no busy/root write).  ``ek``/``esign`` are packed
@@ -751,12 +752,50 @@ class ArrayShadowGraph:
             self._log_pairs_batch(True, uu, nn, _PAIR_SUP)
             self.supervisor[uu] = nn
 
-        if ek.size:
-            u, inv = np.unique(ek, return_inverse=True)
-            delta = np.zeros(u.size, dtype=np.int64)
-            np.add.at(delta, inv, esign)
-            nz = delta != 0
-            self._apply_edge_deltas(u[nz], delta[nz])
+        if not ek.size:
+            return 0
+        u, inv = np.unique(ek, return_inverse=True)
+        delta = np.zeros(u.size, dtype=np.int64)
+        np.add.at(delta, inv, esign)
+        nz = delta != 0
+        self._apply_edge_deltas(u[nz], delta[nz])
+        return int(np.count_nonzero(nz))
+
+    # ------------------------------------------------------------- #
+    # Weighted-snapshot fold (the MAC cycle detector's plane)
+    # ------------------------------------------------------------- #
+
+    def merge_weighted(
+        self,
+        sl: np.ndarray,
+        br: np.ndarray,
+        rd: np.ndarray,
+        ek: np.ndarray,
+        ew: np.ndarray,
+    ) -> bool:
+        """Fold a batch of weighted snapshot rows
+        (engines/mac/detector.py): ``sl``/``br``/``rd`` as
+        :meth:`_apply_batch` takes them (a self row writes busy, last
+        writer wins; every row adds ``rd`` to its slot's ``recv_count``,
+        under MAC the slot's weight balance), ``ek`` packed
+        ``owner << 32 | target`` keys whose pairs' weights change by
+        ``ew``, of either sign (a snapshot that enters the candidates
+        adds its weights, one that leaves takes them out again; deltas
+        commute, so the batch's order is free).  No supervisors, no
+        flush stamps: the detector's one queue orders its rows.
+
+        Returns whether the batch changed anything a trace reads (a
+        flag, a balance, a pair's weight): an actor that unblocked and
+        blocked again with the snapshot it had changes nothing."""
+        touched = np.unique(sl)
+        flags_before = self.flags[touched]
+        recv_before = self.recv_count[touched]
+        pairs = self._apply_batch(sl, br, rd, ek, ew, _NO_UIDS, _NO_UIDS)
+        return bool(
+            pairs
+            or (self.flags[touched] != flags_before).any()
+            or (self.recv_count[touched] != recv_before).any()
+        )
 
     # ------------------------------------------------------------- #
     # Packed-plane fold (packed.py row layout)
@@ -1378,6 +1417,38 @@ class ArrayShadowGraph:
             ev.fields["num_live_actors"] = n_live
         return n_garbage
 
+    def unmarked_slots(self) -> np.ndarray:
+        """The trace without the sweep: the slots in use that the trace
+        left unmarked, ascending.  Nothing is stopped and nothing freed:
+        a caller whose protocol asks the suspects first (MAC's
+        ``CNF``/``ACK`` round) frees them later, by
+        :meth:`stop_and_free`."""
+        with events.recorder.timed(events.TRACING) as ev:
+            garbage_slots, _, n_live = self._verdict_slots(self.compute_marks())
+            ev.fields["num_garbage_actors"] = int(garbage_slots.size)
+            ev.fields["num_live_actors"] = n_live
+        return garbage_slots
+
+    def stop_and_free(self, slots: np.ndarray, message) -> None:
+        """The sweep of a caller that reached its verdict by a protocol
+        of its own: every cell of ``slots`` is told ``message`` in one
+        bulk teardown, then the slots are freed.  The same ``sweep``
+        phase, stamps and counters on the active wake's record as
+        :meth:`_sweep`'s, so the stop cascade is timed as it is there."""
+        wake = self.profile_wake
+        with events.wake_phase(wake, "sweep"), events.recorder.timed(events.SWEEP):
+            if wake is not None:
+                wake.note(freed_local=_stamp_freed(self.cells, slots, wake.ordinal))
+            self._kill_slots_bulk(slots, message)
+            _, examined = self._free_slots_batch(slots)
+            if wake is not None:
+                wake.note(
+                    kills=int(slots.size),
+                    freed=int(slots.size),
+                    sweep_edge_slots=examined,
+                    actors_local=len(self.slot_of),
+                )
+
     def _verdict_slots(self, verdicts) -> Tuple[np.ndarray, np.ndarray, int]:
         """``(garbage_slots, kill_slots, num_live)`` of a trace's
         verdicts over the graph's ``flags`` and ``supervisor``, which
@@ -1466,8 +1537,8 @@ class ArrayShadowGraph:
         is_foreign = codes >= FOREIGN_BIT
         return is_foreign, codes[is_foreign] ^ FOREIGN_BIT
 
-    def _kill_slots_bulk(self, kill_slots: np.ndarray) -> np.ndarray:
-        """Send StopMsg to every kill slot's cell as ONE bulk teardown:
+    def _kill_slots_bulk(self, kill_slots: np.ndarray, message=StopMsg) -> np.ndarray:
+        """Send ``message`` to every kill slot's cell as ONE bulk teardown:
         the finalize cascade is batched per dispatcher (and, for remote
         cells, per peer writer), so a wake that kills K actors costs
         O(batches) dispatcher operations, not O(K).  Slots of foreign
@@ -1480,7 +1551,7 @@ class ArrayShadowGraph:
             is_foreign, kill_uids = self._foreign_among(kill_slots)
             kill_slots = kill_slots[~is_foreign]
         cells = self.cells
-        tell_bulk((cells[slot], StopMsg) for slot in kill_slots.tolist())
+        tell_bulk((cells[slot], message) for slot in kill_slots.tolist())
         return kill_uids
 
     def _free_slots_batch(self, garbage_slots: np.ndarray) -> tuple:

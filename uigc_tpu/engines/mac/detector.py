@@ -1,4 +1,4 @@
-"""MAC cycle detector with real SCC-based collection.
+"""MAC cycle detector: closed garbage sets found by the shadow-graph backend.
 
 The reference's detector only echoes CNF probes at apparently-blocked
 actors and "doesn't actually detect garbage" (reference: reference.conf:48,
@@ -7,47 +7,114 @@ mac/CycleDetector.scala:42-97).  This detector completes the algorithm:
 1. Blocked actors send BLK snapshots carrying their reference count, their
    weight table, and their child count (the protocol channel mirrors
    reference: CycleDetector.scala:16-39, extended with rc/children).
-2. The detector finds strongly connected components among blocked,
-   childless actors and checks each candidate cycle is *closed*: every
-   member's rc is exactly the sum of weights held by members toward it —
-   no external actor can ever message the cycle.
-3. Closed cycles are probed with CNF(token); members still blocked ACK
-   (reference protocol, CycleDetector.scala:63-81).  Because in-process
-   enqueue order is causal here (single node, like the reference's
-   causal-delivery requirement), an app message racing the probe always
-   lands before the CNF and triggers UNB, invalidating the token.
-4. Fully ACKed cycles are garbage: members receive KillMsg.
+2. The detector keeps the blocked table as array state, an
+   ``ArrayShadowGraph`` (engines/crgc/arrays.py) that a wake changes by
+   what arrived.  With ``B`` the actors whose latest word is a BLK and the
+   candidates ``C`` those of ``B`` that are childless and in no pending
+   confirmation:
+
+   - a slot an actor the detector knows; ``recv_count[slot]`` is its
+     weight balance, ``rc + RC_INC`` less the weights the candidates'
+     snapshots hold towards it (its own entry, ``RC_INC`` included, too).
+     A balance other than 0 says that an actor outside ``C`` holds weight
+     or that weight is in flight (a ``DecMsg``, an ``IncMsg``, a ref in a
+     message): the slot is a seed, ``pseudoroots_np``'s ``recv_count !=
+     0``;
+   - ``FLAG_BUSY`` on a known slot that is not in ``C`` (a seed as well);
+   - a pair ``owner -> target`` of the snapshot's weight while the owner
+     is in ``C``, so a mark goes from a live candidate to whatever it can
+     still message.  An actor's own entry counts in its balance and is no
+     pair: a self-loop carries a mark nowhere.
+
+   An actor that leaves ``C`` (a UNB, a pending token) takes its
+   snapshot's pairs and weights out again: signed deltas through the
+   backend's weighted fold (``merge_weighted``), which commute.  The trace
+   is the CRGC trace over those columns, on the host or as the device's
+   wake program, and what it leaves unmarked, ``G``, is the greatest
+   closed set of candidates: every holder of a member is a member and no
+   weight is outside.  No strongly connected component is computed
+   anywhere; a garbage ring that only another garbage ring points to is
+   in ``G`` with it.
+3. ``G`` is probed with one CNF(token) a wake; members still blocked ACK
+   (reference protocol, CycleDetector.scala:63-81), and leave ``C`` while
+   they wait, so a later wake's ``G`` is closed without them.  Because
+   in-process enqueue order is causal here (single node, like the
+   reference's causal-delivery requirement), an app message racing the
+   probe always lands before the CNF and triggers UNB, which voids the
+   token: its other members return to ``C`` in that wake and are asked
+   again by that wake's trace, so a UNB costs the rest of ``G`` one wake.
+4. A token whose members all ACKed is garbage: the wake that drains its
+   last ACK, the tick after the one that asked, sends the members
+   KillMsg and frees their slots.
 
 Cycles containing actors with children are left uncollected (killing a
 parent cascades to children the detector can't reason about) — sound but
 deliberately incomplete, like the reference's supervisor marking
-(ShadowGraph.java:242-267).
+(ShadowGraph.java:242-267).  Killed members send no ``DecMsg`` for what
+they hold, so an actor outside ``G`` that a member pointed to keeps a
+balance above 0 and is never a candidate for collection again, as before.
+
+A wake that finds the queue empty and no token open touches neither the
+table nor the device and leaves no record, and the trace runs only in a
+wake whose batch changed a flag, a balance or a pair.  Under a wake
+profiler (``Engine.wake_profiler``) a wake leaves the collector's
+record: phases ``ingest`` (the drain into columns), ``fold`` (the
+batch), ``sweep`` around kill-and-free, ``trace`` (the verdict's slots
+and the probe) with the backend's own ``layout``/``upload``/``device``/
+``readback`` inside, and the counters ``blk_rows``, ``unb_rows``,
+``ack_rows``, ``candidates``, ``seeds``, ``cnf_sent``, ``tokens_open``,
+``tokens_void``, ``kills``.
 """
 
 from __future__ import annotations
 
 import itertools
-from typing import TYPE_CHECKING, Any, Dict, List, Set, Tuple
+import time
+from typing import TYPE_CHECKING, Any, Callable, Dict, List, Optional, Set, Tuple
 
+import numpy as np
+
+from ...ops import trace as trace_ops
 from ...runtime.behaviors import RawBehavior
 from ...utils import events
+from ...utils.validation import require
+from ..crgc.arrays import ArrayShadowGraph
+from ..crgc.state import CrgcContext
+from .engine import CNF, RC_INC, KillMsg
 
 if TYPE_CHECKING:  # pragma: no cover
-    from ...runtime.cell import ActorCell
     from .engine import MAC
+
+#: the values of ``uigc.mac.shadow-graph`` (config.py describes each)
+SHADOW_GRAPHS = ("array", "decremental")
+#: seconds a token may wait for its ACKs before it is voided and its
+#: members asked again: a member that died by other hands (a parent's
+#: stop) between the probe's liveness check and the CNF answers nothing
+TOKEN_PATIENCE_S = 20.0
+
+_BUSY = int(trace_ops.FLAG_BUSY)
+_IN_C_MASK = np.uint8(
+    int(trace_ops.FLAG_IN_USE) | int(trace_ops.FLAG_INTERNED) | _BUSY
+)
+_IN_C = np.uint8(int(trace_ops.FLAG_IN_USE) | int(trace_ops.FLAG_INTERNED))
 
 
 class BLK:
     """Actor has blocked (reference: CycleDetector.scala:18-23, extended
-    with rc and child count for closedness checking)."""
+    with rc and child count for closedness checking).  ``slots`` is the
+    detector's: the targets' slots while the snapshot is among the
+    candidates, None otherwise; ``token`` the pending confirmation the
+    sender is a member of, 0 for none."""
 
-    __slots__ = ("sender", "rc", "actor_map", "num_children")
+    __slots__ = ("sender", "rc", "actor_map", "num_children", "slots", "token")
 
     def __init__(self, sender, rc, actor_map, num_children):
         self.sender = sender
         self.rc = rc
         self.actor_map = actor_map  # list of (target_cell, weight)
         self.num_children = num_children
+        self.slots: Optional[List[int]] = None
+        self.token = 0
 
 
 class UNB:
@@ -70,60 +137,56 @@ class ACK:
         self.token = token
 
 
+class Audit:
+    """Ask the detector, through its mailbox and so on its own thread,
+    for :meth:`CycleDetector.audit`; ``reply`` is called with it."""
+
+    __slots__ = ("reply",)
+
+    def __init__(self, reply: Callable[[tuple], None]):
+        self.reply = reply
+
+
 class _Wakeup:
+    """The timer's tick."""
+
     __slots__ = ()
 
 
 WAKEUP = _Wakeup()
 
 
-def strongly_connected_components(
-    nodes: List[Any], edges: Dict[Any, List[Any]]
-) -> List[List[Any]]:
-    """Iterative Tarjan SCC over the blocked-actor graph."""
-    index_of: Dict[Any, int] = {}
-    lowlink: Dict[Any, int] = {}
-    on_stack: Set[Any] = set()
-    stack: List[Any] = []
-    sccs: List[List[Any]] = []
-    counter = itertools.count()
+class _Token:
+    """One pending confirmation: a wake's ``G`` by slot, the ACKs still
+    to come, and when it was asked (the detector's clock)."""
 
-    for root in nodes:
-        if root in index_of:
-            continue
-        work = [(root, iter(edges.get(root, ())))]
-        index_of[root] = lowlink[root] = next(counter)
-        stack.append(root)
-        on_stack.add(root)
-        while work:
-            node, it = work[-1]
-            advanced = False
-            for succ in it:
-                if succ not in index_of:
-                    index_of[succ] = lowlink[succ] = next(counter)
-                    stack.append(succ)
-                    on_stack.add(succ)
-                    work.append((succ, iter(edges.get(succ, ()))))
-                    advanced = True
-                    break
-                elif succ in on_stack:
-                    lowlink[node] = min(lowlink[node], index_of[succ])
-            if advanced:
-                continue
-            work.pop()
-            if work:
-                parent = work[-1][0]
-                lowlink[parent] = min(lowlink[parent], lowlink[node])
-            if lowlink[node] == index_of[node]:
-                scc = []
-                while True:
-                    member = stack.pop()
-                    on_stack.discard(member)
-                    scc.append(member)
-                    if member is node:
-                        break
-                sccs.append(scc)
-    return sccs
+    __slots__ = ("slots", "waiting", "asked")
+
+    def __init__(self, slots: np.ndarray, asked: float):
+        self.slots = slots
+        self.waiting = int(slots.size)
+        self.asked = asked
+
+
+class _Rows:
+    """A wake's batch as the columns ``merge_weighted`` takes."""
+
+    __slots__ = ("sl", "br", "rd", "ek", "ew")
+
+    def __init__(self) -> None:
+        self.sl: List[int] = []
+        self.br: List[int] = []
+        self.rd: List[int] = []
+        self.ek: List[int] = []
+        self.ew: List[int] = []
+
+    def fold_into(self, graph: ArrayShadowGraph) -> bool:
+        if not self.sl:
+            return False
+        return graph.merge_weighted(*(
+            np.asarray(column, dtype=np.int64)
+            for column in (self.sl, self.br, self.rd, self.ek, self.ew)
+        ))
 
 
 class CycleDetector(RawBehavior):
@@ -133,20 +196,38 @@ class CycleDetector(RawBehavior):
         self.engine = engine
         self.cell: Any = None
         self.total_entries = 0
+        #: confirmed garbage sets killed (a wake's ``G`` is one)
         self.total_cycles_collected = 0
         self._timer_keys: list = []
-        self.device_scc_threshold = 1 << 30  # set from config in bind()
-        #: blocked actors and their latest BLK snapshot
-        self.blocked: Dict[Any, BLK] = {}
-        #: outstanding confirmation: token -> (members, acks-received)
-        self.pending: Dict[int, Tuple[Set[Any], Set[Any]]] = {}
+        config = engine.system.config
+        impl = config.get_string("uigc.mac.shadow-graph")
+        require(
+            impl in SHADOW_GRAPHS, "config.mac_shadow_graph",
+            "bad uigc.mac.shadow-graph", impl=impl, valid=SHADOW_GRAPHS,
+        )
+        #: the blocked table: slots, balances (``recv_count``), weighted
+        #: pairs; the backend CRGC's ``array`` and ``decremental`` are
+        self.graph = ArrayShadowGraph(
+            CrgcContext(0, 0),
+            engine.system.address,
+            use_device=(impl == "decremental"),
+            trace_mode=config.get_string("uigc.crgc.trace-mode"),
+            pull_density=config.get_float("uigc.crgc.pull-density"),
+        )
+        #: slot -> the latest BLK of an actor whose latest word it is
+        self.blocked: Dict[int, BLK] = {}
+        #: outstanding confirmations by token
+        self.pending: Dict[int, _Token] = {}
         self._token_counter = itertools.count(1)
+        #: how many of ``blocked`` are candidates now
+        self.candidates = 0
+        #: the clock a token's patience is read on
+        self.clock = time.monotonic
+        #: whom the last wake sent a CNF, its ``G``, by uid
+        self.last_asked: List[int] = []
 
     def bind(self, cell: Any) -> None:
         self.cell = cell
-        self.device_scc_threshold = self.engine.system.config.get_int(
-            "uigc.mac.device-scc-threshold"
-        )
         interval_s = self.engine.system.config.get_int("uigc.mac.wakeup-interval") / 1000.0
         key = ("mac-wakeup", id(self))
         self._timer_keys.append(key)
@@ -162,131 +243,287 @@ class CycleDetector(RawBehavior):
     def on_message(self, msg: Any) -> Any:
         if isinstance(msg, _Wakeup):
             self.scan()
+        elif isinstance(msg, Audit):
+            msg.reply(self.audit())
         return None
 
-    def scan(self) -> None:
-        """Drain the protocol queue, then detect and confirm cycles
-        (reference: CycleDetector.scala:51-89, completed)."""
-        from .engine import CNF, KillMsg
+    # ------------------------------------------------------------- #
+    # The candidates as rows
+    # ------------------------------------------------------------- #
 
-        with events.recorder.timed(events.PROCESSING_MESSAGES) as ev:
-            queue = self.engine.queue
-            count = 0
+    def _enter(self, slot: int, blk: BLK, rows: _Rows) -> None:
+        """``blk``'s sender joins the candidates: its own term into its
+        balance, its snapshot's weights out of its targets' balances and
+        into the pairs."""
+        slot_of = self.graph.slot_of.get
+        slot_for = self.graph.slot_for
+        sl, br, rd, ek, ew = rows.sl, rows.br, rows.rd, rows.ek, rows.ew
+        sl.append(slot)
+        br.append(0)
+        rd.append(blk.rc + RC_INC)
+        blk.slots = targets = []
+        for cell, weight in blk.actor_map:
+            target = slot_of(cell)
+            if target is None:
+                target = slot_for(cell)
+            targets.append(target)
+            sl.append(target)
+            br.append(-1)
+            rd.append(-weight)
+            if target != slot:
+                ek.append((slot << 32) | target)
+                ew.append(weight)
+        self.candidates += 1
+
+    def _leave(self, slot: int, blk: BLK, rows: _Rows) -> None:
+        """:meth:`_enter` taken back, by the slots it left on ``blk``."""
+        sl, br, rd, ek, ew = rows.sl, rows.br, rows.rd, rows.ek, rows.ew
+        sl.append(slot)
+        br.append(_BUSY)
+        rd.append(-(blk.rc + RC_INC))
+        for target, (_, weight) in zip(blk.slots, blk.actor_map):
+            sl.append(target)
+            br.append(-1)
+            rd.append(weight)
+            if target != slot:
+                ek.append((slot << 32) | target)
+                ew.append(-weight)
+        blk.slots = None
+        self.candidates -= 1
+
+    def _unblock(self, slot: int, rows: _Rows) -> int:
+        """The actor at ``slot`` is blocked no more: its snapshot goes,
+        out of the candidates or out of the confirmation it was part
+        of, which that invalidates.  Returns the tokens voided."""
+        blk = self.blocked.pop(slot, None)
+        if blk is None:
+            return 0
+        if blk.slots is not None:
+            self._leave(slot, blk, rows)
+        elif blk.token:
+            self._void(blk.token, rows)
+            return 1
+        return 0
+
+    def _void(self, token: int, rows: _Rows) -> None:
+        """Token ``token`` is off: its members that are still blocked
+        go back among the candidates."""
+        blocked = self.blocked
+        for slot in self.pending.pop(token).slots.tolist():
+            blk = blocked.get(slot)
+            if blk is not None and blk.token == token:
+                blk.token = 0
+                self._enter(slot, blk, rows)
+
+    # ------------------------------------------------------------- #
+    # A wake
+    # ------------------------------------------------------------- #
+
+    def scan(self) -> None:
+        """Drain the protocol queue into one batch, settle the complete
+        confirmations, and if the batch changed the table trace it and
+        probe what the trace left unmarked (reference:
+        CycleDetector.scala:51-89, completed)."""
+        queue = self.engine.queue
+        if not queue and not self.pending:
+            return
+        prof = self.engine.wake_profiler
+        wake = prof.begin_wake() if prof is not None else None
+        graph = self.graph
+        graph.profile_wake = wake
+        count = asked = 0
+        try:
+            count, asked = self._scan(queue, wake)
+        finally:
+            if wake is not None:
+                graph.profile_wake = None
+                wake.end(entries=count, garbage=asked)
+
+    def _scan(self, queue, wake: Any) -> Tuple[int, int]:
+        graph = self.graph
+        blocked = self.blocked
+        pending = self.pending
+        rows = _Rows()
+        n_blk = n_unb = n_ack = n_void = 0
+        self.last_asked = []
+
+        with events.wake_phase(wake, "ingest"), \
+                events.recorder.timed(events.PROCESSING_MESSAGES) as ev:
+            slot_of = graph.slot_of.get
             while True:
                 try:
                     msg = queue.popleft()
                 except IndexError:
                     break
-                count += 1
                 if isinstance(msg, BLK):
-                    self.blocked[msg.sender] = msg
+                    n_blk += 1
+                    cell = msg.sender
+                    slot = slot_of(cell)
+                    if slot is None:
+                        # new to the table: in use and not interned, a
+                        # seed until a snapshot of its own enters
+                        slot = graph.slot_for(cell)
+                    else:
+                        # a BLK follows a UNB (``has_sent_blk``); one
+                        # that does not replaces the snapshot it follows
+                        n_void += self._unblock(slot, rows)
+                    blocked[slot] = msg
+                    if msg.num_children == 0:
+                        self._enter(slot, msg, rows)
                 elif isinstance(msg, UNB):
-                    self.blocked.pop(msg.sender, None)
-                    # Invalidate any pending confirmation involving it.
-                    for token, (members, acks) in list(self.pending.items()):
-                        if msg.sender in members:
-                            del self.pending[token]
+                    n_unb += 1
+                    slot = slot_of(msg.sender)
+                    if slot is not None:
+                        n_void += self._unblock(slot, rows)
                 elif isinstance(msg, ACK):
-                    entry = self.pending.get(msg.token)
-                    if entry is not None:
-                        entry[1].add(msg.sender)
+                    n_ack += 1
+                    token = pending.get(msg.token)
+                    if token is not None:
+                        token.waiting -= 1
+            count = n_blk + n_unb + n_ack
             ev.fields["num_messages"] = count
             self.total_entries += count
+            # a token nobody answers (a member stopped by other hands)
+            # does not keep its other members for good
+            stale = self.clock() - TOKEN_PATIENCE_S
+            for token in [t for t, entry in pending.items() if entry.asked <= stale]:
+                n_void += 1
+                self._void(token, rows)
 
-        # Kill fully-confirmed cycles.
-        if self.engine.collect_cycles:
-            for token, (members, acks) in list(self.pending.items()):
-                if members <= acks and all(m in self.blocked for m in members):
-                    for member in members:
-                        member.tell(KillMsg)
-                        self.blocked.pop(member, None)
-                    del self.pending[token]
-                    self.total_cycles_collected += 1
+        with events.wake_phase(wake, "fold"):
+            changed = rows.fold_into(graph)
 
-        # Detect new candidate cycles among blocked, childless actors.
-        pending_members = set()
-        for members, _ in self.pending.values():
-            pending_members |= members
-        candidates = {
-            cell: blk
-            for cell, blk in self.blocked.items()
-            if blk.num_children == 0 and cell not in pending_members
-        }
-        if not candidates:
+        kills = self._settle()
+        asked = 0
+        touched = rows.sl
+        if changed:
+            # the collector's ``trace`` phase: the backend's own phases
+            # pause it, what stays is the verdict's slots and the probe
+            with events.wake_phase(wake, "trace"):
+                asked = self._probe(graph.unmarked_slots(), touched)
+        self._forget(touched)
+        if wake is not None:
+            f = graph.flags
+            wake.note(
+                blk_rows=n_blk, unb_rows=n_unb, ack_rows=n_ack,
+                candidates=self.candidates,
+                seeds=int(np.count_nonzero(
+                    ((f & _IN_C_MASK) == _IN_C) & (graph.recv_count != 0)
+                )),
+                cnf_sent=asked, tokens_open=len(pending),
+                tokens_void=n_void, kills=kills,
+            )
+        return count, asked
+
+    def _settle(self) -> int:
+        """Kill the confirmations every member of which has ACKed (none
+        has sent a UNB since, or the token would be gone) and free their
+        slots.  Returns how many actors that were."""
+        if not self.engine.collect_cycles:
+            return 0
+        pending = self.pending
+        done = [token for token, entry in pending.items() if entry.waiting <= 0]
+        if not done:
+            return 0
+        slots = np.concatenate([pending.pop(token).slots for token in done])
+        blocked = self.blocked
+        for slot in slots.tolist():
+            del blocked[slot]
+        self.total_cycles_collected += len(done)
+        # their pairs and weights went when they were asked: what is
+        # left of a member is its slot
+        self.graph.stop_and_free(slots, KillMsg)
+        return int(slots.size)
+
+    def _probe(self, garbage_slots: np.ndarray, touched: List[int]) -> int:
+        """Send ``garbage_slots``, a trace's unmarked candidates, one
+        CNF(token) and take them out of the candidates while they answer.
+        A member whose cell is stopping or has stopped (by its parent's
+        hand, not the detector's) is dropped from the table instead: it
+        would answer nothing.  Returns how many were asked; the slots
+        whose balance that changed go onto ``touched``."""
+        if not garbage_slots.size:
+            return 0
+        from ...runtime.cell import tell_bulk
+
+        graph = self.graph
+        blocked = self.blocked
+        cells = graph.cells
+        rows = _Rows()
+        token = next(self._token_counter)
+        ask, gone = [], []
+        for slot in garbage_slots.tolist():
+            blk = blocked[slot]
+            self._leave(slot, blk, rows)
+            if not getattr(cells[slot], "is_active", True):
+                del blocked[slot]
+                gone.append(slot)
+            else:
+                blk.token = token
+                ask.append(slot)
+        # nothing a trace reads afterwards can be new: the members were
+        # unmarked, and as seeds without pairs they mark nobody
+        rows.fold_into(graph)
+        touched.extend(rows.sl)
+        if gone:
+            graph._free_slots_batch(np.asarray(gone, dtype=np.int64))
+        if not ask:
+            return 0
+        self.pending[token] = _Token(np.asarray(ask, dtype=np.int64), self.clock())
+        asked = [cells[slot] for slot in ask]
+        self.last_asked = [cell.uid for cell in asked]
+        cnf = CNF(token)
+        tell_bulk((cell, cnf) for cell in asked)
+        return len(ask)
+
+    def _forget(self, touched: List[int]) -> None:
+        """Free the slots among ``touched`` that say nothing any more:
+        an actor that is not blocked and towards which no candidate
+        holds weight (balance 0) is one the table need not know."""
+        if not touched:
             return
-        edges = {
-            cell: [t for t, w in blk.actor_map if t in candidates and w > 0]
-            for cell, blk in candidates.items()
+        graph = self.graph
+        slots = np.unique(np.asarray(touched, dtype=np.int64))
+        f = graph.flags[slots]
+        idle = (
+            ((f & trace_ops.FLAG_IN_USE) != 0)
+            & ((f & _IN_C_MASK) != _IN_C)
+            & (graph.recv_count[slots] == 0)
+        )
+        blocked = self.blocked
+        free = [slot for slot in slots[idle].tolist() if slot not in blocked]
+        if free:
+            graph._free_slots_batch(np.asarray(free, dtype=np.int64))
+
+    # ------------------------------------------------------------- #
+    # Diagnostics
+    # ------------------------------------------------------------- #
+
+    def audit(self) -> tuple:
+        """``(table, pending, asked, garbage)`` on the detector's thread:
+        the blocked table as ``{uid: (rc, num_children, {uid: weight})}``,
+        the uids in a pending confirmation, those of them the last wake
+        asked (its ``G``, found on this table with the others pending),
+        and the uids a trace of the table as it stands leaves unmarked
+        (nobody is asked or freed).  What a plain reference of the
+        equations is compared with."""
+        cells = self.graph.cells
+        table = {
+            blk.sender.uid: (
+                blk.rc,
+                blk.num_children,
+                {target.uid: weight for target, weight in blk.actor_map},
+            )
+            for blk in self.blocked.values()
         }
-        if len(candidates) >= self.device_scc_threshold:
-            sccs = self._device_sccs(candidates, edges)
-        else:
-            sccs = strongly_connected_components(list(candidates), edges)
-        for scc in sccs:
-            scc_set = set(scc)
-            if not self._is_closed(scc_set, candidates):
-                continue
-            token = next(self._token_counter)
-            self.pending[token] = (scc_set, set())
-            for member in scc:
-                member.tell(CNF(token))
-
-    def _device_sccs(
-        self, candidates: Dict[Any, Any], edges: Dict[Any, List[Any]]
-    ) -> List[List[Any]]:
-        """SCCs via the device kernel (ops/scc.py) for large blocked sets.
-
-        Node and edge counts are padded to powers of two (inactive slots /
-        invalid endpoints), so the jitted kernel recompiles at most
-        log-many times as the blocked population grows."""
-        import numpy as np
-
-        from ...ops import scc as scc_ops
-
-        cells = list(candidates)
-        index = {cell: i for i, cell in enumerate(cells)}
-        src = []
-        dst = []
-        for cell, targets in edges.items():
-            i = index[cell]
-            for t in targets:
-                src.append(i)
-                dst.append(index[t])
-
-        n = len(cells)
-        n_pad = 1 << max(0, (n - 1).bit_length())
-        m_pad = 1 << max(0, (max(1, len(src)) - 1).bit_length())
-        active = np.zeros(n_pad, dtype=bool)
-        active[:n] = True
-        src_a = np.full(m_pad, -1, dtype=np.int32)
-        dst_a = np.full(m_pad, -1, dtype=np.int32)
-        src_a[: len(src)] = src
-        dst_a[: len(dst)] = dst
-
-        labels = scc_ops.scc_labels_jax(n_pad, src_a, dst_a, active)
-        groups: Dict[int, List[Any]] = {}
-        for i, cell in enumerate(cells):
-            groups.setdefault(int(labels[i]), []).append(cell)
-        return list(groups.values())
-
-    def _is_closed(self, scc: Set[Any], candidates: Dict[Any, BLK]) -> bool:
-        """A cycle is closed iff for every member, rc + RC_INC equals the
-        total weight held by members toward it (the initial self-map entry
-        carries RC_INC weight that is never counted in rc — reference:
-        MAC.scala:118-120).  Equality means no external actor holds a
-        reference and no Inc/Dec control messages are in flight, so nothing
-        outside the cycle can ever message it."""
-        from .engine import RC_INC
-
-        for member in scc:
-            inbound = 0
-            for owner in scc:
-                for target, weight in candidates[owner].actor_map:
-                    if target is member:
-                        inbound += weight
-            if candidates[member].rc + RC_INC != inbound:
-                return False
-        return True
+        waiting: Set[int] = {
+            cells[slot].uid
+            for entry in self.pending.values()
+            for slot in entry.slots.tolist()
+        }
+        asked = set(self.last_asked)
+        garbage = {cells[slot].uid for slot in self.graph.unmarked_slots().tolist()}
+        return table, waiting, asked, garbage
 
 
-__all__ = ["ACK", "BLK", "CycleDetector", "UNB", "strongly_connected_components"]
+__all__ = ["ACK", "Audit", "BLK", "CycleDetector", "UNB"]

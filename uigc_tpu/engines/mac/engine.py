@@ -9,8 +9,11 @@ like the reference (README.md:32-40).
 
 The cycle detector (detector.py) goes beyond the reference's stub
 (reference.conf:48 "the cycle detector doesn't actually detect garbage"):
-it runs SCC detection over blocked-actor snapshots and collects confirmed
-closed cycles.
+it folds the blocked actors' snapshots into a shadow-graph backend
+(``uigc.mac.shadow-graph``: CRGC's ``ArrayShadowGraph``, traced on the
+host or as the device's wake program), takes what the trace leaves
+unmarked, the greatest closed set of blocked childless actors, and
+collects it once every member has confirmed.
 """
 
 from __future__ import annotations

@@ -816,6 +816,14 @@ class ActorCell:
         if self.parent is not None:
             self.parent.tell_system(_SysChildTerminated(self))
         self.system.unregister_cell(self)
+        # A terminated cell lets go of what made it a cycle of its own:
+        # its context and its behaviour point back at it and the engine's
+        # hook closes over it.  Reference counts then free a dead actor
+        # (and a dead ring, whose members hold each other's cells); left
+        # in place, every one waits for CPython's full collection.
+        self.behavior = None
+        self.context = None
+        self.on_finished_processing = None
 
     def note_freed(self, wake: int) -> bool:
         """The collector's sweep freed this actor in its wake ``wake``
