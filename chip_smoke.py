@@ -346,8 +346,10 @@ def phase_mesh_data_plane(args, n):
     dev_s = NamedSharding(mesh, P("gc", None))
     dev3_s = NamedSharding(mesh, P("gc", None, None))
     repl_s = NamedSharding(mesh, P())
-    flags = jax.device_put(st.pad_to(graph["flags"], n_pad), nodes_s)
-    recv = jax.device_put(st.pad_to(graph["recv_count"], n_pad), nodes_s)
+    # the node arrays as a shard holds them: its supertiles, dealt round-robin
+    part = st.Partition(D)
+    flags = jax.device_put(part.owner_major(st.pad_to(graph["flags"], n_pad)), nodes_s)
+    recv = jax.device_put(part.owner_major(st.pad_to(graph["recv_count"], n_pad)), nodes_s)
     operands = [
         jax.device_put(stacked["bmeta1"], dev_s),
         jax.device_put(stacked["bmeta2"], dev_s),
@@ -393,14 +395,14 @@ def phase_mesh_data_plane(args, n):
     mark_w, _, _, iu_w, _, walks = out
     for o in out[:5]:
         check(len(o.sharding.device_set) == D, "a wake output is on one chip")
-    # the verdict as the mesh backend reads it: packed words, a shard's
-    # for its own slot range, laid end to end
-    garbage_w, marked = pd.verdict_reduce()(mark_w, iu_w)
+    # the verdict as the mesh backend reads it: packed words put back in
+    # slot order on the device, a D-th a chip, laid end to end
+    garbage_w, marked = st.make_sharded_verdict(mesh)(mark_w, iu_w)
     shards = st.shards_in_order(garbage_w)
     check(
         len(shards) == D
         and all(sh.shape[0] * 32 == meta["shard_size"] for sh in shards),
-        "a shard's verdict words are not those of its slot range",
+        "a chip's verdict words are not a D-th of the slot space",
     )
     words = np.concatenate([np.asarray(sh) for sh in shards]).view(np.uint32)
     garbage = np.unpackbits(words.view(np.uint8), bitorder="little")[:n] > 0

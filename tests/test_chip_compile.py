@@ -50,10 +50,11 @@ GEOM_SMALL = dict(n=20_000, n_blocks=128, n_super=5, r_rows=64)
 MESH_10M = dict(n_pad=10_010_624, n_blocks=16_384, r_rows=2496, bucket_m=1024)
 MESH_SMALL = dict(n_pad=65_536, n_blocks=256, r_rows=64, bucket_m=1024)
 #: the benchmark's ``mesh4-10m``: the 10M graph folded into 2^24 slots over
-#: four shards, the insert buckets at the size the window runs (grown in
-#: the warm-up from the floor of 1,024 to twice what a wake's 10,000 new
-#: references put into the fullest shard: ``mesh.py _grow_buckets``)
-MESH_ENGINE_16M = dict(n_pad=1 << 24, n_blocks=8192, r_rows=4096, bucket_m=32_768)
+#: four shards (destination supertiles dealt round-robin: 4,096 blocks a
+#: shard), the insert buckets at the size the window runs (grown in the
+#: warm-up from the floor of 1,024 to twice what a wake's 10,000 new
+#: references put into the fullest shard, ~2,550: ``mesh.py _grow_buckets``)
+MESH_ENGINE_16M = dict(n_pad=1 << 24, n_blocks=4096, r_rows=4096, bucket_m=8192)
 
 
 @pytest.fixture(scope="module")
@@ -419,3 +420,24 @@ def test_sharded_programs_compile(topo, program, geom):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= (1 if program == "trace" else 2)
     assert "all-gather" in text
+
+
+@pytest.mark.parametrize("geom", [MESH_SMALL, MESH_10M, MESH_ENGINE_16M], ids=["64k", "10m", "16m"])
+def test_the_sharded_verdict_compiles_and_leaves_in_slot_order_a_quarter_a_chip(topo, geom):
+    """The mesh's readback reduce (``make_sharded_verdict``): the garbage
+    words interleaved from owner-major back into slot order across the
+    chips, which is data every chip sends every other."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from uigc_tpu.parallel import sharded_trace as st
+
+    D = 4
+    mesh = Mesh(np.array(topo.devices[:D]), ("gc",))
+    words = _struct((geom["n_pad"] // 32,), np.int32, NamedSharding(mesh, P("gc")))
+    compiled = st.make_sharded_verdict(mesh).lower(words, words).compile()
+    garbage_w, marked = compiled.output_shardings
+    assert garbage_w.is_equivalent_to(NamedSharding(mesh, P("gc")), 1)
+    assert marked.is_fully_replicated
+    # an all-to-all where a shard's rows divide by the chips, else an
+    # all-gather and a slice
+    assert any(op in compiled.as_text() for op in ("all-to-all", "all-gather"))

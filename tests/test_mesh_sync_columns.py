@@ -44,7 +44,7 @@ class ParentReplay:
     queued mask, a dict of bucket writes keyed by ``(shard, col)``."""
 
     def __init__(self, g):
-        self.n_pad, self.shard_size, self.n_devices = g._n_pad, g._shard_size, g.n_devices
+        self.n_pad, self.part, self.n_devices = g._n_pad, g._part, g.n_devices
         self.ceiling = g._bucket_ceiling()
         self.bucket_m = g._bucket_m
         self.pb_src, self.pb_dst = g._pb_src.copy(), g._pb_dst.copy()
@@ -118,7 +118,7 @@ class ParentReplay:
             present = (self.pb_slot.get_batch(inserts) >= 0) | (
                 self.base_slot.get_batch(inserts) >= 0)
             srcs, dsts = unpack_keys(inserts)
-            new = np.bincount(dsts[~present] // self.shard_size, minlength=self.n_devices)
+            new = np.bincount(self.part.owner(dsts[~present]), minlength=self.n_devices)
             need = self.bucket_fill(new)
             if need > self.bucket_m:
                 if need > self.ceiling:
@@ -129,7 +129,7 @@ class ParentReplay:
                 if dup:
                     self.anomalies += 1
                     continue
-                shard = dst // self.shard_size
+                shard = self.part.owner(dst)
                 free = self.pb_free[shard]
                 if free:
                     colm = free.pop()
@@ -138,7 +138,7 @@ class ParentReplay:
                     self.pb_count[shard] = colm + 1
                 self.pb_slot.add(key, (shard << 32) | colm)
                 self.pb_src[shard, colm] = src
-                local = dst - shard * self.shard_size
+                local = self.part.local(dst)
                 self.pb_dst[shard, colm] = local
                 writes[(shard, colm)] = (src, local)
         return writes
@@ -173,12 +173,13 @@ class Twin:
         self.oracle = ParentReplay(g)
 
     def fresh(self, k, shard=None):
-        """``k`` pairs that are not live, their targets in ``shard``."""
-        lo, hi = (0, ACTORS) if shard is None else (
-            shard * self.g._shard_size, min(ACTORS, (shard + 1) * self.g._shard_size))
+        """``k`` pairs that are not live, their targets owned by ``shard``."""
+        targets = np.arange(ACTORS)
+        if shard is not None:
+            targets = targets[self.g._part.owner(targets) == shard]
         out = []
         while len(out) < k:
-            key = pack_key(int(self.rng.integers(0, ACTORS)), int(self.rng.integers(lo, hi)),
+            key = pack_key(int(self.rng.integers(0, ACTORS)), int(self.rng.choice(targets)),
                            int(self.rng.integers(0, 2)))
             if key not in self.live and key not in out:
                 out.append(key)
@@ -243,9 +244,9 @@ class Twin:
         assert len(g._pb_slot) == len(oracle.pb_slot)
         srcs, dsts = unpack_keys(keys[vals >= 0])
         shard, col = vals[vals >= 0] >> 32, vals[vals >= 0] & 0xFFFFFFFF
-        assert np.array_equal(shard, dsts // g._shard_size)
+        assert np.array_equal(shard, g._part.owner(dsts))
         assert np.array_equal(g._pb_src[shard, col], srcs)
-        assert np.array_equal(g._pb_dst[shard, col], dsts - shard * g._shard_size)
+        assert np.array_equal(g._pb_dst[shard, col], g._part.local(dsts))
 
         # the batch: the touched columns once each, as the host plane has them
         shs, cols, bsrc, bdst = writes
@@ -323,7 +324,7 @@ def every_pair_on_one_shard(t):
     last = t.g.n_devices - 1
     new = t.fresh(50, shard=last)
     t.wake([(1, k) for k in new])
-    in_shard = [k for k in t.base_keys.tolist() if ((k >> 1) & 0x7FFFFFFF) // t.g._shard_size == last]
+    in_shard = [k for k in t.base_keys.tolist() if t.g._part.owner((k >> 1) & 0x7FFFFFFF) == last]
     t.wake([(0, k) for k in new[:20] + t.some(in_shard, 30)] + [(1, k) for k in t.fresh(10, shard=last)])
     assert not t.g._pb_count[:last].any() and t.g._pb_count[last] == 50
 

@@ -11,9 +11,11 @@ devices), interpreted kernels, seeded graphs of a few thousand actors.
 (c) the shards' kernel counters are what ``tools/sweep_profile.py
     simulate_sweeps`` counts from each destination shard's layout, and a
     mesh of one shard reads the one-chip program's counters to the digit;
-(d) the share test: each shard's verdict words for its own slot range,
-    laid end to end, are the whole verdict, and what every shard
-    computes alike (the gathered table: its sweeps, its dirty chunks,
+(d) the share test: the verdict words as the devices hold them, a D-th
+    of the slot space each in slot order (the shards own supertiles
+    dealt round-robin, ``sharded_trace.Partition``, and the verdict is
+    put back in slot order on the device), laid end to end, are the
+    whole verdict, and what every shard computes alike (the gathered table: its sweeps, its dirty chunks,
     its marks) is counted once.
 """
 
@@ -128,7 +130,7 @@ def test_sharded_verdict_words_equal_the_one_chip_wakes_and_the_oracles(n_device
             assert not verdicts.garbage_w[words:].any()
             assert verdicts.num_live == want_live
         assert sharded._n_pad == CAPACITY and sharded._shard_size == CAPACITY // n_devices
-        # (d) every shard holds the words of its own slot range and no other
+        # (d) every device holds a D-th of the verdict, in slot order
         shards = sharded.shard_verdict_words()
         assert [w.size * 32 for w in shards] == [sharded._shard_size] * n_devices
         assert np.array_equal(np.concatenate(shards), got[1].garbage_w)
@@ -213,11 +215,13 @@ def test_shard_counters_are_the_simulators_and_one_shard_reads_as_one_chip(n_dev
         S_ROWS, bucket, sub=meta["sub"], group=meta["group"], mode=mode)
     zeros = np.zeros(n // 32, np.int32)
     jump = (pt.jump_parents(psrc, pdst, n),) if mode == pt.MODE_AUTO else ()
+    part = st.Partition(n_devices, S_ROWS * 128)
     *state, stats = wake(
-        g["flags"], g["recv_count"], zeros, zeros, *([zeros] * 5), np.zeros((), np.int32),
+        part.owner_major(g["flags"]), part.owner_major(g["recv_count"]), zeros, zeros, *([zeros] * 5), np.zeros((), np.int32),
         stacked["bmeta1"], stacked["bmeta2"], stacked["row_pos"], stacked["emeta"],
         np.full((n_devices, bucket), n, np.int32), np.zeros((n_devices, bucket), np.int32), *jump)
-    marks = np.unpackbits(np.asarray(state[0]).view(np.uint8), bitorder="little")[:n] > 0
+    mark_w = part.slot_major(np.asarray(state[0]), per=32)
+    marks = np.unpackbits(mark_w.view(np.uint8), bitorder="little")[:n] > 0
     assert np.array_equal(marks, F.trace_marks_np(
         g["flags"], g["recv_count"], g["supervisor"], g["edge_src"], g["edge_dst"],
         g["edge_weight"]))

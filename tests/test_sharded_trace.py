@@ -7,6 +7,7 @@ import pytest
 from uigc_tpu.models import powerlaw_actor_graph, ring_graph
 from uigc_tpu.ops import trace as trace_ops
 from uigc_tpu.parallel import build_mesh, make_sharded_trace, shard_graph
+from uigc_tpu.parallel.sharded_trace import Partition
 
 
 @pytest.mark.parametrize(
@@ -99,7 +100,8 @@ def test_sharded_pallas_matches_host(seed, mode):
     )
 
     shard_size = meta["shard_size"]
-    owner = pdst[ins_idx] // shard_size
+    part = Partition(n_devices, super_sz)
+    owner = part.owner(pdst[ins_idx])
     counts = np.bincount(owner, minlength=n_devices)
     m = max(64, int(counts.max(initial=1)))
     bsrc = np.full((n_devices, m), n_pad, np.int32)
@@ -109,7 +111,7 @@ def test_sharded_pallas_matches_host(seed, mode):
     so = np.argsort(owner, kind="stable")
     col = np.arange(ins_idx.size) - starts[owner[so]]
     bsrc[owner[so], col] = psrc[ins_idx][so]
-    bdst[owner[so], col] = (pdst[ins_idx][so] - owner[so] * shard_size)
+    bdst[owner[so], col] = part.local(pdst[ins_idx][so])
 
     mesh = build_mesh(n_devices)
     traced = make_sharded_pallas_trace(
@@ -133,8 +135,8 @@ def test_sharded_pallas_matches_host(seed, mode):
     )
     mark = np.asarray(
         traced(
-            flags,
-            recv,
+            part.owner_major(flags),
+            part.owner_major(recv),
             stacked["bmeta1"],
             stacked["bmeta2"],
             stacked["row_pos"],
@@ -182,8 +184,10 @@ def test_sharded_auto_engages_where_one_device_does():
         meta["r_rows"], s_rows, m, sub=meta["sub"], group=meta["group"],
         mode="auto", with_stats=True,
     )
+    part = Partition(n_devices, s_rows * 128)
     mark, stats = traced(
-        flags, recv, stacked["bmeta1"], stacked["bmeta2"], stacked["row_pos"],
+        part.owner_major(flags), part.owner_major(recv),
+        stacked["bmeta1"], stacked["bmeta2"], stacked["row_pos"],
         stacked["emeta"], np.full((n_devices, m), n_pad, np.int32),
         np.zeros((n_devices, m), np.int32), jp,
     )
@@ -234,6 +238,7 @@ def test_sharded_decremental_wakes(mode):
         psrc, pdst, n_pad, n_devices, s_rows=s_rows
     )
     shard_size = meta["shard_size"]
+    part = Partition(n_devices, super_sz)
     m = 64  # bucket columns per shard
     bsrc = np.full((n_devices, m), n_pad, np.int32)
     bdst = np.zeros((n_devices, m), np.int32)
@@ -271,8 +276,9 @@ def test_sharded_decremental_wakes(mode):
         )
 
     def words_of(ids):
+        """The ids' bits where the wake takes them: in their owners' words."""
         w = np.zeros(n_words, np.uint32)
-        ids = np.asarray(sorted(set(ids)), np.int64)
+        ids = part.owner_major_index(np.asarray(sorted(set(ids)), np.int64), shard_size)
         if ids.size:
             np.bitwise_or.at(
                 w, ids >> 5, np.uint32(1) << (ids & 31).astype(np.uint32)
@@ -282,7 +288,8 @@ def test_sharded_decremental_wakes(mode):
     def run_wake(del_ids, fresh_ids):
         nonlocal state
         out = wake(
-            flags, recv, words_of(del_ids), words_of(fresh_ids),
+            part.owner_major(flags), part.owner_major(recv),
+            words_of(del_ids), words_of(fresh_ids),
             *state,
             stacked["bmeta1"], stacked["bmeta2"],
             stacked["row_pos"], stacked["emeta"],
@@ -290,7 +297,7 @@ def test_sharded_decremental_wakes(mode):
             *((jp,) if use_jump else ()),
         )
         state = [np.asarray(o) for o in out[:-1]]
-        mark_w = state[0].view(np.uint32)
+        mark_w = part.slot_major(state[0], per=32).view(np.uint32)
         return np.unpackbits(mark_w.view(np.uint8), bitorder="little")[:n] > 0
 
     # cold start = full derivation
@@ -315,12 +322,12 @@ def test_sharded_decremental_wakes(mode):
         # bucket-tier inserts (fresh pairs)
         for _ in range(10):
             s_, d_ = int(rng.integers(0, n)), int(rng.integers(0, n))
-            sh = d_ // shard_size
+            sh = int(part.owner(d_))
             c = int(bcount[sh])
             if c >= m or (s_, d_) in bucket_pairs:
                 continue
             bsrc[sh, c] = s_
-            bdst[sh, c] = d_ - sh * shard_size
+            bdst[sh, c] = part.local(d_)
             bcount[sh] = c + 1
             bucket_pairs.append((s_, d_))
             fresh_ids.append(d_)

@@ -267,10 +267,11 @@ class _BlockWalk:
         self.n_chunks = prep["r_rows"] // (pt.ROWS * prep["group"])
         self.tile_nodes = prep["s_rows"] * pt.LANE
         # a destination shard's layout (``sharded_trace.shard_layout``)
-        # names its tiles from its own first
-        first = prep.get("first_tile", 0)
-        self.tile = self.tile + first
-        self.n_tiles = max(first + prep["n_super"], -(-n // self.tile_nodes))
+        # numbers its own tiles, every D-th of the graph's: ``tiles``
+        # names them in the whole graph's
+        tiles = prep.get("tiles", np.arange(prep["n_super"]))
+        self.tile = tiles[self.tile]
+        self.n_tiles = max(int(tiles.max()) + 1, -(-n // self.tile_nodes))
         self.in_use = in_use
 
     def _per(self, flags, size, count):

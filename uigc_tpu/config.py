@@ -40,7 +40,14 @@ DEFAULTS: Dict[str, Any] = {
     #   "native" - C++ data plane (uigc_tpu/native/), batch fold + trace
     #   "mesh"   - fold/trace state sharded across a jax device mesh
     #              (engines/crgc/mesh.py); per-wake deltas stream to the
-    #              devices, the trace all_gathers marks over ICI
+    #              devices, the trace all_gathers marks over ICI.  The
+    #              slot space is dealt to the shards by supertile,
+    #              round-robin (4,096 slots; supertile t belongs to
+    #              shard t % D), not in contiguous slot ranges: slots
+    #              are handed out in interning order and the capacity
+    #              doubles as uids arrive, so ranges give the live
+    #              actors to the first shards
+    #              (parallel/sharded_trace.py Partition)
     #   "decremental" - dense-array graph with the trace run on the
     #              device: each wake re-derives the region the churn may
     #              have invalidated from the previous fixpoint, or

@@ -8,7 +8,11 @@ per host, the detection state is *partitioned* across the devices of one
 slice —
 
 - node feature arrays (flags, recv_count) live device-resident, sharded
-  by contiguous slot range over the mesh axis;
+  over the mesh axis by supertile, dealt round-robin
+  (``sharded_trace.Partition``: supertile ``t`` of 4,096 slots belongs to
+  shard ``t % D``; NOT contiguous slot ranges, which hand the actors
+  interned first, the live ones, to the first shards, because slots are
+  handed out from 0 upward and the capacity doubles as uids arrive);
 - propagation pairs (positive refob edges + supervisor pointers) live
   device-resident as per-destination-shard buckets, so each device's
   scatter lands only in its own node shard;
@@ -193,7 +197,12 @@ class MeshShadowGraph(ArrayShadowGraph):
         self._dev_flags = None
         self._dev_recv = None
         self._n_pad = 0
-        self._shard_size = 0
+        self._shard_size = 0  # slots a shard: n_pad // D
+        #: who owns a slot and where it lies in its owner's shard
+        #: (``sharded_trace.Partition``): set by a pack, from ``s_rows``
+        self._part: Optional[sharded_trace.Partition] = None
+        #: the verdict's way off the device (``make_sharded_verdict``)
+        self._verdict_fn = None
         self._warmed_n_pad = 0  # the padding whose scatters are warm
         # --- packed base plane: per-shard Pallas layouts -------------- #
         self._layout_meta: Optional[dict] = None
@@ -325,6 +334,8 @@ class MeshShadowGraph(ArrayShadowGraph):
         n_pad = ((self.capacity + chunk - 1) // chunk) * chunk
         self._n_pad = n_pad
         self._shard_size = n_pad // D
+        self._part = sharded_trace.Partition(D, super_sz)
+        self._verdict_fn = sharded_trace.make_sharded_verdict(self.mesh, super_sz)
 
         # --- packed base layouts from the host truth -------------- #
         from ...ops.pallas_incremental import IncrementalPallasLayout
@@ -365,7 +376,9 @@ class MeshShadowGraph(ArrayShadowGraph):
         self.invalidate_wake_state()
 
     def _upload_all(self) -> int:
-        """The device's half of a rebuild: every operand put whole.
+        """The device's half of a rebuild: every operand put whole, the
+        node features in owner-major order (each shard its own
+        supertiles: one row permutation of the two arrays a pack).
         Returns the bytes handed over for node features."""
         import jax
 
@@ -375,8 +388,8 @@ class MeshShadowGraph(ArrayShadowGraph):
         flags[: self.capacity] = self.flags
         recv = np.zeros(n_pad, dtype=np.int64)
         recv[: self.capacity] = self.recv_count
-        self._dev_flags = jax.device_put(flags, nodes_s)
-        self._dev_recv = jax.device_put(recv, nodes_s)
+        self._dev_flags = jax.device_put(self._part.owner_major(flags), nodes_s)
+        self._dev_recv = jax.device_put(self._part.owner_major(recv), nodes_s)
         self._dev_stacked = {
             "bmeta1": jax.device_put(stacked["bmeta1"], pairs_s),
             "bmeta2": jax.device_put(stacked["bmeta2"], pairs_s),
@@ -388,8 +401,8 @@ class MeshShadowGraph(ArrayShadowGraph):
         # Host mirror of the last recv values synced to the device: the
         # sharded fold applies *deltas* (reference: ShadowGraph.java:75-83
         # folds counts, not absolutes), so per-wake sync needs the diff
-        # against what the device already holds.
-        self._recv_synced = recv.copy()
+        # against what the device already holds.  By slot, like the host's.
+        self._recv_synced = recv
         self._sync_jump_mirror()
         if self._warmed_n_pad != n_pad:  # a capacity's first copies
             self._warm_scatters()
@@ -529,7 +542,7 @@ class MeshShadowGraph(ArrayShadowGraph):
         keys = inserts[~present]
         srcs, dsts = unpack_keys(keys)
         order, shard, rank, new = _split_by_shard(
-            dsts // self._shard_size, self.n_devices
+            self._part.owner(dsts), self.n_devices
         )
         # the fullest shard's columns once the new pairs are in
         # (the removes above have freed theirs)
@@ -554,7 +567,7 @@ class MeshShadowGraph(ArrayShadowGraph):
         taken = (shard << 32) | cols
         self._pb_slot.add_batch(keys[order], taken)
         self._pb_src[shard, cols] = srcs[order]
-        self._pb_dst[shard, cols] = dsts[order] - shard * self._shard_size
+        self._pb_dst[shard, cols] = self._part.local(dsts[order])
         self._pair_log.clear()
         touched = np.unique(np.concatenate([freed, freed_cond, taken]))
         shs, cols = touched >> 32, touched & 0xFFFFFFFF
@@ -689,14 +702,15 @@ class MeshShadowGraph(ArrayShadowGraph):
             # scatter-applies only its own shard's rows — recv as deltas
             # against the synced mirror, flags as set/clear masks that
             # reproduce absolute assignment ((old | set) & ~clear = new).
-            ss = self._shard_size
-            order, shard, col, counts = _split_by_shard(slots_arr // ss, D)
+            order, shard, col, counts = _split_by_shard(
+                self._part.owner(slots_arr), D
+            )
             slots_arr = slots_arr[order]
             batch = self._nodes_batch(_scatter_pad(int(counts.max(initial=1))))
             lslot, rdelta, fset, fclear = batch
             new_flags = self.flags[slots_arr]
             new_recv = self.recv_count[slots_arr]
-            lslot[shard, col] = (slots_arr - shard * ss).astype(np.int32)
+            lslot[shard, col] = self._part.local(slots_arr)
             rdelta[shard, col] = new_recv - self._recv_synced[slots_arr]
             fset[shard, col] = new_flags
             fclear[shard, col] = ~new_flags
@@ -792,10 +806,12 @@ class MeshShadowGraph(ArrayShadowGraph):
     # ------------------------------------------------------------- #
 
     def _word_array(self, id_chunks: List[np.ndarray]):
-        """Scatter id arrays into the node-word array, sharded like the
-        node arrays (word w of shard d covers nodes d*shard + 32w..).
-        No ids (the quiet steady state) reuse one cached zero array
-        instead of allocating + transferring per wake."""
+        """Scatter id arrays into the node-word array, sharded and
+        ordered like the node arrays: owner-major, so a slot's bit lies
+        in its owner's words (the ids mapped by the partition, then
+        ``id_words``; word ``w`` of shard ``d`` covers its local slots
+        ``32w..``).  No ids (the quiet steady state) reuse one cached
+        zero array instead of allocating + transferring per wake."""
         import jax
 
         nodes_s, _, _ = self._sharding()
@@ -807,7 +823,10 @@ class MeshShadowGraph(ArrayShadowGraph):
                     np.zeros(n_words, np.int32), nodes_s
                 )
             return z
-        words = pallas_decremental.id_words(id_chunks, n_words)
+        placed = self._part.owner_major_index(
+            np.concatenate(id_chunks), self._shard_size
+        )
+        words = pallas_decremental.id_words([placed], n_words)
         return jax.device_put(words.view(np.int32), nodes_s)
 
     def compute_marks(self):
@@ -925,8 +944,9 @@ class MeshShadowGraph(ArrayShadowGraph):
         (the pair log folded on the host, or a pack), ``upload`` (the
         O(churn) scatters, then ``stage``: the program and the suspects'
         words), ``device`` (``dispatch``, then the wait) and
-        ``readback`` (1/8 of a byte a slot: the garbage words of every
-        shard laid end to end, and the number of marks).  Dispatch and
+        ``readback`` (1/8 of a byte a slot: the garbage words put back
+        in slot order on the device, ``make_sharded_verdict``, and laid
+        end to end, and the number of marks).  Dispatch and
         readback share one hold of the collective lock: exactly one
         collective program is in flight at a time.  The wake's state was
         committed at dispatch, so a poisoned result, which surfaces at
@@ -954,9 +974,7 @@ class MeshShadowGraph(ArrayShadowGraph):
                     # record pays for their way to the host
                     wake.defer(self._read_sweep_stats, self._wake_counters[-1])
                 with events.wake_phase(wake, "readback"):
-                    garbage_w, marked = pallas_decremental.verdict_reduce()(
-                        mark_w, iu_w
-                    )
+                    garbage_w, marked = self._verdict_fn(mark_w, iu_w)
                     words = _readback(garbage_w, "marks.mesh_decremental")
                     self._verdict_dev = garbage_w
                     self.last_verdict_words = words.view(np.uint32)
@@ -1020,10 +1038,13 @@ class MeshShadowGraph(ArrayShadowGraph):
         return out
 
     def shard_verdict_words(self) -> List[np.ndarray]:
-        """The last wake's garbage words as each device holds them for
-        its own slot range, in shard order (uint32; bit ``i & 31`` of
-        word ``i >> 5`` is the shard's slot ``i``): laid end to end they
-        are ``last_verdict_words``, the verdict the sweep took."""
+        """The last wake's garbage words as the devices hold them once
+        ``make_sharded_verdict`` has put them in slot order: device ``d``
+        the words of slots ``[d, d + 1) * _shard_size`` (uint32; bit
+        ``i & 31`` of word ``i >> 5`` is the ``i``-th of them), a D-th of
+        the verdict each, which is not the range it OWNS (its supertiles
+        are every D-th).  Laid end to end they are
+        ``last_verdict_words``, the verdict the sweep took."""
         return [
             _readback(rows, "marks.mesh_decremental.shard").view(np.uint32)
             for rows in sharded_trace.shards_in_order(self._verdict_dev)

@@ -6,13 +6,26 @@ DeltaGraphs to every peer (reference: LocalGC.scala:191-196).  On a TPU
 slice we instead *partition* the graph across devices and let XLA
 collectives do the replication work per trace wave:
 
-- node feature arrays are sharded by slot range (axis "gc");
+- node feature arrays are sharded by supertile, dealt round-robin over
+  the axis "gc" (``Partition``: supertile ``t`` of 4,096 slots belongs to
+  shard ``t % D``), and lie on the devices in OWNER-MAJOR order, a shard's
+  supertiles one after another;
 - propagation pairs (ref edges with positive weight, plus supervisor
   pointers re-encoded as edges) are sharded by *destination*, so each
   device's scatter lands only in its own node shard;
 - the mark vector is rebuilt each wave by ``all_gather`` over ICI, which
-  is the collective analogue of the DeltaMsg broadcast;
+  is the collective analogue of the DeltaMsg broadcast, and interleaved
+  back into slot order, so sources stay global slot ids;
 - convergence is decided with a global ``psum`` of per-shard change bits.
+
+Why not contiguous slot ranges: slots are handed out from 0 upward as
+uids are interned and the capacity doubles as they arrive, so the actors
+interned first, the live ones, lie in the lowest slots, and every slot
+handed out before the last doubling in the lower half of the space: a
+range partition gives them to the first shards and leaves the others
+freed garbage (at 10M actors one shard ran 90% of a wake's kernel steps).
+Ownership is therefore a function of the slot that does not change with
+the capacity, and slot ids themselves do not change anywhere on the host.
 
 The fold step (scatter-adding a batch of entry deltas into the sharded
 arrays) rides the same mesh: deltas are bucketed by destination shard on
@@ -21,8 +34,9 @@ the host, then scatter-added device-side.
 
 from __future__ import annotations
 
+from dataclasses import dataclass
 from functools import partial
-from typing import Dict, Tuple
+from typing import Dict
 
 import numpy as np
 
@@ -83,21 +97,101 @@ def pad_to(x: np.ndarray, size: int, fill=0) -> np.ndarray:
     return out
 
 
+#: slots a destination supertile at the kernel's default ``pt.S_ROWS``
+#: (32 rows of 128 lanes): one 128-word row of the packed bit table
+SUPER_SZ = 4096
+
+
+@dataclass(frozen=True)
+class Partition:
+    """THE partition of the slot space over a mesh: who owns a slot and
+    where it lies in its owner's shard.  Destination supertile ``t``
+    (``super_sz`` slots: the unit of the kernel's destination gate and of
+    the pull gate, and with 4,096 slots exactly one 128-word row of the
+    packed bit table) belongs to shard ``t % n_devices``, and a shard
+    holds its supertiles in ascending global order.  A function of the
+    slot alone: it does not move when the capacity doubles, and actors in
+    interning order (the live ones in the lowest slots) are dealt evenly.
+    With one device the map is the identity.
+
+    The methods are array arithmetic, for numpy and for traced jax arrays
+    alike.  An array over the whole slot space is either in SLOT order
+    (index = slot: everything on the host, and every gathered table) or
+    OWNER-MAJOR (shard 0's elements, then shard 1's...: what is sharded
+    over the mesh axis, so that each device holds its own)."""
+
+    n_devices: int
+    super_sz: int = SUPER_SZ
+
+    def owner(self, slot):
+        return (slot // self.super_sz) % self.n_devices
+
+    def local(self, slot):
+        """A slot's index within its owner's shard."""
+        return (
+            slot // (self.super_sz * self.n_devices) * self.super_sz
+            + slot % self.super_sz
+        )
+
+    def global_of(self, shard, local):
+        """The slot that is ``local`` on ``shard``: the inverse."""
+        return (
+            (local // self.super_sz * self.n_devices + shard) * self.super_sz
+            + local % self.super_sz
+        )
+
+    def owner_major_index(self, slot, shard_size):
+        """Where a slot's element lies in an owner-major array over
+        shards of ``shard_size`` slots."""
+        return self.owner(slot) * shard_size + self.local(slot)
+
+    def owner_major(self, x, per: int = 1):
+        """A flat array in slot order (``per`` slots an element: 32 for
+        packed words) reordered owner-major: a row permutation."""
+        if self.n_devices == 1:
+            return x
+        rows = x.reshape(-1, self.n_devices, self.super_sz // per)
+        return rows.swapaxes(0, 1).reshape(-1)
+
+    def slot_major(self, x, per: int = 1):
+        """The inverse reorder: what the shards hold, laid end to end,
+        back in slot order (the interleave every all-gather of a table
+        ends in, and the verdict's way off the device)."""
+        if self.n_devices == 1:
+            return x
+        rows = x.reshape(self.n_devices, -1, self.super_sz // per)
+        return rows.swapaxes(0, 1).reshape(-1)
+
+    def shard_rows(self, x, shard):
+        """Shard ``shard``'s elements of a flat array in slot order, as
+        the strided view they are (``shard`` may be a traced index)."""
+        import jax
+
+        rows = x.reshape(-1, self.n_devices, self.super_sz)
+        return jax.lax.dynamic_index_in_dim(
+            rows, shard, axis=1, keepdims=False
+        ).reshape(-1)
+
+
 def shard_graph(
     graph: Dict[str, np.ndarray], n_devices: int
 ) -> Dict[str, np.ndarray]:
     """Repack kernel arrays for an n-device mesh.
 
-    Nodes are padded to a multiple of n_devices and sharded by contiguous
-    slot range.  Propagation pairs (positive-weight edges + supervisor
-    pointers) are bucketed by destination shard and padded to equal bucket
-    sizes, yielding [n_devices, m] arrays sharded on the leading axis.
+    Nodes are padded to whole supertiles a shard and laid out owner-major
+    by ``Partition(n_devices)`` (round-robin supertiles, not contiguous
+    slot ranges: the module text says why).  Propagation pairs
+    (positive-weight edges + supervisor pointers) are bucketed by
+    destination shard and padded to equal bucket sizes, yielding
+    [n_devices, m] arrays sharded on the leading axis.
     """
+    part = Partition(n_devices)
     n = graph["flags"].shape[0]
-    n_pad = ((n + n_devices - 1) // n_devices) * n_devices
+    chunk = n_devices * part.super_sz
+    n_pad = ((n + chunk - 1) // chunk) * chunk
 
-    flags = pad_to(graph["flags"], n_pad)
-    recv = pad_to(graph["recv_count"], n_pad)
+    flags = part.owner_major(pad_to(graph["flags"], n_pad))
+    recv = part.owner_major(pad_to(graph["recv_count"], n_pad))
 
     live = graph["edge_weight"] > 0
     esrc = graph["edge_src"][live]
@@ -112,7 +206,7 @@ def shard_graph(
     pdst = np.concatenate([edst, sup_dst])
 
     shard_size = n_pad // n_devices
-    owner = pdst // shard_size
+    owner = part.owner(pdst)
 
     buckets_src = []
     buckets_dst = []
@@ -130,7 +224,7 @@ def shard_graph(
         m = buckets_src[d].shape[0]
         src2[d, :m] = buckets_src[d]
         # local destination index within the shard
-        dst2[d, :m] = buckets_dst[d] - d * shard_size
+        dst2[d, :m] = part.local(buckets_dst[d])
 
     return {
         "flags": flags,
@@ -171,11 +265,12 @@ def _seed_masks(flags, recv):
     return in_use, halted, seed
 
 
-def make_local_shard_ops(axis, words_pad, r_rows, n_pad, shard_size, jnp):
+def make_local_shard_ops(axis, part, words_pad, r_rows, n_pad, shard_size, jnp):
     """The per-shard word-space primitives shared by the mesh trace and
-    the mesh decremental wake: local bool pack, global-table all_gather,
-    and the packed-table source-bit gather.  One definition keeps the two
-    fixpoints propagating identically per sweep."""
+    the mesh decremental wake: local bool pack, global-table all_gather
+    (the shards' supertile rows interleaved back into slot order by
+    ``part``), and the packed-table source-bit gather.  One definition
+    keeps the two fixpoints propagating identically per sweep."""
     import jax
 
     from ..ops import pallas_trace as pt
@@ -189,7 +284,10 @@ def make_local_shard_ops(axis, words_pad, r_rows, n_pad, shard_size, jnp):
         ).sum(axis=1, dtype=jnp.int32)
 
     def gather_table(local_words):
-        w_all = jax.lax.all_gather(local_words, axis).reshape(-1)
+        w_all = part.slot_major(
+            jax.lax.all_gather(local_words, axis).reshape(-1),
+            per=pt.WORD_BITS,
+        )
         w_all = jnp.concatenate(
             [w_all, jnp.zeros((words_pad - w_all.shape[0],), jnp.int32)]
         )
@@ -235,13 +333,12 @@ def make_local_shard_ops(axis, words_pad, r_rows, n_pad, shard_size, jnp):
         min-source parent array (n_pad + 1,): the doubling runs
         identically on every shard (gathers through the replicated
         all-gathered tables), so no collective is needed to keep the
-        parents coherent — the shard only slices its own destinations
-        for the propagation gather."""
+        parents coherent — the shard only takes its own destinations'
+        rows (a strided view) for the propagation gather."""
         with pt.scope("jump"):  # the parts as pt.jump_sweep names them
             with pt.scope("hits"):
-                idx = jax.lax.axis_index(axis)
-                j_loc = jax.lax.dynamic_slice(
-                    jump_j, (idx * shard_size,), (shard_size,)
+                j_loc = part.shard_rows(
+                    jump_j[:n_pad], jax.lax.axis_index(axis)
                 )
                 hits = pt.bits_at(table, j_loc, n_pad, jnp)
             with pt.scope("double"):
@@ -259,14 +356,15 @@ def make_local_shard_ops(axis, words_pad, r_rows, n_pad, shard_size, jnp):
 def make_sharded_trace(mesh, axis: str = "gc"):
     """Build the jitted multi-device trace step over ``mesh``.
 
-    Returns fn(flags, recv_count, pair_src, pair_dst) -> mark (bool[n_pad])
-    with flags/recv sharded by node range and pair arrays sharded on their
-    leading device axis.
+    Returns fn(flags, recv_count, pair_src, pair_dst) -> mark (bool[n_pad],
+    in slot order) with flags/recv owner-major as ``shard_graph`` lays
+    them (``Partition(n_devices)``), sharded on the mesh axis, and pair
+    arrays sharded on their leading device axis.
     """
     jax, jnp = _jax()
     from jax.sharding import PartitionSpec as P
 
-    n_devices = mesh.devices.size
+    part = Partition(mesh.devices.size)
     F = __import__("uigc_tpu.ops.trace", fromlist=["trace"])
 
     def local_trace(flags, recv, pair_src, pair_dst):
@@ -290,7 +388,9 @@ def make_sharded_trace(mesh, axis: str = "gc"):
         local_mark = in_use & (~halted) & seed
 
         # Replicated view needed for gathers by global source id.
-        halted_all = jax.lax.all_gather(halted, axis).reshape(-1)
+        halted_all = part.slot_major(
+            jax.lax.all_gather(halted, axis).reshape(-1)
+        )
 
         def cond(carry):
             _, changed = carry
@@ -298,7 +398,9 @@ def make_sharded_trace(mesh, axis: str = "gc"):
 
         def body(carry):
             local_mark, _ = carry
-            mark_all = jax.lax.all_gather(local_mark, axis).reshape(-1)
+            mark_all = part.slot_major(
+                jax.lax.all_gather(local_mark, axis).reshape(-1)
+            )
             mark_all = jnp.concatenate([mark_all, jnp.zeros((1,), bool)])
             halted_pad = jnp.concatenate([halted_all, jnp.zeros((1,), bool)])
             src_active = mark_all[pair_src] & (~halted_pad[pair_src])
@@ -329,7 +431,7 @@ def make_sharded_trace(mesh, axis: str = "gc"):
 
     @jax.jit
     def traced(flags, recv, pair_src, pair_dst):
-        return fn(flags, recv, pair_src, pair_dst).reshape(-1)
+        return part.slot_major(fn(flags, recv, pair_src, pair_dst).reshape(-1))
 
     return traced
 
@@ -348,9 +450,11 @@ def pack_shard_layouts(
     blocks).
 
     Sources stay *global* ids — the kernel gathers them from the
-    all-gathered packed bit table — while destinations are shard-local,
-    so each device's one-hot contraction lands only in its own node
-    shard (prepare_pairs ``n_src`` mode).
+    all-gathered packed bit table, which is in slot order — while
+    destinations are shard-local (``Partition.local``: a shard's
+    supertiles are every ``n_devices``-th of the graph's), so each
+    device's one-hot contraction lands only in its own node shard
+    (prepare_pairs ``n_src`` mode).
 
     Returns (stacked, meta, slot_vals): ``stacked`` holds [D, ...] arrays
     (bmeta1, bmeta2, row_pos, emeta); ``slot_vals`` gives each input
@@ -366,9 +470,10 @@ def pack_shard_layouts(
     assert n_pad % n_devices == 0 and shard_size % super_sz == 0, (
         "n_pad must split into shards of whole supertiles"
     )
+    part = Partition(n_devices, super_sz)
     psrc = np.asarray(psrc, dtype=np.int64)
     pdst = np.asarray(pdst, dtype=np.int64)
-    owner = pdst // shard_size
+    owner = part.owner(pdst)
 
     preps = []
     slot_vals = np.empty(psrc.size, dtype=np.int64)
@@ -376,7 +481,7 @@ def pack_shard_layouts(
         sel = np.nonzero(owner == d)[0]
         prep = pt.prepare_pairs(
             psrc[sel],
-            pdst[sel] - d * shard_size,
+            part.local(pdst[sel]),
             shard_size,
             s_rows=s_rows,
             want_slots=True,
@@ -426,14 +531,17 @@ def shards_in_order(x) -> list:
 def shard_layout(stacked: dict, meta: dict, shard: int) -> dict:
     """Shard ``shard``'s layout of ``pack_shard_layouts`` as the packed
     layout ``prepare_pairs`` gave it (global sources, its own
-    destinations, tiles named from its own first): what a reader that
+    destinations, tiles numbered within the shard): what a reader that
     counts a kernel's work from a layout takes
     (``tools/sweep_profile.py simulate_sweeps``, which finds the shard's
-    tiles in the whole graph's by ``first_tile``)."""
+    tiles in the whole graph's by ``tiles``, the strided list they are:
+    ``Partition.global_of`` in supertiles)."""
     n_super = meta["shard_size"] // (meta["s_rows"] * 128)
+    n_devices = stacked["bmeta1"].shape[0]
     prep = {key: stacked[key][shard] for key in ("bmeta1", "bmeta2", "row_pos", "emeta")}
     prep.update(
-        n=meta["shard_size"], n_super=n_super, first_tile=shard * n_super,
+        n=meta["shard_size"], n_super=n_super,
+        tiles=Partition(n_devices, 1).global_of(shard, np.arange(n_super)),
         **{key: meta[key] for key in ("n_blocks", "r_rows", "s_rows", "sub", "group")},
     )
     return prep
@@ -492,9 +600,11 @@ def make_sharded_pallas_trace(
     too, and a graph engages on the same sweep on one chip and on four.
 
     fn(flags, recv, bmeta1, bmeta2, row_pos, emeta, bsrc, bdst[, jump_j])
-    -> mark with flags/recv sharded by node range, layout operands
-    sharded on their leading device axis, jump_j replicated.  With
-    ``with_stats`` it returns (mark, {"n_sweeps", "jump_sweeps"}).
+    -> mark (in slot order) with flags/recv owner-major
+    (``Partition(D, s_rows * 128).owner_major``), sharded on the mesh
+    axis, layout operands sharded on their leading device axis, jump_j
+    replicated, in slot order.  With ``with_stats`` it returns
+    (mark, {"n_sweeps", "jump_sweeps"}).
     """
     jax, jnp = _jax()
     from jax.sharding import PartitionSpec as P
@@ -518,6 +628,7 @@ def make_sharded_pallas_trace(
     use_jump = mode in (pt.MODE_JUMP, pt.MODE_AUTO)
     use_pull = mode in (pt.MODE_PULL, pt.MODE_AUTO)
     super_sz = s_rows * pt.LANE
+    part = Partition(mesh.devices.size, super_sz)
     n_super_shard = shard_size // super_sz
     sup_words = s_rows * (pt.LANE // pt.WORD_BITS)
     # dst-gated kernel with a constant zero gate == the plain kernel;
@@ -552,7 +663,7 @@ def make_sharded_pallas_trace(
 
         pack_words, gather_table, make_sweep, jump_local = (
             make_local_shard_ops(
-                axis, words_pad, r_rows, n_pad, shard_size, jnp
+                axis, part, words_pad, r_rows, n_pad, shard_size, jnp
             )
         )
         sweep_hits = make_sweep(
@@ -646,11 +757,10 @@ def make_sharded_pallas_trace(
     @jax.jit
     def traced(*args):
         mark, counts = fn(*args)
+        mark = part.slot_major(mark.reshape(-1))
         if not with_stats:
-            return mark.reshape(-1)
-        return mark.reshape(-1), {
-            "n_sweeps": counts[0], "jump_sweeps": counts[1],
-        }
+            return mark
+        return mark, {"n_sweeps": counts[0], "jump_sweeps": counts[1]}
 
     return traced
 
@@ -748,6 +858,42 @@ def make_sharded_fold(mesh, axis: str = "gc", donate: bool = False):
     return _cached_helper("fold", mesh, axis, (donate,), build)
 
 
+def make_sharded_verdict(mesh, super_sz: int = SUPER_SZ, axis: str = "gc"):
+    """The sharded wake's verdict on its way off the device: the mesh's
+    form of ``pallas_decremental.verdict_reduce``.
+
+    fn(mark_w, iu_w) -> (garbage_w, marked): the wake's owner-major word
+    arrays reduced to ``iu_w & ~mark_w`` and interleaved back into SLOT
+    order (``Partition.slot_major``, a 1/8-byte-a-slot row permutation
+    across the shards), and the number of marks.  ``garbage_w`` stays
+    sharded on the mesh axis: each device holds the verdict words of a
+    D-th of the slot space, in slot order, and laid end to end they are
+    the whole verdict by slot, as the sweep takes it."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ..ops import pallas_trace as pt
+
+    part = Partition(mesh.devices.size, super_sz)
+
+    def build():
+        @partial(
+            jax.jit,
+            out_shardings=(
+                NamedSharding(mesh, P(axis)), NamedSharding(mesh, P()),
+            ),
+        )
+        def verdict(mark_w, iu_w):
+            return (
+                part.slot_major(iu_w & ~mark_w, per=pt.WORD_BITS),
+                jax.lax.population_count(mark_w).sum(),
+            )
+
+        return verdict
+
+    return _cached_helper("verdict", mesh, axis, (super_sz,), build)
+
+
 def make_sharded_decremental_wake(
     mesh,
     n_pad: int,
@@ -774,15 +920,19 @@ def make_sharded_decremental_wake(
        bmeta1, bmeta2, row_pos, emeta, bsrc, bdst[, jump_j])
       -> (mark_w, seed_w, halted_w, iu_w, active_w, walks, stats)
 
-    flags/recv sharded by node range; every *_w operand is the flat word
-    array (n_pad/32 ints) sharded by word range (same node partition);
+    flags/recv owner-major (``Partition(D, s_rows * 128)``: a shard's
+    supertiles are every D-th of the slot space, dealt round-robin, not a
+    contiguous slot range, so that actors in interning order are divided
+    evenly whatever the capacity has grown to), sharded on the mesh axis;
+    every *_w operand and result is the flat word array (n_pad/32 ints)
+    in the same order (a supertile is whole words), sharded alike;
     ``prev_walks`` / ``walks`` is the replicated int32 scalar of the
     one-chip wake (the chunk walks of the last derivation from nothing,
     what the closure's price is a share of); layout operands as in
     make_sharded_pallas_trace.  The verdict is the words: a slot is
     garbage iff its bit of ``iu_w & ~mark_w`` is set
-    (``pallas_decremental.verdict_reduce``, which the mesh backend runs
-    over the sharded words as the one-chip backend does over its tables).
+    (``make_sharded_verdict``, which puts them back in slot order on
+    their way off the device).
 
     What a shard shares with the one-chip program is the code: the sweep
     over its layouts is ``pt.build_sweep_contribs`` (its packed blocks
@@ -791,8 +941,10 @@ def make_sharded_decremental_wake(
     before), the dirty lists, the pull gate, the jump step and both
     policies (``pt.closure_gives_up``, ``pt.auto_jump_policy``) the
     ``pt.*`` helpers.  What differs is where a table comes from: a
-    shard packs its own words and ``gather_table`` all-gathers them
-    (scope ``gather``).  Every loop decision is taken on values all
+    shard packs its own words and ``gather_table`` all-gathers them and
+    interleaves the shards' rows into slot order (scope ``gather``), so
+    a block's source span, the dirty chunk lists and both policies see
+    the table one chip would hold.  Every loop decision is taken on values all
     shards hold alike, so every shard leaves a loop in the same sweep
     and no collective is left waiting: the dirty lists, ``changed``, the
     closure's ``spent`` and the repair's ``walks`` are counted on the
@@ -838,6 +990,7 @@ def make_sharded_decremental_wake(
     use_jump = mode in (pt.MODE_JUMP, pt.MODE_AUTO)
     use_pull = mode in (pt.MODE_PULL, pt.MODE_AUTO)
     super_sz = s_rows * pt.LANE
+    part = Partition(mesh.devices.size, super_sz)
     n_super_shard = shard_size // super_sz
     # a shard's layouts, as the one-chip sweep takes them: its packed
     # blocks (global sources, local destinations) and its insert bucket
@@ -878,7 +1031,7 @@ def make_sharded_decremental_wake(
         )
 
         pack_words, gather_table, _, jump_local = make_local_shard_ops(
-            axis, words_pad, r_rows, n_pad, shard_size, jnp
+            axis, part, words_pad, r_rows, n_pad, shard_size, jnp
         )
         # the sink of a bucket's padding is src = n_pad, which the xla
         # tier masks by ``src < n``
