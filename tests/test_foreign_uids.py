@@ -766,3 +766,49 @@ def test_mesh_wake_record_has_every_phase_and_note_of_the_one_chip_road():
     first = records["mesh-decremental"][0]
     # the first wake puts both node arrays whole, at the padded size
     assert first["layout_rebuilt"] == 1 and first["upload_bytes"] == graph._n_pad * (1 + 8)
+
+
+@pytest.mark.parametrize("backend", ["decremental", "mesh-decremental"])
+def test_a_wake_that_swept_carries_the_cpu_clocks_on_the_one_chip_and_the_mesh_road(backend):
+    """Both roads bracket through the same ``_Wake``: a record of a wake
+    that swept has thread CPU beside the wall (the wake's, each phase's,
+    each part's), the workers' CPU clocks and CPython's collections."""
+    import threading
+
+    from uigc_tpu.telemetry.profile import PHASES, WakeProfiler
+
+    graph, plane, _, sink = new_graph(backend=backend)
+    profiler = WakeProfiler("test", threads=lambda: {"workers": [threading.get_ident()]})
+    profiler.start()
+    try:
+        for wake_no in range(2):
+            wake = graph.profile_wake = profiler.begin_wake()
+            fold_foreign(graph, plane, _foreign_tree_rows(9) if not wake_no else [
+                row(0, root=True, updated=[(3, 1)])])
+            graph.trace(should_kill=True)
+            graph.profile_wake = None
+            wake.end(entries=0, garbage=0)
+        doc = profiler.to_json()
+    finally:
+        profiler.close()
+    assert sink.freed.tolist() == [3] and doc["stalls"] is not None
+    first, swept = doc["recent"]
+    assert swept["freed"] == 1
+    for rec in (first, swept):
+        assert set(rec["phases_cpu"]) == set(PHASES)
+        # (the fold above is in no phase here: the wake's CPU, no phase's)
+        assert sum(rec["phases_cpu"].values()) <= rec["cpu_s"] <= rec["wall_s"] + 0.001
+        for name in ("layout", "upload", "device", "readback", "sweep"):
+            assert 0 < rec["phases_cpu"][name] <= rec["phases"][name] + 0.001, name
+        for part in ("stage", "dispatch"):
+            assert 0 < rec[part + "_cpu_s"] <= rec[part + "_s"] + 0.001, part
+        # the test's thread stands for the workers: what it ran, they ran
+        assert abs(rec["workers_cpu_s"] - rec["cpu_s"]) < 0.01
+        assert rec["workers_busy_max_s"] == rec["workers_cpu_s"]
+        assert 0 < rec["workers_cpu_sweep_s"] <= rec["workers_cpu_s"]
+        assert abs(rec["workers_cpu_sweep_s"] - rec["phases_cpu"]["sweep"]) < 0.005
+        assert rec["process_cpu_s"] >= rec["cpu_s"] - 0.005
+        assert rec["gc_s"] >= rec["gc_sweep_s"] >= 0 and rec["gc_full"] >= 0
+    assert first["workers_cpu_gap_s"] is None and swept["workers_cpu_gap_s"] >= 0
+    assert doc["phases"]["sweep"]["cpu_total_s"] == pytest.approx(
+        first["phases_cpu"]["sweep"] + swept["phases_cpu"]["sweep"])

@@ -425,6 +425,57 @@ def test_wake_record_counters_by_hand(backend):
         assert w.detector.graph.trace_impl == "pallas-interpret"
 
 
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_a_detector_wakes_record_carries_the_cpu_clocks(backend):
+    """The MAC road brackets through the same ``_Wake``: thread CPU by
+    phase, the workers' clocks over the wake and inside ``stop_and_free``'s
+    ``sweep``, CPython's collections, as under CRGC."""
+    import threading
+    import time
+
+    from uigc_tpu.telemetry.profile import PHASES
+
+    told, leave = threading.Event(), threading.Event()
+
+    def work():
+        told.wait()
+        until = time.thread_time() + 0.03
+        while time.thread_time() < until:
+            pass
+        leave.wait()
+
+    worker = threading.Thread(target=work, daemon=True)
+    worker.start()
+    prof = WakeProfiler("test", threads=lambda: {"workers": [worker.ident]})
+    prof.start()
+    try:
+        w = World(backend, profiler=prof)
+        w.ring(3)
+        w.wake()
+        told.set()  # the worker runs between the asking wake and the killing one
+        time.sleep(0.1)
+        w.wake()
+        asked, killed = prof.wakes_since(0.0)
+    finally:
+        prof.close()
+        leave.set()
+    assert killed["kills"] == 3 and killed["phases"]["sweep"] > 0
+    for rec in (asked, killed):
+        assert set(rec["phases_cpu"]) == set(PHASES)
+        assert 0 < rec["cpu_s"] <= rec["wall_s"] + 0.001
+        assert 0 <= rec["cpu_s"] - sum(rec["phases_cpu"].values()) < 0.005
+        assert rec["process_cpu_s"] > 0
+        for field in ("workers_cpu_s", "workers_cpu_sweep_s", "workers_busy_max_s"):
+            assert 0 <= rec[field] < 0.01, field
+        assert (rec["gc_s"], rec["gc_sweep_s"]) >= (0.0, 0.0) and rec["gc_full"] >= 0
+    assert asked["workers_cpu_gap_s"] is None and 0.03 <= killed["workers_cpu_gap_s"] < 0.06
+    assert 0 < killed["phases_cpu"]["sweep"] <= killed["phases"]["sweep"] + 0.001
+    assert asked["phases_cpu"]["sweep"] == 0.0
+    if backend == "decremental":
+        assert 0 < asked["stage_cpu_s"] <= asked["stage_s"] + 0.001
+        assert 0 < asked["dispatch_cpu_s"] <= asked["dispatch_s"] + 0.001
+
+
 def test_no_profiler_no_record_and_no_wake_handle():
     w = World("array")
     w.ring(3)

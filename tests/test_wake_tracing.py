@@ -19,10 +19,21 @@ sweep counters, and the collector's phases as trace annotations.
   the wake before, ``stage_s`` and ``dispatch_s`` inside their phases,
   the stop cascade of what the sweep freed counted in from the
   dispatchers' threads; the counters' readback is nobody's phase; the
-  profiler listens to no event; and none of it exists without one.
+  profiler listens to no event; and none of it exists without one;
+- who had the host: thread CPU beside the wall on the wake and on every
+  phase and part, the workers' CPU clocks read from outside over the
+  wake, its sweep and the gap before it, CPython's collections counted
+  into the wake they paused, and a stall of the whole process told from
+  a thread that held the GIL by the process's CPU time across it.
 """
 
 import gc
+import json
+import os
+import random
+import signal
+import subprocess
+import sys
 import threading
 import time
 
@@ -427,7 +438,11 @@ def test_annotations_enclose_every_phase_on_the_collectors_thread():
     log = list(notes.log)
     # the hook was swapped in while the collector ran: start at a whole wake
     first = next(i for i, (kind, name, _, _) in enumerate(log) if (kind, name) == ("enter", "uigc:wake"))
-    log = log[first:]
+    # CPython's collector and the watchdog write theirs on whatever thread
+    # they run on, with their own arguments: each entered and left, apart
+    others = [e for e in log[first:] if e[1] in (profile.GC_ANNOTATION, profile.STALL_ANNOTATION)]
+    assert [e[0] for e in others] == ["enter", "exit"] * (len(others) // 2)
+    log = [e for e in log[first:] if e not in others]
     # one thread, properly nested, every phase inside a wake of its ordinal
     assert len({thread for *_, thread in log}) == 1
     stack, seen = [], set()
@@ -816,6 +831,428 @@ def test_wake_profile_alone_leaves_the_recorder_off_and_still_fills_device_s():
     assert doc["phases"]["trace"]["device_total_s"] >= sum(r["device_s"] for r in called) > 0
 
 
+# ------------------------------------------------------------------- #
+# who had the host: CPU clocks beside the wall clock
+# ------------------------------------------------------------------- #
+
+
+def _spin(seconds):
+    """Burn ``seconds`` of the calling thread's own CPU time."""
+    until = time.thread_time() + seconds
+    while time.thread_time() < until:
+        pass
+
+
+class Spinner:
+    """A thread that stands for a dispatcher worker: it spins for
+    ``seconds`` of its own CPU each time it is told to, and waits."""
+
+    def __init__(self, seconds=0.05):
+        self.seconds = seconds
+        self.go, self.done, self.leave = threading.Event(), threading.Event(), False
+        self.thread = threading.Thread(target=self._run, daemon=True)
+        self.thread.start()
+
+    def _run(self):
+        while True:
+            self.go.wait()
+            self.go.clear()
+            if self.leave:
+                return
+            _spin(self.seconds)
+            self.done.set()
+
+    def spin(self):
+        """Have it spin, and wait (off the CPU) until it has."""
+        self.done.clear()
+        self.go.set()
+        assert self.done.wait(30)
+
+    def exit(self):
+        self.leave = True
+        self.go.set()
+        self.thread.join(10)
+        assert not self.thread.is_alive()
+
+
+def _clocked(threads=None, **kw):
+    """A started profiler on a fast watchdog, its annotations in a log."""
+    notes = FakeAnnotations()
+    prof = profile.WakeProfiler("n", annotate=notes, threads=threads,
+                                watch_period_s=0.01, stall_threshold_s=0.05, **kw)
+    prof.start()
+    return prof, notes
+
+
+def test_phases_cpu_is_exclusive_and_adds_up_to_cpu_s():
+    prof, _ = _clocked()
+    try:
+        wake = prof.begin_wake()
+        with wake.phase("trace"):
+            _spin(0.02)
+            with wake.phase("sweep"):  # pauses ``trace`` on both clocks
+                _spin(0.03)
+            _spin(0.01)
+            with wake.part("stage_s", "stage"):
+                _spin(0.01)
+                time.sleep(0.03)
+        time.sleep(0.02)  # between brackets: the wake's, no phase's
+        wake.end(entries=0, garbage=0)
+        (rec,) = prof.wakes_since(0.0)
+    finally:
+        prof.close()
+    cpu = rec["phases_cpu"]
+    assert set(cpu) == set(profile.PHASES)
+    assert 0.04 <= cpu["trace"] < 0.07 and 0.03 <= cpu["sweep"] < 0.05, cpu
+    assert all(cpu[name] == 0.0 for name in cpu if name not in ("trace", "sweep"))
+    # as ``phases`` to ``wall_s``: the sum, less the statements between brackets
+    assert 0 <= rec["cpu_s"] - sum(cpu.values()) < 0.005, rec
+    assert 0 <= rec["wall_s"] - sum(rec["phases"].values())
+    assert rec["cpu_s"] < rec["wall_s"] - 0.04  # the two sleeps
+    # a part's twin: its thread CPU beside its wall, the phase's clock running on
+    assert 0.01 <= rec["stage_cpu_s"] < 0.02 and rec["stage_s"] >= 0.04
+    assert rec["process_cpu_s"] >= rec["cpu_s"] - 0.005
+
+
+@pytest.mark.parametrize("how", ["sleeps", "spins"])
+def test_a_phase_that_sleeps_stood_and_one_that_spins_ran(how):
+    prof, _ = _clocked()
+    try:
+        wake = prof.begin_wake()
+        with wake.phase("device"):
+            if how == "sleeps":
+                time.sleep(0.1)
+            else:
+                _spin(0.1)
+        wake.end(entries=0, garbage=0)
+        (rec,) = prof.wakes_since(0.0)
+    finally:
+        prof.close()
+    wall, cpu = rec["phases"]["device"], rec["phases_cpu"]["device"]
+    if how == "sleeps":
+        assert wall >= 0.1 and cpu < 0.02, (wall, cpu)
+    else:
+        # beside five other workers the thread may be kept waiting: wide
+        assert 0.1 <= cpu <= wall + 0.001 and cpu > 0.25 * wall, (wall, cpu)
+
+
+def test_a_worker_spinning_inside_the_sweep_is_the_workers_cpu_not_the_collectors():
+    busy, idle = Spinner(), Spinner()
+    prof, _ = _clocked(lambda: {"workers": [busy.thread.ident, idle.thread.ident]})
+    try:
+        wake = prof.begin_wake()
+        with wake.phase("trace"):
+            with wake.phase("sweep"):
+                busy.spin()
+        wake.end(entries=0, garbage=0)
+        (rec,) = prof.wakes_since(0.0)
+    finally:
+        prof.close()
+        busy.exit(), idle.exit()
+    assert 0.05 <= rec["workers_cpu_sweep_s"] < 0.08, rec
+    assert rec["workers_cpu_sweep_s"] <= rec["workers_cpu_s"] < 0.08
+    # one thread had all of it
+    assert 0.05 <= rec["workers_busy_max_s"] <= rec["workers_cpu_s"]
+    assert rec["workers_cpu_s"] - rec["workers_busy_max_s"] < 0.01
+    # the collector stood meanwhile: the sweep's wall is not its CPU
+    assert rec["phases"]["sweep"] >= 0.05 and rec["phases_cpu"]["sweep"] < 0.02
+    assert rec["workers_cpu_gap_s"] is None  # no wake before it
+
+
+def test_a_worker_spinning_between_two_wakes_is_the_gaps_alone_and_a_dead_one_is_dropped():
+    worker = Spinner()
+    prof, _ = _clocked(lambda: {"workers": [worker.thread.ident]})
+
+    def wake():
+        w = prof.begin_wake()
+        with w.phase("sweep"):
+            pass
+        w.end(entries=0, garbage=0)
+
+    try:
+        wake()
+        worker.spin()
+        wake()
+        worker.exit()
+        wake()
+        first, second, third = prof.wakes_since(0.0)
+    finally:
+        prof.close()
+    assert 0.05 <= second["workers_cpu_gap_s"] < 0.08, second
+    assert second["workers_cpu_s"] < 0.01 and second["workers_cpu_sweep_s"] < 0.01
+    assert second["workers_busy_max_s"] < 0.01
+    assert second["gap_s"] >= 0.05
+    # its clock no longer reads (or reads what the exit took): no error
+    assert 0 <= third["workers_cpu_s"] == third["workers_busy_max_s"] < 0.01
+    assert 0 <= third["workers_cpu_gap_s"] < 0.01
+
+
+def test_without_the_runtimes_threads_the_workers_fields_read_none():
+    prof, _ = _clocked()
+    try:
+        wake = prof.begin_wake()
+        with wake.phase("sweep"):
+            pass
+        wake.end(entries=0, garbage=0)
+        (rec,) = prof.wakes_since(0.0)
+    finally:
+        prof.close()
+    for field in ("workers_cpu_s", "workers_cpu_sweep_s", "workers_cpu_gap_s",
+                  "workers_busy_max_s"):
+        assert field in rec and rec[field] is None
+    assert rec["cpu_s"] >= 0 and rec["process_cpu_s"] >= 0
+
+
+def test_a_collection_inside_a_phase_is_that_wakes_pause_and_no_others():
+    prof, notes = _clocked()
+    gc.disable()  # the forced collections only
+    try:
+        def wake(collect):
+            w = prof.begin_wake()
+            with w.phase("fold"):
+                if collect == "fold":
+                    gc.collect()
+                    gc.collect(1)
+            with w.phase("sweep"):
+                if collect == "sweep":
+                    gc.collect()
+                gc.collect(0)  # generation 0: nobody's finding
+            w.end(entries=0, garbage=0)
+
+        wake(None)
+        wake("fold")
+        gc.collect()  # between wakes: no wake's
+        wake("sweep")
+        wake(None)
+        quiet, folded, swept, after = prof.wakes_since(0.0)
+    finally:
+        gc.enable()
+        prof.close()
+    for rec in (quiet, after):
+        assert (rec["gc_s"], rec["gc_sweep_s"], rec["gc_full"]) == (0.0, 0.0, 0)
+    assert folded["gc_s"] > 0 and folded["gc_full"] == 1 and folded["gc_sweep_s"] == 0.0
+    assert folded["gc_s"] <= folded["phases"]["fold"]
+    assert swept["gc_full"] == 1 and 0 < swept["gc_sweep_s"] == swept["gc_s"]
+    assert swept["gc_s"] <= swept["phases"]["sweep"]
+    # on the trace's clock: entered at start, left at stop, on the thread
+    # that collected, with the generation; nothing for generation 0
+    marks = [(kind, args) for kind, name, args, _ in notes.log if name == profile.GC_ANNOTATION]
+    assert marks == [(kind, {"gen": gen}) for gen in (2, 1, 2, 2) for kind in ("enter", "exit")]
+    assert prof._on_gc not in gc.callbacks
+
+
+def test_the_profiler_is_started_by_telemetry_and_stopped_by_its_close():
+    kit, root, Poke, engine, wake = _manual({})
+    try:
+        prof = kit.system.telemetry.profiler
+        assert gc.callbacks.count(prof._on_gc) == 1
+        watchdog = prof._watchdog
+        assert watchdog.is_alive() and watchdog.name == "uigc-stallwatch"
+        system = kit.system
+        clocks = prof._clocks.clocks
+        # the runtime's threads, learned once: the pool, the timer, the Bookkeeper's
+        assert len(clocks["workers"]) == len(system.dispatcher.thread_idents()) > 0
+        assert len(clocks["timer"]) == 1 and len(clocks["collector"]) == 1
+        root.tell(Poke())
+        rec = wake()
+        assert rec["workers_cpu_s"] >= 0 and rec["workers_busy_max_s"] >= 0
+        assert rec["cpu_s"] > 0 and set(rec["phases_cpu"]) == set(profile.PHASES)
+        assert "stalls" in prof.to_json()
+    finally:
+        kit.shutdown()
+    assert prof._on_gc not in gc.callbacks
+    assert not watchdog.is_alive() and prof._watchdog is None
+
+
+def _longest(stalls):
+    assert stalls, "no stall was recorded"
+    return max(stalls, key=lambda stall: stall["late_s"])
+
+
+def test_a_thread_that_holds_the_gil_is_a_stall_with_the_processes_cpu_in_it(tmp_path):
+    holder = Spinner()
+    data = [random.random() for _ in range(2_000_000)]
+    stacks = tmp_path / "stacks.txt"
+    profile.WakeProfiler.dump_stalls_to(str(stacks))
+    prof, notes = _clocked(lambda: {"workers": [holder.thread.ident]})
+    sunk = []
+    profile.record_sink = sunk.append
+    try:
+        time.sleep(0.1)  # the watchdog ticks freely
+        wake = prof.begin_wake()
+        with wake.phase("fold"):
+            t0 = time.perf_counter()
+            data.sort()  # one C call: the GIL is not let go of
+            held = time.perf_counter() - t0
+        wake.end(entries=0, garbage=0)
+        time.sleep(0.1)
+        stalls = prof.to_json()["stalls"]
+    finally:
+        profile.record_sink = None
+        prof.close()
+        profile.WakeProfiler.dump_stalls_to(None)
+        holder.exit()
+    assert held > 0.15, held  # else the case shows nothing
+    stall = _longest(stalls)
+    assert held - 0.1 < stall["late_s"] <= held + 0.1, (held, stall)
+    # somebody RAN all the while: the program's, and not the workers'
+    assert stall["process_cpu_s"] > 0.5 * stall["late_s"], stall
+    assert stall["workers_cpu_s"] < 0.05 and stall["timer_cpu_s"] is None
+    assert (stall["wake"], stall["phase"]) == (0, "fold")
+    assert stall in [s for s in sunk if "late_s" in s] and any("wall_s" in r for r in sunk)
+    marks = [args for kind, name, args, _ in notes.log
+             if (kind, name) == ("enter", profile.STALL_ANNOTATION)]
+    assert {"late_ms": round(stall["late_s"] * 1e3)} in marks
+    # the stacks, written by a thread that needs no GIL WHILE it was held:
+    # the dump begins where the record says, and names the call that held on
+    text = stacks.read_bytes()[stall["dump_offset"]:].decode()
+    assert text.startswith("Timeout (0:00:00.07"), text[:200]
+    assert "test_a_thread_that_holds_the_gil" in text
+
+
+_CHILD = r"""
+import contextlib, json, sys, time
+from uigc_tpu.telemetry import profile
+prof = profile.WakeProfiler("child", annotate=lambda name, **args: contextlib.nullcontext(),
+                            watch_period_s=0.01, stall_threshold_s=0.05)
+prof.start()
+print("ready", flush=True)
+sys.stdin.readline()
+print(json.dumps(prof.to_json()["stalls"]), flush=True)
+prof.close()
+"""
+
+
+def test_a_stopped_process_is_a_stall_in_which_nobody_ran():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    child = subprocess.Popen(
+        [sys.executable, "-c", _CHILD], cwd=root, text=True,
+        stdin=subprocess.PIPE, stdout=subprocess.PIPE)
+    try:
+        assert child.stdout.readline().strip() == "ready"
+        time.sleep(0.1)
+        child.send_signal(signal.SIGSTOP)
+        time.sleep(0.5)
+        child.send_signal(signal.SIGCONT)
+        time.sleep(0.1)
+        child.stdin.write("\n")
+        child.stdin.flush()
+        stalls = json.loads(child.stdout.readline())
+        assert child.wait(30) == 0
+    finally:
+        if child.poll() is None:
+            child.kill()
+    stall = _longest(stalls)
+    assert 0.3 < stall["late_s"] < 5.0, stall
+    # the host's: the process had no CPU across it
+    assert stall["process_cpu_s"] < 0.1 * stall["late_s"], stall
+    assert stall["wake"] is None and stall["workers_cpu_s"] is None
+
+
+def test_a_sink_that_raises_does_not_take_the_wake_with_it():
+    prof = profile.WakeProfiler("n", annotate=FakeAnnotations())
+    seen = []
+
+    def sink(record):
+        seen.append(record["wake"])
+        raise RuntimeError("a full disk")
+
+    profile.record_sink = sink
+    try:
+        for _ in range(2):
+            wake = prof.begin_wake()
+            with wake.phase("sweep"):
+                assert wake.stack  # the sink is called after the wake, outside every phase
+            wake.end(entries=0, garbage=0)
+    finally:
+        profile.record_sink = None
+    assert seen == [0, 1] and [r["wake"] for r in prof.wakes_since(0.0)] == [0, 1]
+    assert prof._active is None
+
+
+def test_telemetry_dump_renders_a_two_wake_document_and_a_sinks_lines(tmp_path, capsys):
+    """``tools/telemetry_dump.py --wakes``: the record's way out of a run.
+    A ``dump()`` document and the JSON lines a ``record_sink`` wrote give
+    the same medians, the phases on and off the CPU, the sweep's wall split
+    into who ran, and each stall with its reading."""
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(__file__)), "tools"))
+    import telemetry_dump
+
+    worker = Spinner(0.03)
+    prof, _ = _clocked(lambda: {"workers": [worker.thread.ident]})
+    lines = tmp_path / "sink.jsonl"
+    with open(lines, "w") as fh:
+        profile.record_sink = lambda record: fh.write(json.dumps(record) + "\n")
+        try:
+            for spin in (0.01, 0.03):
+                wake = prof.begin_wake()
+                assert not wake.stack
+                with wake.phase("device"):
+                    with wake.part("device_s"):
+                        time.sleep(0.02)
+                with wake.phase("sweep"):
+                    _spin(spin)
+                    worker.spin()
+                    wake.note(freed=4, kills=1)
+                wake.end(entries=2, garbage=4)
+            # a made-up stall of each reading, as the watchdog hands them over
+            for cpu, collector in ((0.01, 0.0), (1.9, 1.85)):
+                profile.record_sink({
+                    "t": 0.0, "at": 12.5, "late_s": 2.0, "process_cpu_s": cpu, "wake": 1,
+                    "phase": "sweep", "workers_cpu_s": 0.0, "timer_cpu_s": 0.0,
+                    "collector_cpu_s": collector})
+            doc = prof.dump(str(tmp_path / "doc.json"))
+        finally:
+            profile.record_sink = None
+            prof.close()
+            worker.exit()
+    first, second = doc["recent"]
+    assert telemetry_dump.main(["--wakes", str(tmp_path / "doc.json"), "--format", "json"]) == 0
+    from_doc = json.loads(capsys.readouterr().out)
+    assert telemetry_dump.main(["--wakes", str(lines), "--format", "json"]) == 0
+    from_lines = json.loads(capsys.readouterr().out)
+    assert from_doc["fields"] == from_lines["fields"] and from_doc["sweep"] == from_lines["sweep"]
+    assert from_doc["wakes"] == from_doc["called_the_device"] == 2
+
+    def mid(read):
+        return (read(first) + read(second)) / 2
+
+    fields = from_doc["fields"]
+    assert fields["wall_s"]["all"] == pytest.approx(mid(lambda r: r["wall_s"]))
+    assert fields["workers_cpu_sweep_s"]["device"] == pytest.approx(
+        mid(lambda r: r["workers_cpu_sweep_s"]))
+    assert fields["freed"] == {"all": 4, "device": 4}
+    # the phase that slept stood; the sweep: the collector spun 10 and 30 ms,
+    # the worker 30 each time, while the collector waited for it
+    device, sweep = from_doc["phases"]["device"], from_doc["sweep"]
+    assert device["wall_ms"] >= 20 and device["cpu_ms"] < 5 and device["off_cpu_pct"] > 75
+    assert device["wall_median_ms"] == pytest.approx(device["wall_ms"])  # of two
+    assert from_doc["sweep_typical"] is None  # no full collection: the same wakes
+    assert 0 < from_doc["cpu_tick_ms"] <= min(r["cpu_s"] for r in (first, second)) * 1e3
+    assert 20 <= sweep["sweep_cpu_ms"] < 35 and 30 <= sweep["sweep_workers_cpu_ms"] < 40
+    assert sweep["sweep_ms"] >= 50 and sweep["wakes"] == 2
+    assert sweep["sweep_unrun_ms"] == pytest.approx(
+        mid(lambda r: r["phases"]["sweep"] - r["phases_cpu"]["sweep"]
+            - r["workers_cpu_sweep_s"]) * 1e3)
+    assert sweep["gc_in_sweep_ms"] == 0 and from_doc["gc_in_wake_ms"] == 0
+    assert 20 < from_doc["wake_off_cpu_pct"] < 95
+    assert from_doc["stalls_in_window"] == 0 and from_lines["stalls_in_window"] == 2
+    assert [s["reading"] for s in from_lines["stalls"]] == [
+        "the host's: nobody ran", "the program's: the collector ran"]
+    # and as text: the table and the stall list
+    assert telemetry_dump.main(["--wakes", str(lines)]) == 0
+    text = capsys.readouterr().out
+    assert "wakes 2  called the device 2  stalls 2" in text
+    assert "off-CPU %" in text and "collector's CPU" in text and "nobody ran" in text
+    # the first wake left out: one wake is left
+    assert telemetry_dump.main(["--wakes", str(lines), "--from-wake", "1", "--format", "json"]) == 0
+    assert json.loads(capsys.readouterr().out)["wakes"] == 1
+    assert telemetry_dump.main(
+        ["--wakes", str(lines), "--to-wake", "1", "--format", "json"]) == 0
+    assert json.loads(capsys.readouterr().out)["fields"]["wall_s"]["all"] == first["wall_s"]
+    assert telemetry_dump.main(["--wakes", str(tmp_path / "none.json")]) == 1
+
+
 @pytest.mark.parametrize("extra", [{}, {"uigc.telemetry.metrics": True}],
                          ids=["no-telemetry", "metrics-only"])
 def test_without_a_profiler_no_cell_is_stamped_no_write_is_timed_nothing_is_called(
@@ -829,10 +1266,21 @@ def test_without_a_profiler_no_cell_is_stamped_no_write_is_timed_nothing_is_call
     real = cell_mod.ActorCell.note_freed
     monkeypatch.setattr(cell_mod.ActorCell, "note_freed",
                         lambda self, wake: stamped.append(wake) or real(self, wake))
+    # the second clock: who of this system's threads reads a thread's CPU time
+    clock_reads = []
+    for name in ("thread_time", "pthread_getcpuclockid"):
+        def counted(*a, _real=getattr(time, name), _name=name):
+            clock_reads.append((_name, threading.current_thread().name))
+            return _real(*a)
+        monkeypatch.setattr(time, name, counted)
+    watchdogs = {t for t in threading.enumerate() if t.name == "uigc-stallwatch"}
+    callbacks = list(gc.callbacks)
     kit, root, Spawn, Drop, stopped, size = _tree(extra)
     try:
         tel = kit.system.telemetry
         assert (tel is None) == (not extra) and (tel is None or tel.profiler is None)
+        assert {t for t in threading.enumerate() if t.name == "uigc-stallwatch"} <= watchdogs
+        assert gc.callbacks == callbacks
         engine = kit.system.engine
         plane = engine.packed_plane
         root.tell(Spawn())
@@ -845,5 +1293,6 @@ def test_without_a_profiler_no_cell_is_stamped_no_write_is_timed_nothing_is_call
     finally:
         kit.shutdown()
     assert not stamped and not called
+    assert not [read for read in clock_reads if read[1].startswith("cascade")], clock_reads
     assert all(getattr(c, "_freed_wake", None) is None for c in cells)
     assert not plane.timed and plane.first_write is None and engine.queue_since is None

@@ -323,8 +323,13 @@ DEFAULTS: Dict[str, Any] = {
     # Collector wake profiler: break each Bookkeeper wake into exclusive
     # phases (ingest, fold, trace, layout, upload, device, readback,
     # sweep, broadcast), also written as uigc:<phase> annotations onto a
-    # jax.profiler trace's clock; dump BENCH-style JSON via
-    # system.telemetry.profiler.  Enables the event recorder.
+    # jax.profiler trace's clock, with thread CPU beside the wall on the
+    # wake and every phase, the dispatcher workers' CPU clocks read from
+    # outside over the wake, its sweep and the gap before it, CPython's
+    # collections (one gc.callbacks entry, uigc:gc) and a watchdog thread
+    # that records every stall over 0.5 s with the process's CPU time
+    # across it (uigc:stall); dump BENCH-style JSON via
+    # system.telemetry.profiler.  Leaves the event recorder off.
     "uigc.telemetry.wake-profile": False,
     # Localhost HTTP exposition: serve /metrics (Prometheus text) and
     # /metrics.json on 127.0.0.1.  -1 disables; 0 binds an ephemeral

@@ -16,7 +16,7 @@ import sys
 import threading
 import traceback
 from collections import deque
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 from ..utils import events
 
@@ -149,6 +149,11 @@ class Dispatcher:
         (``uigc_dispatcher_depth``; approximate by nature)."""
         return self._queue.qsize()
 
+    def thread_idents(self) -> List[int]:
+        """``Thread.ident`` of every worker still running: what a reader
+        of their CPU clocks from outside (telemetry/profile.py) needs."""
+        return [t.ident for t in self._workers if t.is_alive()]
+
     def _run(self) -> None:
         events.set_thread_origin(self._origin)
         while True:
@@ -184,6 +189,10 @@ class PinnedDispatcher:
     def execute(self, runnable: Callable[[], None]) -> None:
         if not self._shutdown:
             self._queue.put(runnable)
+
+    def thread_idents(self) -> List[int]:
+        """As :meth:`Dispatcher.thread_idents`, of the one thread."""
+        return [self._thread.ident] if self._thread.is_alive() else []
 
     def _run(self) -> None:
         events.set_thread_origin(self._origin)
@@ -250,6 +259,10 @@ class TimerService:
         with self._cond:
             for key in self._cancelled:
                 self._cancelled[key] = True
+
+    def thread_idents(self) -> List[int]:
+        """As :meth:`Dispatcher.thread_idents`, of the timer thread."""
+        return [self._thread.ident] if self._thread.is_alive() else []
 
     def _run(self) -> None:
         import time

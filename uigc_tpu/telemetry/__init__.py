@@ -38,7 +38,7 @@ root (`system.telemetry`).
 
 from __future__ import annotations
 
-from typing import TYPE_CHECKING, Any, List, Optional
+from typing import TYPE_CHECKING, Any, Dict, List, Optional
 
 from ..utils import events
 from .exporter import (
@@ -149,7 +149,10 @@ class Telemetry:
             # uigc_wake_phase_seconds{phase=...} histograms, not just
             # its BENCH-JSON dump.
             # No listener: the collector hands its backend the wake.
-            self.profiler = WakeProfiler(system.address, registry=self.registry)
+            self.profiler = WakeProfiler(
+                system.address, registry=self.registry, threads=self._runtime_threads
+            )
+            self.profiler.start()
             engine = getattr(system, "engine", None)
             if engine is not None:
                 engine.wake_profiler = self.profiler
@@ -185,6 +188,20 @@ class Telemetry:
             events.recorder.enable()
             for listener in self._listeners:
                 events.recorder.add_listener(listener)
+
+    def _runtime_threads(self) -> Dict[str, List[int]]:
+        """The runtime's threads by class, for the profiler's reads of
+        their CPU clocks (``profile.THREAD_CLASSES``).  The pinned
+        dispatchers that exist when telemetry attaches are the engine's:
+        the Bookkeeper's, or the MAC detector's."""
+        system = self.system
+        return {
+            "workers": system.dispatcher.thread_idents(),
+            "timer": system.timers.thread_idents(),
+            "collector": [
+                ident for pinned in system._pinned for ident in pinned.thread_idents()
+            ],
+        }
 
     @staticmethod
     def _time_packed_plane(engine: Any, on: bool) -> None:
@@ -395,6 +412,8 @@ class Telemetry:
         if engine is not None and engine.wake_profiler is self.profiler:
             engine.wake_profiler = None
             self._time_packed_plane(engine, False)
+        if self.profiler is not None:
+            self.profiler.close()  # its watchdog and its gc.callbacks entry
         if self.observatory is not None:
             if engine is not None and (
                 engine.device_observatory is self.observatory
